@@ -17,6 +17,10 @@ IMAGE = "ghcr.io/example/shai-tpu:latest"        # templated by build/build.sh
 # v5e podslice topology per requested chip count (GKE nodepool contract)
 TPU_TOPOLOGY = {1: "1x1", 4: "2x2", 8: "2x4"}
 CPU_SELECTOR = {"nodepool": "cpu-compute"}
+# the XLA compile cache on the shared artifacts PVC: compile Jobs fill it,
+# serving pods boot from it (core.aot.enable_persistent_cache sets no
+# directory of its own where this variable is set)
+CACHE_DIR = "/artifacts/xla-cache"
 
 # (app, model-name-in-registry, tier, env-overrides, tpu-chips)
 #
@@ -33,7 +37,7 @@ UNITS = [
     # latency tier keeps the MEASURED on-chip dispatch policy (r3
     # perf_attn: XLA attention won at batch 1-2, which is what this tier
     # serves at low occupancy). The perf model says flash wins at batch 4
-    # (PERF_MODEL.md) — the watcher's measured ramp decides before flash
+    # (PERF_MODEL.md) — a measured on-chip ramp decides before flash
     # becomes this tier's default; the batch-8 tier below already runs it.
     ("sd21", "sd", "tpu", {"MODEL_ID": "stabilityai/stable-diffusion-2-1-base",
                            "HEIGHT": "512", "WIDTH": "512",
@@ -54,7 +58,7 @@ UNITS = [
                              # (PERF_MODEL.md) shows XLA-attention batched
                              # steps are HBM-bound on score traffic while
                              # flash flips them MXU-bound (b4: 48.7 -> 21.7
-                             # GB/step); watcher re-validates on-chip
+                             # GB/step); not yet timed on a chip
                              "SHAI_ATTN_IMPL": "pallas"}, 1),
     ("bert", "bert", "tpu", {"MODEL_ID":
                              "distilbert-base-uncased-finetuned-sst-2-english"}, 1),
@@ -143,7 +147,8 @@ def render_unit(app: str, model: str, tier: str, env: dict, chips: int) -> str:
     env_all = {
         "APP": app, "MODEL": model, "DEVICE": "tpu" if _is_tpu(tier) else "cpu",
         "NODEPOOL": f"{tier}-pool", "PORT": "8000",
-        "ARTIFACT_ROOT": "/artifacts", **env,
+        "ARTIFACT_ROOT": "/artifacts",
+        "JAX_COMPILATION_CACHE_DIR": CACHE_DIR, **env,
     }
     env_yaml = _env_yaml(env_all)
     sel_yaml = _selector_yaml(tier, chips)
@@ -226,7 +231,8 @@ def render_job(app: str, model: str, tier: str, env: dict, chips: int) -> str:
     name = f"compile-{app}-{tier}"
     env_all = {
         "APP": app, "MODEL": model, "DEVICE": "tpu" if _is_tpu(tier) else "cpu",
-        "NODEPOOL": f"{tier}-pool", "ARTIFACT_ROOT": "/artifacts", **env,
+        "NODEPOOL": f"{tier}-pool", "ARTIFACT_ROOT": "/artifacts",
+        "JAX_COMPILATION_CACHE_DIR": CACHE_DIR, **env,
     }
     env_yaml = _env_yaml(env_all)
     sel_yaml = _selector_yaml(tier, chips)
@@ -287,7 +293,8 @@ def render_mh_unit(name: str, model: str, model_id: str, hosts: int,
                    extra_env: dict) -> str:
     env_all = {
         "APP": model, "DEVICE": "tpu", "NODEPOOL": "tpu-pool",
-        "PORT": "8000", "ARTIFACT_ROOT": "/artifacts", "MODEL_ID": model_id,
+        "PORT": "8000", "ARTIFACT_ROOT": "/artifacts",
+        "JAX_COMPILATION_CACHE_DIR": CACHE_DIR, "MODEL_ID": model_id,
         "MESH_SPEC": mesh_spec, **extra_env,
         "SHAI_COORDINATOR": f"{name}-0.{name}:8476",
         "SHAI_NUM_PROCESSES": str(hosts),

@@ -32,14 +32,14 @@ from typing import Any, List, Optional, Tuple
 #: wire collectives at jaxpr level (pbroadcast/pcast are shard_map's
 #: varying-manifest bookkeeping, not communication — excluded on purpose)
 JAXPR_COLLECTIVES = frozenset({
-    "psum", "psum2", "ppermute", "pmax", "pmin", "pgather",
+    "psum", "psum_invariant", "ppermute", "pmax", "pmin", "pgather",
     "all_to_all", "all_gather", "all_gather_invariant",
     "reduce_scatter", "psum_scatter",
 })
 
 #: host-callback primitives: each dispatch round-trips to Python
 CALLBACK_PRIMS = frozenset({
-    "pure_callback", "io_callback", "debug_callback",
+    "pure_callback", "io_callback", "debug_callback", "debug_print",
 })
 
 #: collective op mnemonics in post-optimization HLO text

@@ -17,7 +17,7 @@
   at the jaxpr tier (explicit shard_map collectives) and, where compiled,
   in post-optimization HLO (SPMD-inserted ones). A mismatch is not an
   error message at runtime; it is a slice-wide hang.
-- ``host-interop``        ``pure_callback``/``io_callback``/``debug_callback``
+- ``host-interop``        ``pure_callback``/``io_callback``/``debug_callback``/``debug_print``
   (``jax.debug.print``) in a hot executable: every dispatch round-trips
   through Python, re-serializing the step loop the async pipeline exists
   to overlap.
@@ -159,7 +159,7 @@ def _is_explicit_convert(eq) -> bool:
 
 def check_dtype_drift(progs: List[IrProgram], contract, anchors: _Anchors
                       ) -> List[Finding]:
-    import jax.core as jcore
+    from jax.extend.core import Literal
 
     findings: List[Finding] = []
     declared = set(contract.ir.bf16_programs)
@@ -180,7 +180,7 @@ def check_dtype_drift(progs: List[IrProgram], contract, anchors: _Anchors
                         converted.add(eq.outvars[0])
                     continue
                 uses_conv = any(
-                    (not isinstance(v, jcore.Literal)) and v in converted
+                    (not isinstance(v, Literal)) and v in converted
                     for v in eq.invars)
                 if not uses_conv:
                     continue
@@ -263,7 +263,7 @@ def check_host_interop(progs: List[IrProgram], contract, anchors: _Anchors
                 anchors, p, "host-interop", p.key,
                 f"host callback `{prim}` inside a hot executable — every "
                 f"dispatch round-trips through Python, serializing the "
-                f"step loop (jax.debug.print lowers to debug_callback)"))
+                f"step loop"))
     return findings
 
 

@@ -23,9 +23,10 @@ def main() -> None:
     args = ap.parse_args()
 
     cfg = ServeConfig.from_env()
-    from ..core.device import apply_platform
+    from ..core.device import apply_platform, resolve_device
 
     apply_platform(cfg.device)
+    resolve_device(cfg.device)
     report = compile_model(args.model, cfg, artifact_root=args.artifact_root,
                            self_test=not args.no_self_test)
     print(json.dumps(report, indent=1))

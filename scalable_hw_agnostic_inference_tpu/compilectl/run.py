@@ -16,7 +16,8 @@ def compile_model(name: str, cfg=None, artifact_root: Optional[str] = None,
     """AOT-compile serving unit ``name`` into the artifact root.
 
     Runs the unit's real ``load() + warmup()`` with the persistent XLA cache
-    pointed at the root, then (compile-yolo.py's pattern, reference
+    on (``core.aot.enable_persistent_cache`` owns where), then
+    (compile-yolo.py's pattern, reference
     ``app/compile-yolo.py:22-27``) self-tests with one real inference.
     Returns a report with cache contents and timings.
     """
@@ -26,8 +27,7 @@ def compile_model(name: str, cfg=None, artifact_root: Optional[str] = None,
 
     cfg = cfg or ServeConfig.from_env()
     root = artifact_root or cfg.artifact_root
-    cache_dir = os.path.join(root, "xla-cache")
-    enable_persistent_cache(cache_dir)
+    cache_dir = enable_persistent_cache()
 
     service = get_model(name)(cfg)
     t0 = time.perf_counter()

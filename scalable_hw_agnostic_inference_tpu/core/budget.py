@@ -46,32 +46,35 @@ class HbmBudgetError(RuntimeError):
     """Raised when a declared geometry cannot fit its chips' HBM."""
 
 
+#: HBM per chip by ``device_kind`` substring, first match wins ("v5 lite"
+#: before the bare "v5"); used only when the runtime reports no limit
+_HBM_GIB_BY_KIND = (("v5 lite", 16.0), ("v5litepod", 16.0), ("v5e", 16.0),
+                    ("v5p", 95.0), ("v5", 95.0), ("v4", 32.0),
+                    ("v6", 32.0), ("v3", 16.0))
+
+
 def detect_hbm_gib(device) -> float:
     """Per-chip HBM of the LIVE device — ``SHAI_HBM_GIB`` (an explicit
     operator declaration, also the capacity-math pin for deviceless bench
     A/Bs) wins, then the runtime (``memory_stats``), then the device-kind
-    table, then the v5e deploy tier. Gating on a hardcoded 16 GiB would
-    wrongly refuse working v5p/v4 deployments (and wave through smaller
-    devices)."""
+    table. A device that reports no limit and is not in the table is an
+    error: gating on an assumed 16 GiB would wrongly refuse working
+    v5p/v4 deployments and wave through smaller devices."""
     from ..obs.util import env_float
 
     declared = env_float("SHAI_HBM_GIB", 0.0)
     if declared > 0:
         return declared
-    try:
-        stats = device.memory_stats()
-        limit = (stats or {}).get("bytes_limit", 0)
-        if limit:
-            return limit / GIB
-    except Exception:
-        pass
-    kind = str(getattr(device, "device_kind", "")).lower()
-    for tag, gib in (("v5 lite", 16.0), ("v5litepod", 16.0), ("v5e", 16.0),
-                     ("v5p", 95.0), ("v5", 95.0), ("v4", 32.0),
-                     ("v6", 32.0), ("v3", 16.0)):
+    limit = (device.memory_stats() or {}).get("bytes_limit", 0)
+    if limit:
+        return limit / GIB
+    kind = str(device.device_kind).lower()
+    for tag, gib in _HBM_GIB_BY_KIND:
         if tag in kind:
             return gib
-    return HBM_GIB["v5e"]
+    raise HbmBudgetError(
+        f"device kind {device.device_kind!r} reports no memory limit and is "
+        f"not in the HBM table; declare its size with SHAI_HBM_GIB")
 
 
 @dataclasses.dataclass(frozen=True)

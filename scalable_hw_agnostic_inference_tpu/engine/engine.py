@@ -79,7 +79,8 @@ class LLMEngine:
         # HBM budget gate: on a real device an over-budget geometry must
         # refuse to boot HERE, with the breakdown, instead of OOMing minutes
         # into warmup (VERDICT r3 missing #2). CPU runs (tests, virtual-mesh
-        # dryruns) skip unless SHAI_ENFORCE_HBM=1 opts in.
+        # dryruns) skip unless SHAI_ENFORCE_HBM=1 opts in (with the size to
+        # enforce against declared in SHAI_HBM_GIB).
         from ..obs.util import env_flag as _env_flag
 
         if (jax.devices()[0].platform != "cpu"
@@ -310,13 +311,10 @@ class LLMEngine:
                              ecfg.model, quantized=ecfg.quantization == "int8",
                              tp=ecfg.tensor_parallel_size)))
         hbm_limit = 0.0
-        try:
+        if jax.local_devices()[0].platform != "cpu":
             from ..core.budget import GIB, detect_hbm_gib
 
-            if jax.local_devices()[0].platform != "cpu":
-                hbm_limit = detect_hbm_gib(jax.local_devices()[0]) * GIB
-        except Exception:  # deviceless dryruns must still boot
-            pass
+            hbm_limit = detect_hbm_gib(jax.local_devices()[0]) * GIB
         self.obs.hbm = HbmLedger(bytes_limit=hbm_limit)
         # host KV tier counters ride the same ONE provider seam as the
         # conformance instruments: /stats, /metrics, and the admission

@@ -200,23 +200,21 @@ def _tp_attention(shardings: Optional["EngineShardings"], q, k, v, *,
     if shardings is None:
         return dot_product_attention(q, k, v, kv_lengths=kv_lengths,
                                      causal=causal)
-    from jax.experimental.shard_map import shard_map
-
     heads = P(None, None, "tp", None)
     if kv_lengths is None:
-        return shard_map(
+        return jax.shard_map(
             lambda q_, k_, v_: dot_product_attention(q_, k_, v_,
                                                      causal=causal),
             mesh=shardings.mesh, in_specs=(heads,) * 3, out_specs=heads,
-            check_rep=False,
+            check_vma=False,
         )(q, k, v)
-    return shard_map(
+    return jax.shard_map(
         lambda q_, k_, v_, n_: dot_product_attention(
             q_, k_, v_, kv_lengths=n_, causal=causal),
         mesh=shardings.mesh,
         in_specs=(heads, heads, heads, P(None)),
         out_specs=heads,
-        check_rep=False,
+        check_vma=False,
     )(q, k, v, kv_lengths)
 
 
@@ -395,24 +393,22 @@ def _pool_kernel_call(kernel, shardings: Optional["EngineShardings"],
     never diverge between the two."""
     if shardings is None:
         return kernel(qf, kpool, vpool, tf, lf, ks, vs)
-    from jax.experimental.shard_map import shard_map
-
     heads_q = P(None, "tp", None)
     heads_kv = P(None, None, "tp", None)
     if ks is None:
-        return shard_map(
+        return jax.shard_map(
             lambda q_, k_, v_, t_, l_: kernel(q_, k_, v_, t_, l_),
             mesh=shardings.mesh,
             in_specs=(heads_q, heads_kv, heads_kv, P(None, None), P(None)),
-            out_specs=heads_q, check_rep=False,
+            out_specs=heads_q, check_vma=False,
         )(qf, kpool, vpool, tf, lf)
-    return shard_map(
+    return jax.shard_map(
         lambda q_, k_, v_, t_, l_, ks_, vs_: kernel(
             q_, k_, v_, t_, l_, ks_, vs_),
         mesh=shardings.mesh,
         in_specs=(heads_q, heads_kv, heads_kv, P(None, None), P(None),
                   P(None, "tp"), P(None, "tp")),
-        out_specs=heads_q, check_rep=False,
+        out_specs=heads_q, check_vma=False,
     )(qf, kpool, vpool, tf, lf, ks, vs)
 
 

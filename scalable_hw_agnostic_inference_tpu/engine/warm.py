@@ -114,6 +114,25 @@ def warm_executables(eng, prefix_lens: Sequence[int] = (0,)) -> int:
 def _run_warm_calls(eng) -> None:
     ecfg = eng.ecfg
     B, M = ecfg.max_num_seqs, ecfg.blocks_per_seq
+
+    def warm_sampler(logits, per_row: bool) -> None:
+        """The admission-time sampler and the first token's logprob readout
+        are part of the closed set too. They are plain jits, keyed on what
+        the executable hands them — so they are warmed on its OWN logits:
+        under tensor parallelism those are replicated over the mesh, and a
+        warm-up on fresh single-device zeros compiled both a second time
+        after ready (seconds each, inside the first requests)."""
+        K = logits.shape[0]
+        key = jax.random.PRNGKey(0)
+        if per_row:  # _admit_batch / _admit_fanout: per-row knob arrays
+            eng._sample1(logits, key, jnp.ones((K,), jnp.float32),
+                         jnp.zeros((K,), jnp.int32),
+                         jnp.ones((K,), jnp.float32))
+        if K == 1:   # _admit_one, prefix and continuation: scalar knobs
+            eng._sample1(logits, key, 1.0, 0, 1.0)
+        jax.block_until_ready(
+            eng._lp1(logits, jnp.zeros((K,), jnp.int32)))
+
     for key, fn in list(eng._prefill.items()):
         if key[0] == "rcont":
             # dynamic-start ragged continuation: the start rides as data
@@ -125,7 +144,7 @@ def _run_warm_calls(eng) -> None:
                 jnp.ones((1,), jnp.int32),
                 jnp.zeros((1, M), jnp.int32),
                 jnp.zeros((1,), jnp.int32))
-            logits.block_until_ready()
+            warm_sampler(logits, per_row=False)
             continue
         if key[0] == "cont":
             args = [eng.params, eng.cache.kv,
@@ -138,7 +157,7 @@ def _run_warm_calls(eng) -> None:
                          jnp.full((1,), max(eng.cross_seq_len, 1),
                                   jnp.int32)]
             eng.cache.kv, logits = fn(*args)
-            logits.block_until_ready()
+            warm_sampler(logits, per_row=False)
             continue
         bucket, P_, K = key
         ids = jnp.zeros((K, bucket - P_), jnp.int32)
@@ -150,7 +169,7 @@ def _run_warm_calls(eng) -> None:
             args += [eng._cross_zeros(K), jnp.zeros((K,), jnp.float32),
                      jnp.full((K,), max(eng.cross_seq_len, 1), jnp.int32)]
         eng.cache.kv, logits = fn(*args)
-        logits.block_until_ready()
+        warm_sampler(logits, per_row=P_ == 0)
     for (m, bb), fn in list(eng._decode_fns.items()):
         # async engines warm the feedback variant through the same ladder
         # (one extra pos+1 output rides in *_rest; the donated position
@@ -164,7 +183,16 @@ def _run_warm_calls(eng) -> None:
             args += [eng._cross_kv, jnp.zeros((bb,), jnp.float32),
                      jnp.zeros((bb,), jnp.int32),
                      jnp.full((bb,), max(eng.cross_seq_len, 1), jnp.int32)]
-        eng.cache.kv, nxt, *_rest = fn(*args)
+        eng.cache.kv, nxt, *rest = fn(*args)
+        if eng._async:
+            # the steady path feeds a step's sampled tokens and pos + 1
+            # straight back as the next step's inputs. Under tensor
+            # parallelism those outputs carry the mesh in their type, which
+            # makes that call a second trace of the same executable: warm
+            # it the way it will be called, or the first steady step of
+            # every batch bucket compiles after ready
+            args[1:4] = [eng.cache.kv, nxt, rest[0]]
+            eng.cache.kv, nxt, *rest = fn(*args)
         nxt.block_until_ready()
     for bb, fn in list(eng._fused_fns.items()):
         # fused mixed-phase executables: decode-style null rows plus the
@@ -205,19 +233,3 @@ def _run_warm_calls(eng) -> None:
         eng._cross_kv = eng._cross_write(
             eng._cross_kv, per_layer, jnp.int32(0))
         jax.block_until_ready(eng._cross_kv)
-    # the host-side sampler used at admission time is part of the closed
-    # set too — both signatures: scalar knobs (_admit_one, prefix path)
-    # and per-row arrays at every warmed batch size (_admit_batch)
-    V = eng.cfg.vocab_size
-    eng._sample1(
-        jnp.zeros((1, V), jnp.float32),
-        jax.random.PRNGKey(0), 1.0, 0, 1.0).block_until_ready()
-    for key in eng._prefill:
-        if key[0] in ("cont", "rcont"):
-            continue
-        _, P_, K = key
-        if P_ == 0:
-            eng._sample1(
-                jnp.zeros((K, V), jnp.float32), jax.random.PRNGKey(0),
-                jnp.ones((K,), jnp.float32), jnp.zeros((K,), jnp.int32),
-                jnp.ones((K,), jnp.float32)).block_until_ready()

@@ -22,12 +22,14 @@ the reference's ColumnParallelLinear/RowParallelLinear class pair (reference
 from __future__ import annotations
 
 import dataclasses
+import functools
+import itertools
 from typing import Any, Dict, List, Optional, Tuple
 
 import flax.linen as nn
 import jax
 import jax.numpy as jnp
-from jax.sharding import PartitionSpec as P
+from jax.sharding import NamedSharding, PartitionSpec as P
 
 from ..ops.attention import causal_mask, dot_product_attention
 from ..ops.norms import RMSNorm
@@ -431,56 +433,106 @@ def params_from_torch(model_or_sd, cfg: LlamaConfig) -> Dict[str, Any]:
     return {"params": tree}
 
 
-def geometry_params(cfg: LlamaConfig, dtype=jnp.bfloat16,
-                    quant: bool = False) -> Dict[str, Any]:
-    """Shape-exact zero-weight param tree for GEOMETRY benches.
+#: geometry-tier weight statistics: float kernels ~ N(0, GEOMETRY_STD);
+#: int8 kernels uniform on [-127, 127] under ONE constant per-channel scale
+#: chosen so the dequantized weights have the same standard deviation
+GEOMETRY_STD = 0.02
+_GEOMETRY_INT8_SCALE = GEOMETRY_STD / (127.0 * 2 / 12 ** 0.5)
 
-    Mirrors :func:`params_from_torch`'s tree (incl. mllama cross layers),
-    but materializes device-side zeros — no host copy of N billion floats,
-    and with ``quant`` the kernels are BORN int8 (+unit scales), so an 11B
-    geometry stays under one chip's HBM at every instant. Decode cost is
-    weight-value-independent, so throughput numbers are real; outputs are
-    (deterministically) meaningless.
+
+@functools.partial(jax.jit, static_argnames=("shape", "dtype", "sharding"))
+def _geometry_leaf(key, *, shape, dtype, sharding):
+    """One seeded weight leaf, generated in its final dtype under its final
+    sharding: each device computes only its own shard, and nothing wider
+    than the leaf's own dtype outlives the call."""
+    if dtype == jnp.int8:
+        w = jax.random.randint(key, shape, -127, 128, jnp.int8)
+    else:
+        w = (GEOMETRY_STD * jax.random.normal(key, shape, jnp.float32)
+             ).astype(dtype)
+    return w if sharding is None else jax.lax.with_sharding_constraint(
+        w, sharding)
+
+
+def geometry_params(cfg: LlamaConfig, dtype=jnp.bfloat16,
+                    quant: bool = False, seed: int = 0,
+                    mesh=None) -> Dict[str, Any]:
+    """Shape-exact SEEDED param tree for the geometry tier.
+
+    Mirrors :func:`params_from_torch`'s tree (incl. mllama cross layers).
+    Every leaf is born on the device(s) in its final form: no host copy of N
+    billion floats, kernels int8 at birth with ``quant`` (never a
+    full-precision transient, so an int8 7B stays under one chip's HBM at
+    every instant), and with ``mesh`` each leaf is created under its
+    :func:`tp_rules` sharding so no chip ever holds more than its shard.
+    Values are random but reproducible from ``seed`` — a broken kernel and a
+    correct one no longer answer alike, as they did over zero weights.
+    Decode cost is weight-value-independent, so throughput numbers are real;
+    outputs are meaningless text.
     """
     D, HD = cfg.dim, cfg.head_dim
     q_out, kv_out = cfg.n_heads * HD, cfg.n_kv_heads * HD
+    rules = tp_rules()
+    root = jax.random.PRNGKey(seed)
+    n_leaf = itertools.count()
 
-    def lin(i, o):
+    def sharding_of(path: str, ndim: int):
+        if mesh is None:
+            return None
+        return NamedSharding(mesh, rules.spec_for(path, ndim=ndim))
+
+    def rand(path: str, shape, dt):
+        return _geometry_leaf(
+            jax.random.fold_in(root, next(n_leaf)), shape=tuple(shape),
+            dtype=jnp.dtype(dt), sharding=sharding_of(path, len(shape)))
+
+    def const(path: str, shape, value, dt):
+        sh = sharding_of(path, len(shape))
+        return jnp.full(shape, value, dt, device=sh)
+
+    def lin(path: str, i: int, o: int):
         if quant:
-            return {"kernel_q": jnp.zeros((i, o), jnp.int8),
-                    "scale": jnp.ones((o,), jnp.float32)}
-        return {"kernel": jnp.zeros((i, o), dtype)}
+            return {"kernel_q": rand(f"{path}/kernel_q", (i, o), jnp.int8),
+                    "scale": const(f"{path}/scale", (o,),
+                                   _GEOMETRY_INT8_SCALE, jnp.float32)}
+        return {"kernel": rand(f"{path}/kernel", (i, o), dtype)}
 
-    def norm(n=D):
-        return {"scale": jnp.ones((n,), dtype)}
+    def norm(path: str, n: int = D):
+        return {"scale": const(f"{path}/scale", (n,), 1.0, dtype)}
 
     tree: Dict[str, Any] = {
-        "embed": {"embedding": jnp.zeros((cfg.vocab_size, D), dtype)},
-        "final_norm": norm(),
+        "embed": {"embedding": rand("embed/embedding",
+                                    (cfg.vocab_size, D), dtype)},
+        "final_norm": norm("final_norm"),
     }
     for i in range(cfg.n_layers):
+        lp = f"layer_{i}"
         layer: Dict[str, Any] = {
-            "mlp": {"gate": lin(D, cfg.mlp_dim), "up": lin(D, cfg.mlp_dim),
-                    "down": lin(cfg.mlp_dim, D)},
-            "attn_norm": norm(),
-            "mlp_norm": norm(),
+            "mlp": {"gate": lin(f"{lp}/mlp/gate", D, cfg.mlp_dim),
+                    "up": lin(f"{lp}/mlp/up", D, cfg.mlp_dim),
+                    "down": lin(f"{lp}/mlp/down", cfg.mlp_dim, D)},
+            "attn_norm": norm(f"{lp}/attn_norm"),
+            "mlp_norm": norm(f"{lp}/mlp_norm"),
         }
         if i in cfg.cross_attention_layers:
+            ca = f"{lp}/cross_attn"
             layer["cross_attn"] = {
-                "q": lin(D, q_out), "k": lin(D, kv_out), "v": lin(D, kv_out),
-                "o": lin(q_out, D),
-                "q_norm": norm(HD), "k_norm": norm(HD),
+                "q": lin(f"{ca}/q", D, q_out), "k": lin(f"{ca}/k", D, kv_out),
+                "v": lin(f"{ca}/v", D, kv_out), "o": lin(f"{ca}/o", q_out, D),
+                "q_norm": norm(f"{ca}/q_norm", HD),
+                "k_norm": norm(f"{ca}/k_norm", HD),
             }
-            layer["gate_attn"] = jnp.zeros((1,), dtype)
-            layer["gate_mlp"] = jnp.zeros((1,), dtype)
+            layer["gate_attn"] = rand(f"{lp}/gate_attn", (1,), dtype)
+            layer["gate_mlp"] = rand(f"{lp}/gate_mlp", (1,), dtype)
         else:
+            at = f"{lp}/attn"
             layer["attn"] = {
-                "q": lin(D, q_out), "k": lin(D, kv_out), "v": lin(D, kv_out),
-                "o": lin(q_out, D),
+                "q": lin(f"{at}/q", D, q_out), "k": lin(f"{at}/k", D, kv_out),
+                "v": lin(f"{at}/v", D, kv_out), "o": lin(f"{at}/o", q_out, D),
             }
-        tree[f"layer_{i}"] = layer
+        tree[lp] = layer
     if not cfg.tie_embeddings:
-        tree["lm_head"] = lin(D, cfg.vocab_size)
+        tree["lm_head"] = lin("lm_head", D, cfg.vocab_size)
     return {"params": tree}
 
 

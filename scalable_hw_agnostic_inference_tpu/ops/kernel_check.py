@@ -1,0 +1,144 @@
+"""Every Pallas kernel against its XLA oracle, at one engine's shapes.
+
+One list of cases serves two checks that must not drift apart: the tier-1
+test lowers each case for the v5e target without a device
+(``tests/test_kernel_lowering.py``), and ``chip_smoke.py`` runs each case
+compiled on the chip and compares it with the oracle on seeded inputs. A
+case is the shape the engine dispatches for one (model, tensor-parallel
+degree, block size, bucket) — not a toy.
+"""
+
+from __future__ import annotations
+
+import dataclasses
+from typing import Callable, List, Sequence
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+
+from .attention import dot_product_attention, ragged_gather_attention
+from .pallas.flash_attention import flash_attention
+from .pallas.paged_attention import paged_decode_attention
+from .pallas.ragged_paged_attention import ragged_paged_attention
+from .quant import quantize_kv_blocks
+
+#: max-abs error allowed against the oracle. Inputs are unit normal, so an
+#: output (a convex mix of V rows) stays within |o| <= ~4, where one bf16
+#: ulp is 2**-6: the bound is two ulps of the bf16 result, the kernel and
+#: the oracle each rounding once from their own f32 accumulation order.
+#: int8-KV doubles it: the oracle also rounds its dequantized K/V to bf16.
+TOL_BF16 = 2 * 2.0 ** -6
+TOL_INT8_KV = 2 * TOL_BF16
+
+
+@dataclasses.dataclass(frozen=True)
+class KernelCase:
+    """One kernel at one shape: ``kernel(*make_inputs(key))`` must agree
+    with ``oracle(*make_inputs(key))`` within ``tol``. ``kernel`` takes
+    ``interpret=`` (False compiles through Mosaic)."""
+
+    name: str
+    make_inputs: Callable
+    kernel: Callable
+    oracle: Callable
+    tol: float
+
+    def max_abs_err(self, interpret: bool, seed: int = 0) -> float:
+        """Run both sides on seeded inputs; NaN/Inf anywhere reads as inf."""
+        args = jax.jit(self.make_inputs)(jax.random.PRNGKey(seed))
+        out = jax.jit(lambda *a: self.kernel(*a, interpret=interpret))(*args)
+        ref = jax.jit(self.oracle)(*args)
+        out, ref = np.asarray(out, np.float32), np.asarray(ref, np.float32)
+        if out.shape != ref.shape or not np.isfinite(out).all():
+            return float("inf")
+        return float(np.max(np.abs(out - ref)))
+
+
+def _flash_case(H: int, Hkv: int, D: int, T: int, S: int,
+                rows: int) -> KernelCase:
+    """Causal flash prefill of a ``T``-token bucket over ``S`` keys
+    (``S > T`` is a continuation chunk behind ``S - T`` prior tokens),
+    ``rows`` sequences with mixed true lengths."""
+    start = S - T
+    # full bucket, one token, and (when rows allow) lengths in between
+    lens = [start + n for n in (T, 1, T // 2 + 3, T - 1)][:rows]
+
+    def make(key):
+        kq, kk, kv = jax.random.split(key, 3)
+        return (jax.random.normal(kq, (rows, T, H, D), jnp.bfloat16),
+                jax.random.normal(kk, (rows, S, Hkv, D), jnp.bfloat16),
+                jax.random.normal(kv, (rows, S, Hkv, D), jnp.bfloat16),
+                jnp.asarray(lens, jnp.int32))
+
+    return KernelCase(
+        name=f"flash-H{H}x{Hkv}-T{T}-S{S}-b{rows}", make_inputs=make,
+        kernel=lambda q, k, v, n, interpret: flash_attention(
+            q, k, v, causal=True, lengths=n, interpret=interpret),
+        oracle=lambda q, k, v, n: dot_product_attention(
+            q, k, v, causal=True, kv_lengths=n, impl="xla"),
+        tol=TOL_BF16)
+
+
+def _pool_case(kind: str, H: int, Hkv: int, D: int, block_size: int,
+               blocks_per_seq: int, rows: int, int8_kv: bool) -> KernelCase:
+    """A paged-pool kernel (``kind``: ``paged`` bucketed decode, ``ragged``)
+    over ``rows`` single-query rows with mixed context lengths and shuffled
+    block tables."""
+    kern = {"paged": paged_decode_attention,
+            "ragged": ragged_paged_attention}[kind]
+    L = blocks_per_seq * block_size
+    # one token, a partial second block, mid-window, the full window
+    lens = [1, block_size + 3, L // 2 + 5, L]
+    lens = (lens * -(-rows // len(lens)))[:rows]
+    n_blocks = rows * blocks_per_seq + 1          # + the reserved block 0
+
+    def make(key):
+        kq, kk, kv, kt = jax.random.split(key, 4)
+        shape = (n_blocks, block_size, Hkv, D)
+        k = jax.random.normal(kk, shape, jnp.float32)
+        v = jax.random.normal(kv, shape, jnp.float32)
+        # every row owns distinct physical blocks, in shuffled order
+        tables = 1 + jax.random.permutation(kt, n_blocks - 1).reshape(
+            rows, blocks_per_seq).astype(jnp.int32)
+        q = jax.random.normal(kq, (rows, H, D), jnp.bfloat16)
+        n = jnp.asarray(lens, jnp.int32)
+        if int8_kv:
+            kq8, ks = quantize_kv_blocks(k)
+            vq8, vs = quantize_kv_blocks(v)
+            return q, kq8, vq8, tables, n, ks, vs
+        return q, k.astype(jnp.bfloat16), v.astype(jnp.bfloat16), tables, n
+
+    def oracle(q, k, v, tables, n, ks=None, vs=None):
+        return ragged_gather_attention(
+            q[:, None], k, v, tables, (n - 1)[:, None], ks, vs)[:, 0]
+
+    return KernelCase(
+        name=(f"{kind}-H{H}x{Hkv}-bs{block_size}-M{blocks_per_seq}-b{rows}"
+              f"-{'int8kv' if int8_kv else 'bf16'}"),
+        make_inputs=make,
+        kernel=lambda *a, interpret: kern(*a, interpret=interpret),
+        oracle=oracle, tol=TOL_INT8_KV if int8_kv else TOL_BF16)
+
+
+def engine_cases(n_heads: int, n_kv_heads: int, head_dim: int, *,
+                 tp: int = 1, block_size: int = 16,
+                 buckets: Sequence[int] = (128, 512),
+                 max_model_len: int = 2048, max_num_seqs: int = 4,
+                 max_prefill_batch: int = 4) -> List[KernelCase]:
+    """The kernel calls an engine of this geometry dispatches, per TP shard:
+    flash at each prefill bucket (widest prefill batch) and at the first
+    continuation start, then paged decode and ragged over the full block
+    table, bf16 and int8-KV."""
+    H, Hkv = n_heads // tp, n_kv_heads // tp
+    M = max_model_len // block_size
+    top = max(buckets)
+    cases = [_flash_case(H, Hkv, head_dim, b, b, max_prefill_batch)
+             for b in sorted(buckets)]
+    if max_model_len >= 2 * top:
+        cases.append(_flash_case(H, Hkv, head_dim, top, 2 * top, 1))
+    for int8_kv in (False, True):
+        for kind in ("paged", "ragged"):
+            cases.append(_pool_case(kind, H, Hkv, head_dim, block_size, M,
+                                    max_num_seqs, int8_kv))
+    return cases

@@ -46,19 +46,27 @@ from jax.experimental import pallas as pl
 
 NEG_INF = -1e30
 
+#: pool blocks per scale-operand block: the f32 sublane tile, the smallest
+#: row count Mosaic accepts for a [rows, Hkv] block of the [N, Hkv] scales
+_SCALE_ROWS = 8
 
-def _ragged_kernel(tables_ref, lens_ref, q_ref, k_ref, v_ref, *rest,
+
+def _ragged_kernel(tables_ref, lens_ref, *rest,
                    scale: float, block_size: int, n_blocks: int,
                    quantized: bool):
     # q_ref: [Hkv, group, D]; k_ref/v_ref: [block_size, Hkv, D] — one whole
     # pool block per grid step (the head axis must stay in the block shape:
     # a squeezed middle leaves Mosaic's last-two-dims tiling at (1, D),
     # rejected for Hkv > 1 — see paged_attention.py). With ``quantized``,
-    # ks_ref/vs_ref [Hkv] carry the block's per-head f32 scales.
+    # ks_ref/vs_ref [_SCALE_ROWS, Hkv] (SMEM) carry the f32 scales of the
+    # _SCALE_ROWS-aligned group of pool blocks this step's block sits in
+    # (one row per block: a single [Hkv] row is below Mosaic's (8, 128)
+    # block-shape rule), and sidx_ref [B, M] the block's row in that group.
     if quantized:
-        ks_ref, vs_ref, o_ref, m_ref, l_ref, acc_ref = rest
+        (sidx_ref, q_ref, k_ref, v_ref, ks_ref, vs_ref,
+         o_ref, m_ref, l_ref, acc_ref) = rest
     else:
-        o_ref, m_ref, l_ref, acc_ref = rest
+        q_ref, k_ref, v_ref, o_ref, m_ref, l_ref, acc_ref = rest
     b = pl.program_id(0)
     j = pl.program_id(1)
 
@@ -80,17 +88,25 @@ def _ragged_kernel(tables_ref, lens_ref, q_ref, k_ref, v_ref, *rest,
         q = q_ref[:].astype(jnp.float32) * scale      # [Hkv, G, D]
         k = k_ref[:].astype(jnp.float32)              # [bs, Hkv, D]
         v = v_ref[:].astype(jnp.float32)
-        if quantized:
-            # in-kernel dequant: int8 block x per-(block, head) f32 scale
-            k = k * ks_ref[:][None, :, None]
-            v = v * vs_ref[:][None, :, None]
         hkv, g, _ = q.shape
-        # per-kv-head 2D dots unrolled over the static head count (Mosaic's
-        # older lowerings reject 3D dot_general in-kernel; Hkv is the
-        # per-shard head count, 1-8)
+        if quantized:
+            # in-kernel dequant: the per-(block, head) f32 scale is constant
+            # over a head's whole [bs, D] tile, so it factors out of both
+            # dots — scale head h's scores and its p @ v instead of the
+            # tile. The scales are SMEM scalars: a scalar times a tile is a
+            # plain splat, where a [1, 1] VMEM value has no Mosaic
+            # broadcast in both directions.
+            row = sidx_ref[b, j]
+            k_sc = [ks_ref[row, h] for h in range(hkv)]
+            v_sc = [vs_ref[row, h] for h in range(hkv)]
+        else:
+            k_sc = v_sc = [1.0] * hkv
+        # per-kv-head 2D dots unrolled over the static head count (Mosaic
+        # rejects 3D dot_general in-kernel; Hkv is the per-shard head
+        # count, 1-8)
         s = jnp.stack([
             jax.lax.dot_general(q[h], k[:, h, :], (((1,), (1,)), ((), ())),
-                                preferred_element_type=jnp.float32)
+                                preferred_element_type=jnp.float32) * k_sc[h]
             for h in range(hkv)])                     # [Hkv, G, bs]
         k_pos = j * block_size + jax.lax.broadcasted_iota(
             jnp.int32, (hkv, g, block_size), 2)
@@ -106,7 +122,7 @@ def _ragged_kernel(tables_ref, lens_ref, q_ref, k_ref, v_ref, *rest,
         l_new = l_ref[:, :, :1] * corr + jnp.sum(p, axis=-1, keepdims=True)
         acc_ref[:] = acc_ref[:] * corr + jnp.stack([
             jax.lax.dot_general(p[h], v[:, h, :], (((1,), (0,)), ((), ())),
-                                preferred_element_type=jnp.float32)
+                                preferred_element_type=jnp.float32) * v_sc[h]
             for h in range(hkv)])                     # [Hkv, G, D]
         m_ref[:] = jnp.broadcast_to(m_new, m_ref.shape)
         l_ref[:] = jnp.broadcast_to(l_new, l_ref.shape)
@@ -160,39 +176,42 @@ def ragged_paged_attention(
     # dead blocks re-map to the row's first block: consecutive grid steps
     # see an unchanged index -> no re-fetch (and no compute, via the
     # in-kernel skip)
-    def kv_index(b, j, tables, lens):
+    def kv_index(b, j, tables, lens, *_):
         n_live = pl.cdiv(lens[b], block_size)
         jj = jnp.where(j < jnp.maximum(n_live, 1), j, 0)
         return (tables[b, jj], 0, 0, 0)
 
-    def sc_index(b, j, tables, lens):
+    def sc_index(b, j, tables, lens, sidx):
         n_live = pl.cdiv(lens[b], block_size)
         jj = jnp.where(j < jnp.maximum(n_live, 1), j, 0)
-        return (tables[b, jj], 0)
+        return (tables[b, jj] // _SCALE_ROWS, 0)
 
     grid = (B, M)
     kernel = functools.partial(
         _ragged_kernel, scale=scale, block_size=block_size, n_blocks=M,
         quantized=quantized)
+    # scalar-prefetch operands ride every index map; the quantized call
+    # prefetches a third one (each block's row within its scale group)
     in_specs = [
-        pl.BlockSpec((None, Hkv, group, D),
-                     lambda b, j, tables, lens: (b, 0, 0, 0)),
+        pl.BlockSpec((None, Hkv, group, D), lambda b, j, *_: (b, 0, 0, 0)),
         pl.BlockSpec((None, block_size, Hkv, D), kv_index),
         pl.BlockSpec((None, block_size, Hkv, D), kv_index),
     ]
-    args = [tables, lengths, qt, k_pool, v_pool]
+    prefetch = [tables, lengths]
+    args = [qt, k_pool, v_pool]
     if quantized:
-        in_specs += [pl.BlockSpec((None, Hkv), sc_index),
-                     pl.BlockSpec((None, Hkv), sc_index)]
+        prefetch.append(tables % _SCALE_ROWS)
+        in_specs += [pl.BlockSpec((_SCALE_ROWS, Hkv), sc_index,
+                                  memory_space=pltpu.SMEM)] * 2
         args += [k_scale.astype(jnp.float32), v_scale.astype(jnp.float32)]
     out = pl.pallas_call(
         kernel,
         grid_spec=pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=2,
+            num_scalar_prefetch=len(prefetch),
             grid=grid,
             in_specs=in_specs,
             out_specs=pl.BlockSpec((None, Hkv, group, D),
-                                   lambda b, j, tables, lens: (b, 0, 0, 0)),
+                                   lambda b, j, *_: (b, 0, 0, 0)),
             scratch_shapes=[
                 pltpu.VMEM((Hkv, group, 128), jnp.float32),   # m
                 pltpu.VMEM((Hkv, group, 128), jnp.float32),   # l
@@ -201,5 +220,5 @@ def ragged_paged_attention(
         ),
         out_shape=jax.ShapeDtypeStruct((B, Hkv, group, D), q.dtype),
         interpret=interpret,
-    )(*args)
+    )(*prefetch, *args)
     return out.reshape(B, H, D)

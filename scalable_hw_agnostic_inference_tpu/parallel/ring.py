@@ -24,33 +24,6 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
-try:  # JAX >= 0.6 top-level alias
-    _shard_map = jax.shard_map
-except AttributeError:  # JAX 0.4.x
-    from jax.experimental.shard_map import shard_map as _shard_map
-
-
-def _axis_size(axis_name: str) -> int:
-    """Static size of a named mesh axis, on any supported JAX."""
-    try:
-        return jax.lax.axis_size(axis_name)  # JAX >= 0.6
-    except AttributeError:
-        from jax._src import core
-
-        frame = core.axis_frame(axis_name)
-        return frame if isinstance(frame, int) else frame.size
-
-
-def _varying(x, axis_name: str):
-    """Mark a constant as device-varying for shard_map's vma tracking
-    (newer JAX); a no-op where the tracking (and ``lax.pcast``) doesn't
-    exist — 0.4.x shard_map accepts constant carries as-is."""
-    try:
-        return jax.lax.pcast(x, (axis_name,), to="varying")
-    except AttributeError:
-        return x
-
-
 NEG_INF = -1e30
 
 
@@ -84,7 +57,7 @@ def ring_attention_local(q, k, v, axis_name: str = "sp", causal: bool = False):
 
     Returns the local output shard ``[B, H, T_local, D]``.
     """
-    sp = _axis_size(axis_name)
+    sp = jax.lax.axis_size(axis_name)
     my = jax.lax.axis_index(axis_name)
     B, H, T, D = q.shape
     S = k.shape[2]
@@ -121,11 +94,13 @@ def ring_attention_local(q, k, v, axis_name: str = "sp", causal: bool = False):
         return (k_nxt, v_nxt, o, m_new, l), None
 
     # initial accumulators are constants; mark them device-varying so the
-    # scan carry type matches under shard_map's vma tracking (module-level
-    # _varying: no-op on JAX without vma tracking / lax.pcast)
-    o0 = _varying(jnp.zeros((B, H, T, D), jnp.float32), axis_name)
-    m0 = _varying(jnp.full((B, H, T, 1), NEG_INF, jnp.float32), axis_name)
-    l0 = _varying(jnp.zeros((B, H, T, 1), jnp.float32), axis_name)
+    # scan carry type matches under shard_map's vma tracking
+    def varying(x):
+        return jax.lax.pcast(x, (axis_name,), to="varying")
+
+    o0 = varying(jnp.zeros((B, H, T, D), jnp.float32))
+    m0 = varying(jnp.full((B, H, T, 1), NEG_INF, jnp.float32))
+    l0 = varying(jnp.zeros((B, H, T, 1), jnp.float32))
     (_, _, o, m, l), _ = jax.lax.scan(
         step, (k, v, o0, m0, l0), jnp.arange(sp)
     )
@@ -140,7 +115,7 @@ def ring_attention(q, k, v, mesh, axis_name: str = "sp", causal: bool = False):
     """
     fn = functools.partial(ring_attention_local, axis_name=axis_name, causal=causal)
     spec = P(None, None, axis_name, None)
-    return _shard_map(
+    return jax.shard_map(
         fn, mesh=mesh, in_specs=(spec, spec, spec), out_specs=spec
     )(q, k, v)
 
@@ -152,7 +127,7 @@ def ulysses_attention_local(q, k, v, axis_name: str = "sp", causal: bool = False
     the full sequence on H/sp heads, then reshards back. Requires
     ``H % sp == 0``.
     """
-    sp = _axis_size(axis_name)
+    sp = jax.lax.axis_size(axis_name)
     B, H, T, D = q.shape
     if H % sp:
         raise ValueError(f"heads {H} not divisible by sp={sp}")
@@ -180,6 +155,6 @@ def ulysses_attention_local(q, k, v, axis_name: str = "sp", causal: bool = False
 def ulysses_attention(q, k, v, mesh, axis_name: str = "sp", causal: bool = False):
     fn = functools.partial(ulysses_attention_local, axis_name=axis_name, causal=causal)
     spec = P(None, None, axis_name, None)
-    return _shard_map(
+    return jax.shard_map(
         fn, mesh=mesh, in_specs=(spec, spec, spec), out_specs=spec
     )(q, k, v)

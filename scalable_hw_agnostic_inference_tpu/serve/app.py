@@ -580,11 +580,15 @@ def create_app(
     # -- uniform surface ---------------------------------------------------
     @app.get("/")
     def root(request: Request):
+        from ..core.device import live_backend
+
         return {
             "app": cfg.app,
             "task": service.task,
             "model_id": cfg.model_id,
+            # the REQUESTED tier, and beside it what JAX actually brought up
             "device": cfg.device,
+            **live_backend(),
             "endpoints": sorted({r.pattern for r in app.routes}),
             "config": cfg.describe(),
             "served": pub.served,

@@ -223,11 +223,15 @@ def _geometry_models():
     }
 
 
-def _load_causal_lm(cfg: ServeConfig, model_id: str):
+def _load_causal_lm(cfg: ServeConfig, model_id: str, quant: bool = False,
+                    mesh=None):
     """Shared causal-LM bootstrap for LlamaService and VllmService.
 
-    Returns ``(mcfg, model, params, tokenizer, eos_id, pad_id, byte_tok)``;
-    params are host-side (callers place/shard them).
+    Returns ``(mcfg, model, params, tokenizer, eos_id, pad_id, byte_tok)``.
+    Checkpoint and tiny params are host-side and full-precision: callers
+    quantize and place/shard them. The geometry tier alone honours ``quant``
+    and ``mesh`` — its weights are born int8 and sharded on the device(s), so
+    the callers' quantize/place steps find nothing left to do.
     """
     from ...models import llama
     from ...models.generate import ByteTokenizer
@@ -243,16 +247,17 @@ def _load_causal_lm(cfg: ServeConfig, model_id: str):
                 ByteTokenizer.eos_id, ByteTokenizer.pad_id, True)
 
     if model_id in GEOMETRY_MODELS:
-        # serving-GEOMETRY tier: full-size architecture, zero weights
-        # (models.llama.geometry_params) — boots with no hub/network access,
-        # so serving-level load ramps (scripts/breaking_point.py) and the
-        # watcher's on-chip sessions can measure the REAL engine/serving
-        # stack at real shapes. Throughput is weight-value-independent
-        # (bench.py uses the same basis); outputs are meaningless and the
-        # unit's model id says "geometry" honestly.
+        # serving-GEOMETRY tier: full-size architecture, random weights
+        # seeded from cfg.seed (models.llama.geometry_params) — boots with
+        # no hub/network access, so serving-level load ramps
+        # (scripts/breaking_point.py) and chip_smoke.py drive the REAL
+        # engine/serving stack at real shapes. Throughput is
+        # weight-value-independent; outputs are meaningless and the unit's
+        # model id says "geometry" honestly.
         mcfg = GEOMETRY_MODELS[model_id]()
         model = llama.LlamaForCausalLM(mcfg, dtype=jnp.bfloat16)
-        params = llama.geometry_params(mcfg)
+        params = llama.geometry_params(mcfg, quant=quant, seed=cfg.seed,
+                                       mesh=mesh)
         return (mcfg, model, params, ByteTokenizer(),
                 ByteTokenizer.eos_id, ByteTokenizer.pad_id, True)
 
@@ -315,12 +320,14 @@ class LlamaService(ModelService):
         from ...models.generate import make_generate
 
         cfg = self.cfg
+        quant = cfg.quantization == "int8"
+        mesh = build_mesh(cfg.mesh_spec) if cfg.mesh_spec else None
         (mcfg, self.model, params, self.tokenizer,
          self.eos_id, self.pad_id, self._byte_tok) = _load_causal_lm(
-            cfg, cfg.model_id)
+            cfg, cfg.model_id, quant=quant, mesh=mesh)
         self.mcfg = mcfg
 
-        if cfg.quantization == "int8":
+        if quant:
             # weight-only int8 at boot (the engine units' vllm_config knob,
             # env-shaped for this service): halves decode HBM traffic and is
             # what fits an 8B distill on one 16 GiB v5e chip
@@ -331,10 +338,9 @@ class LlamaService(ModelService):
             self.model = llama.LlamaForCausalLM(
                 mcfg, dtype=self.model.dtype, quant=True)
 
-        if cfg.mesh_spec:
+        if mesh is not None:
             from ...parallel.sharding import shard_pytree
 
-            mesh = build_mesh(cfg.mesh_spec)
             params = shard_pytree(params, mesh, llama.tp_rules())
         else:
             params = jax.device_put(params)

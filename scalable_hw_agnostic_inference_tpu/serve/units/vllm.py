@@ -125,6 +125,23 @@ class VllmService(ModelService):
         cfg = self.cfg
         ecfg = self.ecfg
         model_id = ecfg.model or cfg.model_id
+        # tensor_parallel_size is honored, never silently dropped: the
+        # reference's TP=32 serving tier (compile-vllm-job.yaml:54-55) maps to
+        # a tp mesh over local chips; an over-sized config is a deploy error.
+        # Built BEFORE the weights so the geometry tier is born sharded.
+        mesh = None
+        tp = ecfg.tensor_parallel_size
+        if tp > 1:
+            from ...core.device import local_devices
+            from ...core.mesh import build_mesh
+
+            devs = local_devices()
+            if tp > len(devs):
+                raise ValueError(
+                    f"tensor_parallel_size={tp} exceeds the {len(devs)} local "
+                    f"devices of this unit — match it to the nodepool's chip "
+                    f"count (reference compile-vllm-job.yaml:54-55)")
+            mesh = build_mesh(f"tp={tp}", devices=devs[:tp])
         vlm_parts = None
         self._mllama = None
         # a populated mllama artifact routes the boot by itself — a serving
@@ -173,7 +190,7 @@ class VllmService(ModelService):
         else:
             (mcfg, _model, params, self.tokenizer,
              self.eos_id, self.pad_id, self._byte_tok) = _load_causal_lm(
-                cfg, model_id)
+                cfg, model_id, quant=ecfg.quantization == "int8", mesh=mesh)
         if self._byte_tok and model_id in ("", "tiny"):
             # tiny engine shapes: small blocks/buckets so CI exercises
             # paging (geometry model ids also use the byte tokenizer but
@@ -197,35 +214,22 @@ class VllmService(ModelService):
 
         self.ecfg = ecfg
         if ecfg.quantization == "int8":
-            # weight-only int8 at boot (host-side, one pass): halves decode
-            # HBM traffic; the vLLM `quantization:` ConfigMap knob
+            # weight-only int8 at boot (one pass; the geometry tier's
+            # weights were born int8 and pass through untouched): halves
+            # decode HBM traffic; the vLLM `quantization:` ConfigMap knob
             from ...ops.quant import quantize_params_tree
 
             params = quantize_params_tree(params)
-        # tensor_parallel_size is honored, never silently dropped: the
-        # reference's TP=32 serving tier (compile-vllm-job.yaml:54-55) maps to
-        # a tp mesh over local chips; an over-sized config is a deploy error
-        mesh = None
-        tp = ecfg.tensor_parallel_size
-        if tp > 1:
-            from ...core.device import local_devices
-            from ...core.mesh import build_mesh
+        if mesh is not None:
             from ...models import llama as llama_mod
             from ...parallel.sharding import shard_pytree
 
-            devs = local_devices()
-            if tp > len(devs):
-                raise ValueError(
-                    f"tensor_parallel_size={tp} exceeds the {len(devs)} local "
-                    f"devices of this unit — match it to the nodepool's chip "
-                    f"count (reference compile-vllm-job.yaml:54-55)")
             if tp > mcfg.n_kv_heads:
                 # more ranks than GQA kv heads (the reference's 70B TP=32
                 # tier): widen kv heads by weight-side replication so the
                 # head-local engine shardings stay legal
                 # (models.llama.replicate_kv_heads; numerics unchanged)
                 params, mcfg = llama_mod.replicate_kv_heads(params, mcfg, tp)
-            mesh = build_mesh(f"tp={tp}", devices=devs[:tp])
             params = shard_pytree(params, mesh, llama_mod.tp_rules())
         else:
             params = jax.device_put(params)

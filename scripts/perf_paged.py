@@ -59,12 +59,12 @@ def bench(cfg, params, kv, ctx_blocks, n_active, paged):
 
 def main() -> None:
     from scalable_hw_agnostic_inference_tpu.core.aot import (
-        enable_persistent_cache_from_env,
+        enable_persistent_cache,
         host_init,
         to_default_device,
     )
 
-    enable_persistent_cache_from_env()
+    enable_persistent_cache()
     cfg = LlamaConfig(
         vocab_size=128256, dim=2048, n_layers=16, n_heads=32, n_kv_heads=8,
         mlp_dim=8192, max_seq_len=32768, rope_theta=500000.0,

@@ -3,12 +3,11 @@
 BENCH_onchip.json is the judge-visible record (VERDICT r2 next-round #2);
 BASELINE.json.published anchors future rounds' vs_baseline (the reference
 publishes no llama tok/s, so the first on-chip run becomes the
-self-baseline). Idempotent — the watcher runs it after every bench, so a
-partial session still publishes what it measured.
+self-baseline). Idempotent — run after every bench, a partial session still
+publishes what it measured.
 
 ``--check <key>`` mode: exit 0 iff the banked result for <key> is a real
-on-device measurement — THE predicate (shared with the watcher's have()) of
-what counts as done/publishable.
+on-device measurement — THE predicate of what counts as done/publishable.
 """
 
 import json
@@ -83,8 +82,8 @@ def _load_results() -> dict:
 def is_real(v) -> bool:
     """A banked entry that is a genuine on-device measurement.
 
-    Keys off the STRUCTURED ``platform`` field bench.py's inner process
-    stamps from ``jax.devices()[0].platform`` — never off metric-string
+    Keys off the STRUCTURED ``platform`` field bench.py stamps from
+    ``jax.devices()[0].platform`` — never off metric-string
     formatting, which silently diverged per-bench and let cpu-tiny llama
     runs read as real (ADVICE r3 medium). An entry without the field
     (pre-r4 format) is NOT real.

@@ -196,6 +196,9 @@ def test_mllama_tp8_prefill_lowers_at_full_shape():
 
 def test_engine_enforces_budget_when_opted_in(monkeypatch):
     monkeypatch.setenv("SHAI_ENFORCE_HBM", "1")
+    # a CPU device reports no HBM: enforcing a budget on one needs the size
+    # declared
+    monkeypatch.setenv("SHAI_HBM_GIB", "16")
     from scalable_hw_agnostic_inference_tpu.engine.engine import LLMEngine
 
     cfg = LlamaConfig.tiny()
@@ -212,7 +215,7 @@ def test_engine_enforces_budget_when_opted_in(monkeypatch):
 
 
 # ---------------------------------------------------------------------------
-# detect_hbm_gib: runtime first, device-kind table, v5e default (PR 7)
+# detect_hbm_gib: runtime first, then the device-kind table, else an error
 # ---------------------------------------------------------------------------
 
 class _FakeDevice:
@@ -240,30 +243,32 @@ def test_detect_hbm_gib_prefers_runtime_memory_stats():
 def test_detect_hbm_gib_falls_back_to_device_kind_table():
     from scalable_hw_agnostic_inference_tpu.core.budget import detect_hbm_gib
 
-    # memory_stats raising AND returning useless payloads both fall through
-    for broken in (_FakeDevice(raises=True, kind="TPU v5 lite"),
-                   _FakeDevice(stats=None, kind="TPU v5 lite"),
+    # a runtime that reports no limit falls through to the kind table
+    for silent in (_FakeDevice(stats=None, kind="TPU v5 lite"),
                    _FakeDevice(stats={}, kind="TPU v5 lite"),
                    _FakeDevice(stats={"bytes_limit": 0}, kind="TPU v5 lite")):
-        assert detect_hbm_gib(broken) == pytest.approx(16.0)
-    assert detect_hbm_gib(_FakeDevice(raises=True, kind="TPU v4")) == \
-        pytest.approx(32.0)
-    assert detect_hbm_gib(_FakeDevice(raises=True, kind="TPU v5p")) == \
-        pytest.approx(95.0)
+        assert detect_hbm_gib(silent) == pytest.approx(16.0)
+    assert detect_hbm_gib(_FakeDevice(kind="TPU v4")) == pytest.approx(32.0)
+    assert detect_hbm_gib(_FakeDevice(kind="TPU v5p")) == pytest.approx(95.0)
     # order matters: "v5 lite" must hit the 16 GiB row, not the bare "v5"
-    assert detect_hbm_gib(_FakeDevice(raises=True,
-                                      kind="tpu v5litepod-8")) == \
+    assert detect_hbm_gib(_FakeDevice(kind="tpu v5litepod-8")) == \
         pytest.approx(16.0)
 
 
-def test_detect_hbm_gib_defaults_to_v5e_tier():
+def test_detect_hbm_gib_refuses_to_guess(monkeypatch):
     from scalable_hw_agnostic_inference_tpu.core.budget import (
-        HBM_GIB,
+        HbmBudgetError,
         detect_hbm_gib,
     )
 
-    # unknown kind, no stats: the deploy target's tier — never a crash
-    dev = _FakeDevice(raises=True, kind="FutureAccelerator 9000")
-    assert detect_hbm_gib(dev) == HBM_GIB["v5e"] == pytest.approx(16.0)
-    # no device_kind attribute at all (bare object)
-    assert detect_hbm_gib(object()) == pytest.approx(16.0)
+    # unknown kind, no limit from the runtime: an error, never a 16 GiB v5e
+    with pytest.raises(HbmBudgetError, match="FutureAccelerator 9000"):
+        detect_hbm_gib(_FakeDevice(kind="FutureAccelerator 9000"))
+    # a failing memory_stats() is the runtime's error to report, not ours
+    # to swallow
+    with pytest.raises(RuntimeError, match="no memory stats"):
+        detect_hbm_gib(_FakeDevice(raises=True, kind="TPU v5 lite"))
+    # the operator's declaration still wins over everything
+    monkeypatch.setenv("SHAI_HBM_GIB", "24")
+    assert detect_hbm_gib(_FakeDevice(kind="FutureAccelerator 9000")) == \
+        pytest.approx(24.0)

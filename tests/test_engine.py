@@ -335,6 +335,36 @@ def test_engine_warm_executables_closed_set(tiny_model):
     assert eng.n_executables == count, "post-warm request compiled a new executable"
 
 
+def test_warm_set_is_closed_at_the_xla_level_under_tp(tiny_model):
+    """Found on a four-chip host: the engine's own counters said nothing was
+    built after warm-up, while XLA compiled the sampler, the logprob readout
+    and a decode executable a second time inside the first requests — each
+    had been warmed on inputs whose type lacked the mesh that the real
+    inputs (the executables' own outputs) carry. After warm-up, serving
+    compiles none of the engine's jitted functions again."""
+    cfg, _, params = tiny_model
+    eng = _tp_engine(params, cfg, 2, context_encoding_buckets=(16,),
+                     max_num_seqs=2)
+    eng.warm_executables()
+    compiled = []
+
+    def listener(event, secs, fun_name="", **kw):
+        if event == "/jax/core/compile/backend_compile_duration":
+            compiled.append(fun_name)
+
+    jax.monitoring.register_event_duration_secs_listener(listener)
+    try:
+        eng.generate([[1, 2, 3], [4, 5]], SamplingParams(
+            temperature=0.0, max_new_tokens=6, logprobs=1))
+        eng.generate([list(range(2, 30))], SamplingParams(
+            temperature=0.7, max_new_tokens=4))
+    finally:
+        jax.monitoring.unregister_event_duration_listener(listener)
+    engine_fns = {f"jit({n})" for n in (
+        "prefill", "cont", "decode", "sample_logits", "token_logprobs")}
+    assert not engine_fns & set(compiled), compiled
+
+
 def test_engine_decode_ctx_bucket_dispatch(tiny_model):
     """Decode picks the smallest context bucket covering the longest seq."""
     cfg, _, params = tiny_model

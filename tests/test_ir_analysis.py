@@ -194,7 +194,6 @@ def _sp_mesh():
 
 
 def _collective_prog(key, order):
-    from jax.experimental.shard_map import shard_map
     from jax.sharding import PartitionSpec as P
 
     mesh = _sp_mesh()
@@ -208,7 +207,7 @@ def _collective_prog(key, order):
         return x
 
     def f(x):
-        return shard_map(inner, mesh=mesh, in_specs=P("sp"),
+        return jax.shard_map(inner, mesh=mesh, in_specs=P("sp"),
                          out_specs=P(None) if order[-1] == "psum"
                          else P("sp"))(x)
 
@@ -265,7 +264,7 @@ class TestHostInterop:
         p = prog(jax.jit(f), (SDS((4,), jnp.float32),))
         live, _ = run_rules([p], rules=("host-interop",))
         assert len(live) == 1
-        assert "debug_callback" in live[0].message
+        assert "debug_print" in live[0].message
 
     def test_pure_callback_flagged_and_cold_program_exempt(self):
         def f(x):

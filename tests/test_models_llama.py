@@ -289,7 +289,7 @@ def test_llama70b_tp32_lowering_leg():
 
 
 def test_geometry_params_mirror_converter_tree():
-    """geometry_params (the device-side zero-weight bench tree) must stay
+    """geometry_params (the seeded geometry-tier tree) must stay
     structurally identical to params_from_torch's output — the engine
     consumes both interchangeably, so drift would break geometry benches
     silently. Checked for a cross-attention (mllama) config via a synthetic

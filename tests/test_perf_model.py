@@ -24,9 +24,7 @@ def _require_topology() -> None:
     global _TOPO_OK
     if _TOPO_OK is None:
         try:
-            # low retry budget: a transient libtpu-lock collision (another
-            # process probing the real chip) skips rather than stalls CI
-            topo.topology_devices(1, retries=2)
+            topo.topology_devices(1)
             _TOPO_OK = True
         except Exception:
             _TOPO_OK = False

@@ -167,6 +167,13 @@ async def test_app_lifecycle_and_infer():
         body = r.json()
         assert body["app"] == "echo" and body["task"] == "echo"
         assert "/predict" in body["endpoints"]
+        # the requested tier, and beside it the backend JAX brought up
+        import jax
+
+        dev = jax.devices()[0]
+        assert body["device"] == cfg.device
+        assert (body["platform"], body["device_kind"], body["n_devices"]) \
+            == (dev.platform, dev.device_kind, len(jax.devices()))
 
         r = await c.get("/health")
         assert r.json() == {"status": "ok"}
