@@ -29,7 +29,6 @@ from ..obs import sentinel as obs_sentinel
 from ..obs.hbm import HbmLedger
 from ..obs.slo import SloEngine, SloTargets
 from ..obs.steploop import StepTelemetry
-from ..obs.trace import annotate
 from ..resilience import faults as _faults
 from ..resilience import qos as _qos
 from ..ops.sampling import sample_logits
@@ -394,7 +393,11 @@ class LLMEngine:
                     parent_rid: int = -1,
                     kv_holders: Optional[Sequence[str]] = None,
                     traceparent: str = "",
-                    idem_key: str = "") -> int:
+                    idem_key: str = "", t_enqueue: float = 0.0) -> int:
+        """Queue one request. ``t_enqueue``: the monotonic stamp of the
+        caller's submit (``EngineLoop.submit``); this call runs on the loop
+        thread between steps, and the difference is the intake wait. A
+        direct caller passes none and the two stamps are one."""
         params = (params or SamplingParams()).clamp(self.ecfg)
         if not prompt_ids:
             raise ValueError("empty prompt")
@@ -454,11 +457,15 @@ class LLMEngine:
         # migrated in from a peer carries its pre-migration output — the
         # same prompt-suffix semantics a preemption resume uses, so the
         # admission ladder needs nothing new
+        now = time.monotonic()
+        if t_enqueue:
+            self.obs.intake_wait.observe(now - t_enqueue)
         self.waiting.append(Request(rid, list(prompt_ids), params,
                                     prefix=prefix, cross_states=cross_states,
                                     cross_len=cross_len, on_token=on_token,
                                     deadline_at=deadline_at,
-                                    t_submit=time.monotonic(),
+                                    t_submit=now,
+                                    t_enqueue=t_enqueue or now,
                                     priority=priority, tenant=tenant,
                                     already_generated=list(
                                         already_generated or []),
@@ -785,14 +792,20 @@ class LLMEngine:
         host readback; the lock-step path is the reference oracle. Both
         commit/stream/finish the same tokens on the same ``step()`` call.
         """
-        if self._async:
-            return self._step_async()
-        return self._step_sync()
+        outer = self.obs.begin_step(len(self.waiting))
+        try:
+            if self._async:
+                return self._step_async(self.obs.phase_t0)
+            return self._step_sync(self.obs.phase_t0)
+        finally:
+            if outer is None:
+                # no loop around this call (tests, bench, generate()): the
+                # time until the next step is not the engine's
+                self.obs.phase_enter(None)
 
-    def _step_sync(self) -> List[Finished]:
+    def _step_sync(self, t0: float) -> List[Finished]:
         """Lock-step step: marshal -> dispatch -> readback -> bookkeeping,
         one blocking device round-trip per decode step."""
-        t0 = time.monotonic()
         self._step_count += 1
         self._done_this_step = []
         self._tokens_this_step = 0
@@ -810,7 +823,7 @@ class LLMEngine:
         if any(s is not None for s in self.slots):
             self._decode_step()
         self._flush_chunk()  # a deferred window never outlives its step
-        self._record_step(time.monotonic() - t0)
+        self._record_step(t0)
         return self._done_this_step
 
     def _schedule_head(self) -> None:
@@ -876,8 +889,7 @@ class LLMEngine:
     # slot participates in exactly one extra dispatch in both disciplines),
     # so pipelining only reorders host work, never device inputs.
 
-    def _step_async(self) -> List[Finished]:
-        t0 = time.monotonic()
+    def _step_async(self, t0: float) -> List[Finished]:
         self._step_count += 1
         self._done_this_step = []
         self._tokens_this_step = 0
@@ -910,12 +922,13 @@ class LLMEngine:
             if any(s is not None for s in self.slots):
                 self._decode_dispatch()
             self._flush_chunk()  # deferred window never outlives its step
-        self._record_step(time.monotonic() - t0)
+        self._record_step(t0)
         return self._done_this_step
 
     def _steady_step(self) -> None:
         """Pipelined decode step: dispatch N+1 on device feedback, then
         retire step N and do its host bookkeeping while N+1 runs."""
+        self.obs.phase_enter("engine.marshal")
         prev = self._pipe
         running = self._running_slots()
         if not running:
@@ -963,6 +976,7 @@ class LLMEngine:
         """Event-path decode: host-marshaled dispatch (mirrors are current)
         with the readback DEFERRED to the next step — re-establishes the
         pipeline in the same call that handled the event."""
+        self.obs.phase_enter("engine.marshal")
         if self._drafter is not None and self._spec_step():
             self._step_kind = "spec"
             return
@@ -1005,8 +1019,8 @@ class LLMEngine:
             args += [self._cross_kv, a["has_image"], a["slot_idx"],
                      a["cross_len"]]
         cold = self._pipe is None
-        t_d = time.monotonic()
-        with annotate("engine.decode"):
+        with self.obs.phase("engine.decode"):
+            t_d = self.obs.phase_t0
             (self.cache.kv, nxt, pos_next, top_ids, top_lp,
              tok_lp) = decode(*args)
         if cold and gap_ok and self._t_fetch \
@@ -1028,6 +1042,7 @@ class LLMEngine:
         ``pending_token`` + logprob entries. Slots that finished or were
         cancelled since the dispatch are skipped — their extra token is
         exactly the discarded lookahead. Returns the fetch stamp."""
+        outer = self.obs.phase_enter("engine.fetch")
         if pipe.want_lp:
             # shai-lint: allow(host-sync) THE one blocking fetch of the
             # pipeline: retiring step N must read its sampled tokens (and
@@ -1038,9 +1053,10 @@ class LLMEngine:
             # shai-lint: allow(host-sync) same fetch, logprob-free shape
             nxt = np.asarray(pipe.nxt)
             top_ids = top_lp = tok_lp = None
-        t_f = time.monotonic()
-        self._t_fetch = t_f
+        self.obs.phase_enter("engine.apply")
+        t_f = self._t_fetch = self.obs.phase_t0
         self._apply_sampled(pipe.running, nxt, top_ids, top_lp, tok_lp)
+        self.obs.phase_enter(outer)
         return t_f
 
     def _flush_pipeline(self, reason: str,
@@ -1071,11 +1087,14 @@ class LLMEngine:
         # gap as a dispatch gap (seen live: a 1.5 s "gap" between bursts)
         self._last_decode_step = -2
 
-    def _record_step(self, duration_s: float) -> None:
+    def _record_step(self, t0: float) -> None:
         """One obs step record per engine step — occupancy, KV pressure,
         rollback delta, speculative counters at step end — plus the
         conformance feeds: the perf sentinel's (tokens, busy-seconds)
-        sample and one HBM ledger tick."""
+        sample and one HBM ledger tick. The step's duration ends where
+        ``engine.record`` opens, so the record's phase fields tile it."""
+        self.obs.phase_enter("engine.record")
+        duration_s = self.obs.phase_t0 - t0
         rb = self.cache.rollback_tokens
         tenants = None
         if self._tenant_seen:
@@ -1214,7 +1233,8 @@ class LLMEngine:
         not a new first token); returns the timestamp for TPOT's t_first."""
         now = time.monotonic()
         if not req.already_generated and req.t_submit:
-            ttft = now - req.t_submit
+            # from the caller's submit, not from the loop thread's intake
+            ttft = now - (req.t_enqueue or req.t_submit)
             self.ttft.record(ttft)
             self.obs.ttft.observe(ttft)
             if self._tenant_seen:
@@ -1264,6 +1284,7 @@ class LLMEngine:
         t_adm = max(t_sub, t_adm)
         t_f = max(t_adm, t_f)
         out = {
+            "t_enqueue": req.t_enqueue or t_sub,
             "t_submit": t_sub, "t_admit": t_adm, "t_first": t_f,
             "t_done": now,
             "queue_s": round(max(0.0, t_adm - t_sub), 6),
@@ -1271,6 +1292,7 @@ class LLMEngine:
             "decode_s": round(max(0.0, now - t_f), 6),
             "total_s": round(max(0.0, now - t_sub), 6),
         }
+        out["intake_s"] = round(t_sub - out["t_enqueue"], 6)
         # sub-phase attribution (fabric probe, kv restore, recompute
         # fallback, pipeline flushes, migration cut): every Finished exit
         # path prices through here, so merging once covers them all
@@ -1365,7 +1387,7 @@ class LLMEngine:
             args.append(jnp.asarray(req.prefix)[None])
         if self._cross_kv is not None:
             args += list(self._set_slot_cross(slot, req))
-        with annotate("engine.prefill"):
+        with self.obs.phase("engine.prefill"):
             self.cache.kv, logits = fn(*args)
         self.obs.count_pad(n, bucket - n, phase="prefill")  # bucket tail
         # no register_prefix here: this path only ever admits prefix/cross
@@ -1373,9 +1395,10 @@ class LLMEngine:
         # content-address by tokens alone — and cross engines disable the
         # cache at construction anyway
         rng = jax.random.fold_in(self._rng, self._step_count * 2 + 1)
-        tok = int(self._sample1(
-            logits, rng, req.params.temperature, req.params.top_k,
-            req.params.top_p)[0])
+        with self.obs.phase("engine.fetch"):
+            tok = int(self._sample1(
+                logits, rng, req.params.temperature, req.params.top_k,
+                req.params.top_p)[0])
         self._start_slot(slot, req, tok)
         if req.params.logprobs:
             self._record_admission_lps(logits, [tok],
@@ -1483,7 +1506,7 @@ class LLMEngine:
         if self._cross_kv is not None:  # text-only rows through a cross model
             args += [self._cross_zeros(Kp), jnp.zeros((Kp,), jnp.float32),
                      jnp.full((Kp,), max(self.cross_seq_len, 1), jnp.int32)]
-        with annotate("engine.prefill"):
+        with self.obs.phase("engine.prefill"):
             self.cache.kv, logits = fn(*args)
         real = sum(len(r.prompt_ids) for r in group)
         self.obs.count_pad(real, Kp * bucket - real,
@@ -1492,9 +1515,10 @@ class LLMEngine:
             self.cache.register_prefix(req.prompt_ids,
                                        self.cache.seq(req.req_id).blocks)
         rng = jax.random.fold_in(self._rng, self._step_count * 2 + 1)
-        toks = np.asarray(self._sample1(
-            logits, rng, jnp.asarray(temp), jnp.asarray(topk),
-            jnp.asarray(topp)))
+        with self.obs.phase("engine.fetch"):
+            toks = np.asarray(self._sample1(
+                logits, rng, jnp.asarray(temp), jnp.asarray(topk),
+                jnp.asarray(topp)))
         lp_rows = []
         for i, req in enumerate(group):
             slot = self._free_slot()
@@ -1654,7 +1678,7 @@ class LLMEngine:
                 jnp.asarray([start], jnp.int32))
         else:
             fn = self._cont_for(sb, chunk_bucket)
-            with annotate("engine.prefill"):
+            with self.obs.phase("engine.chunk"):
                 self.cache.kv, logits = fn(self.params, self.cache.kv,
                                            jnp.asarray(ids),
                                            jnp.asarray([n], jnp.int32),
@@ -1663,9 +1687,10 @@ class LLMEngine:
                            phase="prefill")  # chunk bucket tail
         self.cache.register_prefix(req.prompt_ids, alloc.blocks)
         rng = jax.random.fold_in(self._rng, self._step_count * 2 + 1)
-        tok = int(self._sample1(
-            logits, rng, req.params.temperature, req.params.top_k,
-            req.params.top_p)[0])
+        with self.obs.phase("engine.fetch"):
+            tok = int(self._sample1(
+                logits, rng, req.params.temperature, req.params.top_k,
+                req.params.top_p)[0])
         self._has_image[slot] = 0.0
         self._start_slot(slot, req, tok)
         if req.params.logprobs:
@@ -1733,7 +1758,7 @@ class LLMEngine:
         ids = np.zeros((1, bucket), np.int32)
         ids[0, :n] = head.prompt_ids
         fn = self._prefill_for(bucket, 0, 1)
-        with annotate("engine.prefill"):
+        with self.obs.phase("engine.prefill"):
             self.cache.kv, logits = fn(self.params, self.cache.kv,
                                        jnp.asarray(ids),
                                        jnp.asarray([n], jnp.int32), table)
@@ -1748,9 +1773,10 @@ class LLMEngine:
             topp[i] = r.params.top_p
         tiled = jnp.broadcast_to(logits[0], (Kp,) + logits.shape[1:])
         rng = jax.random.fold_in(self._rng, self._step_count * 2 + 1)
-        toks = np.asarray(self._sample1(
-            tiled, rng, jnp.asarray(temp), jnp.asarray(topk),
-            jnp.asarray(topp)))
+        with self.obs.phase("engine.fetch"):
+            toks = np.asarray(self._sample1(
+                tiled, rng, jnp.asarray(temp), jnp.asarray(topk),
+                jnp.asarray(topp)))
         lp_rows = []
         for i, r in enumerate(group):
             slot = self._free_slot()
@@ -1804,7 +1830,7 @@ class LLMEngine:
             # seat the vision states (or the text-only gate-off) in the slot
             # buffers once; every chunk and decode step reads them from there
             args += list(self._set_slot_cross(slot, req))
-        with annotate("engine.prefill"):
+        with self.obs.phase("engine.prefill"):
             self.cache.kv, _ = fn(*args)
         # the first chunk's full blocks are final (prefill never rewrites
         # them): register them NOW — a second identical long prompt, or
@@ -1862,7 +1888,7 @@ class LLMEngine:
             args += self._cont_args(start)  # ragged: start rides as data
             if self._cross_kv is not None:
                 args += list(self._slot_cross_args(s.slot))
-            with annotate("engine.prefill"):
+            with self.obs.phase("engine.chunk"):
                 self.cache.kv, logits = fn(*args)
         self.obs.count_pad(n, C - n, phase="chunk")  # final-chunk tail
         if final:
@@ -1873,9 +1899,10 @@ class LLMEngine:
             # either single-fold stream
             rng = jax.random.fold_in(
                 jax.random.fold_in(self._rng, self._step_count), 3)
-            tok = int(self._sample1(
-                logits, rng, req.params.temperature, req.params.top_k,
-                req.params.top_p)[0])
+            with self.obs.phase("engine.fetch"):
+                tok = int(self._sample1(
+                    logits, rng, req.params.temperature, req.params.top_k,
+                    req.params.top_p)[0])
             s.pending_token = tok
             s.prefill_cursor = None
             s.t_first = self._mark_first_token(req)
@@ -1903,7 +1930,7 @@ class LLMEngine:
             if key not in self._prefill:
                 _faults.get().raise_at(_faults.COMPILE)
                 if self._warmed:
-                    self.obs.count_recompile("prefill_cont")
+                    self.obs.count_recompile()
                 self._prefill[key] = make_prefill_cont(
                     self.cfg, self.ecfg.block_size, self.ecfg.blocks_per_seq,
                     bucket, shardings=self.shardings,
@@ -1915,7 +1942,7 @@ class LLMEngine:
             if self._warmed:
                 # post-warm compile == a shape escaped the warmed closed
                 # set (the cold-graph-behind-the-LB signal)
-                self.obs.count_recompile("prefill_cont")
+                self.obs.count_recompile()
             self._prefill[key] = make_prefill_cont(
                 self.cfg, self.ecfg.block_size, self.ecfg.blocks_per_seq,
                 bucket, start_blocks, shardings=self.shardings,
@@ -1988,7 +2015,7 @@ class LLMEngine:
             # chaos site: executable-factory compile failure
             _faults.get().raise_at(_faults.COMPILE)
             if self._warmed:
-                self.obs.count_recompile("prefill")
+                self.obs.count_recompile()
             self._prefill[key] = make_prefill(
                 self.cfg, self.ecfg.block_size, self.ecfg.blocks_per_seq,
                 bucket, prefix_len=prefix_len, n_seqs=n_seqs,
@@ -2016,7 +2043,7 @@ class LLMEngine:
         if key not in self._decode_fns:
             _faults.get().raise_at(_faults.COMPILE)
             if self._warmed:
-                self.obs.count_recompile("decode")
+                self.obs.count_recompile()
             # async engines compile the feedback variant (returns pos+1,
             # donates the position buffer) into the SAME (ctx, batch)
             # ladder — one executable per key either way
@@ -2043,7 +2070,7 @@ class LLMEngine:
 
             _faults.get().raise_at(_faults.COMPILE)
             if self._warmed:
-                self.obs.count_recompile("fused")
+                self.obs.count_recompile()
             self._fused_fns[bb] = make_fused_step(
                 self.cfg, self.ecfg.block_size, self.ecfg.blocks_per_seq,
                 bb, self.buckets.max, shardings=self.shardings,
@@ -2103,7 +2130,7 @@ class LLMEngine:
                 jnp.ones((1,), jnp.float32), jnp.zeros((1,), jnp.int32),
                 jnp.ones((1,), jnp.float32),
                 ids_dev, n_dev, table, start_dev]
-        with annotate("engine.prefill"):
+        with self.obs.phase("engine.chunk"):
             out = fused(*args)
         self.cache.kv = out[0]
         return out[-1]
@@ -2128,7 +2155,7 @@ class LLMEngine:
         if key not in self._verify_fns:
             _faults.get().raise_at(_faults.COMPILE)
             if self._warmed:
-                self.obs.count_recompile("verify")
+                self.obs.count_recompile()
             self._verify_fns[key] = make_verify(
                 self.cfg, self.ecfg.block_size, self.ecfg.blocks_per_seq,
                 bb, self.ecfg.num_speculative_tokens, ctx_blocks=m,
@@ -2228,6 +2255,7 @@ class LLMEngine:
             on_token=victim.req.on_token,
             deadline_at=victim.req.deadline_at,
             t_submit=victim.req.t_submit,
+            t_enqueue=victim.req.t_enqueue,
             t_admit=victim.req.t_admit,
             t_first=victim.req.t_first,
             idem_key=victim.req.idem_key,
@@ -2403,14 +2431,15 @@ class LLMEngine:
         if self._cross_kv is not None:
             args += [self._cross_kv, a["has_image"], a["slot_idx"],
                      a["cross_len"]]
-        t_d = time.monotonic()
-        with annotate("engine.verify"):
+        with self.obs.phase("engine.verify"):
+            t_d = self.obs.phase_t0
             (self.cache.kv, o, oex, accept_p, o_lp, d_lp, oex_lp,
              top_ids, top_lp) = verify(*args)
         if self._t_fetch and self.n_executables == n_exec \
                 and self._last_decode_step == self._step_count - 1:
             self.obs.step_gap.observe(max(0.0, t_d - self._t_fetch))
         self._last_decode_step = self._step_count
+        self.obs.phase_enter("engine.fetch")
         o = np.asarray(o)
         oex = np.asarray(oex)
         accept_p = np.asarray(accept_p)
@@ -2421,7 +2450,9 @@ class LLMEngine:
             oex_lp = np.asarray(oex_lp)
             top_ids = np.asarray(top_ids)
             top_lp = np.asarray(top_lp)
-        self._t_fetch = time.monotonic()
+        # the verified tokens commit, stream and finish in the loop below
+        self.obs.phase_enter("engine.commit")
+        self._t_fetch = self.obs.phase_t0
 
         from .speculative import accept_drafts
 
@@ -2488,6 +2519,7 @@ class LLMEngine:
         return True
 
     def _decode_step(self) -> None:
+        self.obs.phase_enter("engine.marshal")
         if self._drafter is not None and self._spec_step():
             self._step_kind = "spec"
             return
@@ -2519,8 +2551,8 @@ class LLMEngine:
         if self._cross_kv is not None:
             args += [self._cross_kv, jnp.asarray(a["has_image"]),
                      jnp.asarray(a["slot_idx"]), jnp.asarray(a["cross_len"])]
-        t_d = time.monotonic()
-        with annotate("engine.decode"):
+        with self.obs.phase("engine.decode"):
+            t_d = self.obs.phase_t0
             self.cache.kv, nxt, top_ids_d, top_lp_d, tok_lp_d = decode(*args)
         if self._t_fetch and self.n_executables == n_exec \
                 and self._last_decode_step == self._step_count - 1:
@@ -2529,6 +2561,7 @@ class LLMEngine:
             # (a first-use compile is warmup, not a dispatch gap — skipped)
             self.obs.step_gap.observe(max(0.0, t_d - self._t_fetch))
         self._last_decode_step = self._step_count
+        self.obs.phase_enter("engine.fetch")
         nxt = np.asarray(nxt)
         if any(s.req.params.logprobs for s in running):
             top_ids_d = np.asarray(top_ids_d)
@@ -2536,10 +2569,11 @@ class LLMEngine:
             tok_lp_d = np.asarray(tok_lp_d)
         else:
             top_ids_d = top_lp_d = tok_lp_d = None
-        self._t_fetch = time.monotonic()
 
         self._commit_pending(running)
-        self._apply_sampled(running, nxt, top_ids_d, top_lp_d, tok_lp_d)
+        self._t_fetch = self.obs.phase_t0   # where the fetch ended
+        with self.obs.phase("engine.apply"):
+            self._apply_sampled(running, nxt, top_ids_d, top_lp_d, tok_lp_d)
 
     def _commit_pending(self, running) -> None:
         """Commit every running slot's pending token — the host half of a
@@ -2547,6 +2581,7 @@ class LLMEngine:
         finish+release what's done. Shared verbatim by the lock-step and
         async paths so the two disciplines cannot drift. Slots finished or
         cancelled since the snapshot are skipped (identity check)."""
+        self.obs.phase_enter("engine.commit")
         for s in running:
             if self.slots[s.slot] is not s:
                 continue  # defensive: slot changed mid-step

@@ -26,6 +26,10 @@ log = logging.getLogger(__name__)
 class EngineLoop:
     def __init__(self, engine: LLMEngine, poll_s: float = 0.005):
         self.engine = engine
+        # the loop thread's phases (loop.idle / loop.intake / loop.resolve
+        # here, engine.* inside step()) are entered on the engine's
+        # telemetry: one object tiles the thread's time
+        self._tele = engine.obs
         # items: (prompt_ids, params, extras, future) — or the fan-out
         # group form (prompt_ids, [params]*K, extras, [future]*K), told
         # apart by the future slot holding a list
@@ -126,7 +130,8 @@ class EngineLoop:
             (list(prompt_ids), params or SamplingParams(),
              (prefix, cross_states, cross_len, on_token, deadline_at,
               priority, tenant, already_generated, already_lp,
-              orig_n_prompt, kv_holders, traceparent, idem_key), fut))
+              orig_n_prompt, kv_holders, traceparent, idem_key,
+              time.monotonic()), fut))
         # close the put-after-drain window: if the loop died between our
         # _stop check and the put, nobody will ever drain this item
         if self._stop.is_set():
@@ -153,7 +158,7 @@ class EngineLoop:
         self._submit_q.put(
             (list(prompt_ids), list(params_list),
              (list(on_tokens) if on_tokens else [None] * len(futs),
-              deadline_at, priority, tenant), futs))
+              deadline_at, priority, tenant, time.monotonic()), futs))
         if self._stop.is_set():
             self._fail_all(RuntimeError("engine loop is stopped"))
         return futs
@@ -211,11 +216,19 @@ class EngineLoop:
     # -- loop --------------------------------------------------------------
 
     def _drain_submissions(self, block: bool) -> None:
+        """Take in what callers submitted. ``block``: the engine has no
+        work, so wait one poll for some — ``loop.idle``, one span a poll
+        (the trace reader ignores spans over 50 ms). Leaves ``loop.intake``
+        open either way."""
+        if block:
+            self._tele.phase_enter("loop.idle")
         try:
-            item = self._submit_q.get(timeout=self._poll_s if block else None) \
-                if block else self._submit_q.get_nowait()
+            item = (self._submit_q.get(timeout=self._poll_s) if block
+                    else self._submit_q.get_nowait())
         except queue.Empty:
             return
+        finally:
+            self._tele.phase_enter("loop.intake")
         while True:
             ids, params, extras, fut = item
             if isinstance(fut, list):  # submit_group fan-out item
@@ -223,7 +236,8 @@ class EngineLoop:
             else:
                 (prefix, cross_states, cross_len, on_token, deadline_at,
                  priority, tenant, already_generated, already_lp,
-                 orig_n_prompt, kv_holders, traceparent, idem_key) = extras
+                 orig_n_prompt, kv_holders, traceparent, idem_key,
+                 t_enqueue) = extras
                 try:
                     rid = self.engine.add_request(
                         ids, params, prefix=prefix,
@@ -233,7 +247,7 @@ class EngineLoop:
                         already_generated=already_generated,
                         already_lp=already_lp, orig_n_prompt=orig_n_prompt,
                         kv_holders=kv_holders, traceparent=traceparent,
-                        idem_key=idem_key)
+                        idem_key=idem_key, t_enqueue=t_enqueue)
                     with self._futures_lock:
                         self._futures[rid] = fut
                 except Exception as e:  # bad request (e.g. empty prompt)
@@ -248,14 +262,14 @@ class EngineLoop:
         and a parent id (first admitted member leads). A member whose
         add_request raises fails only its own future — the engine-side
         group-admission guards simply see a smaller group."""
-        on_tokens, deadline_at, priority, tenant = extras
+        on_tokens, deadline_at, priority, tenant, t_enqueue = extras
         parent = -2  # sentinel: first admitted sibling becomes the parent
         for on_token, params, fut in zip(on_tokens, params_list, futs):
             try:
                 rid = self.engine.add_request(
                     ids, params, on_token=on_token,
                     deadline_at=deadline_at, priority=priority,
-                    tenant=tenant, parent_rid=parent)
+                    tenant=tenant, parent_rid=parent, t_enqueue=t_enqueue)
                 if parent == -2:
                     parent = rid
                 with self._futures_lock:
@@ -327,7 +341,9 @@ class EngineLoop:
                     self.engine.finish_pending()
                     continue
                 try:
-                    for fin in self.engine.step():
+                    done = self.engine.step()
+                    self._tele.phase_enter("loop.resolve")
+                    for fin in done:
                         with self._futures_lock:
                             fut = self._futures.pop(fin.req_id, None)
                         if fut is not None:
@@ -339,4 +355,5 @@ class EngineLoop:
         finally:
             # sole cleanup point: runs on clean stop AND on crash, from the
             # loop thread itself, so callers never race live future updates
+            self._tele.phase_enter(None)
             self._fail_all(RuntimeError("engine loop is stopped"))

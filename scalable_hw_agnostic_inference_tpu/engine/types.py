@@ -64,8 +64,13 @@ class Request:
     # and slot free instead of decoding past a budget nobody is waiting on.
     # Survives preemption (the budget is the request's, not the segment's).
     deadline_at: float = 0.0
-    # submission time (monotonic) for TTFT accounting; survives preemption
+    # time the engine took the request in (``add_request``, on the loop
+    # thread, between steps): where queue wait starts; survives preemption
     t_submit: float = 0.0
+    # time the caller submitted it (``EngineLoop.submit``, on the caller's
+    # thread): where TTFT starts, and with t_submit the intake wait. Equal
+    # to t_submit for a direct ``add_request``. Survives preemption.
+    t_enqueue: float = 0.0
     # first-admission time (monotonic): queue-wait accounting. Survives
     # preemption like t_submit — a resume is not a second queue wait.
     t_admit: float = 0.0
@@ -136,8 +141,8 @@ class Finished:
     # one entry per token_ids element when the request asked for logprobs:
     # {"token", "logprob", "top_ids", "top_logprobs"}
     logprobs: Optional[List[Dict[str, Any]]] = None
-    # per-phase timeline (obs): monotonic stamps t_submit/t_admit/t_first/
-    # t_done plus derived queue_s/prefill_s/decode_s/total_s — the serving
+    # per-phase timeline (obs): monotonic stamps t_enqueue/t_submit/t_admit/
+    # t_first/t_done plus derived intake_s/queue_s/prefill_s/decode_s/total_s — the serving
     # layer turns these into request-trace spans and bench.py aggregates
     # them into per-phase report fields
     timing: Optional[Dict[str, float]] = None

@@ -84,11 +84,6 @@ class FlightRecorder:
             recs = self._by_trace.get(trace_id) or []
             return [r["trace"] for r in recs]
 
-    @property
-    def n_recorded(self) -> int:
-        with self._lock:
-            return self._seq
-
     def dump(self, step_source: Optional[Callable[[int],
                                                   List[Dict]]] = None,
              n_requests: Optional[int] = None) -> Dict[str, Any]:

@@ -164,10 +164,6 @@ class HbmLedger:
             snap["samples"] = float(self.samples)
             self._last = snap
 
-    @property
-    def leak_suspect(self) -> bool:
-        return self._drift.leak_suspect
-
     def snapshot(self) -> Dict[str, Any]:
         """Latest sample (flat numeric keys — the ``/stats`` ``"hbm"``
         section; ``serve.metrics`` prefixes each with ``shai_hbm_``)."""

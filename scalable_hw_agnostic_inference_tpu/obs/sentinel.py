@@ -193,8 +193,3 @@ class PerfSentinel:
             "window_busy_s": round(busy, 4),
             "degraded": 1.0 if degraded else 0.0,
         }
-
-    @property
-    def degraded(self) -> bool:
-        with self._lock:
-            return self._degraded

@@ -205,7 +205,3 @@ class SloEngine:
         out["breach"] = 1.0 if any(
             v for k, v in out.items() if k.endswith("_breach")) else 0.0
         return out
-
-    @property
-    def breached(self) -> bool:
-        return bool(self.snapshot()["breach"])

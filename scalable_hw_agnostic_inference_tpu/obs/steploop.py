@@ -23,6 +23,8 @@ import time
 from collections import deque
 from typing import Any, Dict, List, Optional, Sequence, Tuple
 
+from .trace import annotate
+
 #: explicit histogram bounds (seconds). TTFT includes queue time, so its
 #: range reaches minutes; TPOT is per-token decode pace (milliseconds).
 TTFT_BUCKETS = (0.01, 0.025, 0.05, 0.1, 0.25, 0.5, 1.0, 2.5, 5.0, 10.0,
@@ -38,6 +40,29 @@ QUEUE_WAIT_BUCKETS = (0.001, 0.005, 0.01, 0.05, 0.1, 0.25, 0.5, 1.0, 2.5,
 #: lock-step observes the full marshal+bookkeeping gap every step.
 STEP_GAP_BUCKETS = (0.0001, 0.00025, 0.0005, 0.001, 0.0025, 0.005, 0.01,
                     0.025, 0.05, 0.1, 0.5)
+#: submit-to-intake wait: ``EngineLoop.submit`` on the caller's thread to
+#: ``add_request`` on the loop thread, which runs between steps only — a
+#: request that arrives during a prefill program waits out the program here
+INTAKE_WAIT_BUCKETS = (0.0005, 0.001, 0.0025, 0.005, 0.01, 0.025, 0.05, 0.1,
+                       0.25, 0.5, 1.0, 5.0)
+#: the phases of the ``engine-loop`` thread, in the order the event path
+#: walks them. Exactly one is open at any instant (``phase_enter`` closes
+#: the open one at the clock read that opens the next), so their seconds
+#: tile the thread's time and their profiler spans never nest — the trace
+#: reader names a device gap by the host span that overlaps it most, and an
+#: enclosing span would name them all.
+PHASES = ("loop.idle", "loop.intake", "engine.fetch", "engine.apply",
+          "engine.admit", "engine.prefill", "engine.chunk", "engine.verify",
+          "engine.decode", "engine.marshal", "engine.commit",
+          "engine.record", "loop.resolve")
+#: engine phase -> the field of the step's ring record its milliseconds
+#: add to (the four dispatch families share one)
+_STEP_FIELD = {"engine.fetch": "fetch_ms", "engine.apply": "apply_ms",
+               "engine.admit": "admit_ms", "engine.marshal": "marshal_ms",
+               "engine.commit": "commit_ms", "engine.prefill": "dispatch_ms",
+               "engine.chunk": "dispatch_ms", "engine.verify": "dispatch_ms",
+               "engine.decode": "dispatch_ms"}
+_STEP_FIELDS = tuple(dict.fromkeys(_STEP_FIELD.values()))
 #: bounded tenant-label cardinality for the per-tenant instruments: at
 #: most this many distinct tenants get their own label; later arrivals
 #: collapse into "other" so a hostile client minting tenant names cannot
@@ -89,6 +114,25 @@ class BucketHistogram:
             cum += c
             out.append((b, cum))
         return {"buckets": out + [("+Inf", n)], "sum": total, "count": n}
+
+
+class _PhaseScope:
+    """``with tele.phase(name):`` — the open phase gives way to ``name``
+    and resumes (as a new span of its own name) when the body ends: nested
+    in the code, flat in the trace."""
+
+    __slots__ = ("tele", "name", "prev")
+
+    def __init__(self, tele: "StepTelemetry", name: str):
+        self.tele, self.name, self.prev = tele, name, None
+
+    def __enter__(self) -> "_PhaseScope":
+        self.prev = self.tele.phase_enter(self.name)
+        return self
+
+    def __exit__(self, *exc) -> bool:
+        self.tele.phase_enter(self.prev)
+        return False
 
 
 class StepTelemetry:
@@ -143,6 +187,18 @@ class StepTelemetry:
         self.tpot = BucketHistogram(TPOT_BUCKETS)
         self.queue_wait = BucketHistogram(QUEUE_WAIT_BUCKETS)
         self.step_gap = BucketHistogram(STEP_GAP_BUCKETS)
+        self.intake_wait = BucketHistogram(INTAKE_WAIT_BUCKETS)
+        # where the engine-loop thread's time goes (PHASES): cumulative
+        # seconds by phase, the open phase with its start and annotation,
+        # and the running step's share by ring-record field. One thread,
+        # the one that steps the engine, enters phases; any thread reads.
+        self.phase_s: Dict[str, float] = dict.fromkeys(PHASES, 0.0)
+        self.phase_t0 = 0.0          # monotonic start of the open phase
+        self._phase: Optional[str] = None
+        self._phase_ann = None
+        self._step_ms: Dict[str, float] = {}
+        self._step_no = 0
+        self._waiting_peak = 0
         # cumulative counters
         self.steps = 0
         self.preemptions = 0
@@ -167,6 +223,9 @@ class StepTelemetry:
         # (bench.py fused) reads its win off the decode+chunk rows
         self.pad_by_phase: Dict[str, int] = {}
         self.real_by_phase: Dict[str, int] = {}
+        # the same accounting's count of dispatches: work done has a
+        # number of programs, not only of tokens
+        self.dispatches_by_phase: Dict[str, int] = {}
         self.warmed_executables = 0  # closed-set size at readiness
         # last-step gauges (scraped between steps)
         self._gauges: Dict[str, float] = {}
@@ -181,7 +240,8 @@ class StepTelemetry:
         with self._lock:
             self.preemptions += 1
 
-    def count_recompile(self, kind: str = "") -> None:
+    def count_recompile(self) -> None:
+        """One executable built after warm-up."""
         with self._lock:
             self.recompiles += 1
 
@@ -192,9 +252,57 @@ class StepTelemetry:
                 self._flush_reasons[reason] = (
                     self._flush_reasons.get(reason, 0) + 1)
 
-    def flush_reasons(self) -> Dict[str, int]:
+    # -- phases of the engine-loop thread -----------------------------------
+
+    def phase_enter(self, name: Optional[str]) -> Optional[str]:
+        """Close the open phase and open ``name`` (``None``: open nothing)
+        at one read of the monotonic clock. The closed phase's seconds add
+        to ``phase_s`` and to the running step's ring record; the opened
+        one is a ``TraceAnnotation`` through ``obs.trace.annotate`` (tracing
+        switched off: seconds only), an engine phase's with its step's
+        number: the join with the ring. Returns the phase it closed."""
+        now = time.monotonic()
+        if self._phase_ann is not None:
+            self._phase_ann.__exit__(None, None, None)
         with self._lock:
-            return dict(self._flush_reasons)
+            prev = self._phase
+            if prev is not None:
+                dt = now - self.phase_t0
+                self.phase_s[prev] = self.phase_s.get(prev, 0.0) + dt
+                field = _STEP_FIELD.get(prev)
+                if field is not None:
+                    self._step_ms[field] = (self._step_ms.get(field, 0.0)
+                                            + dt * 1e3)
+                elif prev == "engine.record" and self._steps:
+                    # the record was written as this phase opened
+                    self._steps[-1]["record_ms"] = round(dt * 1e3, 4)
+            self._phase, self.phase_t0 = name, now
+            step = self._step_no
+        if name is None:
+            self._phase_ann = None
+        else:
+            self._phase_ann = (annotate(name, step=step)
+                               if name.startswith("engine.")
+                               else annotate(name))
+            self._phase_ann.__enter__()
+        return prev
+
+    def phase(self, name: str) -> _PhaseScope:
+        """``with`` form of :meth:`phase_enter`: ``name`` interrupts the open
+        phase, which resumes when the body ends."""
+        return _PhaseScope(self, name)
+
+    def begin_step(self, n_waiting: int) -> Optional[str]:
+        """An engine step starts: its phases add to a fresh record,
+        ``n_waiting`` (the queue after intake, before admission: the most
+        it holds this step) is its ``waiting_peak``, and ``engine.admit``
+        opens. Returns the phase that was open (``None``: the caller steps
+        the engine with no loop around it, and closes the last phase)."""
+        with self._lock:
+            self._step_ms = {}
+            self._step_no = self.steps + 1
+            self._waiting_peak = n_waiting
+        return self.phase_enter("engine.admit")
 
     # -- per-tenant attribution (multi-tenant QoS) -------------------------
 
@@ -276,15 +384,8 @@ class StepTelemetry:
                     self.real_by_phase.get(phase, 0) + max(0, real))
                 self.pad_by_phase[phase] = (
                     self.pad_by_phase.get(phase, 0) + max(0, padded))
-
-    def pad_phase_snapshot(self) -> Dict[str, Dict[str, int]]:
-        """phase -> {real, pad} cumulative counts (the ``/metrics``
-        label export and the ``/stats`` -> ``pad_by_phase`` payload)."""
-        with self._lock:
-            return {p: {"real": self.real_by_phase.get(p, 0),
-                        "pad": self.pad_by_phase.get(p, 0)}
-                    for p in set(self.real_by_phase)
-                    | set(self.pad_by_phase)}
+                self.dispatches_by_phase[phase] = (
+                    self.dispatches_by_phase.get(phase, 0) + 1)
 
     def record_step(self, *, kind: str, duration_s: float, n_running: int,
                     n_waiting: int, n_chunking: int, blocks_free: int,
@@ -330,6 +431,10 @@ class StepTelemetry:
             self.steps += 1
             self.requests_finished += finished
             rec["step"] = self.steps
+            for field in _STEP_FIELDS:
+                rec[field] = round(self._step_ms.get(field, 0.0), 4)
+            rec["record_ms"] = 0.0   # set when ``engine.record`` closes
+            rec["waiting_peak"] = self._waiting_peak
             rec["preemptions_total"] = self.preemptions
             rec["recompiles_total"] = self.recompiles
             self._steps.append(rec)
@@ -404,6 +509,15 @@ class StepTelemetry:
                 p: {"real": self.real_by_phase.get(p, 0),
                     "pad": self.pad_by_phase.get(p, 0)}
                 for p in set(self.real_by_phase) | set(self.pad_by_phase)}
+            out["dispatches_by_phase"] = dict(self.dispatches_by_phase)
+            out["flush_by_reason"] = dict(self._flush_reasons)
+            # the open phase's seconds so far included: two readings
+            # differ by the time between them, whatever each caught open
+            out["phase_s"] = dict(self.phase_s)
+            if self._phase is not None:
+                out["phase_s"][self._phase] = (
+                    out["phase_s"].get(self._phase, 0.0)
+                    + max(0.0, time.monotonic() - self.phase_t0))
             out.update(self._gauges)
         kvt = self.kvtier
         if kvt is not None:
@@ -419,7 +533,8 @@ class StepTelemetry:
             out["host_kv_hit_rate"] = ksnap.get("hit_rate", 0.0)
         for name, h in (("ttft", self.ttft), ("tpot", self.tpot),
                         ("queue_wait", self.queue_wait),
-                        ("step_gap", self.step_gap)):
+                        ("step_gap", self.step_gap),
+                        ("intake_wait", self.intake_wait)):
             out[f"{name}_count"] = h.count
         return out
 
@@ -428,4 +543,5 @@ class StepTelemetry:
         return {"ttft_seconds": self.ttft.snapshot(),
                 "tpot_seconds": self.tpot.snapshot(),
                 "queue_wait_seconds": self.queue_wait.snapshot(),
-                "step_gap_seconds": self.step_gap.snapshot()}
+                "step_gap_seconds": self.step_gap.snapshot(),
+                "intake_wait_seconds": self.intake_wait.snapshot()}

@@ -184,25 +184,28 @@ class _NoopSpan:
 NOOP = _NoopSpan()
 
 
-def _annotation(name: str):
+def _annotation(name: str, **meta):
     """A ``jax.profiler.TraceAnnotation`` when JAX is already imported
-    (never imports jax itself — tracing must not pull the backend in)."""
+    (never imports jax itself — tracing must not pull the backend in).
+    ``meta`` lands in the event's stats, not in its name."""
     jax = sys.modules.get("jax")
     if jax is None:
         return None
     try:
-        return jax.profiler.TraceAnnotation(name)
+        return jax.profiler.TraceAnnotation(name, **meta)
     except Exception:  # pragma: no cover - profiler API moved
         return None
 
 
-def annotate(name: str):
-    """Bare device-trace annotation (no span bookkeeping): the engine wraps
-    its dispatch phases with this so ``/profile`` traces show step structure
-    even for work not tied to one request."""
+def annotate(name: str, **meta):
+    """Bare device-trace annotation (no span bookkeeping): the engine
+    loop's phases (``StepTelemetry.phase_enter``) and the unit's start-up
+    phases are written through this, so ``/profile`` traces show step
+    structure even for work not tied to one request. ``meta`` (the step's
+    number) rides as the event's stats."""
     if not _enabled:
         return NOOP
-    return _annotation(name) or NOOP
+    return _annotation(name, **meta) or NOOP
 
 
 # -- traces ------------------------------------------------------------------
@@ -261,7 +264,7 @@ class Trace:
 
     def add_phase_spans(self, timing: Dict[str, float],
                         parent: Optional[Span] = None) -> None:
-        """Engine ``Finished.timing`` → queue/prefill/decode child spans,
+        """Engine ``Finished.timing`` → intake/queue/prefill/decode child spans,
         plus the sub-phase events the span tree cannot see from outside:
         the fabric-probe rung and KV-tier restore become child spans of
         whichever phase window contains them (the probe can run before
@@ -275,6 +278,11 @@ class Trace:
         t_done = timing.get("t_done") or t_first
         if not t_sub:
             return
+        t_enq = timing.get("t_enqueue") or t_sub
+        if t_enq < t_sub:
+            # submitted on the caller's thread, taken in by the loop thread
+            # between steps: the wait ahead of the queue
+            self.add_span("intake", t_enq, t_sub, parent=parent)
         queue = self.add_span("queue", t_sub, t_adm, parent=parent)
         prefill = self.add_span("prefill", t_adm, t_first, parent=parent)
         decode = self.add_span("decode", t_first, t_done, parent=parent)

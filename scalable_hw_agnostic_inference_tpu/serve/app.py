@@ -71,6 +71,9 @@ class ModelService:
     #: entry would wedge the slice; serve_multihost refuses it.
     supports_multihost: bool = False
     mirror_methods: Tuple[str, ...] = ("infer",)
+    #: seconds from boot to ready by phase, written once by a unit that
+    #: keeps them (the ``vllm`` unit); ``/stats`` shows it as ``startup``
+    startup: Dict[str, float] = {}
 
     def __init__(self, cfg: ServeConfig):
         self.cfg = cfg
@@ -763,6 +766,8 @@ def create_app(
             svc = {}
         if svc:
             out["service"] = svc
+        if service.startup:
+            out["startup"] = dict(service.startup)
         tele = service.engine_telemetry()
         if tele is not None:
             out["engine"] = tele.snapshot()

@@ -56,6 +56,9 @@ ENGINE_HISTOGRAMS = {
                          "Inter-step device gap: host time between a decode "
                          "readback and the next dispatch (0 when the async "
                          "pipeline dispatched ahead of the readback)"),
+    "intake_wait_seconds": ("shai_intake_wait_seconds",
+                            "Submit on the caller's thread to intake by "
+                            "the engine loop, which runs between steps"),
 }
 _ENGINE_GAUGES = {
     "running": ("shai_engine_running", "Sequences decoding right now"),
@@ -101,6 +104,11 @@ _PAD_PHASE_COUNTERS = {
                     "cumulative",
                     "real"),
 }
+#: where the engine-loop thread's time goes: cumulative seconds by phase
+#: (obs.steploop.PHASES — loop.idle, loop.intake, engine.admit, ...). The
+#: phases tile the thread, so the rates over a window sum to one.
+_PHASE_SECONDS = ("shai_engine_phase_seconds_total",
+                  "Seconds the engine-loop thread spent in each phase")
 #: conformance-layer gauge families: each instrument riding the engine
 #: telemetry object exports its flat numeric snapshot verbatim under a
 #: prefix — obs.slo → shai_slo_* (per-objective burn rates + breach),
@@ -276,6 +284,10 @@ class EngineTelemetryCollector:
             if total - phased or not phases:
                 c.add_metric([self.app, ""], total - phased)
             yield c
+        c = CounterMetricFamily(*_PHASE_SECONDS, labels=["app", "phase"])
+        for phase, secs in sorted((snap.get("phase_s") or {}).items()):
+            c.add_metric([self.app, phase], float(secs))
+        yield c
         hists = tele.histograms()
         for key, (name, doc) in ENGINE_HISTOGRAMS.items():
             hs = hists.get(key)

@@ -9,6 +9,7 @@ from __future__ import annotations
 
 import logging
 import threading
+import time
 from typing import Any, Dict, List, Optional
 
 import jax
@@ -125,162 +126,184 @@ class VllmService(ModelService):
         cfg = self.cfg
         ecfg = self.ecfg
         model_id = ecfg.model or cfg.model_id
-        # tensor_parallel_size is honored, never silently dropped: the
-        # reference's TP=32 serving tier (compile-vllm-job.yaml:54-55) maps to
-        # a tp mesh over local chips; an over-sized config is a deploy error.
-        # Built BEFORE the weights so the geometry tier is born sharded.
-        mesh = None
-        tp = ecfg.tensor_parallel_size
-        if tp > 1:
-            from ...core.device import local_devices
-            from ...core.mesh import build_mesh
+        # where the time from boot to ready goes (/stats "startup"): plain
+        # stamps, and a span each on the profiler's clock
+        t = [time.monotonic()]
+        with obs_trace.annotate("startup.weights"):
+            # tensor_parallel_size is honored, never silently dropped: the
+            # reference's TP=32 serving tier (compile-vllm-job.yaml:54-55)
+            # maps to a tp mesh over local chips; an over-sized config is a
+            # deploy error. Built BEFORE the weights so the geometry tier is
+            # born sharded.
+            mesh = None
+            tp = ecfg.tensor_parallel_size
+            if tp > 1:
+                from ...core.device import local_devices
+                from ...core.mesh import build_mesh
 
-            devs = local_devices()
-            if tp > len(devs):
-                raise ValueError(
-                    f"tensor_parallel_size={tp} exceeds the {len(devs)} local "
-                    f"devices of this unit — match it to the nodepool's chip "
-                    f"count (reference compile-vllm-job.yaml:54-55)")
-            mesh = build_mesh(f"tp={tp}", devices=devs[:tp])
-        vlm_parts = None
-        self._mllama = None
-        # a populated mllama artifact routes the boot by itself — a serving
-        # pod with the artifacts PVC must not need hub access to know what
-        # architecture it is serving
-        from ...core import weights as wstore
+                devs = local_devices()
+                if tp > len(devs):
+                    raise ValueError(
+                        f"tensor_parallel_size={tp} exceeds the {len(devs)} "
+                        f"local devices of this unit — match it to the "
+                        f"nodepool's chip count (reference "
+                        f"compile-vllm-job.yaml:54-55)")
+                mesh = build_mesh(f"tp={tp}", devices=devs[:tp])
+            vlm_parts = None
+            self._mllama = None
+            # a populated mllama artifact routes the boot by itself — a
+            # serving pod with the artifacts PVC must not need hub access to
+            # know what architecture it is serving
+            from ...core import weights as wstore
 
-        from .causal_lm import _geometry_models
+            from .causal_lm import _geometry_models
 
-        # geometry ids are architecture names, not hub repos: the VLM
-        # autoconfig probe must not fire an HF lookup for them (the tier's
-        # whole point is booting with zero network access)
-        real_id = (model_id not in ("", "tiny")
-                   and model_id not in _geometry_models())
-        has_mllama_artifact = real_id and wstore.has_params(
-            cfg.artifact_root, f"mllama--{model_id}")
-        has_vlm_artifact = real_id and wstore.has_params(
-            cfg.artifact_root, f"vlm--{model_id}")
-        offline = has_mllama_artifact or has_vlm_artifact
-        # tiny/geometry ids never consult the hub (no network on bench hosts)
-        hf_cfg = None if (offline or not real_id) else _autoconfig_of(
-            cfg, model_id)
-        is_vlm = offline or (
-            hf_cfg is not None and hasattr(hf_cfg, "vision_config")
-            and hasattr(hf_cfg, "text_config"))
-        if is_vlm:
-            if (has_mllama_artifact
-                    or getattr(hf_cfg, "model_type", "") == "mllama"):
-                # Llama-3.2-Vision: gated cross-attention architecture —
-                # the reference's actual multimodal unit
-                # (cova/mllama-32-11b-vllm-trn1-config.yaml)
-                (mcfg, params, mvcfg, encode_image, p1,
-                 self.tokenizer) = _load_mllama(cfg, model_id, hf_cfg)
-                self._mllama = (mvcfg, encode_image, p1)
+            # geometry ids are architecture names, not hub repos: the VLM
+            # autoconfig probe must not fire an HF lookup for them (the tier's
+            # whole point is booting with zero network access)
+            real_id = (model_id not in ("", "tiny")
+                       and model_id not in _geometry_models())
+            has_mllama_artifact = real_id and wstore.has_params(
+                cfg.artifact_root, f"mllama--{model_id}")
+            has_vlm_artifact = real_id and wstore.has_params(
+                cfg.artifact_root, f"vlm--{model_id}")
+            offline = has_mllama_artifact or has_vlm_artifact
+            # tiny/geometry ids never consult the hub (no network on bench
+            # hosts)
+            hf_cfg = None if (offline or not real_id) else _autoconfig_of(
+                cfg, model_id)
+            is_vlm = offline or (
+                hf_cfg is not None and hasattr(hf_cfg, "vision_config")
+                and hasattr(hf_cfg, "text_config"))
+            if is_vlm:
+                if (has_mllama_artifact
+                        or getattr(hf_cfg, "model_type", "") == "mllama"):
+                    # Llama-3.2-Vision: gated cross-attention architecture —
+                    # the reference's actual multimodal unit
+                    # (cova/mllama-32-11b-vllm-trn1-config.yaml)
+                    (mcfg, params, mvcfg, encode_image, p1,
+                     self.tokenizer) = _load_mllama(cfg, model_id, hf_cfg)
+                    self._mllama = (mvcfg, encode_image, p1)
+                else:
+                    (mcfg, params, real_vcfg, real_vparams,
+                     self.tokenizer) = _load_vlm(cfg, model_id, hf_cfg)
+                    vlm_parts = (real_vcfg, real_vparams)
+                eos = self.tokenizer.eos_token_id
+                if eos is None:
+                    raise ValueError(
+                        f"tokenizer for {model_id} has no eos_token_id")
+                pad = self.tokenizer.pad_token_id
+                self.eos_id = int(eos)
+                self.pad_id = int(pad) if pad is not None else int(eos)
+                self._byte_tok = False
             else:
-                (mcfg, params, real_vcfg, real_vparams,
-                 self.tokenizer) = _load_vlm(cfg, model_id, hf_cfg)
-                vlm_parts = (real_vcfg, real_vparams)
-            eos = self.tokenizer.eos_token_id
-            if eos is None:
-                raise ValueError(f"tokenizer for {model_id} has no eos_token_id")
-            pad = self.tokenizer.pad_token_id
-            self.eos_id = int(eos)
-            self.pad_id = int(pad) if pad is not None else int(eos)
-            self._byte_tok = False
-        else:
-            (mcfg, _model, params, self.tokenizer,
-             self.eos_id, self.pad_id, self._byte_tok) = _load_causal_lm(
-                cfg, model_id, quant=ecfg.quantization == "int8", mesh=mesh)
-        if self._byte_tok and model_id in ("", "tiny"):
-            # tiny engine shapes: small blocks/buckets so CI exercises
-            # paging (geometry model ids also use the byte tokenizer but
-            # keep their REAL engine shapes — they exist to measure the
-            # real serving stack)
-            ecfg = EngineConfig(
-                model="tiny", max_model_len=256, max_num_seqs=ecfg.max_num_seqs,
-                block_size=16, context_encoding_buckets=(32, 64, 128),
-                token_generation_buckets=ecfg.token_generation_buckets,
-                tensor_parallel_size=ecfg.tensor_parallel_size,
-                quantization=ecfg.quantization,
-                enable_prefix_caching=ecfg.enable_prefix_caching,
-                max_new_tokens=min(ecfg.max_new_tokens, 64),
-                # speculative knobs ride through: the tiny tier is how CI
-                # and serving smokes exercise the verify executables
-                speculative_model=ecfg.speculative_model,
-                num_speculative_tokens=ecfg.num_speculative_tokens,
-                ngram_prompt_lookup_max=ecfg.ngram_prompt_lookup_max,
-                ngram_prompt_lookup_min=ecfg.ngram_prompt_lookup_min,
-                role=ecfg.role)
+                (mcfg, _model, params, self.tokenizer,
+                 self.eos_id, self.pad_id, self._byte_tok) = _load_causal_lm(
+                    cfg, model_id, quant=ecfg.quantization == "int8",
+                    mesh=mesh)
+            if self._byte_tok and model_id in ("", "tiny"):
+                # tiny engine shapes: small blocks/buckets so CI exercises
+                # paging (geometry model ids also use the byte tokenizer but
+                # keep their REAL engine shapes — they exist to measure the
+                # real serving stack)
+                ecfg = EngineConfig(
+                    model="tiny", max_model_len=256,
+                    max_num_seqs=ecfg.max_num_seqs,
+                    block_size=16, context_encoding_buckets=(32, 64, 128),
+                    token_generation_buckets=ecfg.token_generation_buckets,
+                    tensor_parallel_size=ecfg.tensor_parallel_size,
+                    quantization=ecfg.quantization,
+                    enable_prefix_caching=ecfg.enable_prefix_caching,
+                    max_new_tokens=min(ecfg.max_new_tokens, 64),
+                    # speculative knobs ride through: the tiny tier is how CI
+                    # and serving smokes exercise the verify executables
+                    speculative_model=ecfg.speculative_model,
+                    num_speculative_tokens=ecfg.num_speculative_tokens,
+                    ngram_prompt_lookup_max=ecfg.ngram_prompt_lookup_max,
+                    ngram_prompt_lookup_min=ecfg.ngram_prompt_lookup_min,
+                    role=ecfg.role)
 
-        self.ecfg = ecfg
-        if ecfg.quantization == "int8":
-            # weight-only int8 at boot (one pass; the geometry tier's
-            # weights were born int8 and pass through untouched): halves
-            # decode HBM traffic; the vLLM `quantization:` ConfigMap knob
-            from ...ops.quant import quantize_params_tree
+            self.ecfg = ecfg
+            if ecfg.quantization == "int8":
+                # weight-only int8 at boot (one pass; the geometry tier's
+                # weights were born int8 and pass through untouched): halves
+                # decode HBM traffic; the vLLM `quantization:` ConfigMap knob
+                from ...ops.quant import quantize_params_tree
 
-            params = quantize_params_tree(params)
-        if mesh is not None:
-            from ...models import llama as llama_mod
-            from ...parallel.sharding import shard_pytree
+                params = quantize_params_tree(params)
+            if mesh is not None:
+                from ...models import llama as llama_mod
+                from ...parallel.sharding import shard_pytree
 
-            if tp > mcfg.n_kv_heads:
-                # more ranks than GQA kv heads (the reference's 70B TP=32
-                # tier): widen kv heads by weight-side replication so the
-                # head-local engine shardings stay legal
-                # (models.llama.replicate_kv_heads; numerics unchanged)
-                params, mcfg = llama_mod.replicate_kv_heads(params, mcfg, tp)
-            params = shard_pytree(params, mesh, llama_mod.tp_rules())
-        else:
-            params = jax.device_put(params)
-        engine = LLMEngine(
-            mcfg, params, ecfg, mesh=mesh,
-            cross_seq_len=self._mllama[2] if self._mllama else 0)
-        self._engine = engine
-        self._SamplingParams = SamplingParams
-        # the lane is max_num_seqs wide; HF fast tokenizers mutate Rust-side
-        # truncation state per call and are not thread-safe
-        import threading
+                if tp > mcfg.n_kv_heads:
+                    # more ranks than GQA kv heads (the reference's 70B TP=32
+                    # tier): widen kv heads by weight-side replication so the
+                    # head-local engine shardings stay legal
+                    # (models.llama.replicate_kv_heads; numerics unchanged)
+                    params, mcfg = llama_mod.replicate_kv_heads(
+                        params, mcfg, tp)
+                params = shard_pytree(params, mesh, llama_mod.tp_rules())
+            else:
+                params = jax.device_put(params)
+            # dispatch is asynchronous: the weights' seconds end on the device
+            jax.block_until_ready(params)
+        t.append(time.monotonic())
+        with obs_trace.annotate("startup.engine"):
+            engine = LLMEngine(
+                mcfg, params, ecfg, mesh=mesh,
+                cross_seq_len=self._mllama[2] if self._mllama else 0)
+            self._engine = engine
+            self._SamplingParams = SamplingParams
+            # the lane is max_num_seqs wide; HF fast tokenizers mutate
+            # Rust-side truncation state per call and are not thread-safe
+            import threading
 
-        self._tok_lock = threading.Lock()
-        # multimodal tier (reference vllm_model_api_m.py): a vision tower
-        # projecting image patches into the LM embedding space as a soft
-        # prefix. The tiny tier always carries one so the path is CI-tested;
-        # real VLM checkpoints attach through the same seam.
-        self._vision = None
-        if vlm_parts is not None:
-            from ...models.vlm import VisionProjector
+            self._tok_lock = threading.Lock()
+            # multimodal tier (reference vllm_model_api_m.py): a vision tower
+            # projecting image patches into the LM embedding space as a soft
+            # prefix. The tiny tier always carries one so the path is
+            # CI-tested; real VLM checkpoints attach through the same seam.
+            self._vision = None
+            if vlm_parts is not None:
+                from ...models.vlm import VisionProjector
 
-            vcfg, vparams = vlm_parts
-            vm = VisionProjector(vcfg, dtype=jnp.bfloat16)
-            vparams = jax.device_put(vparams)
-            self._vision = (vcfg, jax.jit(lambda px: vm.apply(vparams, px)))
-        elif self._byte_tok and model_id in ("", "tiny"):
-            from ...models.vlm import VisionProjector, VisionTowerConfig
+                vcfg, vparams = vlm_parts
+                vm = VisionProjector(vcfg, dtype=jnp.bfloat16)
+                vparams = jax.device_put(vparams)
+                self._vision = (
+                    vcfg, jax.jit(lambda px: vm.apply(vparams, px)))
+            elif self._byte_tok and model_id in ("", "tiny"):
+                from ...models.vlm import VisionProjector, VisionTowerConfig
 
-            vcfg = VisionTowerConfig.tiny(lm_dim=mcfg.dim)
-            vm = VisionProjector(vcfg)
-            vp = vm.init(jax.random.PRNGKey(cfg.seed + 9),
-                         jnp.zeros((1, vcfg.image_size, vcfg.image_size, 3)))
-            self._vision = (vcfg, jax.jit(lambda px: vm.apply(vp, px)))
-        if self._vision is not None:  # the vision jit is in the closed set too
-            vcfg = self._vision[0]
-            self._vision[1](jnp.zeros(
-                (1, vcfg.image_size, vcfg.image_size, 3))).block_until_ready()
-        if self._mllama is not None:  # so is the mllama vision front-end
-            from PIL import Image
+                vcfg = VisionTowerConfig.tiny(lm_dim=mcfg.dim)
+                vm = VisionProjector(vcfg)
+                vp = vm.init(jax.random.PRNGKey(cfg.seed + 9),
+                             jnp.zeros((1, vcfg.image_size,
+                                        vcfg.image_size, 3)))
+                self._vision = (vcfg, jax.jit(lambda px: vm.apply(vp, px)))
+            if self._vision is not None:  # its jit is in the closed set too
+                vcfg = self._vision[0]
+                self._vision[1](jnp.zeros(
+                    (1, vcfg.image_size, vcfg.image_size, 3))
+                ).block_until_ready()
+            if self._mllama is not None:  # so is the mllama vision front-end
+                from PIL import Image
 
-            mvcfg, encode_image, _lv = self._mllama
-            encode_image(Image.new(
-                "RGB", (mvcfg.image_size, mvcfg.image_size), (127, 127, 127)))
-        # compile the CLOSED executable set — every (bucket, prefix) prefill
-        # plus every context-bucket decode — BEFORE the engine loop starts
-        # serving, so no post-ready request ever eats an XLA compile (the
-        # cold-graph-behind-the-ALB failure; reference run-sd.py:144-146)
-        prefix_lens = [0]
-        if self._vision is not None:
-            prefix_lens.append(self._vision[0].n_patches)
-        n = engine.warm_executables(prefix_lens)
+                mvcfg, encode_image, _lv = self._mllama
+                encode_image(Image.new(
+                    "RGB", (mvcfg.image_size, mvcfg.image_size),
+                    (127, 127, 127)))
+            # compile the CLOSED executable set — every (bucket, prefix)
+            # prefill plus every context-bucket decode — BEFORE the loop starts
+            # serving, so no post-ready request ever eats an XLA compile (the
+            # cold-graph-behind-the-ALB failure; reference run-sd.py:144-146)
+            prefix_lens = [0]
+            if self._vision is not None:
+                prefix_lens.append(self._vision[0].n_patches)
+        t.append(time.monotonic())
+        with obs_trace.annotate("startup.warm_executables"):
+            n = engine.warm_executables(prefix_lens)
+        t.append(time.monotonic())
         log.info("engine: warmed %d executables (buckets=%s, prefixes=%s)",
                  n, list(engine.buckets.buckets), prefix_lens)
         # network KV transport (kvnet): with a host tier attached this pod
@@ -318,6 +341,23 @@ class VllmService(ModelService):
             lambda: engine.obs, lambda: engine.has_work,
             multiplier=env_float("SHAI_WATCHDOG_MULT", 30.0),
             min_stall_s=env_float("SHAI_WATCHDOG_MIN_S", 10.0))
+        self._startup_s = {"weights_s": t[1] - t[0], "engine_s": t[2] - t[1],
+                           "warm_executables_s": t[3] - t[2]}
+        self._t_load0 = t[0]
+
+    def warmup(self) -> None:
+        t0 = time.monotonic()
+        with obs_trace.annotate("startup.warmup"):
+            super().warmup()
+        # seconds from the start of ``load()`` to ready, by phase, written
+        # once; ``other_s`` is what lies between and after the named ones
+        now = time.monotonic()
+        s = dict(self._startup_s, warmup_s=now - t0)
+        s["other_s"] = now - self._t_load0 - sum(s.values())
+        s["total_s"] = now - self._t_load0
+        self.startup = {k: round(v, 3) for k, v in s.items()}
+        log.info("%s: ready in %.1f s: %s", self.cfg.app,
+                 self.startup["total_s"], self.startup)
 
     def ready_error(self) -> Optional[str]:
         # a dead engine loop (crashed step()) must drain the pod: /readiness
@@ -950,13 +990,11 @@ class VllmService(ModelService):
             out["ttft_p99_ms"] = round(rep["p99"] * 1e3, 2)
         if eng.tpot.count:
             out["tpot_p50_ms"] = round(eng.tpot.report()["p50"] * 1e3, 2)
-        # async decode pipeline health: flush count (serialization events,
-        # per-reason breakdown as flat keys) and the realized inter-step
-        # gap — near-zero mean gap says the lookahead is actually hiding
-        # the host work (SHAI_ASYNC_DECODE)
+        # async decode pipeline health: flush count (serialization events;
+        # by reason under /stats "engine": flush_by_reason) and the realized
+        # inter-step gap — near-zero mean gap says the lookahead is actually
+        # hiding the host work (SHAI_ASYNC_DECODE)
         out["pipeline_flushes"] = eng.obs.pipeline_flushes
-        for reason, n in eng.obs.flush_reasons().items():
-            out[f"pipeline_flush_{reason}"] = n
         gap = eng.obs.step_gap.snapshot()
         if gap["count"]:
             out["step_gap_mean_ms"] = round(

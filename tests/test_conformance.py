@@ -99,7 +99,6 @@ def test_hbm_ledger_leak_flag_reaches_snapshot():
     for drift in (0, 100, 200):
         led.sample(pools={"kv_pool": 100}, composition=(0, 0, 0),
                    drift_value=drift)
-    assert led.leak_suspect
     assert led.snapshot()["leak_suspect"] == 1.0
 
 
@@ -446,7 +445,6 @@ def test_engine_hbm_leak_detector_flags_kv_block_leak(tiny_model,
     snap = eng.obs.hbm.snapshot()
     assert snap["kv_leaked_bytes"] > 0.0
     assert snap["leak_suspect"] == 1.0, snap
-    assert eng.obs.hbm.leak_suspect
 
 
 def test_engine_sentinel_degrades_under_slowed_step_loop(tiny_model,

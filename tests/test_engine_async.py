@@ -9,6 +9,7 @@ The lock-step path (``SHAI_ASYNC_DECODE=0``) is kept alive exactly to be
 this oracle.
 """
 
+import threading
 import time
 
 import numpy as np
@@ -234,7 +235,7 @@ def test_async_cancel_mid_decode_flush_conserves_blocks(tiny_model,
         out[mode] = (fins, rid, keep)
         assert pool_balanced(eng)
         if mode:
-            assert eng.obs.flush_reasons().get("cancelled") == 1
+            assert eng.obs.snapshot()["flush_by_reason"].get("cancelled") == 1
     fa, rid, keep = out[True]
     fb, _, _ = out[False]
     assert_finished_equal(fa[rid], fb[rid])
@@ -405,3 +406,195 @@ def test_async_gate_env_off_is_lockstep(tiny_model, monkeypatch):
                                              max_new_tokens=4))
     assert eng._pipe is None
     assert eng.obs.pipeline_flushes == 0
+
+
+# ---------------------------------------------------------------------------
+# the loop thread accounts for its own time (obs.steploop phases)
+# ---------------------------------------------------------------------------
+
+class SpanLog:
+    """Stands in for ``obs.trace.annotate`` under ``obs.steploop``: every
+    annotation it hands out records its enter (name and metadata) and its
+    exit, and refuses an enter while another is open on the same thread
+    (another test's engine loop may still be polling in this process)."""
+
+    def __init__(self):
+        self._here = threading.local()
+        self.names = []
+        self.meta = []
+
+    @property
+    def open(self):
+        return getattr(self._here, "open", None)
+
+    def __call__(self, name, **meta):
+        log = self
+
+        class Ann:
+            def __enter__(self):
+                assert log.open is None, (
+                    f"{name} entered while {log.open} is open")
+                log._here.open = name
+                log.names.append(name)
+                log.meta.append(meta)
+
+            def __exit__(self, *exc):
+                assert log.open == name
+                log._here.open = None
+
+        return Ann()
+
+
+def test_phases_tile_the_loop_thread(tiny_model, monkeypatch):
+    """Arrivals, a pause with nothing to run, more arrivals, a finish: the
+    loop thread's phases are flat, their seconds add up to the time that
+    passed, and each step's record holds no more than the step took."""
+    from scalable_hw_agnostic_inference_tpu.engine.loop import EngineLoop
+    from scalable_hw_agnostic_inference_tpu.obs import steploop
+
+    spans = SpanLog()
+    monkeypatch.setattr(steploop, "annotate", spans)
+    eng = make_engine(tiny_model, True, monkeypatch)
+    loop = EngineLoop(eng).start()
+    sp = SamplingParams(temperature=0.0, max_new_tokens=6)
+    try:
+        loop.submit([1, 2, 3], sp).result(120)   # compiles: before the clock
+        t0a = time.monotonic()
+        before, t0b = eng.obs.snapshot()["phase_s"], time.monotonic()
+        futs = [loop.submit([5, 6, 7, 8], sp) for _ in range(4)]  # 3 slots
+        for f in futs:
+            f.result(120)
+        time.sleep(0.05)                          # the loop polls, idle
+        futs = [loop.submit([9, 10, 11], sp) for _ in range(2)]
+        loop.cancel(futs[1])
+        for f in futs:
+            f.result(120)
+        t1a = time.monotonic()
+        after, t1b = eng.obs.snapshot()["phase_s"], time.monotonic()
+    finally:
+        loop.stop()
+    spent = {k: after[k] - before[k] for k in after}
+    # each reading lies between two reads of the clock
+    assert (t1a - t0b) * 0.98 <= sum(spent.values()) <= (t1b - t0a) * 1.02
+    for phase in ("loop.idle", "loop.intake", "loop.resolve", "engine.admit",
+                  "engine.prefill", "engine.fetch", "engine.apply",
+                  "engine.marshal", "engine.decode", "engine.commit",
+                  "engine.record"):
+        assert spent[phase] > 0, phase
+        assert phase in spans.names
+    fields = ("admit_ms", "marshal_ms", "dispatch_ms", "commit_ms",
+              "fetch_ms", "apply_ms")
+    steps = eng.obs.recent_steps()
+    assert steps
+    for rec in steps:
+        assert sum(rec[f] for f in fields) <= rec["duration_s"] * 1e3 + 0.01
+        assert rec["waiting_peak"] >= rec["waiting"]
+    assert max(r["waiting_peak"] for r in steps) >= 1   # 4 callers, 3 slots
+
+
+def test_stepping_with_no_loop_leaves_no_phase_open(tiny_model, monkeypatch):
+    eng = make_engine(tiny_model, True, monkeypatch)
+    eng.generate([[1, 2, 3]], SamplingParams(temperature=0.0,
+                                             max_new_tokens=4))
+    a = eng.obs.snapshot()["phase_s"]
+    time.sleep(0.02)
+    assert eng.obs.snapshot()["phase_s"] == a   # nothing runs on
+    assert a["engine.decode"] > 0 and a["loop.idle"] == 0
+
+
+def test_callers_beyond_the_slots_flush_every_step(tiny_model, monkeypatch):
+    """Pins today's behaviour for the ``perf_opt`` PR that changes it: with
+    12 callers on 8 slots about four requests always wait, so every step
+    goes the event way and retires the lookahead for reason ``admission``:
+    the async pipeline never streams."""
+    from scalable_hw_agnostic_inference_tpu.engine.loop import EngineLoop
+
+    eng = make_engine(tiny_model, True, monkeypatch, max_num_seqs=8,
+                      max_model_len=64)
+    loop = EngineLoop(eng).start()
+    sp = SamplingParams(temperature=0.0, max_new_tokens=10)
+    marks = []          # snapshots as requests finish, on the loop thread
+
+    def caller(i):
+        for j in range(3):
+            fut = loop.submit([1 + i, 2 + j, 3], sp)
+            fut.add_done_callback(
+                lambda _f: marks.append(eng.obs.snapshot()))
+            fut.result(300)
+
+    try:
+        threads = [threading.Thread(target=caller, args=(i,))
+                   for i in range(12)]
+        for t in threads:
+            t.start()
+        for t in threads:
+            t.join(600)
+    finally:
+        loop.stop()
+    assert len(marks) == 36
+    # between the first finish and the twentieth all 12 callers are live
+    a, b = marks[0], marks[19]
+    steps = b["steps"] - a["steps"]
+    flushes = (b["flush_by_reason"].get("admission", 0)
+               - a["flush_by_reason"].get("admission", 0))
+    assert steps > 10
+    assert flushes >= 0.9 * steps, (flushes, steps)
+    assert flushes >= 0.9 * (b["pipeline_flushes"] - a["pipeline_flushes"])
+
+
+def test_a_request_submitted_while_a_step_runs_waits_at_intake(
+        tiny_model, monkeypatch):
+    from scalable_hw_agnostic_inference_tpu.engine.loop import EngineLoop
+    from scalable_hw_agnostic_inference_tpu.obs.trace import (
+        Trace,
+        well_formed_problems,
+    )
+
+    eng = make_engine(tiny_model, True, monkeypatch)
+    loop = EngineLoop(eng).start()
+    sp = SamplingParams(temperature=0.0, max_new_tokens=4)
+    in_step, go_on = threading.Event(), threading.Event()
+
+    def hold_the_step(tok):
+        # runs on the loop thread, inside a step's commit
+        if not in_step.is_set():
+            in_step.set()
+            go_on.wait(30)
+
+    try:
+        first = loop.submit([1, 2, 3], sp, on_token=hold_the_step)
+        assert in_step.wait(120)
+        second = loop.submit([4, 5, 6, 7], sp)   # the loop thread is busy
+        time.sleep(0.02)
+        go_on.set()
+        fins = [first.result(120), second.result(120)]
+    finally:
+        go_on.set()
+        loop.stop()
+    t = fins[1].timing
+    assert t["t_enqueue"] < t["t_submit"] <= t["t_admit"] <= t["t_first"]
+    assert t["intake_s"] > 0
+    hist = eng.obs.histograms()
+    assert hist["intake_wait_seconds"]["count"] == 2
+    assert hist["intake_wait_seconds"]["sum"] >= t["intake_s"] * 0.99
+    # TTFT starts at the caller's submit; queue wait keeps its meaning
+    ttft = sum(f.timing["t_first"] - f.timing["t_enqueue"] for f in fins)
+    assert hist["ttft_seconds"]["sum"] == pytest.approx(ttft, rel=1e-6)
+    queue = sum(f.timing["t_admit"] - f.timing["t_submit"] for f in fins)
+    assert hist["queue_wait_seconds"]["sum"] == pytest.approx(queue,
+                                                              rel=1e-6)
+    assert (t["t_first"] - t["t_enqueue"]
+            > t["t_first"] - t["t_submit"])
+    tr = Trace("POST /generate")
+    tr.add_phase_spans(t)
+    tr.close()
+    d = tr.to_dict()
+    assert not well_formed_problems(d), well_formed_problems(d)
+    names = [s["name"] for s in d["spans"]]
+    assert names.index("intake") < names.index("queue") \
+        < names.index("prefill") < names.index("decode")
+    # a direct caller passes no stamp: both are one, and nothing is observed
+    rid = eng.add_request([1, 2], sp)
+    req = next(r for r in eng.waiting if r.req_id == rid)
+    assert req.t_enqueue == req.t_submit
+    assert eng.obs.histograms()["intake_wait_seconds"]["count"] == 2

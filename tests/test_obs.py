@@ -20,10 +20,12 @@ from scalable_hw_agnostic_inference_tpu.obs import (
 )
 from scalable_hw_agnostic_inference_tpu.obs import trace as obs_trace
 from scalable_hw_agnostic_inference_tpu.obs.trace import (
+    Trace,
     well_formed_problems,
 )
 
 from test_engine import make_engine, tiny_model  # noqa: F401 (fixture)
+from test_engine_async import SpanLog
 from test_serve_http import EchoService, make_cfg, make_client, wait_ready
 
 
@@ -191,9 +193,134 @@ def test_step_telemetry_ring_is_bounded():
     snap = t.snapshot()
     assert snap["steps"] == 9 and snap["waiting"] == 8.0
     t.count_preemption()
-    t.count_recompile("decode")
+    t.count_recompile()
     snap = t.snapshot()
     assert snap["preemptions"] == 1 and snap["recompiles"] == 1
+
+
+def test_phases_are_flat_and_tile_the_time(monkeypatch):
+    from scalable_hw_agnostic_inference_tpu.obs import steploop
+
+    log = SpanLog()
+    monkeypatch.setattr(steploop, "annotate", log)
+    t = StepTelemetry()
+    t0 = time.monotonic()
+    assert t.phase_enter("loop.intake") is None
+    time.sleep(0.01)
+    with t.phase("loop.idle"):      # interrupts intake, which resumes after
+        time.sleep(0.01)
+        mid = t.snapshot()["phase_s"]  # the open phase's seconds so far
+    time.sleep(0.01)
+    assert t.phase_enter(None) == "loop.intake"
+    elapsed = time.monotonic() - t0
+    secs = t.snapshot()["phase_s"]
+    assert log.names == ["loop.intake", "loop.idle", "loop.intake"]
+    assert log.open is None
+    assert mid["loop.idle"] > 0 and mid["loop.intake"] > 0
+    assert secs["loop.intake"] > secs["loop.idle"] > 0
+    assert all(v == 0 for k, v in secs.items()
+               if k not in ("loop.intake", "loop.idle"))
+    # the phases tile the time from the first enter to the close: no less
+    # than the three sleeps, no more than the clock reads around them
+    assert 0.03 <= sum(secs.values()) <= elapsed
+    # closed: nothing runs on, a later reading is the same
+    assert t.snapshot()["phase_s"] == secs
+
+
+def test_phases_count_seconds_with_tracing_off(monkeypatch):
+    """``SHAI_TRACE=0``: the phases still account for the time, and no
+    profiler annotation is made."""
+    def no_annotation(name, **meta):
+        raise AssertionError(f"annotation {name} with tracing off")
+
+    monkeypatch.setattr(obs_trace, "_annotation", no_annotation)
+    obs_trace.configure(False)
+    try:
+        t = StepTelemetry()
+        t.begin_step(0)
+        time.sleep(0.005)
+        with t.phase("engine.decode"):
+            time.sleep(0.005)
+        t.phase_enter(None)
+    finally:
+        obs_trace.configure(True)
+    secs = t.snapshot()["phase_s"]
+    assert secs["engine.admit"] > 0 and secs["engine.decode"] > 0
+
+
+def test_step_record_carries_its_phases_and_the_queue_at_entry(monkeypatch):
+    from scalable_hw_agnostic_inference_tpu.obs import steploop
+
+    log = SpanLog()
+    monkeypatch.setattr(steploop, "annotate", log)
+    t = StepTelemetry(total_blocks=10)
+    assert t.begin_step(n_waiting=3) is None      # opens engine.admit
+    t0 = t.phase_t0
+    with t.phase("engine.prefill"):
+        time.sleep(0.002)
+    t.phase_enter("engine.marshal")
+    with t.phase("engine.decode"):
+        time.sleep(0.002)
+    t.phase_enter("engine.commit")
+    t.phase_enter("engine.record")
+    t.record_step(kind="decode", duration_s=t.phase_t0 - t0, n_running=1,
+                  n_waiting=2, n_chunking=0, blocks_free=5)
+    t.phase_enter("loop.resolve")
+    t.phase_enter(None)
+    rec = t.recent_steps()[-1]
+    assert rec["waiting_peak"] == 3 and rec["waiting"] == 2
+    fields = ("admit_ms", "marshal_ms", "dispatch_ms", "commit_ms",
+              "fetch_ms", "apply_ms")
+    assert rec["dispatch_ms"] > rec["marshal_ms"] >= 0   # both dispatches
+    assert rec["record_ms"] > 0      # set when engine.record closed
+    # the phases up to the record tile the step's duration (rounding apart)
+    assert sum(rec[f] for f in fields) == pytest.approx(
+        rec["duration_s"] * 1e3, abs=0.01)
+    # engine phases carry the step's number, loop phases none
+    assert all(m == ({"step": 1} if n.startswith("engine.") else {})
+               for n, m in zip(log.names, log.meta))
+    assert "loop.resolve" in log.names
+
+
+def test_counters_by_reason_and_phase_ride_the_snapshot():
+    t = StepTelemetry()
+    t.count_flush("admission")
+    t.count_flush("admission")
+    t.count_flush("idle")
+    t.count_pad(10, 6, phase="prefill")
+    t.count_pad(8, 0, phase="decode")
+    t.count_pad(8, 8, phase="decode")
+    snap = t.snapshot()
+    assert snap["flush_by_reason"] == {"admission": 2, "idle": 1}
+    assert snap["pipeline_flushes"] == 3
+    assert snap["dispatches_by_phase"] == {"prefill": 1, "decode": 2}
+    assert snap["pad_by_phase"]["decode"] == {"real": 16, "pad": 8}
+    assert set(snap["phase_s"]) >= {"loop.idle", "engine.fetch",
+                                    "engine.record", "loop.resolve"}
+    assert "intake_wait_seconds" in t.histograms()
+    assert snap["intake_wait_count"] == 0
+
+
+def test_phase_spans_put_intake_before_queue():
+    tr = Trace("req")
+    now = time.monotonic()
+    tr.add_phase_spans({"t_enqueue": now - 0.5, "t_submit": now - 0.4,
+                        "t_admit": now - 0.3, "t_first": now - 0.2,
+                        "t_done": now - 0.1})
+    tr.close()
+    d = tr.to_dict()
+    assert not well_formed_problems(d)
+    spans = {s["name"]: s for s in d["spans"]}
+    names = [s["name"] for s in d["spans"]]
+    assert names.index("intake") < names.index("queue")
+    assert spans["intake"]["t_start"] + spans["intake"]["duration_s"] == \
+        pytest.approx(spans["queue"]["t_start"], abs=1e-4)
+    # a direct add_request stamps both at once: no intake span
+    tr2 = Trace("req")
+    tr2.add_phase_spans({"t_enqueue": now - 0.4, "t_submit": now - 0.4,
+                         "t_admit": now - 0.3, "t_first": now - 0.2,
+                         "t_done": now - 0.1})
+    assert "intake" not in [s.name for s in tr2.spans]
 
 
 def test_flight_recorder_ring_and_dump():
@@ -366,7 +493,41 @@ def spec_app():
     service.ecfg = dataclasses.replace(
         service.ecfg, speculative_model="[ngram]", num_speculative_tokens=3,
         max_num_seqs=2, max_prefill_batch=1)
+    # the load-and-warm time as the app's lane sees it, for the start-up
+    # phases to be held against
+    load, warmup = service.load, service.warmup
+
+    def timed_load():
+        service.t_load0 = time.monotonic()
+        load()
+
+    def timed_warmup():
+        warmup()
+        service.t_warm1 = time.monotonic()
+
+    service.load, service.warmup = timed_load, timed_warmup
     return cfg, service, create_app(cfg, service)
+
+
+@pytest.mark.asyncio
+async def test_startup_phases_are_on_stats_after_ready(spec_app):
+    cfg, service, app = spec_app
+    async with make_client(app) as c:
+        await wait_ready(c, timeout=600.0)
+        st = (await c.get("/stats")).json()
+    startup = st["startup"]
+    phases = {k: v for k, v in startup.items() if k != "total_s"}
+    assert set(phases) == {"weights_s", "engine_s", "warm_executables_s",
+                           "warmup_s", "other_s"}
+    assert all(v >= 0 for v in phases.values())
+    # every program of the closed set was compiled (or loaded) in there:
+    # it is the longest phase of a boot
+    assert phases["warm_executables_s"] == max(phases.values())
+    assert sum(phases.values()) == pytest.approx(startup["total_s"],
+                                                 abs=0.01)
+    load_and_warm = service.t_warm1 - service.t_load0
+    assert startup["total_s"] == pytest.approx(load_and_warm, rel=0.05)
+    assert startup == service.startup
 
 
 @pytest.mark.slow  # tier-1 budget: see scripts/check_tier1_budget.py
@@ -459,7 +620,11 @@ async def test_metrics_exposes_engine_histograms_and_gauges(spec_app):
                      "shai_engine_kv_utilization",
                      "shai_engine_preemptions_total",
                      "shai_engine_recompiles_total",
-                     "shai_spec_acceptance_rate"):
+                     "shai_spec_acceptance_rate",
+                     "shai_intake_wait_seconds_bucket",
+                     'shai_engine_phase_seconds_total{app="llm-obs",'
+                     'phase="engine.fetch"}',
+                     'phase="loop.idle"}'):
             assert name in r.text, f"{name} missing from /metrics"
         # histogram actually observed something
         assert 'shai_ttft_seconds_count{app="llm-obs"}' in r.text
@@ -467,6 +632,15 @@ async def test_metrics_exposes_engine_histograms_and_gauges(spec_app):
         st = (await c.get("/stats")).json()
         assert st["engine"]["steps"] > 0
         assert "kv_utilization" in st["engine"]
+        # where the loop thread's time went, and the flushes by reason: one
+        # place, the engine's snapshot
+        assert st["engine"]["phase_s"]["loop.idle"] > 0
+        assert st["engine"]["phase_s"]["engine.verify"] > 0
+        assert (sum(st["engine"]["flush_by_reason"].values())
+                == st["engine"]["pipeline_flushes"]
+                >= st["service"]["pipeline_flushes"])
+        assert not any(k.startswith("pipeline_flush_")
+                       for k in st["service"])
         assert "exports" in st["aot"]
 
 

@@ -49,6 +49,9 @@ from scalable_hw_agnostic_inference_tpu.engine.loop import (  # noqa: E402
 from scalable_hw_agnostic_inference_tpu.engine.types import (  # noqa: E402
     Finished,
 )
+from scalable_hw_agnostic_inference_tpu.obs.steploop import (  # noqa: E402
+    StepTelemetry,
+)
 from scalable_hw_agnostic_inference_tpu.kvtier.pool import (  # noqa: E402
     HostKVTier,
 )
@@ -587,6 +590,7 @@ class StubEngine:
         self.tier = tier
         self.steps_per_req = steps_per_req
         self.demote_every = demote_every
+        self.obs = StepTelemetry()   # the loop enters its phases here
         self.waiting = deque()
         self.running = {}
         self.finished_ids = []

@@ -128,7 +128,7 @@ def test_flight_trace_index_eviction_and_zero_capacity():
     # a zero-capacity ring records (counts) but never indexes
     z = FlightRecorder(max_requests=0, max_steps=1)
     z.record_request({"trace_id": "x", "spans": []})
-    assert z.n_recorded == 1 and z.traces_for("x") == []
+    assert z.dump()["recorded_total"] == 1 and z.traces_for("x") == []
     assert z._by_trace == {}
 
 
@@ -408,9 +408,9 @@ async def test_excluded_route_opens_hop_trace_only_with_traceparent():
         assert r.status_code == 200, r.text
         assert r.json()["traces"][0]["trace_id"] == tid
         # a MALFORMED traceparent on an excluded route stays untraced
-        before = app.state["flight"].n_recorded
+        before = app.state["flight"].dump()["recorded_total"]
         await c.get("/health", headers={"traceparent": "garbage"})
-        assert app.state["flight"].n_recorded == before
+        assert app.state["flight"].dump()["recorded_total"] == before
 
 
 # ---------------------------------------------------------------------------
