@@ -31,6 +31,7 @@ from ..obs.slo import SloEngine, SloTargets
 from ..obs.steploop import StepTelemetry
 from ..resilience import faults as _faults
 from ..resilience import qos as _qos
+from ..ops.pallas.paged_attention import live_tile_tokens, tile_tokens
 from ..ops.sampling import sample_logits
 from .cache import PagedKVCache
 from .config import EngineConfig
@@ -118,9 +119,10 @@ class LLMEngine:
         self._kv_quant = kvq == "int8"
         # ragged paged attention (SHAI_RAGGED_ATTENTION, default off):
         # decode/verify attend mixed context lengths in ONE full-window
-        # dispatch (per-row compute skip), so the token_generation_buckets
-        # ladder collapses to a single context entry and chunked prefill's
-        # continuation ladder collapses to one dynamic-start executable
+        # dispatch (a row walks its live tiles), so the
+        # token_generation_buckets ladder collapses to a single context
+        # entry and chunked prefill's continuation ladder collapses to one
+        # dynamic-start executable
         # per chunk bucket. Text engines only: the ragged continuation
         # does not carry the mllama cross tail.
         self._ragged = bool(_env_flag("SHAI_RAGGED_ATTENTION", False)
@@ -166,6 +168,13 @@ class LLMEngine:
             if self._kv_quant:
                 kv_sharding["ks"] = self.shardings.kv_scale
                 kv_sharding["vs"] = self.shardings.kv_scale
+        # tokens one tile of the paged kernel covers at this engine's
+        # per-shard pool shape: what the decode pad accounting counts in
+        self._attn_tile = tile_tokens(
+            ecfg.block_size,
+            model_cfg.n_kv_heads // (mesh.shape["tp"] if self.shardings
+                                     is not None else 1),
+            model_cfg.head_dim, np.int8 if self._kv_quant else kv_dtype)
         self.cache = PagedKVCache(
             n_pool_layers, model_cfg.n_kv_heads, model_cfg.head_dim,
             ecfg.total_blocks, ecfg.block_size, ecfg.blocks_per_seq,
@@ -2290,32 +2299,22 @@ class LLMEngine:
                            rows_per_seq: int = 1) -> None:
         """Pad-waste accounting for ONE decode/verify dispatch: ``real``
         is the context tokens the rows actually hold, ``padded`` the token
-        slots the executable walks beyond them — batch pad rows plus the
-        context window past each row's live tokens. Bucketed dispatch
-        walks the dispatched context bucket for EVERY row; the ragged
-        kernel walks each row's own blocks (partial-tail slots only).
+        slots the paged kernel walks beyond them. The kernel walks each
+        row's live tiles and nothing else, whatever the table's width
+        (bucketed and ragged dispatch alike: one body), so the pad is tile
+        rounding plus one tile of the null block per batch pad row; the
+        tile size is the kernel module's own (``tile_tokens``).
         ``rows_per_seq``: the verify executable flattens ``k + 1`` query
-        rows per sequence, each walking the window — both sides scale.
-        Exported as ``shai_engine_pad_tokens_total``/``pad_fraction`` so
-        the ragged win is measurable on a live pod — and a ladder growing
-        back is visible. Pure host arithmetic (hot-path safe)."""
-        bs = self.ecfg.block_size
+        rows per sequence, each walking the row's tiles — both sides scale.
+        Exported as ``shai_engine_pad_tokens_total``/``pad_fraction``.
+        Pure host arithmetic (hot-path safe)."""
+        tile = self._attn_tile
         real = 0
-        walked = 0
-        if self._ragged:
-            for s in running:
-                n = self.cache.seq(s.req.req_id).n_tokens
-                real += n
-                walked += self.cache._blocks_needed(n) * bs
-            walked += (Bb - len(running)) * bs  # pad rows walk one block
-        else:
-            m_blocks = 1
-            for s in running:
-                n = self.cache.seq(s.req.req_id).n_tokens
-                real += n
-                m_blocks = max(m_blocks, self.cache._blocks_needed(n))
-            m = next(b for b in self._ctx_buckets if b >= m_blocks)
-            walked = Bb * m * bs
+        walked = (Bb - len(running)) * tile
+        for s in running:
+            n = self.cache.seq(s.req.req_id).n_tokens
+            real += n
+            walked += live_tile_tokens(n, tile)
         self.obs.count_pad(real * rows_per_seq,
                            (walked - real) * rows_per_seq,
                            phase="verify" if rows_per_seq > 1 else "decode")

@@ -659,10 +659,10 @@ def _make_token_forward(cfg: LlamaConfig, block_size: int, m_ctx: int,
     def paged_attn(qf, kpool, vpool, tablesf, lengthsf, ks=None, vs=None):
         """qf [rows, H, D] over the pool, through the shared
         ``_pool_kernel_call`` dispatch seam (head-split shard_map under
-        TP). ``ragged`` swaps in the ragged kernel — same layout, per-row
-        compute skip instead of a caller-side context bucket; ``ks``/``vs``
-        are an int8 pool's per-(block, head) scales, dequantized in-kernel
-        by both."""
+        TP). ``ragged`` swaps in the ragged entry point — same layout and
+        the same kernel body (a row walks its live tiles), handed the full
+        table instead of a caller-side context bucket; ``ks``/``vs`` are
+        an int8 pool's per-(block, head) scales, dequantized in-kernel."""
         if ragged:
             from ..ops.pallas.ragged_paged_attention import (
                 ragged_paged_attention as kernel,
@@ -818,8 +818,8 @@ def make_decode(cfg: LlamaConfig, block_size: int, blocks_per_seq: int,
     ``ragged``: one dispatch for mixed context lengths
     (``SHAI_RAGGED_ATTENTION``) — the attention window is the FULL
     ``blocks_per_seq`` table, per-row cost following each row's own
-    length (compute skip + fetch elision in
-    ``ops.pallas.ragged_paged_attention``), so the engine compiles ONE
+    length (a row walks its live tiles only:
+    ``ops.pallas.paged_attention``), so the engine compiles ONE
     context entry instead of the ``token_generation_buckets`` ladder and
     never dispatches on the longest sequence's bucket.
 

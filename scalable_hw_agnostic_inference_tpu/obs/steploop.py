@@ -212,9 +212,9 @@ class StepTelemetry:
         self._flush_reasons: Dict[str, int] = {}
         # pad-waste accounting: per dispatch, how many token slots the
         # executable walked for REAL context vs shape padding (batch pad
-        # rows + bucket window beyond each row's live tokens + prefill
-        # bucket tails). The ragged kernel's win — and any ladder
-        # regression — shows up as pad_fraction on a live pod.
+        # rows + the paged kernel's tiles beyond each row's live tokens +
+        # prefill bucket tails). A kernel or a ladder that walks more
+        # than the rows hold shows up as pad_fraction on a live pod.
         self.pad_tokens = 0
         self.real_tokens = 0
         # per-phase split of the same accounting (prefill admission /

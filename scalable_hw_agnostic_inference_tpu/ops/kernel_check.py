@@ -19,7 +19,7 @@ import numpy as np
 
 from .attention import dot_product_attention, ragged_gather_attention
 from .pallas.flash_attention import flash_attention
-from .pallas.paged_attention import paged_decode_attention
+from .pallas.paged_attention import paged_decode_attention, tile_tokens
 from .pallas.ragged_paged_attention import ragged_paged_attention
 from .quant import quantize_kv_blocks
 
@@ -81,15 +81,28 @@ def _flash_case(H: int, Hkv: int, D: int, T: int, S: int,
 
 
 def _pool_case(kind: str, H: int, Hkv: int, D: int, block_size: int,
-               blocks_per_seq: int, rows: int, int8_kv: bool) -> KernelCase:
+               blocks_per_seq: int, rows: int, int8_kv: bool,
+               tile_edges: bool = False) -> KernelCase:
     """A paged-pool kernel (``kind``: ``paged`` bucketed decode, ``ragged``)
     over ``rows`` single-query rows with mixed context lengths and shuffled
-    block tables."""
+    block tables.
+
+    ``tile_edges`` is what a kernel that walks several pool blocks a tile
+    can get wrong: lengths on both sides of a tile's edge, and every pool
+    block no row's live tokens sit in filled with NaN (an int8 pool: NaN
+    scales), so a page that is fetched though dead, or multiplied though
+    never fetched, shows in the output."""
     kern = {"paged": paged_decode_attention,
             "ragged": ragged_paged_attention}[kind]
     L = blocks_per_seq * block_size
-    # one token, a partial second block, mid-window, the full window
-    lens = [1, block_size + 3, L // 2 + 5, L]
+    if tile_edges:
+        t = tile_tokens(block_size, Hkv, D,
+                        jnp.int8 if int8_kv else jnp.bfloat16)
+        lens = [t - 1, t, t + 1, 2 * t, 2 * t + 1, 1, block_size + 3, L]
+    else:
+        # one token, a partial second block, mid-window, the full window
+        lens = [1, block_size + 3, L // 2 + 5, L]
+    lens = [min(max(n, 1), L) for n in lens]
     lens = (lens * -(-rows // len(lens)))[:rows]
     n_blocks = rows * blocks_per_seq + 1          # + the reserved block 0
 
@@ -103,19 +116,37 @@ def _pool_case(kind: str, H: int, Hkv: int, D: int, block_size: int,
             rows, blocks_per_seq).astype(jnp.int32)
         q = jax.random.normal(kq, (rows, H, D), jnp.bfloat16)
         n = jnp.asarray(lens, jnp.int32)
+        poison = lambda x: x                          # noqa: E731
+        if tile_edges:
+            # NaN in every block that holds no live token of any row (the
+            # null block stays clean)
+            held = (jnp.arange(blocks_per_seq)[None, :] * block_size
+                    < n[:, None])
+            owned = jnp.zeros((n_blocks,), bool).at[0].set(True).at[
+                jnp.where(held, tables, 0).ravel()].set(True)
+            poison = lambda x: jnp.where(             # noqa: E731
+                owned.reshape((-1,) + (1,) * (x.ndim - 1)), x, jnp.nan)
         if int8_kv:
             kq8, ks = quantize_kv_blocks(k)
             vq8, vs = quantize_kv_blocks(v)
-            return q, kq8, vq8, tables, n, ks, vs
-        return q, k.astype(jnp.bfloat16), v.astype(jnp.bfloat16), tables, n
+            return q, kq8, vq8, tables, n, poison(ks), poison(vs)
+        return (q, poison(k).astype(jnp.bfloat16),
+                poison(v).astype(jnp.bfloat16), tables, n)
 
     def oracle(q, k, v, tables, n, ks=None, vs=None):
+        # the gather reference multiplies masked slots by 0: it reads the
+        # poisoned pool with the poison taken out
+        if ks is None:
+            k, v = jnp.nan_to_num(k), jnp.nan_to_num(v)
+        else:
+            ks, vs = jnp.nan_to_num(ks), jnp.nan_to_num(vs)
         return ragged_gather_attention(
             q[:, None], k, v, tables, (n - 1)[:, None], ks, vs)[:, 0]
 
     return KernelCase(
         name=(f"{kind}-H{H}x{Hkv}-bs{block_size}-M{blocks_per_seq}-b{rows}"
-              f"-{'int8kv' if int8_kv else 'bf16'}"),
+              f"-{'int8kv' if int8_kv else 'bf16'}"
+              f"{'-edges' if tile_edges else ''}"),
         make_inputs=make,
         kernel=lambda *a, interpret: kern(*a, interpret=interpret),
         oracle=oracle, tol=TOL_INT8_KV if int8_kv else TOL_BF16)
@@ -129,7 +160,9 @@ def engine_cases(n_heads: int, n_kv_heads: int, head_dim: int, *,
     """The kernel calls an engine of this geometry dispatches, per TP shard:
     flash at each prefill bucket (widest prefill batch) and at the first
     continuation start, then paged decode and ragged over the full block
-    table, bf16 and int8-KV."""
+    table, bf16 and int8-KV: ``max_num_seqs`` rows of mixed lengths, then
+    8 rows (the benchmark cells' batch) at the tile's edges over a
+    NaN-poisoned pool."""
     H, Hkv = n_heads // tp, n_kv_heads // tp
     M = max_model_len // block_size
     top = max(buckets)
@@ -141,4 +174,6 @@ def engine_cases(n_heads: int, n_kv_heads: int, head_dim: int, *,
         for kind in ("paged", "ragged"):
             cases.append(_pool_case(kind, H, Hkv, head_dim, block_size, M,
                                     max_num_seqs, int8_kv))
+            cases.append(_pool_case(kind, H, Hkv, head_dim, block_size, M,
+                                    8, int8_kv, tile_edges=True))
     return cases

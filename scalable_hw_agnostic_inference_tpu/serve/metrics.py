@@ -76,8 +76,8 @@ _ENGINE_GAUGES = {
                              "Speculative draft acceptance rate"),
     "pad_fraction": ("shai_engine_pad_fraction",
                      "Fraction of dispatched token slots that were shape "
-                     "padding (bucket windows past live tokens + batch pad "
-                     "rows) — the waste the ragged kernel removes"),
+                     "padding (prefill bucket tails, the paged kernel's "
+                     "tile rounding past live tokens, batch pad rows)"),
 }
 _ENGINE_COUNTERS = {
     "steps": ("shai_engine_steps", "Engine steps executed"),

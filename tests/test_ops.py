@@ -375,6 +375,65 @@ class TestPagedDecodeAttention:
                                    rtol=1e-6, atol=1e-6)
 
 
+def _poisoned_pool(lengths, M, bs, Hkv, D, tile, seed=0):
+    """Tables of ``M`` distinct blocks a row; a block is clean only if it
+    holds a live token. Dead blocks inside a live tile and whole dead
+    tiles are NaN, K and V."""
+    rng = np.random.default_rng(seed)
+    B = len(lengths)
+    N = B * M + 1
+    kp = rng.standard_normal((N, bs, Hkv, D)).astype(np.float32)
+    vp = rng.standard_normal((N, bs, Hkv, D)).astype(np.float32)
+    tables = (1 + rng.permutation(N - 1)).reshape(B, M).astype(np.int32)
+    clean_k, clean_v = kp.copy(), vp.copy()
+    for b, n in enumerate(lengths):
+        if n == 0:
+            tables[b] = 0                 # an inactive slot: the null block
+            continue
+        dead = tables[b, -(-n // bs):]
+        kp[dead] = np.nan
+        vp[dead] = np.nan
+    return kp, vp, clean_k, clean_v, tables
+
+
+@pytest.mark.parametrize("M,lengths", [
+    # M = 21 blocks of 16: one 256-token tile and a part of a second
+    (21, [255, 256, 257, 336, 1, 17]),
+    # a length-0 row (inactive slot) between live ones; M = 40: 2.5 tiles
+    (40, [300, 0, 640, 513]),
+])
+def test_paged_kernel_walks_only_live_tiles(M, lengths):
+    """Every pool block that holds no live token of its row is NaN, K and
+    V: dead blocks inside a live tile, whole dead tiles, all of a table a
+    caller did not truncate. A kernel that fetches a dead block, or
+    multiplies a page it never fetched, returns NaN."""
+    B, H, Hkv, D, bs = len(lengths), 4, 2, 64, 16
+    from scalable_hw_agnostic_inference_tpu.ops.pallas.paged_attention import (
+        paged_decode_attention,
+        tile_tokens,
+    )
+
+    tile = tile_tokens(bs, Hkv, D, jnp.float32)
+    assert tile == 256 and (M * bs) % tile      # M is no multiple of a tile
+    kp, vp, ck, cv, tables = _poisoned_pool(lengths, M, bs, Hkv, D, tile)
+    q = np.random.default_rng(1).standard_normal((B, H, D)).astype(np.float32)
+    n = np.asarray(lengths, np.int32)
+    out = np.asarray(paged_decode_attention(
+        jnp.asarray(q), jnp.asarray(kp), jnp.asarray(vp),
+        jnp.asarray(tables), jnp.asarray(n), interpret=True))
+    assert np.isfinite(out).all()
+    live = n > 0
+    ref = TestPagedDecodeAttention()._dense_ref(
+        q[live], ck, cv, tables[live], n[live])
+    np.testing.assert_allclose(out[live], ref, rtol=2e-4, atol=2e-4)
+    # a table truncated to the live tiles' blocks gives the same bits
+    m_live = -(-max(lengths) // bs)
+    cut = np.asarray(paged_decode_attention(
+        jnp.asarray(q), jnp.asarray(kp), jnp.asarray(vp),
+        jnp.asarray(tables[:, :m_live]), jnp.asarray(n), interpret=True))
+    np.testing.assert_array_equal(cut, out)
+
+
 def test_effective_platform_respects_default_device(monkeypatch):
     """The r5 on-chip SD bench crash: ``host_init`` places whole-model flax
     inits on the CPU device while the global backend is the TPU — dispatch
