@@ -246,10 +246,26 @@ def causal_lm_budget(cfg, ecfg, *, hbm_gib_per_chip: float = HBM_GIB["v5e"],
     # projections have the same shapes as a self layer's (q/k/v/o + mlp;
     # the per-layer gate scalars are noise), so the byte total matches
     plain = dataclasses.replace(cfg, cross_attention_layers=())
-    model = LlamaForCausalLM(plain, dtype=jnp.float32)
-    shapes = jax.eval_shape(model.init, jax.random.PRNGKey(0),
-                            jnp.zeros((1, 8), jnp.int32))
+    if cfg.engine_only:
+        # experts, the output gate, head norms, a head_dim that is not
+        # dim // n_heads: the flax module builds none of them, the
+        # geometry tree (the engine's own leaves) has them all. Stacked
+        # expert leaves and float32 routers are no nn.Dense kernels, so
+        # they are priced at their own width (no int8, no tp split: the
+        # boot refuses both with experts).
+        from ..models.llama import geometry_params
+
+        shapes = jax.eval_shape(lambda: geometry_params(plain))
+    else:
+        model = LlamaForCausalLM(plain, dtype=jnp.float32)
+        shapes = jax.eval_shape(model.init, jax.random.PRNGKey(0),
+                                jnp.zeros((1, 8), jnp.int32))
     bpe = _leaf_bytes_fn(ecfg.dtype, ecfg.quantization, shapes)
+    if cfg.n_experts:
+        dense_bpe = bpe
+        bpe = lambda name, leaf: (      # noqa: E731
+            4.0 if "/moe/router/" in name or name.endswith("/moe/bias")
+            else dense_bpe(name, leaf))
     p_bytes = params_bytes_per_chip(shapes, tp_rules("tp"), {"tp": tp}, bpe)
 
     # paged KV pool (engine.runner allocation): self-attn layers only —
@@ -282,8 +298,12 @@ def causal_lm_budget(cfg, ecfg, *, hbm_gib_per_chip: float = HBM_GIB["v5e"],
     # attention keeps scores out of HBM. 1.5x margin for XLA temporaries.
     B = max(int(getattr(ecfg, "max_prefill_batch", 1)), 1)
     T = max(ecfg.context_encoding_buckets)
-    width_chip = (2 * cfg.dim + 2 * cfg.mlp_dim // tp
-                  + 4 * cfg.n_heads * cfg.head_dim // tp)
+    # a routed FFN's live set is the k assignments of each token at the
+    # experts' width; the output gate is one more head-wide projection
+    ffn = max(cfg.mlp_dim, cfg.n_experts_per_tok * cfg.moe_mlp_dim)
+    width_chip = (2 * cfg.dim + 2 * ffn // tp
+                  + (5 if cfg.attn_gate else 4) * cfg.n_heads
+                  * cfg.head_dim // tp)
     act_bytes = 1.5 * B * T * width_chip * 2.0
     act_bytes += B * cfg.vocab_size * 4.0     # sampling logits row (fp32)
 

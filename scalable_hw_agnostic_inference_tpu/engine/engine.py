@@ -117,6 +117,29 @@ class LLMEngine:
                         " — KV quantization stays off", kvq)
             kvq = ""
         self._kv_quant = kvq == "int8"
+        # what this PR's layer kinds do not run with yet is refused here,
+        # by name, before anything computes a wrong answer quietly
+        self._window_pool_layers = model_cfg.window_layers
+        self._moe_layers = model_cfg.n_moe_layers
+        refused = []
+        if self._moe_layers and ecfg.tensor_parallel_size > 1:
+            refused.append("tensor_parallel_size > 1 with expert layers "
+                           "(no expert axis on the engine mesh yet)")
+        if self._moe_layers and ecfg.quantization == "int8":
+            refused.append("quantization: int8 with expert layers (no "
+                           "quantised expert product yet)")
+        if self._window_pool_layers and self._kv_quant:
+            refused.append("SHAI_KV_QUANT=int8 with window layers")
+        if (self._window_pool_layers or self._moe_layers) and _env_flag(
+                "SHAI_FUSED_STEP", False):
+            refused.append("SHAI_FUSED_STEP with window or expert layers")
+        if self._window_pool_layers and _env_flag("SHAI_KVTIER", False):
+            refused.append("SHAI_KVTIER (the host KV tier) with window "
+                           "layers")
+        if refused:
+            raise ValueError(
+                "this model's layers are not served with: "
+                + "; ".join(refused))
         # ragged paged attention (SHAI_RAGGED_ATTENTION, default off):
         # decode/verify attend mixed context lengths in ONE full-window
         # dispatch (a row walks its live tiles), so the
@@ -1031,7 +1054,7 @@ class LLMEngine:
         with self.obs.phase("engine.decode"):
             t_d = self.obs.phase_t0
             (self.cache.kv, nxt, pos_next, top_ids, top_lp,
-             tok_lp) = decode(*args)
+             tok_lp, *fetch) = decode(*args)
         if cold and gap_ok and self._t_fetch \
                 and self._last_decode_step == self._step_count - 1:
             # flush/cold step: the dispatch had to wait for the readback —
@@ -1043,7 +1066,7 @@ class LLMEngine:
             nxt=nxt, pos_next=pos_next, top_ids=top_ids, top_lp=top_lp,
             tok_lp=tok_lp,
             want_lp=any(s.req.params.logprobs for s in running),
-            t_dispatch=t_d)
+            t_dispatch=t_d, fetch=fetch[0] if fetch else None)
 
     def _retire_pipe(self, pipe: InflightStep) -> float:
         """Host half of a dispatched step: fetch the sampled tokens (the
@@ -1052,16 +1075,21 @@ class LLMEngine:
         cancelled since the dispatch are skipped — their extra token is
         exactly the discarded lookahead. Returns the fetch stamp."""
         outer = self.obs.phase_enter("engine.fetch")
+        # a routed model's step hands back its routing counts behind the
+        # sampled tokens, in the one array this fetch reads anyway
+        src = pipe.nxt if pipe.fetch is None else pipe.fetch
         if pipe.want_lp:
             # shai-lint: allow(host-sync) THE one blocking fetch of the
             # pipeline: retiring step N must read its sampled tokens (and
             # logprobs) back — everything else overlaps step N+1
             nxt, top_ids, top_lp, tok_lp = jax.device_get(
-                (pipe.nxt, pipe.top_ids, pipe.top_lp, pipe.tok_lp))
+                (src, pipe.top_ids, pipe.top_lp, pipe.tok_lp))
         else:
             # shai-lint: allow(host-sync) same fetch, logprob-free shape
-            nxt = np.asarray(pipe.nxt)
+            nxt = np.asarray(src)
             top_ids = top_lp = tok_lp = None
+        if pipe.fetch is not None:
+            nxt = self._note_routing(nxt, len(pipe.running))
         self.obs.phase_enter("engine.apply")
         t_f = self._t_fetch = self.obs.phase_t0
         self._apply_sampled(pipe.running, nxt, top_ids, top_lp, tok_lp)
@@ -1122,6 +1150,7 @@ class LLMEngine:
             kind=self._step_kind, duration_s=duration_s,
             n_running=self.n_running, n_waiting=self.n_waiting,
             n_chunking=self.n_chunking,
+            slots_free=sum(s is None for s in self.slots),
             blocks_free=self.cache.allocator.n_free,
             blocks_evictable=(self.cache.n_evictable
                               if self.cache.prefix_caching else 0),
@@ -2295,6 +2324,19 @@ class LLMEngine:
                     if self.slots[s.slot] is not s:
                         break  # s itself was preempted
 
+    def _note_routing(self, fetched: np.ndarray, n_rows: int) -> np.ndarray:
+        """Split a routed step's one fetched array: the sampled tokens
+        (returned), and behind them what routing did — distinct experts
+        touched and the largest load on one expert, each summed over the
+        step's expert layers — which go to the ``moe`` counters with the
+        step's real rows."""
+        touched, load_max = int(fetched[-2]), int(fetched[-1])
+        self.obs.count_moe(
+            self._moe_layers,
+            n_rows * self.cfg.n_experts_per_tok * self._moe_layers,
+            touched, load_max)
+        return fetched[:-2]
+
     def _note_dispatch_pad(self, running, Bb: int,
                            rows_per_seq: int = 1) -> None:
         """Pad-waste accounting for ONE decode/verify dispatch: ``real``
@@ -2318,6 +2360,30 @@ class LLMEngine:
         self.obs.count_pad(real * rows_per_seq,
                            (walked - real) * rows_per_seq,
                            phase="verify" if rows_per_seq > 1 else "decode")
+        if self._window_pool_layers:
+            self._note_window(running, rows_per_seq)
+
+    def _note_window(self, running, rows_per_seq: int) -> None:
+        """The same dispatch as its window layers saw it (``window``
+        counters): a window layer walks the tiles from its window's lower
+        edge on — the kernel's own rule, ``first_live_tile`` — and sees
+        the last ``sliding_window`` keys; what lies below stays in the pool
+        (ONE block table for all layers) and is counted as dead."""
+        tile, window = self._attn_tile, self.cfg.sliding_window
+        n_win = len(self._window_pool_layers)
+        full = walked = visible = dead = held = 0
+        for s in running:
+            n = self.cache.seq(s.req.req_id).n_tokens
+            full += live_tile_tokens(n, tile)
+            walked += live_tile_tokens(n, tile, window)
+            visible += min(n, window)
+            dead += max(n - window, 0)
+            held += n
+        self.obs.count_window(
+            walked * n_win * rows_per_seq,
+            (full - walked) * n_win * rows_per_seq,
+            visible * n_win * rows_per_seq, dead * n_win,
+            held * self.cache.n_layers)
 
     def _running_slots(self) -> List["_Running"]:
         return [s for s in self.slots
@@ -2552,7 +2618,8 @@ class LLMEngine:
                      jnp.asarray(a["slot_idx"]), jnp.asarray(a["cross_len"])]
         with self.obs.phase("engine.decode"):
             t_d = self.obs.phase_t0
-            self.cache.kv, nxt, top_ids_d, top_lp_d, tok_lp_d = decode(*args)
+            (self.cache.kv, nxt, top_ids_d, top_lp_d, tok_lp_d,
+             *fetch) = decode(*args)
         if self._t_fetch and self.n_executables == n_exec \
                 and self._last_decode_step == self._step_count - 1:
             # lock-step inter-step gap: the host work (marshal, bookkeeping)
@@ -2561,7 +2628,9 @@ class LLMEngine:
             self.obs.step_gap.observe(max(0.0, t_d - self._t_fetch))
         self._last_decode_step = self._step_count
         self.obs.phase_enter("engine.fetch")
-        nxt = np.asarray(nxt)
+        nxt = np.asarray(fetch[0] if fetch else nxt)
+        if fetch:
+            nxt = self._note_routing(nxt, len(running))
         if any(s.req.params.logprobs for s in running):
             top_ids_d = np.asarray(top_ids_d)
             top_lp_d = np.asarray(top_lp_d)

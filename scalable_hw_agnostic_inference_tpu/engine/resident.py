@@ -66,6 +66,9 @@ class InflightStep:
     tok_lp: Any
     want_lp: bool
     t_dispatch: float                 # monotonic enqueue stamp (gap metric)
+    # a routed model's step: [Bb + 2] int32, the sampled tokens with the
+    # routing counts behind them — what the host reads INSTEAD of ``nxt``
+    fetch: Optional[Any] = None
 
     def device_bytes(self) -> int:
         """Bytes the un-retired step's outputs pin on device (HBM ledger)."""

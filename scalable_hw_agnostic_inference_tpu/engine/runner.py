@@ -16,6 +16,7 @@ Decode is ONE executable for the whole running batch: [B] tokens in,
 
 from __future__ import annotations
 
+import dataclasses
 import functools
 from typing import Any, Dict, List, Optional, Tuple
 
@@ -25,6 +26,7 @@ from jax.sharding import NamedSharding, PartitionSpec as P
 
 from ..models.llama import LlamaConfig
 from ..ops.attention import dot_product_attention
+from ..ops.moe import expert_layer, gated_mlp
 from ..ops.quant import quant_matmul
 from ..ops.rope import apply_rope
 from ..ops.sampling import (
@@ -97,28 +99,129 @@ def _proj(x: jax.Array, p: Dict[str, jax.Array]) -> jax.Array:
     return quant_matmul(x, p)
 
 
-def _qkv(lp: Dict, x: jax.Array, positions: jax.Array, cfg: LlamaConfig):
-    B, T, _ = x.shape
-    Dh = cfg.head_dim
-    q = _proj(x, lp["attn"]["q"]).reshape(B, T, cfg.n_heads, Dh)
-    k = _proj(x, lp["attn"]["k"]).reshape(B, T, cfg.n_kv_heads, Dh)
-    v = _proj(x, lp["attn"]["v"]).reshape(B, T, cfg.n_kv_heads, Dh)
-    q = apply_rope(q, positions, cfg.rope_theta, cfg.rope_scaling)
-    k = apply_rope(k, positions, cfg.rope_theta, cfg.rope_scaling)
-    return q, k, v
-
-
 def _mlp(lp: Dict, x: jax.Array) -> jax.Array:
-    gate = _proj(x, lp["mlp"]["gate"])
-    up = _proj(x, lp["mlp"]["up"])
-    return _proj(jax.nn.silu(gate) * up, lp["mlp"]["down"])
+    return gated_mlp(lp["mlp"], x)
 
 
 def _head_rmsnorm(x: jax.Array, scale: jax.Array, eps: float) -> jax.Array:
-    """RMSNorm over the head dim of ``[B, T, H, Dh]`` (mllama q/k norms)."""
+    """RMSNorm over the head dim of ``[B, T, H, Dh]`` (q/k head norms)."""
     x32 = x.astype(jnp.float32)
     n = x32 * jax.lax.rsqrt(jnp.mean(x32 * x32, -1, keepdims=True) + eps)
     return (n * scale).astype(x.dtype)
+
+
+def _embed(p: Dict, ids: jax.Array, cfg: LlamaConfig) -> jax.Array:
+    x = p["embed"]["embedding"][ids]
+    if cfg.embed_scale:
+        x = x.astype(jnp.float32) * (cfg.dim ** 0.5)
+    return x.astype(jnp.bfloat16)
+
+
+@dataclasses.dataclass(frozen=True)
+class LayerKind:
+    """What one layer is, read off the model config (never a model's
+    name): gated cross-attention over vision states or self-attention;
+    the keys a query sees behind it (0 = all); rotary embedding or none;
+    a routed FFN or the dense MLP."""
+    cross: bool = False
+    window: int = 0
+    rope: bool = True
+    moe: bool = False
+
+
+def layer_kinds(cfg: LlamaConfig) -> List[LayerKind]:
+    cross = set(cfg.cross_attention_layers)
+    return [LayerKind(cross=li in cross, window=cfg.window_of(li),
+                      rope=cfg.rope_of(li), moe=cfg.moe_of(li))
+            for li in range(cfg.n_layers)]
+
+
+def _layer(lp: Dict, kind: LayerKind, xs, positions, attend,
+           cfg: LlamaConfig, *, cross=None, active=None, shardings=None):
+    """THE decoder layer: every program of this module calls it, with its
+    own attention closure. Mistral, mllama and the routed/windowed models
+    are its cases, chosen by ``kind`` and the config's flags.
+
+    ``xs``: a tuple of token streams ``[B, T, dim]`` (one, but for the
+    fused step's decode rows and chunk window, which share each layer's
+    attention call), ``positions`` their ``[B, T]`` cache positions.
+    ``attend(qs, ks, vs, window) -> os``: the program's attention — where
+    this layer's new keys and values go in the pool, and what each query
+    sees; tuples in, a tuple of ``[B, T, H, Dh]`` out. ``cross``:
+    ``(k, v, has_image, cross_len)`` of a cross layer, which attends those
+    and touches no pool. ``active``: per stream, the rows that hold a real
+    token (bool, ``[B, T]``); padded rows route to no expert.
+
+    Returns ``(xs, stats)``: ``stats`` the routed FFN's int32 ``[2]``
+    (experts touched, largest load), ``None`` for a dense layer."""
+    if kind.cross:
+        ck, cv, has_image, cross_len = cross
+        return tuple(_cross_layer(lp, x, ck, cv, has_image, cfg,
+                                  cross_len=cross_len, shardings=shardings)
+                     for x in xs), None
+    at, Dh = lp["attn"], cfg.head_dim
+    qs, ks, vs, gates = [], [], [], []
+    for x, pos in zip(xs, positions):
+        B, T, _ = x.shape
+        h = _rmsnorm(x, lp["attn_norm"]["scale"], cfg.rms_eps)
+        q = _proj(h, at["q"]).reshape(B, T, cfg.n_heads, Dh)
+        k = _proj(h, at["k"]).reshape(B, T, cfg.n_kv_heads, Dh)
+        v = _proj(h, at["v"]).reshape(B, T, cfg.n_kv_heads, Dh)
+        if cfg.qk_norm:
+            q = _head_rmsnorm(q, at["q_norm"]["scale"], cfg.rms_eps)
+            k = _head_rmsnorm(k, at["k_norm"]["scale"], cfg.rms_eps)
+        if kind.rope:
+            q = apply_rope(q, pos, cfg.rope_theta, cfg.rope_scaling)
+            k = apply_rope(k, pos, cfg.rope_theta, cfg.rope_scaling)
+        qs.append(q), ks.append(k), vs.append(v)
+        gates.append(_proj(h, at["gate"]) if cfg.attn_gate else None)
+    os = attend(tuple(qs), tuple(ks), tuple(vs), kind.window)
+    out, stats = [], None
+    for i, (x, o, g) in enumerate(zip(xs, os, gates)):
+        B, T, _ = x.shape
+        o = o.reshape(B, T, -1)
+        if g is not None:
+            o = o * jax.nn.sigmoid(g)
+        h = _proj(o, at["o"])
+        if cfg.sandwich_norms:
+            h = _rmsnorm(h, lp["post_attn_norm"]["scale"], cfg.rms_eps)
+        x = x + h
+        m = _rmsnorm(x, lp["mlp_norm"]["scale"], cfg.rms_eps)
+        if kind.moe:
+            f, st = expert_layer(
+                lp["moe"], m, cfg,
+                active=None if active is None else active[i])
+            stats = st if stats is None else stats + st
+        else:
+            f = _mlp(lp, m)
+        if cfg.sandwich_norms:
+            f = _rmsnorm(f, lp["post_mlp_norm"]["scale"], cfg.rms_eps)
+        out.append(x + f)
+    return tuple(out), stats
+
+
+def _run_layers(p: Dict, cfg: LlamaConfig, xs, positions, attend, *,
+                cross=None, active=None, shardings=None):
+    """Walk the stack through :func:`_layer`. ``attend(pi, qs, ks, vs,
+    window)`` gets the layer's POOL index first (cross layers own no pool
+    entry); ``cross(ci) -> (k, v, has_image, cross_len)`` serves the
+    ``ci``-th cross layer. Returns ``(xs, stats)``, the routed layers'
+    stats summed (``None`` with no routed layer)."""
+    ci = pi = 0
+    stats = None
+    for li, kind in enumerate(layer_kinds(cfg)):
+        lp = p[f"layer_{li}"]
+        if kind.cross:
+            xs, _ = _layer(lp, kind, xs, positions, None, cfg,
+                           cross=cross(ci), shardings=shardings)
+            ci += 1
+            continue
+        xs, st = _layer(lp, kind, xs, positions,
+                        functools.partial(attend, pi), cfg, active=active)
+        pi += 1
+        if st is not None:
+            stats = st if stats is None else stats + st
+    return xs, stats
 
 
 # top-N alternatives reported per sampled token when a request asks for
@@ -182,7 +285,7 @@ def make_cross_slot_write(cfg: LlamaConfig):
 
 
 def _tp_attention(shardings: Optional["EngineShardings"], q, k, v, *,
-                  kv_lengths=None, causal=False):
+                  kv_lengths=None, causal=False, window=0):
     """Self/cross attention, head-split over ``tp`` via shard_map under TP.
 
     The flash kernel behind ``dot_product_attention`` (``ops.pallas``) is a
@@ -199,18 +302,18 @@ def _tp_attention(shardings: Optional["EngineShardings"], q, k, v, *,
     """
     if shardings is None:
         return dot_product_attention(q, k, v, kv_lengths=kv_lengths,
-                                     causal=causal)
+                                     causal=causal, window=window)
     heads = P(None, None, "tp", None)
     if kv_lengths is None:
         return jax.shard_map(
-            lambda q_, k_, v_: dot_product_attention(q_, k_, v_,
-                                                     causal=causal),
+            lambda q_, k_, v_: dot_product_attention(
+                q_, k_, v_, causal=causal, window=window),
             mesh=shardings.mesh, in_specs=(heads,) * 3, out_specs=heads,
             check_vma=False,
         )(q, k, v)
     return jax.shard_map(
         lambda q_, k_, v_, n_: dot_product_attention(
-            q_, k_, v_, kv_lengths=n_, causal=causal),
+            q_, k_, v_, kv_lengths=n_, causal=causal, window=window),
         mesh=shardings.mesh,
         in_specs=(heads, heads, heads, P(None)),
         out_specs=heads,
@@ -310,35 +413,23 @@ def make_prefill(cfg: LlamaConfig, block_size: int, blocks_per_seq: int,
                       cross_kv=None, has_image=None, cross_len=None):
         p = params["params"]
         B = ids.shape[0]  # == n_seqs
-        x = p["embed"]["embedding"][ids].astype(jnp.bfloat16)
+        x = _embed(p, ids, cfg)
         if prefix_len:
             x = jnp.concatenate([prefix.astype(jnp.bfloat16), x], axis=1)
         T = x.shape[1]  # == bucket
         n = n_text + prefix_len
         positions = jnp.broadcast_to(jnp.arange(T, dtype=jnp.int32), (B, T))
         tbl = block_tables[:, :m_used]  # [B, m_used]
-        ci = 0
-        pi = 0  # pool index: cross layers own no KV pool entries
-        for li in range(cfg.n_layers):
-            lp = p[f"layer_{li}"]
-            if li in cross_set:
-                # gated cross-attention over vision states: no rope, no KV
-                # pool traffic — its keys are static per request
-                x = _cross_layer(lp, x, cross_kv[ci]["k"], cross_kv[ci]["v"],
-                                 has_image, cfg, cross_len=cross_len,
-                                 shardings=shardings)
-                ci += 1
-                continue
-            h = _rmsnorm(x, lp["attn_norm"]["scale"], cfg.rms_eps)
-            q, k, v = _qkv(lp, h, positions, cfg)
+
+        def attend(pi, qs, ks, vs, window):
+            (q,), (k,), (v,) = qs, ks, vs
             # causal within the prompt; pad keys masked by the true length —
             # kv_lengths (not a mask) keeps the pallas flash kernel eligible
             # for bucketed prefill shapes (VERDICT r1 #3); head-split
             # shard_map under TP (the raw Mosaic kernel cannot be
             # auto-partitioned)
-            o = _tp_attention(shardings, q, k, v, kv_lengths=n, causal=True)
-            x = x + _proj(o.reshape(B, T, -1), lp["attn"]["o"])
-            x = x + _mlp(lp, _rmsnorm(x, lp["mlp_norm"]["scale"], cfg.rms_eps))
+            o = _tp_attention(shardings, q, k, v, kv_lengths=n, causal=True,
+                              window=window)
             # scatter each row's k/v blocks into the pool ([B, m_used]
             # index); int8 pools quantize per block x head on the way in
             kv[pi] = _scatter_blocks(
@@ -347,7 +438,15 @@ def make_prefill(cfg: LlamaConfig, block_size: int, blocks_per_seq: int,
                           cfg.head_dim),
                 v.reshape(B, m_used, block_size, cfg.n_kv_heads,
                           cfg.head_dim), kv_quant)
-            pi += 1
+            return (o,)
+
+        # gated cross-attention over vision states: no rope, no KV pool
+        # traffic — its keys are static per request
+        (x,), _ = _run_layers(
+            p, cfg, (x,), (positions,), attend,
+            cross=lambda ci: (cross_kv[ci]["k"], cross_kv[ci]["v"],
+                              has_image, cross_len),
+            active=(positions < n[:, None],), shardings=shardings)
         last = jnp.take_along_axis(x, (n - 1).reshape(B, 1, 1), axis=1)
         return kv, _logits(p, last, cfg)[:, 0]  # [B, V]
 
@@ -382,7 +481,8 @@ def make_prefill(cfg: LlamaConfig, block_size: int, blocks_per_seq: int,
 
 
 def _pool_kernel_call(kernel, shardings: Optional["EngineShardings"],
-                      qf, kpool, vpool, tf, lf, ks=None, vs=None):
+                      qf, kpool, vpool, tf, lf, ks=None, vs=None,
+                      window: int = 0):
     """THE dispatch seam for a paged/ragged pool kernel on flattened rows:
     direct call on one device, head-split shard_map under TP (the raw
     Mosaic kernel cannot be auto-partitioned; attention is head-local so
@@ -390,7 +490,10 @@ def _pool_kernel_call(kernel, shardings: Optional["EngineShardings"],
     present, split on the same kv-head axis as the blocks they scale.
     Shared by decode/verify (``_make_token_forward``) and the ragged
     continuation (``_ragged_pool_attention``) so the sharding specs can
-    never diverge between the two."""
+    never diverge between the two. ``window``: a window layer's bound,
+    handed to the kernel as a static argument (0 hands it nothing)."""
+    if window:
+        kernel = functools.partial(kernel, window=window)
     if shardings is None:
         return kernel(qf, kpool, vpool, tf, lf, ks, vs)
     heads_q = P(None, "tp", None)
@@ -414,7 +517,8 @@ def _pool_kernel_call(kernel, shardings: Optional["EngineShardings"],
 
 def _ragged_pool_attention(q: jax.Array, kv_layer: Dict, tables: jax.Array,
                            positions: jax.Array, block_size: int,
-                           shardings: Optional["EngineShardings"]):
+                           shardings: Optional["EngineShardings"],
+                           window: int = 0):
     """Ragged attention of ``[B, T, H, D]`` queries over the paged pool:
     the Pallas ragged kernel on TPU platforms (``T`` queries flattened
     into the row axis, through the shared ``_pool_kernel_call`` dispatch
@@ -427,7 +531,7 @@ def _ragged_pool_attention(q: jax.Array, kv_layer: Dict, tables: jax.Array,
 
     if not on_tpu_platform():
         return ragged_gather_attention(q, kpool, vpool, tables, positions,
-                                       ks, vs)
+                                       ks, vs, window=window)
     from ..ops.pallas.ragged_paged_attention import (
         ragged_paged_attention as kern,
     )
@@ -436,7 +540,8 @@ def _ragged_pool_attention(q: jax.Array, kv_layer: Dict, tables: jax.Array,
     qf = q.reshape(B * T, H, D)
     tf = jnp.repeat(tables, T, axis=0) if T > 1 else tables
     lf = jnp.clip(positions + 1, 1, L).reshape(B * T)
-    o = _pool_kernel_call(kern, shardings, qf, kpool, vpool, tf, lf, ks, vs)
+    o = _pool_kernel_call(kern, shardings, qf, kpool, vpool, tf, lf, ks, vs,
+                          window=window)
     return o.reshape(B, T, H, D)
 
 
@@ -493,22 +598,20 @@ def make_prefill_cont(cfg: LlamaConfig, block_size: int, blocks_per_seq: int,
     def _ragged_impl(params, kv, ids, n_text, block_tables, start_arr):
         p = params["params"]
         B = ids.shape[0]  # == 1
-        x = p["embed"]["embedding"][ids].astype(jnp.bfloat16)
+        x = _embed(p, ids, cfg)
         T = x.shape[1]  # == bucket
         start_arr = start_arr.astype(jnp.int32)
-        positions = start_arr[:, None] + jnp.broadcast_to(
-            jnp.arange(T, dtype=jnp.int32), (B, T))
+        offs = jnp.broadcast_to(jnp.arange(T, dtype=jnp.int32), (B, T))
+        positions = start_arr[:, None] + offs
         sb = start_arr // block_size                        # [B]
         tbl_chunk = jnp.take_along_axis(
             block_tables,
             sb[:, None] + jnp.arange(c_blocks, dtype=jnp.int32)[None, :],
             axis=1)                                         # [B, c_blocks]
         tables = block_tables[:, :blocks_per_seq]
-        pi = 0
-        for li in range(cfg.n_layers):
-            lp = p[f"layer_{li}"]
-            h = _rmsnorm(x, lp["attn_norm"]["scale"], cfg.rms_eps)
-            q, k, v = _qkv(lp, h, positions, cfg)
+
+        def attend(pi, qs, ks, vs, window):
+            (q,), (k,), (v,) = qs, ks, vs
             # scatter the chunk FIRST: its queries then attend their own
             # freshly-written keys through the pool, exactly like decode —
             # [prior, chunk] is the pool's table order, no concat needed
@@ -518,12 +621,11 @@ def make_prefill_cont(cfg: LlamaConfig, block_size: int, blocks_per_seq: int,
                           cfg.head_dim),
                 v.reshape(B, c_blocks, block_size, cfg.n_kv_heads,
                           cfg.head_dim), kv_quant)
-            o = _ragged_pool_attention(q, kv[pi], tables, positions,
-                                       block_size, shardings)
-            x = x + _proj(o.reshape(B, T, -1), lp["attn"]["o"])
-            x = x + _mlp(lp, _rmsnorm(x, lp["mlp_norm"]["scale"],
-                                      cfg.rms_eps))
-            pi += 1
+            return (_ragged_pool_attention(q, kv[pi], tables, positions,
+                                           block_size, shardings, window),)
+
+        (x,), _ = _run_layers(p, cfg, (x,), (positions,), attend,
+                              active=(offs < n_text[:, None],))
         last = jnp.take_along_axis(x, (n_text - 1).reshape(B, 1, 1), axis=1)
         return kv, _logits(p, last, cfg)[:, 0]  # [B, V]
 
@@ -544,27 +646,18 @@ def make_prefill_cont(cfg: LlamaConfig, block_size: int, blocks_per_seq: int,
                    has_image=None, cross_len=None):
         p = params["params"]
         B = ids.shape[0]  # == 1
-        x = p["embed"]["embedding"][ids].astype(jnp.bfloat16)
+        x = _embed(p, ids, cfg)
         T = x.shape[1]  # == bucket
         n = n_text + start  # total valid tokens after this chunk
-        positions = start + jnp.broadcast_to(
-            jnp.arange(T, dtype=jnp.int32), (B, T))
+        offs = jnp.broadcast_to(jnp.arange(T, dtype=jnp.int32), (B, T))
+        positions = start + offs
         tbl_prior = block_tables[:, :start_blocks]        # [B, start_blocks]
         goff = (tbl_prior[:, :, None] * block_size
                 + jnp.arange(block_size)[None, None, :]).reshape(B, start)
         tbl_chunk = block_tables[:, start_blocks:start_blocks + c_blocks]
-        ci = 0
-        pi = 0  # pool index: cross layers own no KV pool entries
-        for li in range(cfg.n_layers):
-            lp = p[f"layer_{li}"]
-            if li in cross_set:
-                x = _cross_layer(lp, x, cross_kv[ci]["k"], cross_kv[ci]["v"],
-                                 has_image, cfg, cross_len=cross_len,
-                                 shardings=shardings)
-                ci += 1
-                continue
-            h = _rmsnorm(x, lp["attn_norm"]["scale"], cfg.rms_eps)
-            q, k, v = _qkv(lp, h, positions, cfg)
+
+        def attend(pi, qs, ks, vs, window):
+            (q,), (k,), (v,) = qs, ks, vs
             if kv_quant:
                 # int8 prior context: block-shaped gather so the
                 # per-(block, head) scales broadcast on the dequant
@@ -584,16 +677,20 @@ def make_prefill_cont(cfg: LlamaConfig, block_size: int, blocks_per_seq: int,
             kcat = jnp.concatenate([kprior, k], axis=1)  # [B, start+T, ...]
             vcat = jnp.concatenate([vprior, v], axis=1)
             o = _tp_attention(shardings, q, kcat, vcat, kv_lengths=n,
-                              causal=True)
-            x = x + _proj(o.reshape(B, T, -1), lp["attn"]["o"])
-            x = x + _mlp(lp, _rmsnorm(x, lp["mlp_norm"]["scale"], cfg.rms_eps))
+                              causal=True, window=window)
             kv[pi] = _scatter_blocks(
                 kv[pi], tbl_chunk,
                 k.reshape(B, c_blocks, block_size, cfg.n_kv_heads,
                           cfg.head_dim),
                 v.reshape(B, c_blocks, block_size, cfg.n_kv_heads,
                           cfg.head_dim), kv_quant)
-            pi += 1
+            return (o,)
+
+        (x,), _ = _run_layers(
+            p, cfg, (x,), (positions,), attend,
+            cross=lambda ci: (cross_kv[ci]["k"], cross_kv[ci]["v"],
+                              has_image, cross_len),
+            active=(offs < n_text[:, None],), shardings=shardings)
         last = jnp.take_along_axis(x, (n_text - 1).reshape(B, 1, 1), axis=1)
         return kv, _logits(p, last, cfg)[:, 0]  # [B, V]
 
@@ -656,7 +753,8 @@ def _make_token_forward(cfg: LlamaConfig, block_size: int, m_ctx: int,
     L = block_size * m_ctx
     cross_set = set(cfg.cross_attention_layers)
 
-    def paged_attn(qf, kpool, vpool, tablesf, lengthsf, ks=None, vs=None):
+    def paged_attn(qf, kpool, vpool, tablesf, lengthsf, ks=None, vs=None,
+                   window=0):
         """qf [rows, H, D] over the pool, through the shared
         ``_pool_kernel_call`` dispatch seam (head-split shard_map under
         TP). ``ragged`` swaps in the ragged entry point — same layout and
@@ -673,14 +771,14 @@ def _make_token_forward(cfg: LlamaConfig, block_size: int, m_ctx: int,
             )
 
         return _pool_kernel_call(kernel, shardings, qf, kpool, vpool,
-                                 tablesf, lengthsf, ks, vs)
+                                 tablesf, lengthsf, ks, vs, window=window)
 
     def fwd(params, kv, tokens, positions, tables, cross_kv=None,
-            has_image=None, slot_idx=None, cross_len=None):
+            has_image=None, slot_idx=None, cross_len=None, active=None):
         p = params["params"]
         B = max_num_seqs
         tables = tables[:, :m_ctx]
-        x = p["embed"]["embedding"][tokens].astype(jnp.bfloat16)  # [B,T,d]
+        x = _embed(p, tokens, cfg)                                # [B,T,d]
         # flat write offsets for the T new tokens' kv: [B, T]
         pblk = positions // block_size
         blk = jnp.where(
@@ -695,24 +793,11 @@ def _make_token_forward(cfg: LlamaConfig, block_size: int, m_ctx: int,
                     + jnp.arange(block_size)[None, None, :]).reshape(B, L)
             # query t attends exactly positions <= positions[b, t] (its own
             # just-written token included); padding rows see one dummy token
-            mask = (jnp.arange(L)[None, None, :]
-                    <= positions[:, :, None])[:, None]  # [B, 1, T, L]
-        ci = 0
-        pi = 0  # pool index: cross layers own no KV pool entries
-        for li in range(cfg.n_layers):
-            lp = p[f"layer_{li}"]
-            if li in cross_set:
-                # slot_idx maps the COMPACTED batch row back to its slot's
-                # rows in the full cross-kv buffers (gather fuses into the
-                # attention read)
-                ck = cross_kv[ci]["k"][slot_idx]
-                cv = cross_kv[ci]["v"][slot_idx]
-                x = _cross_layer(lp, x, ck, cv, has_image, cfg,
-                                 cross_len=cross_len, shardings=shardings)
-                ci += 1
-                continue
-            h = _rmsnorm(x, lp["attn_norm"]["scale"], cfg.rms_eps)
-            q, kk, vv = _qkv(lp, h, positions, cfg)
+            behind = positions[:, :, None] - jnp.arange(L)[None, None, :]
+            mask = (behind >= 0)[:, None]               # [B, 1, T, L]
+
+        def attend(pi, qs, ks, vs, window):
+            (q,), (kk,), (vv,) = qs, ks, vs
             if kv_quant:
                 # int8 pool: one read-modify-write requantize per new token
                 # (T is 1 for decode, k+1 for verify — a tiny unroll); the
@@ -721,19 +806,19 @@ def _make_token_forward(cfg: LlamaConfig, block_size: int, m_ctx: int,
                 from ..ops.quant import requantize_block_tokens
 
                 kpool, vpool = kv[pi]["k"], kv[pi]["v"]
-                ks, vs = kv[pi]["ks"], kv[pi]["vs"]
+                ks_, vs_ = kv[pi]["ks"], kv[pi]["vs"]
                 for t in range(T):
                     bt = blk[:, t]
                     pin = positions[:, t] % block_size
                     kq, ksn = requantize_block_tokens(
-                        kpool[bt], ks[bt], kk[:, t], pin)
+                        kpool[bt], ks_[bt], kk[:, t], pin)
                     vq, vsn = requantize_block_tokens(
-                        vpool[bt], vs[bt], vv[:, t], pin)
+                        vpool[bt], vs_[bt], vv[:, t], pin)
                     kpool = kpool.at[bt].set(kq)
                     vpool = vpool.at[bt].set(vq)
-                    ks = ks.at[bt].set(ksn)
-                    vs = vs.at[bt].set(vsn)
-                kv[pi] = {"k": kpool, "v": vpool, "ks": ks, "vs": vs}
+                    ks_ = ks_.at[bt].set(ksn)
+                    vs_ = vs_.at[bt].set(vsn)
+                kv[pi] = {"k": kpool, "v": vpool, "ks": ks_, "vs": vs_}
             else:
                 pool_shape = kv[pi]["k"].shape
                 kflat = kv[pi]["k"].reshape(-1, cfg.n_kv_heads, cfg.head_dim)
@@ -749,26 +834,34 @@ def _make_token_forward(cfg: LlamaConfig, block_size: int, m_ctx: int,
                     kv[pi]["k"], kv[pi]["v"],
                     jnp.repeat(tables, T, axis=0) if T > 1 else tables,
                     jnp.clip(positions + 1, 1, L).reshape(B * T),
-                    ksc, vsc)
-                o = o.reshape(B, T, cfg.n_heads, cfg.head_dim)
-            elif kv_quant:
+                    ksc, vsc, window=window)
+                return (o.reshape(B, T, cfg.n_heads, cfg.head_dim),)
+            if kv_quant:
                 # deviceless int8 path: the gather reference dequantizes
                 # right after the block gather (ops.attention)
                 from ..ops.attention import ragged_gather_attention
 
-                o = ragged_gather_attention(q, kv[pi]["k"], kv[pi]["v"],
-                                            tables, positions, ksc, vsc)
-            else:
-                kflat = kv[pi]["k"].reshape(-1, cfg.n_kv_heads, cfg.head_dim)
-                vflat = kv[pi]["v"].reshape(-1, cfg.n_kv_heads, cfg.head_dim)
-                kctx = kflat[goff]  # [B, L, Hkv, Dh]
-                vctx = vflat[goff]
-                o = dot_product_attention(q, kctx, vctx, mask=mask)
-            pi += 1
-            x = x + _proj(o.reshape(B, T, -1), lp["attn"]["o"])
-            x = x + _mlp(lp, _rmsnorm(x, lp["mlp_norm"]["scale"],
-                                      cfg.rms_eps))
-        return kv, _logits(p, x, cfg)  # [B, T, V] f32
+                return (ragged_gather_attention(
+                    q, kv[pi]["k"], kv[pi]["v"], tables, positions, ksc,
+                    vsc, window=window),)
+            kflat = kv[pi]["k"].reshape(-1, cfg.n_kv_heads, cfg.head_dim)
+            vflat = kv[pi]["v"].reshape(-1, cfg.n_kv_heads, cfg.head_dim)
+            # a window layer's query also drops what lies a window behind
+            m = mask & (behind < window)[:, None] if window else mask
+            return (dot_product_attention(q, kflat[goff], vflat[goff],
+                                          mask=m),)
+
+        # slot_idx maps the COMPACTED batch row back to its slot's rows in
+        # the full cross-kv buffers (gather fuses into the attention read)
+        (x,), stats = _run_layers(
+            p, cfg, (x,), (positions,), attend,
+            cross=lambda ci: (cross_kv[ci]["k"][slot_idx],
+                              cross_kv[ci]["v"][slot_idx], has_image,
+                              cross_len),
+            active=None if active is None or not cfg.n_experts else (
+                jnp.broadcast_to(active[:, None] > 0, (B, T)),),
+            shardings=shardings)
+        return kv, _logits(p, x, cfg), stats  # [B, T, V] f32
 
     return fwd
 
@@ -843,17 +936,22 @@ def make_decode(cfg: LlamaConfig, block_size: int, blocks_per_seq: int,
     def _decode_impl(params, kv, tokens, pos, tables, active, rng,
                      temperature, top_k, top_p, cross_kv=None, has_image=None,
                      slot_idx=None, cross_len=None):
-        kv, logits = fwd(params, kv, tokens[:, None], pos[:, None], tables,
-                         cross_kv=cross_kv, has_image=has_image,
-                         slot_idx=slot_idx, cross_len=cross_len)
+        kv, logits, stats = fwd(
+            params, kv, tokens[:, None], pos[:, None], tables,
+            cross_kv=cross_kv, has_image=has_image, slot_idx=slot_idx,
+            cross_len=cross_len, active=active)
         logits = logits[:, 0]  # [B, V]
         nxt = sample_logits(logits, rng, temperature, top_k, top_p)
         # logprob data rides along (tiny vs the matmuls); the engine only
         # transfers it to the host when a running request asked for it
         top_ids, top_lp, tok_lp = token_logprobs(logits, nxt)
-        if feedback:
-            return kv, nxt, pos + 1, top_ids, top_lp, tok_lp
-        return kv, nxt, top_ids, top_lp, tok_lp
+        out = (kv, nxt) + ((pos + 1,) if feedback else ()) + (
+            top_ids, top_lp, tok_lp)
+        # a routed model's step says what routing did (ROUTE_STATS int32
+        # behind the sampled tokens, ONE array: the host's one read of the
+        # step carries both); a dense model's outputs are what they were
+        return out if stats is None else out + (
+            jnp.concatenate([nxt.astype(jnp.int32), stats]),)
 
     if cross_set:
         def decode(params, kv, tokens, pos, tables, active, rng,
@@ -877,7 +975,8 @@ def make_decode(cfg: LlamaConfig, block_size: int, blocks_per_seq: int,
     in_sh = (sh.params, kvsh) + (rep,) * 8
     if cross_set:
         in_sh += (sh.cross_pool(len(cross_set)), rep, rep, rep)
-    out_sh = (kvsh,) + (rep,) * (5 if feedback else 4)
+    out_sh = (kvsh,) + (rep,) * ((5 if feedback else 4)
+                                 + bool(cfg.n_experts))
     return jax.jit(decode, donate_argnums=donate,
                    in_shardings=in_sh, out_shardings=out_sh)
 
@@ -932,9 +1031,10 @@ def make_verify(cfg: LlamaConfig, block_size: int, blocks_per_seq: int,
                      slot_idx=None, cross_len=None):
         B = max_num_seqs
         positions = pos0[:, None] + jnp.arange(T, dtype=jnp.int32)[None, :]
-        kv, logits = fwd(params, kv, tokens, positions, tables,
-                         cross_kv=cross_kv, has_image=has_image,
-                         slot_idx=slot_idx, cross_len=cross_len)
+        kv, logits, _ = fwd(params, kv, tokens, positions, tables,
+                            cross_kv=cross_kv, has_image=has_image,
+                            slot_idx=slot_idx, cross_len=cross_len,
+                            active=active)
         draft = tokens[:, 1:]  # [B, k]
         bt = jnp.broadcast_to(temperature[:, None], (B, T))
         bk = jnp.broadcast_to(top_k[:, None], (B, T))
@@ -1049,6 +1149,8 @@ def make_fused_step(cfg: LlamaConfig, block_size: int, blocks_per_seq: int,
     assert bucket % block_size == 0
     assert not cfg.cross_attention_layers, \
         "fused step serves text engines (the ragged gate)"
+    assert not cfg.window_layers and not cfg.n_experts, \
+        "fused step: no window layers, no experts (the boot refuses them)"
     m_ctx = blocks_per_seq
     c_blocks = bucket // block_size
     L = block_size * m_ctx
@@ -1073,7 +1175,7 @@ def make_fused_step(cfg: LlamaConfig, block_size: int, blocks_per_seq: int,
         Hkv, Dh = cfg.n_kv_heads, cfg.head_dim
         tables = tables[:, :m_ctx]
         # -- decode section inputs: make_decode verbatim (T == 1) --------
-        x = p["embed"]["embedding"][tokens[:, None]].astype(jnp.bfloat16)
+        x = _embed(p, tokens[:, None], cfg)
         positions = pos[:, None]                                # [B, 1]
         pblk = positions // block_size
         blk = jnp.where(
@@ -1088,7 +1190,7 @@ def make_fused_step(cfg: LlamaConfig, block_size: int, blocks_per_seq: int,
             mask = (jnp.arange(L)[None, None, :]
                     <= positions[:, :, None])[:, None]  # [B, 1, 1, L]
         # -- chunk section inputs: the ragged continuation verbatim ------
-        xc = p["embed"]["embedding"][c_ids].astype(jnp.bfloat16)
+        xc = _embed(p, c_ids, cfg)
         c_start32 = c_start.astype(jnp.int32)
         c_positions = c_start32[:, None] + jnp.broadcast_to(
             jnp.arange(C, dtype=jnp.int32), (1, C))
@@ -1098,34 +1200,32 @@ def make_fused_step(cfg: LlamaConfig, block_size: int, blocks_per_seq: int,
             sb[:, None] + jnp.arange(c_blocks, dtype=jnp.int32)[None, :],
             axis=1)                                      # [1, c_blocks]
         c_tables = c_table[:, :m_ctx]
-        for li in range(cfg.n_layers):
-            lp = p[f"layer_{li}"]
+
+        def attend(li, qs, ks, vs, window):
+            # the two streams of the one layer call: decode rows, chunk
+            (q, qc), (kk, kc), (vv, vc) = qs, ks, vs
             # chunk scatter FIRST each layer: the oracle's continuation
             # dispatch finishes before its decode dispatch, so stale-table
             # write collisions must resolve in the same order here
-            hc = _rmsnorm(xc, lp["attn_norm"]["scale"], cfg.rms_eps)
-            qc, kc, vc = _qkv(lp, hc, c_positions, cfg)
             kv[li] = _scatter_blocks(
                 kv[li], tbl_chunk,
                 kc.reshape(1, c_blocks, block_size, Hkv, Dh),
                 vc.reshape(1, c_blocks, block_size, Hkv, Dh), kv_quant)
-            h = _rmsnorm(x, lp["attn_norm"]["scale"], cfg.rms_eps)
-            q, kk, vv = _qkv(lp, h, positions, cfg)
             if kv_quant:
                 from ..ops.quant import requantize_block_tokens
 
                 kpool, vpool = kv[li]["k"], kv[li]["v"]
-                ks, vs = kv[li]["ks"], kv[li]["vs"]
+                ks_, vs_ = kv[li]["ks"], kv[li]["vs"]
                 bt = blk[:, 0]
                 pin = positions[:, 0] % block_size
                 kq, ksn = requantize_block_tokens(
-                    kpool[bt], ks[bt], kk[:, 0], pin)
+                    kpool[bt], ks_[bt], kk[:, 0], pin)
                 vq, vsn = requantize_block_tokens(
-                    vpool[bt], vs[bt], vv[:, 0], pin)
+                    vpool[bt], vs_[bt], vv[:, 0], pin)
                 kv[li] = {"k": kpool.at[bt].set(kq),
                           "v": vpool.at[bt].set(vq),
-                          "ks": ks.at[bt].set(ksn),
-                          "vs": vs.at[bt].set(vsn)}
+                          "ks": ks_.at[bt].set(ksn),
+                          "vs": vs_.at[bt].set(vsn)}
             else:
                 pool_shape = kv[li]["k"].shape
                 kflat = kv[li]["k"].reshape(-1, Hkv, Dh)
@@ -1142,32 +1242,28 @@ def make_fused_step(cfg: LlamaConfig, block_size: int, blocks_per_seq: int,
                     kv[li]["k"], kv[li]["v"], tables, c_tables,
                     pos, c_positions.reshape(C), ksc, vsc,
                     pool_call=_pool_call)
-                o = o_dec.reshape(B, 1, cfg.n_heads, Dh)
-                oc = o_chk.reshape(1, C, cfg.n_heads, Dh)
-            else:
-                # off-TPU each section keeps ITS OWN oracle's attention
-                # function — the two reference softmaxes need not match
-                # bitwise, and token-exactness is per-section
-                if kv_quant:
-                    from ..ops.attention import ragged_gather_attention
+                return (o_dec.reshape(B, 1, cfg.n_heads, Dh),
+                        o_chk.reshape(1, C, cfg.n_heads, Dh))
+            # off-TPU each section keeps ITS OWN oracle's attention
+            # function — the two reference softmaxes need not match
+            # bitwise, and token-exactness is per-section
+            if kv_quant:
+                from ..ops.attention import ragged_gather_attention
 
-                    o = ragged_gather_attention(
-                        q, kv[li]["k"], kv[li]["v"], tables, positions,
-                        ksc, vsc)
-                else:
-                    kflat = kv[li]["k"].reshape(-1, Hkv, Dh)
-                    vflat = kv[li]["v"].reshape(-1, Hkv, Dh)
-                    o = dot_product_attention(q, kflat[goff], vflat[goff],
-                                              mask=mask)
-                oc = _ragged_pool_attention(qc, kv[li], c_tables,
-                                            c_positions, block_size,
-                                            shardings)
-            x = x + _proj(o.reshape(B, 1, -1), lp["attn"]["o"])
-            x = x + _mlp(lp, _rmsnorm(x, lp["mlp_norm"]["scale"],
-                                      cfg.rms_eps))
-            xc = xc + _proj(oc.reshape(1, C, -1), lp["attn"]["o"])
-            xc = xc + _mlp(lp, _rmsnorm(xc, lp["mlp_norm"]["scale"],
-                                        cfg.rms_eps))
+                o = ragged_gather_attention(
+                    q, kv[li]["k"], kv[li]["v"], tables, positions,
+                    ksc, vsc)
+            else:
+                kflat = kv[li]["k"].reshape(-1, Hkv, Dh)
+                vflat = kv[li]["v"].reshape(-1, Hkv, Dh)
+                o = dot_product_attention(q, kflat[goff], vflat[goff],
+                                          mask=mask)
+            return o, _ragged_pool_attention(qc, kv[li], c_tables,
+                                             c_positions, block_size,
+                                             shardings)
+
+        (x, xc), _ = _run_layers(p, cfg, (x, xc), (positions, c_positions),
+                                 attend)
         logits = _logits(p, x, cfg)[:, 0]                       # [B, V]
         nxt = sample_logits(logits, rng, temperature, top_k, top_p)
         top_ids, top_lp, tok_lp = token_logprobs(logits, nxt)

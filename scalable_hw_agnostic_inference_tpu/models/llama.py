@@ -42,6 +42,10 @@ LayerCache = Dict[str, jax.Array]
 Cache = List[LayerCache]
 
 
+#: AFMoE's attention pattern: three window layers, then one full layer
+_AFMOE_PERIOD = ("sliding_attention",) * 3 + ("full_attention",)
+
+
 @dataclasses.dataclass(frozen=True)
 class LlamaConfig:
     vocab_size: int = 128256
@@ -62,6 +66,33 @@ class LlamaConfig:
     # (reference serves this architecture via the vLLM fork,
     # ``cova/mllama-32-11b-vllm-trn1-config.yaml``). Empty = plain llama.
     cross_attention_layers: Tuple[int, ...] = ()
+    # width of one attention head; None = ``dim // n_heads`` (filled in by
+    # ``__post_init__``), a number where the published config says otherwise
+    head_dim: Optional[int] = None
+    # -- what a layer IS, as data (``layer_kind``): the engine's one layer
+    # function reads these and no model's name --------------------------
+    # per-layer attention kind, HF's names: "sliding_attention" (a window
+    # of ``sliding_window`` keys) or "full_attention"; () = all full
+    layer_types: Tuple[str, ...] = ()
+    sliding_window: int = 0
+    # full-attention layers carry rotary embedding (False: none at all)
+    rope_on_full_attention: bool = True
+    qk_norm: bool = False          # RMSNorm over each q and k head
+    attn_gate: bool = False        # o * sigmoid(Wg a) before the o matrix
+    sandwich_norms: bool = False   # a norm after each half too (four a layer)
+    embed_scale: bool = False      # x0 = Embed[ids] * sqrt(dim)
+    # routed FFN: ``n_experts`` experts of width ``moe_mlp_dim`` scored by a
+    # float32 sigmoid router, ``n_experts_per_tok`` chosen on score + bias,
+    # weights renormalised (``route_norm``) and scaled; ``n_shared_experts``
+    # always-on experts beside them; the first ``n_dense_layers`` layers
+    # keep the dense MLP of width ``mlp_dim``. 0 experts = all dense.
+    n_experts: int = 0
+    n_experts_per_tok: int = 0
+    n_shared_experts: int = 0
+    moe_mlp_dim: int = 0
+    n_dense_layers: int = 0
+    route_norm: bool = True
+    route_scale: float = 1.0
 
     def __post_init__(self):
         # sequence fields normalize to tuples so configs hash and compare
@@ -73,10 +104,46 @@ class LlamaConfig:
                 and not isinstance(self.rope_scaling, tuple)):
             object.__setattr__(self, "rope_scaling",
                                tuple(self.rope_scaling))
+        if not isinstance(self.layer_types, tuple):
+            object.__setattr__(self, "layer_types", tuple(self.layer_types))
+        if self.head_dim is None:
+            object.__setattr__(self, "head_dim", self.dim // self.n_heads)
+        if self.layer_types and len(self.layer_types) != self.n_layers:
+            raise ValueError(
+                f"layer_types names {len(self.layer_types)} layers, "
+                f"n_layers is {self.n_layers}")
+
+    def window_of(self, li: int) -> int:
+        """Keys layer ``li``'s queries see behind them, themselves
+        included; 0 = every key (plain causal)."""
+        if self.layer_types and self.layer_types[li] == "sliding_attention":
+            return int(self.sliding_window)
+        return 0
+
+    def rope_of(self, li: int) -> bool:
+        return bool(self.window_of(li) or self.rope_on_full_attention)
+
+    def moe_of(self, li: int) -> bool:
+        return bool(self.n_experts) and li >= self.n_dense_layers
 
     @property
-    def head_dim(self) -> int:
-        return self.dim // self.n_heads
+    def window_layers(self) -> Tuple[int, ...]:
+        """Pool indices (cross layers own none) of the window layers."""
+        pool = [li for li in range(self.n_layers)
+                if li not in self.cross_attention_layers]
+        return tuple(pi for pi, li in enumerate(pool) if self.window_of(li))
+
+    @property
+    def n_moe_layers(self) -> int:
+        return sum(self.moe_of(li) for li in range(self.n_layers))
+
+    @property
+    def engine_only(self) -> bool:
+        """Mechanisms only ``engine.runner``'s layer function implements
+        (the contiguous-cache flax module does not)."""
+        return bool(self.n_experts or self.layer_types or self.qk_norm
+                    or self.attn_gate or self.sandwich_norms
+                    or self.embed_scale)
 
     @classmethod
     def tiny(cls) -> "LlamaConfig":
@@ -116,6 +183,53 @@ class LlamaConfig:
         return cls(vocab_size=32768, dim=4096, n_layers=32, n_heads=32,
                    n_kv_heads=8, mlp_dim=14336, max_seq_len=32768,
                    rope_theta=1000000.0)
+
+    @classmethod
+    def trinity_mini(cls, layer_types: Tuple[str, ...] = (),
+                     n_dense_layers: int = 2) -> "LlamaConfig":
+        """Trinity-Mini (``model_type: afmoe``) geometry: 128 sigmoid-routed
+        experts of width 1024 beside a shared one, three window layers
+        (2048, rotary) to one full layer (no positional embedding), gated
+        QK-normed heads of 128 (32 x 128 = twice the hidden size), four
+        norms a layer, a 200k vocabulary. Whole (32 layers, the default) it
+        is 52 GB in bf16; ``layer_types`` cuts the depth."""
+        layer_types = tuple(layer_types) or _AFMOE_PERIOD * 8
+        return cls(
+            vocab_size=200192, dim=2048, n_layers=len(layer_types),
+            n_heads=32, n_kv_heads=4, head_dim=128, mlp_dim=6144,
+            max_seq_len=131072, rope_theta=10000.0, rms_eps=1e-5,
+            layer_types=layer_types, sliding_window=2048,
+            rope_on_full_attention=False, qk_norm=True, attn_gate=True,
+            sandwich_norms=True, embed_scale=True, n_experts=128,
+            n_experts_per_tok=8, n_shared_experts=1, moe_mlp_dim=1024,
+            n_dense_layers=n_dense_layers, route_norm=True,
+            route_scale=2.826)
+
+    @classmethod
+    def trinity_mini_stage(cls) -> "LlamaConfig":
+        """One chip's pipeline stage of Trinity-Mini: the embedding, the
+        head, one dense layer and one whole period of four expert layers
+        (sliding, sliding, sliding, full) of the 32. Not a servable whole
+        model: 8.48 GB of the 52 GB."""
+        return cls.trinity_mini(_AFMOE_PERIOD[:1] + _AFMOE_PERIOD, 1)
+
+    @classmethod
+    def tiny_afmoe(cls) -> "LlamaConfig":
+        """CI-tier stand-in with Trinity-Mini's mechanisms: a dense layer,
+        three window layers and a full one without rotary embedding, 32
+        experts top-8 beside a shared one (a flipped choice then swaps an
+        eighth of the routed output, as at full size, not half), gated
+        QK-normed heads wider than ``dim // n_heads``, a window (32)
+        shorter than the test prompts."""
+        return cls(
+            vocab_size=512, dim=64, n_layers=5, n_heads=4, n_kv_heads=2,
+            head_dim=32, mlp_dim=128, max_seq_len=8192, rope_theta=10000.0,
+            layer_types=("sliding_attention",) * 4 + ("full_attention",),
+            sliding_window=32, rope_on_full_attention=False, qk_norm=True,
+            attn_gate=True, sandwich_norms=True, embed_scale=True,
+            n_experts=32, n_experts_per_tok=8, n_shared_experts=1,
+            moe_mlp_dim=16, n_dense_layers=1, route_norm=True,
+            route_scale=2.826)
 
     @classmethod
     def llama3_70b(cls) -> "LlamaConfig":
@@ -283,11 +397,12 @@ class LlamaForCausalLM(nn.Module):
         write_index: Optional[jax.Array] = None,
     ) -> Tuple[jax.Array, Optional[Cache]]:
         cfg = self.cfg
-        if cfg.cross_attention_layers:
+        if cfg.cross_attention_layers or cfg.engine_only:
             raise ValueError(
-                "mllama configs (cross_attention_layers) run through the "
-                "paged engine (engine.runner), not the contiguous-cache "
-                "flax path")
+                "mllama configs (cross_attention_layers) and configs with "
+                "experts, window layers, head norms, an output gate or "
+                "sandwich norms run through the paged engine "
+                "(engine.runner), not the contiguous-cache flax path")
         B, T = ids.shape
         if positions is None:
             positions = jnp.broadcast_to(jnp.arange(T, dtype=jnp.int32), (B, T))
@@ -440,15 +555,16 @@ GEOMETRY_STD = 0.02
 _GEOMETRY_INT8_SCALE = GEOMETRY_STD / (127.0 * 2 / 12 ** 0.5)
 
 
-@functools.partial(jax.jit, static_argnames=("shape", "dtype", "sharding"))
-def _geometry_leaf(key, *, shape, dtype, sharding):
+@functools.partial(jax.jit,
+                   static_argnames=("shape", "dtype", "sharding", "std"))
+def _geometry_leaf(key, *, shape, dtype, sharding, std=GEOMETRY_STD):
     """One seeded weight leaf, generated in its final dtype under its final
     sharding: each device computes only its own shard, and nothing wider
     than the leaf's own dtype outlives the call."""
     if dtype == jnp.int8:
         w = jax.random.randint(key, shape, -127, 128, jnp.int8)
     else:
-        w = (GEOMETRY_STD * jax.random.normal(key, shape, jnp.float32)
+        w = (std * jax.random.normal(key, shape, jnp.float32)
              ).astype(dtype)
     return w if sharding is None else jax.lax.with_sharding_constraint(
         w, sharding)
@@ -470,6 +586,10 @@ def geometry_params(cfg: LlamaConfig, dtype=jnp.bfloat16,
     Decode cost is weight-value-independent, so throughput numbers are real;
     outputs are meaningless text.
     """
+    if cfg.n_experts and (quant or mesh is not None):
+        raise ValueError(
+            "expert layers have no int8 weights and no sharding plan yet "
+            "(quantization: int8 / tensor_parallel_size > 1 with experts)")
     D, HD = cfg.dim, cfg.head_dim
     q_out, kv_out = cfg.n_heads * HD, cfg.n_kv_heads * HD
     rules = tp_rules()
@@ -481,10 +601,15 @@ def geometry_params(cfg: LlamaConfig, dtype=jnp.bfloat16,
             return None
         return NamedSharding(mesh, rules.spec_for(path, ndim=ndim))
 
+    # float32 leaves are the CI-sized stand-ins': unit fan-in scale (at
+    # 0.02 a width of 64 gives logits too flat for a broken layer to show)
+    std = D ** -0.5 if jnp.dtype(dtype) == jnp.float32 else GEOMETRY_STD
+
     def rand(path: str, shape, dt):
         return _geometry_leaf(
             jax.random.fold_in(root, next(n_leaf)), shape=tuple(shape),
-            dtype=jnp.dtype(dt), sharding=sharding_of(path, len(shape)))
+            dtype=jnp.dtype(dt), sharding=sharding_of(path, len(shape)),
+            std=std)
 
     def const(path: str, shape, value, dt):
         sh = sharding_of(path, len(shape))
@@ -505,15 +630,37 @@ def geometry_params(cfg: LlamaConfig, dtype=jnp.bfloat16,
                                     (cfg.vocab_size, D), dtype)},
         "final_norm": norm("final_norm"),
     }
+    def mlp(path: str, width: int):
+        return {"gate": lin(f"{path}/gate", D, width),
+                "up": lin(f"{path}/up", D, width),
+                "down": lin(f"{path}/down", width, D)}
+
+    E, F = cfg.n_experts, cfg.moe_mlp_dim
     for i in range(cfg.n_layers):
         lp = f"layer_{i}"
         layer: Dict[str, Any] = {
-            "mlp": {"gate": lin(f"{lp}/mlp/gate", D, cfg.mlp_dim),
-                    "up": lin(f"{lp}/mlp/up", D, cfg.mlp_dim),
-                    "down": lin(f"{lp}/mlp/down", cfg.mlp_dim, D)},
             "attn_norm": norm(f"{lp}/attn_norm"),
             "mlp_norm": norm(f"{lp}/mlp_norm"),
         }
+        if cfg.sandwich_norms:
+            layer["post_attn_norm"] = norm(f"{lp}/post_attn_norm")
+            layer["post_mlp_norm"] = norm(f"{lp}/post_mlp_norm")
+        if cfg.moe_of(i):
+            # a layer's experts are stacked leaves; the router and the
+            # bias that only selects stay float32
+            mo = f"{lp}/moe"
+            layer["moe"] = {
+                "router": {"kernel": rand(f"{mo}/router/kernel", (D, E),
+                                          jnp.float32)},
+                "bias": rand(f"{mo}/bias", (E,), jnp.float32),
+                "experts": {
+                    "gate": rand(f"{mo}/experts/gate", (E, D, F), dtype),
+                    "up": rand(f"{mo}/experts/up", (E, D, F), dtype),
+                    "down": rand(f"{mo}/experts/down", (E, F, D), dtype)},
+                "shared": mlp(f"{mo}/shared", F * cfg.n_shared_experts),
+            }
+        else:
+            layer["mlp"] = mlp(f"{lp}/mlp", cfg.mlp_dim)
         if i in cfg.cross_attention_layers:
             ca = f"{lp}/cross_attn"
             layer["cross_attn"] = {
@@ -530,6 +677,11 @@ def geometry_params(cfg: LlamaConfig, dtype=jnp.bfloat16,
                 "q": lin(f"{at}/q", D, q_out), "k": lin(f"{at}/k", D, kv_out),
                 "v": lin(f"{at}/v", D, kv_out), "o": lin(f"{at}/o", q_out, D),
             }
+            if cfg.attn_gate:
+                layer["attn"]["gate"] = lin(f"{at}/gate", D, q_out)
+            if cfg.qk_norm:
+                layer["attn"]["q_norm"] = norm(f"{at}/q_norm", HD)
+                layer["attn"]["k_norm"] = norm(f"{at}/k_norm", HD)
         tree[lp] = layer
     if not cfg.tie_embeddings:
         tree["lm_head"] = lin("lm_head", D, cfg.vocab_size)

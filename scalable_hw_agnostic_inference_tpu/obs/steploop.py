@@ -226,6 +226,22 @@ class StepTelemetry:
         # the same accounting's count of dispatches: work done has a
         # number of programs, not only of tokens
         self.dispatches_by_phase: Dict[str, int] = {}
+        # what routing did, in the decode dispatches of a model with expert
+        # layers (the device counts inside the step it already runs; the
+        # counts ride back behind the sampled tokens, in the same read):
+        # expert-layer steps, assignments (real rows x experts per token),
+        # distinct experts touched and the largest load on one expert, the
+        # last two summed over expert layers and steps. None = no experts:
+        # the snapshot then has no ``moe`` entry.
+        self.moe: Optional[Dict[str, int]] = None
+        # what the window bought in the same dispatches of a model with
+        # window layers: token slots the paged kernel walked and skipped
+        # and the keys its queries could see, in window layers;
+        # ``pool_tokens_dead``: tokens x window layers the ONE block table
+        # still holds below the rows' windows (gauge, last dispatch), with
+        # its sum over dispatches beside the sum of all tokens x layers
+        # held — what a per-kind allocator would have to free
+        self.window: Optional[Dict[str, int]] = None
         self.warmed_executables = 0  # closed-set size at readiness
         # last-step gauges (scraped between steps)
         self._gauges: Dict[str, float] = {}
@@ -387,8 +403,33 @@ class StepTelemetry:
                 self.dispatches_by_phase[phase] = (
                     self.dispatches_by_phase.get(phase, 0) + 1)
 
+    def count_moe(self, layer_steps: int, assignments: int,
+                  experts_touched: int, load_max: int) -> None:
+        with self._lock:
+            m = self.moe if self.moe is not None else {}
+            for key, v in (("layer_steps", layer_steps),
+                           ("assignments", assignments),
+                           ("experts_touched", experts_touched),
+                           ("load_max", load_max)):
+                m[key] = m.get(key, 0) + int(v)
+            self.moe = m
+
+    def count_window(self, walked: int, skipped: int, visible: int,
+                     dead: int, held: int) -> None:
+        with self._lock:
+            w = self.window if self.window is not None else {}
+            for key, v in (("tokens_walked", walked),
+                           ("tokens_skipped", skipped),
+                           ("tokens_visible", visible),
+                           ("pool_dead_token_steps", dead),
+                           ("pool_token_steps", held)):
+                w[key] = w.get(key, 0) + int(v)
+            w["pool_tokens_dead"] = int(dead)
+            self.window = w
+
     def record_step(self, *, kind: str, duration_s: float, n_running: int,
                     n_waiting: int, n_chunking: int, blocks_free: int,
+                    slots_free: int = 0,
                     blocks_evictable: int = 0, finished: int = 0,
                     rollback_tokens: int = 0,
                     spec: Optional[Dict[str, Any]] = None,
@@ -442,6 +483,7 @@ class StepTelemetry:
                 "running": float(n_running),
                 "waiting": float(n_waiting),
                 "chunking": float(n_chunking),
+                "slots_free": float(slots_free),
                 "kv_utilization": rec["kv_utilization"],
                 "kv_occupancy": rec["kv_occupancy"],
                 "kv_blocks_free": float(blocks_free),
@@ -511,6 +553,10 @@ class StepTelemetry:
                 for p in set(self.real_by_phase) | set(self.pad_by_phase)}
             out["dispatches_by_phase"] = dict(self.dispatches_by_phase)
             out["flush_by_reason"] = dict(self._flush_reasons)
+            if self.moe is not None:
+                out["moe"] = dict(self.moe)
+            if self.window is not None:
+                out["window"] = dict(self.window)
             # the open phase's seconds so far included: two readings
             # differ by the time between them, whatever each caught open
             out["phase_s"] = dict(self.phase_s)

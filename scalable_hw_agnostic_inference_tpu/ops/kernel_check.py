@@ -56,10 +56,12 @@ class KernelCase:
 
 
 def _flash_case(H: int, Hkv: int, D: int, T: int, S: int,
-                rows: int) -> KernelCase:
+                rows: int, window: int = 0) -> KernelCase:
     """Causal flash prefill of a ``T``-token bucket over ``S`` keys
     (``S > T`` is a continuation chunk behind ``S - T`` prior tokens),
-    ``rows`` sequences with mixed true lengths."""
+    ``rows`` sequences with mixed true lengths. ``window``: a window
+    layer's bound (the chunk's queries then cross its lower edge when
+    ``S > window``)."""
     start = S - T
     # full bucket, one token, and (when rows allow) lengths in between
     lens = [start + n for n in (T, 1, T // 2 + 3, T - 1)][:rows]
@@ -72,17 +74,19 @@ def _flash_case(H: int, Hkv: int, D: int, T: int, S: int,
                 jnp.asarray(lens, jnp.int32))
 
     return KernelCase(
-        name=f"flash-H{H}x{Hkv}-T{T}-S{S}-b{rows}", make_inputs=make,
+        name=(f"flash-H{H}x{Hkv}-T{T}-S{S}-b{rows}"
+              f"{f'-w{window}' if window else ''}"), make_inputs=make,
         kernel=lambda q, k, v, n, interpret: flash_attention(
-            q, k, v, causal=True, lengths=n, interpret=interpret),
+            q, k, v, causal=True, lengths=n, interpret=interpret,
+            window=window),
         oracle=lambda q, k, v, n: dot_product_attention(
-            q, k, v, causal=True, kv_lengths=n, impl="xla"),
+            q, k, v, causal=True, kv_lengths=n, impl="xla", window=window),
         tol=TOL_BF16)
 
 
 def _pool_case(kind: str, H: int, Hkv: int, D: int, block_size: int,
                blocks_per_seq: int, rows: int, int8_kv: bool,
-               tile_edges: bool = False) -> KernelCase:
+               tile_edges: bool = False, window: int = 0) -> KernelCase:
     """A paged-pool kernel (``kind``: ``paged`` bucketed decode, ``ragged``)
     over ``rows`` single-query rows with mixed context lengths and shuffled
     block tables.
@@ -91,13 +95,26 @@ def _pool_case(kind: str, H: int, Hkv: int, D: int, block_size: int,
     can get wrong: lengths on both sides of a tile's edge, and every pool
     block no row's live tokens sit in filled with NaN (an int8 pool: NaN
     scales), so a page that is fetched though dead, or multiplied though
-    never fetched, shows in the output."""
+    never fetched, shows in the output.
+
+    ``window``: a window layer's bound. Lengths then sit at the window,
+    just below it and far above it (and, with ``tile_edges``, where the
+    window's lower edge meets a tile's), and the poison also fills every
+    block wholly BELOW a row's window: a tile the kernel should have
+    skipped, or a block of the edge tile it should not have copied."""
     kern = {"paged": paged_decode_attention,
             "ragged": ragged_paged_attention}[kind]
     L = blocks_per_seq * block_size
-    if tile_edges:
-        t = tile_tokens(block_size, Hkv, D,
-                        jnp.int8 if int8_kv else jnp.bfloat16)
+    t = tile_tokens(block_size, Hkv, D,
+                    jnp.int8 if int8_kv else jnp.bfloat16)
+    if window and tile_edges:
+        # the window's lower edge on a tile's edge, one past it, one short
+        lens = [window + t, window + t + 1, window + t - 1, window + 1,
+                window + 2 * t + block_size + 3, L, L - 1, window]
+    elif window:
+        lens = [window - 1, window, window + 1, L, 1, block_size + 3,
+                window + t + 5, 2 * window + 7]
+    elif tile_edges:
         lens = [t - 1, t, t + 1, 2 * t, 2 * t + 1, 1, block_size + 3, L]
     else:
         # one token, a partial second block, mid-window, the full window
@@ -120,8 +137,10 @@ def _pool_case(kind: str, H: int, Hkv: int, D: int, block_size: int,
         if tile_edges:
             # NaN in every block that holds no live token of any row (the
             # null block stays clean)
-            held = (jnp.arange(blocks_per_seq)[None, :] * block_size
-                    < n[:, None])
+            first = jnp.arange(blocks_per_seq)[None, :] * block_size
+            held = first < n[:, None]
+            if window:
+                held &= first + block_size > n[:, None] - window
             owned = jnp.zeros((n_blocks,), bool).at[0].set(True).at[
                 jnp.where(held, tables, 0).ravel()].set(True)
             poison = lambda x: jnp.where(             # noqa: E731
@@ -141,14 +160,17 @@ def _pool_case(kind: str, H: int, Hkv: int, D: int, block_size: int,
         else:
             ks, vs = jnp.nan_to_num(ks), jnp.nan_to_num(vs)
         return ragged_gather_attention(
-            q[:, None], k, v, tables, (n - 1)[:, None], ks, vs)[:, 0]
+            q[:, None], k, v, tables, (n - 1)[:, None], ks, vs,
+            window=window)[:, 0]
 
     return KernelCase(
         name=(f"{kind}-H{H}x{Hkv}-bs{block_size}-M{blocks_per_seq}-b{rows}"
               f"-{'int8kv' if int8_kv else 'bf16'}"
-              f"{'-edges' if tile_edges else ''}"),
+              f"{'-edges' if tile_edges else ''}"
+              f"{f'-w{window}' if window else ''}"),
         make_inputs=make,
-        kernel=lambda *a, interpret: kern(*a, interpret=interpret),
+        kernel=lambda *a, interpret: kern(*a, interpret=interpret,
+                                          window=window),
         oracle=oracle, tol=TOL_INT8_KV if int8_kv else TOL_BF16)
 
 
@@ -156,13 +178,19 @@ def engine_cases(n_heads: int, n_kv_heads: int, head_dim: int, *,
                  tp: int = 1, block_size: int = 16,
                  buckets: Sequence[int] = (128, 512),
                  max_model_len: int = 2048, max_num_seqs: int = 4,
-                 max_prefill_batch: int = 4) -> List[KernelCase]:
+                 max_prefill_batch: int = 4,
+                 window: int = 0) -> List[KernelCase]:
     """The kernel calls an engine of this geometry dispatches, per TP shard:
     flash at each prefill bucket (widest prefill batch) and at the first
     continuation start, then paged decode and ragged over the full block
     table, bf16 and int8-KV: ``max_num_seqs`` rows of mixed lengths, then
     8 rows (the benchmark cells' batch) at the tile's edges over a
-    NaN-poisoned pool."""
+    NaN-poisoned pool. ``window`` (a model with window layers) adds the
+    same calls under the window: flash at the top bucket and at the
+    continuation start that crosses the window's edge, paged and ragged
+    (bf16: the boot refuses int8 KV with window layers) at, just below
+    and far above the window, and at the tile's edges over the poisoned
+    pool."""
     H, Hkv = n_heads // tp, n_kv_heads // tp
     M = max_model_len // block_size
     top = max(buckets)
@@ -176,4 +204,18 @@ def engine_cases(n_heads: int, n_kv_heads: int, head_dim: int, *,
                                     max_num_seqs, int8_kv))
             cases.append(_pool_case(kind, H, Hkv, head_dim, block_size, M,
                                     8, int8_kv, tile_edges=True))
+    if window:
+        cases.append(_flash_case(H, Hkv, head_dim, top, top,
+                                 max_prefill_batch, window))
+        # the continuation chunk whose queries cross the window's edge
+        prior = -(-window // top) * top
+        if max_model_len >= prior + top:
+            cases.append(_flash_case(H, Hkv, head_dim, top, prior + top, 1,
+                                     window))
+        for kind in ("paged", "ragged"):
+            cases.append(_pool_case(kind, H, Hkv, head_dim, block_size, M,
+                                    8, False, window=window))
+            cases.append(_pool_case(kind, H, Hkv, head_dim, block_size, M,
+                                    8, False, tile_edges=True,
+                                    window=window))
     return cases

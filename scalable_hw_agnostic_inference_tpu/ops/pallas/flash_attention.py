@@ -70,7 +70,7 @@ def flash_eligible(q, k, v, mask=None, bias=None) -> bool:
 
 def _flash_kernel(lens_ref, q_ref, k_ref, v_ref, o_ref, *, scale: float,
                   causal: bool, has_lengths: bool, block_q: int, block_k: int,
-                  seq_k: int, q_offset: int):
+                  seq_k: int, q_offset: int, window: int = 0):
     # lens_ref: [B] in SMEM (scalar-prefetch); q_ref: [BLOCK_Q, D];
     # k_ref/v_ref: [S, D]; o_ref: [BLOCK_Q, D]. ``q_offset`` = S - T: causal
     # queries start at key position S - T (the decode-step layout contract of
@@ -97,6 +97,11 @@ def _flash_kernel(lens_ref, q_ref, k_ref, v_ref, o_ref, *, scale: float,
         bound = jnp.minimum(bound, q_offset + (qi + 1) * block_q)
     n_live = pl.cdiv(bound, block_k) if (has_lengths or causal) else (
         seq_k // block_k)
+    # a window (static, causal only; 0 = none): the query tile's first row
+    # sees no key below its position - window + 1, so the key blocks
+    # wholly below that are skipped like the ones above the diagonal
+    j0 = 0 if not window else jnp.maximum(
+        q_offset + qi * block_q - window + 1, 0) // block_k
 
     def body(j, carry):
         m, l, o = carry
@@ -113,6 +118,8 @@ def _flash_kernel(lens_ref, q_ref, k_ref, v_ref, o_ref, *, scale: float,
             q_pos = q_offset + qi * block_q + jax.lax.broadcasted_iota(
                 jnp.int32, (bq, block_k), 0)
             c = q_pos >= k_pos
+            if window:
+                c = jnp.logical_and(c, q_pos - k_pos < window)
             live = c if live is None else jnp.logical_and(live, c)
         if live is not None:
             s = jnp.where(live, s, NEG_INF)
@@ -124,11 +131,12 @@ def _flash_kernel(lens_ref, q_ref, k_ref, v_ref, o_ref, *, scale: float,
         o = o * corr + jnp.dot(p, v_blk, preferred_element_type=jnp.float32)
         return m_new, l, o
 
-    m, l, o = jax.lax.fori_loop(0, n_live, body, (m0, l0, o0))
+    m, l, o = jax.lax.fori_loop(j0, n_live, body, (m0, l0, o0))
     o_ref[:] = (o / jnp.maximum(l, 1e-20)).astype(o_ref.dtype)
 
 
-@functools.partial(jax.jit, static_argnames=("causal", "scale", "interpret"))
+@functools.partial(jax.jit, static_argnames=("causal", "scale", "interpret",
+                                             "window"))
 def flash_attention(
     q: jax.Array,
     k: jax.Array,
@@ -138,8 +146,13 @@ def flash_attention(
     scale: Optional[float] = None,
     lengths: Optional[jax.Array] = None,
     interpret: Optional[bool] = None,
+    window: int = 0,
 ) -> jax.Array:
     """Flash attention. q ``[B,T,H,D]``, k/v ``[B,S,Hkv,D]`` → ``[B,T,H,D]``.
+
+    ``window`` (static, with ``causal``; 0 = none): query ``i`` sees keys
+    ``j`` with ``0 <= i - j < window``; key blocks wholly below a query
+    tile's window are skipped, the block its edge cuts is masked.
 
     ``lengths`` ``[B]`` int32 marks the valid key count per row (keys beyond
     it are masked AND their blocks skipped entirely) — the bucketed-prefill
@@ -152,6 +165,8 @@ def flash_attention(
     B, T, H, D = q.shape
     S, Hkv = k.shape[1], k.shape[2]
     group = H // Hkv
+    if window and not causal:
+        raise ValueError("a window bounds causal attention only")
     if scale is None:
         scale = 1.0 / (D ** 0.5)
     if interpret is None:
@@ -192,6 +207,7 @@ def flash_attention(
     kernel = functools.partial(
         _flash_kernel, scale=scale, causal=causal, has_lengths=has_lengths,
         block_q=block_q, block_k=block_k, seq_k=S, q_offset=q_offset,
+        window=int(window),
     )
     out = pl.pallas_call(
         kernel,

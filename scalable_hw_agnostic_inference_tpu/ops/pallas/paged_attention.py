@@ -84,14 +84,28 @@ def tile_tokens(block_size: int, n_kv_heads: int, head_dim: int,
     return tile
 
 
-def live_tile_tokens(n_tokens: int, tile: int) -> int:
+def first_live_tile(n_tokens, tile: int, window: int):
+    """The first tile a row of ``n_tokens`` walks in a layer whose queries
+    see ``window`` keys behind them (0 = all): the tile that holds key
+    ``n_tokens - window``. The kernel and the engine's accounting share
+    this rule (``n_tokens`` a Python int or a traced scalar)."""
+    if not window:
+        return 0
+    lo = n_tokens - window
+    lo = max(lo, 0) if isinstance(lo, int) else jnp.maximum(lo, 0)
+    return lo // tile
+
+
+def live_tile_tokens(n_tokens: int, tile: int, window: int = 0) -> int:
     """Token slots the kernel walks for a row holding ``n_tokens``: its
-    live tiles, whole (a row of length 0 walks one)."""
-    return -(-max(n_tokens, 1) // tile) * tile
+    live tiles, whole (a row of length 0 walks one). A window layer skips
+    the tiles wholly below ``n_tokens - window``."""
+    return (-(-max(n_tokens, 1) // tile)
+            - first_live_tile(n_tokens, tile, window)) * tile
 
 
 def _pool_kernel(tables_ref, lens_ref, *rest, scale: float, block_size: int,
-                 tile: int, hkv: int, quantized: bool):
+                 tile: int, hkv: int, quantized: bool, window: int = 0):
     # q_ref/o_ref [H, D]; k_hbm/v_hbm [N, bs * Hkv, D] left in HBM, row
     # ``t * Hkv + h`` of a block is token t's head h; kbuf/vbuf [2, tile *
     # Hkv, D] VMEM in the same row order, sem [2 (k, v), 2 (slot)];
@@ -119,10 +133,22 @@ def _pool_kernel(tables_ref, lens_ref, *rest, scale: float, block_size: int,
     def n_units(row):
         return jnp.clip(pl.cdiv(lens_ref[row], unit), 1, max_units)
 
+    def first_tile(row):
+        # a window layer (static ``window`` > 0) starts at the tile that
+        # holds the row's first visible key; with no window this is the
+        # Python 0 it always was, and nothing below traces differently
+        if not window:
+            return 0
+        return first_live_tile(lens_ref[row], tile, window)
+
     def each_copy(row, i, slot, act):
         """``act`` on the K and V copy of every live unit of tile ``i`` of
-        ``row`` into ``slot``."""
+        ``row`` into ``slot``. Under a window, the units of the first live
+        tile that lie wholly below the window are not copied either."""
         first = i * per_tile
+        u0 = 0 if not window else jnp.clip(
+            jnp.maximum(lens_ref[row] - window, 0) // unit - first,
+            0, per_tile)
 
         def one(u, carry):
             pos = (first + u) * unit
@@ -142,7 +168,7 @@ def _pool_kernel(tables_ref, lens_ref, *rest, scale: float, block_size: int,
             return carry
 
         jax.lax.fori_loop(
-            0, jnp.minimum(n_units(row) - first, per_tile), one, 0)
+            u0, jnp.minimum(n_units(row) - first, per_tile), one, 0)
 
     @pl.when(b == 0)
     def _first_row():
@@ -150,10 +176,11 @@ def _pool_kernel(tables_ref, lens_ref, *rest, scale: float, block_size: int,
         # copied; its p is 0, and 0 * stale-VMEM must not be NaN
         vbuf[...] = jnp.zeros_like(vbuf)
         base_ref[0] = 0
-        each_copy(0, 0, 0, lambda c: c.start())
+        each_copy(0, first_tile(0), 0, lambda c: c.start())
 
     base = base_ref[0]
     length = lens_ref[b]
+    t0 = first_tile(b)
     n_tiles = pl.cdiv(n_units(b), per_tile)
     # K/V enter the dots as stored when q shares their dtype (int8 is exact
     # in every float type here); otherwise both sides go to f32
@@ -171,7 +198,7 @@ def _pool_kernel(tables_ref, lens_ref, *rest, scale: float, block_size: int,
 
     def tile_step(i, carry):
         m_prev, l_prev, acc = carry
-        slot = jax.lax.rem(base + i, 2)
+        slot = jax.lax.rem(base + (i - t0 if window else i), 2)
         # what to fetch while this tile computes: the row's next tile, or
         # after its last the next row's first
         last = i + 1 == n_tiles
@@ -179,12 +206,17 @@ def _pool_kernel(tables_ref, lens_ref, *rest, scale: float, block_size: int,
 
         @pl.when(nxt_row < n_rows)
         def _prefetch():
-            each_copy(nxt_row, jnp.where(last, 0, i + 1), 1 - slot,
+            nxt_first = first_tile(
+                jnp.minimum(b + 1, n_rows - 1)) if window else 0
+            each_copy(nxt_row, jnp.where(last, nxt_first, i + 1), 1 - slot,
                       lambda c: c.start())
 
         each_copy(b, i, slot, lambda c: c.wait())
 
         live = own_head & (col_tok < length - i * tile)
+        if window:
+            # the tile the window's lower edge cuts: keys below it masked
+            live = live & (col_tok >= length - window - i * tile)
         s = jax.lax.dot_general(
             q, kbuf[slot].astype(dot_dt), (((1,), (1,)), ((), ())),
             preferred_element_type=jnp.float32) * scale   # [H, cols]
@@ -205,11 +237,12 @@ def _pool_kernel(tables_ref, lens_ref, *rest, scale: float, block_size: int,
         return m_new, l_new, acc * corr + pv
 
     _, l_fin, acc = jax.lax.fori_loop(
-        0, n_tiles, tile_step,
+        t0, n_tiles, tile_step,
         (jnp.full((n_heads, 1), NEG_INF, jnp.float32),
          jnp.zeros((n_heads, 1), jnp.float32),
          jnp.zeros((n_heads, d), jnp.float32)))
-    base_ref[0] = jax.lax.rem(base + n_tiles, 2)
+    base_ref[0] = jax.lax.rem(
+        base + (n_tiles - t0 if window else n_tiles), 2)
     o_ref[...] = (acc / jnp.maximum(l_fin, 1e-20)).astype(o_ref.dtype)
 
 
@@ -243,10 +276,13 @@ def _column_scales(scales, i, length, hkv: int, tile: int, block_size: int):
 
 def pool_attention(name: str, q, k_pool, v_pool, tables, lengths,
                    k_scale=None, v_scale=None, *, scale=None,
-                   interpret=None) -> jax.Array:
+                   interpret=None, window: int = 0) -> jax.Array:
     """The one paged-pool kernel call behind both entry points
     (``paged_decode_attention`` here, ``ragged_paged_attention`` beside
-    it). ``name`` is the device op's name in a trace."""
+    it). ``name`` is the device op's name in a trace. ``window`` (static;
+    0 = none): a row's query sees its last ``window`` keys only — the
+    tiles wholly below ``lengths[b] - window`` are neither copied nor
+    computed, and the tile that edge cuts is masked below it."""
     from jax.experimental.pallas import tpu as pltpu
 
     B, H, D = q.shape
@@ -279,7 +315,7 @@ def pool_attention(name: str, q, k_pool, v_pool, tables, lengths,
              v_pool.reshape(N, block_size * Hkv, D)]
     kernel = functools.partial(
         _pool_kernel, scale=scale, block_size=block_size, tile=tile,
-        hkv=Hkv, quantized=quantized)
+        hkv=Hkv, quantized=quantized, window=int(window))
     return pl.pallas_call(
         kernel,
         grid_spec=pltpu.PrefetchScalarGridSpec(
@@ -303,7 +339,8 @@ def pool_attention(name: str, q, k_pool, v_pool, tables, lengths,
     )(tables.astype(jnp.int32), lengths.astype(jnp.int32), *args)
 
 
-@functools.partial(jax.jit, static_argnames=("scale", "interpret"))
+@functools.partial(jax.jit,
+                   static_argnames=("scale", "interpret", "window"))
 def paged_decode_attention(
     q: jax.Array,           # [B, H, D] one query token per sequence
     k_pool: jax.Array,      # [N, block_size, Hkv, D] the paged pool
@@ -315,6 +352,7 @@ def paged_decode_attention(
     *,
     scale: Optional[float] = None,
     interpret: Optional[bool] = None,
+    window: int = 0,
 ) -> jax.Array:
     """Attend each row's query over its paged context. Returns ``[B, H, D]``.
 
@@ -329,4 +367,4 @@ def paged_decode_attention(
     """
     return pool_attention("paged_decode_attention", q, k_pool, v_pool,
                           tables, lengths, k_scale, v_scale, scale=scale,
-                          interpret=interpret)
+                          interpret=interpret, window=window)

@@ -37,7 +37,8 @@ import jax
 from .paged_attention import pool_attention
 
 
-@functools.partial(jax.jit, static_argnames=("scale", "interpret"))
+@functools.partial(jax.jit,
+                   static_argnames=("scale", "interpret", "window"))
 def ragged_paged_attention(
     q: jax.Array,           # [B, H, D] one query token per row
     k_pool: jax.Array,      # [N, block_size, Hkv, D] (float or int8 pool)
@@ -49,6 +50,7 @@ def ragged_paged_attention(
     *,
     scale: Optional[float] = None,
     interpret: Optional[bool] = None,
+    window: int = 0,
 ) -> jax.Array:
     """Attend each row's query over its OWN ragged paged context in one
     dispatch. Returns ``[B, H, D]``.
@@ -60,4 +62,4 @@ def ragged_paged_attention(
     """
     return pool_attention("ragged_paged_attention", q, k_pool, v_pool,
                           tables, lengths, k_scale, v_scale, scale=scale,
-                          interpret=interpret)
+                          interpret=interpret, window=window)

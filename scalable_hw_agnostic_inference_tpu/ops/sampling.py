@@ -63,7 +63,14 @@ def masked_scaled_logits(
     (categorical over these == the actual sampling distribution)."""
     t, k, p = _broadcast_knobs(logits, temperature, top_k, top_p)
     scaled = logits.astype(jnp.float32) / jnp.maximum(t, 1e-6)[..., None]
-    return _mask_top_p(_mask_top_k(scaled, k), p)
+    # each mask sorts the whole ``[rows, V]`` (at 32 x 200k, longer than
+    # the rest of a decode step): skipped, on the device, in a step where
+    # no row asks for that knob — a row with the knob off gets its logits
+    # back unchanged from the mask too, so the outputs are the same bits
+    scaled = jax.lax.cond(jnp.any(k > 0), _mask_top_k,
+                          lambda lg, _: lg, scaled, k)
+    return jax.lax.cond(jnp.any(p < 1.0), _mask_top_p,
+                        lambda lg, _: lg, scaled, p)
 
 
 def sample_excluding(
@@ -130,6 +137,13 @@ def sample_logits(
     per-request array in a continuous batch).
     """
     t, _, _ = _broadcast_knobs(logits, temperature, top_k, top_p)
-    masked = masked_scaled_logits(logits, temperature, top_k, top_p)
-    sampled = jax.random.categorical(rng, masked, axis=-1).astype(jnp.int32)
-    return jnp.where(t <= 0.0, greedy(logits), sampled)
+
+    def draw(_):
+        masked = masked_scaled_logits(logits, temperature, top_k, top_p)
+        sampled = jax.random.categorical(rng, masked, axis=-1)
+        return jnp.where(t <= 0.0, greedy(logits), sampled.astype(jnp.int32))
+
+    # an all-greedy step draws nothing (no noise over the vocabulary, no
+    # sort): the argmax is what the other branch returns for such rows
+    return jax.lax.cond(jnp.any(t > 0.0), draw,
+                        lambda _: greedy(logits), None)

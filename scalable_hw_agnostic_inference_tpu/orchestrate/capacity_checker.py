@@ -74,6 +74,16 @@ def slo_breached(stats: Optional[dict]) -> bool:
     return isinstance(stats, dict) and bool(stats.get("slo_breach"))
 
 
+def queue_depth(stats: dict) -> float:
+    """The line behind full rows: waiting requests beyond the rows that stand
+    free for them. A burst that finishes many rows in one step leaves as many
+    callers waiting for ONE admission step, not queued behind anyone; pricing
+    them as a line shed a closed loop of ``rows + 4`` callers whenever its
+    answers ended together. A snapshot without ``slots_free`` (an older
+    image) counts every waiting request, as before."""
+    return stats.get("waiting", 0) - stats.get("slots_free", 0)
+
+
 def is_overloaded(stats: Optional[dict],
                   th: OverloadThresholds = OverloadThresholds()) -> bool:
     """One pod's engine snapshot → saturated? Missing/partial snapshots
@@ -83,7 +93,7 @@ def is_overloaded(stats: Optional[dict],
     TTFT/TPOT targets needs traffic moved exactly like a full queue."""
     if not isinstance(stats, dict):
         return False
-    if stats.get("waiting", 0) > th.max_queue_depth:
+    if queue_depth(stats) > th.max_queue_depth:
         return True
     if slo_breached(stats):
         return True

@@ -24,7 +24,11 @@ import dataclasses
 import threading
 from typing import Dict, Optional
 
-from ..orchestrate.capacity_checker import OverloadThresholds, is_overloaded
+from ..orchestrate.capacity_checker import (
+    OverloadThresholds,
+    is_overloaded,
+    queue_depth,
+)
 from .qos import TenantLedger
 
 
@@ -148,7 +152,7 @@ class AdmissionGate:
             return Shed(429, "kv_pressure", self.retry_after_s)
         if isinstance(stats, dict) and is_overloaded(stats, self.thresholds):
             reason = ("queue_depth"
-                      if stats.get("waiting", 0) > self.thresholds.max_queue_depth
+                      if queue_depth(stats) > self.thresholds.max_queue_depth
                       else "kv_pressure")
             return Shed(429, reason, self.retry_after_s)
         return None

@@ -64,6 +64,9 @@ _ENGINE_GAUGES = {
     "running": ("shai_engine_running", "Sequences decoding right now"),
     "waiting": ("shai_engine_waiting", "Requests in the admission queue"),
     "chunking": ("shai_engine_chunking", "Slots mid chunked-prefill"),
+    "slots_free": ("shai_engine_slots_free",
+                   "Engine rows that hold no sequence (a waiting request "
+                   "with a free row is in admission, not in line)"),
     "kv_utilization": ("shai_engine_kv_utilization",
                        "KV page pool fraction held by LIVE sequences "
                        "(evictable prefix-cache blocks excluded — they "
@@ -109,6 +112,16 @@ _PAD_PHASE_COUNTERS = {
 #: phases tile the thread, so the rates over a window sum to one.
 _PHASE_SECONDS = ("shai_engine_phase_seconds_total",
                   "Seconds the engine-loop thread spent in each phase")
+#: what routing and the attention window did (obs.steploop ``moe`` /
+#: ``window``): one family each, the snapshot's keys under ``counter``
+_MOE_COUNTERS = ("shai_engine_moe_total",
+                 "Expert routing in decode dispatches, by counter: "
+                 "layer_steps, assignments, experts_touched, load_max")
+_WINDOW_COUNTERS = ("shai_engine_window_total",
+                    "Window layers in decode dispatches, by counter: "
+                    "tokens_walked, tokens_skipped, tokens_visible, "
+                    "pool_tokens_dead (gauge), pool_dead_token_steps, "
+                    "pool_token_steps")
 #: conformance-layer gauge families: each instrument riding the engine
 #: telemetry object exports its flat numeric snapshot verbatim under a
 #: prefix — obs.slo → shai_slo_* (per-objective burn rates + breach),
@@ -288,6 +301,13 @@ class EngineTelemetryCollector:
         for phase, secs in sorted((snap.get("phase_s") or {}).items()):
             c.add_metric([self.app, phase], float(secs))
         yield c
+        for key, family in (("moe", _MOE_COUNTERS),
+                            ("window", _WINDOW_COUNTERS)):
+            if snap.get(key):
+                c = CounterMetricFamily(*family, labels=["app", "counter"])
+                for counter, v in sorted(snap[key].items()):
+                    c.add_metric([self.app, counter], float(v))
+                yield c
         hists = tele.histograms()
         for key, (name, doc) in ENGINE_HISTOGRAMS.items():
             hs = hists.get(key)

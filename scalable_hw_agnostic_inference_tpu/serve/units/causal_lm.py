@@ -213,6 +213,11 @@ def _is_vlm_checkpoint(cfg: ServeConfig, model_id: str) -> bool:
 
 
 def _geometry_models():
+    """Geometry ids: an architecture at its published widths with seeded
+    random weights. ``trinity-mini-geometry`` is NOT a servable whole
+    model: it is one chip's stage of a pipeline (the embedding, the head,
+    one dense and one period of four expert layers of the 32: 8.48 GB of
+    52 GB in bf16)."""
     from ...models.llama import LlamaConfig
 
     return {
@@ -220,6 +225,19 @@ def _geometry_models():
         "llama-3b-geometry": LlamaConfig.llama32_3b,
         "llama-8b-geometry": LlamaConfig.llama3_8b,
         "mistral-7b-geometry": LlamaConfig.mistral_7b,
+        "trinity-mini-geometry": LlamaConfig.trinity_mini_stage,
+    }
+
+
+def _stand_in_models():
+    """CI-sized stand-ins (the hermetic tier): float32 leaves from the
+    seed, the byte tokenizer, and in the ``vllm`` unit ONE tiny engine
+    shape. ``tiny-afmoe`` has ``trinity-mini-geometry``'s mechanisms."""
+    from ...models.llama import LlamaConfig
+
+    return {
+        "tiny": LlamaConfig.tiny,
+        "tiny-afmoe": LlamaConfig.tiny_afmoe,
     }
 
 
@@ -237,12 +255,19 @@ def _load_causal_lm(cfg: ServeConfig, model_id: str, quant: bool = False,
     from ...models.generate import ByteTokenizer
 
     GEOMETRY_MODELS = _geometry_models()
+    STAND_INS = _stand_in_models()
 
-    if model_id in ("", "tiny"):
-        mcfg = llama.LlamaConfig.tiny()
+    if (model_id or "tiny") in STAND_INS:
+        mcfg = STAND_INS[model_id or "tiny"]()
         model = llama.LlamaForCausalLM(mcfg, dtype=jnp.float32)
-        params = model.init(
-            jax.random.PRNGKey(cfg.seed), jnp.zeros((1, 8), jnp.int32))
+        if mcfg.engine_only:
+            # layer kinds the flax module does not run have no ``init``:
+            # their float32 leaves are born as the geometry tier's are
+            params = llama.geometry_params(mcfg, dtype=jnp.float32,
+                                           seed=cfg.seed)
+        else:
+            params = model.init(
+                jax.random.PRNGKey(cfg.seed), jnp.zeros((1, 8), jnp.int32))
         return (mcfg, model, params, ByteTokenizer(),
                 ByteTokenizer.eos_id, ByteTokenizer.pad_id, True)
 

@@ -156,13 +156,13 @@ class VllmService(ModelService):
             # know what architecture it is serving
             from ...core import weights as wstore
 
-            from .causal_lm import _geometry_models
+            from .causal_lm import _geometry_models, _stand_in_models
 
             # geometry ids are architecture names, not hub repos: the VLM
             # autoconfig probe must not fire an HF lookup for them (the tier's
             # whole point is booting with zero network access)
-            real_id = (model_id not in ("", "tiny")
-                       and model_id not in _geometry_models())
+            stand_in = (model_id or "tiny") in _stand_in_models()
+            real_id = not stand_in and model_id not in _geometry_models()
             has_mllama_artifact = real_id and wstore.has_params(
                 cfg.artifact_root, f"mllama--{model_id}")
             has_vlm_artifact = real_id and wstore.has_params(
@@ -201,13 +201,13 @@ class VllmService(ModelService):
                  self.eos_id, self.pad_id, self._byte_tok) = _load_causal_lm(
                     cfg, model_id, quant=ecfg.quantization == "int8",
                     mesh=mesh)
-            if self._byte_tok and model_id in ("", "tiny"):
+            if stand_in:
                 # tiny engine shapes: small blocks/buckets so CI exercises
                 # paging (geometry model ids also use the byte tokenizer but
                 # keep their REAL engine shapes — they exist to measure the
                 # real serving stack)
                 ecfg = EngineConfig(
-                    model="tiny", max_model_len=256,
+                    model=model_id or "tiny", max_model_len=256,
                     max_num_seqs=ecfg.max_num_seqs,
                     block_size=16, context_encoding_buckets=(32, 64, 128),
                     token_generation_buckets=ecfg.token_generation_buckets,

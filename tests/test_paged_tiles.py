@@ -53,7 +53,7 @@ def _engine_stub(lengths, block_size, hkv, d, dtype):
     eng = types.SimpleNamespace(
         _attn_tile=tile_tokens(block_size, hkv, d, dtype),
         cache=types.SimpleNamespace(seq=seqs.__getitem__),
-        obs=StepTelemetry())
+        obs=StepTelemetry(), _window_pool_layers=())
     return eng, running
 
 

@@ -185,6 +185,63 @@ def test_admission_gate_thresholds_mirror_controller():
     assert gate.shed_by_reason() == {"queue_depth": 1, "kv_pressure": 1}
 
 
+@pytest.mark.parametrize("waiting,slots_free,reason", [
+    (36.0, 32.0, None),             # every row ended at once: 4 in line
+    (12.0, 0.0, "queue_depth"),     # full rows: all 12 stand in line
+    (12.0, 3.0, "queue_depth"),     # 9 behind the rows that stand free
+    (11.0, 3.0, None),
+    (4.0, 32.0, None),              # fewer waiting than free rows
+])
+def test_the_gate_prices_the_line_behind_full_rows(waiting, slots_free,
+                                                   reason):
+    """A waiting request with a free row is one admission step from
+    running, not in line: a closed loop of ``rows + 4`` callers is never
+    shed, however many of its answers end in the same step."""
+    gate = AdmissionGate(OverloadThresholds(max_queue_depth=8.0))
+    shed = gate.check({"waiting": waiting, "slots_free": slots_free,
+                       "kv_utilization": 0.5})
+    assert (shed and shed.reason) == reason
+
+
+def test_the_engine_snapshot_carries_its_free_rows():
+    """``slots_free`` on the engine's own snapshot: a closed loop of more
+    callers than rows never reads deeper than the callers beyond the rows."""
+    import jax
+    import jax.numpy as jnp
+
+    from scalable_hw_agnostic_inference_tpu.engine import EngineConfig
+    from scalable_hw_agnostic_inference_tpu.engine.engine import (
+        LLMEngine,
+        SamplingParams,
+    )
+    from scalable_hw_agnostic_inference_tpu.models.llama import (
+        LlamaConfig,
+        LlamaForCausalLM,
+    )
+    from scalable_hw_agnostic_inference_tpu.orchestrate.capacity_checker \
+        import queue_depth
+
+    cfg = LlamaConfig.tiny()
+    params = LlamaForCausalLM(cfg, dtype=jnp.float32).init(
+        jax.random.PRNGKey(0), jnp.zeros((1, 8), jnp.int32))
+    eng = LLMEngine(cfg, params, EngineConfig(
+        max_model_len=64, max_num_seqs=4, block_size=8,
+        context_encoding_buckets=(16,), max_new_tokens=4,
+        max_prefill_batch=1))
+    assert eng.obs.snapshot().get("slots_free", 0) == 0   # no step yet
+    for i in range(6):
+        eng.add_request([1, 5 + i, 9], SamplingParams(max_new_tokens=3))
+    depths = []
+    while eng.has_work:
+        eng.step()
+        snap = eng.obs.snapshot()
+        assert snap["slots_free"] == 4 - snap["running"]
+        depths.append(queue_depth(snap))
+    # one prompt a step is admitted: 5 wait beside 3 free rows, and so on
+    assert depths[0] == 2 and max(depths) == 2
+    assert eng.obs.snapshot()["slots_free"] == 4
+
+
 def test_admission_gate_drain_and_inflight():
     gate = AdmissionGate(max_inflight=2)
     shed = gate.check(None, draining=True)
