@@ -823,6 +823,13 @@ class LLMEngine:
         the async path pipelines decode dispatches one step ahead of the
         host readback; the lock-step path is the reference oracle. Both
         commit/stream/finish the same tokens on the same ``step()`` call.
+
+        An async step STREAMS (dispatches on device feedback before it
+        reads the previous step back) when the lookahead is in flight and
+        the step has nothing to decide: no admission work (``_can_admit``:
+        a waiter AND a free slot — callers queued behind full slots are
+        not work), no slot mid-prefill, no deadline due, no drafter.
+        Anything else is an event step: flush, then the lock-step order.
         """
         outer = self.obs.begin_step(len(self.waiting))
         try:
@@ -867,6 +874,17 @@ class LLMEngine:
         if self._sched is not None:
             _qos.schedule_rotate(self.waiting, self._sched)
 
+    def _can_admit(self) -> bool:
+        """THE admission predicate: a step has admission work only if a
+        request waits AND a slot is free — every rung of the ladder needs a
+        free slot before it touches the queue, the cache or the tier.
+        ``_admit_phase`` enters the ladder on it and ``_step_async`` gates
+        the steady path on it, so the two cannot drift: a blocked step is a
+        no-op for the queue and for the weighted-fair stride in both
+        disciplines. A free slot with a dry pool still counts as work (the
+        ladder owns wait-or-reject)."""
+        return bool(self.waiting) and self._free_slot() is not None
+
     def _admit_phase(self) -> None:
         """One step's chunk-continuation + admission ladder (shared by the
         lock-step and async step bodies)."""
@@ -876,27 +894,30 @@ class LLMEngine:
             # one continuation chunk per step: the long prompt encodes
             # incrementally while the running batch keeps decoding below
             self._continue_prefill(chunking[0])
+        if not self._can_admit():
+            # nothing to dequeue into: the stride moves only when a dequeue
+            # can follow, so a saturated engine's blocked steps leave the
+            # scheduler (and the queue's order) exactly as they found it
+            return
         # class-aware dequeue BEFORE the ladder branches on the head: the
         # branch taken (prefix/cached/long/cross/batch) must be the branch
         # for the request fairness actually selected
         self._schedule_head()
         # admission proceeds even while a long prompt chunks (its slot is
         # untouched) — queued short prompts must not pay k chunk-steps of
-        # TTFT; only a SECOND long prompt waits for the active chunker
-        if self.waiting and self.waiting[0].prefix is not None:
+        # TTFT; only a SECOND long prompt waits for the active chunker.
+        # No rung that declines consumes the head, so it stays the head
+        if self.waiting[0].prefix is not None:
             self._admit_one()       # soft-prefix: bucket-bound single-seq
-        elif (self._kv_cow and self.waiting
-              and self.waiting[0].parent_rid >= 0
+        elif (self._kv_cow and self.waiting[0].parent_rid >= 0
               and self._admit_fanout()):
             pass                    # CoW fan-out: one prefill, K forks
-        elif (self.cache.prefix_caching and self.waiting
-              and self._admit_cached()):
+        elif self.cache.prefix_caching and self._admit_cached():
             pass                    # cached-prefix admission handled it
-        elif (self.waiting
-              and len(self.waiting[0].prompt_ids) > self.buckets.max):
+        elif len(self.waiting[0].prompt_ids) > self.buckets.max:
             if not chunking:
                 self._admit_long()  # chunked prefill (text or cross)
-        elif self.waiting and self.waiting[0].cross_states is not None:
+        elif self.waiting[0].cross_states is not None:
             self._admit_one()       # short multimodal: single-seq
         else:
             self._admit_batch()
@@ -914,6 +935,15 @@ class LLMEngine:
     # first: the in-flight step is retired, surviving slots' host mirrors
     # catch up, and a finished/cancelled slot's extra computed token is
     # discarded (never emitted; its reservation frees with the slot).
+    #
+    # A request that WAITS is not such an event; a request that can be
+    # ADMITTED is. The gate is ``_can_admit`` (a waiter and a free slot),
+    # the same predicate the admission ladder enters on, so a saturated
+    # engine — every slot full, callers queued behind them: the state of
+    # every batch and agent deployment — streams until a commit frees a
+    # slot, flushes once for ``admission``, admits, and re-establishes the
+    # pipe in the same call. A queued request's due deadline still makes
+    # an event step; cancels and migrations flush at their own sites.
     #
     # Token-exactness vs the lock-step oracle holds by construction: the
     # dispatch composition, batch-row packing, and rng folds of step k are
@@ -937,17 +967,20 @@ class LLMEngine:
                    for s in self.slots))
         chunking = any(s is not None and s.prefill_cursor is not None
                        for s in self.slots)
+        admitting = self._can_admit()
         # the steady (pure-decode) path needs no host-side inputs at all;
         # anything else — admission work, chunked prefill, a due deadline,
-        # a drafter wanting the pending token — is an event step
-        if (self._pipe is not None and not self.waiting and not chunking
+        # a drafter wanting the pending token — is an event step. Callers
+        # queued behind full slots are NOT admission work: a saturated
+        # engine streams until a commit frees a slot
+        if (self._pipe is not None and not admitting and not chunking
                 and not deadline_due and self._drafter is None):
             self._steady_step()
         else:
             if self._pipe is not None:
                 self._flush_pipeline(
                     "deadline" if deadline_due else
-                    "admission" if self.waiting else
+                    "admission" if admitting else
                     "chunking" if chunking else "spec")
             self._expire_deadlines()
             self._admit_phase()
@@ -1395,8 +1428,6 @@ class LLMEngine:
         return False
 
     def _admit_one(self) -> None:
-        if not self.waiting:
-            return
         slot = self._free_slot()
         if slot is None:
             return
@@ -1833,8 +1864,6 @@ class LLMEngine:
         leave a cursor for ``_continue_prefill`` to advance one chunk per
         step (decode keeps running between chunks). At most one sequence
         chunks at a time — a second long prompt waits."""
-        if not self.waiting:
-            return
         slot = self._free_slot()
         if slot is None:
             return

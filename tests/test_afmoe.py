@@ -386,6 +386,33 @@ def test_routing_and_window_counters(tiny_params):
     assert "shai_engine_window" in fams
 
 
+def test_a_saturated_routed_engine_streams_and_counts_each_step_once(
+        tiny_params, monkeypatch):
+    """Five requests on two slots: the steady path retires a routed step's
+    ``fetch`` array (routing counts behind the tokens) one step late, so
+    every decode dispatch's counts must land exactly once — none lost to a
+    flush, none read twice — and the tokens equal the lock-step oracle's."""
+    sp = SamplingParams(temperature=0.0, max_new_tokens=14)
+    prompts = [_prompt(n) for n in (9, 12, 20, 7, 15)]
+    eng = _engine(tiny_params, max_num_seqs=2)
+    assert eng._async
+    fins = eng.generate(prompts, sp)
+    eng.finish_pending()                # the trailing lookahead, if any
+    snap = eng.obs.snapshot()
+    decodes = snap["dispatches_by_phase"]["decode"]
+    assert snap["moe"]["layer_steps"] == TINY.n_moe_layers * decodes
+    # it streamed: callers queued behind full slots flushed nothing
+    assert snap["flush_by_reason"].get("admission", 0) <= len(prompts)
+    assert snap["pipeline_flushes"] < 0.5 * snap["steps"], snap
+    monkeypatch.setenv("SHAI_ASYNC_DECODE", "0")
+    oracle = _engine(tiny_params, max_num_seqs=2)
+    assert not oracle._async
+    want = oracle.generate(prompts, sp)
+    assert [f.token_ids for f in fins] == [f.token_ids for f in want]
+    assert (oracle.obs.snapshot()["moe"]["layer_steps"]
+            == snap["moe"]["layer_steps"])
+
+
 def test_the_budget_knows_experts_the_gate_and_the_head_size():
     from scalable_hw_agnostic_inference_tpu.core.budget import (
         GIB,

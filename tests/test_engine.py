@@ -6,6 +6,8 @@ The load-bearing test is greedy-decode parity: the engine (bucketed prefill
 plain ``models.generate`` path produces for the same weights and prompts.
 """
 
+import contextlib
+
 import numpy as np
 import pytest
 
@@ -335,6 +337,26 @@ def test_engine_warm_executables_closed_set(tiny_model):
     assert eng.n_executables == count, "post-warm request compiled a new executable"
 
 
+ENGINE_FNS = {f"jit({n})" for n in (
+    "prefill", "cont", "decode", "sample_logits", "token_logprobs")}
+
+
+@contextlib.contextmanager
+def _xla_compiles():
+    """The names of the functions XLA compiles while the block runs."""
+    compiled = []
+
+    def listener(event, secs, fun_name="", **kw):
+        if event == "/jax/core/compile/backend_compile_duration":
+            compiled.append(fun_name)
+
+    jax.monitoring.register_event_duration_secs_listener(listener)
+    try:
+        yield compiled
+    finally:
+        jax.monitoring.unregister_event_duration_listener(listener)
+
+
 def test_warm_set_is_closed_at_the_xla_level_under_tp(tiny_model):
     """Found on a four-chip host: the engine's own counters said nothing was
     built after warm-up, while XLA compiled the sampler, the logprob readout
@@ -346,23 +368,32 @@ def test_warm_set_is_closed_at_the_xla_level_under_tp(tiny_model):
     eng = _tp_engine(params, cfg, 2, context_encoding_buckets=(16,),
                      max_num_seqs=2)
     eng.warm_executables()
-    compiled = []
-
-    def listener(event, secs, fun_name="", **kw):
-        if event == "/jax/core/compile/backend_compile_duration":
-            compiled.append(fun_name)
-
-    jax.monitoring.register_event_duration_secs_listener(listener)
-    try:
+    with _xla_compiles() as compiled:
         eng.generate([[1, 2, 3], [4, 5]], SamplingParams(
             temperature=0.0, max_new_tokens=6, logprobs=1))
         eng.generate([list(range(2, 30))], SamplingParams(
             temperature=0.7, max_new_tokens=4))
-    finally:
-        jax.monitoring.unregister_event_duration_listener(listener)
-    engine_fns = {f"jit({n})" for n in (
-        "prefill", "cont", "decode", "sample_logits", "token_logprobs")}
-    assert not engine_fns & set(compiled), compiled
+    assert not ENGINE_FNS & set(compiled), compiled
+
+
+def test_a_saturated_tp_engine_streams_without_a_second_compile(tiny_model):
+    """Callers beyond the slots under tensor parallelism: the steady step
+    feeds a step's outputs (which carry the mesh in their type) straight
+    back, the call ``warm_executables`` warms as a second trace. Twenty and
+    more steady steps behind a queue build no executable and compile none
+    of the engine's jitted functions again."""
+    cfg, _, params = tiny_model
+    eng = _tp_engine(params, cfg, 2, context_encoding_buckets=(16,),
+                     max_num_seqs=2)
+    eng.warm_executables()
+    sp = SamplingParams(temperature=0.0, max_new_tokens=30)
+    with _xla_compiles() as compiled:
+        eng.generate([[1, 2, 3], [4, 5], [6, 7, 8], [9, 10]], sp)
+    snap = eng.obs.snapshot()
+    assert snap["recompiles"] == 0
+    assert snap["flush_by_reason"].get("admission", 0) <= 4
+    assert snap["steps"] - snap["pipeline_flushes"] >= 20, snap
+    assert not ENGINE_FNS & set(compiled), compiled
 
 
 def test_engine_decode_ctx_bucket_dispatch(tiny_model):

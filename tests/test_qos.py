@@ -582,6 +582,85 @@ def test_expired_queued_requests_free_same_step(tiny_model, monkeypatch):
     assert {running, live} <= done
 
 
+def _sched_state(eng):
+    sched = eng._sched
+    return (dict(sched._pass), dict(sched._skipped), dict(sched.picks),
+            sched.aged_picks, [r.req_id for r in eng.waiting])
+
+
+@pytest.mark.parametrize("async_on", ["0", "1"], ids=["lockstep", "async"])
+def test_blocked_steps_leave_the_scheduler_untouched(tiny_model, monkeypatch,
+                                                     async_on):
+    """Two classes queued behind full slots: a step that can dequeue
+    nothing is a no-op for the weighted-fair scheduler in BOTH disciplines
+    — no pass advances, no aging streak grows, the queue keeps its order.
+    The stride moves only when a dequeue can follow, so the async path may
+    stream through such steps and still admit in the oracle's order."""
+    monkeypatch.setenv("SHAI_QOS", "1")
+    monkeypatch.setenv("SHAI_ASYNC_DECODE", async_on)
+    eng = make_engine(tiny_model, max_num_seqs=2)
+    long_sp = SamplingParams(temperature=0.0, max_new_tokens=60)
+    for i in range(2):
+        eng.add_request([2 + i, 5, 7], long_sp)
+    eng.step()
+    assert eng._free_slot() is None
+    for i, prio in enumerate((qos.PRIORITY_LOW, qos.PRIORITY_HIGH,
+                              qos.PRIORITY_LOW, qos.PRIORITY_NORMAL)):
+        eng.add_request([9, 3 + i], long_sp, priority=prio)
+    before = _sched_state(eng)
+    flushes = eng.obs.snapshot()["pipeline_flushes"]
+    for _ in range(12):
+        assert eng.step() == []
+    assert _sched_state(eng) == before
+    assert eng._free_slot() is None and eng.n_waiting == 4
+    # ...and the async engine streamed through all twelve
+    assert eng.obs.snapshot()["pipeline_flushes"] == flushes
+
+
+def test_mixed_class_admission_order_same_in_both_disciplines(tiny_model,
+                                                              monkeypatch):
+    """Twelve callers of three classes on four slots, closed loop: the
+    async engine (which skips blocked steps) and the lock-step oracle admit
+    the same requests in the same order on the same steps, and deliver the
+    same tokens."""
+    monkeypatch.setenv("SHAI_QOS", "1")
+    prios = (qos.PRIORITY_HIGH, qos.PRIORITY_NORMAL, qos.PRIORITY_LOW)
+    out = {}
+    for async_on in ("1", "0"):
+        monkeypatch.setenv("SHAI_ASYNC_DECODE", async_on)
+        eng = make_engine(tiny_model, max_num_seqs=4)
+        admitted, tokens, owner, sent = [], {}, {}, [0] * 12
+        note = eng._note_admitted
+
+        def note_admitted(req):
+            admitted.append((eng._step_count, req.req_id))
+            note(req)
+
+        eng._note_admitted = note_admitted
+
+        def send(c):
+            rid = eng.add_request(
+                [2 + c, 3 + sent[c], 5],
+                SamplingParams(temperature=0.0,
+                               max_new_tokens=(5, 9, 7)[(c + sent[c]) % 3]),
+                priority=prios[c % 3], tenant=f"t{c % 3}")
+            owner[rid] = c
+            sent[c] += 1
+
+        for c in range(12):
+            send(c)
+        while eng.has_work:
+            for f in eng.step():
+                tokens[f.req_id] = f.token_ids
+                if sent[owner[f.req_id]] < 2:
+                    send(owner[f.req_id])
+        out[async_on] = (admitted, tokens, dict(eng._sched.picks))
+        assert len(admitted) == 24
+    assert out["1"] == out["0"]
+    # the scheduler really arbitrated: every class was picked
+    assert set(out["1"][2]) == set(prios)
+
+
 # ---------------------------------------------------------------------------
 # adversarial tenant-mix fuzz: starvation-freedom + exactly-once +
 # pool-exact accounting
