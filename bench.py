@@ -572,8 +572,7 @@ def bench_ragged(tiny: bool) -> dict:
 
     Reports tok/s at MIXED prompt lengths (the case the bucket ladder
     padded on), the pad fraction each mode dispatched, the decode
-    executable-ladder entry count (ragged collapses the
-    ``token_generation_buckets`` grid to one context entry), and
+    executable-ladder entry count (one per batch bucket in both), and
     ``kv_quant_capacity_ratio``: how many KV blocks each pool dtype fits
     at a fixed ``SHAI_HBM_GIB`` (params + activations priced by
     ``core.budget.causal_lm_budget``, per-block bytes measured from the
@@ -599,7 +598,6 @@ def bench_ragged(tiny: bool) -> dict:
         cfg = llama_mod.LlamaConfig.tiny()
         ecfg = EngineConfig(max_model_len=256, max_num_seqs=4, block_size=8,
                             context_encoding_buckets=(32, 64, 128),
-                            token_generation_buckets=(64, 128),
                             max_new_tokens=16)
         lens, new = (12, 40, 90, 120), 12
         name = "ragged-tiny"
@@ -608,7 +606,6 @@ def bench_ragged(tiny: bool) -> dict:
         ecfg = EngineConfig(max_model_len=1024, max_num_seqs=4,
                             block_size=16,
                             context_encoding_buckets=(128, 256, 512),
-                            token_generation_buckets=(256, 512),
                             max_new_tokens=32)
         lens, new = (60, 200, 450, 700), 24
         name = "ragged-1b-geometry"
@@ -721,7 +718,6 @@ def bench_fused(tiny: bool) -> dict:
         cfg = llama_mod.LlamaConfig.tiny()
         ecfg = EngineConfig(max_model_len=256, max_num_seqs=4, block_size=8,
                             context_encoding_buckets=(32, 64, 128),
-                            token_generation_buckets=(64, 128),
                             max_new_tokens=16)
         wave1, wave2, new = (12, 40, 90), (140, 20), 12
         name = "fused-tiny"
@@ -730,7 +726,6 @@ def bench_fused(tiny: bool) -> dict:
         ecfg = EngineConfig(max_model_len=1024, max_num_seqs=4,
                             block_size=16,
                             context_encoding_buckets=(128, 256, 512),
-                            token_generation_buckets=(256, 512),
                             max_new_tokens=32)
         wave1, wave2, new = (60, 200, 450), (700, 100), 24
         name = "fused-1b-geometry"
@@ -783,8 +778,8 @@ def bench_fused(tiny: bool) -> dict:
         dt = (time.perf_counter() - t0) / runs
         n_prompts = len(p1) + len(p2)
         # decode-side ladder: the per-step dispatch executables — fused
-        # entries replace BOTH the (ctx, batch) decode grid and the
-        # ragged continuation ladder
+        # entries replace BOTH the decode batch ladder and the
+        # dynamic-start continuation ladder
         ladder = (len(eng._fused_fns) if fused else
                   len(eng._decode_fns)
                   + sum(1 for k in eng._prefill if k[0] == "rcont"))

@@ -256,7 +256,7 @@ DEFAULT_CONTRACT = Contract(
         "LLMEngine": ClassPolicy(
             immutable_after_init=(
                 "cfg", "ecfg", "params", "cross_seq_len", "shardings",
-                "cache", "buckets", "_chunk_cap", "_ctx_buckets",
+                "cache", "buckets", "_chunk_cap",
                 "_drafter", "spec", "_spec_rng", "_sample1", "_lp1",
                 "_cross_embed", "_cross_write", "ttft", "tpot", "obs",
                 "_hbm_every", "_hbm_dev", "_async", "_ids", "_res",
@@ -582,10 +582,8 @@ DEFAULT_CONTRACT = Contract(
             "prefill", "prefill@tp2", "prefill_cont",
             "decode", "decode_feedback",
             "decode@tp2", "decode_feedback@tp2", "decode@tp2_paged",
-            # ragged paged attention (SHAI_RAGGED_ATTENTION): full-window
-            # decode + dynamic-start continuation, CPU gather legs and the
-            # tpu-lowered Pallas kernel leg
-            "decode_ragged", "decode_ragged@tp2",
+            # the dynamic-start continuation (SHAI_RAGGED_ATTENTION), CPU
+            # gather legs
             "prefill_rcont", "prefill_rcont@tp2",
             # fused mixed-phase step (SHAI_FUSED_STEP): decode rows + one
             # continuation-chunk window per dispatch, both async
@@ -612,7 +610,6 @@ DEFAULT_CONTRACT = Contract(
             "prefill", "prefill@tp2", "prefill_cont",
             "decode", "decode_feedback",
             "decode@tp2", "decode_feedback@tp2", "decode@tp2_paged",
-            "decode_ragged", "decode_ragged@tp2",
             "prefill_rcont", "prefill_rcont@tp2",
             "fused_step", "fused_step_feedback", "fused_step@tp2",
             "prefill_kvquant", "decode_kvquant", "tier_restore_quant",
@@ -625,7 +622,6 @@ DEFAULT_CONTRACT = Contract(
             "prefill", "prefill@tp2", "prefill_cont",
             "decode", "decode_feedback",
             "decode@tp2", "decode_feedback@tp2", "decode@tp2_paged",
-            "decode_ragged", "decode_ragged@tp2",
             "prefill_rcont", "prefill_rcont@tp2",
             "fused_step", "fused_step_feedback", "fused_step@tp2",
             "prefill_kvquant", "decode_kvquant", "tier_restore_quant",

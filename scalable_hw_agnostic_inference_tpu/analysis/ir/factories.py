@@ -144,15 +144,14 @@ def _decode_args(cfg, rep=None, shardings=None, quant: bool = False):
 
 def _build_decode(key: str, feedback: bool, tp: bool = False,
                   paged: bool = False, artifact: bool = False,
-                  compile_cpu: bool = False, ragged: bool = False,
+                  compile_cpu: bool = False,
                   kv_quant: bool = False) -> IrProgram:
     from ...engine.runner import make_decode
 
     cfg = _tiny_cfg()
     sh = _engine_shardings(cfg, _mesh("tp")) if tp else None
     fn = make_decode(cfg, BS, BPS, max_num_seqs=B, shardings=sh,
-                     paged=paged, feedback=feedback, ragged=ragged,
-                     kv_quant=kv_quant)
+                     paged=paged, feedback=feedback, kv_quant=kv_quant)
     args = _decode_args(cfg, rep=sh.rep if sh else None, shardings=sh,
                         quant=kv_quant)
     return IrProgram(
@@ -206,7 +205,7 @@ def _build_rcont(key: str, tp: bool = False,
     # the ragged continuation (SHAI_RAGGED_ATTENTION): chunk start as DATA
     # — ONE executable per chunk bucket. Built on the CPU platform, so the
     # traced attention is the XLA gather reference (the Pallas leg is
-    # covered by decode_ragged@tp2's tpu lowering).
+    # covered by decode@tp2_paged's tpu lowering).
     import jax.numpy as jnp
 
     from ...engine.runner import make_prefill_cont
@@ -407,14 +406,7 @@ BUILDERS = {
                                                    compile_cpu=True),
     "decode@tp2_paged": lambda k: _build_decode(k, feedback=False, tp=True,
                                                 paged=True),
-    # ragged paged attention (SHAI_RAGGED_ATTENTION): full-window decode,
-    # CPU leg traces the gather reference; the @tp2 leg lowers the Pallas
-    # ragged kernel for the tpu platform (paged=True forces the kernel,
-    # dryrun-style, like decode@tp2_paged)
-    "decode_ragged": lambda k: _build_decode(k, feedback=False, ragged=True,
-                                             compile_cpu=True),
-    "decode_ragged@tp2": lambda k: _build_decode(k, feedback=False, tp=True,
-                                                 paged=True, ragged=True),
+    # the dynamic-start continuation (SHAI_RAGGED_ATTENTION)
     "prefill_rcont": lambda k: _build_rcont(k),
     "prefill_rcont@tp2": lambda k: _build_rcont(k, tp=True),
     # fused mixed-phase step (SHAI_FUSED_STEP): decode + chunk window in

@@ -21,9 +21,6 @@ class EngineConfig:
     block_size: int = 16                  # KV block granularity (tokens)
     num_blocks: int = 0                   # 0 = auto from max_model_len*max_num_seqs
     context_encoding_buckets: Sequence[int] = (128, 512)   # prefill shapes
-    # decode attention-window buckets: one decode executable per bucket,
-    # dispatched on the longest running sequence (empty = max_model_len only)
-    token_generation_buckets: Sequence[int] = ()
     is_continuous_batching: bool = True
     # max same-bucket prompts admitted as ONE batched prefill call (rounded
     # to a power of two per compiled executable); 1 = serial prefill
@@ -81,21 +78,6 @@ class EngineConfig:
             raise ValueError(
                 f"prefill buckets {misaligned} not multiples of "
                 f"block_size={self.block_size}")
-        # token_generation_buckets get the SAME shape discipline as the
-        # prefill buckets: a decode executable compiled past max_model_len
-        # (or off block alignment) would warm a window no sequence can
-        # reach — or worse, mis-size its block-table slice
-        bad = [b for b in self.token_generation_buckets
-               if b > self.max_model_len]
-        if bad:
-            raise ValueError(
-                f"token_generation_buckets {bad} exceed max_model_len")
-        misaligned = [b for b in self.token_generation_buckets
-                      if b < 1 or b % self.block_size]
-        if misaligned:
-            raise ValueError(
-                f"token_generation_buckets {misaligned} not positive "
-                f"multiples of block_size={self.block_size}")
         if self.quantization not in (None, "", "int8"):
             raise ValueError(
                 f"unsupported quantization {self.quantization!r} "
@@ -151,6 +133,10 @@ class EngineConfig:
             "device": None,                 # vLLM "neuron"/"cuda" — meaningless here
             "max_num_batched_tokens": None,  # derived from buckets
             "override_neuron_config": None,
+            # a decode program is chosen by its batch bucket alone: a row
+            # of the paged kernel pays for the tiles it holds, so a window
+            # ladder has nothing to buy
+            "token_generation_buckets": None,
         }
         kwargs, ignored = {}, []
         for k, v in d.items():

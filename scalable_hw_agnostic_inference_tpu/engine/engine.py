@@ -140,14 +140,15 @@ class LLMEngine:
             raise ValueError(
                 "this model's layers are not served with: "
                 + "; ".join(refused))
-        # ragged paged attention (SHAI_RAGGED_ATTENTION, default off):
-        # decode/verify attend mixed context lengths in ONE full-window
-        # dispatch (a row walks its live tiles), so the
-        # token_generation_buckets ladder collapses to a single context
-        # entry and chunked prefill's continuation ladder collapses to one
-        # dynamic-start executable
-        # per chunk bucket. Text engines only: the ragged continuation
-        # does not carry the mllama cross tail.
+        # SHAI_RAGGED_ATTENTION (default off) selects ONE thing: the
+        # dynamic-start continuation. Chunked prefill's one-per-start
+        # ladder collapses to one executable per chunk bucket whose
+        # chunk queries attend through the pool kernel, a query a row
+        # (runner.make_prefill_cont(ragged=True)); it is also what
+        # SHAI_FUSED_STEP rides. Decode and verify do not read it: they
+        # are one program per batch bucket either way. Text engines only:
+        # the dynamic-start continuation does not carry the mllama cross
+        # tail.
         self._ragged = bool(_env_flag("SHAI_RAGGED_ATTENTION", False)
                             and not model_cfg.cross_attention_layers)
         # prefix caching serves the plain-text path only: cross models'
@@ -215,24 +216,15 @@ class LLMEngine:
         self._chunk_cap = min(ecfg.max_model_len - 1,
                               (ecfg.max_model_len // C) * C)
         self._prefill = {}
-        # decode executables keyed (ctx_bucket, batch_bucket): the attention
-        # window is the smallest token_generation_bucket covering the longest
-        # running sequence, the batch the smallest power of two covering the
-        # active slots — decode cost tracks context AND occupancy in use
-        bs = ecfg.block_size
-        tg = [min(-(-t // bs), ecfg.blocks_per_seq)
-              for t in ecfg.token_generation_buckets]
-        self._ctx_buckets = sorted(set(tg) | {ecfg.blocks_per_seq})
-        if self._ragged:
-            # the ragged kernel owns the FULL window with per-row cost:
-            # the context-bucket ladder collapses to one entry, and no
-            # dispatch ever keys on the longest running sequence again
-            self._ctx_buckets = [ecfg.blocks_per_seq]
-        self._decode_fns: Dict[Tuple[int, int], Any] = {}
+        # decode executables keyed by the batch bucket alone: the smallest
+        # power of two covering the active slots. Attention is handed the
+        # full table and a row pays for the tiles it holds, so nothing
+        # about the rows' lengths chooses a program
+        self._decode_fns: Dict[int, Any] = {}
         # speculative decoding: a host-side prompt-lookup drafter plus one
-        # multi-token verify executable per (ctx_bucket, batch_bucket) —
-        # same dispatch grid as decode, k+1 positions per call
-        self._verify_fns: Dict[Tuple[int, int], Any] = {}
+        # multi-token verify executable per batch bucket — same dispatch
+        # rule as decode, k+1 positions per call
+        self._verify_fns: Dict[int, Any] = {}
         self._drafter = None
         self.spec = None
         if ecfg.speculative_enabled:
@@ -248,7 +240,7 @@ class LLMEngine:
             self._spec_rng = np.random.default_rng(ecfg.seed + 0x5EC)
         # fused mixed-phase step (SHAI_FUSED_STEP, default off): decode and
         # the chunked-prefill continuation share ONE ragged executable per
-        # batch bucket — the decode (ctx x batch), ragged-continuation, and
+        # batch bucket — the decode, dynamic-start-continuation, and
         # cached-admission-continuation ladders all collapse into it. Rides
         # the ragged kernel (rows fuse by pure layout, the kernel never
         # learns phases) and stays out of speculative engines (verify owns
@@ -1021,9 +1013,7 @@ class LLMEngine:
         self._step_kind = "decode"
         for s in running:
             self.cache.extend(s.req.req_id, 1)
-        Bb = self._batch_bucket(len(running))
-        _, decode = self._decode_for(self._max_ctx_blocks(running),
-                                     len(running))
+        Bb, decode = self._decode_for(len(running))
         self._note_dispatch_pad(running, Bb)
         a = self._res.refresh(self, running, Bb)  # tables re-up if grown
         rng = jax.random.fold_in(self._rng, self._step_count * 2)
@@ -1053,10 +1043,8 @@ class LLMEngine:
             # rides the decode dispatch, so the window pays its own
             self._flush_chunk()
             return
-        Bb = self._batch_bucket(len(running))
         n_exec = self.n_executables
-        _, decode = self._decode_for(self._max_ctx_blocks(running),
-                                     len(running))
+        Bb, decode = self._decode_for(len(running))
         self._note_dispatch_pad(running, Bb)
         a = self._res.refresh(self, running, Bb)
         tokens = np.zeros((Bb,), np.int32)
@@ -2098,28 +2086,25 @@ class LLMEngine:
             b *= 2
         return min(b, self.ecfg.max_num_seqs)
 
-    def _decode_for(self, m_blocks: int, n_active: int = -1):
-        """Decode executable for the smallest (context, batch) buckets
-        covering the running set."""
+    def _decode_for(self, n_active: int = -1):
+        """Decode executable for the smallest batch bucket covering the
+        running set: the rows it holds choose the program, nothing else."""
         if self._fused:
             return self._fused_decode_for(n_active)
-        m = next(b for b in self._ctx_buckets if b >= m_blocks)
         bb = (self.ecfg.max_num_seqs if n_active < 0
               else self._batch_bucket(n_active))
-        key = (m, bb)
-        if key not in self._decode_fns:
+        if bb not in self._decode_fns:
             _faults.get().raise_at(_faults.COMPILE)
             if self._warmed:
                 self.obs.count_recompile()
             # async engines compile the feedback variant (returns pos+1,
-            # donates the position buffer) into the SAME (ctx, batch)
-            # ladder — one executable per key either way
-            self._decode_fns[key] = make_decode(
+            # donates the position buffer) into the SAME ladder — one
+            # executable per batch bucket either way
+            self._decode_fns[bb] = make_decode(
                 self.cfg, self.ecfg.block_size, self.ecfg.blocks_per_seq,
-                bb, ctx_blocks=m, shardings=self.shardings,
-                feedback=self._async, ragged=self._ragged,
+                bb, shardings=self.shardings, feedback=self._async,
                 kv_quant=self._kv_quant)
-        return bb, self._decode_fns[key]
+        return bb, self._decode_fns[bb]
 
     # -- fused mixed-phase step (SHAI_FUSED_STEP) --------------------------
 
@@ -2128,8 +2113,8 @@ class LLMEngine:
         covering the running set: the decode rows plus ONE continuation-
         chunk window in a single ragged dispatch (runner.make_fused_step).
         Mirrors ``_decode_for``'s ladder discipline — one entry per batch
-        bucket; the context ladder is already collapsed by ragged, and the
-        chunk window is pinned to the largest prefill bucket."""
+        bucket; the chunk window is pinned to the largest prefill
+        bucket."""
         bb = (self.ecfg.max_num_seqs if n_active < 0
               else self._batch_bucket(n_active))
         if bb not in self._fused_fns:
@@ -2209,26 +2194,23 @@ class LLMEngine:
         if self._pending_chunk is not None:
             self._fused_chunk_call(*self._take_chunk_args())
 
-    def _verify_for(self, m_blocks: int, n_active: int = -1):
-        """Speculative verify executable for the smallest (context, batch)
-        buckets covering the running set — the same dispatch rule as
+    def _verify_for(self, n_active: int = -1):
+        """Speculative verify executable for the smallest batch bucket
+        covering the running set — the same dispatch rule as
         ``_decode_for``, k+1 scored positions per sequence."""
         from .runner import make_verify
 
-        m = next(b for b in self._ctx_buckets if b >= m_blocks)
         bb = (self.ecfg.max_num_seqs if n_active < 0
               else self._batch_bucket(n_active))
-        key = (m, bb)
-        if key not in self._verify_fns:
+        if bb not in self._verify_fns:
             _faults.get().raise_at(_faults.COMPILE)
             if self._warmed:
                 self.obs.count_recompile()
-            self._verify_fns[key] = make_verify(
+            self._verify_fns[bb] = make_verify(
                 self.cfg, self.ecfg.block_size, self.ecfg.blocks_per_seq,
-                bb, self.ecfg.num_speculative_tokens, ctx_blocks=m,
-                shardings=self.shardings, ragged=self._ragged,
-                kv_quant=self._kv_quant)
-        return bb, self._verify_fns[key]
+                bb, self.ecfg.num_speculative_tokens,
+                shardings=self.shardings, kv_quant=self._kv_quant)
+        return bb, self._verify_fns[bb]
 
     @property
     def n_executables(self) -> int:
@@ -2371,10 +2353,9 @@ class LLMEngine:
         """Pad-waste accounting for ONE decode/verify dispatch: ``real``
         is the context tokens the rows actually hold, ``padded`` the token
         slots the paged kernel walks beyond them. The kernel walks each
-        row's live tiles and nothing else, whatever the table's width
-        (bucketed and ragged dispatch alike: one body), so the pad is tile
-        rounding plus one tile of the null block per batch pad row; the
-        tile size is the kernel module's own (``tile_tokens``).
+        row's live tiles and nothing else, whatever the table's width, so
+        the pad is tile rounding plus one tile of the null block per batch
+        pad row; the tile size is the kernel module's own (``tile_tokens``).
         ``rows_per_seq``: the verify executable flattens ``k + 1`` query
         rows per sequence, each walking the row's tiles — both sides scale.
         Exported as ``shai_engine_pad_tokens_total``/``pad_fraction``.
@@ -2417,13 +2398,6 @@ class LLMEngine:
     def _running_slots(self) -> List["_Running"]:
         return [s for s in self.slots
                 if s is not None and s.prefill_cursor is None]
-
-    def _max_ctx_blocks(self, running) -> int:
-        m_blocks = 1
-        for s in running:
-            m_blocks = max(m_blocks, self.cache._blocks_needed(
-                self.cache.seq(s.req.req_id).n_tokens))
-        return m_blocks
 
     def _marshal_running(self, running, Bb: int) -> Dict[str, np.ndarray]:
         """Compact the active slots into the first ``len(running)`` batch
@@ -2500,8 +2474,7 @@ class LLMEngine:
         if not running:
             return True  # everything preempted away; step is done
         n_exec = self.n_executables
-        Bb, verify = self._verify_for(self._max_ctx_blocks(running),
-                                      len(running))
+        Bb, verify = self._verify_for(len(running))
         self._note_dispatch_pad(running, Bb, rows_per_seq=k + 1)
 
         # verify shares the device-resident batch view with decode: same
@@ -2626,8 +2599,7 @@ class LLMEngine:
             self._flush_chunk()  # chunk-only step: no decode to ride
             return
         n_exec = self.n_executables
-        Bb, decode = self._decode_for(self._max_ctx_blocks(running),
-                                      len(running))
+        Bb, decode = self._decode_for(len(running))
         self._note_dispatch_pad(running, Bb)
 
         a = self._marshal_running(running, Bb)

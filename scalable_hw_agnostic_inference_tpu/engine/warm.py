@@ -16,7 +16,7 @@ import numpy as np
 def warm_executables(eng, prefix_lens: Sequence[int] = (0,)) -> int:
     """Compile the engine's CLOSED executable set up front.
 
-    Every (prefill bucket, prefix_len) pair plus every context-bucket
+    Every (prefill bucket, prefix_len) pair plus every batch-bucket
     decode step is built here, so no post-ready request can trigger an
     XLA compile — the reference's warmup-gates-readiness idiom
     (``app/run-sd.py:144-146``) applied to the engine. Returns the number
@@ -92,17 +92,16 @@ def warm_executables(eng, prefix_lens: Sequence[int] = (0,)) -> int:
         batch_buckets.append(bb)
         bb *= 2
     batch_buckets.append(eng.ecfg.max_num_seqs)
-    for m in eng._ctx_buckets:
-        for bb in batch_buckets:
-            eng._decode_for(m, bb)
+    for bb in batch_buckets:
+        eng._decode_for(bb)
+        n += 1
+        if eng._drafter is not None:
+            # the speculative verify ladder mirrors decode's batch
+            # buckets: a post-ready verify dispatch must never compile
+            # (vanilla decode stays in the set too — the engine falls
+            # back to it whenever drafting comes up empty)
+            eng._verify_for(bb)
             n += 1
-            if eng._drafter is not None:
-                # the speculative verify ladder mirrors decode's (ctx,
-                # batch) grid: a post-ready verify dispatch must never
-                # compile (vanilla decode stays in the set too — the
-                # engine falls back to it whenever drafting comes up empty)
-                eng._verify_for(m, bb)
-                n += 1
     # force compilation (jit is lazy until first call) with null args
     eng._run_warm_calls()
     eng._warmed = True  # cached admission now refuses cold compiles
@@ -170,7 +169,7 @@ def _run_warm_calls(eng) -> None:
                      jnp.full((K,), max(eng.cross_seq_len, 1), jnp.int32)]
         eng.cache.kv, logits = fn(*args)
         warm_sampler(logits, per_row=P_ == 0)
-    for (m, bb), fn in list(eng._decode_fns.items()):
+    for bb, fn in list(eng._decode_fns.items()):
         # async engines warm the feedback variant through the same ladder
         # (one extra pos+1 output rides in *_rest; the donated position
         # buffer here is a warm-only throwaway)
@@ -212,7 +211,7 @@ def _run_warm_calls(eng) -> None:
         eng.cache.kv, nxt, *_rest = fn(*args)
         nxt.block_until_ready()
     K = eng.ecfg.num_speculative_tokens
-    for (m, bb), fn in list(eng._verify_fns.items()):
+    for bb, fn in list(eng._verify_fns.items()):
         args = [eng.params, eng.cache.kv,
                 jnp.zeros((bb, K + 1), jnp.int32),
                 jnp.zeros((bb,), jnp.int32), jnp.zeros((bb, M), jnp.int32),

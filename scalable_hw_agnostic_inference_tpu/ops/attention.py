@@ -268,10 +268,10 @@ def ragged_gather_attention(
 
     Every query ``(b, t)`` attends pool positions ``<= positions[b, t]``
     through row ``b``'s block table — mixed context lengths in one call,
-    no bucketing. This is THE deviceless oracle for the Pallas ragged
-    kernel (``ops.pallas.ragged_paged_attention``): a dense gather of the
-    table window plus a per-query mask, exactly the engine's pre-ragged
-    CPU decode path, so quant-off numerics are bit-identical to it. int8
+    no bucketing. This is THE deviceless oracle for the Pallas pool
+    kernel (``ops.pallas.paged_attention``): a dense gather of the
+    table window plus a per-query mask, exactly the engine's CPU decode
+    path, so quant-off numerics are bit-identical to it. int8
     pools dequantize right after the gather (``ops.quant``). Returns
     ``[B, T, H, D]``.
     """
@@ -323,15 +323,12 @@ def ragged_paged_attention(
     """Ragged paged attention with implementation dispatch: the Pallas
     kernel on TPU platforms, the XLA gather reference elsewhere (tier-1
     runs deviceless). Multi-token callers flatten ``T`` queries into the
-    row axis with per-row ``lengths``, the same layout both impls share
-    with the bucketed kernel."""
+    row axis with per-row ``lengths``, the layout both impls share."""
     if on_tpu_platform():
-        from .pallas.ragged_paged_attention import (
-            ragged_paged_attention as _kernel,
-        )
+        from .pallas.paged_attention import paged_decode_attention
 
-        return _kernel(q, k_pool, v_pool, tables, lengths, k_scale,
-                       v_scale, scale=scale)
+        return paged_decode_attention(q, k_pool, v_pool, tables, lengths,
+                                      k_scale, v_scale, scale=scale)
     out = ragged_gather_attention(
         q[:, None], k_pool, v_pool, tables,
         (lengths.astype(jnp.int32) - 1)[:, None], k_scale, v_scale,

@@ -20,7 +20,6 @@ import numpy as np
 from .attention import dot_product_attention, ragged_gather_attention
 from .pallas.flash_attention import flash_attention
 from .pallas.paged_attention import paged_decode_attention, tile_tokens
-from .pallas.ragged_paged_attention import ragged_paged_attention
 from .quant import quantize_kv_blocks
 
 #: max-abs error allowed against the oracle. Inputs are unit normal, so an
@@ -84,12 +83,15 @@ def _flash_case(H: int, Hkv: int, D: int, T: int, S: int,
         tol=TOL_BF16)
 
 
-def _pool_case(kind: str, H: int, Hkv: int, D: int, block_size: int,
+def _pool_case(H: int, Hkv: int, D: int, block_size: int,
                blocks_per_seq: int, rows: int, int8_kv: bool,
-               tile_edges: bool = False, window: int = 0) -> KernelCase:
-    """A paged-pool kernel (``kind``: ``paged`` bucketed decode, ``ragged``)
-    over ``rows`` single-query rows with mixed context lengths and shuffled
-    block tables.
+               tile_edges: bool = False, window: int = 0,
+               one_seq: bool = False) -> KernelCase:
+    """The paged-pool kernel over ``rows`` single-query rows with shuffled
+    block tables: rows of mixed context lengths, each with its own table
+    (a decode step), or, ``one_seq``, ``rows`` consecutive queries of ONE
+    sequence flattened a query a row, all sharing its table (what
+    speculative verify and the dynamic-start continuation dispatch).
 
     ``tile_edges`` is what a kernel that walks several pool blocks a tile
     can get wrong: lengths on both sides of a tile's edge, and every pool
@@ -102,12 +104,18 @@ def _pool_case(kind: str, H: int, Hkv: int, D: int, block_size: int,
     window's lower edge meets a tile's), and the poison also fills every
     block wholly BELOW a row's window: a tile the kernel should have
     skipped, or a block of the edge tile it should not have copied."""
-    kern = {"paged": paged_decode_attention,
-            "ragged": ragged_paged_attention}[kind]
     L = blocks_per_seq * block_size
     t = tile_tokens(block_size, Hkv, D,
                     jnp.int8 if int8_kv else jnp.bfloat16)
-    if window and tile_edges:
+    if one_seq:
+        # consecutive queries that straddle the tile's edge, the window,
+        # or both; else mid-table
+        if window:
+            mid = window + (t if tile_edges else 0)
+        else:
+            mid = t if tile_edges else L // 2 + 5
+        lens = [mid - rows // 2 + 1 + i for i in range(rows)]
+    elif window and tile_edges:
         # the window's lower edge on a tile's edge, one past it, one short
         lens = [window + t, window + t + 1, window + t - 1, window + 1,
                 window + 2 * t + block_size + 3, L, L - 1, window]
@@ -119,8 +127,8 @@ def _pool_case(kind: str, H: int, Hkv: int, D: int, block_size: int,
     else:
         # one token, a partial second block, mid-window, the full window
         lens = [1, block_size + 3, L // 2 + 5, L]
-    lens = [min(max(n, 1), L) for n in lens]
     lens = (lens * -(-rows // len(lens)))[:rows]
+    lens = [min(max(n, 1), L) for n in lens]
     n_blocks = rows * blocks_per_seq + 1          # + the reserved block 0
 
     def make(key):
@@ -131,6 +139,8 @@ def _pool_case(kind: str, H: int, Hkv: int, D: int, block_size: int,
         # every row owns distinct physical blocks, in shuffled order
         tables = 1 + jax.random.permutation(kt, n_blocks - 1).reshape(
             rows, blocks_per_seq).astype(jnp.int32)
+        if one_seq:
+            tables = jnp.repeat(tables[:1], rows, axis=0)
         q = jax.random.normal(kq, (rows, H, D), jnp.bfloat16)
         n = jnp.asarray(lens, jnp.int32)
         poison = lambda x: x                          # noqa: E731
@@ -164,13 +174,14 @@ def _pool_case(kind: str, H: int, Hkv: int, D: int, block_size: int,
             window=window)[:, 0]
 
     return KernelCase(
-        name=(f"{kind}-H{H}x{Hkv}-bs{block_size}-M{blocks_per_seq}-b{rows}"
+        name=(f"paged-H{H}x{Hkv}-bs{block_size}-M{blocks_per_seq}-b{rows}"
               f"-{'int8kv' if int8_kv else 'bf16'}"
               f"{'-edges' if tile_edges else ''}"
-              f"{f'-w{window}' if window else ''}"),
+              f"{f'-w{window}' if window else ''}"
+              f"{'-oneseq' if one_seq else ''}"),
         make_inputs=make,
-        kernel=lambda *a, interpret: kern(*a, interpret=interpret,
-                                          window=window),
+        kernel=lambda *a, interpret: paged_decode_attention(
+            *a, interpret=interpret, window=window),
         oracle=oracle, tol=TOL_INT8_KV if int8_kv else TOL_BF16)
 
 
@@ -182,15 +193,15 @@ def engine_cases(n_heads: int, n_kv_heads: int, head_dim: int, *,
                  window: int = 0) -> List[KernelCase]:
     """The kernel calls an engine of this geometry dispatches, per TP shard:
     flash at each prefill bucket (widest prefill batch) and at the first
-    continuation start, then paged decode and ragged over the full block
-    table, bf16 and int8-KV: ``max_num_seqs`` rows of mixed lengths, then
-    8 rows (the benchmark cells' batch) at the tile's edges over a
-    NaN-poisoned pool. ``window`` (a model with window layers) adds the
-    same calls under the window: flash at the top bucket and at the
-    continuation start that crosses the window's edge, paged and ragged
-    (bf16: the boot refuses int8 KV with window layers) at, just below
-    and far above the window, and at the tile's edges over the poisoned
-    pool."""
+    continuation start, then the paged kernel over the full block table,
+    bf16 and int8-KV, a row a sequence and as one sequence's consecutive
+    queries: ``max_num_seqs`` rows of mixed lengths, then 8 rows (the
+    benchmark cells' batch) at the tile's edges over a NaN-poisoned pool.
+    ``window`` (a model with window layers) adds the same calls under the
+    window: flash at the top bucket and at the continuation start that
+    crosses the window's edge, the paged kernel in both layouts (bf16:
+    the boot refuses int8 KV with window layers) at, just below and far
+    above the window, and at the tile's edges over the poisoned pool."""
     H, Hkv = n_heads // tp, n_kv_heads // tp
     M = max_model_len // block_size
     top = max(buckets)
@@ -199,11 +210,12 @@ def engine_cases(n_heads: int, n_kv_heads: int, head_dim: int, *,
     if max_model_len >= 2 * top:
         cases.append(_flash_case(H, Hkv, head_dim, top, 2 * top, 1))
     for int8_kv in (False, True):
-        for kind in ("paged", "ragged"):
-            cases.append(_pool_case(kind, H, Hkv, head_dim, block_size, M,
-                                    max_num_seqs, int8_kv))
-            cases.append(_pool_case(kind, H, Hkv, head_dim, block_size, M,
-                                    8, int8_kv, tile_edges=True))
+        for one_seq in (False, True):
+            cases.append(_pool_case(H, Hkv, head_dim, block_size, M,
+                                    max_num_seqs, int8_kv, one_seq=one_seq))
+            cases.append(_pool_case(H, Hkv, head_dim, block_size, M, 8,
+                                    int8_kv, tile_edges=True,
+                                    one_seq=one_seq))
     if window:
         cases.append(_flash_case(H, Hkv, head_dim, top, top,
                                  max_prefill_batch, window))
@@ -212,10 +224,10 @@ def engine_cases(n_heads: int, n_kv_heads: int, head_dim: int, *,
         if max_model_len >= prior + top:
             cases.append(_flash_case(H, Hkv, head_dim, top, prior + top, 1,
                                      window))
-        for kind in ("paged", "ragged"):
-            cases.append(_pool_case(kind, H, Hkv, head_dim, block_size, M,
-                                    8, False, window=window))
-            cases.append(_pool_case(kind, H, Hkv, head_dim, block_size, M,
-                                    8, False, tile_edges=True,
-                                    window=window))
+        for one_seq in (False, True):
+            cases.append(_pool_case(H, Hkv, head_dim, block_size, M, 8,
+                                    False, window=window, one_seq=one_seq))
+            cases.append(_pool_case(H, Hkv, head_dim, block_size, M, 8,
+                                    False, tile_edges=True, window=window,
+                                    one_seq=one_seq))
     return cases

@@ -274,12 +274,34 @@ def _column_scales(scales, i, length, hkv: int, tile: int, block_size: int):
     return [spread(sc) for sc in scales]
 
 
-def pool_attention(name: str, q, k_pool, v_pool, tables, lengths,
-                   k_scale=None, v_scale=None, *, scale=None,
-                   interpret=None, window: int = 0) -> jax.Array:
-    """The one paged-pool kernel call behind both entry points
-    (``paged_decode_attention`` here, ``ragged_paged_attention`` beside
-    it). ``name`` is the device op's name in a trace. ``window`` (static;
+@functools.partial(jax.jit,
+                   static_argnames=("scale", "interpret", "window"))
+def paged_decode_attention(
+    q: jax.Array,           # [B, H, D] one query token per row
+    k_pool: jax.Array,      # [N, block_size, Hkv, D] the paged pool
+    v_pool: jax.Array,
+    tables: jax.Array,      # [B, M] physical block ids (0-padded)
+    lengths: jax.Array,     # [B] valid token count per row
+    k_scale: Optional[jax.Array] = None,   # [N, Hkv] f32 (int8 pools)
+    v_scale: Optional[jax.Array] = None,
+    *,
+    scale: Optional[float] = None,
+    interpret: Optional[bool] = None,
+    window: int = 0,
+) -> jax.Array:
+    """Attend each row's query over its paged context. Returns ``[B, H, D]``.
+
+    The one entry point of the pool kernel. A row walks
+    ``cdiv(lengths[b], tile)`` tiles of its table and never looks past
+    them, so the table's width only bounds what a row may hold. A row of
+    length 0 (an inactive or pad slot, a table of zeros) walks one tile of
+    the null block and returns finite values. Multi-token callers
+    (speculative verify, the dynamic-start continuation, the fused step)
+    flatten their ``T`` queries into the batch axis with per-query lengths:
+    the kernel never learns which phase a row belongs to.
+
+    ``k_scale``/``v_scale``: per-block x kv-head f32 scales of an int8 pool
+    (``SHAI_KV_QUANT=int8``), dequantized in-kernel. ``window`` (static;
     0 = none): a row's query sees its last ``window`` keys only — the
     tiles wholly below ``lengths[b] - window`` are neither copied nor
     computed, and the tile that edge cuts is masked below it."""
@@ -335,36 +357,5 @@ def pool_attention(name: str, q, k_pool, v_pool, tables, lengths,
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary",)),
         interpret=interpret,
-        name=name,
+        name="paged_decode_attention",
     )(tables.astype(jnp.int32), lengths.astype(jnp.int32), *args)
-
-
-@functools.partial(jax.jit,
-                   static_argnames=("scale", "interpret", "window"))
-def paged_decode_attention(
-    q: jax.Array,           # [B, H, D] one query token per sequence
-    k_pool: jax.Array,      # [N, block_size, Hkv, D] the paged pool
-    v_pool: jax.Array,
-    tables: jax.Array,      # [B, M] physical block ids (0-padded)
-    lengths: jax.Array,     # [B] valid token count per sequence
-    k_scale: Optional[jax.Array] = None,   # [N, Hkv] f32 (int8 pools)
-    v_scale: Optional[jax.Array] = None,
-    *,
-    scale: Optional[float] = None,
-    interpret: Optional[bool] = None,
-    window: int = 0,
-) -> jax.Array:
-    """Attend each row's query over its paged context. Returns ``[B, H, D]``.
-
-    ``tables`` may be pre-truncated to a context bucket (``tables[:, :m]``,
-    any ``m``): a row walks ``cdiv(lengths[b], tile)`` tiles of its table
-    and never looks past them, so the width only bounds what a row may
-    hold. A row of length 0 (an inactive or pad slot, a table of zeros)
-    walks one tile of the null block and returns finite values.
-
-    ``k_scale``/``v_scale``: per-block x kv-head f32 scales of an int8 pool
-    (``SHAI_KV_QUANT=int8``), dequantized in-kernel.
-    """
-    return pool_attention("paged_decode_attention", q, k_pool, v_pool,
-                          tables, lengths, k_scale, v_scale, scale=scale,
-                          interpret=interpret, window=window)

@@ -330,8 +330,8 @@ def _paged_decode(cfg, name: str, *, quant: bool, batch: int, ctx: int,
     else:
         sh = None
         s = _repl(topo.device_mesh(1))
-    fn = make_decode(cfg, block_size, m_ctx, batch, ctx_blocks=m_ctx,
-                     shardings=sh, paged=True)
+    fn = make_decode(cfg, block_size, m_ctx, batch, shardings=sh,
+                     paged=True)
 
     def aval(shape, dtype):
         if s is None:
@@ -389,8 +389,7 @@ def wl_vllm_verify(geometry: str = "1b", *, k: int = 4, quant: bool = False,
     params_avals = topo.abstract_params(
         lambda: llama_mod.geometry_params(cfg, quant=quant))
     s = _repl(topo.device_mesh(1))
-    fn = make_verify(cfg, block_size, m_ctx, batch, k, ctx_blocks=m_ctx,
-                     paged=True)
+    fn = make_verify(cfg, block_size, m_ctx, batch, k, paged=True)
 
     def aval(shape, dtype):
         return jax.ShapeDtypeStruct(shape, dtype, sharding=s)

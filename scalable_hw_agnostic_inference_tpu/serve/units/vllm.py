@@ -210,7 +210,6 @@ class VllmService(ModelService):
                     model=model_id or "tiny", max_model_len=256,
                     max_num_seqs=ecfg.max_num_seqs,
                     block_size=16, context_encoding_buckets=(32, 64, 128),
-                    token_generation_buckets=ecfg.token_generation_buckets,
                     tensor_parallel_size=ecfg.tensor_parallel_size,
                     quantization=ecfg.quantization,
                     enable_prefix_caching=ecfg.enable_prefix_caching,
@@ -1238,11 +1237,22 @@ class VllmService(ModelService):
         def chunks():
             first = True
             finish = None
+            # the drain can close a generator only between pulls, so one
+            # that waits (a queued request) or holds bytes back (a partial
+            # character) without yielding cannot be cancelled by a client
+            # that went away: after a second with nothing to send, hand
+            # back an empty turn (the drain sends no empty chunk). A stream
+            # whose tokens flow never takes one
+            quiet_s = 1.0
+            t_turn = _time.monotonic()
             try:
                 if kind == "chat":
                     yield event("", None, True)  # role preamble chunk
                     first = False
                 while True:
+                    if _time.monotonic() - t_turn >= quiet_s:
+                        yield ""
+                        t_turn = _time.monotonic()
                     try:
                         tok = tokq.get(timeout=0.2)
                     except _q.Empty:
@@ -1253,6 +1263,7 @@ class VllmService(ModelService):
                     if delta:
                         yield event(delta, None, first)
                         first = False
+                        t_turn = _time.monotonic()
                     if asm.stopped:
                         # the engine would decode to max_new_tokens for
                         # nobody — abort and reclaim the slot/blocks

@@ -31,7 +31,7 @@ STEPS = 50
 
 def bench(cfg, params, kv, ctx_blocks, n_active, paged):
     M = ctx_blocks
-    fn = make_decode(cfg, BS, M, B, ctx_blocks=M, paged=paged)
+    fn = make_decode(cfg, BS, M, B, paged=paged)
     rng = np.random.default_rng(0)
     tables = np.zeros((B, M), np.int32)
     pos = np.zeros((B,), np.int32)

@@ -222,8 +222,8 @@ def test_window_kernel_agrees_with_its_oracle(case):
 def test_window_cases_cover_the_kernels():
     names = [c.name for c in _window_cases()]
     assert sum(n.startswith("flash") for n in names) == 2
-    assert sum(n.startswith("paged") for n in names) == 2
-    assert sum(n.startswith("ragged") for n in names) == 2
+    assert sum(n.startswith("paged") for n in names) == 4
+    assert sum(n.endswith("-oneseq") for n in names) == 2
     assert any("-edges" in n for n in names)
 
 

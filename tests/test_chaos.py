@@ -228,25 +228,31 @@ async def test_client_disconnect_mid_stream_cancels_engine(stack):
     generator is closed (its finally runs ``loop.cancel``), the KV blocks
     free, and the engine does NOT decode to max_new_tokens for a dead
     socket. Driven through the real app with a fake ASGI ``receive`` that
-    injects ``http.disconnect`` after a few chunks."""
+    injects ``http.disconnect`` once the ENGINE has taken a few decode
+    steps for the request, whether or not a delta has reached the client:
+    the tiny model's greedy continuation of this prompt is bytes the
+    detokenizer holds back to the end, so no visible chunk flows before
+    the disconnect, which is the hard case."""
     import json as _json
 
     cfg, service, app = stack
     async with make_client(app) as c:
         await wait_ready(c, timeout=300.0)
 
-    faults.configure("engine.step=delay(0.05)")  # ~3s full generation
-    # prompt chosen because the tiny byte-tokenizer model's greedy
-    # continuation decodes to visible text ("Z"*n) — deltas actually flow
+    max_tokens, after = 60, 5
+    faults.configure("engine.step=delay(0.1)")   # 6 s if it ran to the end
     body = _json.dumps({"prompt": "aaaa", "stream": True,
-                        "max_tokens": 60, "temperature": 0.0}).encode()
+                        "max_tokens": max_tokens,
+                        "temperature": 0.0}).encode()
     scope = {"type": "http", "method": "POST", "path": "/v1/completions",
              "query_string": b"", "headers": [
                  (b"content-type", b"application/json"),
                  (b"content-length", str(len(body)).encode())]}
+    eng = service._engine
     disconnect = asyncio.Event()
     sent_body = False
     chunks = []
+    inflight_seen = []
 
     async def receive():
         nonlocal sent_body
@@ -256,25 +262,36 @@ async def test_client_disconnect_mid_stream_cancels_engine(stack):
         await disconnect.wait()
         return {"type": "http.disconnect"}
 
-    inflight_seen = []
-
     async def send(message):
         if message["type"] == "http.response.body" and message.get("body"):
             chunks.append(message["body"])
-            # a LIVE stream counts against the in-flight gauge (it holds
-            # engine work) — not just until the handler returned
-            inflight_seen.append(app.state["status"]["inflight"])
-            if len(chunks) >= 3:
-                disconnect.set()   # client "goes away" mid-stream
 
-    t0 = time.monotonic()
+    def generated():
+        return max((len(s.generated) for s in eng.slots if s is not None),
+                   default=0)
+
+    async def client_goes_away():
+        # the engine's progress decides when, not the wall clock
+        while generated() < after:
+            await asyncio.sleep(0.01)
+        # a LIVE stream counts against the in-flight gauge (it holds
+        # engine work) — not just until the handler returned
+        inflight_seen.append(app.state["status"]["inflight"])
+        disconnect.set()
+
+    steps0 = eng.obs.snapshot()["steps"]
+    away = asyncio.ensure_future(client_goes_away())
     await asyncio.wait_for(app(scope, receive, send), timeout=30.0)
-    # the request must have been aborted early, not decoded to the end
-    assert 3 <= len(chunks) < 50, f"stream ran to completion? {len(chunks)}"
-    assert not any(b"[DONE]" in ch for ch in chunks)
-    assert inflight_seen and max(inflight_seen) >= 1
+    await asyncio.wait_for(away, timeout=5.0)
     _assert_engine_clean(service)
-    assert time.monotonic() - t0 < 10.0
+    # the request was aborted early: every decode step makes one token of
+    # this lone request, and it took far fewer than max_tokens of them
+    steps = eng.obs.snapshot()["steps"] - steps0
+    assert after <= steps < max_tokens - 10, (
+        f"engine decoded to the end for a dead socket? {steps} steps")
+    assert not any(b"[DONE]" in ch for ch in chunks)
+    assert not any(b"finish_reason\": \"length" in ch for ch in chunks)
+    assert inflight_seen == [1]
     # the abort released the in-flight slot (generator finally ran)
     deadline = time.monotonic() + 5.0
     while (app.state["status"]["inflight"] > 0

@@ -321,17 +321,16 @@ def test_engine_warm_executables_closed_set(tiny_model):
     """warm_executables compiles the full closed set; a post-warm request mix
     spanning every bucket adds NO new executables (VERDICT r1 weak#2)."""
     cfg, _, params = tiny_model
-    eng = make_engine((cfg, None, params),
-                      token_generation_buckets=(16, 64))
+    eng = make_engine((cfg, None, params))
     n = eng.warm_executables(prefix_lens=(0, 6))
     count = eng.n_executables
     assert n == count
     # buckets (16, 32) x prefill batch {1, 2} (max_num_seqs=3 caps the
     # power-of-two ladder) = 4, plus buckets x prefix 6 at K=1 = 2,
-    # plus ctx buckets {2, 8} x decode batch buckets {1, 2, 3} = 6,
+    # plus decode batch buckets {1, 2, 3} = 3,
     # plus the chunked-prefill continuation at start=32 (max_model_len 64
     # exceeds the largest bucket) = 1
-    assert count == 13
+    assert count == 10
     prompts = [[1, 2, 3], list(range(2, 20)), [7] * 30]
     eng.generate(prompts, SamplingParams(temperature=0.0, max_new_tokens=12))
     assert eng.n_executables == count, "post-warm request compiled a new executable"
@@ -396,18 +395,22 @@ def test_a_saturated_tp_engine_streams_without_a_second_compile(tiny_model):
     assert not ENGINE_FNS & set(compiled), compiled
 
 
-def test_engine_decode_ctx_bucket_dispatch(tiny_model):
-    """Decode picks the smallest context bucket covering the longest seq."""
+def test_engine_decode_keyed_by_batch_bucket_alone(tiny_model):
+    """A decode program is chosen by the rows it holds: a longer sequence
+    after a short one runs the program the short one ran."""
     cfg, _, params = tiny_model
-    eng = make_engine((cfg, None, params),
-                      token_generation_buckets=(16,), max_model_len=64)
-    assert eng._ctx_buckets == [2, 8]  # 16 tokens / bs 8, and 64/8
+    eng = make_engine((cfg, None, params), max_model_len=64)
+    eng.warm_executables()
+    assert sorted(eng._decode_fns) == [1, 2, 3]   # max_num_seqs 3
+    n_exec = eng.n_executables
     sp = SamplingParams(temperature=0.0, max_new_tokens=4)
-    [f] = eng.generate([[1, 2, 3]], sp)   # 3+4 tokens fit the 2-block bucket
-    # (ctx_bucket, batch_bucket): one sequence -> batch bucket 1
-    assert list(eng._decode_fns) == [(2, 1)]
-    [f] = eng.generate([list(range(2, 20))], sp)  # 18+4 tokens need 8 blocks
-    assert sorted(eng._decode_fns) == [(2, 1), (8, 1)]
+    [f] = eng.generate([[1, 2, 3]], sp)           # 7 tokens: one block
+    one = eng._decode_for(1)
+    [f] = eng.generate([list(range(2, 20))], sp)  # 22 tokens: three blocks
+    assert eng._decode_for(1) == one
+    assert sorted(eng._decode_fns) == [1, 2, 3]
+    assert eng.n_executables == n_exec
+    assert eng.obs.snapshot()["recompiles"] == 0
 
 
 @pytest.mark.slow  # tier-1 budget: see scripts/check_tier1_budget.py

@@ -52,8 +52,7 @@ def make_engine(tiny_model, monkeypatch, *, fused=False, ragged=True,
     monkeypatch.setenv("SHAI_KV_QUANT", "int8" if quant else "")
     monkeypatch.setenv("SHAI_KV_COW", "1" if cow else "0")
     kw = dict(max_model_len=128, max_num_seqs=3, block_size=8,
-              context_encoding_buckets=(16, 32),
-              token_generation_buckets=(32, 64), max_new_tokens=16)
+              context_encoding_buckets=(16, 32), max_new_tokens=16)
     kw.update(over)
     eng = LLMEngine(cfg, params, EngineConfig(**kw))
     assert eng._fused is (fused and ragged)

@@ -446,10 +446,10 @@ def test_rejected_request_books_wait_as_queue_not_decode(tiny_model):
 
 def test_post_warm_executable_build_counts_as_recompile(tiny_model):
     eng = make_engine(tiny_model)
-    eng._decode_for(1, 1)
+    eng._decode_for(1)
     assert eng.obs.recompiles == 0  # pre-warm builds are the closed set
     eng._warmed = True
-    eng._decode_for(1, 2)
+    eng._decode_for(2)
     eng._prefill_for(16, 0, 2)
     assert eng.obs.recompiles == 2
 

@@ -33,8 +33,8 @@ from scalable_hw_agnostic_inference_tpu.ops.attention import (
     ragged_gather_attention,
     ragged_paged_attention,
 )
-from scalable_hw_agnostic_inference_tpu.ops.pallas.ragged_paged_attention import (  # noqa: E501
-    ragged_paged_attention as ragged_kernel,
+from scalable_hw_agnostic_inference_tpu.ops.pallas.paged_attention import (
+    paged_decode_attention,
 )
 from scalable_hw_agnostic_inference_tpu.ops.quant import (
     dequantize_kv_blocks,
@@ -101,7 +101,7 @@ def test_requantize_single_token_into_empty_block():
 
 
 # ---------------------------------------------------------------------------
-# ops: ragged kernel (interpret) vs the XLA gather reference
+# ops: the pool kernel (interpret) vs the XLA gather reference
 # ---------------------------------------------------------------------------
 
 def _pool_fixture(quant):
@@ -124,7 +124,8 @@ def test_ragged_kernel_matches_gather_reference(quant):
     q, kp, vp, ks, vs, tables, lengths = _pool_fixture(quant)
     ref = ragged_gather_attention(q[:, None], kp, vp, tables,
                                   (lengths - 1)[:, None], ks, vs)[:, 0]
-    out = ragged_kernel(q, kp, vp, tables, lengths, ks, vs, interpret=True)
+    out = paged_decode_attention(q, kp, vp, tables, lengths, ks, vs,
+                                 interpret=True)
     np.testing.assert_allclose(np.asarray(out), np.asarray(ref),
                                rtol=2e-5, atol=2e-5)
 
@@ -138,17 +139,16 @@ def test_ragged_dispatcher_uses_reference_on_cpu():
 
 
 def test_bucketed_paged_kernel_accepts_int8_pool():
-    # the bucketed entry point shares the ragged kernel body for int8
-    # pools ("dequantize in-kernel in BOTH ragged and bucketed attention")
-    from scalable_hw_agnostic_inference_tpu.ops.pallas.paged_attention import (  # noqa: E501
-        paged_decode_attention,
-    )
-
+    # what a context bucket used to hand the kernel: an int8 pool behind a
+    # table cut to the rows' live blocks reads as behind the full table (a
+    # row walks its own tiles, so the width chooses nothing)
     q, kp, vp, ks, vs, tables, lengths = _pool_fixture(True)
-    out = paged_decode_attention(q, kp, vp, tables, lengths, ks, vs,
-                                 interpret=True)
-    ref = ragged_kernel(q, kp, vp, tables, lengths, ks, vs, interpret=True)
-    np.testing.assert_allclose(np.asarray(out), np.asarray(ref))
+    rows = slice(1, 3)                  # 11 and 3 tokens: two blocks hold them
+    full = paged_decode_attention(q[rows], kp, vp, tables[rows],
+                                  lengths[rows], ks, vs, interpret=True)
+    cut = paged_decode_attention(q[rows], kp, vp, tables[rows, :2],
+                                 lengths[rows], ks, vs, interpret=True)
+    np.testing.assert_allclose(np.asarray(cut), np.asarray(full))
 
 
 # ---------------------------------------------------------------------------
@@ -170,8 +170,7 @@ def make_engine(tiny_model, monkeypatch, *, ragged=False, quant=False,
     monkeypatch.setenv("SHAI_RAGGED_ATTENTION", "1" if ragged else "0")
     monkeypatch.setenv("SHAI_KV_QUANT", "int8" if quant else "")
     kw = dict(max_model_len=128, max_num_seqs=3, block_size=8,
-              context_encoding_buckets=(16, 32),
-              token_generation_buckets=(32, 64), max_new_tokens=16)
+              context_encoding_buckets=(16, 32), max_new_tokens=16)
     kw.update(over)
     eng = LLMEngine(cfg, params, EngineConfig(**kw))
     assert eng._ragged is ragged
@@ -274,16 +273,16 @@ def test_ragged_speculative_fallback_parity(tiny_model, monkeypatch):
 
 @pytest.mark.slow  # tier-1 budget: see scripts/check_tier1_budget.py
 def test_ragged_ladder_shrinks_and_stays_closed(tiny_model, monkeypatch):
-    # the measurable tentpole claim: fewer decode executables at warm, and
-    # the warmed set stays closed over a mixed-length run (no post-ready
-    # compiles — the cold-graph-behind-the-LB discipline)
+    # the measurable claim: fewer continuation executables at warm (decode
+    # is one program a batch bucket either way), and the warmed set stays
+    # closed over a mixed-length run (no post-ready compiles — the
+    # cold-graph-behind-the-LB discipline)
     kw = dict(max_model_len=128, enable_prefix_caching=True)
     a = make_engine(tiny_model, monkeypatch, ragged=True, **kw)
     b = make_engine(tiny_model, monkeypatch, ragged=False, **kw)
     a.warm_executables()
     b.warm_executables()
-    assert len(a._ctx_buckets) == 1
-    assert len(a._decode_fns) < len(b._decode_fns)
+    assert sorted(a._decode_fns) == sorted(b._decode_fns) == [1, 2, 3]
     assert a.n_executables < b.n_executables
     sp = SamplingParams(temperature=0.0, max_new_tokens=6)
     rng = np.random.default_rng(9)
@@ -299,16 +298,14 @@ def test_pad_accounting_ragged_equals_bucketed(tiny_model, monkeypatch):
     fracs = {}
     for ragged in (True, False):
         eng = make_engine(tiny_model, monkeypatch, ragged=ragged)
-        # the ladder claim, cheaply: ragged owns ONE context bucket
-        assert len(eng._ctx_buckets) == (1 if ragged else 3)
         eng.generate(MIXED, sp)
         snap = eng.obs.snapshot()
         assert snap["real_tokens"] > 0
         assert snap["pad_tokens"] >= 0
         assert 0.0 <= snap["pad_fraction"] < 1.0
         fracs[ragged] = snap["pad_fraction"]
-    # one kernel body behind both dispatches: a row walks its live tiles
-    # whatever the table's width, so the bucket ladder pads nothing more
+    # the flag chooses the continuation alone: decode dispatches are the
+    # same programs on the same rows, so they pad the same
     assert fracs[True] == fracs[False]
 
 

@@ -118,17 +118,18 @@ def test_accept_drafts_rejection_sampling_uses_masked_resample():
 # config contract
 # ---------------------------------------------------------------------------
 
-def test_token_generation_buckets_validated():
-    kw = dict(max_model_len=256, block_size=16,
-              context_encoding_buckets=(64, 128))
-    ok = EngineConfig(token_generation_buckets=(64, 256), **kw)
-    assert ok.token_generation_buckets == (64, 256)
-    with pytest.raises(ValueError):  # exceeds max_model_len
-        EngineConfig(token_generation_buckets=(64, 512), **kw)
-    with pytest.raises(ValueError):  # not block-aligned
-        EngineConfig(token_generation_buckets=(60,), **kw)
-    with pytest.raises(ValueError):  # non-positive
-        EngineConfig(token_generation_buckets=(0,), **kw)
+def test_token_generation_buckets_is_an_ignored_key():
+    """A manifest that still carries the window ladder boots: the key is
+    recorded as ignored, whatever it holds, and names no field."""
+    d = dict(max_model_len=256, block_size=16,
+             context_encoding_buckets=[64, 128])
+    for tg in ([64, 256], [64, 512], [60], [0]):
+        cfg = EngineConfig.from_dict(dict(d, token_generation_buckets=tg))
+        assert cfg.ignored_keys == ("token_generation_buckets",)
+        assert not hasattr(cfg, "token_generation_buckets")
+        assert cfg == EngineConfig.from_dict(d)
+    with pytest.raises(TypeError):
+        EngineConfig(token_generation_buckets=(64,))
 
 
 def test_speculative_config_knobs():

@@ -243,7 +243,7 @@ class TestDonation:
         m = mod("engine/engine.py", """\
             class Engine:
                 def _decode_step(self):
-                    _, decode = self._decode_for(4, 2)
+                    _, decode = self._decode_for(2)
                     args = [self.params, self.cache.kv]
                     args += [self.tokens, self.pos_dev]
                     out = decode(*args)
@@ -262,7 +262,7 @@ class TestDonation:
         m = mod("engine/engine.py", """\
             class Engine:
                 def _decode_step(self):
-                    _, decode = self._decode_for(4, 2)
+                    _, decode = self._decode_for(2)
                     args = [self.params, self.cache.kv, self.tokens,
                             self.pos_dev]
                     self.cache.kv, nxt, pos = decode(*args)
