@@ -137,6 +137,7 @@ def _decode_args(cfg, rep=None, shardings=None, quant: bool = False):
             _sds((B, BPS), jnp.int32, rep),    # tables
             _sds((B,), jnp.bool_, rep),        # active
             _sds((2,), jnp.uint32, rep),       # rng
+            _sds((), jnp.int32, rep),          # fold
             _sds((B,), jnp.float32, rep),      # temperature
             _sds((B,), jnp.int32, rep),        # top_k
             _sds((B,), jnp.float32, rep))      # top_p
@@ -292,6 +293,7 @@ def _build_verify(key: str) -> IrProgram:
             _sds((B, BPS), jnp.int32),
             _sds((B,), jnp.bool_),
             _sds((2,), jnp.uint32),
+            _sds((), jnp.int32),
             _sds((B,), jnp.float32),
             _sds((B,), jnp.int32),
             _sds((B,), jnp.float32))

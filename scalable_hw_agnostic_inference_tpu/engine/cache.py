@@ -13,6 +13,7 @@ sees fragmentation: gathers go through block tables.
 from __future__ import annotations
 
 import dataclasses
+import itertools
 import logging
 from typing import Dict, List, Optional, Sequence, Tuple
 
@@ -86,13 +87,32 @@ class BlockAllocator:
                 self._free.append(b)
 
 
+#: stamps for :attr:`SeqAllocation.version`: one counter for every
+#: allocation, so no two block lists ever carry the same stamp — a request
+#: preempted and re-admitted under its id gets a NEW allocation, and a new
+#: stamp with it
+_VERSIONS = itertools.count(1)
+
+
 @dataclasses.dataclass
 class SeqAllocation:
-    """Host bookkeeping for one running sequence."""
+    """Host bookkeeping for one running sequence.
+
+    ``version`` names the block LIST as it stands: every mutation of
+    ``blocks`` (a grow that allocates, a shrink that frees, a copy-on-write
+    swap) goes through :meth:`touch` and takes a fresh stamp, so a reader
+    that kept ``(seq_id, version)`` knows in O(1) whether the table it
+    built from this allocation is still true — block IDENTITY, not count:
+    a shrink-then-regrow that swaps two rows' blocks moves both stamps."""
 
     seq_id: int
     blocks: List[int]
     n_tokens: int = 0
+    version: int = dataclasses.field(
+        default_factory=lambda: next(_VERSIONS))
+
+    def touch(self) -> None:
+        self.version = next(_VERSIONS)
 
     def table(self, blocks_per_seq: int) -> np.ndarray:
         t = np.zeros((blocks_per_seq,), np.int32)
@@ -639,6 +659,7 @@ class PagedKVCache:
                 lay[name] = self._cow_copy(lay[name], s, d)
         self.allocator.free([src])
         alloc.blocks[idx] = dst
+        alloc.touch()
         self.cow_copies += 1
 
     def _cow_pending(self, alloc: SeqAllocation) -> bool:
@@ -685,6 +706,7 @@ class PagedKVCache:
             if len(alloc.blocks) + need > self.blocks_per_seq:
                 raise MemoryError(f"seq {seq_id} exceeds max_model_len")
             alloc.blocks.extend(self._alloc(need))
+            alloc.touch()
         alloc.n_tokens += n_new
         return alloc
 
@@ -712,6 +734,7 @@ class PagedKVCache:
         if keep < len(alloc.blocks):
             tail = alloc.blocks[keep:]
             del alloc.blocks[keep:]
+            alloc.touch()
             self.allocator.free(tail)
             self.rollback_blocks += len(tail)
         return alloc

@@ -7,7 +7,9 @@ explicitly — they are the same code paths, re-homed.
 
 from __future__ import annotations
 
+import jax
 import jax.numpy as jnp
+import numpy as np
 
 from .types import Request
 
@@ -19,8 +21,8 @@ def _set_slot_cross(eng, slot: int, req: Request):
     if req.cross_states is None:
         eng._has_image[slot] = 0.0
         eng._cross_len[slot] = Lv
-        return (eng._cross_zeros(1), jnp.zeros((1,), jnp.float32),
-                jnp.full((1,), Lv, jnp.int32))
+        return (eng._cross_zeros(1), eng._put(np.zeros((1,), np.float32)),
+                eng._put([Lv], np.int32))
     per_layer = eng._cross_embed(eng.params,
                                   jnp.asarray(req.cross_states))
     eng._cross_kv = eng._cross_write(
@@ -32,8 +34,8 @@ def _set_slot_cross(eng, slot: int, req: Request):
     dt = eng._cross_kv[0]["k"].dtype
     one = [{"k": c["k"][None].astype(dt), "v": c["v"][None].astype(dt)}
            for c in per_layer]
-    return (one, jnp.ones((1,), jnp.float32),
-            jnp.full((1,), n_valid, jnp.int32))
+    return (one, eng._put(np.ones((1,), np.float32)),
+            eng._put([n_valid], np.int32))
 
 def _cross_zeros(eng, K: int):
     """Zero cross-kv prefill args for text-only rows, cached per K."""
@@ -43,9 +45,10 @@ def _cross_zeros(eng, K: int):
     if K not in cache:
         tmpl = eng._cross_kv[0]["k"]
         shape = (K,) + tmpl.shape[1:]
-        cache[K] = [{"k": jnp.zeros(shape, tmpl.dtype),
-                     "v": jnp.zeros(shape, tmpl.dtype)}
-                    for _ in eng._cross_kv]
+        sh = (None if eng.shardings is None
+              else eng.shardings.cross_pool(1)[0]["k"])
+        cache[K] = [{n: jax.device_put(jnp.zeros(shape, tmpl.dtype), sh)
+                     for n in ("k", "v")} for _ in eng._cross_kv]
     return cache[K]
 
 
@@ -55,5 +58,5 @@ def _slot_cross_args(eng, slot: int):
     one = [{"k": buf["k"][slot][None], "v": buf["v"][slot][None]}
            for buf in eng._cross_kv]
     return (one,
-            jnp.asarray([eng._has_image[slot]], jnp.float32),
-            jnp.asarray([eng._cross_len[slot]], jnp.int32))
+            eng._put([eng._has_image[slot]], np.float32),
+            eng._put([eng._cross_len[slot]], np.int32))

@@ -36,7 +36,7 @@ from ..ops.sampling import sample_logits
 from .cache import PagedKVCache
 from .config import EngineConfig
 from .resident import InflightStep, ResidentBatch, composition_sig
-from .runner import make_decode, make_prefill
+from .runner import FOLD_STRIDE, make_decode, make_prefill
 from .types import (  # noqa: F401  (re-exported: public engine API)
     Finished,
     Request,
@@ -397,7 +397,10 @@ class LLMEngine:
         self._last_decode_step = -2  # step-gap continuity gate
         self._ids = itertools.count()
         self._step_count = 0
-        self._rng = jax.random.PRNGKey(ecfg.seed)
+        self._step_uploads = 0       # decode-family puts of this step
+        # the BASE key lives on the device, placed like every other step
+        # input; the step programs fold the step's index into it themselves
+        self._rng = self._put(jax.random.PRNGKey(ecfg.seed))
         self.finished: List[Finished] = []
         self._done_this_step: List[Finished] = []
 
@@ -982,6 +985,43 @@ class LLMEngine:
         self._record_step(t0)
         return self._done_this_step
 
+    def _put(self, x, dtype=None):
+        """THE road of a host value to a step program: placed the way the
+        programs were compiled to take it. An engine that holds shardings
+        compiled them with every small input replicated over the mesh, so
+        the value is put there, once, by whoever made it; an array left on
+        one device would be resharded to the mesh inside EVERY call that
+        takes it. An engine that holds none compiled for the default
+        device, where ``jnp.asarray`` puts it. ``x`` is one array (any
+        sequence, with a ``dtype``) or a tuple or dict of numpy arrays,
+        which go up in ONE transfer."""
+        if dtype is not None:
+            x = np.asarray(x, dtype)
+        if self.shardings is None:
+            return jax.tree.map(jnp.asarray, x)
+        return jax.device_put(x, self.shardings.rep)
+
+    def _put_step(self, x):
+        """``_put`` for a decode, verify or fused dispatch: the same put,
+        each array of it counted (``decode_input_uploads``: what a step
+        hands the device beyond what already lives there)."""
+        self._step_uploads += len(jax.tree.leaves(x))
+        return self._put(x)
+
+    def _fold(self) -> np.int32:
+        """What this step's decode-family program folds into the base key,
+        for a step that puts its inputs from the host. The steady path
+        never calls this: its index is the counter the previous step's
+        program handed back."""
+        return np.int32(self._step_count * FOLD_STRIDE)
+
+    def _admit_rng(self):
+        """The admission sampler's key for this step: the odd index beside
+        the decode family's even one. Folded eagerly, once an admission;
+        the key follows the base key's placement."""
+        return jax.random.fold_in(self._rng,
+                                  self._step_count * FOLD_STRIDE + 1)
+
     def _steady_step(self) -> None:
         """Pipelined decode step: dispatch N+1 on device feedback, then
         retire step N and do its host bookkeeping while N+1 runs."""
@@ -1015,12 +1055,16 @@ class LLMEngine:
             self.cache.extend(s.req.req_id, 1)
         Bb, decode = self._decode_for(len(running))
         self._note_dispatch_pad(running, Bb)
-        a = self._res.refresh(self, running, Bb)  # tables re-up if grown
-        rng = jax.random.fold_in(self._rng, self._step_count * 2)
+        a = self._res.refresh(self, running, Bb)  # stale table rows only
         tokens_dev, pos_dev = prev.nxt, prev.pos_next
         prev.pos_next = None  # donated into this dispatch
-        self._dispatch_async(decode, running, Bb, tokens_dev, pos_dev,
-                             a, rng)
+        # the program advanced its own fold index by one step's stride. A
+        # step that raised after the count ticked (a fault hook) leaves a
+        # pipe from an older step, whose index is not this step's
+        fold = (prev.fold_next if prev.step == self._step_count - 1
+                else self._put_step(self._fold()))
+        self._dispatch_async(decode, running, Bb, tokens_dev, pos_dev, a,
+                             fold)
         t_f = self._retire_pipe(prev)
         # the dispatch beat the readback: the recorded inter-step gap is
         # (clamped) zero — the device went straight into step N+1
@@ -1052,29 +1096,29 @@ class LLMEngine:
         for i, s in enumerate(running):
             tokens[i] = s.pending_token
             pos[i] = self.cache.seq(s.req.req_id).n_tokens - 1
-        rng = jax.random.fold_in(self._rng, self._step_count * 2)
-        self._dispatch_async(decode, running, Bb, jnp.asarray(tokens),
-                             jnp.asarray(pos), a, rng,
-                             gap_ok=self.n_executables == n_exec)
+        tokens_dev, pos_dev, fold = self._put_step(
+            (tokens, pos, self._fold()))
+        self._dispatch_async(decode, running, Bb, tokens_dev, pos_dev, a,
+                             fold, gap_ok=self.n_executables == n_exec)
         self._commit_pending(running)
 
     def _dispatch_async(self, decode, running, Bb: int, tokens_dev,
-                        pos_dev, a, rng, gap_ok: bool = True) -> None:
+                        pos_dev, a, fold, gap_ok: bool = True) -> None:
         """Enqueue one feedback-decode dispatch and record it in-flight.
 
         ``gap_ok=False`` suppresses the step-gap observation (the caller
         compiled a new executable this step — warmup, not a dispatch gap).
         """
         args = [self.params, self.cache.kv, tokens_dev, pos_dev,
-                a["tables"], a["active"], rng, a["temp"], a["topk"],
-                a["topp"]]
+                a["tables"], a["active"], self._rng, fold,
+                a["temp"], a["topk"], a["topp"]]
         if self._cross_kv is not None:
             args += [self._cross_kv, a["has_image"], a["slot_idx"],
                      a["cross_len"]]
         cold = self._pipe is None
         with self.obs.phase("engine.decode"):
             t_d = self.obs.phase_t0
-            (self.cache.kv, nxt, pos_next, top_ids, top_lp,
+            (self.cache.kv, nxt, pos_next, fold_next, top_ids, top_lp,
              tok_lp, *fetch) = decode(*args)
         if cold and gap_ok and self._t_fetch \
                 and self._last_decode_step == self._step_count - 1:
@@ -1084,7 +1128,8 @@ class LLMEngine:
         self._last_decode_step = self._step_count
         self._pipe = InflightStep(
             sig=composition_sig(running, Bb), running=list(running),
-            nxt=nxt, pos_next=pos_next, top_ids=top_ids, top_lp=top_lp,
+            nxt=nxt, pos_next=pos_next, fold_next=fold_next,
+            step=self._step_count, top_ids=top_ids, top_lp=top_lp,
             tok_lp=tok_lp,
             want_lp=any(s.req.params.logprobs for s in running),
             t_dispatch=t_d, fetch=fetch[0] if fetch else None)
@@ -1179,7 +1224,8 @@ class LLMEngine:
             rollback_tokens=rb - self._last_rollback_tokens,
             spec=self.spec.as_dict() if self.spec is not None else None,
             finished_ids=[f.req_id for f in self._done_this_step],
-            tenants=tenants)
+            tenants=tenants, input_uploads=self._step_uploads)
+        self._step_uploads = 0
         self._last_rollback_tokens = rb
         # first-use executable builds are warmup, not throughput: a step
         # that compiled must not enter the sentinel's rate window (same
@@ -1434,14 +1480,14 @@ class LLMEngine:
         n_text = len(req.prompt_ids)
         bucket = self.buckets.bucket_for(n)
         alloc = self.cache.admit(req.req_id, n)
-        table = jnp.asarray(alloc.table(self.ecfg.blocks_per_seq))[None]
+        table = self._put(alloc.table(self.ecfg.blocks_per_seq)[None])
         ids = np.zeros((1, bucket - P), np.int32)
         ids[0, :n_text] = req.prompt_ids
         fn = self._prefill_for(bucket, P)
-        args = [self.params, self.cache.kv, jnp.asarray(ids),
-                jnp.asarray([n_text], jnp.int32), table]
+        args = [self.params, self.cache.kv, self._put(ids),
+                self._put([n_text], np.int32), table]
         if P:
-            args.append(jnp.asarray(req.prefix)[None])
+            args.append(self._put(np.asarray(req.prefix)[None]))
         if self._cross_kv is not None:
             args += list(self._set_slot_cross(slot, req))
         with self.obs.phase("engine.prefill"):
@@ -1451,7 +1497,7 @@ class LLMEngine:
         # (vision-conditioned) requests, whose blocks must NOT
         # content-address by tokens alone — and cross engines disable the
         # cache at construction anyway
-        rng = jax.random.fold_in(self._rng, self._step_count * 2 + 1)
+        rng = self._admit_rng()
         with self.obs.phase("engine.fetch"):
             tok = int(self._sample1(
                 logits, rng, req.params.temperature, req.params.top_k,
@@ -1558,11 +1604,13 @@ class LLMEngine:
             topk[i] = req.params.top_k
             topp[i] = req.params.top_p
         fn = self._prefill_for(bucket, 0, Kp)
-        args = [self.params, self.cache.kv, jnp.asarray(ids),
-                jnp.asarray(n_text), jnp.asarray(tables)]
+        args = [self.params, self.cache.kv, self._put(ids),
+                self._put(n_text), self._put(tables)]
         if self._cross_kv is not None:  # text-only rows through a cross model
-            args += [self._cross_zeros(Kp), jnp.zeros((Kp,), jnp.float32),
-                     jnp.full((Kp,), max(self.cross_seq_len, 1), jnp.int32)]
+            args += [self._cross_zeros(Kp),
+                     self._put(np.zeros((Kp,), np.float32)),
+                     self._put(np.full((Kp,), max(self.cross_seq_len, 1),
+                                       np.int32))]
         with self.obs.phase("engine.prefill"):
             self.cache.kv, logits = fn(*args)
         real = sum(len(r.prompt_ids) for r in group)
@@ -1571,11 +1619,11 @@ class LLMEngine:
         for req in group:  # batch rows are always plain text
             self.cache.register_prefix(req.prompt_ids,
                                        self.cache.seq(req.req_id).blocks)
-        rng = jax.random.fold_in(self._rng, self._step_count * 2 + 1)
+        rng = self._admit_rng()
         with self.obs.phase("engine.fetch"):
             toks = np.asarray(self._sample1(
-                logits, rng, jnp.asarray(temp), jnp.asarray(topk),
-                jnp.asarray(topp)))
+                logits, rng, self._put(temp), self._put(topk),
+                self._put(topp)))
         lp_rows = []
         for i, req in enumerate(group):
             slot = self._free_slot()
@@ -1721,7 +1769,7 @@ class LLMEngine:
         # recompute fallback: the prompt suffix past the warm start is
         # re-prefilled, not restored — the trace's prefill span carries it
         req.obs_extra["recompute_tokens"] = float(n_total - start)
-        table = jnp.asarray(alloc.table(self.ecfg.blocks_per_seq))[None]
+        table = self._put(alloc.table(self.ecfg.blocks_per_seq)[None])
         n = n_total - start
         ids = np.zeros((1, chunk_bucket), np.int32)
         ids[0, :n] = req.prompt_ids[start:]
@@ -1731,19 +1779,19 @@ class LLMEngine:
             # chunk is still due to write)
             self._flush_chunk()
             logits = self._fused_chunk_call(
-                jnp.asarray(ids), jnp.asarray([n], jnp.int32), table,
-                jnp.asarray([start], jnp.int32))
+                self._put(ids), self._put([n], np.int32), table,
+                self._put([start], np.int32))
         else:
             fn = self._cont_for(sb, chunk_bucket)
             with self.obs.phase("engine.chunk"):
                 self.cache.kv, logits = fn(self.params, self.cache.kv,
-                                           jnp.asarray(ids),
-                                           jnp.asarray([n], jnp.int32),
+                                           self._put(ids),
+                                           self._put([n], np.int32),
                                            table, *self._cont_args(start))
         self.obs.count_pad(n, chunk_bucket - n,
                            phase="prefill")  # chunk bucket tail
         self.cache.register_prefix(req.prompt_ids, alloc.blocks)
-        rng = jax.random.fold_in(self._rng, self._step_count * 2 + 1)
+        rng = self._admit_rng()
         with self.obs.phase("engine.fetch"):
             tok = int(self._sample1(
                 logits, rng, req.params.temperature, req.params.top_k,
@@ -1811,14 +1859,14 @@ class LLMEngine:
             self._note_admitted(r)
         for r in group[1:]:
             self.cache.fork_sequence(head.req_id, r.req_id)
-        table = jnp.asarray(alloc.table(self.ecfg.blocks_per_seq))[None]
+        table = self._put(alloc.table(self.ecfg.blocks_per_seq)[None])
         ids = np.zeros((1, bucket), np.int32)
         ids[0, :n] = head.prompt_ids
         fn = self._prefill_for(bucket, 0, 1)
         with self.obs.phase("engine.prefill"):
             self.cache.kv, logits = fn(self.params, self.cache.kv,
-                                       jnp.asarray(ids),
-                                       jnp.asarray([n], jnp.int32), table)
+                                       self._put(ids),
+                                       self._put([n], np.int32), table)
         self.obs.count_pad(n, bucket - n, phase="prefill")
         self.cache.register_prefix(head.prompt_ids, alloc.blocks)
         temp = np.ones((Kp,), np.float32)
@@ -1829,11 +1877,11 @@ class LLMEngine:
             topk[i] = r.params.top_k
             topp[i] = r.params.top_p
         tiled = jnp.broadcast_to(logits[0], (Kp,) + logits.shape[1:])
-        rng = jax.random.fold_in(self._rng, self._step_count * 2 + 1)
+        rng = self._admit_rng()
         with self.obs.phase("engine.fetch"):
             toks = np.asarray(self._sample1(
-                tiled, rng, jnp.asarray(temp), jnp.asarray(topk),
-                jnp.asarray(topp)))
+                tiled, rng, self._put(temp), self._put(topk),
+                self._put(topp)))
         lp_rows = []
         for i, r in enumerate(group):
             slot = self._free_slot()
@@ -1874,12 +1922,12 @@ class LLMEngine:
         self.waiting.popleft()
         self._note_admitted(req)
         self.cache.admit(req.req_id, n_total)
-        table = jnp.asarray(
-            self.cache.seq(req.req_id).table(self.ecfg.blocks_per_seq))[None]
+        table = self._put(
+            self.cache.seq(req.req_id).table(self.ecfg.blocks_per_seq)[None])
         ids = np.asarray(req.prompt_ids[:C], np.int32)[None]
         fn = self._prefill_for(C, 0, 1)
-        args = [self.params, self.cache.kv, jnp.asarray(ids),
-                jnp.asarray([C], jnp.int32), table]
+        args = [self.params, self.cache.kv, self._put(ids),
+                self._put([C], np.int32), table]
         self._has_image[slot] = 0.0
         if self._cross_kv is not None:
             # seat the vision states (or the text-only gate-off) in the slot
@@ -1907,8 +1955,8 @@ class LLMEngine:
         n = len(chunk)
         ids = np.zeros((1, C), np.int32)
         ids[0, :n] = chunk
-        table = jnp.asarray(
-            self.cache.seq(req.req_id).table(self.ecfg.blocks_per_seq))[None]
+        table = self._put(
+            self.cache.seq(req.req_id).table(self.ecfg.blocks_per_seq)[None])
         final = start + n >= len(req.prompt_ids)
         if self._fused and not final:
             # intermediate chunk: DEFER the window — it rides this step's
@@ -1918,9 +1966,9 @@ class LLMEngine:
             # discards intermediate-chunk logits; registration and the
             # cursor advance keep the oracle's timing.
             self._flush_chunk()  # never stack two windows
-            self._pending_chunk = (jnp.asarray(ids),
-                                   jnp.asarray([n], jnp.int32), table,
-                                   jnp.asarray([start], jnp.int32))
+            self._pending_chunk = (self._put(ids),
+                                   self._put([n], np.int32), table,
+                                   self._put([start], np.int32))
             self.obs.count_pad(n, C - n, phase="chunk")
             self.cache.register_prefix(
                 req.prompt_ids[:start + n],
@@ -1934,12 +1982,12 @@ class LLMEngine:
             # 2 dispatches, the laddered oracle's own structure
             self._flush_chunk()
             logits = self._fused_chunk_call(
-                jnp.asarray(ids), jnp.asarray([n], jnp.int32), table,
-                jnp.asarray([start], jnp.int32))
+                self._put(ids), self._put([n], np.int32), table,
+                self._put([start], np.int32))
         else:
             fn = self._cont_for(start // self.ecfg.block_size)
-            args = [self.params, self.cache.kv, jnp.asarray(ids),
-                    jnp.asarray([n], jnp.int32), table]
+            args = [self.params, self.cache.kv, self._put(ids),
+                    self._put([n], np.int32), table]
             args += self._cont_args(start)  # ragged: start rides as data
             if self._cross_kv is not None:
                 args += list(self._slot_cross_args(s.slot))
@@ -2038,7 +2086,7 @@ class LLMEngine:
         ``(params, kv, ids, n_text, block_tables)``: the ragged variant
         carries the chunk start as DATA."""
         if self._ragged:
-            return [jnp.asarray([start], jnp.int32)]
+            return [self._put([start], np.int32)]
         return []
 
     def _cached_starts(self) -> List[int]:
@@ -2151,10 +2199,10 @@ class LLMEngine:
         reserved block 0, outside every live window; nothing reads them."""
         if self._null_chunk is None:
             self._null_chunk = [
-                jnp.zeros((1, self.buckets.max), jnp.int32),
-                jnp.ones((1,), jnp.int32),
-                jnp.zeros((1, self.ecfg.blocks_per_seq), jnp.int32),
-                jnp.zeros((1,), jnp.int32)]
+                self._put(np.zeros((1, self.buckets.max), np.int32)),
+                self._put(np.ones((1,), np.int32)),
+                self._put(np.zeros((1, self.ecfg.blocks_per_seq), np.int32)),
+                self._put(np.zeros((1,), np.int32))]
         return self._null_chunk
 
     def _take_chunk_args(self) -> list:
@@ -2175,12 +2223,13 @@ class LLMEngine:
         donates the position argument, so aliasing them would donate the
         token buffer too."""
         _, fused = self._fused_for(1)
-        args = [self.params, self.cache.kv,
-                jnp.zeros((1,), jnp.int32), jnp.zeros((1,), jnp.int32),
-                jnp.zeros((1, self.ecfg.blocks_per_seq), jnp.int32),
-                jnp.zeros((1,), bool), self._rng,
-                jnp.ones((1,), jnp.float32), jnp.zeros((1,), jnp.int32),
-                jnp.ones((1,), jnp.float32),
+        z = np.zeros((1,), np.int32)
+        args = [self.params, self.cache.kv, self._put(z), self._put(z),
+                self._put(np.zeros((1, self.ecfg.blocks_per_seq), np.int32)),
+                self._put(np.zeros((1,), bool)), self._rng,
+                self._put(self._fold()),
+                self._put(np.ones((1,), np.float32)), self._put(z),
+                self._put(np.ones((1,), np.float32)),
                 ids_dev, n_dev, table, start_dev]
         with self.obs.phase("engine.chunk"):
             out = fused(*args)
@@ -2491,10 +2540,11 @@ class LLMEngine:
             pos0[i] = self.cache.seq(s.req.req_id).n_tokens - (1 + len(d))
 
         # same device stream slot as the vanilla decode this step replaces
-        rng = jax.random.fold_in(self._rng, self._step_count * 2)
-        args = [self.params, self.cache.kv, jnp.asarray(tokens),
-                jnp.asarray(pos0), a["tables"], a["active"], rng,
-                a["temp"], a["topk"], a["topp"]]
+        tokens_dev, pos_dev, fold = self._put_step(
+            (tokens, pos0, self._fold()))
+        args = [self.params, self.cache.kv, tokens_dev, pos_dev,
+                a["tables"], a["active"], self._rng, fold, a["temp"],
+                a["topk"], a["topp"]]
         if self._cross_kv is not None:
             args += [self._cross_kv, a["has_image"], a["slot_idx"],
                      a["cross_len"]]
@@ -2609,14 +2659,18 @@ class LLMEngine:
             tokens[i] = s.pending_token
             pos[i] = self.cache.seq(s.req.req_id).n_tokens - 1
 
-        rng = jax.random.fold_in(self._rng, self._step_count * 2)
-        args = [self.params, self.cache.kv, jnp.asarray(tokens),
-                jnp.asarray(pos), jnp.asarray(a["tables"]),
-                jnp.asarray(a["active"]), rng, jnp.asarray(a["temp"]),
-                jnp.asarray(a["topk"]), jnp.asarray(a["topp"])]
+        cols = ("tables", "active", "temp", "topk", "topp") + (
+            ("has_image", "slot_idx", "cross_len")
+            if self._cross_kv is not None else ())
+        d = self._put_step({"tokens": tokens, "pos": pos,
+                            "fold": self._fold(),
+                            **{k: a[k] for k in cols}})
+        args = [self.params, self.cache.kv, d["tokens"], d["pos"],
+                d["tables"], d["active"], self._rng, d["fold"], d["temp"],
+                d["topk"], d["topp"]]
         if self._cross_kv is not None:
-            args += [self._cross_kv, jnp.asarray(a["has_image"]),
-                     jnp.asarray(a["slot_idx"]), jnp.asarray(a["cross_len"])]
+            args += [self._cross_kv, d["has_image"], d["slot_idx"],
+                     d["cross_len"]]
         with self.obs.phase("engine.decode"):
             t_d = self.obs.phase_t0
             (self.cache.kv, nxt, top_ids_d, top_lp_d, tok_lp_d,

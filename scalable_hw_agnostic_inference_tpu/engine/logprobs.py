@@ -9,7 +9,6 @@ from __future__ import annotations
 
 from typing import Dict
 
-import jax.numpy as jnp
 import numpy as np
 
 def _lp_entry(n_top: int, tok: int, tok_lp, top_ids, top_lp) -> Dict:
@@ -21,7 +20,7 @@ def _record_admission_lps(eng, logits, toks, rows) -> None:
     """Per-token logprobs for freshly sampled first tokens — ``rows``
     maps batch row -> the seated _Running; only called when some row
     asked for logprobs (logits stay on device otherwise)."""
-    ids, lps, tok_lp = eng._lp1(logits, jnp.asarray(toks, jnp.int32))
+    ids, lps, tok_lp = eng._lp1(logits, eng._put(toks, np.int32))
     ids, lps, tok_lp = np.asarray(ids), np.asarray(lps), np.asarray(tok_lp)
     for i, s in rows:
         n_top = s.req.params.logprobs

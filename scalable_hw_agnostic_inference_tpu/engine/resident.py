@@ -1,18 +1,20 @@
 """Device-resident decode batch state + the in-flight lookahead record.
 
-The lock-step decode loop re-marshals the full batch view host->device on
-EVERY step — seven ``jnp.asarray`` uploads for arrays that change at most
-when the batch composition changes — then blocks on ``np.asarray(nxt)``
-before doing its host bookkeeping, stacking a fixed serial host gap onto
-every HBM-bound decode step. The async pipeline (``SHAI_ASYNC_DECODE``)
-removes both halves:
+A decode step's inputs reach the device by ONE road, ``LLMEngine._put``
+(placed where the step programs were compiled to take them: replicated
+over the mesh when the engine holds shardings, the default device when it
+holds none; ``_put_step`` is the same put, counted as
+``decode_input_uploads``), and only when they changed:
 
 * :class:`ResidentBatch` keeps the composition-dependent arrays
   (``tables/active/temp/topk/topp`` plus the mllama slot tail) as
-  persistent DEVICE arrays, keyed by a composition signature. They are
-  re-uploaded only when the signature changes (join/finish/preempt) —
-  block-table growth alone refreshes just the ``tables`` upload. The
-  speculative verify path shares this cache: same composition, same
+  persistent DEVICE arrays, keyed by a composition signature. All of them
+  go up when the signature changes (join/finish/preempt). Between two
+  such events only ``tables`` can go stale, and it is tracked BY ROW: the
+  mirror keeps a host ``[Bb, M]`` copy and each row's
+  ``SeqAllocation.version``; a step rewrites the rows whose stamp moved
+  and puts the table once if any did — O(rows) a step, not O(blocks).
+  The speculative verify path shares this cache: same composition, same
   device arrays, whichever executable dispatches next.
 
   The mirror is COLUMN-AGNOSTIC: whatever dict ``engine.
@@ -28,7 +30,9 @@ removes both halves:
 * :class:`InflightStep` records one dispatched-but-not-retired decode
   step: the device-side sampled tokens (which feed straight back as the
   next dispatch's ``tokens`` input — the host never sees them until one
-  step later), the donated next-positions array, and the logprob outputs.
+  step later), the donated next-positions array, the next step's rng
+  fold index (a device counter the program advances), and the logprob
+  outputs.
   Retiring the record is the ONLY place the host blocks on the device.
 
 Layering: pure data + marshaling helpers; the scheduling policy (when to
@@ -40,7 +44,6 @@ from __future__ import annotations
 import dataclasses
 from typing import Any, Dict, List, Optional, Tuple
 
-import jax.numpy as jnp
 import numpy as np
 
 
@@ -61,6 +64,8 @@ class InflightStep:
     running: List[Any]                # _Running snapshot, batch-row order
     nxt: Any                          # device [Bb] sampled tokens (feedback)
     pos_next: Optional[Any]           # device [Bb] pos+1; None once donated
+    fold_next: Any                    # device int32: the NEXT step's rng fold
+    step: int                         # the engine step that dispatched it
     top_ids: Any
     top_lp: Any
     tok_lp: Any
@@ -84,12 +89,18 @@ class ResidentBatch:
     def __init__(self) -> None:
         self.sig: Optional[Tuple] = None
         self.arrays: Dict[str, Any] = {}
-        self.blocks: Tuple[Tuple[int, ...], ...] = ()
+        #: host copy of ``arrays["tables"]`` and, per batch row, the
+        #: ``SeqAllocation.version`` that row was written from
+        self.tables: Optional[np.ndarray] = None
+        self.versions: List[int] = []
+        #: cumulative: table rows rewritten by the by-row path
+        self.rows_rewritten = 0
 
     def invalidate(self) -> None:
         self.sig = None
         self.arrays = {}
-        self.blocks = ()
+        self.tables = None
+        self.versions = []
 
     def device_bytes(self) -> int:
         """Bytes the resident mirror holds on device (HBM ledger feed)."""
@@ -99,30 +110,34 @@ class ResidentBatch:
     def refresh(self, engine, running, Bb: int) -> Dict[str, Any]:
         """Device arrays for ``running`` compacted into ``Bb`` rows.
 
-        Composition unchanged: reuse every resident array, re-uploading
-        only ``tables`` when some row's block LIST changed since the last
-        marshal. Staleness is keyed on the block IDENTITIES, not counts:
-        the allocator's free list is LIFO, so a shrink-then-regrow cycle
-        (speculative rollback) can hand two slots each other's freed
-        blocks with every per-row count unchanged — a count key would
-        reuse tables that now point rows at the wrong physical blocks.
-        Composition changed: one full host marshal (the engine's
-        lock-step ``_marshal_running``) uploaded wholesale.
+        Composition unchanged: reuse every resident array; rewrite the
+        table rows whose allocation's ``version`` moved since the row was
+        written, and put the table once if any did. Staleness is keyed on
+        the block IDENTITIES, not counts: the allocator's free list is
+        LIFO, so a shrink-then-regrow cycle (speculative rollback) can
+        hand two slots each other's freed blocks with every per-row count
+        unchanged — the stamp moves on every mutation of a block list, so
+        both rows are rewritten. Composition changed: one full host
+        marshal (the engine's lock-step ``_marshal_running``) uploaded
+        wholesale.
         """
         sig = composition_sig(running, Bb)
-        blocks = tuple(tuple(engine.cache.seq(s.req.req_id).blocks)
-                       for s in running)
-        if sig == self.sig:
-            if blocks != self.blocks:
-                M = engine.ecfg.blocks_per_seq
-                tables = np.zeros((Bb, M), np.int32)
-                for i, s in enumerate(running):
-                    tables[i] = engine.cache.seq(s.req.req_id).table(M)
-                self.arrays["tables"] = jnp.asarray(tables)
-                self.blocks = blocks
+        seqs = [engine.cache.seq(s.req.req_id) for s in running]
+        if sig != self.sig:
+            host = engine._marshal_running(running, Bb)
+            self.arrays = engine._put_step(host)   # ONE transfer
+            self.tables = host["tables"].copy()
+            self.versions = [a.version for a in seqs]
+            self.sig = sig
             return self.arrays
-        host = engine._marshal_running(running, Bb)
-        self.arrays = {k: jnp.asarray(v) for k, v in host.items()}
-        self.sig = sig
-        self.blocks = blocks
+        done = self.rows_rewritten
+        for i, a in enumerate(seqs):
+            if a.version != self.versions[i]:
+                self.tables[i] = a.table(self.tables.shape[1])
+                self.versions[i] = a.version
+                self.rows_rewritten += 1
+        if self.rows_rewritten != done:
+            # a COPY goes up: the transfer may read the host buffer after
+            # the put returns, and the next step writes into the mirror
+            self.arrays["tables"] = engine._put_step(self.tables.copy())
         return self.arrays

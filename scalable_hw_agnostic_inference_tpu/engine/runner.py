@@ -844,6 +844,11 @@ def _make_token_forward(cfg: LlamaConfig, block_size: int,
     return fwd
 
 
+#: a step's decode-family draw folds ``step * FOLD_STRIDE`` into the base
+#: key; the odd indices between are the admission sampler's (``engine.py``)
+FOLD_STRIDE = 2
+
+
 def make_decode(cfg: LlamaConfig, block_size: int, blocks_per_seq: int,
                 max_num_seqs: int,
                 shardings: Optional[EngineShardings] = None,
@@ -852,12 +857,24 @@ def make_decode(cfg: LlamaConfig, block_size: int, blocks_per_seq: int,
     """Compile one decode step for the whole slot batch.
 
     ``decode(params, kv, tokens [B], pos [B], tables [B, M], active [B],
-    rng, temperature [B], top_k [B], top_p [B]) -> (kv, next_tokens [B])``.
+    rng, fold, temperature [B], top_k [B], top_p [B]) ->
+    (kv, next_tokens [B])``.
+
+    ``rng`` is the engine's BASE key, resident on the device like the
+    weights, and ``fold`` the int32 scalar this step folds into it: the
+    program draws from ``fold_in(rng, fold)``, the same threefry the host
+    used to launch as two eager programs before every dispatch, so the
+    keys, and the sampled tokens, are bit for bit what they were. The
+    ``feedback`` variant hands back ``fold + FOLD_STRIDE`` beside
+    ``pos + 1``: the next step's index, already on the device (on a
+    four-chip mesh a host scalar costs the call 0.5 ms, a resident one
+    nothing: PERF.md, PR 30).
 
     ``feedback``: the async-pipeline variant (``SHAI_ASYNC_DECODE``). The
-    executable additionally returns ``pos + 1`` so the engine can feed the
-    sampled-token and position arrays of step N straight back as step
-    N+1's inputs without a host round-trip, and ``pos`` is donated along
+    executable additionally returns ``pos + 1`` (and the next ``fold``) so
+    the engine can feed the sampled-token and position arrays of step N
+    straight back as step N+1's inputs without a host round-trip, and
+    ``pos`` is donated along
     with the KV pool (the position buffer ping-pongs in place; ``tokens``
     is NOT donated — the host still reads step N's sampled tokens back one
     step later for EOS/stop bookkeeping, and a donated buffer could not be
@@ -893,9 +910,10 @@ def make_decode(cfg: LlamaConfig, block_size: int, blocks_per_seq: int,
     fwd = _make_token_forward(cfg, block_size, blocks_per_seq, max_num_seqs,
                               1, shardings, paged, kv_quant=kv_quant)
 
-    def _decode_impl(params, kv, tokens, pos, tables, active, rng,
+    def _decode_impl(params, kv, tokens, pos, tables, active, rng, fold,
                      temperature, top_k, top_p, cross_kv=None, has_image=None,
                      slot_idx=None, cross_len=None):
+        rng = jax.random.fold_in(rng, fold)
         kv, logits, stats = fwd(
             params, kv, tokens[:, None], pos[:, None], tables,
             cross_kv=cross_kv, has_image=has_image, slot_idx=slot_idx,
@@ -905,7 +923,8 @@ def make_decode(cfg: LlamaConfig, block_size: int, blocks_per_seq: int,
         # logprob data rides along (tiny vs the matmuls); the engine only
         # transfers it to the host when a running request asked for it
         top_ids, top_lp, tok_lp = token_logprobs(logits, nxt)
-        out = (kv, nxt) + ((pos + 1,) if feedback else ()) + (
+        out = (kv, nxt) + (
+            (pos + 1, fold + FOLD_STRIDE) if feedback else ()) + (
             top_ids, top_lp, tok_lp)
         # a routed model's step says what routing did (ROUTE_STATS int32
         # behind the sampled tokens, ONE array: the host's one read of the
@@ -914,28 +933,28 @@ def make_decode(cfg: LlamaConfig, block_size: int, blocks_per_seq: int,
             jnp.concatenate([nxt.astype(jnp.int32), stats]),)
 
     if cross_set:
-        def decode(params, kv, tokens, pos, tables, active, rng,
+        def decode(params, kv, tokens, pos, tables, active, rng, fold,
                    temperature, top_k, top_p, cross_kv, has_image, slot_idx,
                    cross_len):
             return _decode_impl(params, kv, tokens, pos, tables, active, rng,
-                                temperature, top_k, top_p,
+                                fold, temperature, top_k, top_p,
                                 cross_kv=cross_kv, has_image=has_image,
                                 slot_idx=slot_idx, cross_len=cross_len)
     else:
-        def decode(params, kv, tokens, pos, tables, active, rng,
+        def decode(params, kv, tokens, pos, tables, active, rng, fold,
                    temperature, top_k, top_p):
             return _decode_impl(params, kv, tokens, pos, tables, active, rng,
-                                temperature, top_k, top_p)
+                                fold, temperature, top_k, top_p)
 
     donate = (1, 3) if feedback else (1,)
     if shardings is None:
         return jax.jit(decode, donate_argnums=donate)
     sh, rep = shardings, shardings.rep
     kvsh = sh.kv_pool(cfg.n_layers - len(cross_set), quant=kv_quant)
-    in_sh = (sh.params, kvsh) + (rep,) * 8
+    in_sh = (sh.params, kvsh) + (rep,) * 9
     if cross_set:
         in_sh += (sh.cross_pool(len(cross_set)), rep, rep, rep)
-    out_sh = (kvsh,) + (rep,) * ((5 if feedback else 4)
+    out_sh = (kvsh,) + (rep,) * ((6 if feedback else 4)
                                  + bool(cfg.n_experts))
     return jax.jit(decode, donate_argnums=donate,
                    in_shardings=in_sh, out_shardings=out_sh)
@@ -949,7 +968,7 @@ def make_verify(cfg: LlamaConfig, block_size: int, blocks_per_seq: int,
     sequence in ONE paged-attention dispatch.
 
     ``verify(params, kv, tokens [B, k+1], pos0 [B], tables [B, M],
-    active [B], rng, temperature [B], top_k [B], top_p [B]) ->
+    active [B], rng, fold, temperature [B], top_k [B], top_p [B]) ->
     (kv, o [B, k+1], oex [B, k], accept_p [B, k], o_lp [B, k+1],
     d_lp [B, k], oex_lp [B, k], top_ids [B, k+1, K], top_lp [B, k+1, K])``.
 
@@ -980,10 +999,11 @@ def make_verify(cfg: LlamaConfig, block_size: int, blocks_per_seq: int,
     fwd = _make_token_forward(cfg, block_size, blocks_per_seq, max_num_seqs,
                               T, shardings, paged, kv_quant=kv_quant)
 
-    def _verify_impl(params, kv, tokens, pos0, tables, active, rng,
+    def _verify_impl(params, kv, tokens, pos0, tables, active, rng, fold,
                      temperature, top_k, top_p, cross_kv=None, has_image=None,
                      slot_idx=None, cross_len=None):
         B = max_num_seqs
+        rng = jax.random.fold_in(rng, fold)  # as make_decode
         positions = pos0[:, None] + jnp.arange(T, dtype=jnp.int32)[None, :]
         kv, logits, _ = fwd(params, kv, tokens, positions, tables,
                             cross_kv=cross_kv, has_image=has_image,
@@ -1017,24 +1037,24 @@ def make_verify(cfg: LlamaConfig, block_size: int, blocks_per_seq: int,
                 top_ids.astype(jnp.int32), top_lp)
 
     if cross_set:
-        def verify(params, kv, tokens, pos0, tables, active, rng,
+        def verify(params, kv, tokens, pos0, tables, active, rng, fold,
                    temperature, top_k, top_p, cross_kv, has_image, slot_idx,
                    cross_len):
             return _verify_impl(params, kv, tokens, pos0, tables, active,
-                                rng, temperature, top_k, top_p,
+                                rng, fold, temperature, top_k, top_p,
                                 cross_kv=cross_kv, has_image=has_image,
                                 slot_idx=slot_idx, cross_len=cross_len)
     else:
-        def verify(params, kv, tokens, pos0, tables, active, rng,
+        def verify(params, kv, tokens, pos0, tables, active, rng, fold,
                    temperature, top_k, top_p):
             return _verify_impl(params, kv, tokens, pos0, tables, active,
-                                rng, temperature, top_k, top_p)
+                                rng, fold, temperature, top_k, top_p)
 
     if shardings is None:
         return jax.jit(verify, donate_argnums=(1,))
     sh, rep = shardings, shardings.rep
     kvsh = sh.kv_pool(cfg.n_layers - len(cross_set), quant=kv_quant)
-    in_sh = (sh.params, kvsh) + (rep,) * 8
+    in_sh = (sh.params, kvsh) + (rep,) * 9
     if cross_set:
         in_sh += (sh.cross_pool(len(cross_set)), rep, rep, rep)
     return jax.jit(verify, donate_argnums=(1,),
@@ -1052,10 +1072,10 @@ def make_fused_step(cfg: LlamaConfig, block_size: int, blocks_per_seq: int,
     a single dispatch.
 
     ``fused(params, kv, tokens [B], pos [B], tables [B, M], active [B],
-    rng, temperature [B], top_k [B], top_p [B], c_ids [1, C],
+    rng, fold, temperature [B], top_k [B], top_p [B], c_ids [1, C],
     c_ntext [1], c_table [1, M], c_start [1]) ->
-    (kv, next_tokens [B][, pos + 1], top_ids, top_lp, tok_lp,
-    c_logits [1, V])``.
+    (kv, next_tokens [B][, pos + 1, fold + FOLD_STRIDE], top_ids, top_lp,
+    tok_lp, c_logits [1, V])``.
 
     Two sections share one layer walk over one donated pool:
 
@@ -1110,11 +1130,12 @@ def make_fused_step(cfg: LlamaConfig, block_size: int, blocks_per_seq: int,
     paged = _resolve_paged(paged)
     pool_call = functools.partial(_pool_kernel_call, shardings)
 
-    def _fused_impl(params, kv, tokens, pos, tables, active, rng,
+    def _fused_impl(params, kv, tokens, pos, tables, active, rng, fold,
                     temperature, top_k, top_p, c_ids, c_ntext, c_table,
                     c_start):
         from ..ops.attention import mixed_phase_ragged_attention
 
+        rng = jax.random.fold_in(rng, fold)  # as make_decode
         p = params["params"]
         B = max_num_seqs
         C = bucket
@@ -1215,13 +1236,14 @@ def make_fused_step(cfg: LlamaConfig, block_size: int, blocks_per_seq: int,
                                     axis=1)
         c_logits = _logits(p, lastc, cfg)[:, 0]                 # [1, V]
         if feedback:
-            return kv, nxt, pos + 1, top_ids, top_lp, tok_lp, c_logits
+            return (kv, nxt, pos + 1, fold + FOLD_STRIDE, top_ids, top_lp,
+                    tok_lp, c_logits)
         return kv, nxt, top_ids, top_lp, tok_lp, c_logits
 
-    def fused(params, kv, tokens, pos, tables, active, rng, temperature,
-              top_k, top_p, c_ids, c_ntext, c_table, c_start):
+    def fused(params, kv, tokens, pos, tables, active, rng, fold,
+              temperature, top_k, top_p, c_ids, c_ntext, c_table, c_start):
         return _fused_impl(params, kv, tokens, pos, tables, active, rng,
-                           temperature, top_k, top_p, c_ids, c_ntext,
+                           fold, temperature, top_k, top_p, c_ids, c_ntext,
                            c_table, c_start)
 
     donate = (1, 3) if feedback else (1,)
@@ -1229,7 +1251,7 @@ def make_fused_step(cfg: LlamaConfig, block_size: int, blocks_per_seq: int,
         return jax.jit(fused, donate_argnums=donate)
     sh, rep = shardings, shardings.rep
     kvsh = sh.kv_pool(cfg.n_layers, quant=kv_quant)
-    in_sh = (sh.params, kvsh) + (rep,) * 12
-    out_sh = (kvsh,) + (rep,) * (6 if feedback else 5)
+    in_sh = (sh.params, kvsh) + (rep,) * 13
+    out_sh = (kvsh,) + (rep,) * (7 if feedback else 5)
     return jax.jit(fused, donate_argnums=donate,
                    in_shardings=in_sh, out_shardings=out_sh)

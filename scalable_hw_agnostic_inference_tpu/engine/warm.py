@@ -113,6 +113,18 @@ def warm_executables(eng, prefix_lens: Sequence[int] = (0,)) -> int:
 def _run_warm_calls(eng) -> None:
     ecfg = eng.ecfg
     B, M = ecfg.max_num_seqs, ecfg.blocks_per_seq
+    # every warm argument takes the road the engine's own take
+    # (``LLMEngine._put``): a program is warmed the way it will be called
+    put = eng._put
+
+    def zeros(shape, dtype=np.int32):
+        return put(np.zeros(shape, dtype))
+
+    def ones(shape, dtype=np.int32):
+        return put(np.ones(shape, dtype))
+
+    def cross_len(n):
+        return put(np.full((n,), max(eng.cross_seq_len, 1), np.int32))
 
     def warm_sampler(logits, per_row: bool) -> None:
         """The admission-time sampler and the first token's logprob readout
@@ -122,15 +134,14 @@ def _run_warm_calls(eng) -> None:
         warm-up on fresh single-device zeros compiled both a second time
         after ready (seconds each, inside the first requests)."""
         K = logits.shape[0]
-        key = jax.random.PRNGKey(0)
+        key = eng._admit_rng()  # the eager fold is warmed by being made
         if per_row:  # _admit_batch / _admit_fanout: per-row knob arrays
-            eng._sample1(logits, key, jnp.ones((K,), jnp.float32),
-                         jnp.zeros((K,), jnp.int32),
-                         jnp.ones((K,), jnp.float32))
+            eng._sample1(logits, key, ones((K,), np.float32), zeros((K,)),
+                         ones((K,), np.float32))
         if K == 1:   # _admit_one, prefix and continuation: scalar knobs
             eng._sample1(logits, key, 1.0, 0, 1.0)
         jax.block_until_ready(
-            eng._lp1(logits, jnp.zeros((K,), jnp.int32)))
+            eng._lp1(logits, zeros((K,))))
 
     for key, fn in list(eng._prefill.items()):
         if key[0] == "rcont":
@@ -138,59 +149,56 @@ def _run_warm_calls(eng) -> None:
             # (a zero start against the null table writes into reserved
             # block 0 — garbage there is allowed by contract)
             eng.cache.kv, logits = fn(
-                eng.params, eng.cache.kv,
-                jnp.zeros((1, key[1]), jnp.int32),
-                jnp.ones((1,), jnp.int32),
-                jnp.zeros((1, M), jnp.int32),
-                jnp.zeros((1,), jnp.int32))
+                eng.params, eng.cache.kv, zeros((1, key[1])), ones((1,)),
+                zeros((1, M)), zeros((1,)))
             warm_sampler(logits, per_row=False)
             continue
         if key[0] == "cont":
-            args = [eng.params, eng.cache.kv,
-                    jnp.zeros((1, key[2]), jnp.int32),
-                    jnp.ones((1,), jnp.int32),
-                    jnp.zeros((1, M), jnp.int32)]
+            args = [eng.params, eng.cache.kv, zeros((1, key[2])),
+                    ones((1,)), zeros((1, M))]
             if eng._cross_kv is not None:
-                args += [eng._cross_zeros(1),
-                         jnp.zeros((1,), jnp.float32),
-                         jnp.full((1,), max(eng.cross_seq_len, 1),
-                                  jnp.int32)]
+                args += [eng._cross_zeros(1), zeros((1,), np.float32),
+                         cross_len(1)]
             eng.cache.kv, logits = fn(*args)
             warm_sampler(logits, per_row=False)
             continue
         bucket, P_, K = key
-        ids = jnp.zeros((K, bucket - P_), jnp.int32)
-        args = [eng.params, eng.cache.kv, ids,
-                jnp.ones((K,), jnp.int32), jnp.zeros((K, M), jnp.int32)]
+        args = [eng.params, eng.cache.kv, zeros((K, bucket - P_)),
+                ones((K,)), zeros((K, M))]
         if P_:
-            args.append(jnp.zeros((K, P_, eng.cfg.dim), jnp.float32))
+            args.append(zeros((K, P_, eng.cfg.dim), np.float32))
         if eng._cross_kv is not None:
-            args += [eng._cross_zeros(K), jnp.zeros((K,), jnp.float32),
-                     jnp.full((K,), max(eng.cross_seq_len, 1), jnp.int32)]
+            args += [eng._cross_zeros(K), zeros((K,), np.float32),
+                     cross_len(K)]
         eng.cache.kv, logits = fn(*args)
         warm_sampler(logits, per_row=P_ == 0)
+
+    def step_args(bb, tokens):
+        """The argument list the decode family shares: null rows."""
+        args = [eng.params, eng.cache.kv, tokens, zeros((bb,)),
+                zeros((bb, M)), zeros((bb,), bool), eng._rng, zeros(()),
+                ones((bb,), np.float32), zeros((bb,)),
+                ones((bb,), np.float32)]
+        if eng._cross_kv is not None:
+            args += [eng._cross_kv, zeros((bb,), np.float32), zeros((bb,)),
+                     cross_len(bb)]
+        return args
+
     for bb, fn in list(eng._decode_fns.items()):
         # async engines warm the feedback variant through the same ladder
-        # (one extra pos+1 output rides in *_rest; the donated position
-        # buffer here is a warm-only throwaway)
-        args = [eng.params, eng.cache.kv, jnp.zeros((bb,), jnp.int32),
-                jnp.zeros((bb,), jnp.int32), jnp.zeros((bb, M), jnp.int32),
-                jnp.zeros((bb,), bool), jax.random.PRNGKey(0),
-                jnp.ones((bb,), jnp.float32), jnp.zeros((bb,), jnp.int32),
-                jnp.ones((bb,), jnp.float32)]
-        if eng._cross_kv is not None:
-            args += [eng._cross_kv, jnp.zeros((bb,), jnp.float32),
-                     jnp.zeros((bb,), jnp.int32),
-                     jnp.full((bb,), max(eng.cross_seq_len, 1), jnp.int32)]
+        # (pos + 1 and the next fold index ride in *rest; the donated
+        # position buffer here is a warm-only throwaway)
+        args = step_args(bb, zeros((bb,)))
         eng.cache.kv, nxt, *rest = fn(*args)
         if eng._async:
-            # the steady path feeds a step's sampled tokens and pos + 1
-            # straight back as the next step's inputs. Under tensor
-            # parallelism those outputs carry the mesh in their type, which
-            # makes that call a second trace of the same executable: warm
-            # it the way it will be called, or the first steady step of
-            # every batch bucket compiles after ready
+            # the steady path feeds a step's sampled tokens, pos + 1 and
+            # fold index straight back as the next step's inputs. Under
+            # tensor parallelism those outputs carry the mesh in their
+            # type, which can make that call a second trace of the same
+            # executable: warm it the way it will be called, or the first
+            # steady step of every batch bucket compiles after ready
             args[1:4] = [eng.cache.kv, nxt, rest[0]]
+            args[7] = rest[1]
             eng.cache.kv, nxt, *rest = fn(*args)
         nxt.block_until_ready()
     for bb, fn in list(eng._fused_fns.items()):
@@ -199,30 +207,14 @@ def _run_warm_calls(eng) -> None:
         # write lands in reserved block 0, allowed by contract). tokens
         # and pos must be SEPARATE buffers: the feedback variant donates
         # the position argument.
-        args = [eng.params, eng.cache.kv, jnp.zeros((bb,), jnp.int32),
-                jnp.zeros((bb,), jnp.int32), jnp.zeros((bb, M), jnp.int32),
-                jnp.zeros((bb,), bool), jax.random.PRNGKey(0),
-                jnp.ones((bb,), jnp.float32), jnp.zeros((bb,), jnp.int32),
-                jnp.ones((bb,), jnp.float32),
-                jnp.zeros((1, eng.buckets.max), jnp.int32),
-                jnp.ones((1,), jnp.int32),
-                jnp.zeros((1, M), jnp.int32),
-                jnp.zeros((1,), jnp.int32)]
+        args = step_args(bb, zeros((bb,))) + [
+            zeros((1, eng.buckets.max)), ones((1,)), zeros((1, M)),
+            zeros((1,))]
         eng.cache.kv, nxt, *_rest = fn(*args)
         nxt.block_until_ready()
     K = eng.ecfg.num_speculative_tokens
     for bb, fn in list(eng._verify_fns.items()):
-        args = [eng.params, eng.cache.kv,
-                jnp.zeros((bb, K + 1), jnp.int32),
-                jnp.zeros((bb,), jnp.int32), jnp.zeros((bb, M), jnp.int32),
-                jnp.zeros((bb,), bool), jax.random.PRNGKey(0),
-                jnp.ones((bb,), jnp.float32), jnp.zeros((bb,), jnp.int32),
-                jnp.ones((bb,), jnp.float32)]
-        if eng._cross_kv is not None:
-            args += [eng._cross_kv, jnp.zeros((bb,), jnp.float32),
-                     jnp.zeros((bb,), jnp.int32),
-                     jnp.full((bb,), max(eng.cross_seq_len, 1), jnp.int32)]
-        eng.cache.kv, o, *_rest = fn(*args)
+        eng.cache.kv, o, *_rest = fn(*step_args(bb, zeros((bb, K + 1))))
         o.block_until_ready()
     if eng._cross_embed is not None:  # the admission-time projector
         per_layer = eng._cross_embed(

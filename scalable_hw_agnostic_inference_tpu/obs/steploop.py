@@ -226,6 +226,11 @@ class StepTelemetry:
         # the same accounting's count of dispatches: work done has a
         # number of programs, not only of tokens
         self.dispatches_by_phase: Dict[str, int] = {}
+        # host-to-device arrays put for decode, verify and fused dispatches
+        # (a table refresh counts one, a new composition each of its
+        # arrays): over ``steps`` it says whether a steady step hands the
+        # device only what changed
+        self.decode_input_uploads = 0
         # what routing did, in the decode dispatches of a model with expert
         # layers (the device counts inside the step it already runs; the
         # counts ride back behind the sampled tokens, in the same read):
@@ -434,8 +439,8 @@ class StepTelemetry:
                     rollback_tokens: int = 0,
                     spec: Optional[Dict[str, Any]] = None,
                     finished_ids: Sequence[int] = (),
-                    tenants: Optional[Dict[str, Sequence[int]]] = None
-                    ) -> None:
+                    tenants: Optional[Dict[str, Sequence[int]]] = None,
+                    input_uploads: int = 0) -> None:
         """One engine ``step()`` completed; ``kind`` names the decode path
         taken (``"decode"``, ``"spec"``, ``"idle"``). ``finished_ids`` are
         the engine request ids that reached a terminal state this step —
@@ -471,6 +476,7 @@ class StepTelemetry:
         with self._lock:
             self.steps += 1
             self.requests_finished += finished
+            self.decode_input_uploads += input_uploads
             rec["step"] = self.steps
             for field in _STEP_FIELDS:
                 rec[field] = round(self._step_ms.get(field, 0.0), 4)
@@ -539,6 +545,7 @@ class StepTelemetry:
                 "warmed_executables": self.warmed_executables,
                 "kv_blocks_total": self.total_blocks,
                 "pipeline_flushes": self.pipeline_flushes,
+                "decode_input_uploads": self.decode_input_uploads,
                 "pad_tokens": self.pad_tokens,
                 "real_tokens": self.real_tokens,
             }

@@ -350,7 +350,7 @@ def _paged_decode(cfg, name: str, *, quant: bool, batch: int, ctx: int,
     vec = lambda dt: aval((batch,), dt)  # noqa: E731
     args = (params, kv, vec(jnp.int32), vec(jnp.int32),
             aval((batch, m_ctx), jnp.int32), vec(jnp.bool_),
-            atree(lambda: jax.random.PRNGKey(0)),
+            atree(lambda: jax.random.PRNGKey(0)), aval((), jnp.int32),
             vec(jnp.float32), vec(jnp.int32), vec(jnp.float32))
     if n_cross:
         cbuf = aval((batch, lv, cfg.n_kv_heads, cfg.head_dim), jnp.bfloat16)
@@ -403,6 +403,7 @@ def wl_vllm_verify(geometry: str = "1b", *, k: int = 4, quant: bool = False,
             aval((batch, m_ctx), jnp.int32), vec(jnp.bool_),
             topo.with_sharding(topo.abstract_params(
                 lambda: jax.random.PRNGKey(0)), s),
+            aval((), jnp.int32),
             vec(jnp.float32), vec(jnp.int32), vec(jnp.float32))
     return fn, args, {
         "family": "llama", "component": "spec_verify_step", "batch": batch,

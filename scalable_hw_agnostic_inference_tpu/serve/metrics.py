@@ -93,6 +93,10 @@ _ENGINE_COUNTERS = {
     "pipeline_flushes": ("shai_engine_pipeline_flushes",
                          "Async-decode lookahead steps retired early by a "
                          "composition/control-flow event"),
+    "decode_input_uploads": ("shai_engine_decode_input_uploads",
+                             "Host-to-device arrays put for decode, verify "
+                             "and fused dispatches (a block-table refresh "
+                             "counts one)"),
 }
 #: pad/real token counters export with a ``phase`` label (prefill /
 #: chunk / decode / verify — where in a request's life the pad burned).

@@ -44,7 +44,7 @@ def bench(cfg, params, kv, ctx_blocks, n_active, paged):
         pos[b] = n_tok - 1
     args = [params, kv, jnp.zeros((B,), jnp.int32), jnp.asarray(pos),
             jnp.asarray(tables), jnp.asarray(np.arange(B) < n_active),
-            jax.random.PRNGKey(0), jnp.ones((B,), jnp.float32),
+            jax.random.PRNGKey(0), jnp.int32(0), jnp.ones((B,), jnp.float32),
             jnp.zeros((B,), jnp.int32), jnp.ones((B,), jnp.float32)]
     kv2, nxt, *_ = fn(*args)
     np.asarray(nxt)  # warm + sync

@@ -196,8 +196,9 @@ def test_a_config_with_no_window_layer_traces_no_window(monkeypatch):
            "v": sds((9, bs, 2, 16), jnp.float32)} for _ in range(2)]
     args = (params, kv, sds((B,), jnp.int32), sds((B,), jnp.int32),
             sds((B, M), jnp.int32), sds((B,), jnp.float32),
-            sds((2,), jnp.uint32), sds((B,), jnp.float32),
-            sds((B,), jnp.int32), sds((B,), jnp.float32))
+            sds((2,), jnp.uint32), sds((), jnp.int32),
+            sds((B,), jnp.float32), sds((B,), jnp.int32),
+            sds((B,), jnp.float32))
     texts = [runner.make_decode(c, bs, M, B).lower(*args).as_text()
              for c in (plain, named)]
     assert texts[0] == texts[1]

@@ -514,48 +514,6 @@ def test_finish_pending_retires_trailing_inflight(tiny_model, monkeypatch):
     assert pool_balanced(eng)
 
 
-def test_resident_tables_track_block_identity_not_count():
-    """The allocator's free list is LIFO: a shrink-then-regrow cycle
-    (speculative rollback) can hand two slots each other's freed blocks
-    with every per-row block COUNT unchanged. The resident batch view must
-    re-upload tables on block IDENTITY change, or dispatches read/write
-    the wrong physical blocks with no error."""
-    import types
-
-    from scalable_hw_agnostic_inference_tpu.engine.resident import (
-        ResidentBatch,
-    )
-
-    M = 4
-
-    class _Seq:
-        def __init__(self, blocks):
-            self.blocks = blocks
-
-        def table(self, m):
-            t = np.zeros((m,), np.int32)
-            t[:len(self.blocks)] = self.blocks
-            return t
-
-    seqs = {0: _Seq([1]), 1: _Seq([2])}
-    eng = types.SimpleNamespace(
-        cache=types.SimpleNamespace(seq=lambda rid: seqs[rid]),
-        ecfg=types.SimpleNamespace(blocks_per_seq=M),
-        _marshal_running=lambda running, Bb: {
-            "tables": np.stack([seqs[s.req.req_id].table(M)
-                                for s in running]),
-            "active": np.ones((Bb,), bool)})
-    running = [types.SimpleNamespace(req=types.SimpleNamespace(req_id=i),
-                                     slot=i) for i in range(2)]
-    res = ResidentBatch()
-    a1 = res.refresh(eng, running, 2)
-    assert np.asarray(a1["tables"]).tolist() == [[1, 0, 0, 0], [2, 0, 0, 0]]
-    # swap block identities, counts unchanged — the LIFO churn shape
-    seqs[0].blocks, seqs[1].blocks = [2], [1]
-    a2 = res.refresh(eng, running, 2)
-    assert np.asarray(a2["tables"]).tolist() == [[2, 0, 0, 0], [1, 0, 0, 0]]
-
-
 def test_async_gate_env_off_is_lockstep(tiny_model, monkeypatch):
     eng = make_engine(tiny_model, False, monkeypatch)
     eng.generate([[1, 2, 3]], SamplingParams(temperature=0.0,
