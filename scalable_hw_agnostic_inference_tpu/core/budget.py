@@ -285,6 +285,11 @@ def causal_lm_budget(cfg, ecfg, *, hbm_gib_per_chip: float = HBM_GIB["v5e"],
     kv_dtype = 1.0 if kv_quant else 2.0
     kv_bytes = (num_blocks * ecfg.block_size * n_self * 2
                 * kv_heads_chip * cfg.head_dim * kv_dtype)
+    if cfg.latent:
+        # one latent row a token a layer, whatever the heads (the boot
+        # refuses an 8-bit pool and a tp split with it)
+        kv_bytes = (num_blocks * ecfg.block_size * n_self
+                    * cfg.latent_width * 2.0)
     if kv_quant:
         kv_bytes += num_blocks * n_self * 2 * kv_heads_chip * 4.0
     if cfg.cross_attention_layers:

@@ -2,7 +2,9 @@
 
 The reference gets paged KV from vLLM's neuron fork (``block_size: 4096``,
 reference ``cova/mllama-32-11b-vllm-trn1-config.yaml:16``). TPU-natively the
-pool is one device array per layer ``[num_blocks, block_size, n_kv, head_dim]``
+pool is one device array per layer and leaf ``[num_blocks, block_size,
+<what the attention kind keeps of a token>]`` (per-head keys and values
+``n_kv, head_dim`` twice, or ONE latent row: ``models.llama.cache_leaves``)
 — block tables are *data* (int32 arrays), so one compiled executable serves
 any allocation pattern; only bucket shapes trigger compiles.
 
@@ -123,17 +125,29 @@ class SeqAllocation:
 class PagedKVCache:
     """Device block pool + per-sequence block accounting.
 
-    ``kv`` is a pytree: per layer ``{"k": [N, Bs, Hkv, Dh], "v": ...}``.
-    The jitted model paths update it functionally (donated) via
-    :func:`write_prefill` / :func:`write_decode` in ``engine.runner``.
+    ``kv`` is a pytree: per layer one array ``[N, Bs, *shape]`` for each
+    entry of ``leaves``, which says what a token costs the pool
+    (``models.llama.cache_leaves``: ``{"k": (Hkv, Dh), "v": (Hkv, Dh)}``, or
+    latent attention's one row ``{"c": (width,)}``). The jitted model paths
+    update it functionally (donated) in ``engine.runner``. Block accounting,
+    preemption and copy-on-write never look inside a block; the int8 pool
+    and the host tier do, and refuse other leaves than ``k``/``v`` by name.
     """
 
-    def __init__(self, n_layers: int, n_kv_heads: int, head_dim: int,
+    def __init__(self, n_layers: int, leaves: Dict[str, Tuple[int, ...]],
                  total_blocks: int, block_size: int, blocks_per_seq: int,
                  dtype=jnp.bfloat16, sharding=None,
                  enable_prefix_caching: bool = False, tier=None,
                  quant: bool = False):
         self.n_layers = n_layers
+        #: leaf name -> a token's shape in it (behind [N, block_size])
+        self.leaves = {name: tuple(per) for name, per in leaves.items()}
+        self._plain_kv = set(self.leaves) == {"k", "v"}
+        if quant and not self._plain_kv:
+            raise ValueError(
+                f"an int8 pool (SHAI_KV_QUANT=int8) scales per block and kv "
+                f"head: it has no form for the leaves {sorted(self.leaves)} "
+                f"(a latent cache)")
         self.block_size = block_size
         self.blocks_per_seq = blocks_per_seq
         #: int8 KV pool (SHAI_KV_QUANT): blocks live as int8 with ONE f32
@@ -157,8 +171,6 @@ class PagedKVCache:
         # head while the tail still pins blocks)
         self._parent: Dict[int, int] = {}
         self._nchild: Dict[int, int] = {}
-        shape = (total_blocks, block_size, n_kv_heads, head_dim)
-        sc_shape = (total_blocks, n_kv_heads)
 
         def zeros(name: str, shp, dt) -> jax.Array:
             z = jnp.zeros(shp, dt)
@@ -169,10 +181,12 @@ class PagedKVCache:
             return z
 
         block_dt = jnp.int8 if quant else dtype
-        self.kv = [{"k": zeros("k", shape, block_dt),
-                    "v": zeros("v", shape, block_dt)}
+        self.kv = [{name: zeros(name, (total_blocks, block_size) + per,
+                                block_dt)
+                    for name, per in self.leaves.items()}
                    for _ in range(n_layers)]
         if quant:
+            sc_shape = (total_blocks, self.leaves["k"][0])
             for lay in self.kv:
                 lay["ks"] = zeros("ks", sc_shape, jnp.float32)
                 lay["vs"] = zeros("vs", sc_shape, jnp.float32)
@@ -356,6 +370,11 @@ class PagedKVCache:
         (the cold-graph-behind-the-LB discipline)."""
         from ..kvtier.restore import make_tier_gather, make_tier_restore
 
+        if not self._plain_kv:
+            raise ValueError(
+                f"the host KV tier (SHAI_KVTIER) moves k and v blocks: it "
+                f"has no form for the leaves {sorted(self.leaves)} (a "
+                f"latent cache)")
         self.tier = tier
         self._tier_gather = make_tier_gather(quant=self.quant)
         self._tier_restore = make_tier_restore(quant=self.quant)

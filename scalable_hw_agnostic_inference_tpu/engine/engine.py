@@ -24,7 +24,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from ..core.bucketing import BucketRegistry
-from ..models.llama import LlamaConfig
+from ..models.llama import LlamaConfig, cache_leaves
 from ..obs import sentinel as obs_sentinel
 from ..obs.hbm import HbmLedger
 from ..obs.slo import SloEngine, SloTargets
@@ -136,6 +136,41 @@ class LLMEngine:
         if self._window_pool_layers and _env_flag("SHAI_KVTIER", False):
             refused.append("SHAI_KVTIER (the host KV tier) with window "
                            "layers")
+        # a latent cache (models.llama.cache_leaves: one row a token, no
+        # per-head keys or values) serves through prefill, the static
+        # continuation ladder and the absorbed decode kernel; every path
+        # that reads a pool block as k and v heads is refused with it
+        self._latent_layers = n_pool_layers if model_cfg.latent else 0
+        if model_cfg.latent:
+            for on, what in (
+                    (ecfg.tensor_parallel_size > 1,
+                     "tensor_parallel_size > 1 (one latent row serves "
+                     "every head: there is no kv-head axis to split)"),
+                    (ecfg.quantization == "int8",
+                     "quantization: int8 (no quantised latent "
+                     "projections)"),
+                    (self._kv_quant,
+                     "SHAI_KV_QUANT=int8 (an 8-bit pool scales per kv "
+                     "head)"),
+                    (ecfg.enable_prefix_caching,
+                     "enable_prefix_caching (shared latent blocks are "
+                     "not built)"),
+                    (_env_flag("SHAI_KVTIER", False),
+                     "SHAI_KVTIER (the host KV tier and the kvnet frames "
+                     "it feeds move k and v blocks)"),
+                    (ecfg.speculative_enabled,
+                     "speculative decoding (no multi-token verify over a "
+                     "latent pool)"),
+                    (_env_flag("SHAI_RAGGED_ATTENTION", False),
+                     "SHAI_RAGGED_ATTENTION (the dynamic-start "
+                     "continuation reads k and v heads)"),
+                    (_env_flag("SHAI_FUSED_STEP", False),
+                     "SHAI_FUSED_STEP (the fused step reads k and v "
+                     "heads)"),
+                    (bool(model_cfg.cross_attention_layers),
+                     "cross-attention layers")):
+                if on:
+                    refused.append(what + " with a latent cache")
         if refused:
             raise ValueError(
                 "this model's layers are not served with: "
@@ -194,14 +229,20 @@ class LLMEngine:
                 kv_sharding["vs"] = self.shardings.kv_scale
         # tokens one tile of the paged kernel covers at this engine's
         # per-shard pool shape: what the decode pad accounting counts in
-        self._attn_tile = tile_tokens(
-            ecfg.block_size,
-            model_cfg.n_kv_heads // (mesh.shape["tp"] if self.shardings
-                                     is not None else 1),
-            model_cfg.head_dim, np.int8 if self._kv_quant else kv_dtype)
+        if model_cfg.latent:
+            from ..ops.pallas.mla_paged_attention import mla_tile_tokens
+
+            self._attn_tile = mla_tile_tokens(ecfg.block_size)
+        else:
+            self._attn_tile = tile_tokens(
+                ecfg.block_size,
+                model_cfg.n_kv_heads // (mesh.shape["tp"] if self.shardings
+                                         is not None else 1),
+                model_cfg.head_dim, np.int8 if self._kv_quant else kv_dtype)
         self.cache = PagedKVCache(
-            n_pool_layers, model_cfg.n_kv_heads, model_cfg.head_dim,
-            ecfg.total_blocks, ecfg.block_size, ecfg.blocks_per_seq,
+            n_pool_layers, cache_leaves(model_cfg),
+            ecfg.total_blocks, ecfg.block_size,
+            ecfg.blocks_per_seq,
             dtype=kv_dtype,
             sharding=kv_sharding,
             enable_prefix_caching=prefix_caching,
@@ -2385,11 +2426,17 @@ class LLMEngine:
                         break  # s itself was preempted
 
     def _note_routing(self, fetched: np.ndarray, n_rows: int) -> np.ndarray:
-        """Split a routed step's one fetched array: the sampled tokens
-        (returned), and behind them what routing did — distinct experts
-        touched and the largest load on one expert, each summed over the
-        step's expert layers — which go to the ``moe`` counters with the
-        step's real rows."""
+        """Split a step's one fetched array: the sampled tokens
+        (returned), and behind them what the device counted. Of a routed
+        model, what routing did — distinct experts touched and the largest
+        load on one expert, each summed over the step's expert layers —
+        which go to the ``moe`` counters with the step's real rows. Of a
+        latent model, last, the cache rows its kernel read (``mla``)."""
+        if self._latent_layers:
+            self.obs.count_mla(self._latent_layers, int(fetched[-1]))
+            fetched = fetched[:-1]
+            if not self._moe_layers:
+                return fetched
         touched, load_max = int(fetched[-2]), int(fetched[-1])
         self.obs.count_moe(
             self._moe_layers,

@@ -25,10 +25,11 @@ import jax.numpy as jnp
 from jax.sharding import NamedSharding, PartitionSpec as P
 
 from ..models.llama import LlamaConfig
+from ..ops import mla
 from ..ops.attention import dot_product_attention
 from ..ops.moe import expert_layer, gated_mlp
 from ..ops.quant import quant_matmul
-from ..ops.rope import apply_rope
+from ..ops.rope import apply_rope, apply_rope_interleaved
 from ..ops.sampling import (
     sample_excluding,
     sample_logits,
@@ -117,6 +118,28 @@ def _embed(p: Dict, ids: jax.Array, cfg: LlamaConfig) -> jax.Array:
     return x.astype(jnp.bfloat16)
 
 
+def _latent_qk(at: Dict, h: jax.Array, q: jax.Array, pos: jax.Array,
+               cfg: LlamaConfig):
+    """Latent attention's half of a layer's projections: ``q``
+    ``[B, T, H, head_dim]`` with its rotary lanes (the last
+    ``qk_rope_head_dim``) turned, and the token's cache row ``[B, T,
+    latent_width]``: the latent under its own norm, the ONE rotary key all
+    heads share, zeros behind (``ops.mla.latent_rows``)."""
+    N, R = cfg.qk_nope_head_dim, cfg.kv_lora_rank
+    if cfg.rope_interleave:
+        turn = functools.partial(apply_rope_interleaved, positions=pos,
+                                 theta=cfg.rope_theta)
+    else:
+        turn = functools.partial(apply_rope, positions=pos,
+                                 theta=cfg.rope_theta,
+                                 scaling=cfg.rope_scaling)
+    q = jnp.concatenate([q[..., :N], turn(q[..., N:])], axis=-1)
+    ckr = _proj(h, at["kv_a"])
+    c = _rmsnorm(ckr[..., :R], at["kv_norm"]["scale"], cfg.rms_eps)
+    k_rope = turn(ckr[:, :, None, R:])[:, :, 0]
+    return q, mla.latent_rows(c, k_rope, cfg.latent_width)
+
+
 @dataclasses.dataclass(frozen=True)
 class LayerKind:
     """What one layer is, read off the model config (never a model's
@@ -147,7 +170,10 @@ def _layer(lp: Dict, kind: LayerKind, xs, positions, attend,
     attention call), ``positions`` their ``[B, T]`` cache positions.
     ``attend(qs, ks, vs, window) -> os``: the program's attention — where
     this layer's new keys and values go in the pool, and what each query
-    sees; tuples in, a tuple of ``[B, T, H, Dh]`` out. ``cross``:
+    sees; tuples in, a tuple of ``[B, T, H, Dh]`` out. With latent
+    attention (``cfg.latent``, the attention KIND) ``ks`` are the tokens'
+    cache rows ``[B, T, latent_width]`` and ``vs`` is the layer's ``kv_b``
+    leaf, which the program expands or absorbs as its phase wants. ``cross``:
     ``(k, v, has_image, cross_len)`` of a cross layer, which attends those
     and touches no pool. ``active``: per stream, the rows that hold a real
     token (bool, ``[B, T]``); padded rows route to no expert.
@@ -165,6 +191,10 @@ def _layer(lp: Dict, kind: LayerKind, xs, positions, attend,
         B, T, _ = x.shape
         h = _rmsnorm(x, lp["attn_norm"]["scale"], cfg.rms_eps)
         q = _proj(h, at["q"]).reshape(B, T, cfg.n_heads, Dh)
+        if cfg.latent:
+            q, row = _latent_qk(at, h, q, pos, cfg)
+            qs.append(q), ks.append(row), gates.append(None)
+            continue
         k = _proj(h, at["k"]).reshape(B, T, cfg.n_kv_heads, Dh)
         v = _proj(h, at["v"]).reshape(B, T, cfg.n_kv_heads, Dh)
         if cfg.qk_norm:
@@ -175,7 +205,8 @@ def _layer(lp: Dict, kind: LayerKind, xs, positions, attend,
             k = apply_rope(k, pos, cfg.rope_theta, cfg.rope_scaling)
         qs.append(q), ks.append(k), vs.append(v)
         gates.append(_proj(h, at["gate"]) if cfg.attn_gate else None)
-    os = attend(tuple(qs), tuple(ks), tuple(vs), kind.window)
+    os = attend(tuple(qs), tuple(ks),
+                at["kv_b"] if cfg.latent else tuple(vs), kind.window)
     out, stats = [], None
     for i, (x, o, g) in enumerate(zip(xs, os, gates)):
         B, T, _ = x.shape
@@ -421,6 +452,19 @@ def make_prefill(cfg: LlamaConfig, block_size: int, blocks_per_seq: int,
         positions = jnp.broadcast_to(jnp.arange(T, dtype=jnp.int32), (B, T))
         tbl = block_tables[:, :m_used]  # [B, m_used]
 
+        def attend_latent(pi, qs, rows, kv_b, window):
+            # the expanded path: every head's keys and values up-projected
+            # from the prompt's own latents, then the flash kernel (keys of
+            # head_dim beside values of v_head_dim); the pool gets the rows
+            (q,), (r,) = qs, rows
+            k, v = mla.expand(r, kv_b, cfg)
+            o = dot_product_attention(q, k, v, kv_lengths=n, causal=True,
+                                      scale=mla.softmax_scale(cfg))
+            pool = kv[pi]["c"]
+            kv[pi] = {"c": pool.at[tbl].set(r.reshape(
+                B, m_used, block_size, -1).astype(pool.dtype))}
+            return (o,)
+
         def attend(pi, qs, ks, vs, window):
             (q,), (k,), (v,) = qs, ks, vs
             # causal within the prompt; pad keys masked by the true length —
@@ -443,7 +487,8 @@ def make_prefill(cfg: LlamaConfig, block_size: int, blocks_per_seq: int,
         # gated cross-attention over vision states: no rope, no KV pool
         # traffic — its keys are static per request
         (x,), _ = _run_layers(
-            p, cfg, (x,), (positions,), attend,
+            p, cfg, (x,), (positions,),
+            attend_latent if cfg.latent else attend,
             cross=lambda ci: (cross_kv[ci]["k"], cross_kv[ci]["v"],
                               has_image, cross_len),
             active=(positions < n[:, None],), shardings=shardings)
@@ -591,8 +636,9 @@ def make_prefill_cont(cfg: LlamaConfig, block_size: int, blocks_per_seq: int,
     c_blocks = bucket // block_size
     assert ragged or start_blocks + c_blocks <= blocks_per_seq
     cross_set = set(cfg.cross_attention_layers)
-    assert not (ragged and cross_set), \
-        "ragged continuation serves text engines (the engine gate)"
+    assert not (ragged and (cross_set or cfg.latent)), \
+        "ragged continuation serves text engines with per-head keys " \
+        "(the engine gate)"
 
     def _ragged_impl(params, kv, ids, n_text, block_tables, start_arr):
         p = params["params"]
@@ -655,6 +701,21 @@ def make_prefill_cont(cfg: LlamaConfig, block_size: int, blocks_per_seq: int,
                 + jnp.arange(block_size)[None, None, :]).reshape(B, start)
         tbl_chunk = block_tables[:, start_blocks:start_blocks + c_blocks]
 
+        def attend_latent(pi, qs, rows, kv_b, window):
+            # the chunk's prefix is LATENT in the pool: its rows are
+            # gathered through the block table and expanded beside the
+            # chunk's own (one up-projection of start + T rows a layer,
+            # a third of the absorbed form's operations at these widths)
+            (q,), (r,) = qs, rows
+            pool = kv[pi]["c"]
+            prior = pool.reshape(-1, pool.shape[-1])[goff].astype(r.dtype)
+            k, v = mla.expand(jnp.concatenate([prior, r], axis=1), kv_b, cfg)
+            o = dot_product_attention(q, k, v, kv_lengths=n, causal=True,
+                                      scale=mla.softmax_scale(cfg))
+            kv[pi] = {"c": pool.at[tbl_chunk].set(r.reshape(
+                B, c_blocks, block_size, -1).astype(pool.dtype))}
+            return (o,)
+
         def attend(pi, qs, ks, vs, window):
             (q,), (k,), (v,) = qs, ks, vs
             if kv_quant:
@@ -686,7 +747,8 @@ def make_prefill_cont(cfg: LlamaConfig, block_size: int, blocks_per_seq: int,
             return (o,)
 
         (x,), _ = _run_layers(
-            p, cfg, (x,), (positions,), attend,
+            p, cfg, (x,), (positions,),
+            attend_latent if cfg.latent else attend,
             cross=lambda ci: (cross_kv[ci]["k"], cross_kv[ci]["v"],
                               has_image, cross_len),
             active=(offs < n_text[:, None],), shardings=shardings)
@@ -765,7 +827,7 @@ def _make_token_forward(cfg: LlamaConfig, block_size: int,
                 tables, jnp.clip(pblk, 0, blocks_per_seq - 1), axis=1),
             0)
         widx = blk * block_size + positions % block_size
-        if not paged and not kv_quant:
+        if not paged and not kv_quant and not cfg.latent:
             # flat gather offsets for the whole context window: [B, L]
             goff = (tables[:, :, None] * block_size
                     + jnp.arange(block_size)[None, None, :]).reshape(B, L)
@@ -773,6 +835,21 @@ def _make_token_forward(cfg: LlamaConfig, block_size: int,
             # just-written token included); padding rows see one dummy token
             behind = positions[:, :, None] - jnp.arange(L)[None, None, :]
             mask = (behind >= 0)[:, None]               # [B, 1, T, L]
+
+        def attend_latent(pi, qs, rows, kv_b, window):
+            # the absorbed path: the new tokens' rows go into the pool,
+            # the queries into the rows' own coordinates, and every head
+            # reads the row's tiles ONCE through the latent kernel (the
+            # gather reference off the TPU); W^V comes after the softmax
+            (q,), (r,) = qs, rows
+            pool = kv[pi]["c"]
+            kv[pi] = {"c": pool.reshape(-1, pool.shape[-1]).at[widx].set(
+                r.astype(pool.dtype)).reshape(pool.shape)}
+            u = mla.paged_latent_attention(
+                mla.absorb_q(q, kv_b, cfg), kv[pi]["c"], tables, positions,
+                rank=cfg.kv_lora_rank, scale=mla.softmax_scale(cfg),
+                paged=paged)
+            return (mla.unabsorb(u, kv_b, cfg),)
 
         def attend(pi, qs, ks, vs, window):
             (q,), (kk,), (vv,) = qs, ks, vs
@@ -832,7 +909,8 @@ def _make_token_forward(cfg: LlamaConfig, block_size: int,
         # slot_idx maps the COMPACTED batch row back to its slot's rows in
         # the full cross-kv buffers (gather fuses into the attention read)
         (x,), stats = _run_layers(
-            p, cfg, (x,), (positions,), attend,
+            p, cfg, (x,), (positions,),
+            attend_latent if cfg.latent else attend,
             cross=lambda ci: (cross_kv[ci]["k"][slot_idx],
                               cross_kv[ci]["v"][slot_idx], has_image,
                               cross_len),
@@ -919,6 +997,13 @@ def make_decode(cfg: LlamaConfig, block_size: int, blocks_per_seq: int,
             cross_kv=cross_kv, has_image=has_image, slot_idx=slot_idx,
             cross_len=cross_len, active=active)
         logits = logits[:, 0]  # [B, V]
+        if cfg.latent:
+            # what the latent kernel read: a live row's tokens so far, this
+            # one included, in every layer (one more int32 behind the
+            # routing counts, in the same read)
+            seen = (jnp.sum(jnp.where(active > 0, pos + 1, 0))
+                    * (cfg.n_layers - len(cross_set))).astype(jnp.int32)[None]
+            stats = seen if stats is None else jnp.concatenate([stats, seen])
         nxt = sample_logits(logits, rng, temperature, top_k, top_p)
         # logprob data rides along (tiny vs the matmuls); the engine only
         # transfers it to the host when a running request asked for it
@@ -928,7 +1013,8 @@ def make_decode(cfg: LlamaConfig, block_size: int, blocks_per_seq: int,
             top_ids, top_lp, tok_lp)
         # a routed model's step says what routing did (ROUTE_STATS int32
         # behind the sampled tokens, ONE array: the host's one read of the
-        # step carries both); a dense model's outputs are what they were
+        # step carries both), a latent one what its kernel read; a dense
+        # model's outputs are what they were
         return out if stats is None else out + (
             jnp.concatenate([nxt.astype(jnp.int32), stats]),)
 
@@ -955,7 +1041,7 @@ def make_decode(cfg: LlamaConfig, block_size: int, blocks_per_seq: int,
     if cross_set:
         in_sh += (sh.cross_pool(len(cross_set)), rep, rep, rep)
     out_sh = (kvsh,) + (rep,) * ((6 if feedback else 4)
-                                 + bool(cfg.n_experts))
+                                 + bool(cfg.n_experts or cfg.latent))
     return jax.jit(decode, donate_argnums=donate,
                    in_shardings=in_sh, out_shardings=out_sh)
 
@@ -1150,7 +1236,7 @@ def make_fused_step(cfg: LlamaConfig, block_size: int, blocks_per_seq: int,
                 tables, jnp.clip(pblk, 0, blocks_per_seq - 1), axis=1),
             0)
         widx = blk * block_size + positions % block_size
-        if not paged and not kv_quant:
+        if not paged and not kv_quant and not cfg.latent:
             goff = (tables[:, :, None] * block_size
                     + jnp.arange(block_size)[None, None, :]).reshape(B, L)
             mask = (jnp.arange(L)[None, None, :]

@@ -93,6 +93,18 @@ class LlamaConfig:
     n_dense_layers: int = 0
     route_norm: bool = True
     route_scale: float = 1.0
+    # -- the attention KIND, as data: ``kv_lora_rank`` > 0 is multi-head
+    # latent attention. A token's cache entry is then ONE normed latent of
+    # ``kv_lora_rank`` and ONE rotary key of ``qk_rope_head_dim`` shared by
+    # all heads (``cache_leaves``), queries are ``head_dim`` = nope + rope
+    # wide, values ``v_head_dim``; 0 = per-head keys and values
+    kv_lora_rank: int = 0
+    qk_nope_head_dim: int = 0
+    qk_rope_head_dim: int = 0
+    v_head_dim: int = 0
+    # rotary pairs are lanes ``(2i, 2i+1)`` (HF ``rope_interleave``), not
+    # the half-rotation's ``(i, i + D/2)``
+    rope_interleave: bool = False
 
     def __post_init__(self):
         # sequence fields normalize to tuples so configs hash and compare
@@ -112,6 +124,26 @@ class LlamaConfig:
             raise ValueError(
                 f"layer_types names {len(self.layer_types)} layers, "
                 f"n_layers is {self.n_layers}")
+        if self.latent and (
+                self.head_dim != self.qk_nope_head_dim + self.qk_rope_head_dim
+                or self.n_kv_heads != self.n_heads or not self.v_head_dim):
+            raise ValueError(
+                "latent attention: head_dim is qk_nope_head_dim + "
+                "qk_rope_head_dim, every head has its own keys "
+                "(n_kv_heads == n_heads) and v_head_dim is given")
+
+    @property
+    def latent(self) -> bool:
+        """Multi-head latent attention (the attention kind)."""
+        return self.kv_lora_rank > 0
+
+    @property
+    def latent_width(self) -> int:
+        """Lanes of one token's latent cache entry: the latent, the shared
+        rotary key behind it, and zeros up to a whole number of the TPU's
+        128 lanes (the device pads a last dim to that anyway; a declared
+        pad keeps every copy and product aligned)."""
+        return -(-(self.kv_lora_rank + self.qk_rope_head_dim) // 128) * 128
 
     def window_of(self, li: int) -> int:
         """Keys layer ``li``'s queries see behind them, themselves
@@ -143,7 +175,7 @@ class LlamaConfig:
         (the contiguous-cache flax module does not)."""
         return bool(self.n_experts or self.layer_types or self.qk_norm
                     or self.attn_gate or self.sandwich_norms
-                    or self.embed_scale)
+                    or self.embed_scale or self.latent)
 
     @classmethod
     def tiny(cls) -> "LlamaConfig":
@@ -230,6 +262,47 @@ class LlamaConfig:
             n_experts=32, n_experts_per_tok=8, n_shared_experts=1,
             moe_mlp_dim=16, n_dense_layers=1, route_norm=True,
             route_scale=2.826)
+
+    @classmethod
+    def kanana2_30b(cls, n_layers: int = 48) -> "LlamaConfig":
+        """Kanana-2-30B-A3B (``model_type: deepseek_v3``) geometry: latent
+        attention (a latent of 512 and one shared rotary key of 64 a token;
+        32 heads of 128 + 64 against values of 128, interleaved rotary
+        pairs, no q latent, no rope scaling), one leading dense layer of
+        6144, then 128 sigmoid-routed experts of 768, 6 a token, scores
+        renormalised and scaled by 2.448, beside 2 shared experts; a 128k
+        vocabulary, untied. Whole (48 layers) it is 61 GB in bf16;
+        ``n_layers`` cuts the depth."""
+        return cls(
+            vocab_size=128256, dim=2048, n_layers=n_layers, n_heads=32,
+            n_kv_heads=32, head_dim=192, mlp_dim=6144, max_seq_len=32768,
+            rope_theta=1000000.0, rms_eps=1e-6, n_experts=128,
+            n_experts_per_tok=6, n_shared_experts=2, moe_mlp_dim=768,
+            n_dense_layers=1, route_norm=True, route_scale=2.448,
+            kv_lora_rank=512, qk_nope_head_dim=128, qk_rope_head_dim=64,
+            v_head_dim=128, rope_interleave=True)
+
+    @classmethod
+    def kanana2_stage(cls) -> "LlamaConfig":
+        """One chip's pipeline stage of Kanana-2-30B-A3B: the embedding,
+        the head, the leading dense layer and six expert layers of the 48,
+        every expert held. Not a servable whole model: 8.86 GB of 61 GB."""
+        return cls.kanana2_30b(7)
+
+    @classmethod
+    def tiny_mla(cls) -> "LlamaConfig":
+        """CI-tier stand-in with Kanana-2's mechanisms: latent attention
+        (a latent of 32 and a shared rotary key of 8, interleaved pairs;
+        heads of 16 + 8 against values of 16), a dense layer and three
+        expert layers of 16 experts top-4 beside two shared ones."""
+        return cls(
+            vocab_size=512, dim=64, n_layers=4, n_heads=4, n_kv_heads=4,
+            head_dim=24, mlp_dim=128, max_seq_len=8192, rope_theta=10000.0,
+            rms_eps=1e-6, n_experts=16, n_experts_per_tok=4,
+            n_shared_experts=2, moe_mlp_dim=16, n_dense_layers=1,
+            route_norm=True, route_scale=2.448, kv_lora_rank=32,
+            qk_nope_head_dim=16, qk_rope_head_dim=8, v_head_dim=16,
+            rope_interleave=True)
 
     @classmethod
     def llama3_70b(cls) -> "LlamaConfig":
@@ -400,9 +473,9 @@ class LlamaForCausalLM(nn.Module):
         if cfg.cross_attention_layers or cfg.engine_only:
             raise ValueError(
                 "mllama configs (cross_attention_layers) and configs with "
-                "experts, window layers, head norms, an output gate or "
-                "sandwich norms run through the paged engine "
-                "(engine.runner), not the contiguous-cache flax path")
+                "experts, window layers, head norms, an output gate, "
+                "sandwich norms or latent attention run through the paged "
+                "engine (engine.runner), not the contiguous-cache flax path")
         B, T = ids.shape
         if positions is None:
             positions = jnp.broadcast_to(jnp.arange(T, dtype=jnp.int32), (B, T))
@@ -487,6 +560,17 @@ def tp_rules(axis: str = "tp") -> ShardingRules:
     ])
 
 
+def cache_leaves(cfg: LlamaConfig) -> Dict[str, Tuple[int, ...]]:
+    """What ONE token costs the paged pool in ONE layer, by leaf: the
+    shape behind ``[num_blocks, block_size]``. Per-head keys and values,
+    or — latent attention — one leaf ``c`` of ``latent_width`` lanes: the
+    normed latent, the shared rotary key, zeros to a lane multiple."""
+    if cfg.latent:
+        return {"c": (cfg.latent_width,)}
+    return {"k": (cfg.n_kv_heads, cfg.head_dim),
+            "v": (cfg.n_kv_heads, cfg.head_dim)}
+
+
 def cache_specs(
     cfg: LlamaConfig, axis: str = "tp", axis_size: int = 1
 ) -> Dict[str, P]:
@@ -548,6 +632,19 @@ def params_from_torch(model_or_sd, cfg: LlamaConfig) -> Dict[str, Any]:
     return {"params": tree}
 
 
+#: latent attention's seeded leaves, as multiples of the tier's standard
+#: deviation. Seeded weights at one deviation give an attention that is an
+#: even average over thousands of keys: a hundredth of the residual stream,
+#: the same vector in every row, so a broken rotary embedding or softmax
+#: scale would pass any comparison and every row of a batch would decode,
+#: and route, alike. A query ten times larger puts the scores' deviation
+#: near 4, where a few keys carry most of a softmax over some thousand (as
+#: in a trained model), and what the heads return is then as large as what
+#: the MLP does; a latent projection at half makes the latent's norm (it
+#: doubles it) a part of the result. Other architectures' draws are theirs.
+LATENT_Q_GAIN = 10.0
+LATENT_KVA_GAIN = 0.5
+
 #: geometry-tier weight statistics: float kernels ~ N(0, GEOMETRY_STD);
 #: int8 kernels uniform on [-127, 127] under ONE constant per-channel scale
 #: chosen so the dequantized weights have the same standard deviation
@@ -590,6 +687,11 @@ def geometry_params(cfg: LlamaConfig, dtype=jnp.bfloat16,
         raise ValueError(
             "expert layers have no int8 weights and no sharding plan yet "
             "(quantization: int8 / tensor_parallel_size > 1 with experts)")
+    if cfg.latent and (quant or mesh is not None):
+        raise ValueError(
+            "latent attention has no int8 weights and no sharding plan yet "
+            "(quantization: int8 / tensor_parallel_size > 1 with a latent "
+            "cache)")
     D, HD = cfg.dim, cfg.head_dim
     q_out, kv_out = cfg.n_heads * HD, cfg.n_kv_heads * HD
     rules = tp_rules()
@@ -605,22 +707,22 @@ def geometry_params(cfg: LlamaConfig, dtype=jnp.bfloat16,
     # 0.02 a width of 64 gives logits too flat for a broken layer to show)
     std = D ** -0.5 if jnp.dtype(dtype) == jnp.float32 else GEOMETRY_STD
 
-    def rand(path: str, shape, dt):
+    def rand(path: str, shape, dt, gain: float = 1.0):
         return _geometry_leaf(
             jax.random.fold_in(root, next(n_leaf)), shape=tuple(shape),
             dtype=jnp.dtype(dt), sharding=sharding_of(path, len(shape)),
-            std=std)
+            std=std * gain)
 
     def const(path: str, shape, value, dt):
         sh = sharding_of(path, len(shape))
         return jnp.full(shape, value, dt, device=sh)
 
-    def lin(path: str, i: int, o: int):
+    def lin(path: str, i: int, o: int, gain: float = 1.0):
         if quant:
             return {"kernel_q": rand(f"{path}/kernel_q", (i, o), jnp.int8),
                     "scale": const(f"{path}/scale", (o,),
                                    _GEOMETRY_INT8_SCALE, jnp.float32)}
-        return {"kernel": rand(f"{path}/kernel", (i, o), dtype)}
+        return {"kernel": rand(f"{path}/kernel", (i, o), dtype, gain)}
 
     def norm(path: str, n: int = D):
         return {"scale": const(f"{path}/scale", (n,), 1.0, dtype)}
@@ -671,6 +773,20 @@ def geometry_params(cfg: LlamaConfig, dtype=jnp.bfloat16,
             }
             layer["gate_attn"] = rand(f"{lp}/gate_attn", (1,), dtype)
             layer["gate_mlp"] = rand(f"{lp}/gate_mlp", (1,), dtype)
+        elif cfg.latent:
+            # HF's names: q_proj, kv_a_proj_with_mqa (the latent and the
+            # shared rotary key), kv_a_layernorm, kv_b_proj (per head: keys
+            # without position, then values), o_proj
+            at, H, R = f"{lp}/attn", cfg.n_heads, cfg.kv_lora_rank
+            layer["attn"] = {
+                "q": lin(f"{at}/q", D, q_out, LATENT_Q_GAIN),
+                "kv_a": lin(f"{at}/kv_a", D, R + cfg.qk_rope_head_dim,
+                            LATENT_KVA_GAIN),
+                "kv_norm": norm(f"{at}/kv_norm", R),
+                "kv_b": lin(f"{at}/kv_b", R,
+                            H * (cfg.qk_nope_head_dim + cfg.v_head_dim)),
+                "o": lin(f"{at}/o", H * cfg.v_head_dim, D),
+            }
         else:
             at = f"{lp}/attn"
             layer["attn"] = {

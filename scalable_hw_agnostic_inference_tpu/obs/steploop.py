@@ -247,6 +247,11 @@ class StepTelemetry:
         # its sum over dispatches beside the sum of all tokens x layers
         # held — what a per-kind allocator would have to free
         self.window: Optional[Dict[str, int]] = None
+        # what the absorbed kernel read in the decode dispatches of a model
+        # with a latent cache (counted on the device, like ``moe``):
+        # latent-layer steps, and the cache rows the steps' live rows held,
+        # summed over latent layers. None = no latent cache.
+        self.mla: Optional[Dict[str, int]] = None
         self.warmed_executables = 0  # closed-set size at readiness
         # last-step gauges (scraped between steps)
         self._gauges: Dict[str, float] = {}
@@ -419,6 +424,14 @@ class StepTelemetry:
                 m[key] = m.get(key, 0) + int(v)
             self.moe = m
 
+    def count_mla(self, layer_steps: int, tokens_visible: int) -> None:
+        with self._lock:
+            m = self.mla if self.mla is not None else {}
+            m["layer_steps"] = m.get("layer_steps", 0) + int(layer_steps)
+            m["tokens_visible"] = (m.get("tokens_visible", 0)
+                                   + int(tokens_visible))
+            self.mla = m
+
     def count_window(self, walked: int, skipped: int, visible: int,
                      dead: int, held: int) -> None:
         with self._lock:
@@ -564,6 +577,8 @@ class StepTelemetry:
                 out["moe"] = dict(self.moe)
             if self.window is not None:
                 out["window"] = dict(self.window)
+            if self.mla is not None:
+                out["mla"] = dict(self.mla)
             # the open phase's seconds so far included: two readings
             # differ by the time between them, whatever each caught open
             out["phase_s"] = dict(self.phase_s)

@@ -231,3 +231,121 @@ def engine_cases(n_heads: int, n_kv_heads: int, head_dim: int, *,
                                     False, tile_edges=True, window=window,
                                     one_seq=one_seq))
     return cases
+
+
+def _latent_flash_case(H: int, D: int, Dv: int, T: int, S: int) -> KernelCase:
+    """Latent attention's expanded prefill: causal flash over keys of ``D``
+    beside values of ``Dv``, every head its own keys; ``S > T`` is a
+    continuation chunk behind its expanded prefix."""
+    lens = [S - T + n for n in (T, T // 2 + 3)]
+
+    def make(key):
+        kq, kk, kv = jax.random.split(key, 3)
+        return (jax.random.normal(kq, (2, T, H, D), jnp.bfloat16),
+                jax.random.normal(kk, (2, S, H, D), jnp.bfloat16),
+                jax.random.normal(kv, (2, S, H, Dv), jnp.bfloat16),
+                jnp.asarray(lens, jnp.int32))
+
+    return KernelCase(
+        name=f"flash-latent-H{H}-D{D}v{Dv}-T{T}-S{S}", make_inputs=make,
+        kernel=lambda q, k, v, n, interpret: flash_attention(
+            q, k, v, causal=True, lengths=n, interpret=interpret,
+            scale=D ** -0.5),
+        oracle=lambda q, k, v, n: dot_product_attention(
+            q, k, v, causal=True, kv_lengths=n, impl="xla",
+            scale=D ** -0.5),
+        tol=TOL_BF16)
+
+
+def _latent_pool_case(H: int, width: int, rank: int, scale: float,
+                      block_size: int, blocks_per_seq: int, rows: int,
+                      tile_edges: bool = False,
+                      one_seq: bool = False) -> KernelCase:
+    """The absorbed latent kernel over ``rows`` single-query rows with
+    shuffled block tables: ragged lengths with an EMPTY row (length 0, a
+    table of zeros) among them, or lengths on both sides of a tile's edge
+    over a pool whose every block no row holds is NaN; ``one_seq``:
+    consecutive queries of one sequence, a query a row."""
+    from .mla import latent_gather_attention
+    from .pallas.mla_paged_attention import mla_paged_decode, mla_tile_tokens
+
+    L = blocks_per_seq * block_size
+    t = mla_tile_tokens(block_size)
+    if one_seq:
+        mid = t if tile_edges else L // 2 + 5
+        lens = [mid - rows // 2 + 1 + i for i in range(rows)]
+    elif tile_edges:
+        lens = [t - 1, t, t + 1, 2 * t, 2 * t + 1, 1, block_size + 3, L]
+    else:
+        lens = [0, 1, block_size + 3, L // 2 + 5, L]
+    lens = (lens * -(-rows // len(lens)))[:rows]
+    lens = [min(max(n, 0), L) for n in lens]
+    n_blocks = rows * blocks_per_seq + 1
+
+    def make(key):
+        kq, kc, kt = jax.random.split(key, 3)
+        c = jax.random.normal(kc, (n_blocks, block_size, width), jnp.float32)
+        tables = 1 + jax.random.permutation(kt, n_blocks - 1).reshape(
+            rows, blocks_per_seq).astype(jnp.int32)
+        if one_seq:
+            tables = jnp.repeat(tables[:1], rows, axis=0)
+        n = jnp.asarray(lens, jnp.int32)
+        tables = jnp.where((n > 0)[:, None], tables, 0)   # an empty slot
+        if tile_edges:
+            held = (jnp.arange(blocks_per_seq)[None, :] * block_size
+                    < n[:, None])
+            owned = jnp.zeros((n_blocks,), bool).at[0].set(True).at[
+                jnp.where(held, tables, 0).ravel()].set(True)
+            c = jnp.where(owned[:, None, None], c, jnp.nan)
+        q = jax.random.normal(kq, (rows, H, width), jnp.bfloat16)
+        return q, c.astype(jnp.bfloat16), tables, n
+
+    live = np.asarray(lens) > 0
+
+    def kernel(q, c, tables, n, interpret):
+        u = mla_paged_decode(q, c, tables, n, rank=rank, scale=scale,
+                             interpret=interpret)
+        # an empty row's output is finite and otherwise anyone's
+        return jnp.where(live[:, None, None], u, jnp.where(
+            jnp.isfinite(u.astype(jnp.float32)), 0, jnp.nan).astype(u.dtype))
+
+    def oracle(q, c, tables, n):
+        u = latent_gather_attention(
+            q[:, None], jnp.nan_to_num(c), tables, (n - 1)[:, None],
+            rank=rank, scale=scale)[:, 0]
+        return jnp.where(live[:, None, None], u, 0)
+
+    return KernelCase(
+        name=(f"mla-H{H}-w{width}r{rank}-bs{block_size}-M{blocks_per_seq}"
+              f"-b{rows}{'-edges' if tile_edges else ''}"
+              f"{'-oneseq' if one_seq else ''}"),
+        make_inputs=make, kernel=kernel, oracle=oracle, tol=TOL_BF16)
+
+
+def latent_cases(n_heads: int, head_dim: int, v_head_dim: int, width: int,
+                 rank: int, *, block_size: int = 16,
+                 buckets: Sequence[int] = (1024, 2048),
+                 max_model_len: int = 10240,
+                 max_num_seqs: int = 8) -> List[KernelCase]:
+    """The kernel calls an engine with a LATENT cache dispatches: flash
+    over keys of ``head_dim`` beside values of ``v_head_dim`` at each
+    prefill bucket and at the last continuation start, and the absorbed
+    kernel over the full block table: ragged rows with an empty one, the
+    tile's edges over a NaN-poisoned pool, and one sequence's consecutive
+    queries."""
+    M = max_model_len // block_size
+    top = max(buckets)
+    scale = head_dim ** -0.5
+    cases = [_latent_flash_case(n_heads, head_dim, v_head_dim, b, b)
+             for b in sorted(buckets)]
+    last = (max_model_len // top - 1) * top
+    if last >= top:
+        cases.append(_latent_flash_case(n_heads, head_dim, v_head_dim, top,
+                                        last + top))
+    cases.append(_latent_pool_case(n_heads, width, rank, scale, block_size,
+                                   M, max_num_seqs))
+    cases.append(_latent_pool_case(n_heads, width, rank, scale, block_size,
+                                   M, 8, tile_edges=True))
+    cases.append(_latent_pool_case(n_heads, width, rank, scale, block_size,
+                                   M, 8, tile_edges=True, one_seq=True))
+    return cases

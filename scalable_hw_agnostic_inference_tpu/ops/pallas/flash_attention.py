@@ -59,7 +59,7 @@ def flash_eligible(q, k, v, mask=None, bias=None) -> bool:
         return False
     B, T, H, D = q.shape
     S, Hkv = k.shape[1], k.shape[2]
-    if D % _MIN_D or D > 256:
+    if D % _MIN_D or D > 256 or v.shape[-1] % _MIN_D or v.shape[-1] > D:
         return False
     if not _pick_block(T, BLOCK_Q):
         return False
@@ -72,8 +72,10 @@ def _flash_kernel(lens_ref, q_ref, k_ref, v_ref, o_ref, *, scale: float,
                   causal: bool, has_lengths: bool, block_q: int, block_k: int,
                   seq_k: int, q_offset: int, window: int = 0):
     # lens_ref: [B] in SMEM (scalar-prefetch); q_ref: [BLOCK_Q, D];
-    # k_ref/v_ref: [S, D]; o_ref: [BLOCK_Q, D]. ``q_offset`` = S - T: causal
-    # queries start at key position S - T (the decode-step layout contract of
+    # k_ref: [S, D]; v_ref: [S, Dv]; o_ref: [BLOCK_Q, Dv] (Dv is D but for
+    # latent attention's expanded heads: values narrower than keys).
+    # ``q_offset`` = S - T: causal queries start at key position S - T (the
+    # decode-step layout contract of
     # ``ops.attention.dot_product_attention``).
     b = pl.program_id(0)
     qi = pl.program_id(2)
@@ -82,7 +84,7 @@ def _flash_kernel(lens_ref, q_ref, k_ref, v_ref, o_ref, *, scale: float,
 
     m0 = jnp.full((bq, 1), NEG_INF, jnp.float32)
     l0 = jnp.zeros((bq, 1), jnp.float32)
-    o0 = jnp.zeros((bq, d), jnp.float32)
+    o0 = jnp.zeros((bq, v_ref.shape[-1]), jnp.float32)
 
     # key blocks past the valid length contribute nothing; with causal also
     # skip blocks strictly above the diagonal. has_lengths is static: the
@@ -148,7 +150,9 @@ def flash_attention(
     interpret: Optional[bool] = None,
     window: int = 0,
 ) -> jax.Array:
-    """Flash attention. q ``[B,T,H,D]``, k/v ``[B,S,Hkv,D]`` → ``[B,T,H,D]``.
+    """Flash attention. q ``[B,T,H,D]``, k ``[B,S,Hkv,D]``, v
+    ``[B,S,Hkv,Dv]`` → ``[B,T,H,Dv]`` (``Dv`` is ``D`` everywhere but in
+    latent attention's expanded prefill).
 
     ``window`` (static, with ``causal``; 0 = none): query ``i`` sees keys
     ``j`` with ``0 <= i - j < window``; key blocks wholly below a query
@@ -164,6 +168,7 @@ def flash_attention(
 
     B, T, H, D = q.shape
     S, Hkv = k.shape[1], k.shape[2]
+    Dv = v.shape[-1]
     group = H // Hkv
     if window and not causal:
         raise ValueError("a window bounds causal attention only")
@@ -219,13 +224,13 @@ def flash_attention(
                              lambda b, h, i, lens: (b, h, i, 0)),
                 pl.BlockSpec((None, None, S, D),
                              lambda b, h, i, lens: (b, h // group, 0, 0)),
-                pl.BlockSpec((None, None, S, D),
+                pl.BlockSpec((None, None, S, Dv),
                              lambda b, h, i, lens: (b, h // group, 0, 0)),
             ],
-            out_specs=pl.BlockSpec((None, None, block_q, D),
+            out_specs=pl.BlockSpec((None, None, block_q, Dv),
                                    lambda b, h, i, lens: (b, h, i, 0)),
         ),
-        out_shape=jax.ShapeDtypeStruct((B, H, T, D), q.dtype),
+        out_shape=jax.ShapeDtypeStruct((B, H, T, Dv), q.dtype),
         interpret=interpret,
     )(lengths, qt, kt, vt)
     return out.transpose(0, 2, 1, 3)

@@ -62,3 +62,20 @@ def apply_rope(x: jax.Array, positions: jax.Array, theta: float = 10000.0,
     x1, x2 = jnp.split(x.astype(jnp.float32), 2, axis=-1)
     out = jnp.concatenate([x1 * cos - x2 * sin, x2 * cos + x1 * sin], axis=-1)
     return out.astype(x.dtype)
+
+
+def apply_rope_interleaved(x: jax.Array, positions: jax.Array,
+                           theta: float = 10000.0) -> jax.Array:
+    """Rotate ``x`` ``[B, T, H, D]`` by ``positions`` ``[B, T]`` with the
+    pairs on NEIGHBOURING lanes: lanes ``(2i, 2i+1)`` turn by
+    ``positions * theta ** (-2i / D)`` and stay where they are (HF's
+    ``rope_interleave``, DeepSeek-V3's layout). A dot product of two
+    vectors rotated this way is the one HF computes after its
+    de-interleaving permutation, which it applies to both sides alike."""
+    B, T, H, D = x.shape
+    cos, sin = rope_angles(positions, D, theta)           # [B, T, D/2]
+    cos, sin = cos[:, :, None, :], sin[:, :, None, :]
+    pairs = x.astype(jnp.float32).reshape(B, T, H, D // 2, 2)
+    x1, x2 = pairs[..., 0], pairs[..., 1]
+    out = jnp.stack([x1 * cos - x2 * sin, x2 * cos + x1 * sin], axis=-1)
+    return out.reshape(B, T, H, D).astype(x.dtype)

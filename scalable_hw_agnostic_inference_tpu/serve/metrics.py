@@ -116,8 +116,9 @@ _PAD_PHASE_COUNTERS = {
 #: phases tile the thread, so the rates over a window sum to one.
 _PHASE_SECONDS = ("shai_engine_phase_seconds_total",
                   "Seconds the engine-loop thread spent in each phase")
-#: what routing and the attention window did (obs.steploop ``moe`` /
-#: ``window``): one family each, the snapshot's keys under ``counter``
+#: what routing, the attention window and the latent kernel did
+#: (obs.steploop ``moe`` / ``window`` / ``mla``): one family each, the
+#: snapshot's keys under ``counter``
 _MOE_COUNTERS = ("shai_engine_moe_total",
                  "Expert routing in decode dispatches, by counter: "
                  "layer_steps, assignments, experts_touched, load_max")
@@ -126,6 +127,9 @@ _WINDOW_COUNTERS = ("shai_engine_window_total",
                     "tokens_walked, tokens_skipped, tokens_visible, "
                     "pool_tokens_dead (gauge), pool_dead_token_steps, "
                     "pool_token_steps")
+_MLA_COUNTERS = ("shai_engine_mla_total",
+                 "Latent attention in decode dispatches, by counter: "
+                 "layer_steps, tokens_visible")
 #: conformance-layer gauge families: each instrument riding the engine
 #: telemetry object exports its flat numeric snapshot verbatim under a
 #: prefix — obs.slo → shai_slo_* (per-objective burn rates + breach),
@@ -306,7 +310,8 @@ class EngineTelemetryCollector:
             c.add_metric([self.app, phase], float(secs))
         yield c
         for key, family in (("moe", _MOE_COUNTERS),
-                            ("window", _WINDOW_COUNTERS)):
+                            ("window", _WINDOW_COUNTERS),
+                            ("mla", _MLA_COUNTERS)):
             if snap.get(key):
                 c = CounterMetricFamily(*family, labels=["app", "counter"])
                 for counter, v in sorted(snap[key].items()):

@@ -217,7 +217,9 @@ def _geometry_models():
     random weights. ``trinity-mini-geometry`` is NOT a servable whole
     model: it is one chip's stage of a pipeline (the embedding, the head,
     one dense and one period of four expert layers of the 32: 8.48 GB of
-    52 GB in bf16)."""
+    52 GB in bf16), and so is ``kanana-2-geometry`` (Kanana-2-30B-A3B's
+    leading dense layer and six of its 47 expert layers, latent attention:
+    8.86 GB of 61 GB)."""
     from ...models.llama import LlamaConfig
 
     return {
@@ -226,18 +228,21 @@ def _geometry_models():
         "llama-8b-geometry": LlamaConfig.llama3_8b,
         "mistral-7b-geometry": LlamaConfig.mistral_7b,
         "trinity-mini-geometry": LlamaConfig.trinity_mini_stage,
+        "kanana-2-geometry": LlamaConfig.kanana2_stage,
     }
 
 
 def _stand_in_models():
     """CI-sized stand-ins (the hermetic tier): float32 leaves from the
     seed, the byte tokenizer, and in the ``vllm`` unit ONE tiny engine
-    shape. ``tiny-afmoe`` has ``trinity-mini-geometry``'s mechanisms."""
+    shape. ``tiny-afmoe`` has ``trinity-mini-geometry``'s mechanisms,
+    ``tiny-mla`` ``kanana-2-geometry``'s."""
     from ...models.llama import LlamaConfig
 
     return {
         "tiny": LlamaConfig.tiny,
         "tiny-afmoe": LlamaConfig.tiny_afmoe,
+        "tiny-mla": LlamaConfig.tiny_mla,
     }
 
 

@@ -47,6 +47,14 @@ class VllmService(ModelService):
     requests via the engine loop, paged KV, bucketed prefill, on-device
     sampling. ``concurrency`` widens the serving lane so requests actually
     coalesce into the running batch.
+
+    ``MODEL_ID``: a hub id, ``tiny`` / ``tiny-afmoe`` / ``tiny-mla`` (the
+    hermetic stand-ins), or a geometry id (``units/causal_lm.py``): an
+    architecture at its published widths over seeded weights. Two of those
+    are ONE CHIP'S STAGE of a pipeline and not a servable whole model:
+    ``trinity-mini-geometry`` (AFMoE: routed experts, window and full
+    layers) and ``kanana-2-geometry`` (``deepseek_v3``: a latent paged
+    cache with absorbed decode beside routed experts; 7 of 48 layers).
     """
 
     task = "text-generation"

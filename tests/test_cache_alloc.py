@@ -17,9 +17,9 @@ from scalable_hw_agnostic_inference_tpu.engine.cache import PagedKVCache
 
 
 def make_cache(**over):
-    kw = dict(n_layers=2, n_kv_heads=2, head_dim=4, total_blocks=16,
-              block_size=4, blocks_per_seq=8, dtype=jnp.float32,
-              enable_prefix_caching=True)
+    kw = dict(n_layers=2, leaves={"k": (2, 4), "v": (2, 4)},
+              total_blocks=16, block_size=4, blocks_per_seq=8,
+              dtype=jnp.float32, enable_prefix_caching=True)
     kw.update(over)
     return PagedKVCache(**kw)
 

@@ -270,7 +270,8 @@ class TableRig:
     whole-table rebuild as the engine's marshal AND as the oracle."""
 
     def __init__(self):
-        self.cache = PagedKVCache(1, 1, 4, 32, BS, M, dtype=jnp.float32)
+        self.cache = PagedKVCache(1, {"k": (1, 4), "v": (1, 4)}, 32, BS, M,
+                                  dtype=jnp.float32)
         self.ecfg = types.SimpleNamespace(blocks_per_seq=M)
         self.res = ResidentBatch()
         self.puts = 0

@@ -5,7 +5,10 @@ tier-1 on a CPU-only container and catches what interpret mode cannot — a
 block shape, layout or broadcast the TPU compiler refuses. The cases are the
 ones ``chip_smoke.py`` then runs on the chip against the XLA oracles
 (``ops.kernel_check``): Mistral-7B heads on one chip and as a tp=4 shard,
-block sizes 16 and 128, bf16 and int8-KV pools. The case builders themselves
+block sizes 16 and 128, bf16 and int8-KV pools; and Kanana-2's latent
+attention at its published widths: flash over keys of 192 beside values of
+128 at the cell's buckets and its last continuation start, the absorbed
+kernel over 640-lane rows at 64 rows. The case builders themselves
 are checked against their oracles in interpret mode by the smoke's dry run
 (``tests/test_chip_smoke.py``).
 """
@@ -25,6 +28,9 @@ def _cases():
             for c in kernel_check.engine_cases(
                     32, 8, 128, tp=tp, block_size=block_size):
                 seen.setdefault(c.name, c)   # flash repeats across blocks
+    for c in kernel_check.latent_cases(32, 192, 128, 640, 512,
+                                       max_num_seqs=64):
+        seen.setdefault(c.name, c)
     return list(seen.values())
 
 

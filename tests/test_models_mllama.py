@@ -142,7 +142,7 @@ def test_cross_attention_prefill_logits_match_hf(hf_model):
         want = out.logits[0, -1].numpy()
 
     block_size, M = 8, 4
-    cache = PagedKVCache(mcfg.n_layers, mcfg.n_kv_heads, mcfg.head_dim,
+    cache = PagedKVCache(mcfg.n_layers, llama.cache_leaves(mcfg),
                          total_blocks=8, block_size=block_size,
                          blocks_per_seq=M, dtype=jnp.float32)
     cross = make_cross_kv(mcfg)(params, jnp.asarray(states))

@@ -459,8 +459,8 @@ def test_cache_shrink_counts_rollback_tokens():
 
     from scalable_hw_agnostic_inference_tpu.engine.cache import PagedKVCache
 
-    c = PagedKVCache(1, 1, 4, total_blocks=8, block_size=4,
-                     blocks_per_seq=4, dtype=jnp.float32)
+    c = PagedKVCache(1, {"k": (1, 4), "v": (1, 4)}, total_blocks=8,
+                     block_size=4, blocks_per_seq=4, dtype=jnp.float32)
     c.admit(0, 10)  # 3 blocks
     c.extend(0, 4)  # reserve like a spec step would
     c.shrink(0, 3)  # reject 3 drafted tokens

@@ -152,8 +152,8 @@ def test_speculative_config_knobs():
 # ---------------------------------------------------------------------------
 
 def test_cache_shrink_rolls_back_trailing_blocks():
-    cache = PagedKVCache(1, 1, 4, total_blocks=16, block_size=4,
-                         blocks_per_seq=8, dtype=jnp.float32)
+    cache = PagedKVCache(1, {"k": (1, 4), "v": (1, 4)}, total_blocks=16,
+                         block_size=4, blocks_per_seq=8, dtype=jnp.float32)
     free0 = cache.allocator.n_free
     cache.admit(0, 5)                      # 2 blocks
     cache.extend(0, 7)                     # 12 tokens -> 3 blocks
@@ -169,8 +169,8 @@ def test_cache_shrink_rolls_back_trailing_blocks():
 
 
 def test_cache_shrink_keeps_partially_used_block():
-    cache = PagedKVCache(1, 1, 4, total_blocks=16, block_size=4,
-                         blocks_per_seq=8, dtype=jnp.float32)
+    cache = PagedKVCache(1, {"k": (1, 4), "v": (1, 4)}, total_blocks=16,
+                         block_size=4, blocks_per_seq=8, dtype=jnp.float32)
     cache.admit(0, 4)                      # exactly 1 full block
     cache.extend(0, 4)                     # 8 tokens -> 2 blocks
     cache.shrink(0, 3)                     # 5 tokens still need 2 blocks
