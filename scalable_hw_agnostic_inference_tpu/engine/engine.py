@@ -31,6 +31,7 @@ from ..obs.slo import SloEngine, SloTargets
 from ..obs.steploop import StepTelemetry
 from ..resilience import faults as _faults
 from ..resilience import qos as _qos
+from ..ops.moe import expert_form
 from ..ops.pallas.paged_attention import live_tile_tokens, tile_tokens
 from ..ops.sampling import sample_logits
 from .cache import PagedKVCache
@@ -1634,7 +1635,7 @@ class LLMEngine:
         ids = np.zeros((Kp, bucket), np.int32)
         n_text = np.ones((Kp,), np.int32)     # dummy rows: 1 masked token
         tables = np.zeros((Kp, M), np.int32)  # dummy rows: null block 0
-        temp = np.ones((Kp,), np.float32)
+        temp = np.zeros((Kp,), np.float32)    # dummy rows: greedy
         topk = np.zeros((Kp,), np.int32)
         topp = np.ones((Kp,), np.float32)
         for i, req in enumerate(group):
@@ -1910,7 +1911,7 @@ class LLMEngine:
                                        self._put([n], np.int32), table)
         self.obs.count_pad(n, bucket - n, phase="prefill")
         self.cache.register_prefix(head.prompt_ids, alloc.blocks)
-        temp = np.ones((Kp,), np.float32)
+        temp = np.zeros((Kp,), np.float32)    # dummy rows: greedy
         topk = np.zeros((Kp,), np.int32)
         topp = np.ones((Kp,), np.float32)
         for i, r in enumerate(group):
@@ -2430,19 +2431,24 @@ class LLMEngine:
         (returned), and behind them what the device counted. Of a routed
         model, what routing did — distinct experts touched and the largest
         load on one expert, each summed over the step's expert layers —
-        which go to the ``moe`` counters with the step's real rows. Of a
-        latent model, last, the cache rows its kernel read (``mla``)."""
+        which go to the ``moe`` counters with the step's real rows, and
+        with the form the program's rows (the bucket: one sampled token
+        each) gave its expert product. Of a latent model, last, the cache
+        rows its kernel read (``mla``)."""
         if self._latent_layers:
             self.obs.count_mla(self._latent_layers, int(fetched[-1]))
             fetched = fetched[:-1]
             if not self._moe_layers:
                 return fetched
         touched, load_max = int(fetched[-2]), int(fetched[-1])
+        fetched = fetched[:-2]
+        streamed = expert_form(len(fetched), self.cfg) == "streamed"
         self.obs.count_moe(
             self._moe_layers,
             n_rows * self.cfg.n_experts_per_tok * self._moe_layers,
-            touched, load_max)
-        return fetched[:-2]
+            touched, load_max,
+            streamed_layer_steps=self._moe_layers if streamed else 0)
+        return fetched
 
     def _note_dispatch_pad(self, running, Bb: int,
                            rows_per_seq: int = 1) -> None:
@@ -2505,7 +2511,13 @@ class LLMEngine:
         a = {
             "tables": np.zeros((Bb, M), np.int32),
             "active": np.zeros((Bb,), bool),
-            "temp": np.ones((Bb,), np.float32),
+            # a padding row is GREEDY: the sampler skips its draw and its
+            # full-vocabulary sorts only in a step where no row asks for
+            # them, and every real row's top_k is on (``global_topk``), so
+            # one padding row at temperature 1 cost an all-greedy step of
+            # 63 rows a sort of 64 x 128,256 (9.7 ms of a 26 ms decode
+            # program; PERF.md, PR 33)
+            "temp": np.zeros((Bb,), np.float32),
             "topk": np.zeros((Bb,), np.int32),
             "topp": np.ones((Bb,), np.float32),
             "slot_idx": np.zeros((Bb,), np.int32),

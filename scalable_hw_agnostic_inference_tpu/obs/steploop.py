@@ -414,13 +414,18 @@ class StepTelemetry:
                     self.dispatches_by_phase.get(phase, 0) + 1)
 
     def count_moe(self, layer_steps: int, assignments: int,
-                  experts_touched: int, load_max: int) -> None:
+                  experts_touched: int, load_max: int,
+                  streamed_layer_steps: int = 0) -> None:
+        """One decode dispatch's expert layers; ``streamed_layer_steps``:
+        those of them whose product took the streamed form (all or none:
+        ``ops.moe.expert_form`` of the dispatch's rows)."""
         with self._lock:
             m = self.moe if self.moe is not None else {}
             for key, v in (("layer_steps", layer_steps),
                            ("assignments", assignments),
                            ("experts_touched", experts_touched),
-                           ("load_max", load_max)):
+                           ("load_max", load_max),
+                           ("streamed_layer_steps", streamed_layer_steps)):
                 m[key] = m.get(key, 0) + int(v)
             self.moe = m
 

@@ -349,3 +349,59 @@ def latent_cases(n_heads: int, head_dim: int, v_head_dim: int, width: int,
     cases.append(_latent_pool_case(n_heads, width, rank, scale, block_size,
                                    M, 8, tile_edges=True, one_seq=True))
     return cases
+
+
+#: the streamed expert product against the grouped form. Rows are unit
+#: normal and leaves N(0, 0.02) at widths in the thousands, so a row's
+#: weighted sum over its experts stays under 1: the bound is two bf16 ulps
+#: there. The grouped form rounds ``g``, ``u``, ``silu(g) * u`` and each
+#: expert's result to bf16, the kernel only the weighted ``h``.
+TOL_EXPERTS = 2 * 2.0 ** -8
+
+
+def _expert_case(n_experts: int, top_k: int, D: int, F: int,
+                 rows: int) -> KernelCase:
+    """The streamed expert product (``moe_grouped_ffn_streamed``) at one
+    decode bucket: ``rows`` rows of ``top_k`` distinct experts each, drawn
+    so that some experts hold several rows and some none; the last row is
+    inactive (it chose no expert)."""
+    from . import moe
+    from .pallas.moe_ffn import moe_streamed_ffn
+
+    def make(key):
+        kx, ks, kw, kg, ku, kd = jax.random.split(key, 6)
+        leaf = lambda k, s: (jax.random.normal(k, s, jnp.float32)   # noqa: E731
+                             * 0.02).astype(jnp.bfloat16)
+        # a shared popularity plus each row's own noise: top-k of it
+        logits = (jax.random.normal(ks, (n_experts,))
+                  + jax.random.gumbel(kw, (rows, n_experts)))
+        _, sel = jax.lax.top_k(logits, top_k)
+        sel = sel.astype(jnp.int32).at[rows - 1].set(n_experts)
+        w = jnp.full((rows, top_k), 1.0 / top_k, jnp.float32)
+        return (jax.random.normal(kx, (rows, D), jnp.bfloat16), sel, w,
+                leaf(kg, (n_experts, D, F)), leaf(ku, (n_experts, D, F)),
+                leaf(kd, (n_experts, F, D)))
+
+    def sizes(sel):
+        return moe.expert_counts(sel, n_experts)
+
+    def kernel(x, sel, w, gate, up, down, interpret):
+        return moe_streamed_ffn(
+            x, *moe.streamed_operands(sel, w, sizes(sel), 0), gate, up, down,
+            interpret=interpret)
+
+    def oracle(x, sel, w, gate, up, down):
+        return moe._grouped({"gate": gate, "up": up, "down": down}, x, sel,
+                            w, sizes(sel), 0)
+
+    return KernelCase(
+        name=f"experts-E{n_experts}k{top_k}-D{D}-F{F}-b{rows}",
+        make_inputs=make, kernel=kernel, oracle=oracle, tol=TOL_EXPERTS)
+
+
+def expert_cases(n_experts: int, top_k: int, D: int, F: int, *,
+                 max_num_seqs: int = 8) -> List[KernelCase]:
+    """The streamed expert product at an engine's largest decode bucket and
+    at one small one (rows the kernel pads to a tile of sublanes)."""
+    return [_expert_case(n_experts, top_k, D, F, rows)
+            for rows in sorted({max_num_seqs, min(max_num_seqs, 8)})]

@@ -1,22 +1,48 @@
 """The routed expert layer: a sigmoid router, top-k on score plus a stored
-bias, and ONE grouped matrix product over the experts a step touched.
+bias, and ONE product over the experts a step touched, in the form the
+call's row count asks for.
 
 ``expert_layer`` serves prefill (thousands of tokens) and decode (a row's
-``k`` assignments) alike: the ``N x k`` (token, expert) assignments are
-sorted by expert, counted into group sizes, and pushed through three grouped
-products (gate, up, down: ``jax.lax.ragged_dot`` over the stacked expert
-leaves ``[E, D, F]`` / ``[E, F, D]``), then weighted and summed back onto
-their tokens. 128 dense products would cost ``E / k`` times the routed
-FLOPs; the grouped product costs the assignments' own, and reads the
-weights of the experts that hold at least one.
+``k`` assignments) alike, and ``expert_form`` — a pure function of the
+call's static row count and the model's widths, which the engine's
+accounting calls too — picks how the product is made:
+
+- ``"grouped"`` (prefill and continuation: 512-2048 tokens, a hundred rows
+  an expert): the ``N x k`` (token, expert) assignments are sorted by
+  expert, counted into group sizes, and pushed through three grouped
+  products (gate, up, down: ``jax.lax.ragged_dot`` over the stacked expert
+  leaves ``[E, D, F]`` / ``[E, F, D]``), then weighted and summed back onto
+  their tokens. It costs the assignments' own FLOPs (128 dense products
+  would cost ``E / k`` times as many) plus a sort, a gather and a scatter
+  of ``N x k`` rows, and reads the weights of the experts that hold at
+  least one. At two or three rows an expert libtpu's grouped product does
+  not overlap an expert's bytes with its weight pushes: a Kanana-2 decode
+  layer (64 rows, 114 experts touched) took 2.2 ms where its bytes take
+  1.31 (ledger, PR 32).
+- ``"streamed"`` (every decode bucket): ``ops.pallas.moe_ffn`` pushes ALL
+  the rows through every touched expert and weights them by a dense
+  ``[N, E]`` combine matrix, 0 where a row did not choose the expert: no
+  sort, no permutation, no ragged boundary, no scatter. It costs ``E_touched
+  / k`` times the routed FLOPs, which at few rows hide under the bytes:
+  each touched expert is read once, the next one in flight meanwhile. The
+  same Kanana-2 layer alone: 2.35 ms grouped, 1.43 ms streamed, 91% of its
+  bytes' time (my chip run, PR 33; ``scripts/moe_bench.py``).
+
+The bound between them comes from the chip's peaks and is no option: an
+expert's bytes over 819 GB/s against ``N`` rows of its FLOPs over 197
+TFLOP/s cross near 480 rows; ``STREAMED_MAX_ROWS`` 128 is one MXU tile of
+rows and leaves the margin the weight pushes need. Widths the kernel cannot
+tile (``D`` or ``F`` no multiple of 128: the CPU stand-ins) take the grouped
+form whatever their rows. Both forms read the same leaves in the same
+layout; nothing is repacked at load.
 
 The layer is told which experts it HOLDS (``held = (first, count)``: the
 stacked leaves are that slice of the ``E``). It routes over all ``E`` —
 every holder makes the same choice — and computes its own experts' part;
 assignments to experts held elsewhere, and every assignment of an inactive
-or padded row, go to no expert at all (they sort behind the last group and
-carry weight 0). Summing the holders' parts, plus the shared expert once,
-is the uncut layer.
+or padded row, go to no expert at all (grouped: they sort behind the last
+group and carry weight 0; streamed: a zero of the combine matrix). Summing
+the holders' parts, plus the shared expert once, is the uncut layer.
 
 No token is dropped, whatever the load: there is no capacity.
 """
@@ -34,6 +60,10 @@ from .quant import quant_matmul
 #: fused ops XLA makes of it carry it in their metadata, the ragged-dot
 #: custom call in its name)
 GROUPED_NAME = "moe_grouped_ffn"
+
+#: the most rows a call may hold and take the streamed form: one MXU tile
+#: of rows (module docstring: the peaks cross near 480)
+STREAMED_MAX_ROWS = 128
 
 
 def route(mp: Dict, x2: jax.Array, cfg) -> Tuple[jax.Array, jax.Array]:
@@ -58,41 +88,37 @@ def gated_mlp(p: Dict, x: jax.Array) -> jax.Array:
         p["down"])
 
 
-def expert_layer(mp: Dict, x: jax.Array, cfg, *,
-                 active: Optional[jax.Array] = None,
-                 held: Optional[Tuple[int, int]] = None):
-    """The routed FFN on ``x`` ``[..., D]``. Returns ``(y, stats)``:
-    ``y`` like ``x``; ``stats`` int32 ``[2]``: distinct experts that got at
-    least one assignment, and the largest assignment count on one expert
-    (both over ALL experts and the active rows).
-
-    ``active`` (bool, ``x``'s leading shape): rows that hold a real token.
-    ``held``: ``(first, count)`` of the experts ``mp["experts"]`` stacks;
-    default all."""
-    E, k = cfg.n_experts, cfg.n_experts_per_tok
-    first, count = held or (0, E)
-    lead, D = x.shape[:-1], x.shape[-1]
-    x2 = x.reshape(-1, D)
-    N = x2.shape[0]
-    sel, w = route(mp, x2, cfg)
-    if active is not None:
-        sel = jnp.where(active.reshape(N, 1), sel, E)     # to no expert
-    flat = sel.reshape(N * k)
-    # per-expert counts over every expert: the routing statistics, and
-    # (sliced) the group sizes of the experts held here
-    counts = jnp.sum(
-        flat[:, None] == jnp.arange(E, dtype=jnp.int32)[None, :],
+def expert_counts(sel: jax.Array, n_experts: int) -> jax.Array:
+    """``[n_experts]`` int32: the assignments each expert got (an id of
+    ``n_experts`` or more, a row that chose no expert, counts nowhere)."""
+    return jnp.sum(
+        sel.reshape(-1)[:, None]
+        == jnp.arange(n_experts, dtype=jnp.int32)[None, :],
         axis=0, dtype=jnp.int32)
-    stats = jnp.stack([jnp.sum(counts > 0, dtype=jnp.int32),
-                       jnp.max(counts)])
-    local = flat - first
+
+
+def expert_form(n_rows: int, cfg) -> str:
+    """``"streamed"`` or ``"grouped"``: the form the expert product of a
+    call with ``n_rows`` rows takes (module docstring). A function of the
+    static row count and the model's widths, and of nothing else."""
+    if (n_rows <= STREAMED_MAX_ROWS and cfg.dim % 128 == 0
+            and cfg.moe_mlp_dim % 128 == 0):
+        return "streamed"
+    return "grouped"
+
+
+def _grouped(ex: Dict, x2: jax.Array, sel: jax.Array, w: jax.Array,
+             sizes: jax.Array, first: int) -> jax.Array:
+    """``[N, D]`` float32: the ``N x k`` assignments sorted by expert,
+    through three grouped products, weighted and summed back."""
+    N, k = sel.shape
+    count = sizes.shape[0]
+    local = sel.reshape(N * k) - first
     mine = (local >= 0) & (local < count)
     key = jnp.where(mine, local, count)                   # others: behind
     order = jnp.argsort(key, stable=True)
     tok = order // k
     xs = x2[tok]                                          # [N * k, D]
-    sizes = counts[first:first + count]
-    ex = mp["experts"]
     with jax.named_scope(GROUPED_NAME):
         g = jax.lax.ragged_dot(xs, ex["gate"], sizes)
         u = jax.lax.ragged_dot(xs, ex["up"], sizes)
@@ -107,7 +133,71 @@ def expert_layer(mp: Dict, x: jax.Array, cfg, *,
     # each token summed
     inverse = jnp.zeros_like(order).at[order].set(
         jnp.arange(N * k, dtype=order.dtype))
-    y = d[inverse].reshape(N, k, D).sum(axis=1).astype(x.dtype)
+    return d[inverse].reshape(N, k, -1).sum(axis=1)
+
+
+def streamed_operands(sel: jax.Array, w: jax.Array, sizes: jax.Array,
+                      first: int):
+    """What the streamed kernel is told of a routing, for the ``count``
+    experts held from ``first`` on: ``(combine [N, count] float32, ids
+    [steps] int32, n_touched int32)``. ``combine`` holds a row's weight on
+    each expert, 0 where it did not choose it (an inactive row: all 0);
+    ``ids`` the touched experts in ascending order without a sort (the i-th
+    is the number of experts whose running count of touched ones is <= i),
+    behind the last one that one again; ``steps`` is the most experts the
+    rows can touch."""
+    N, k = sel.shape
+    count = sizes.shape[0]
+    held = jnp.arange(first, first + count, dtype=jnp.int32)
+    combine = jnp.sum(
+        jnp.where(sel[:, :, None] == held[None, None, :], w[:, :, None],
+                  0.0), axis=1)
+    touched = sizes > 0
+    steps = jnp.arange(min(count, N * k), dtype=jnp.int32)
+    ids = jnp.sum(jnp.cumsum(touched)[None, :] <= steps[:, None], axis=1,
+                  dtype=jnp.int32)
+    last = jnp.max(jnp.where(touched, jnp.arange(count, dtype=jnp.int32), 0))
+    return (combine, jnp.minimum(ids, last),
+            jnp.sum(touched, dtype=jnp.int32))
+
+
+def _streamed(ex: Dict, x2: jax.Array, sel: jax.Array, w: jax.Array,
+              sizes: jax.Array, first: int) -> jax.Array:
+    """``[N, D]`` float32: every row through every touched expert held
+    here, weighted by the dense combine matrix (``ops.pallas.moe_ffn``)."""
+    from .pallas.moe_ffn import moe_streamed_ffn
+
+    return moe_streamed_ffn(x2, *streamed_operands(sel, w, sizes, first),
+                            ex["gate"], ex["up"], ex["down"])
+
+
+def expert_layer(mp: Dict, x: jax.Array, cfg, *,
+                 active: Optional[jax.Array] = None,
+                 held: Optional[Tuple[int, int]] = None):
+    """The routed FFN on ``x`` ``[..., D]``. Returns ``(y, stats)``:
+    ``y`` like ``x``; ``stats`` int32 ``[2]``: distinct experts that got at
+    least one assignment, and the largest assignment count on one expert
+    (both over ALL experts and the active rows).
+
+    ``active`` (bool, ``x``'s leading shape): rows that hold a real token.
+    ``held``: ``(first, count)`` of the experts ``mp["experts"]`` stacks;
+    default all."""
+    E = cfg.n_experts
+    first, count = held or (0, E)
+    lead, D = x.shape[:-1], x.shape[-1]
+    x2 = x.reshape(-1, D)
+    N = x2.shape[0]
+    sel, w = route(mp, x2, cfg)
+    if active is not None:
+        sel = jnp.where(active.reshape(N, 1), sel, E)     # to no expert
+    # per-expert counts over every expert: the routing statistics, and
+    # (sliced) the group sizes of the experts held here
+    counts = expert_counts(sel, E)
+    stats = jnp.stack([jnp.sum(counts > 0, dtype=jnp.int32),
+                       jnp.max(counts)])
+    product = _streamed if expert_form(N, cfg) == "streamed" else _grouped
+    y = product(mp["experts"], x2, sel, w, counts[first:first + count],
+                first).astype(x.dtype)
     if cfg.n_shared_experts:
         y = y + gated_mlp(mp["shared"], x2)
     return y.reshape(*lead, D), stats

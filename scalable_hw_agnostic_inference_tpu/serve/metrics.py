@@ -121,7 +121,8 @@ _PHASE_SECONDS = ("shai_engine_phase_seconds_total",
 #: snapshot's keys under ``counter``
 _MOE_COUNTERS = ("shai_engine_moe_total",
                  "Expert routing in decode dispatches, by counter: "
-                 "layer_steps, assignments, experts_touched, load_max")
+                 "layer_steps, assignments, experts_touched, load_max, "
+                 "streamed_layer_steps")
 _WINDOW_COUNTERS = ("shai_engine_window_total",
                     "Window layers in decode dispatches, by counter: "
                     "tokens_walked, tokens_skipped, tokens_visible, "

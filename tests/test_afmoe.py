@@ -387,6 +387,43 @@ def test_routing_and_window_counters(tiny_params):
     assert "shai_engine_window" in fams
 
 
+def test_the_form_of_the_expert_product_is_counted(monkeypatch):
+    """A decode dispatch's expert layers are counted as streamed where
+    ``expert_form`` of the program's rows says so: all of them at widths
+    the kernel can tile (here 128 x 128, interpreted), none at the
+    stand-in's own (64 x 16). The counter reaches ``/stats``'s snapshot
+    and ``shai_engine_moe_total``; the tokens are the grouped form's."""
+    from scalable_hw_agnostic_inference_tpu.ops import moe
+    from scalable_hw_agnostic_inference_tpu.serve.metrics import (
+        EngineTelemetryCollector,
+    )
+
+    wide = dataclasses.replace(TINY, dim=128, moe_mlp_dim=128, n_experts=8,
+                               n_experts_per_tok=2)
+    params = geometry_params(wide, dtype=jnp.float32, seed=3)
+    sp = SamplingParams(temperature=0.0, max_new_tokens=6)
+    prompts = [_prompt(n) for n in (12, 9)]
+    assert moe.expert_form(2, wide) == "streamed"
+    eng = _engine(params, wide)
+    fins = eng.generate(prompts, sp)
+    got = eng.obs.snapshot()["moe"]
+    assert got["streamed_layer_steps"] == got["layer_steps"] > 0
+    fams = {f.name: f for f in EngineTelemetryCollector(
+        lambda: eng.obs, "t").collect()}
+    exported = {s.labels["counter"]: s.value
+                for s in fams["shai_engine_moe"].samples}
+    assert exported["streamed_layer_steps"] == got["layer_steps"]
+    monkeypatch.setattr(moe, "expert_form", lambda n, cfg: "grouped")
+    want = _engine(params, wide).generate(prompts, sp)
+    assert [f.token_ids for f in fins] == [f.token_ids for f in want]
+    monkeypatch.undo()
+    assert moe.expert_form(2, TINY) == "grouped"
+    narrow = _engine(geometry_params(TINY, dtype=jnp.float32, seed=3))
+    narrow.generate(prompts[:1], sp)
+    got = narrow.obs.snapshot()["moe"]
+    assert got["streamed_layer_steps"] == 0 < got["layer_steps"]
+
+
 def test_a_saturated_routed_engine_streams_and_counts_each_step_once(
         tiny_params, monkeypatch):
     """Five requests on two slots: the steady path retires a routed step's

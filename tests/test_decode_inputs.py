@@ -258,6 +258,36 @@ def test_uploads_are_counted_on_the_snapshot(tiny_model, monkeypatch):
         "dispatches_by_phase"]["decode"]
 
 
+@pytest.mark.parametrize("async_on", [True, False], ids=["async", "lockstep"])
+def test_a_padding_row_asks_the_sampler_for_nothing(tiny_model, monkeypatch,
+                                                    async_on):
+    """Three greedy rows in a bucket of four: the padding row is greedy
+    too, so the step stays one in which no row asks for a draw, and the
+    sampler's full-vocabulary sort behind every real row's ``top_k``
+    (``global_topk``) is skipped on the device. At temperature 1 the
+    padding row made every step with a free slot pay that sort (PR 33)."""
+    eng = make_engine(tiny_model, async_on, monkeypatch, max_num_seqs=4)
+    assert eng.ecfg.global_topk > 0
+    for n in (3, 4, 5):
+        eng.add_request(list(range(1, n + 1)),
+                        SamplingParams(temperature=0.0, max_new_tokens=6))
+    seen = []
+    put = eng._put_step
+    monkeypatch.setattr(eng, "_put_step",
+                        lambda x: seen.append(x) or put(x))
+    while eng.has_work:
+        eng.step()
+    knobs = [d for d in seen if isinstance(d, dict) and "temp" in d]
+    assert knobs and any(len(d["temp"]) > int(d["active"].sum())
+                         for d in knobs)          # a bucket with a pad row
+    for d in knobs:
+        live = np.asarray(d["active"], bool)
+        assert (np.asarray(d["topk"])[live] > 0).all()
+        assert not np.asarray(d["temp"]).any()   # no row asks for a draw
+        assert not np.asarray(d["topk"])[~live].any()
+        assert (np.asarray(d["topp"])[~live] == 1.0).all()
+
+
 # ---------------------------------------------------------------------------
 # tables by row
 # ---------------------------------------------------------------------------

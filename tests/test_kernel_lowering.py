@@ -8,9 +8,12 @@ ones ``chip_smoke.py`` then runs on the chip against the XLA oracles
 block sizes 16 and 128, bf16 and int8-KV pools; and Kanana-2's latent
 attention at its published widths: flash over keys of 192 beside values of
 128 at the cell's buckets and its last continuation start, the absorbed
-kernel over 640-lane rows at 64 rows. The case builders themselves
+kernel over 640-lane rows at 64 rows; and the streamed expert product at
+both routed cells' widths and largest decode buckets (128 experts of 2048 x
+768 at 64 rows, of 2048 x 1024 at 32). The case builders themselves
 are checked against their oracles in interpret mode by the smoke's dry run
-(``tests/test_chip_smoke.py``).
+(``tests/test_chip_smoke.py``; the expert cases by
+``tests/test_moe_ffn_kernel.py``).
 """
 
 import jax
@@ -31,6 +34,10 @@ def _cases():
     for c in kernel_check.latent_cases(32, 192, 128, 640, 512,
                                        max_num_seqs=64):
         seen.setdefault(c.name, c)
+    for top_k, F, rows in ((6, 768, 64), (8, 1024, 32)):
+        for c in kernel_check.expert_cases(128, top_k, 2048, F,
+                                           max_num_seqs=rows):
+            seen.setdefault(c.name, c)
     return list(seen.values())
 
 
