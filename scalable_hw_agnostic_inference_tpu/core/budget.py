@@ -270,7 +270,10 @@ def causal_lm_budget(cfg, ecfg, *, hbm_gib_per_chip: float = HBM_GIB["v5e"],
 
     # paged KV pool (engine.runner allocation): self-attn layers only —
     # cross layers hold the per-slot vision KV counted separately below
-    n_self = cfg.n_layers - len(cfg.cross_attention_layers)
+    # and layers that have cache rows only: a recurrent (KDA) layer costs
+    # the pool nothing and every slot a state (priced below)
+    n_state = len(getattr(cfg, "kda_layers", ()))
+    n_self = cfg.n_layers - len(cfg.cross_attention_layers) - n_state
     num_blocks = ecfg.num_blocks or (
         ecfg.max_model_len * ecfg.max_num_seqs // ecfg.block_size)
     kv_heads_chip = (cfg.n_kv_heads // tp if cfg.n_kv_heads % tp == 0
@@ -292,6 +295,15 @@ def causal_lm_budget(cfg, ecfg, *, hbm_gib_per_chip: float = HBM_GIB["v5e"],
                     * cfg.latent_width * 2.0)
     if kv_quant:
         kv_bytes += num_blocks * n_self * 2 * kv_heads_chip * 4.0
+    if n_state:
+        # the slot arena: max_num_seqs slots and the null slot, a float32
+        # state and a bf16 convolution tail a recurrent layer
+        from ..models.llama import state_leaves
+
+        per_slot = sum(
+            float(np.prod(shape)) * (4.0 if dt == "float32" else 2.0)
+            for shape, dt in state_leaves(cfg).values())
+        kv_bytes += (ecfg.max_num_seqs + 1) * n_state * per_slot
     if cfg.cross_attention_layers:
         # cross-KV buffers stay bf16 (per-slot vision states, not pooled)
         kv_bytes += (ecfg.max_num_seqs * cross_seq_len

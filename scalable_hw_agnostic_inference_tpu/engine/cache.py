@@ -122,6 +122,19 @@ class SeqAllocation:
         return t
 
 
+@dataclasses.dataclass(frozen=True)
+class RecurrentSpec:
+    """The second kind of per-sequence state: ``layers``, the places of the
+    recurrent layers in the model's (non-cross) layer order; ``leaves``,
+    what ONE slot costs in one of them, ``name -> (shape, dtype)`` (dtype
+    ``None``: the pool's own; ``models.llama.state_leaves``); ``n_slots``,
+    the engine's ``max_num_seqs``."""
+
+    layers: Tuple[int, ...]
+    leaves: Dict[str, Tuple[Tuple[int, ...], Optional[str]]]
+    n_slots: int
+
+
 class PagedKVCache:
     """Device block pool + per-sequence block accounting.
 
@@ -132,13 +145,25 @@ class PagedKVCache:
     update it functionally (donated) in ``engine.runner``. Block accounting,
     preemption and copy-on-write never look inside a block; the int8 pool
     and the host tier do, and refuse other leaves than ``k``/``v`` by name.
+
+    ``n_layers`` counts the layers that HAVE leaves: the pool is sized by
+    them. A model with recurrent layers (``recurrent``) keeps a second kind
+    of per-sequence state in the same manager: a slot-indexed ARENA a
+    recurrent layer, ``[n_slots + 1, *shape]`` for each of its
+    ``state_leaves`` (the last slot is the null slot: padded rows step
+    it, as padded tokens write block 0). ``kv`` then lists every layer in
+    model order, a paged layer's blocks or a recurrent layer's arena, and
+    the step programs carry it as the one donated pytree. A sequence of
+    such a model is admitted WITH its slot; release gives both kinds back,
+    and the ledger's feeds (``state_*``, ``leaked_bytes``) know both.
     """
 
     def __init__(self, n_layers: int, leaves: Dict[str, Tuple[int, ...]],
                  total_blocks: int, block_size: int, blocks_per_seq: int,
                  dtype=jnp.bfloat16, sharding=None,
                  enable_prefix_caching: bool = False, tier=None,
-                 quant: bool = False):
+                 quant: bool = False,
+                 recurrent: Optional["RecurrentSpec"] = None):
         self.n_layers = n_layers
         #: leaf name -> a token's shape in it (behind [N, block_size])
         self.leaves = {name: tuple(per) for name, per in leaves.items()}
@@ -190,6 +215,28 @@ class PagedKVCache:
             for lay in self.kv:
                 lay["ks"] = zeros("ks", sc_shape, jnp.float32)
                 lay["vs"] = zeros("vs", sc_shape, jnp.float32)
+        #: the recurrent layers' arenas, spliced into ``kv`` at their places
+        self.recurrent = recurrent
+        #: slot -> the sequence that holds it (recurrent models only)
+        self._slot_seq: Dict[int, int] = {}
+        self._state_bytes = self._slot_bytes = 0
+        if recurrent is not None:
+            assert not quant and sharding is None and tier is None, \
+                "recurrent state: no int8 pool, no mesh, no host tier"
+            paged = iter(self.kv)
+            self.kv = []
+            for i in range(n_layers + len(recurrent.layers)):
+                if i not in recurrent.layers:
+                    self.kv.append(next(paged))
+                    continue
+                self.kv.append({
+                    name: jnp.zeros((recurrent.n_slots + 1,) + tuple(shp),
+                                    dt or dtype)
+                    for name, (shp, dt) in recurrent.leaves.items()})
+            self._state_bytes = sum(
+                int(a.nbytes) for i in recurrent.layers
+                for a in self.kv[i].values())
+            self._slot_bytes = self._state_bytes // (recurrent.n_slots + 1)
         self._seqs: Dict[int, SeqAllocation] = {}
         self.total_blocks = total_blocks
         # fixed device allocation: price it ONCE (the HBM ledger reads it
@@ -197,7 +244,8 @@ class PagedKVCache:
         # Every leaf counts, scale arrays included: shai_hbm_kv_pool_bytes
         # must show the REAL int8 pool cost, not the bf16 one
         self._pool_bytes = sum(int(a.nbytes)
-                               for lay in self.kv for a in lay.values())
+                               for lay in self.kv for a in lay.values()
+                               ) - self._state_bytes
         # telemetry counters (obs.steploop reads them through the engine):
         # speculative rollbacks give reserved tokens/blocks back via shrink —
         # a high rollback rate is the "drafter wasting pool headroom" signal
@@ -617,14 +665,26 @@ class PagedKVCache:
         return self._blocks_needed(n_tokens) <= self.n_available
 
     def admit(self, seq_id: int, n_tokens: int,
-              reuse_blocks: Optional[List[int]] = None) -> SeqAllocation:
+              reuse_blocks: Optional[List[int]] = None,
+              slot: Optional[int] = None) -> SeqAllocation:
         """Allocate blocks to cover ``n_tokens`` prompt tokens.
 
         ``reuse_blocks``: cached prefix blocks to share (prefix caching) —
         they are increfed, and only the remainder is freshly allocated.
+        ``slot``: the arena slot the sequence's recurrent state lives in
+        (a model with recurrent layers admits with one, and only a free
+        one; nothing is cleared: a prefill from position 0 overwrites it).
         """
         if seq_id in self._seqs:
             raise ValueError(f"seq {seq_id} already admitted")
+        if self.recurrent is not None:
+            if slot is None or not 0 <= slot < self.recurrent.n_slots:
+                raise ValueError(
+                    f"seq {seq_id}: a model with recurrent layers admits "
+                    f"with a slot in [0, {self.recurrent.n_slots})")
+            if slot in self._slot_seq:
+                raise ValueError(f"slot {slot} is held by seq "
+                                 f"{self._slot_seq[slot]}")
         reuse = list(reuse_blocks or [])
         need = self._blocks_needed(n_tokens) - len(reuse)
         assert need >= 0, "reuse longer than the prompt"
@@ -639,6 +699,8 @@ class PagedKVCache:
             raise
         alloc = SeqAllocation(seq_id, reuse + fresh, n_tokens)
         self._seqs[seq_id] = alloc
+        if self.recurrent is not None:
+            self._slot_seq[slot] = seq_id
         return alloc
 
     def fork_sequence(self, parent_id: int, child_id: int) -> SeqAllocation:
@@ -761,6 +823,8 @@ class PagedKVCache:
     def release(self, seq_id: int) -> None:
         alloc = self._seqs.pop(seq_id)
         self.allocator.free(alloc.blocks)  # cached blocks survive (cache ref)
+        for slot in [s for s, q in self._slot_seq.items() if q == seq_id]:
+            del self._slot_seq[slot]       # the state stays: nobody's now
 
     def seq(self, seq_id: int) -> SeqAllocation:
         return self._seqs[seq_id]
@@ -801,9 +865,35 @@ class PagedKVCache:
 
     @property
     def leaked_bytes(self) -> float:
+        """Both kinds of per-sequence state no live holder explains."""
         if self.total_blocks <= 0:
-            return 0.0
-        return self.pool_bytes * (self.leaked_blocks / self.total_blocks)
+            return float(self.state_leaked_bytes)
+        return (self.pool_bytes * (self.leaked_blocks / self.total_blocks)
+                + self.state_leaked_bytes)
+
+    # -- the recurrent arena's feeds ---------------------------------------
+
+    @property
+    def state_bytes(self) -> int:
+        """Device bytes of the recurrent layers' arenas (all slots and the
+        null slot; 0 for a model without recurrent layers)."""
+        return self._state_bytes
+
+    @property
+    def slots_live(self) -> int:
+        return len(self._slot_seq)
+
+    @property
+    def state_used_bytes(self) -> int:
+        """Arena bytes of the slots admitted sequences hold."""
+        return self._slot_bytes * len(self._slot_seq)
+
+    @property
+    def state_leaked_bytes(self) -> int:
+        """Arena bytes of slots marked held whose sequence is gone: always
+        0 in a correct engine (release gives the slot back)."""
+        return self._slot_bytes * sum(
+            q not in self._seqs for q in self._slot_seq.values())
 
     @property
     def active(self) -> List[int]:

@@ -24,7 +24,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from ..core.bucketing import BucketRegistry
-from ..models.llama import LlamaConfig, cache_leaves
+from ..models.llama import LlamaConfig, cache_leaves, state_leaves
 from ..obs import sentinel as obs_sentinel
 from ..obs.hbm import HbmLedger
 from ..obs.slo import SloEngine, SloTargets
@@ -34,7 +34,7 @@ from ..resilience import qos as _qos
 from ..ops.moe import expert_form
 from ..ops.pallas.paged_attention import live_tile_tokens, tile_tokens
 from ..ops.sampling import sample_logits
-from .cache import PagedKVCache
+from .cache import PagedKVCache, RecurrentSpec
 from .config import EngineConfig
 from .resident import InflightStep, ResidentBatch, composition_sig
 from .runner import FOLD_STRIDE, make_decode, make_prefill
@@ -101,8 +101,12 @@ class LLMEngine:
             self.shardings = EngineShardings(mesh, params, model_cfg)
         # cross layers own no pool entries — sizing the pool by self-attn
         # layer count returns ~20% of KV HBM on 11B-Vision to real blocks
+        # ... and a recurrent (KDA) layer's state is a slot's, not the
+        # pool's: the pool is sized by the layers that have cache rows
+        n_state_layers = len(model_cfg.kda_layers)
         n_pool_layers = (model_cfg.n_layers
-                         - len(model_cfg.cross_attention_layers))
+                         - len(model_cfg.cross_attention_layers)
+                         - n_state_layers)
         kv_dtype = jnp.bfloat16 if ecfg.dtype == "bfloat16" else jnp.float32
         # int8 KV-block quantization (SHAI_KV_QUANT=int8, default off):
         # the pool holds int8 blocks + per-(block, head) f32 scales — ~2x
@@ -172,6 +176,42 @@ class LLMEngine:
                      "cross-attention layers")):
                 if on:
                     refused.append(what + " with a latent cache")
+        # recurrent slot state (KDA layers beside the pool) serves through
+        # prefill from position 0, the static continuation ladder (a chunk
+        # reads its slot's state) and one recurrent step a row; every path
+        # that rebuilds a sequence from pool blocks alone, rolls tokens
+        # back, or moves a sequence without its state is refused with it
+        self._state_layers = n_state_layers
+        if n_state_layers:
+            for on, what in (
+                    (ecfg.enable_prefix_caching,
+                     "enable_prefix_caching (a cached block run restores "
+                     "no state)"),
+                    (_env_flag("SHAI_KVTIER", False),
+                     "SHAI_KVTIER (the host KV tier, the kvnet frames and "
+                     "the migration it feeds move blocks, not a state)"),
+                    (ecfg.speculative_enabled,
+                     "speculative decoding (a rejected draft cannot be "
+                     "rolled back out of a state)"),
+                    (_env_flag("SHAI_RAGGED_ATTENTION", False),
+                     "SHAI_RAGGED_ATTENTION (the dynamic-start "
+                     "continuation carries no slot)"),
+                    (_env_flag("SHAI_FUSED_STEP", False),
+                     "SHAI_FUSED_STEP (the fused step carries no slot)"),
+                    (_env_flag("SHAI_KV_COW", False),
+                     "SHAI_KV_COW (a forked sibling has no copy of the "
+                     "state)"),
+                    (ecfg.tensor_parallel_size > 1,
+                     "tensor_parallel_size > 1 (no head axis on the state "
+                     "arena yet)"),
+                    (ecfg.quantization == "int8",
+                     "quantization: int8 (no quantised KDA projections)"),
+                    (self._kv_quant,
+                     "SHAI_KV_QUANT=int8 (the state is float32)"),
+                    (bool(model_cfg.cross_attention_layers),
+                     "cross-attention layers")):
+                if on:
+                    refused.append(what + " with recurrent state")
         if refused:
             raise ValueError(
                 "this model's layers are not served with: "
@@ -249,7 +289,13 @@ class LLMEngine:
             enable_prefix_caching=prefix_caching,
             tier=tier,
             quant=self._kv_quant,
+            recurrent=RecurrentSpec(
+                model_cfg.kda_layers, state_leaves(model_cfg),
+                ecfg.max_num_seqs) if n_state_layers else None,
         )
+        #: the arena's null slot: what a dummy prefill row and a padded
+        #: decode row carry
+        self._null_slot = ecfg.max_num_seqs
         self.buckets = BucketRegistry(sorted(ecfg.context_encoding_buckets))
         # chunked-prefill prompt cap: whole bucket-sized chunks only (the
         # continuation ladder is a static set of start offsets), and at
@@ -470,6 +516,9 @@ class LLMEngine:
         params = (params or SamplingParams()).clamp(self.ecfg)
         if not prompt_ids:
             raise ValueError("empty prompt")
+        if prefix is not None and self._state_layers:
+            raise ValueError("a soft prefix is not served with recurrent "
+                             "state (its prefill carries no slot)")
         if cross_states is not None:
             if self._cross_kv is None:
                 raise ValueError("model has no cross-attention layers")
@@ -1050,6 +1099,14 @@ class LLMEngine:
         self._step_uploads += len(jax.tree.leaves(x))
         return self._put(x)
 
+    def _slot_args(self, slots: Sequence[int]) -> list:
+        """Trailing argument of a prefill or continuation program of a
+        model with recurrent layers: the rows' arena slots, as data (a
+        dummy row's is the null slot). Every other model's take none."""
+        if not self._state_layers:
+            return []
+        return [self._put(slots, np.int32)]
+
     def _fold(self) -> np.int32:
         """What this step's decode-family program folds into the base key,
         for a step that puts its inputs from the host. The steady path
@@ -1157,6 +1214,10 @@ class LLMEngine:
         if self._cross_kv is not None:
             args += [self._cross_kv, a["has_image"], a["slot_idx"],
                      a["cross_len"]]
+        elif self._state_layers:
+            args.append(a["slot_idx"])
+            self.obs.count_kda(rows_stepped=len(running)
+                               * self._state_layers)
         cold = self._pipe is None
         with self.obs.phase("engine.decode"):
             t_d = self.obs.phase_t0
@@ -1266,7 +1327,9 @@ class LLMEngine:
             rollback_tokens=rb - self._last_rollback_tokens,
             spec=self.spec.as_dict() if self.spec is not None else None,
             finished_ids=[f.req_id for f in self._done_this_step],
-            tenants=tenants, input_uploads=self._step_uploads)
+            tenants=tenants, input_uploads=self._step_uploads,
+            state_slots=(self.cache.slots_live if self._state_layers
+                         else None))
         self._step_uploads = 0
         self._last_rollback_tokens = rb
         # first-use executable builds are warmup, not throughput: a step
@@ -1329,6 +1392,8 @@ class LLMEngine:
                  "inflight": inflight}
         if self._cross_kv is not None:
             pools["cross_kv"] = self._cross_bytes
+        if self._state_layers:
+            pools["recurrent_state"] = self.cache.state_bytes
         stats = None
         dev = self._hbm_dev
         if dev.platform != "cpu":
@@ -1360,7 +1425,9 @@ class LLMEngine:
             drift_value=drift,
             host_pools=host_pools,
             extra={"kv_used_bytes": kv_used,
-                   "kv_leaked_bytes": kv_leaked})
+                   "kv_leaked_bytes": kv_leaked,
+                   **({"state_used_bytes": self.cache.state_used_bytes}
+                      if self._state_layers else {})})
 
     def _finish(self, fin: Finished) -> None:
         self.finished.append(fin)
@@ -1521,7 +1588,7 @@ class LLMEngine:
         P = req.prefix_len
         n_text = len(req.prompt_ids)
         bucket = self.buckets.bucket_for(n)
-        alloc = self.cache.admit(req.req_id, n)
+        alloc = self.cache.admit(req.req_id, n, slot=slot)
         table = self._put(alloc.table(self.ecfg.blocks_per_seq)[None])
         ids = np.zeros((1, bucket - P), np.int32)
         ids[0, :n_text] = req.prompt_ids
@@ -1590,6 +1657,10 @@ class LLMEngine:
         while kmax & (kmax - 1):
             kmax &= kmax - 1
         group: List[Request] = []
+        # the i-th admitted request takes the i-th free slot (what
+        # _free_slot hands out below, in order); a model with recurrent
+        # layers is admitted WITH it
+        free_slots = [i for i, s in enumerate(self.slots) if s is None]
         bucket = -1
         first = True
         while self.waiting and len(group) < kmax:
@@ -1625,7 +1696,7 @@ class LLMEngine:
             bucket = b
             self.waiting.popleft()
             self._note_admitted(req)
-            self.cache.admit(req.req_id, n)
+            self.cache.admit(req.req_id, n, slot=free_slots[len(group)])
             group.append(req)
         if not group:
             return
@@ -1648,6 +1719,10 @@ class LLMEngine:
         fn = self._prefill_for(bucket, 0, Kp)
         args = [self.params, self.cache.kv, self._put(ids),
                 self._put(n_text), self._put(tables)]
+        args += self._slot_args(
+            free_slots[:K] + [self._null_slot] * (Kp - K))
+        self.obs.count_kda(prefill_tokens=int(n_text[:K].sum())
+                           * self._state_layers)
         if self._cross_kv is not None:  # text-only rows through a cross model
             args += [self._cross_zeros(Kp),
                      self._put(np.zeros((Kp,), np.float32)),
@@ -1963,13 +2038,14 @@ class LLMEngine:
             return
         self.waiting.popleft()
         self._note_admitted(req)
-        self.cache.admit(req.req_id, n_total)
+        self.cache.admit(req.req_id, n_total, slot=slot)
         table = self._put(
             self.cache.seq(req.req_id).table(self.ecfg.blocks_per_seq)[None])
         ids = np.asarray(req.prompt_ids[:C], np.int32)[None]
         fn = self._prefill_for(C, 0, 1)
         args = [self.params, self.cache.kv, self._put(ids),
-                self._put([C], np.int32), table]
+                self._put([C], np.int32), table] + self._slot_args([slot])
+        self.obs.count_kda(prefill_tokens=C * self._state_layers)
         self._has_image[slot] = 0.0
         if self._cross_kv is not None:
             # seat the vision states (or the text-only gate-off) in the slot
@@ -2031,6 +2107,11 @@ class LLMEngine:
             args = [self.params, self.cache.kv, self._put(ids),
                     self._put([n], np.int32), table]
             args += self._cont_args(start)  # ragged: start rides as data
+            # recurrent layers: the chunk reads its slot's state, not the
+            # pool, and writes it back
+            args += self._slot_args([s.slot])
+            self.obs.count_kda(prefill_tokens=n * self._state_layers,
+                               chunk_carries=bool(self._state_layers))
             if self._cross_kv is not None:
                 args += list(self._slot_cross_args(s.slot))
             with self.obs.phase("engine.chunk"):
@@ -2720,7 +2801,8 @@ class LLMEngine:
 
         cols = ("tables", "active", "temp", "topk", "topp") + (
             ("has_image", "slot_idx", "cross_len")
-            if self._cross_kv is not None else ())
+            if self._cross_kv is not None else ()) + (
+            ("slot_idx",) if self._state_layers else ())
         d = self._put_step({"tokens": tokens, "pos": pos,
                             "fold": self._fold(),
                             **{k: a[k] for k in cols}})
@@ -2730,6 +2812,10 @@ class LLMEngine:
         if self._cross_kv is not None:
             args += [self._cross_kv, d["has_image"], d["slot_idx"],
                      d["cross_len"]]
+        elif self._state_layers:
+            args.append(d["slot_idx"])
+            self.obs.count_kda(rows_stepped=len(running)
+                               * self._state_layers)
         with self.obs.phase("engine.decode"):
             t_d = self.obs.phase_t0
             (self.cache.kv, nxt, top_ids_d, top_lp_d, tok_lp_d,

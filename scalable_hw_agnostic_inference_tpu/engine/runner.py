@@ -25,8 +25,8 @@ import jax.numpy as jnp
 from jax.sharding import NamedSharding, PartitionSpec as P
 
 from ..models.llama import LlamaConfig
-from ..ops import mla
-from ..ops.attention import dot_product_attention
+from ..ops import kda, mla
+from ..ops.attention import dot_product_attention, on_tpu_platform
 from ..ops.moe import expert_layer, gated_mlp
 from ..ops.quant import quant_matmul
 from ..ops.rope import apply_rope, apply_rope_interleaved
@@ -119,14 +119,17 @@ def _embed(p: Dict, ids: jax.Array, cfg: LlamaConfig) -> jax.Array:
 
 
 def _latent_qk(at: Dict, h: jax.Array, q: jax.Array, pos: jax.Array,
-               cfg: LlamaConfig):
+               cfg: LlamaConfig, rope: bool = True):
     """Latent attention's half of a layer's projections: ``q``
     ``[B, T, H, head_dim]`` with its rotary lanes (the last
     ``qk_rope_head_dim``) turned, and the token's cache row ``[B, T,
     latent_width]``: the latent under its own norm, the ONE rotary key all
-    heads share, zeros behind (``ops.mla.latent_rows``)."""
+    heads share, zeros behind (``ops.mla.latent_rows``). ``rope`` False (a
+    layer without positional embedding): those lanes are plain lanes."""
     N, R = cfg.qk_nope_head_dim, cfg.kv_lora_rank
-    if cfg.rope_interleave:
+    if not rope:
+        turn = lambda x: x                                    # noqa: E731
+    elif cfg.rope_interleave:
         turn = functools.partial(apply_rope_interleaved, positions=pos,
                                  theta=cfg.rope_theta)
     else:
@@ -145,17 +148,20 @@ class LayerKind:
     """What one layer is, read off the model config (never a model's
     name): gated cross-attention over vision states or self-attention;
     the keys a query sees behind it (0 = all); rotary embedding or none;
-    a routed FFN or the dense MLP."""
+    a routed FFN or the dense MLP; ``kda``: linear attention (``ops.kda``),
+    whose state is a slot's and not the pool's."""
     cross: bool = False
     window: int = 0
     rope: bool = True
     moe: bool = False
+    kda: bool = False
 
 
 def layer_kinds(cfg: LlamaConfig) -> List[LayerKind]:
     cross = set(cfg.cross_attention_layers)
     return [LayerKind(cross=li in cross, window=cfg.window_of(li),
-                      rope=cfg.rope_of(li), moe=cfg.moe_of(li))
+                      rope=cfg.rope_of(li), moe=cfg.moe_of(li),
+                      kda=cfg.kda_of(li))
             for li in range(cfg.n_layers)]
 
 
@@ -173,7 +179,11 @@ def _layer(lp: Dict, kind: LayerKind, xs, positions, attend,
     sees; tuples in, a tuple of ``[B, T, H, Dh]`` out. With latent
     attention (``cfg.latent``, the attention KIND) ``ks`` are the tokens'
     cache rows ``[B, T, latent_width]`` and ``vs`` is the layer's ``kv_b``
-    leaf, which the program expands or absorbs as its phase wants. ``cross``:
+    leaf, which the program expands or absorbs as its phase wants. In a KDA
+    layer (``kind.kda``) ``qs`` are the NORMED streams ``[B, T, dim]`` and
+    ``ks`` the layer's attention leaves: the program's closure makes the
+    recurrence's operands (``ops.kda.inputs``), runs its phase of it over
+    its slots and hands back the gated outputs ``[B, T, H * d]``. ``cross``:
     ``(k, v, has_image, cross_len)`` of a cross layer, which attends those
     and touches no pool. ``active``: per stream, the rows that hold a real
     token (bool, ``[B, T]``); padded rows route to no expert.
@@ -190,9 +200,12 @@ def _layer(lp: Dict, kind: LayerKind, xs, positions, attend,
     for x, pos in zip(xs, positions):
         B, T, _ = x.shape
         h = _rmsnorm(x, lp["attn_norm"]["scale"], cfg.rms_eps)
+        if kind.kda:
+            qs.append(h), gates.append(None)
+            continue
         q = _proj(h, at["q"]).reshape(B, T, cfg.n_heads, Dh)
         if cfg.latent:
-            q, row = _latent_qk(at, h, q, pos, cfg)
+            q, row = _latent_qk(at, h, q, pos, cfg, kind.rope)
             qs.append(q), ks.append(row), gates.append(None)
             continue
         k = _proj(h, at["k"]).reshape(B, T, cfg.n_kv_heads, Dh)
@@ -205,8 +218,11 @@ def _layer(lp: Dict, kind: LayerKind, xs, positions, attend,
             k = apply_rope(k, pos, cfg.rope_theta, cfg.rope_scaling)
         qs.append(q), ks.append(k), vs.append(v)
         gates.append(_proj(h, at["gate"]) if cfg.attn_gate else None)
-    os = attend(tuple(qs), tuple(ks),
-                at["kv_b"] if cfg.latent else tuple(vs), kind.window)
+    if kind.kda:
+        os = attend(tuple(qs), at, None, 0)
+    else:
+        os = attend(tuple(qs), tuple(ks),
+                    at["kv_b"] if cfg.latent else tuple(vs), kind.window)
     out, stats = [], None
     for i, (x, o, g) in enumerate(zip(xs, os, gates)):
         B, T, _ = x.shape
@@ -221,7 +237,8 @@ def _layer(lp: Dict, kind: LayerKind, xs, positions, attend,
         if kind.moe:
             f, st = expert_layer(
                 lp["moe"], m, cfg,
-                active=None if active is None else active[i])
+                active=None if active is None else active[i],
+                held=cfg.held)
             stats = st if stats is None else stats + st
         else:
             f = _mlp(lp, m)
@@ -232,12 +249,13 @@ def _layer(lp: Dict, kind: LayerKind, xs, positions, attend,
 
 
 def _run_layers(p: Dict, cfg: LlamaConfig, xs, positions, attend, *,
-                cross=None, active=None, shardings=None):
+                cross=None, active=None, shardings=None, attend_kda=None):
     """Walk the stack through :func:`_layer`. ``attend(pi, qs, ks, vs,
     window)`` gets the layer's POOL index first (cross layers own no pool
-    entry); ``cross(ci) -> (k, v, has_image, cross_len)`` serves the
-    ``ci``-th cross layer. Returns ``(xs, stats)``, the routed layers'
-    stats summed (``None`` with no routed layer)."""
+    entry; a KDA layer's entry of the state list is its slot arena, and
+    ``attend_kda`` is its closure); ``cross(ci) -> (k, v, has_image,
+    cross_len)`` serves the ``ci``-th cross layer. Returns ``(xs, stats)``,
+    the routed layers' stats summed (``None`` with no routed layer)."""
     ci = pi = 0
     stats = None
     for li, kind in enumerate(layer_kinds(cfg)):
@@ -248,7 +266,8 @@ def _run_layers(p: Dict, cfg: LlamaConfig, xs, positions, attend, *,
             ci += 1
             continue
         xs, st = _layer(lp, kind, xs, positions,
-                        functools.partial(attend, pi), cfg, active=active)
+                        functools.partial(attend_kda if kind.kda else attend,
+                                          pi), cfg, active=active)
         pi += 1
         if st is not None:
             stats = st if stats is None else stats + st
@@ -402,6 +421,15 @@ def _scatter_blocks(kv_layer: Dict, tbl: jax.Array, k: jax.Array,
             "v": kv_layer["v"].at[tbl].set(v.astype(kv_layer["v"].dtype))}
 
 
+def _write_slots(state: Dict, slots: jax.Array, s: jax.Array,
+                 tail: jax.Array) -> Dict:
+    """A KDA layer's slot arena with ``slots``' states and tails replaced
+    (``[K, ...]`` each): THE write seam of prefill and continuation. A
+    dummy row's slot is the null slot (the arena's last)."""
+    return {"s": state["s"].at[slots].set(s.astype(state["s"].dtype)),
+            "t": state["t"].at[slots].set(tail.astype(state["t"].dtype))}
+
+
 def _pool_scales(kv_layer: Dict):
     """``(k_scale, v_scale)`` of an int8 pool layer, ``(None, None)`` for a
     float pool — the read-side twin of :func:`_scatter_blocks`."""
@@ -441,7 +469,8 @@ def make_prefill(cfg: LlamaConfig, block_size: int, blocks_per_seq: int,
     cross_set = set(cfg.cross_attention_layers)
 
     def _prefill_impl(params, kv, ids, n_text, block_tables, prefix=None,
-                      cross_kv=None, has_image=None, cross_len=None):
+                      cross_kv=None, has_image=None, cross_len=None,
+                      slots=None):
         p = params["params"]
         B = ids.shape[0]  # == n_seqs
         x = _embed(p, ids, cfg)
@@ -464,6 +493,17 @@ def make_prefill(cfg: LlamaConfig, block_size: int, blocks_per_seq: int,
             kv[pi] = {"c": pool.at[tbl].set(r.reshape(
                 B, m_used, block_size, -1).astype(pool.dtype))}
             return (o,)
+
+        def attend_kda(pi, hs, at, _vs, _window):
+            # from position 0 the scan starts from a ZERO state and a zero
+            # tail, whatever the slot held: a reused slot needs no clearing.
+            # The bucket's padded tail is identity tokens, so what is
+            # written is the state and tail the last REAL token left
+            (h,) = hs
+            q, k, v, g, beta, tail = kda.inputs(at, h, None, n, cfg)
+            o, s = kda.scan(q, k, v, g, beta, kernel=on_tpu_platform())
+            kv[pi] = _write_slots(kv[pi], slots, s, tail)
+            return (kda.output(at, h, o, cfg),)
 
         def attend(pi, qs, ks, vs, window):
             (q,), (k,), (v,) = qs, ks, vs
@@ -491,12 +531,22 @@ def make_prefill(cfg: LlamaConfig, block_size: int, blocks_per_seq: int,
             attend_latent if cfg.latent else attend,
             cross=lambda ci: (cross_kv[ci]["k"], cross_kv[ci]["v"],
                               has_image, cross_len),
-            active=(positions < n[:, None],), shardings=shardings)
+            active=(positions < n[:, None],), shardings=shardings,
+            attend_kda=attend_kda)
         last = jnp.take_along_axis(x, (n - 1).reshape(B, 1, 1), axis=1)
         return kv, _logits(p, last, cfg)[:, 0]  # [B, V]
 
     # positional signature per variant (in_shardings needs positional args)
-    if cross_set:
+    if cfg.recurrent:
+        # recurrent slot state beside the pool: the rows' SLOTS ride as data
+        # (a dummy row carries the null slot). The boot refuses a soft
+        # prefix, cross layers and a mesh with it
+        assert not prefix_len and not cross_set and shardings is None
+
+        def prefill(params, kv, ids, n_text, block_tables, slots):
+            return _prefill_impl(params, kv, ids, n_text, block_tables,
+                                 slots=slots)
+    elif cross_set:
         assert not prefix_len, "mllama prefill: cross states, not soft prefix"
 
         def prefill(params, kv, ids, n_text, block_tables, cross_kv,
@@ -636,7 +686,7 @@ def make_prefill_cont(cfg: LlamaConfig, block_size: int, blocks_per_seq: int,
     c_blocks = bucket // block_size
     assert ragged or start_blocks + c_blocks <= blocks_per_seq
     cross_set = set(cfg.cross_attention_layers)
-    assert not (ragged and (cross_set or cfg.latent)), \
+    assert not (ragged and (cross_set or cfg.latent or cfg.recurrent)), \
         "ragged continuation serves text engines with per-head keys " \
         "(the engine gate)"
 
@@ -688,7 +738,7 @@ def make_prefill_cont(cfg: LlamaConfig, block_size: int, blocks_per_seq: int,
                        out_shardings=(kvsh, rep))
 
     def _cont_impl(params, kv, ids, n_text, block_tables, cross_kv=None,
-                   has_image=None, cross_len=None):
+                   has_image=None, cross_len=None, slots=None):
         p = params["params"]
         B = ids.shape[0]  # == 1
         x = _embed(p, ids, cfg)
@@ -715,6 +765,17 @@ def make_prefill_cont(cfg: LlamaConfig, block_size: int, blocks_per_seq: int,
             kv[pi] = {"c": pool.at[tbl_chunk].set(r.reshape(
                 B, c_blocks, block_size, -1).astype(pool.dtype))}
             return (o,)
+
+        def attend_kda(pi, hs, at, _vs, _window):
+            # a continuation chunk's prefix is the SLOT's state and tail,
+            # not the pool: read, scanned over the chunk, written back
+            (h,) = hs
+            q, k, v, g, beta, tail = kda.inputs(
+                at, h, kv[pi]["t"][slots], n_text, cfg)
+            o, s = kda.scan(q, k, v, g, beta, kv[pi]["s"][slots],
+                            kernel=on_tpu_platform())
+            kv[pi] = _write_slots(kv[pi], slots, s, tail)
+            return (kda.output(at, h, o, cfg),)
 
         def attend(pi, qs, ks, vs, window):
             (q,), (k,), (v,) = qs, ks, vs
@@ -751,11 +812,18 @@ def make_prefill_cont(cfg: LlamaConfig, block_size: int, blocks_per_seq: int,
             attend_latent if cfg.latent else attend,
             cross=lambda ci: (cross_kv[ci]["k"], cross_kv[ci]["v"],
                               has_image, cross_len),
-            active=(offs < n_text[:, None],), shardings=shardings)
+            active=(offs < n_text[:, None],), shardings=shardings,
+            attend_kda=attend_kda)
         last = jnp.take_along_axis(x, (n_text - 1).reshape(B, 1, 1), axis=1)
         return kv, _logits(p, last, cfg)[:, 0]  # [B, V]
 
-    if cross_set:
+    if cfg.recurrent:
+        assert not cross_set and shardings is None  # as make_prefill
+
+        def cont(params, kv, ids, n_text, block_tables, slots):
+            return _cont_impl(params, kv, ids, n_text, block_tables,
+                              slots=slots)
+    elif cross_set:
         def cont(params, kv, ids, n_text, block_tables, cross_kv, has_image,
                  cross_len):
             return _cont_impl(params, kv, ids, n_text, block_tables,
@@ -851,6 +919,22 @@ def _make_token_forward(cfg: LlamaConfig, block_size: int,
                 paged=paged)
             return (mla.unabsorb(u, kv_b, cfg),)
 
+        def attend_kda(pi, hs, at, _vs, _window):
+            # one recurrent step a row, in place on the row's slot; a
+            # padded or finished row steps the NULL slot (the arena's last),
+            # so no sequence's state or tail is touched for it
+            assert T == 1, "one token a step over recurrent state"
+            (h,) = hs
+            arena, tails = kv[pi]["s"], kv[pi]["t"]
+            slots = jnp.where(active > 0, slot_idx, arena.shape[0] - 1)
+            q, k, v, g, beta, tail = kda.inputs(at, h, tails[slots], None,
+                                                cfg)
+            o, arena = kda.step_slots(q[:, 0], k[:, 0], v[:, 0], g[:, 0],
+                                      beta[:, 0], arena, slots, kernel=paged)
+            kv[pi] = {"s": arena,
+                      "t": tails.at[slots].set(tail.astype(tails.dtype))}
+            return (kda.output(at, h, o[:, None], cfg),)
+
         def attend(pi, qs, ks, vs, window):
             (q,), (kk,), (vv,) = qs, ks, vs
             if kv_quant:
@@ -916,7 +1000,7 @@ def _make_token_forward(cfg: LlamaConfig, block_size: int,
                               cross_len),
             active=None if active is None or not cfg.n_experts else (
                 jnp.broadcast_to(active[:, None] > 0, (B, T)),),
-            shardings=shardings)
+            shardings=shardings, attend_kda=attend_kda)
         return kv, _logits(p, x, cfg), stats  # [B, T, V] f32
 
     return fwd
@@ -1002,7 +1086,8 @@ def make_decode(cfg: LlamaConfig, block_size: int, blocks_per_seq: int,
             # one included, in every layer (one more int32 behind the
             # routing counts, in the same read)
             seen = (jnp.sum(jnp.where(active > 0, pos + 1, 0))
-                    * (cfg.n_layers - len(cross_set))).astype(jnp.int32)[None]
+                    * (cfg.n_layers - len(cross_set) - len(cfg.kda_layers))
+                    ).astype(jnp.int32)[None]
             stats = seen if stats is None else jnp.concatenate([stats, seen])
         nxt = sample_logits(logits, rng, temperature, top_k, top_p)
         # logprob data rides along (tiny vs the matmuls); the engine only
@@ -1018,7 +1103,16 @@ def make_decode(cfg: LlamaConfig, block_size: int, blocks_per_seq: int,
         return out if stats is None else out + (
             jnp.concatenate([nxt.astype(jnp.int32), stats]),)
 
-    if cross_set:
+    if cfg.recurrent:
+        # the rows' SLOTS ride as data, as the cross tail's do
+        assert not cross_set and shardings is None  # as make_prefill
+
+        def decode(params, kv, tokens, pos, tables, active, rng, fold,
+                   temperature, top_k, top_p, slot_idx):
+            return _decode_impl(params, kv, tokens, pos, tables, active, rng,
+                                fold, temperature, top_k, top_p,
+                                slot_idx=slot_idx)
+    elif cross_set:
         def decode(params, kv, tokens, pos, tables, active, rng, fold,
                    temperature, top_k, top_p, cross_kv, has_image, slot_idx,
                    cross_len):

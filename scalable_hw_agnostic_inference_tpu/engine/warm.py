@@ -126,6 +126,10 @@ def _run_warm_calls(eng) -> None:
     def cross_len(n):
         return put(np.full((n,), max(eng.cross_seq_len, 1), np.int32))
 
+    def null_slots(n):
+        """A recurrent model's warm rows write the arena's null slot."""
+        return eng._slot_args([eng._null_slot] * n)
+
     def warm_sampler(logits, per_row: bool) -> None:
         """The admission-time sampler and the first token's logprob readout
         are part of the closed set too. They are plain jits, keyed on what
@@ -155,7 +159,7 @@ def _run_warm_calls(eng) -> None:
             continue
         if key[0] == "cont":
             args = [eng.params, eng.cache.kv, zeros((1, key[2])),
-                    ones((1,)), zeros((1, M))]
+                    ones((1,)), zeros((1, M))] + null_slots(1)
             if eng._cross_kv is not None:
                 args += [eng._cross_zeros(1), zeros((1,), np.float32),
                          cross_len(1)]
@@ -164,7 +168,7 @@ def _run_warm_calls(eng) -> None:
             continue
         bucket, P_, K = key
         args = [eng.params, eng.cache.kv, zeros((K, bucket - P_)),
-                ones((K,)), zeros((K, M))]
+                ones((K,)), zeros((K, M))] + null_slots(K)
         if P_:
             args.append(zeros((K, P_, eng.cfg.dim), np.float32))
         if eng._cross_kv is not None:
@@ -182,6 +186,8 @@ def _run_warm_calls(eng) -> None:
         if eng._cross_kv is not None:
             args += [eng._cross_kv, zeros((bb,), np.float32), zeros((bb,)),
                      cross_len(bb)]
+        elif eng._state_layers:
+            args.append(zeros((bb,)))   # inactive rows: the null slot's
         return args
 
     for bb, fn in list(eng._decode_fns.items()):

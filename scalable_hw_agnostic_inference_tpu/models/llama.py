@@ -29,6 +29,7 @@ from typing import Any, Dict, List, Optional, Tuple
 import flax.linen as nn
 import jax
 import jax.numpy as jnp
+import numpy as np
 from jax.sharding import NamedSharding, PartitionSpec as P
 
 from ..ops.attention import causal_mask, dot_product_attention
@@ -72,7 +73,8 @@ class LlamaConfig:
     # -- what a layer IS, as data (``layer_kind``): the engine's one layer
     # function reads these and no model's name --------------------------
     # per-layer attention kind, HF's names: "sliding_attention" (a window
-    # of ``sliding_window`` keys) or "full_attention"; () = all full
+    # of ``sliding_window`` keys), "full_attention", or "linear_attention"
+    # (KDA, ``ops.kda``: recurrent slot state, no cache rows); () = all full
     layer_types: Tuple[str, ...] = ()
     sliding_window: int = 0
     # full-attention layers carry rotary embedding (False: none at all)
@@ -105,6 +107,18 @@ class LlamaConfig:
     # rotary pairs are lanes ``(2i, 2i+1)`` (HF ``rope_interleave``), not
     # the half-rotation's ``(i, i + D/2)``
     rope_interleave: bool = False
+    # -- "linear_attention" layers (Kimi Delta Attention): ``kda_heads``
+    # heads of ``kda_head_dim`` behind a causal depthwise convolution of
+    # ``kda_conv`` taps; the decay's and the output gate's low-rank width
+    # is ``kda_head_dim``. Their per-sequence state is a SLOT's
+    # (``state_leaves``), constant in the context's length
+    kda_heads: int = 0
+    kda_head_dim: int = 0
+    kda_conv: int = 4
+    # the routed experts HELD here, ``(first, count)`` of the ``n_experts``
+    # the router scores: this chip's share of an expert-parallel layout
+    # (the stacked expert leaves are that slice); () = all of them
+    experts_held: Tuple[int, ...] = ()
 
     def __post_init__(self):
         # sequence fields normalize to tuples so configs hash and compare
@@ -118,6 +132,9 @@ class LlamaConfig:
                                tuple(self.rope_scaling))
         if not isinstance(self.layer_types, tuple):
             object.__setattr__(self, "layer_types", tuple(self.layer_types))
+        if not isinstance(self.experts_held, tuple):
+            object.__setattr__(self, "experts_held",
+                               tuple(self.experts_held))
         if self.head_dim is None:
             object.__setattr__(self, "head_dim", self.dim // self.n_heads)
         if self.layer_types and len(self.layer_types) != self.n_layers:
@@ -158,6 +175,34 @@ class LlamaConfig:
     def moe_of(self, li: int) -> bool:
         return bool(self.n_experts) and li >= self.n_dense_layers
 
+    def kda_of(self, li: int) -> bool:
+        """Layer ``li`` is linear attention: slot state, no cache rows."""
+        return bool(self.layer_types) and (
+            self.layer_types[li] == "linear_attention")
+
+    @property
+    def kda_layers(self) -> Tuple[int, ...]:
+        """Pool indices (cross layers own none) of the KDA layers: where
+        the engine's per-layer state list holds a slot arena and no
+        blocks."""
+        pool = [li for li in range(self.n_layers)
+                if li not in self.cross_attention_layers]
+        return tuple(pi for pi, li in enumerate(pool) if self.kda_of(li))
+
+    @property
+    def recurrent(self) -> bool:
+        """Some layer keeps recurrent slot state."""
+        return bool(self.kda_layers)
+
+    @property
+    def held(self) -> Optional[Tuple[int, int]]:
+        """``(first, count)`` of the experts held here; None = all."""
+        return tuple(self.experts_held) or None
+
+    @property
+    def n_experts_held(self) -> int:
+        return self.experts_held[1] if self.experts_held else self.n_experts
+
     @property
     def window_layers(self) -> Tuple[int, ...]:
         """Pool indices (cross layers own none) of the window layers."""
@@ -175,7 +220,7 @@ class LlamaConfig:
         (the contiguous-cache flax module does not)."""
         return bool(self.n_experts or self.layer_types or self.qk_norm
                     or self.attn_gate or self.sandwich_norms
-                    or self.embed_scale or self.latent)
+                    or self.embed_scale or self.latent or self.recurrent)
 
     @classmethod
     def tiny(cls) -> "LlamaConfig":
@@ -303,6 +348,66 @@ class LlamaConfig:
             route_norm=True, route_scale=2.448, kv_lora_rank=32,
             qk_nope_head_dim=16, qk_rope_head_dim=8, v_head_dim=16,
             rope_interleave=True)
+
+    @classmethod
+    def kimi_linear_48b(cls, layer_types: Tuple[str, ...] = (),
+                        experts_held: Tuple[int, ...] = ()
+                        ) -> "LlamaConfig":
+        """Kimi-Linear-48B-A3B (``model_type: kimi_linear``) geometry: three
+        KDA layers (32 heads of 128 behind a convolution of 4; a float32
+        state of 32 x 128 x 128 a sequence a layer) to one MLA layer (a
+        latent of 512 and one shared key of 64 a token, 32 heads of 128 +
+        64 against values of 128, NO positional embedding anywhere:
+        ``mla_use_nope``), one leading dense layer of 9216, then 256
+        sigmoid-routed experts of 1024, 8 a token, scores renormalised and
+        scaled by 2.446, beside one shared expert; a 164k vocabulary,
+        untied. Whole (27 layers, the default; published 1-based layers
+        4, 8, 12, 16, 20, 24 and 27 are the MLA ones) it is 98 GB in bf16;
+        ``layer_types`` cuts the depth, ``experts_held`` the experts to one
+        chip's share."""
+        layer_types = tuple(layer_types) or tuple(
+            "full_attention" if li in (4, 8, 12, 16, 20, 24, 27)
+            else "linear_attention" for li in range(1, 28))
+        return cls(
+            vocab_size=163840, dim=2304, n_layers=len(layer_types),
+            n_heads=32, n_kv_heads=32, head_dim=192, mlp_dim=9216,
+            max_seq_len=1048576, rope_theta=10000.0, rms_eps=1e-5,
+            layer_types=layer_types, rope_on_full_attention=False,
+            n_experts=256, n_experts_per_tok=8, n_shared_experts=1,
+            moe_mlp_dim=1024, n_dense_layers=1, route_norm=True,
+            route_scale=2.446, kv_lora_rank=512, qk_nope_head_dim=128,
+            qk_rope_head_dim=64, v_head_dim=128, kda_heads=32,
+            kda_head_dim=128, kda_conv=4, experts_held=experts_held)
+
+    @classmethod
+    def kimi_linear_stage(cls) -> "LlamaConfig":
+        """One chip's share of a stage of Kimi-Linear-48B-A3B: the
+        embedding, the head, the leading dense layer (published layer 1,
+        KDA) and one whole period (published layers 5-8: KDA, KDA, KDA,
+        MLA), the expert layers divided over TWO chips by experts: 128 of
+        the 256 held here. Not a servable whole model: 9.32 GB of 98 GB."""
+        return cls.kimi_linear_48b(
+            ("linear_attention",) * 4 + ("full_attention",), (0, 128))
+
+    @classmethod
+    def tiny_kda(cls) -> "LlamaConfig":
+        """CI-tier stand-in with Kimi-Linear's mechanisms in the cut's
+        pattern: a dense KDA layer, three KDA expert layers and one MLA
+        expert layer without positional embedding; KDA heads of 16 behind
+        a convolution of 4; 16 experts top-8 beside a shared one, 8 of
+        them held here (8 a token as published: at 4 a flipped choice
+        carries a quarter of the routed output and the stand-in's rounding
+        floor doubles)."""
+        return cls(
+            vocab_size=512, dim=64, n_layers=5, n_heads=4, n_kv_heads=4,
+            head_dim=24, mlp_dim=128, max_seq_len=8192, rope_theta=10000.0,
+            rms_eps=1e-5,
+            layer_types=("linear_attention",) * 4 + ("full_attention",),
+            rope_on_full_attention=False, n_experts=16, n_experts_per_tok=8,
+            n_shared_experts=1, moe_mlp_dim=16, n_dense_layers=1,
+            route_norm=True, route_scale=2.446, kv_lora_rank=32,
+            qk_nope_head_dim=16, qk_rope_head_dim=8, v_head_dim=16,
+            kda_heads=4, kda_head_dim=16, kda_conv=4, experts_held=(0, 8))
 
     @classmethod
     def llama3_70b(cls) -> "LlamaConfig":
@@ -560,26 +665,45 @@ def tp_rules(axis: str = "tp") -> ShardingRules:
     ])
 
 
-def cache_leaves(cfg: LlamaConfig) -> Dict[str, Tuple[int, ...]]:
+def cache_leaves(cfg: LlamaConfig, li: Optional[int] = None
+                 ) -> Dict[str, Tuple[int, ...]]:
     """What ONE token costs the paged pool in ONE layer, by leaf: the
     shape behind ``[num_blocks, block_size]``. Per-head keys and values,
     or — latent attention — one leaf ``c`` of ``latent_width`` lanes: the
-    normed latent, the shared rotary key, zeros to a lane multiple."""
+    normed latent, the shared rotary key, zeros to a lane multiple. ``li``
+    names the layer: a KDA layer costs the pool nothing (``{}``; what it
+    costs a SLOT is ``state_leaves``). ``None``: a layer that has rows."""
+    if li is not None and cfg.kda_of(li):
+        return {}
     if cfg.latent:
         return {"c": (cfg.latent_width,)}
     return {"k": (cfg.n_kv_heads, cfg.head_dim),
             "v": (cfg.n_kv_heads, cfg.head_dim)}
 
 
+def state_leaves(cfg: LlamaConfig) -> Dict[str, Tuple[Tuple[int, ...], str]]:
+    """What ONE slot costs in ONE KDA layer, by leaf: ``(shape, dtype)``
+    behind ``[slots]`` (``ops.kda.state_shapes``); ``{}`` for a model with
+    no such layer."""
+    if not cfg.recurrent:
+        return {}
+    from ..ops.kda import state_shapes
+
+    return state_shapes(cfg)
+
+
 def cache_specs(
     cfg: LlamaConfig, axis: str = "tp", axis_size: int = 1
 ) -> Dict[str, P]:
-    """KV cache sharded over kv heads (dim 2) when divisible, else replicated."""
-    if axis_size > 1 and cfg.n_kv_heads % axis_size == 0:
-        spec = P(None, None, axis, None)
-    else:
-        spec = P()
-    return {"k": spec, "v": spec}
+    """The pool's leaves (``cache_leaves``) sharded over kv heads (dim 2)
+    when they have that axis and it divides, else replicated."""
+    out = {}
+    for name, per in cache_leaves(cfg).items():
+        heads = len(per) == 2 and per[0] == cfg.n_kv_heads
+        out[name] = (P(None, None, axis, None)
+                     if heads and axis_size > 1
+                     and cfg.n_kv_heads % axis_size == 0 else P())
+    return out
 
 
 # ---------------------------------------------------------------------------
@@ -645,6 +769,19 @@ def params_from_torch(model_or_sd, cfg: LlamaConfig) -> Dict[str, Any]:
 LATENT_Q_GAIN = 10.0
 LATENT_KVA_GAIN = 0.5
 
+#: KDA's seeded leaves that are not N(0, std). The three depthwise
+#: convolutions are drawn at the deviation of a depthwise ``Conv1d`` of 4
+#: taps as the public code initialises it (uniform on +-0.5: 0.29): at the
+#: tier's 0.02 a convolution's output is 0.04 of its input, ``v`` and with
+#: it ``o`` so small that the per-head norm's epsilon, not ``o``, sets the
+#: layer's output. ``A_log`` and ``dt_bias`` are drawn as the public
+#: initialisation draws them: ``A`` uniform in (1, 16) a head,
+#: ``softplus(dt_bias)`` log-uniform in (0.001, 0.1) a channel, so a
+#: channel remembers from one token to some hundreds.
+KDA_CONV_STD = 0.29
+KDA_A_RANGE = (1.0, 16.0)
+KDA_DT_RANGE = (1e-3, 1e-1)
+
 #: geometry-tier weight statistics: float kernels ~ N(0, GEOMETRY_STD);
 #: int8 kernels uniform on [-127, 127] under ONE constant per-channel scale
 #: chosen so the dequantized weights have the same standard deviation
@@ -692,6 +829,11 @@ def geometry_params(cfg: LlamaConfig, dtype=jnp.bfloat16,
             "latent attention has no int8 weights and no sharding plan yet "
             "(quantization: int8 / tensor_parallel_size > 1 with a latent "
             "cache)")
+    if cfg.recurrent and (quant or mesh is not None):
+        raise ValueError(
+            "KDA layers have no int8 weights and no sharding plan yet "
+            "(quantization: int8 / tensor_parallel_size > 1 with recurrent "
+            "state)")
     D, HD = cfg.dim, cfg.head_dim
     q_out, kv_out = cfg.n_heads * HD, cfg.n_kv_heads * HD
     rules = tp_rules()
@@ -707,11 +849,15 @@ def geometry_params(cfg: LlamaConfig, dtype=jnp.bfloat16,
     # 0.02 a width of 64 gives logits too flat for a broken layer to show)
     std = D ** -0.5 if jnp.dtype(dtype) == jnp.float32 else GEOMETRY_STD
 
-    def rand(path: str, shape, dt, gain: float = 1.0):
+    def rand(path: str, shape, dt, gain: float = 1.0, at: float = 0.0):
         return _geometry_leaf(
             jax.random.fold_in(root, next(n_leaf)), shape=tuple(shape),
             dtype=jnp.dtype(dt), sharding=sharding_of(path, len(shape)),
-            std=std * gain)
+            std=at or std * gain)
+
+    def uniform(shape, lo: float, hi: float):
+        return jax.random.uniform(jax.random.fold_in(root, next(n_leaf)),
+                                  shape, jnp.float32, lo, hi)
 
     def const(path: str, shape, value, dt):
         sh = sharding_of(path, len(shape))
@@ -737,7 +883,8 @@ def geometry_params(cfg: LlamaConfig, dtype=jnp.bfloat16,
                 "up": lin(f"{path}/up", D, width),
                 "down": lin(f"{path}/down", width, D)}
 
-    E, F = cfg.n_experts, cfg.moe_mlp_dim
+    # the router scores all E experts; the stacked leaves are the held ones
+    E, F, Eh = cfg.n_experts, cfg.moe_mlp_dim, cfg.n_experts_held
     for i in range(cfg.n_layers):
         lp = f"layer_{i}"
         layer: Dict[str, Any] = {
@@ -756,9 +903,9 @@ def geometry_params(cfg: LlamaConfig, dtype=jnp.bfloat16,
                                           jnp.float32)},
                 "bias": rand(f"{mo}/bias", (E,), jnp.float32),
                 "experts": {
-                    "gate": rand(f"{mo}/experts/gate", (E, D, F), dtype),
-                    "up": rand(f"{mo}/experts/up", (E, D, F), dtype),
-                    "down": rand(f"{mo}/experts/down", (E, F, D), dtype)},
+                    "gate": rand(f"{mo}/experts/gate", (Eh, D, F), dtype),
+                    "up": rand(f"{mo}/experts/up", (Eh, D, F), dtype),
+                    "down": rand(f"{mo}/experts/down", (Eh, F, D), dtype)},
                 "shared": mlp(f"{mo}/shared", F * cfg.n_shared_experts),
             }
         else:
@@ -773,6 +920,29 @@ def geometry_params(cfg: LlamaConfig, dtype=jnp.bfloat16,
             }
             layer["gate_attn"] = rand(f"{lp}/gate_attn", (1,), dtype)
             layer["gate_mlp"] = rand(f"{lp}/gate_mlp", (1,), dtype)
+        elif cfg.kda_of(i):
+            # the public names: q/k/v_proj and their depthwise q/k/v_conv1d,
+            # f_a/f_b_proj with dt_bias and A_log (the decay), b_proj
+            # (beta), g_a/g_b_proj (the output gate), o_norm, o_proj
+            at, KH, Kd = f"{lp}/attn", cfg.kda_heads, cfg.kda_head_dim
+            wide = KH * Kd
+            layer["attn"] = {
+                **{n: lin(f"{at}/{n}", D, wide) for n in ("q", "k", "v")},
+                **{f"{n}_conv": rand(f"{at}/{n}_conv", (cfg.kda_conv, wide),
+                                     dtype, at=KDA_CONV_STD)
+                   for n in ("q", "k", "v")},
+                "f_a": lin(f"{at}/f_a", D, Kd),
+                "f_b": lin(f"{at}/f_b", Kd, wide),
+                "A_log": jnp.log(uniform((KH,), *KDA_A_RANGE)),
+                # softplus^-1 of a log-uniform dt
+                "dt_bias": jnp.log(jnp.expm1(jnp.exp(uniform(
+                    (wide,), *(float(np.log(x)) for x in KDA_DT_RANGE))))),
+                "b": lin(f"{at}/b", D, KH),
+                "g_a": lin(f"{at}/g_a", D, Kd),
+                "g_b": lin(f"{at}/g_b", Kd, wide),
+                "o_norm": norm(f"{at}/o_norm", Kd),
+                "o": lin(f"{at}/o", wide, D),
+            }
         elif cfg.latent:
             # HF's names: q_proj, kv_a_proj_with_mqa (the latent and the
             # shared rotary key), kv_a_layernorm, kv_b_proj (per head: keys

@@ -252,6 +252,11 @@ class StepTelemetry:
         # latent-layer steps, and the cache rows the steps' live rows held,
         # summed over latent layers. None = no latent cache.
         self.mla: Optional[Dict[str, int]] = None
+        # what the recurrent (KDA) layers did, counted on the host where
+        # it is known: real tokens x KDA layers through the prefill scan,
+        # continuation programs that read a slot's state, live rows x KDA
+        # layers stepped in decode dispatches. None = no such layer.
+        self.kda: Optional[Dict[str, int]] = None
         self.warmed_executables = 0  # closed-set size at readiness
         # last-step gauges (scraped between steps)
         self._gauges: Dict[str, float] = {}
@@ -437,6 +442,20 @@ class StepTelemetry:
                                    + int(tokens_visible))
             self.mla = m
 
+    def count_kda(self, prefill_tokens: int = 0, chunk_carries: int = 0,
+                  rows_stepped: int = 0) -> None:
+        """One dispatch of a model with KDA layers; a model without them
+        counts nothing (every argument 0) and shows no ``kda`` entry."""
+        if not (prefill_tokens or chunk_carries or rows_stepped):
+            return
+        with self._lock:
+            m = self.kda if self.kda is not None else dict.fromkeys(
+                ("prefill_tokens", "chunk_carries", "rows_stepped"), 0)
+            m["prefill_tokens"] += int(prefill_tokens)
+            m["chunk_carries"] += int(chunk_carries)
+            m["rows_stepped"] += int(rows_stepped)
+            self.kda = m
+
     def count_window(self, walked: int, skipped: int, visible: int,
                      dead: int, held: int) -> None:
         with self._lock:
@@ -458,12 +477,15 @@ class StepTelemetry:
                     spec: Optional[Dict[str, Any]] = None,
                     finished_ids: Sequence[int] = (),
                     tenants: Optional[Dict[str, Sequence[int]]] = None,
-                    input_uploads: int = 0) -> None:
+                    input_uploads: int = 0,
+                    state_slots: Optional[int] = None) -> None:
         """One engine ``step()`` completed; ``kind`` names the decode path
         taken (``"decode"``, ``"spec"``, ``"idle"``). ``finished_ids`` are
         the engine request ids that reached a terminal state this step —
         the join key between ``/debug/flight`` step records and request
-        traces (whose root carries ``engine_req_id``)."""
+        traces (whose root carries ``engine_req_id``). ``state_slots``:
+        arena slots held at the step's end, of a model with recurrent
+        layers (the record's ``state_slots_live``, ``kda.slots_live``)."""
         total = self.total_blocks or 1
         used = max(0, total - blocks_free)
         # pressure vs occupancy: evictable prefix-cache blocks are
@@ -491,7 +513,11 @@ class StepTelemetry:
         }
         if spec:
             rec["spec"] = dict(spec)
+        if state_slots is not None:
+            rec["state_slots_live"] = int(state_slots)
         with self._lock:
+            if state_slots is not None and self.kda is not None:
+                self.kda["slots_live"] = int(state_slots)
             self.steps += 1
             self.requests_finished += finished
             self.decode_input_uploads += input_uploads
@@ -584,6 +610,8 @@ class StepTelemetry:
                 out["window"] = dict(self.window)
             if self.mla is not None:
                 out["mla"] = dict(self.mla)
+            if self.kda is not None:
+                out["kda"] = dict(self.kda)
             # the open phase's seconds so far included: two readings
             # differ by the time between them, whatever each caught open
             out["phase_s"] = dict(self.phase_s)

@@ -1,0 +1,280 @@
+"""Kimi Delta Attention (KDA, Kimi Linear's linear-attention layer): a gated
+delta rule whose per-sequence state is constant in the context's length.
+
+Per head, on a token's ``q, k, v`` (``d`` channels each; ``q, k`` L2-normed,
+``q`` scaled by ``d ** -0.5``), a log-decay ``g <= 0`` per key CHANNEL
+(``alpha = exp(g)``) and a write strength ``beta`` in ``(0, 1)``, the state
+``S`` (``[d_k, d_v]``) moves as
+
+    S_t = (I - beta_t k_t k_t^T) Diag(alpha_t) S_{t-1} + beta_t k_t v_t^T
+    o_t = S_t^T q_t
+
+This module holds the function three ways, and everything of the layer
+around it:
+
+- :func:`recurrence`: one ``lax.scan`` step a token, float32. THE oracle:
+  the chunked form, both Pallas kernels (``ops.pallas.kda_chunk``,
+  ``ops.pallas.kda_step``) and the served path's tests are held to it.
+- :func:`chunk_math`: ``CHUNK`` tokens at once from the state that enters
+  the chunk (the WY form of the delta rule with a per-channel decay). It is
+  written on plain 2-D values so that the chunk kernel's body IS this
+  function; :func:`chunked` maps it over rows and heads and scans it over
+  the chunks (the engine's prefill off the TPU).
+- :func:`step`: one token for a batch of rows (the engine's decode off the
+  TPU; the step kernel's oracle beside the recurrence).
+
+The state is STORED TRANSPOSED, ``s[..., j, i] = S[i, j]`` (``[d_v, d_k]``):
+the decay then scales lanes, which is what both kernels want. Float32
+always; the convolution's tail (the last ``conv - 1`` inputs of the q, k and
+v convolutions) lives beside it in the activations' type.
+
+A PAD token is the identity: ``beta = 0`` and ``g = 0`` leave ``S`` as it
+was, and the tail is read from the last REAL tokens (:func:`inputs`).
+
+``chunk_math`` exponentiates differences of the cumulative log-decay against
+a reference point a ``BLOCK`` of 16 rows, the block's middle, so that the two
+factors of a kept entry stay within ``exp(+-8 * max|g|)`` of each other's
+inverse: sound for ``|g| <= 8`` a token and channel (``alpha >= 0.0003``;
+the public initialisation gives at most about 3). Against the chunk's start
+alone a factor would reach ``exp(64 * |g|)`` and overflow; against the
+block's start, ``exp(-16 * |g|)`` times a small ``q`` fell under float32's
+smallest normal at ``|g| = 5`` and the CPU flushed it (PR 34's test).
+"""
+
+from __future__ import annotations
+
+from typing import Dict, Optional, Tuple
+
+import jax
+import jax.numpy as jnp
+
+from .quant import quant_matmul
+
+#: tokens one step of the chunked form takes
+CHUNK = 64
+#: rows that share a reference point of the cumulative decay
+BLOCK = 16
+#: added under the root of the q and k norms (the public kernels' value)
+L2_EPS = 1e-6
+_HI = jax.lax.Precision.HIGHEST
+#: largest exponent taken (float32 overflows past 88): a KEPT entry stays
+#: under it while 8 * |g| does; masked entries may reach it and are dropped
+_EXP_CAP = 87.0
+
+
+# -- the layer around the recurrence ---------------------------------------
+
+def state_shapes(cfg) -> Dict[str, Tuple[Tuple[int, ...], Optional[str]]]:
+    """What ONE slot costs in ONE KDA layer, by leaf: ``(shape, dtype)``.
+    ``s``: the transposed state a head, float32; ``t``: the last
+    ``conv - 1`` inputs of the three convolutions, side by side, in the
+    activations' type (``None``: the holder's own)."""
+    H, d = cfg.kda_heads, cfg.kda_head_dim
+    return {"s": ((H, d, d), "float32"),
+            "t": ((cfg.kda_conv - 1, 3 * H * d), None)}
+
+
+def _l2norm(x: jax.Array) -> jax.Array:
+    return x * jax.lax.rsqrt(jnp.sum(x * x, axis=-1, keepdims=True) + L2_EPS)
+
+
+def inputs(at: Dict, h: jax.Array, tail: Optional[jax.Array],
+           n_valid: Optional[jax.Array], cfg):
+    """The recurrence's operands from the normed stream ``h`` ``[B, T, D]``.
+
+    ``tail`` ``[B, conv - 1, 3 * H * d]``: the convolutions' inputs of the
+    tokens before ``h`` (``None``: position 0, zeros). ``n_valid`` ``[B]``:
+    real tokens of each row (``None``: all); the rest are pads, which get
+    ``beta = 0``, ``g = 0`` and do not enter the new tail.
+
+    Returns ``(q, k, v, g, beta, new_tail)``: ``q, k, v, g`` ``[B, T, H, d]``
+    float32, ``beta`` ``[B, T, H]`` float32, ``new_tail`` like ``tail``."""
+    B, T, _ = h.shape
+    H, d, K = cfg.kda_heads, cfg.kda_head_dim, cfg.kda_conv
+    pre = jnp.concatenate(
+        [quant_matmul(h, at[n]) for n in ("q", "k", "v")], axis=-1)
+    if tail is None:
+        tail = jnp.zeros((B, K - 1, pre.shape[-1]), pre.dtype)
+    ext = jnp.concatenate([tail.astype(pre.dtype), pre], axis=1)
+    w = jnp.concatenate([at[n] for n in ("q_conv", "k_conv", "v_conv")],
+                        axis=-1).astype(jnp.float32)          # [K, 3HD]
+    y = sum(ext[:, i:i + T].astype(jnp.float32) * w[i] for i in range(K))
+    y = jax.nn.silu(y).reshape(B, T, 3, H, d)
+    q = _l2norm(y[:, :, 0]) * (d ** -0.5)
+    k = _l2norm(y[:, :, 1])
+    v = y[:, :, 2]
+    f = quant_matmul(quant_matmul(h, at["f_a"]), at["f_b"])
+    g = -jnp.exp(at["A_log"].astype(jnp.float32))[:, None] * jax.nn.softplus(
+        f.astype(jnp.float32).reshape(B, T, H, d)
+        + at["dt_bias"].astype(jnp.float32).reshape(H, d))
+    beta = jax.nn.sigmoid(quant_matmul(h, at["b"]).astype(jnp.float32))
+    if n_valid is None:
+        return q, k, v, g, beta, ext[:, T:]
+    real = jnp.arange(T)[None, :] < n_valid[:, None]          # [B, T]
+    g = jnp.where(real[..., None, None], g, 0.0)
+    beta = jnp.where(real[..., None], beta, 0.0)
+    # the last conv - 1 REAL inputs: rows n .. n + K - 2 of the extension
+    rows = n_valid[:, None] + jnp.arange(K - 1)[None, :]
+    return q, k, v, g, beta, jnp.take_along_axis(ext, rows[..., None], axis=1)
+
+
+def output(at: Dict, h: jax.Array, o: jax.Array, cfg) -> jax.Array:
+    """``o`` ``[B, T, H, d]`` under its per-head RMSNorm and the layer's
+    low-rank sigmoid gate, as ``[B, T, H * d]`` in the stream's type (the
+    caller applies ``W_o``)."""
+    B, T, H, d = o.shape
+    o = o.astype(jnp.float32)
+    o = o * jax.lax.rsqrt(jnp.mean(o * o, -1, keepdims=True) + cfg.rms_eps)
+    o = o * at["o_norm"]["scale"].astype(jnp.float32)
+    gate = quant_matmul(quant_matmul(h, at["g_a"]), at["g_b"])
+    o = o * jax.nn.sigmoid(gate.astype(jnp.float32).reshape(B, T, H, d))
+    return o.reshape(B, T, H * d).astype(h.dtype)
+
+
+# -- the recurrence, one token at a time (the oracle) ----------------------
+
+def step(q, k, v, g, beta, s):
+    """One token: ``q, k, v, g`` ``[..., d]``, ``beta`` ``[...]``, ``s``
+    ``[..., d_v, d_k]`` (transposed). Returns ``(o [..., d_v], s)``."""
+    s = s * jnp.exp(g)[..., None, :]
+    pred = jnp.sum(s * k[..., None, :], axis=-1)              # S'^T k
+    u = beta[..., None] * (v - pred)
+    s = s + u[..., :, None] * k[..., None, :]
+    return jnp.sum(s * q[..., None, :], axis=-1), s
+
+
+def recurrence(q, k, v, g, beta, s0=None):
+    """``q, k, v, g`` ``[B, T, H, d]``, ``beta`` ``[B, T, H]``, ``s0``
+    ``[B, H, d, d]`` transposed (``None``: zeros). Returns
+    ``(o [B, T, H, d], s_T)``, float32: a ``lax.scan`` over the tokens."""
+    B, T, H, d = q.shape
+    if s0 is None:
+        s0 = jnp.zeros((B, H, d, d), jnp.float32)
+    f32 = lambda a: jnp.moveaxis(a.astype(jnp.float32), 1, 0)  # noqa: E731
+
+    def one(s, x):
+        o, s = step(*x, s)
+        return s, o
+
+    s, o = jax.lax.scan(one, s0.astype(jnp.float32),
+                        (f32(q), f32(k), f32(v), f32(g), f32(beta)))
+    return jnp.moveaxis(o, 0, 1), s
+
+
+# -- the chunked form -------------------------------------------------------
+
+def _mm(a, b):
+    return jax.lax.dot_general(a, b, (((1,), (0,)), ((), ())), precision=_HI,
+                               preferred_element_type=jnp.float32)
+
+
+def _mm_nt(a, b):
+    return jax.lax.dot_general(a, b, (((1,), (1,)), ((), ())), precision=_HI,
+                               preferred_element_type=jnp.float32)
+
+
+def _neumann(x, eye, n: int):
+    """``(I + x)^-1`` of a matrix nilpotent of index ``n`` (a power of
+    two): ``(I - x)(I + x^2)(I + x^4)...``, products only."""
+    inv, p = eye - x, x
+    m = 2
+    while m < n:
+        p = _mm(p, p)
+        inv = _mm(inv, eye + p)
+        m *= 2
+    return inv
+
+
+def chunk_math(q, k, kb, vb, g, st):
+    """One chunk of one head. ``q, k, g`` ``[C, d]``; ``kb = beta * k`` and
+    ``vb = beta * v`` ``[C, d]``; ``st`` ``[d_v, d_k]`` the transposed state
+    that enters. All float32. Returns ``(o [C, d_v], st_out)``.
+
+    With ``G`` the inclusive cumulative log-decay, ``u`` the delta rule's
+    corrected values solve ``(I + A) u = beta (v - K+ S0)``, ``A_ij = beta_i
+    (k_i e^{G_i}) . (k_j e^{-G_j})`` for ``j < i``; then ``o = Q+ S0 + P u``
+    with ``P_ij = (q_i e^{G_i}) . (k_j e^{-G_j})`` for ``j <= i`` and ``S_C =
+    Diag(e^{G_C}) S0 + (k e^{G_C - G})^T u``. Exponents are taken against
+    the cumulative decay at the middle of the row's block of ``BLOCK`` rows,
+    never against the chunk's start alone (module docstring); ``(I + A)^-1``
+    is the block-diagonal part's Neumann product times the block-lower
+    remainder's, matrix products only."""
+    C, d = q.shape
+    row = jax.lax.broadcasted_iota(jnp.int32, (C, C), 0)
+    col = jax.lax.broadcasted_iota(jnp.int32, (C, C), 1)
+    G = _mm((col <= row).astype(jnp.float32), g)              # cumulative
+    # the cumulative decay at the MIDDLE of each row's block: the reference
+    # point of the block's exponents, half a block from any of its rows
+    Gs = _mm((col < (row // BLOCK) * BLOCK + BLOCK // 2).astype(jnp.float32),
+             g)
+    a_rows, p_rows = [], []
+    for lo in range(0, C, BLOCK):
+        rs = Gs[lo:lo + 1]                                    # [1, d]
+        e = jnp.exp(G[lo:lo + BLOCK] - rs)
+        kneg = k * jnp.exp(jnp.minimum(rs - G, _EXP_CAP))     # [C, d]
+        a_rows.append(_mm_nt(kb[lo:lo + BLOCK] * e, kneg))
+        p_rows.append(_mm_nt(q[lo:lo + BLOCK] * e, kneg))
+    A = jnp.where(col < row, jnp.concatenate(a_rows, axis=0), 0.0)
+    P = jnp.where(col <= row, jnp.concatenate(p_rows, axis=0), 0.0)
+    eye = (row == col).astype(jnp.float32)
+    diag = jnp.where(row // BLOCK == col // BLOCK, A, 0.0)
+    inv_d = _neumann(diag, eye, BLOCK)
+    inv = _mm(_neumann(_mm(inv_d, A - diag), eye, C // BLOCK), inv_d)
+    decay = jnp.exp(G)                                        # from the start
+    u = _mm(inv, vb - _mm_nt(kb * decay, st))                 # [C, d_v]
+    o = _mm_nt(q * decay, st) + _mm(P, u)
+    g_end = G[C - 1:C]
+    st = st * jnp.exp(g_end) + _mm(u.T, k * jnp.exp(g_end - G))
+    return o, st
+
+
+def chunked(q, k, v, g, beta, s0=None):
+    """:func:`recurrence`'s function, ``CHUNK`` tokens a step: same
+    arguments and results. ``T`` is padded to whole chunks with identity
+    tokens. Plain ``jnp`` (the engine's prefill off the TPU, and the chunk
+    kernel's shape-for-shape twin)."""
+    B, T, H, d = q.shape
+    if s0 is None:
+        s0 = jnp.zeros((B, H, d, d), jnp.float32)
+    pad = -T % CHUNK
+    bh = lambda a: jnp.pad(                                   # noqa: E731
+        jnp.moveaxis(a.astype(jnp.float32), 2, 1),
+        ((0, 0), (0, 0), (0, pad), (0, 0))).reshape(
+            B, H, (T + pad) // CHUNK, CHUNK, d)
+    b = beta.astype(jnp.float32)[..., None]
+    xs = (bh(q), bh(k), bh(k * b), bh(v * b), bh(g))
+
+    def one_head(qh, kh, kbh, vbh, gh, sh):
+        def one(st, x):
+            o, st = chunk_math(*x, st)
+            return st, o
+
+        st, o = jax.lax.scan(one, sh, (qh, kh, kbh, vbh, gh))
+        return o.reshape(-1, d), st
+
+    o, s = jax.vmap(jax.vmap(one_head))(*xs, s0.astype(jnp.float32))
+    return jnp.moveaxis(o[:, :, :T], 1, 2), s
+
+
+def scan(q, k, v, g, beta, s0=None, *, kernel: bool):
+    """The prefill's scan with implementation dispatch: the Pallas chunk
+    kernel where ``kernel`` (the TPU), :func:`chunked` elsewhere."""
+    if not kernel:
+        return chunked(q, k, v, g, beta, s0)
+    from .pallas.kda_chunk import kda_chunk_prefill
+
+    return kda_chunk_prefill(q, k, v, g, beta, s0)
+
+
+def step_slots(q, k, v, g, beta, arena, slots, *, kernel: bool):
+    """One decode step for ``B`` rows over their slots of ``arena``
+    ``[S, H, d, d]``: ``q, k, v, g`` ``[B, H, d]``, ``beta`` ``[B, H]``,
+    ``slots`` ``[B]`` int32 (a padded row's is the arena's last, the null
+    slot). Returns ``(o [B, H, d], arena)``: the kernel updates the arena in
+    place; the plain form gathers, steps and scatters."""
+    if kernel:
+        from .pallas.kda_step import kda_decode_step
+
+        return kda_decode_step(q, k, v, g, beta, arena, slots)
+    o, s = step(q, k, v, g, beta, arena[slots])
+    return o, arena.at[slots].set(s)

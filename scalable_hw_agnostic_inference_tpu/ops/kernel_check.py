@@ -405,3 +405,115 @@ def expert_cases(n_experts: int, top_k: int, D: int, F: int, *,
     at one small one (rows the kernel pads to a tile of sublanes)."""
     return [_expert_case(n_experts, top_k, D, F, rows)
             for rows in sorted({max_num_seqs, min(max_num_seqs, 8)})]
+
+
+#: the KDA kernels against the token-by-token recurrence, float32 on both
+#: sides: what is left is the order of the sums (the chunk's triangular
+#: solve against one step a token), well under 1e-4 of outputs near 1
+TOL_KDA = 2e-4
+
+
+def _kda_operands(key, shape_bthd):
+    """Seeded operands of the recurrence at ``[B, T, H, d]``: L2-normed
+    ``q`` (scaled) and ``k``, unit ``v``, a per-channel log-decay drawn as
+    the public initialisation draws it (``A`` in U(1, 16) a head, ``dt``
+    log-uniform in 0.001-0.1), ``beta`` in (0, 1)."""
+    from . import kda
+
+    B, T, H, d = shape_bthd
+    kq, kk, kv, ka, kd, kb = jax.random.split(key, 6)
+    q = kda._l2norm(jax.random.normal(kq, shape_bthd)) * d ** -0.5
+    k = kda._l2norm(jax.random.normal(kk, shape_bthd))
+    v = jax.random.normal(kv, shape_bthd)
+    A = jax.random.uniform(ka, (H, 1), minval=1.0, maxval=16.0)
+    dt = jnp.exp(jax.random.uniform(kd, shape_bthd, minval=np.log(1e-3),
+                                    maxval=np.log(0.1)))
+    beta = jax.nn.sigmoid(jax.random.normal(kb, (B, T, H)))
+    return q, k, v, -A * dt, beta
+
+
+def _kda_chunk_case(H: int, d: int, T: int, rows: int) -> KernelCase:
+    """The chunk kernel over ``T`` tokens from a state that is not zero (a
+    continuation chunk), final state and outputs side by side."""
+    from . import kda
+    from .pallas.kda_chunk import kda_chunk_prefill
+
+    def make(key):
+        k0, k1 = jax.random.split(key)
+        return _kda_operands(k0, (rows, T, H, d)) + (
+            jax.random.normal(k1, (rows, H, d, d)),)
+
+    def flat(o, s):
+        return jnp.concatenate([o.reshape(-1), s.reshape(-1)])
+
+    return KernelCase(
+        name=f"kda-chunk-H{H}x{d}-T{T}-b{rows}", make_inputs=make,
+        kernel=lambda *a, interpret: flat(
+            *kda_chunk_prefill(*a, interpret=interpret)),
+        oracle=lambda *a: flat(*kda.recurrence(*a)), tol=TOL_KDA)
+
+
+def kda_state_bf16_err(case: KernelCase, key=None) -> float:
+    """The precision control of a chunk case: the largest difference of the
+    recurrence with its STATE rounded to bfloat16 after every token from the
+    float32 recurrence, on the case's operands. ``case.tol`` has to refuse
+    it: the logits of five layers under bfloat16 activations cannot tell
+    the two apart (``benchmark/reference/tolerance.kimi_linear.json``), the
+    final state and the outputs of one layer's scan can."""
+    from . import kda
+
+    q, k, v, g, beta, s0 = jax.jit(case.make_inputs)(
+        jax.random.PRNGKey(0) if key is None else key)
+    t_first = lambda a: jnp.moveaxis(a.astype(jnp.float32), 1, 0)  # noqa: E731
+
+    def one(s, x):
+        o, s = kda.step(*x, s)
+        return jax.lax.reduce_precision(s, exponent_bits=8,
+                                        mantissa_bits=7), o
+
+    s, o = jax.jit(lambda *a: jax.lax.scan(one, a[5], tuple(
+        t_first(x) for x in a[:5])))(q, k, v, g, beta, s0)
+    got = jnp.concatenate([jnp.moveaxis(o, 0, 1).reshape(-1), s.reshape(-1)])
+    return float(jnp.max(jnp.abs(got - case.oracle(q, k, v, g, beta, s0))))
+
+
+def _kda_step_case(H: int, d: int, rows: int, slots: int) -> KernelCase:
+    """The step kernel for ``rows`` rows over an arena of ``slots`` and
+    the null slot: the rows' slots are a permutation's head, the last two
+    rows padded (the null slot twice); the arena comes back whole, so a
+    write to a slot no row names shows."""
+    from . import kda
+    from .pallas.kda_step import kda_decode_step
+
+    def make(key):
+        k0, k1, k2 = jax.random.split(key, 3)
+        q, k, v, g, beta = (a[:, 0] for a in _kda_operands(
+            k0, (rows, 1, H, d)))
+        ids = jax.random.permutation(k2, slots)[:rows].astype(jnp.int32)
+        ids = jnp.where(jnp.arange(rows) >= rows - 2, slots, ids)
+        return q, k, v, g, beta, jax.random.normal(
+            k1, (slots + 1, H, d, d)), ids
+
+    def keep_null(o, arena):
+        # what lands in the null slot is nobody's: compare the rest
+        return jnp.concatenate([o[:-2].reshape(-1), arena[:-1].reshape(-1)])
+
+    def oracle(q, k, v, g, beta, arena, ids):
+        return keep_null(*kda.step_slots(q, k, v, g, beta, arena, ids,
+                                         kernel=False))
+
+    return KernelCase(
+        name=f"kda-step-H{H}x{d}-b{rows}-S{slots}", make_inputs=make,
+        kernel=lambda *a, interpret: keep_null(
+            *kda_decode_step(*a, interpret=interpret)),
+        oracle=oracle, tol=TOL_KDA)
+
+
+def kda_cases(n_heads: int, head_dim: int, *, bucket: int = 2048,
+              max_num_seqs: int = 16) -> List[KernelCase]:
+    """The kernel calls an engine with KDA layers dispatches: the chunk
+    kernel over a prefill bucket (one row), and the step kernel at the
+    largest decode bucket and at a small one."""
+    return [_kda_chunk_case(n_heads, head_dim, bucket, 1)] + [
+        _kda_step_case(n_heads, head_dim, rows, max_num_seqs)
+        for rows in sorted({max_num_seqs, min(max_num_seqs, 4)})]

@@ -32,6 +32,8 @@ BLOCK_K = 128
 # lane width: head_dim and seq tiles must respect TPU tiling
 _MIN_D = 64
 _MIN_BLOCK = 8  # smallest sublane tile the kernel will use for short T
+# what Mosaic lets a kernel's blocks and stack take unless it is told more
+_SCOPED_VMEM_DEFAULT = 16 * 1024 * 1024
 
 
 def _pick_block(n: int, preferred: int) -> int:
@@ -214,8 +216,18 @@ def flash_attention(
         block_q=block_q, block_k=block_k, seq_k=S, q_offset=q_offset,
         window=int(window),
     )
+    # K and V of one head stay in VMEM, two buffers each, lanes padded to
+    # 128: past the compiler's scoped default (16 MiB; 16k keys of 192
+    # beside values of 128 want 24) the call asks for what it holds. Below
+    # it nothing is asked, and the call is what it was
+    lanes = lambda n: -(-n // 128) * 128                      # noqa: E731
+    resident = 2 * S * (lanes(D) + lanes(Dv)) * k.dtype.itemsize
+    roomy = {} if resident <= _SCOPED_VMEM_DEFAULT else {
+        "compiler_params": pltpu.CompilerParams(
+            vmem_limit_bytes=resident + _SCOPED_VMEM_DEFAULT // 2)}
     out = pl.pallas_call(
         kernel,
+        **roomy,
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=1,
             grid=grid,

@@ -131,6 +131,12 @@ _WINDOW_COUNTERS = ("shai_engine_window_total",
 _MLA_COUNTERS = ("shai_engine_mla_total",
                  "Latent attention in decode dispatches, by counter: "
                  "layer_steps, tokens_visible")
+_KDA_COUNTERS = ("shai_engine_kda_total",
+                 "Recurrent (KDA) layers, by counter: prefill_tokens (real "
+                 "tokens x KDA layers through the chunked scan), "
+                 "chunk_carries (continuation programs that read a slot's "
+                 "state), rows_stepped (live rows x KDA layers in decode "
+                 "dispatches), slots_live (gauge: arena slots held)")
 #: conformance-layer gauge families: each instrument riding the engine
 #: telemetry object exports its flat numeric snapshot verbatim under a
 #: prefix — obs.slo → shai_slo_* (per-objective burn rates + breach),
@@ -312,7 +318,8 @@ class EngineTelemetryCollector:
         yield c
         for key, family in (("moe", _MOE_COUNTERS),
                             ("window", _WINDOW_COUNTERS),
-                            ("mla", _MLA_COUNTERS)):
+                            ("mla", _MLA_COUNTERS),
+                            ("kda", _KDA_COUNTERS)):
             if snap.get(key):
                 c = CounterMetricFamily(*family, labels=["app", "counter"])
                 for counter, v in sorted(snap[key].items()):

@@ -219,7 +219,11 @@ def _geometry_models():
     one dense and one period of four expert layers of the 32: 8.48 GB of
     52 GB in bf16), and so is ``kanana-2-geometry`` (Kanana-2-30B-A3B's
     leading dense layer and six of its 47 expert layers, latent attention:
-    8.86 GB of 61 GB)."""
+    8.86 GB of 61 GB). ``kimi-linear-geometry`` is one chip's SHARE of a
+    stage: Kimi-Linear-48B-A3B's leading dense layer and one period (three
+    KDA layers to one MLA layer), 128 of each layer's 256 experts held here
+    (the other chip of the two that share the stage holds the rest):
+    9.32 GB of 98 GB."""
     from ...models.llama import LlamaConfig
 
     return {
@@ -229,6 +233,7 @@ def _geometry_models():
         "mistral-7b-geometry": LlamaConfig.mistral_7b,
         "trinity-mini-geometry": LlamaConfig.trinity_mini_stage,
         "kanana-2-geometry": LlamaConfig.kanana2_stage,
+        "kimi-linear-geometry": LlamaConfig.kimi_linear_stage,
     }
 
 
@@ -236,13 +241,15 @@ def _stand_in_models():
     """CI-sized stand-ins (the hermetic tier): float32 leaves from the
     seed, the byte tokenizer, and in the ``vllm`` unit ONE tiny engine
     shape. ``tiny-afmoe`` has ``trinity-mini-geometry``'s mechanisms,
-    ``tiny-mla`` ``kanana-2-geometry``'s."""
+    ``tiny-mla`` ``kanana-2-geometry``'s, ``tiny-kda``
+    ``kimi-linear-geometry``'s."""
     from ...models.llama import LlamaConfig
 
     return {
         "tiny": LlamaConfig.tiny,
         "tiny-afmoe": LlamaConfig.tiny_afmoe,
         "tiny-mla": LlamaConfig.tiny_mla,
+        "tiny-kda": LlamaConfig.tiny_kda,
     }
 
 

@@ -48,13 +48,17 @@ class VllmService(ModelService):
     sampling. ``concurrency`` widens the serving lane so requests actually
     coalesce into the running batch.
 
-    ``MODEL_ID``: a hub id, ``tiny`` / ``tiny-afmoe`` / ``tiny-mla`` (the
-    hermetic stand-ins), or a geometry id (``units/causal_lm.py``): an
-    architecture at its published widths over seeded weights. Two of those
-    are ONE CHIP'S STAGE of a pipeline and not a servable whole model:
-    ``trinity-mini-geometry`` (AFMoE: routed experts, window and full
-    layers) and ``kanana-2-geometry`` (``deepseek_v3``: a latent paged
-    cache with absorbed decode beside routed experts; 7 of 48 layers).
+    ``MODEL_ID``: a hub id, ``tiny`` / ``tiny-afmoe`` / ``tiny-mla`` /
+    ``tiny-kda`` (the hermetic stand-ins), or a geometry id
+    (``units/causal_lm.py``): an architecture at its published widths over
+    seeded weights. Three of those are ONE CHIP'S STAGE of a pipeline and
+    not a servable whole model: ``trinity-mini-geometry`` (AFMoE: routed
+    experts, window and full layers), ``kanana-2-geometry``
+    (``deepseek_v3``: a latent paged cache with absorbed decode beside
+    routed experts; 7 of 48 layers) and ``kimi-linear-geometry``
+    (``kimi_linear``: recurrent slot state in three KDA layers of four
+    beside the latent pool of the fourth; 5 of 27 layers, 128 of 256
+    experts a layer).
     """
 
     task = "text-generation"

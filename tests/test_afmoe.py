@@ -520,7 +520,8 @@ def test_every_stand_in_gets_the_one_tiny_engine_shape(served):
         _stand_in_models,
     )
 
-    assert set(_stand_in_models()) == {"tiny", "tiny-afmoe", "tiny-mla"}
+    assert set(_stand_in_models()) == {"tiny", "tiny-afmoe", "tiny-mla",
+                                      "tiny-kda"}
     assert not set(_stand_in_models()) & set(_geometry_models())
     ecfg = served.service.ecfg
     assert ecfg.max_num_seqs == served.config["engine"]["max_num_seqs"]

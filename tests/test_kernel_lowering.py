@@ -10,7 +10,8 @@ attention at its published widths: flash over keys of 192 beside values of
 128 at the cell's buckets and its last continuation start, the absorbed
 kernel over 640-lane rows at 64 rows; and the streamed expert product at
 both routed cells' widths and largest decode buckets (128 experts of 2048 x
-768 at 64 rows, of 2048 x 1024 at 32). The case builders themselves
+768 at 64 rows, of 2048 x 1024 at 32); and Kimi-Linear's: flash over 16,384
+keys, KDA's chunk and step kernels at 32 heads of 128. The case builders themselves
 are checked against their oracles in interpret mode by the smoke's dry run
 (``tests/test_chip_smoke.py``; the expert cases by
 ``tests/test_moe_ffn_kernel.py``).
@@ -38,6 +39,14 @@ def _cases():
         for c in kernel_check.expert_cases(128, top_k, 2048, F,
                                            max_num_seqs=rows):
             seen.setdefault(c.name, c)
+    # Kimi-Linear's flash call at its last continuation start (16k keys of
+    # 192 beside values of 128: past Mosaic's default VMEM, which the call
+    # asks to be raised), and KDA's two kernels at 32 heads of 128: the
+    # chunk kernel over a 2048-token program, the step kernel at 16 rows
+    seen.setdefault("flash-kimi-16k", kernel_check._latent_flash_case(
+        32, 192, 128, 2048, 16384))
+    for c in kernel_check.kda_cases(32, 128, bucket=2048, max_num_seqs=16):
+        seen.setdefault(c.name, c)
     return list(seen.values())
 
 
