@@ -1601,7 +1601,7 @@ class LLMEngine:
             args += list(self._set_slot_cross(slot, req))
         with self.obs.phase("engine.prefill"):
             self.cache.kv, logits = fn(*args)
-        self.obs.count_pad(n, bucket - n, phase="prefill")  # bucket tail
+        self._note_program_pad(n, bucket - n, phase="prefill")  # bucket tail
         # no register_prefix here: this path only ever admits prefix/cross
         # (vision-conditioned) requests, whose blocks must NOT
         # content-address by tokens alone — and cross engines disable the
@@ -1731,8 +1731,8 @@ class LLMEngine:
         with self.obs.phase("engine.prefill"):
             self.cache.kv, logits = fn(*args)
         real = sum(len(r.prompt_ids) for r in group)
-        self.obs.count_pad(real, Kp * bucket - real,
-                           phase="prefill")  # bucket + batch pad
+        self._note_program_pad(real, Kp * bucket - real,
+                               phase="prefill")  # bucket + batch pad
         for req in group:  # batch rows are always plain text
             self.cache.register_prefix(req.prompt_ids,
                                        self.cache.seq(req.req_id).blocks)
@@ -1905,8 +1905,8 @@ class LLMEngine:
                                            self._put(ids),
                                            self._put([n], np.int32),
                                            table, *self._cont_args(start))
-        self.obs.count_pad(n, chunk_bucket - n,
-                           phase="prefill")  # chunk bucket tail
+        self._note_program_pad(n, chunk_bucket - n,
+                               phase="prefill")  # chunk bucket tail
         self.cache.register_prefix(req.prompt_ids, alloc.blocks)
         rng = self._admit_rng()
         with self.obs.phase("engine.fetch"):
@@ -1984,7 +1984,7 @@ class LLMEngine:
             self.cache.kv, logits = fn(self.params, self.cache.kv,
                                        self._put(ids),
                                        self._put([n], np.int32), table)
-        self.obs.count_pad(n, bucket - n, phase="prefill")
+        self._note_program_pad(n, bucket - n, phase="prefill")
         self.cache.register_prefix(head.prompt_ids, alloc.blocks)
         temp = np.zeros((Kp,), np.float32)    # dummy rows: greedy
         topk = np.zeros((Kp,), np.int32)
@@ -2087,7 +2087,7 @@ class LLMEngine:
             self._pending_chunk = (self._put(ids),
                                    self._put([n], np.int32), table,
                                    self._put([start], np.int32))
-            self.obs.count_pad(n, C - n, phase="chunk")
+            self._note_program_pad(n, C - n, phase="chunk")
             self.cache.register_prefix(
                 req.prompt_ids[:start + n],
                 self.cache.seq(req.req_id).blocks)
@@ -2116,7 +2116,7 @@ class LLMEngine:
                 args += list(self._slot_cross_args(s.slot))
             with self.obs.phase("engine.chunk"):
                 self.cache.kv, logits = fn(*args)
-        self.obs.count_pad(n, C - n, phase="chunk")  # final-chunk tail
+        self._note_program_pad(n, C - n, phase="chunk")  # final-chunk tail
         if final:
             self.cache.register_prefix(
                 req.prompt_ids, self.cache.seq(req.req_id).blocks)
@@ -2530,6 +2530,18 @@ class LLMEngine:
             touched, load_max,
             streamed_layer_steps=self._moe_layers if streamed else 0)
         return fetched
+
+    def _note_program_pad(self, real: int, padded: int, *,
+                          phase: str) -> None:
+        """Pad accounting for ONE prefill or continuation dispatch (``real``
+        prompt tokens, ``padded`` token slots of bucket tail and pad rows)
+        and, of a routed model, its expert layers if their product took the
+        tiled form: ``expert_form`` of the program's rows, which are every
+        token slot, so all of its layers or none."""
+        self.obs.count_pad(real, padded, phase=phase)
+        if self._moe_layers and expert_form(real + padded,
+                                            self.cfg) == "tiled":
+            self.obs.count_moe_tiled(self._moe_layers)
 
     def _note_dispatch_pad(self, running, Bb: int,
                            rows_per_seq: int = 1) -> None:

@@ -434,6 +434,17 @@ class StepTelemetry:
                 m[key] = m.get(key, 0) + int(v)
             self.moe = m
 
+    def count_moe_tiled(self, layer_calls: int) -> None:
+        """One prefill or continuation dispatch's expert layers whose
+        product took the tiled form (all or none: ``ops.moe.expert_form``
+        of the program's rows); the engine calls this for a dispatch that
+        has them, so the key is absent until one did."""
+        with self._lock:
+            m = self.moe if self.moe is not None else {}
+            m["tiled_layer_calls"] = (m.get("tiled_layer_calls", 0)
+                                      + int(layer_calls))
+            self.moe = m
+
     def count_mla(self, layer_steps: int, tokens_visible: int) -> None:
         with self._lock:
             m = self.mla if self.mla is not None else {}

@@ -11,6 +11,7 @@ degree, block size, bucket) — not a toy.
 from __future__ import annotations
 
 import dataclasses
+import functools
 from typing import Callable, List, Sequence
 
 import jax
@@ -351,12 +352,32 @@ def latent_cases(n_heads: int, head_dim: int, v_head_dim: int, width: int,
     return cases
 
 
-#: the streamed expert product against the grouped form. Rows are unit
-#: normal and leaves N(0, 0.02) at widths in the thousands, so a row's
-#: weighted sum over its experts stays under 1: the bound is two bf16 ulps
-#: there. The grouped form rounds ``g``, ``u``, ``silu(g) * u`` and each
-#: expert's result to bf16, the kernel only the weighted ``h``.
+#: the streamed and the tiled expert product against the grouped form. Rows
+#: are unit normal and leaves N(0, 0.02) at widths in the thousands, so a
+#: row's weighted sum over its experts stays under 1: the bound is two bf16
+#: ulps there. The grouped form rounds ``g``, ``u``, ``silu(g) * u`` and each
+#: expert's result to bf16, the streamed kernel only the weighted ``h``, the
+#: tiled one ``h`` and each expert's result.
 TOL_EXPERTS = 2 * 2.0 ** -8
+
+
+def _expert_inputs(key, *, n_experts: int, held: int, top_k: int, D: int,
+                   F: int, rows: int):
+    """``(x, sel, w, gate, up, down)``: ``rows`` rows of ``top_k`` distinct
+    experts of ``n_experts`` each (a shared popularity plus each row's own
+    noise: top-k of it, so some experts hold several rows and some none),
+    the last row inactive, and the leaves of ``held`` experts."""
+    kx, ks, kw, kg, ku, kd = jax.random.split(key, 6)
+    leaf = lambda k, s: (jax.random.normal(k, s, jnp.float32)       # noqa: E731
+                         * 0.02).astype(jnp.bfloat16)
+    logits = (jax.random.normal(ks, (n_experts,))
+              + jax.random.gumbel(kw, (rows, n_experts)))
+    _, sel = jax.lax.top_k(logits, top_k)
+    sel = sel.astype(jnp.int32).at[rows - 1].set(n_experts)
+    w = jnp.full((rows, top_k), 1.0 / top_k, jnp.float32)
+    return (jax.random.normal(kx, (rows, D), jnp.bfloat16), sel, w,
+            leaf(kg, (held, D, F)), leaf(ku, (held, D, F)),
+            leaf(kd, (held, F, D)))
 
 
 def _expert_case(n_experts: int, top_k: int, D: int, F: int,
@@ -368,19 +389,8 @@ def _expert_case(n_experts: int, top_k: int, D: int, F: int,
     from . import moe
     from .pallas.moe_ffn import moe_streamed_ffn
 
-    def make(key):
-        kx, ks, kw, kg, ku, kd = jax.random.split(key, 6)
-        leaf = lambda k, s: (jax.random.normal(k, s, jnp.float32)   # noqa: E731
-                             * 0.02).astype(jnp.bfloat16)
-        # a shared popularity plus each row's own noise: top-k of it
-        logits = (jax.random.normal(ks, (n_experts,))
-                  + jax.random.gumbel(kw, (rows, n_experts)))
-        _, sel = jax.lax.top_k(logits, top_k)
-        sel = sel.astype(jnp.int32).at[rows - 1].set(n_experts)
-        w = jnp.full((rows, top_k), 1.0 / top_k, jnp.float32)
-        return (jax.random.normal(kx, (rows, D), jnp.bfloat16), sel, w,
-                leaf(kg, (n_experts, D, F)), leaf(ku, (n_experts, D, F)),
-                leaf(kd, (n_experts, F, D)))
+    make = functools.partial(_expert_inputs, n_experts=n_experts,
+                             held=n_experts, top_k=top_k, D=D, F=F, rows=rows)
 
     def sizes(sel):
         return moe.expert_counts(sel, n_experts)
@@ -399,12 +409,47 @@ def _expert_case(n_experts: int, top_k: int, D: int, F: int,
         make_inputs=make, kernel=kernel, oracle=oracle, tol=TOL_EXPERTS)
 
 
+def _tiled_expert_case(n_experts: int, top_k: int, D: int, F: int,
+                       rows: int, held: int) -> KernelCase:
+    """The tiled expert product (``moe_grouped_ffn_tiled``) at one prefill
+    bucket: ``rows`` tokens of ``top_k`` distinct experts of ``n_experts``
+    each, a shared popularity making the groups uneven, the LAST ``held``
+    experts held here (fewer than all: assignments held elsewhere), the
+    last row inactive."""
+    from . import moe
+
+    first = n_experts - held
+
+    make = functools.partial(_expert_inputs, n_experts=n_experts, held=held,
+                             top_k=top_k, D=D, F=F, rows=rows)
+
+    def product(form):
+        def run(x, sel, w, gate, up, down, **interpret):
+            sizes = moe.expert_counts(sel, n_experts)[first:]
+            return form({"gate": gate, "up": up, "down": down}, x, sel, w,
+                        sizes, first, **interpret)
+        return run
+
+    return KernelCase(
+        name=(f"experts-tiled-E{held}of{n_experts}k{top_k}-D{D}-F{F}"
+              f"-b{rows}"),
+        make_inputs=make, kernel=product(moe._tiled),
+        oracle=product(moe._grouped), tol=TOL_EXPERTS)
+
+
 def expert_cases(n_experts: int, top_k: int, D: int, F: int, *,
-                 max_num_seqs: int = 8) -> List[KernelCase]:
+                 max_num_seqs: int = 8, prefill_rows: int = 0,
+                 held: int = 0) -> List[KernelCase]:
     """The streamed expert product at an engine's largest decode bucket and
-    at one small one (rows the kernel pads to a tile of sublanes)."""
-    return [_expert_case(n_experts, top_k, D, F, rows)
-            for rows in sorted({max_num_seqs, min(max_num_seqs, 8)})]
+    at one small one (rows the kernel pads to a tile of sublanes); with
+    ``prefill_rows``, the tiled one at that bucket, ``held`` of the experts
+    on this chip (default all)."""
+    cases = [_expert_case(n_experts, top_k, D, F, rows)
+             for rows in sorted({max_num_seqs, min(max_num_seqs, 8)})]
+    if prefill_rows:
+        cases.append(_tiled_expert_case(n_experts, top_k, D, F,
+                                        prefill_rows, held or n_experts))
+    return cases
 
 
 #: the KDA kernels against the token-by-token recurrence, float32 on both

@@ -7,41 +7,62 @@ call's row count asks for.
 call's static row count and the model's widths, which the engine's
 accounting calls too — picks how the product is made:
 
-- ``"grouped"`` (prefill and continuation: 512-2048 tokens, a hundred rows
-  an expert): the ``N x k`` (token, expert) assignments are sorted by
-  expert, counted into group sizes, and pushed through three grouped
-  products (gate, up, down: ``jax.lax.ragged_dot`` over the stacked expert
-  leaves ``[E, D, F]`` / ``[E, F, D]``), then weighted and summed back onto
-  their tokens. It costs the assignments' own FLOPs (128 dense products
-  would cost ``E / k`` times as many) plus a sort, a gather and a scatter
-  of ``N x k`` rows, and reads the weights of the experts that hold at
-  least one. At two or three rows an expert libtpu's grouped product does
-  not overlap an expert's bytes with its weight pushes: a Kanana-2 decode
-  layer (64 rows, 114 experts touched) took 2.2 ms where its bytes take
-  1.31 (ledger, PR 32).
-- ``"streamed"`` (every decode bucket): ``ops.pallas.moe_ffn`` pushes ALL
-  the rows through every touched expert and weights them by a dense
-  ``[N, E]`` combine matrix, 0 where a row did not choose the expert: no
-  sort, no permutation, no ragged boundary, no scatter. It costs ``E_touched
-  / k`` times the routed FLOPs, which at few rows hide under the bytes:
-  each touched expert is read once, the next one in flight meanwhile. The
-  same Kanana-2 layer alone: 2.35 ms grouped, 1.43 ms streamed, 91% of its
-  bytes' time (my chip run, PR 33; ``scripts/moe_bench.py``).
+- ``"streamed"`` (every decode bucket: at most ``STREAMED_MAX_ROWS`` rows):
+  ``ops.pallas.moe_ffn`` pushes ALL the rows through every touched expert
+  and weights them by a dense ``[N, E]`` combine matrix, 0 where a row did
+  not choose the expert: no sort, no permutation, no ragged boundary, no
+  scatter. It costs ``E_touched / k`` times the routed FLOPs, which at few
+  rows hide under the bytes: each touched expert is read once, the next one
+  in flight meanwhile. A Kanana-2 decode layer alone (64 rows, 113 experts
+  touched): 2.35 ms grouped, 1.43 ms streamed, 91% of its bytes' time (my
+  chip run, PR 33; ``scripts/moe_bench.py``).
+- ``"tiled"`` (prefill, continuation, verify: 512-2048 tokens, 32-96 rows an
+  expert): the ``N x k`` (token, expert) assignments are grouped by expert
+  into a layout in which each group starts on a tile of 64 or 128 rows
+  (``tiled_operands``: no sort, an assignment's place is the count of
+  earlier ones to its expert), the rows gathered into it, and ONE kernel
+  (``ops.pallas.moe_ffn.moe_tiled_ffn``) walks the row tiles, keeping an
+  expert's three matrices across its tiles: gate, up, ``silu * up`` and
+  down fused, each held expert that got a row read once a call. The result
+  is weighted and summed back onto its tokens in float32. It costs the
+  assignments' own FLOPs rounded up to whole tiles (at 64 rows an expert
+  twice the routed FLOPs, 1.2 ms of MXU time at Kimi-Linear's widths, under
+  the 2.2 ms its 128 held experts' bytes take), a gather of the rows into
+  the layout and one back: 4.20 ms a Kimi-Linear layer of 2,048 tokens,
+  3.12 Kanana-2's, 2.94 Trinity-Mini's of 1,024, the kernel 67-81% of the
+  held bytes' time (my chip run, PR 37).
+- ``"grouped"`` (widths no kernel can tile, ``D`` or ``F`` no multiple of
+  128: the CPU stand-ins, whatever their rows; the plain form and both
+  kernels' oracle): the assignments sorted by expert, counted into group
+  sizes, pushed through three grouped products (gate, up, down:
+  ``jax.lax.ragged_dot`` over the stacked expert leaves ``[E, D, F]`` /
+  ``[E, F, D]``), then weighted and summed back. It costs the assignments'
+  own FLOPs plus a sort, a gather and a scatter of ``N x k`` rows, writes
+  ``g``, ``u`` and ``silu(g) * u`` to HBM between its calls, and on the chip
+  libtpu's grouped product does not overlap an expert's bytes with its
+  weight pushes: 55-61% of the bytes' time at two or three rows an expert
+  (ledger, PR 32), and at a prefill chunk's 64-96 rows an expert 23-32%:
+  a Kimi-Linear layer of 2,048 tokens (top-8 of 256, 128 of 2304 x 1024
+  held) took 9.13 ms where its bytes take 2.21, Kanana-2's (top-6, 128 of
+  2048 x 768) 6.35 for 1.48, Trinity-Mini's 1,024 tokens (top-8, 128 of
+  2048 x 1024) 6.09 for 1.97 (my chip run, PR 37; ``scripts/moe_bench.py``;
+  PERF.md section 6).
 
-The bound between them comes from the chip's peaks and is no option: an
-expert's bytes over 819 GB/s against ``N`` rows of its FLOPs over 197
-TFLOP/s cross near 480 rows; ``STREAMED_MAX_ROWS`` 128 is one MXU tile of
-rows and leaves the margin the weight pushes need. Widths the kernel cannot
-tile (``D`` or ``F`` no multiple of 128: the CPU stand-ins) take the grouped
-form whatever their rows. Both forms read the same leaves in the same
-layout; nothing is repacked at load.
+The bound between streamed and tiled comes from the chip's peaks and is no
+option: an expert's bytes over 819 GB/s against ``N`` rows of its FLOPs over
+197 TFLOP/s cross near 480 rows (all rows through every expert would cost a
+2,048-token chunk 21 times its routed FLOPs); ``STREAMED_MAX_ROWS`` 128 is
+one MXU tile of rows and leaves the margin the weight pushes need. All
+three forms read the same leaves in the same layout; nothing is repacked at
+load.
 
 The layer is told which experts it HOLDS (``held = (first, count)``: the
 stacked leaves are that slice of the ``E``). It routes over all ``E`` —
 every holder makes the same choice — and computes its own experts' part;
 assignments to experts held elsewhere, and every assignment of an inactive
 or padded row, go to no expert at all (grouped: they sort behind the last
-group and carry weight 0; streamed: a zero of the combine matrix). Summing
+group and carry weight 0; tiled: they have no row in the layout and are
+selected away; streamed: a zero of the combine matrix). Summing
 the holders' parts, plus the shared expert once, is the uncut layer.
 
 No token is dropped, whatever the load: there is no capacity.
@@ -98,13 +119,13 @@ def expert_counts(sel: jax.Array, n_experts: int) -> jax.Array:
 
 
 def expert_form(n_rows: int, cfg) -> str:
-    """``"streamed"`` or ``"grouped"``: the form the expert product of a
-    call with ``n_rows`` rows takes (module docstring). A function of the
-    static row count and the model's widths, and of nothing else."""
-    if (n_rows <= STREAMED_MAX_ROWS and cfg.dim % 128 == 0
-            and cfg.moe_mlp_dim % 128 == 0):
-        return "streamed"
-    return "grouped"
+    """``"streamed"``, ``"tiled"`` or ``"grouped"``: the form the expert
+    product of a call with ``n_rows`` rows takes (module docstring). A
+    function of the static row count and the model's widths, and of nothing
+    else."""
+    if cfg.dim % 128 or cfg.moe_mlp_dim % 128:
+        return "grouped"
+    return "streamed" if n_rows <= STREAMED_MAX_ROWS else "tiled"
 
 
 def _grouped(ex: Dict, x2: jax.Array, sel: jax.Array, w: jax.Array,
@@ -134,6 +155,91 @@ def _grouped(ex: Dict, x2: jax.Array, sel: jax.Array, w: jax.Array,
     inverse = jnp.zeros_like(order).at[order].set(
         jnp.arange(N * k, dtype=order.dtype))
     return d[inverse].reshape(N, k, -1).sum(axis=1)
+
+
+def _tile_experts(sizes: jax.Array, tm: int, tiles: int):
+    """``(pstart [count], tile_expert [tiles], n_tiles)``: where each
+    expert's group starts in a layout that pads every group to a multiple
+    of ``tm`` rows, the expert of each of its ``tiles`` row tiles (behind
+    the ``n_tiles`` real ones the last real one's again), and how many
+    tiles are real."""
+    count = sizes.shape[0]
+    per = -(-sizes // tm)                                 # tiles an expert
+    end = jnp.cumsum(per)
+    steps = jnp.arange(tiles, dtype=jnp.int32)
+    tile_expert = jnp.sum(end[None, :] <= steps[:, None], axis=1,
+                          dtype=jnp.int32)
+    last = jnp.max(jnp.where(per > 0, jnp.arange(count, dtype=jnp.int32), 0))
+    return (end - per) * tm, jnp.minimum(tile_expert, last), end[-1]
+
+
+def tiled_operands(sel: jax.Array, sizes: jax.Array, first: int, tm: int,
+                   block: int = 256):
+    """What the tiled kernel is told of a routing, for the ``count`` experts
+    held from ``first`` on: the assignments grouped by expert, in token
+    order inside a group, each group starting on a tile of ``tm`` rows.
+    ``(tok [R] int32, tile_expert [tiles] int32, n_tiles int32, mine
+    [N * k] bool, pos [N * k] int32)``: the token of each row of the layout
+    (a row that pads a group: token 0), each tile's expert, how many tiles
+    are real, and where each assignment's row lies (``mine`` False: nowhere,
+    held elsewhere or inactive; ``pos`` 0). ``R = tiles * tm``; ``tiles`` is
+    the most the rows could fill.
+
+    No sort: an assignment's place in its group is the number of earlier
+    assignments to the same expert, counted exactly by a triangular product
+    over blocks of ``block`` one-hot rows (0/1 operands, float32 sums) and
+    the blocks' running totals; the layout's tokens are then ONE scatter of
+    ``N * k`` integers."""
+    from .pallas.moe_ffn import tile_bound
+
+    N, k = sel.shape
+    A, count = N * k, sizes.shape[0]
+    tiles = tile_bound(A, count, tm)
+    pstart, tile_expert, n_tiles = _tile_experts(sizes, tm, tiles)
+    local = sel.reshape(A) - first
+    mine = (local >= 0) & (local < count)
+    padded = -(-A // block) * block
+    onehot = (jnp.pad(local, (0, padded - A), constant_values=-1)[:, None]
+              == jnp.arange(count, dtype=jnp.int32)[None, :]).reshape(
+                  padded // block, block, count)
+    ones = onehot.astype(jnp.bfloat16)
+    earlier = jnp.einsum(
+        "ij,bjc->bic", jnp.tril(jnp.ones((block, block), jnp.bfloat16), -1),
+        ones, preferred_element_type=jnp.float32)
+    total = jnp.sum(ones, axis=1, dtype=jnp.float32)
+    before = jnp.cumsum(total, axis=0) - total
+    place = earlier + (before + pstart.astype(jnp.float32))[:, None, :]
+    pos = jnp.sum(jnp.where(onehot, place, 0.0), axis=-1).reshape(
+        padded)[:A].astype(jnp.int32)
+    R = tiles * tm
+    a = jnp.arange(A, dtype=jnp.int32)
+    # the others land behind the layout, each on an index of its own, and
+    # are dropped
+    tok = jnp.zeros((R,), jnp.int32).at[jnp.where(mine, pos, R + a)].set(
+        a // k, mode="drop", unique_indices=True)
+    return tok, tile_expert, n_tiles, mine, pos
+
+
+def _tiled(ex: Dict, x2: jax.Array, sel: jax.Array, w: jax.Array,
+           sizes: jax.Array, first: int, *,
+           interpret: Optional[bool] = None,
+           tile_rows: Optional[int] = None) -> jax.Array:
+    """``[N, D]`` float32: the assignments grouped by expert into a layout
+    of whole row tiles, through ONE kernel that reads each held expert once
+    (``ops.pallas.moe_ffn.moe_tiled_ffn``), weighted and summed back.
+    ``tile_rows``: the bench's, to try a row tile other than ``row_tile``'s."""
+    from .pallas.moe_ffn import moe_tiled_ffn, row_tile
+
+    N, k = sel.shape
+    tok, tile_expert, n_tiles, mine, pos = tiled_operands(
+        sel, sizes, first, tile_rows or row_tile(N * k, sizes.shape[0]))
+    d = moe_tiled_ffn(x2[tok], tile_expert, n_tiles, ex["gate"], ex["up"],
+                      ex["down"], interpret=interpret)
+    # an assignment computed nowhere here reads row 0 and is selected away
+    # (select, do not multiply: the rows behind the last real tile hold
+    # whatever was there)
+    d = d[pos].astype(jnp.float32) * w.reshape(N * k, 1)
+    return jnp.where(mine[:, None], d, 0.0).reshape(N, k, -1).sum(axis=1)
 
 
 def streamed_operands(sel: jax.Array, w: jax.Array, sizes: jax.Array,
@@ -171,6 +277,9 @@ def _streamed(ex: Dict, x2: jax.Array, sel: jax.Array, w: jax.Array,
                             ex["gate"], ex["up"], ex["down"])
 
 
+_FORMS = {"streamed": _streamed, "tiled": _tiled, "grouped": _grouped}
+
+
 def expert_layer(mp: Dict, x: jax.Array, cfg, *,
                  active: Optional[jax.Array] = None,
                  held: Optional[Tuple[int, int]] = None):
@@ -195,7 +304,7 @@ def expert_layer(mp: Dict, x: jax.Array, cfg, *,
     counts = expert_counts(sel, E)
     stats = jnp.stack([jnp.sum(counts > 0, dtype=jnp.int32),
                        jnp.max(counts)])
-    product = _streamed if expert_form(N, cfg) == "streamed" else _grouped
+    product = _FORMS[expert_form(N, cfg)]
     y = product(mp["experts"], x2, sel, w, counts[first:first + count],
                 first).astype(x.dtype)
     if cfg.n_shared_experts:

@@ -1,5 +1,9 @@
-"""The routed experts' product for a call with FEW ROWS, one Pallas kernel
-that streams each touched expert's three matrices out of HBM once.
+"""The routed experts' product as ONE Pallas kernel that takes each expert's
+three matrices out of HBM once a call: ``moe_streamed_ffn`` for a call with
+FEW ROWS (a decode step), and its sibling ``moe_tiled_ffn`` for a call with
+many (a prefill chunk), whose rows are grouped by expert first.
+
+**Streamed.**
 
 A decode step hands the expert layer a few dozen rows and ``k`` choices a
 row: two or three rows an expert. At that size the layer costs the BYTES of
@@ -35,6 +39,51 @@ at 819 GB/s; Trinity-Mini's (32 rows, top-8, 65 of 128 x [2048, 1024])
 1.650 -> 1.100 ms, 91%; 8 rows (37 touched) 0.685 -> 0.472 ms, 90%. A step
 that is a whole expert is the fastest: tiles of 128 to 384 columns read
 within 0.5% at 768 columns and 2-10% slower at 1024.
+
+**Tiled.** A prefill or continuation program hands the layer 512-2048
+tokens: 32-96 rows an expert. There ``ops.moe.tiled_operands`` groups the
+``N x k`` assignments by expert into a layout whose groups each start on a
+tile of ``row_tile`` rows (a group is padded to whole tiles), the caller
+gathers the rows into it, and the grid walks the ROW TILES: a
+scalar-prefetched list names each tile's expert, consecutive tiles of one
+expert name the same block, so the pipeline keeps it and an expert's three
+matrices leave HBM once a call, the next expert's in flight under the double
+buffering (a whole Kimi-Linear expert twice is 28.3 MB of
+``_TILE_BUDGET_BYTES``). A step is gate, up, ``silu * up`` and down of one
+tile of rows in float32 accumulation, ``h`` rounded once to feed the down
+product, the result rounded once to the rows' type as the grouped product
+rounds its own (in float32 where tiles of ``F`` add up); the routing weight
+and the sum over a token's ``k`` parts are the caller's, in float32, as it
+gathers the rows back. The static bound on tiles is ``ceil(N k / tile) +
+count`` (``tile_bound``: every assignment could land on the held experts);
+the tiles behind the last real one repeat its blocks, so nothing is fetched
+or written for them, and are skipped.
+
+Measured alone on a v5e the same way, each of the three layers routing its
+own way (my chip run, PR 37; PERF.md section 6), against the ``ragged_dot``
+form on the same assignments; ms a layer, whole (placement, gather into the
+layout, kernel, gather back with weight and sum) and the kernel alone with
+its share of the HELD experts' bytes' time, at row tiles 64 / 128 / 256:
+
+- Kimi-Linear's largest program (2,048 tokens, top-8 of 256, 128 of 2304 x
+  1024 held, 64 rows an expert, 133 real tiles of 128 of a bound of 256):
+  ``ragged_dot`` form 9.13 (its three calls 7.38); tiled 4.24 / **4.20** /
+  4.70, kernel 2.91 / 2.74 (81%) / 2.92;
+- Kanana-2's (2,048, top-6, 128 of 2048 x 768, 96 rows an expert): 6.35
+  (5.21); tiled **3.12** / 3.41 / 3.67, kernel 2.22 (67%) / 2.09 (71%) /
+  2.11;
+- Trinity-Mini's (1,024, top-8, 128 of 2048 x 1024, 64 rows an expert, the
+  fullest 7.7 times the mean): 6.09 (5.60); tiled **2.94** / 3.22 / 3.52,
+  kernel 2.62 (75%) / 2.58 (76%) / 2.62.
+
+The kernel alone is fastest at 128 rows a tile everywhere (at 64 rows an
+expert a 128-row tile computes twice the routed FLOPs, 1.2 ms of MXU time
+under 2.2 ms of bytes; a 64-row tile doubles the steps), but the LAYER is
+fastest at 64 wherever groups are shorter than a tile, because the layout
+the rows are gathered into and back from is ``tile_bound`` x tile rows long
+whatever lands in it: ``row_tile`` takes 128 where the assignments could
+give every held expert a full tile and 64 below. The placement alone is
+0.57-0.69 ms a call, the two gathers with it 0.66-1.47 ms of the layer.
 """
 
 from __future__ import annotations
@@ -47,7 +96,7 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
 #: the kernel's name in a device trace; begins with ``ops.moe.GROUPED_NAME``
-#: so that a metric reading the whole expert product finds both forms
+#: so that a metric reading the whole expert product finds every form
 KERNEL_NAME = "moe_grouped_ffn_streamed"
 
 #: bytes the double-buffered weight tiles of one grid step may hold; a whole
@@ -162,3 +211,123 @@ def moe_streamed_ffn(
     )(ids.astype(jnp.int32), n_touched.astype(jnp.int32).reshape(1),
       x, combine.astype(jnp.float32), gate, up, down)
     return out[:N]
+
+
+#: the tiled kernel's name in a device trace: begins with
+#: ``ops.moe.GROUPED_NAME`` (the whole expert product's metrics read it) and
+#: does not contain ``KERNEL_NAME`` (the streamed kernel's own roofline
+#: does not)
+TILED_NAME = "moe_grouped_ffn_tiled"
+
+
+def row_tile(n_assignments: int, count: int) -> int:
+    """Rows of one grid step of the tiled kernel, from the call's static
+    shapes (the assignments it groups, the experts it holds): one MXU tile
+    of rows where the assignments could give every held expert one, half
+    of it below (module docstring: the kernel alone is fastest at 128, the
+    layer with its gathers at 64 once groups are shorter than a tile)."""
+    return 128 if n_assignments >= 128 * count else 64
+
+
+def tile_bound(n_assignments: int, count: int, tm: int) -> int:
+    """The most row tiles ``n_assignments`` rows in ``count`` groups, each
+    padded to a multiple of ``tm``, can fill."""
+    return -(-n_assignments // tm) + min(count, n_assignments)
+
+
+def _tiled_kernel(te_ref, n_ref, x_ref, g_ref, u_ref, d_ref, o_ref):
+    # te_ref [tiles], n_ref [1] SMEM; x_ref [tm, D]; g_ref, u_ref [D, tf];
+    # d_ref [tf, D]; o_ref [tm, D], resident over j
+    i, j = pl.program_id(0), pl.program_id(1)
+
+    @pl.when(i < n_ref[0])
+    def _row_tile():
+        x = x_ref[...]
+        g = jnp.dot(x, g_ref[...], preferred_element_type=jnp.float32)
+        u = jnp.dot(x, u_ref[...], preferred_element_type=jnp.float32)
+        h = (jax.nn.silu(g) * u).astype(d_ref.dtype)
+        part = jnp.dot(h, d_ref[...], preferred_element_type=jnp.float32)
+
+        @pl.when(j == 0)
+        def _first():
+            o_ref[...] = part.astype(o_ref.dtype)
+
+        @pl.when(j > 0)
+        def _add():
+            o_ref[...] += part.astype(o_ref.dtype)
+
+
+@functools.partial(jax.jit, static_argnames=("tile_f", "interpret"))
+def moe_tiled_ffn(
+    xs: jax.Array,           # [tiles * tm, D] the rows grouped by expert,
+                             # each group starting on a row tile
+    tile_expert: jax.Array,  # [tiles] int32: the expert of each row tile,
+                             # behind the last real tile that one's again
+    n_tiles: jax.Array,      # [] or [1] int32: how many tiles are real
+    gate: jax.Array,         # [E', D, F]
+    up: jax.Array,           # [E', D, F]
+    down: jax.Array,         # [E', F, D]
+    *,
+    tile_f: Optional[int] = None,
+    interpret: Optional[bool] = None,
+) -> jax.Array:
+    """``[tiles * tm, D]``: each row through its tile's expert, unweighted,
+    in the rows' type where a step is a whole expert (rounded once, as the
+    grouped product rounds its result) and in float32 where tiles of the
+    inner width add up. Rows of the tiles behind ``n_tiles`` are not
+    written."""
+    from jax.experimental.pallas import tpu as pltpu
+
+    R, D = xs.shape
+    _E, _, F = gate.shape
+    tiles = tile_expert.shape[0]
+    tm = R // tiles
+    if interpret is None:
+        from ..attention import on_tpu_platform
+
+        interpret = not on_tpu_platform()
+    tf = tile_f or inner_tile(D, F, gate.dtype.itemsize)
+    nj = F // tf
+    out_dtype = xs.dtype if nj == 1 else jnp.float32
+
+    def row(i, n_ref):
+        # behind the last real tile: the block it ended on (nothing to
+        # fetch, nothing new to write back)
+        return jnp.maximum(jnp.minimum(i, n_ref[0] - 1), 0)
+
+    def col(i, j, n_ref):
+        return jnp.where(i < n_ref[0], j, nj - 1)
+
+    rows = lambda i, j, te_ref, n_ref: (row(i, n_ref), 0)   # noqa: E731
+    itemsize = gate.dtype.itemsize
+    return pl.pallas_call(
+        _tiled_kernel,
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=2,
+            grid=(tiles, nj),
+            in_specs=[
+                pl.BlockSpec((tm, D), rows),
+                pl.BlockSpec((None, D, tf), lambda i, j, te_ref, n_ref: (
+                    te_ref[i], 0, col(i, j, n_ref))),
+                pl.BlockSpec((None, D, tf), lambda i, j, te_ref, n_ref: (
+                    te_ref[i], 0, col(i, j, n_ref))),
+                pl.BlockSpec((None, tf, D), lambda i, j, te_ref, n_ref: (
+                    te_ref[i], col(i, j, n_ref), 0)),
+            ],
+            out_specs=pl.BlockSpec((tm, D), rows),
+        ),
+        out_shape=jax.ShapeDtypeStruct((R, D), out_dtype),
+        # a tile's sum over j lives in its resident block; tiles of one
+        # expert follow each other, so its weights stay
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary", "arbitrary"),
+            vmem_limit_bytes=(
+                2 * 3 * D * tf * itemsize
+                # the rows and the result twice, the product in float32;
+                # g, u and h of one tile
+                + tm * (D * (2 * itemsize + 3 * 4) + tf * 3 * 4)
+                + 4 * 2 ** 20)),
+        interpret=interpret,
+        name=TILED_NAME,
+    )(tile_expert.astype(jnp.int32), n_tiles.astype(jnp.int32).reshape(1),
+      xs, gate, up, down)

@@ -122,7 +122,9 @@ _PHASE_SECONDS = ("shai_engine_phase_seconds_total",
 _MOE_COUNTERS = ("shai_engine_moe_total",
                  "Expert routing in decode dispatches, by counter: "
                  "layer_steps, assignments, experts_touched, load_max, "
-                 "streamed_layer_steps")
+                 "streamed_layer_steps; tiled_layer_calls: expert layers "
+                 "of prefill and continuation dispatches whose product "
+                 "took the tiled form")
 _WINDOW_COUNTERS = ("shai_engine_window_total",
                     "Window layers in decode dispatches, by counter: "
                     "tokens_walked, tokens_skipped, tokens_visible, "

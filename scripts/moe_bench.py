@@ -1,19 +1,32 @@
-"""The routed experts' product alone, both forms, at the benchmark cells'
-shapes: ``chiprun -- python scripts/moe_bench.py`` (``--dry-run``: tiny, on
-the CPU, kernel interpreted; its times mean nothing).
+"""The routed experts' product alone, each form against ``ragged_dot``, at
+the benchmark cells' shapes: ``chiprun -- python scripts/moe_bench.py``
+(``--dry-run``: tiny, on the CPU, kernels interpreted; its times mean
+nothing).
 
-Per shape: rows, top-k and 128 experts of the published widths, an
-assignment crafted to touch about as many experts as the cell's counters
-read (``experts_touched_mean.moe``, ``expert_load_max_over_mean.moe``), and
-three expert layers chained in one program so that the device time of a
-layer is the program's over three. Timed: the grouped form (sort, gather,
-three ``ragged_dot``, scatter), the streamed kernel at each inner tile, and
-the whole ``expert_layer`` (router and shared expert with it) under each
-form. ``share`` is the touched experts' bytes at the chip's HBM peak over
-the time. Writes ``chiprun_out/moe_bench.json``.
+First the PREFILL shapes (``--prefill-only`` stops there): the largest
+prefill program of each routed configuration (tokens, top-k, the experts
+routed over and HELD, the published widths), a routing skewed by a shared
+popularity, three expert layers chained in one program, each routing its own
+way, so that the device time of a layer is the program's over three. Timed:
+the grouped form (sort, gather, three ``ragged_dot``, scatter), the tiled
+form whole at each row tile (placement, gather into the layout, kernel,
+gather back), its placement alone and its kernel alone; ``share`` is the
+HELD experts' bytes at the chip's HBM peak over the time. ``--trace`` adds
+the device's self time by op for both forms.
+
+Then the DECODE shapes: rows, top-k and 128 experts of the published widths,
+an assignment crafted to touch about as many experts as the cell's counters
+read (``experts_touched_mean.moe``, ``expert_load_max_over_mean.moe``).
+Timed: the grouped form, the streamed kernel at each inner tile, and the
+whole ``expert_layer`` (router and shared expert with it) under each form.
+``share`` is the touched experts' bytes at the HBM peak over the time.
+
+Writes ``chiprun_out/moe_bench.json`` (``--prefill-only``:
+``chiprun_out/moe_bench_prefill.json``).
 """
 
 import dataclasses
+import functools
 import json
 import os
 import sys
@@ -41,6 +54,16 @@ SHAPES = {
     "kanana-8": (8, 6, 128, 2048, 768, 0.5, 0.04),
 }
 DRY_SHAPES = {"dry-16": (16, 2, 8, 256, 128, 0.5, 0.04)}
+
+#: the largest prefill program of each routed configuration: name -> rows,
+#: top-k, experts routed over, experts HELD (the first so many), D, F, skew
+PREFILL_SHAPES = {
+    "kimi-2048": (2048, 8, 256, 128, 2304, 1024, 0.5),
+    "kanana-2048": (2048, 6, 128, 128, 2048, 768, 0.7),
+    "trinity-1024": (1024, 8, 128, 128, 2048, 1024, 1.2),
+}
+DRY_PREFILL_SHAPES = {"dry-300": (300, 2, 8, 4, 256, 128, 0.7)}
+ROW_TILES = (64, 128, 256)
 
 
 def crafted_routing(rows, k, E, skew, seed=0):
@@ -157,20 +180,142 @@ def bench_shape(name, shape, n, dry):
     return out
 
 
+def op_times(label, f, *args, runs=5):
+    """Device self time by op over ``runs`` calls, from a profiler trace."""
+    import glob
+    import tempfile
+
+    from benchmark import trace as tr
+
+    d = tempfile.mkdtemp(dir=os.path.join(ROOT, "chiprun_out"))
+    jax.block_until_ready(f(*args))
+    jax.profiler.start_trace(d)
+    for _ in range(runs):
+        out = f(*args)
+    jax.block_until_ready(out)
+    jax.profiler.stop_trace()
+    files = sorted(glob.glob(os.path.join(d, "plugins", "profile", "*",
+                                          "*.xplane.pb")))
+    planes = tr.load_xplane(files[-1])["planes"]
+    for pname, lines in planes.items():
+        if tr.DEVICE_PLANE.match(pname) and tr.OPS_LINE in lines:
+            times = tr.self_times(lines[tr.OPS_LINE])
+            top = sorted(times.items(), key=lambda kv: -kv[1])[:14]
+            print(json.dumps({"ops_ms_per_layer": label, "top": [
+                [k, round(v / runs / LAYERS * 1e3, 4)] for k, v in top],
+                "sum": round(sum(times.values()) / runs / LAYERS * 1e3, 4)}),
+                flush=True)
+            break
+
+
+def bench_prefill_shape(name, shape, n, trace=False):
+    """A prefill program's product: today's grouped form, and the tiled one
+    at each row tile, whole (sort, gather into the layout, kernel, sum back)
+    and the kernel alone on the first layer's operands. Each of the three
+    chained layers routes its own way, so nothing of one layer's sort or
+    layout serves another. ``share``: the HELD experts' bytes at the HBM
+    peak over the time."""
+    rows, k, E, held, D, F, skew = shape
+    sels = [crafted_routing(rows, k, E, skew, seed=i) for i in range(LAYERS)]
+    counts = [np.bincount(s.ravel(), minlength=E)[:held] for s in sels]
+    keys = jax.random.split(jax.random.PRNGKey(0), 4 * LAYERS + 2)
+    leaf = lambda key, s: (jax.random.normal(key, s, jnp.float32)  # noqa: E731
+                           * 0.02).astype(jnp.bfloat16)
+    layers = [{"gate": leaf(keys[4 * i], (held, D, F)),
+               "up": leaf(keys[4 * i + 1], (held, D, F)),
+               "down": leaf(keys[4 * i + 2], (held, F, D)),
+               "sel": jnp.asarray(sels[i]),
+               "sizes": jnp.asarray(counts[i], jnp.int32)}
+              for i in range(LAYERS)]
+    x = jax.random.normal(keys[-1], (rows, D), jnp.bfloat16)
+    w = jnp.full((rows, k), 1.0 / k, jnp.float32)
+    least_s = held * 3 * D * F * 2 / HBM_BYTES_PER_S
+    mine = int(np.mean([c.sum() for c in counts]))
+    base = dict(shape=name, rows=rows, k=k, experts=E, held=held, D=D, F=F,
+                assignments_held=mine,
+                load_max=int(max(c.max() for c in counts)),
+                load_max_over_mean=float(np.mean(
+                    [c.max() / (c.sum() / held) for c in counts])),
+                bytes_ms=least_s * 1e3,
+                flops_ms=mine * 3 * 2 * D * F / 197e12 * 1e3)
+    out = []
+
+    def record(form, seconds, layers_timed=LAYERS, **extra):
+        rec = dict(base, form=form,
+                   ms_per_layer=seconds / layers_timed * 1e3,
+                   share=least_s / (seconds / layers_timed), **extra)
+        print(json.dumps(rec), flush=True)
+        out.append(rec)
+
+    def chained(product):
+        def run(layers, x, w):
+            for ex in layers:
+                x = x + product(ex, x, ex["sel"], w, ex["sizes"],
+                                0).astype(x.dtype)
+            return x
+        return jax.jit(run)
+
+    grouped = chained(moe._grouped)
+    t, want = timed(grouped, layers, x, w, n=n)
+    record("grouped", t)
+    want = np.asarray(want, np.float32)
+    for tm in ROW_TILES:
+        tag = f"rows{tm}"
+
+        def operands(sel, sizes, tm=tm):
+            return moe.tiled_operands(sel, sizes, 0, tm)
+
+        try:
+            f = chained(functools.partial(moe._tiled, tile_rows=tm))
+            t, got = timed(f, layers, x, w, n=n)
+            err = float(np.abs(np.asarray(got, np.float32) - want).max())
+            ex = layers[0]
+            t2, (tok, te, nt, _, _) = timed(
+                jax.jit(operands), ex["sel"], ex["sizes"], n=n)
+            record(f"tiled-{tag}", t, max_abs_diff_vs_grouped=err,
+                   out_abs_max=float(np.abs(want).max()), tiles=int(nt),
+                   tiles_bound=int(te.shape[0]))
+            record(f"tiled-operands-alone-{tag}", t2, layers_timed=1)
+            t, _ = timed(moe_ffn.moe_tiled_ffn, x[tok], te, nt, ex["gate"],
+                         ex["up"], ex["down"], n=n)
+            record(f"tiled-kernel-alone-{tag}", t, layers_timed=1)
+            if trace and tm == 128:
+                op_times(f"tiled-{tag}", f, layers, x, w)
+        except Exception as e:      # a tile Mosaic refuses: say so, go on
+            print(json.dumps(dict(base, form=f"tiled-{tag}",
+                                  error=f"{type(e).__name__}: "
+                                        f"{str(e)[:300]}")), flush=True)
+    if trace:
+        op_times("grouped", grouped, layers, x, w)
+    return out
+
+
 def main():
     dry = "--dry-run" in sys.argv
     if not dry and jax.default_backend() != "tpu":
         sys.exit("moe_bench needs the chip (or --dry-run)")
     out = []
+    for name, shape in (DRY_PREFILL_SHAPES if dry
+                        else PREFILL_SHAPES).items():
+        out += bench_prefill_shape(name, shape, n=2 if dry else 20,
+                                   trace="--trace" in sys.argv)
+    if "--prefill-only" in sys.argv:
+        if not dry:
+            write_out(out, "moe_bench_prefill.json")
+        return
     for name, shape in (DRY_SHAPES if dry else SHAPES).items():
         out += bench_shape(name, shape, n=2 if dry else 40, dry=dry)
     if not dry:
-        dev = jax.devices()[0]
-        out.append({"device": dev.device_kind, "platform": dev.platform})
-        os.makedirs(os.path.join(ROOT, "chiprun_out"), exist_ok=True)
-        with open(os.path.join(ROOT, "chiprun_out", "moe_bench.json"),
-                  "w") as fh:
-            json.dump(out, fh, indent=1)
+        write_out(out, "moe_bench.json")
+
+
+def write_out(out, name):
+    dev = jax.devices()[0]
+    out.append({"device": dev.device_kind, "platform": dev.platform})
+    print(json.dumps(out[-1]))
+    os.makedirs(os.path.join(ROOT, "chiprun_out"), exist_ok=True)
+    with open(os.path.join(ROOT, "chiprun_out", name), "w") as fh:
+        json.dump(out, fh, indent=1)
 
 
 if __name__ == "__main__":

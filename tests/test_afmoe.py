@@ -424,6 +424,66 @@ def test_the_form_of_the_expert_product_is_counted(monkeypatch):
     assert got["streamed_layer_steps"] == 0 < got["layer_steps"]
 
 
+@pytest.mark.parametrize("width,want_form", [(128, "tiled"),
+                                             (None, "grouped")])
+def test_the_tiled_form_is_counted_a_prefill_family_dispatch(
+        width, want_form, monkeypatch):
+    """``moe.tiled_layer_calls`` counts every expert layer of each prefill
+    or continuation dispatch whose rows (the bucket: over 128 here) and
+    widths give the tiled form (128 x 128, the kernel interpreted), and
+    nothing at the stand-in's own widths (64 x 16), which keep
+    ``ragged_dot``; beside ``dispatches_by_phase`` it reads the expert
+    layers a program, and it reaches ``shai_engine_moe_total``. The tokens
+    are the grouped form's."""
+    from scalable_hw_agnostic_inference_tpu.ops import moe
+    from scalable_hw_agnostic_inference_tpu.serve.metrics import (
+        EngineTelemetryCollector,
+    )
+
+    cfg = TINY if width is None else dataclasses.replace(
+        TINY, dim=width, moe_mlp_dim=width, n_experts=8,
+        n_experts_per_tok=2)
+    params = geometry_params(cfg, dtype=jnp.float32, seed=3)
+    over = dict(max_model_len=512, context_encoding_buckets=(256,),
+                max_new_tokens=4)
+    sp = SamplingParams(temperature=0.0, max_new_tokens=3)
+    prompts = [_prompt(140), _prompt(300)]    # one program; two (chunked)
+    assert moe.expert_form(256, cfg) == want_form
+    eng = _engine(params, cfg, **over)
+    fins = eng.generate(prompts, sp)
+    snap = eng.obs.snapshot()
+    programs = sum(snap["dispatches_by_phase"].get(p, 0)
+                   for p in ("prefill", "chunk"))
+    assert programs >= 2
+    n_moe = cfg.n_moe_layers
+    if want_form == "tiled":
+        assert snap["moe"]["tiled_layer_calls"] == n_moe * programs > 0
+        fams = {f.name: f for f in EngineTelemetryCollector(
+            lambda: eng.obs, "t").collect()}
+        exported = {s.labels["counter"]: s.value
+                    for s in fams["shai_engine_moe"].samples}
+        assert exported["tiled_layer_calls"] == n_moe * programs
+        monkeypatch.setattr(
+            moe, "expert_form",
+            lambda n, c: "grouped" if n > moe.STREAMED_MAX_ROWS
+            else "streamed")
+        want = _engine(params, cfg, **over).generate(prompts, sp)
+        assert [f.token_ids for f in fins] == [f.token_ids for f in want]
+    else:
+        assert "tiled_layer_calls" not in snap["moe"]
+    # the metric file's reader over this very snapshot
+    import json
+
+    from benchmark.readers import counter_ratio
+
+    with open(os.path.join(SPEC.root, "benchmark", "layer_metrics",
+                           "moe_tiled_layers_per_program.kda.json")) as f:
+        reader = json.load(f)["reader"]
+    got = counter_ratio.read(
+        {"before": {"engine": {}}, "after": {"engine": snap}}, reader)
+    assert got == (n_moe if want_form == "tiled" else 0)
+
+
 def test_a_saturated_routed_engine_streams_and_counts_each_step_once(
         tiny_params, monkeypatch):
     """Five requests on two slots: the steady path retires a routed step's

@@ -844,6 +844,24 @@ def test_with_no_kda_layer_the_step_programs_are_what_they_were(
     assert "kda_" not in a
 
 
+@pytest.mark.parametrize("preset", ["tiny", "tiny_afmoe", "tiny_mla"])
+@pytest.mark.parametrize("program", ["decode", "prefill", "cont"])
+def test_the_stand_ins_keep_the_plain_expert_product(preset, program):
+    """At widths no kernel can tile the expert product stays ``ragged_dot``
+    whatever the rows, so the stand-ins' step programs (and Mistral's, which
+    has no expert layer) trace no expert kernel: with the tiled form in the
+    tree they are the parent's character for character (compared text for
+    text against the parent commit at 16 and 256 rows, with ``mistral_7b``
+    at 512 and the routed stages' decode programs: PERF.md, PR 37)."""
+    cfg = getattr(LlamaConfig, preset)()
+    leaf = {n: jax.ShapeDtypeStruct((9, 8) + per, jnp.float32)
+            for n, per in cache_leaves(cfg).items()}
+    text = _step_program_text(cfg, leaf, program)
+    assert "moe_grouped_ffn_tiled" not in text
+    assert "moe_grouped_ffn_streamed" not in text
+    assert ("ragged_dot" in text) == bool(cfg.n_moe_layers)
+
+
 def test_a_short_flash_call_asks_for_no_more_vmem_than_it_did():
     """The flash kernel asks Mosaic for VMEM past its default only where K
     and V of a head outgrow it (16k keys of 192): Kanana's longest call

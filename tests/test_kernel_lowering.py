@@ -10,7 +10,10 @@ attention at its published widths: flash over keys of 192 beside values of
 128 at the cell's buckets and its last continuation start, the absorbed
 kernel over 640-lane rows at 64 rows; and the streamed expert product at
 both routed cells' widths and largest decode buckets (128 experts of 2048 x
-768 at 64 rows, of 2048 x 1024 at 32); and Kimi-Linear's: flash over 16,384
+768 at 64 rows, of 2048 x 1024 at 32) and the tiled one at the three routed
+configurations' largest prefill programs (2048 rows over 128 of Kimi's 256
+experts of 2304 x 1024, 2048 over Kanana's 128, 1024 over Trinity's); and
+Kimi-Linear's: flash over 16,384
 keys, KDA's chunk and step kernels at 32 heads of 128. The case builders themselves
 are checked against their oracles in interpret mode by the smoke's dry run
 (``tests/test_chip_smoke.py``; the expert cases by
@@ -35,10 +38,15 @@ def _cases():
     for c in kernel_check.latent_cases(32, 192, 128, 640, 512,
                                        max_num_seqs=64):
         seen.setdefault(c.name, c)
-    for top_k, F, rows in ((6, 768, 64), (8, 1024, 32)):
+    for top_k, F, rows, prefill in ((6, 768, 64, 2048), (8, 1024, 32, 1024)):
         for c in kernel_check.expert_cases(128, top_k, 2048, F,
-                                           max_num_seqs=rows):
+                                           max_num_seqs=rows,
+                                           prefill_rows=prefill):
             seen.setdefault(c.name, c)
+    # Kimi-Linear's share of its experts: 128 held of the 256 routed over
+    seen.setdefault("experts-tiled-kimi", kernel_check.expert_cases(
+        256, 8, 2304, 1024, max_num_seqs=16, prefill_rows=2048,
+        held=128)[-1])
     # Kimi-Linear's flash call at its last continuation start (16k keys of
     # 192 beside values of 128: past Mosaic's default VMEM, which the call
     # asks to be raised), and KDA's two kernels at 32 heads of 128: the

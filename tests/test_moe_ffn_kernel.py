@@ -1,6 +1,6 @@
-"""The streamed expert product (``ops/pallas/moe_ffn.py``, interpreter mode)
-against the grouped form of ``ops/moe.py`` as the plain oracle, and the one
-function that chooses between them."""
+"""The streamed and the tiled expert product (``ops/pallas/moe_ffn.py``,
+interpreter mode) against the grouped form of ``ops/moe.py`` as the plain
+oracle, and the one function that chooses between the three."""
 
 import dataclasses
 
@@ -168,36 +168,192 @@ def test_tiles_of_the_inner_width_sum_to_the_whole(tile_f):
     assert np.abs(np.asarray(y) - np.asarray(want)).max() < BOUND
 
 
+def _sel(groups, k=K, n_experts=E):
+    """``[N, k]`` assignments in which expert ``e`` gets ``groups[e]`` rows
+    (``groups[n_experts]``: assignments to no expert), dealt round the
+    tokens so that a token's ``k`` choices differ where the counts allow."""
+    flat = np.concatenate([np.full(n, e) for e, n in enumerate(groups)])
+    assert flat.size % k == 0
+    return jnp.asarray(flat.reshape(k, -1).T, jnp.int32)
+
+
+#: tile 64 (``moe_ffn.row_tile`` of some 600 assignments over 8 or 4
+#: experts); case -> rows of each of the 8 experts
+#: (a ninth entry: assignments to no expert, as an inactive row's), and
+#: ``held`` where the leaves stack a slice
+TILED_CASES = {
+    "uneven-groups": dict(groups=[5, 130, 64, 1, 257, 40, 100, 43]),
+    "an-empty-expert": dict(groups=[90, 0, 120, 0, 200, 60, 0, 170]),
+    "the-first-and-last-empty": dict(groups=[0, 100, 100, 100, 100, 100,
+                                             140, 0]),
+    "one-expert-holds-most-rows": dict(groups=[2, 3, 600, 1, 4, 2, 3, 25]),
+    "a-group-of-exactly-one-tile": dict(groups=[128, 64, 64, 128, 64, 64,
+                                                64, 64]),
+    "a-group-of-one-row-over-a-tile": dict(groups=[129, 63, 64, 257, 63, 64,
+                                                   64, 64]),
+    "held-with-assignments-elsewhere": dict(
+        groups=[70, 90, 10, 150, 128, 33, 99, 60], held=(2, 4)),
+    "held-and-nobody-chose-them": dict(
+        groups=[200, 100, 0, 0, 0, 0, 140, 200], held=(2, 4)),
+    "inactive-rows": dict(groups=[50, 60, 70, 80, 20, 30, 40, 50, 240]),
+    "no-active-row": dict(groups=[0] * 8 + [400]),
+}
+
+
+@pytest.mark.parametrize("case", sorted(TILED_CASES))
+def test_the_tiled_form_is_the_grouped_form(case):
+    """``_tiled`` against ``_grouped`` (``ragged_dot``) on the same
+    assignments: bfloat16 values in float32, so the forms multiply the same
+    numbers and differ by the order of their sums."""
+    c = TILED_CASES[case]
+    sel = _sel(c["groups"])
+    N = sel.shape[0]
+    assert moe.expert_form(N, CFG) == "tiled"
+    ks = jax.random.split(jax.random.PRNGKey(5), 2)
+    x = _bf16_values(ks[0], (N, D), 1.0)
+    w = jax.random.uniform(ks[1], (N, K), minval=0.1)
+    first, count = c.get("held", (0, E))
+    ex = {n: leaf[first:first + count]
+          for n, leaf in _layer()["experts"].items()}
+    sizes = moe.expert_counts(sel, E)[first:first + count]
+    assert np.asarray(sizes).tolist() == c["groups"][first:first + count]
+    got = np.asarray(moe._tiled(ex, x, sel, w, sizes, first))
+    want = np.asarray(moe._grouped(ex, x, sel, w, sizes, first))
+    assert got.shape == (N, D) and np.isfinite(got).all()
+    assert np.abs(got - want).max() < BOUND
+    if int(np.asarray(sizes).sum()):
+        assert np.abs(want).max() > 1e-3     # the oracle says something
+    else:
+        assert not got.any()
+
+
+@pytest.mark.parametrize("groups", [
+    [129, 0, 64, 257, 1, 0, 128, 61],
+    [0, 0, 300, 0, 0, 1, 255, 256],
+], ids=["mixed", "empty-ends-and-a-full-tile"])
+def test_the_tiled_layout_starts_each_group_on_a_tile(groups):
+    """Each expert's rows lie together from a multiple of the tile on, in
+    token order (what a stable sort by expert would give, without one), the
+    rows that pad a group read token 0, the tiles behind the last real one
+    repeat its expert, and every held assignment is told where its row
+    is."""
+    sel = _sel(groups)
+    N = sel.shape[0]
+    tm = 128
+    tok, tile_expert, n_tiles, mine, pos = (
+        np.asarray(a) for a in moe.tiled_operands(
+            sel, jnp.asarray(groups, jnp.int32), 0, tm))
+    tiles = moe_ffn.tile_bound(N * K, E, tm)
+    assert tile_expert.shape == (tiles,) and tok.shape == (tiles * tm,)
+    want = [e for e, g in enumerate(groups) for _ in range(-(-g // tm))]
+    n = len(want)
+    assert int(n_tiles) == n and 8 <= n < tiles
+    assert tile_expert[:n].tolist() == want
+    assert (tile_expert[n:] == want[-1]).all()
+    assert mine.all()
+    flat_sel = np.asarray(sel).ravel()
+    assert len(set(pos.tolist())) == N * K                # one row each
+    for a in range(N * K):
+        assert tile_expert[pos[a] // tm] == flat_sel[a]
+        assert tok[pos[a]] == a // K
+    for e in range(E):                                    # token order
+        at = pos[flat_sel == e]
+        assert (np.diff(at) == 1).all()
+        assert at.size == 0 or at[0] % tm == 0
+    pad = np.ones(tiles * tm, bool)
+    pad[pos] = False
+    assert not tok[pad].any()
+
+
+def test_the_tiled_walk_skips_the_tiles_behind_the_last():
+    """Poisoned leaves of experts nobody chose never reach a row, and rows
+    of the layout behind the last real tile are never read back."""
+    groups = [0, 150, 0, 0, 130, 0, 120, 0]
+    sel = _sel(groups)
+    N = sel.shape[0]
+    x = _bf16_values(jax.random.PRNGKey(3), (N, D), 1.0)
+    w = jnp.full((N, K), 0.5)
+    ex = _layer()["experts"]
+    poisoned = {n: leaf.at[jnp.asarray([0, 2, 3, 5, 7])].set(jnp.nan)
+                for n, leaf in ex.items()}
+    sizes = jnp.asarray(groups, jnp.int32)
+    got = moe._tiled(poisoned, x, sel, w, sizes, 0)
+    want = moe._grouped(ex, x, sel, w, sizes, 0)
+    assert np.abs(np.asarray(got) - np.asarray(want)).max() < BOUND
+
+
+@pytest.mark.parametrize("tile_f", [128, 256])
+def test_tiles_of_the_inner_width_sum_to_the_whole_in_the_tiled_kernel(
+        tile_f):
+    """A step that is a whole expert answers in the rows' type; tiles of
+    the inner width add up in float32."""
+    ks = jax.random.split(jax.random.PRNGKey(1), 4)
+    tm, tiles = 16, 5
+    xs = _bf16_values(ks[0], (tiles * tm, 128), 1.0)
+    gate, up = (_bf16_values(k, (4, 128, 256), 0.1) for k in ks[1:3])
+    down = _bf16_values(ks[3], (4, 256, 128), 0.1)
+    tile_expert = jnp.asarray([0, 0, 1, 3, 3], jnp.int32)
+    y = moe_ffn.moe_tiled_ffn(xs, tile_expert, jnp.asarray(4), gate, up,
+                              down, tile_f=tile_f, interpret=True)
+    assert y.dtype == jnp.float32
+    e = jnp.repeat(tile_expert, tm)
+    h = jnp.einsum("nd,ndf->nf", xs, gate[e])
+    h = jax.nn.silu(h) * jnp.einsum("nd,ndf->nf", xs, up[e])
+    want = jnp.einsum("nf,nfd->nd", h, down[e])
+    real = 4 * tm
+    assert np.abs(np.asarray(y)[:real] - np.asarray(want)[:real]).max() < BOUND
+    whole = moe_ffn.moe_tiled_ffn(
+        xs.astype(jnp.bfloat16), tile_expert, jnp.asarray(4),
+        *(m.astype(jnp.bfloat16) for m in (gate, up, down)), interpret=True)
+    assert whole.dtype == jnp.bfloat16
+
+
 @pytest.mark.parametrize("preset,decode,prefill", [
     ("kanana2_stage", (1, 2, 4, 8, 16, 32, 64), (1024, 2048)),
     ("trinity_mini_stage", (1, 2, 4, 8, 16, 32), (512, 1024, 2048)),
+    ("kimi_linear_stage", (1, 2, 4, 8, 16), (1024, 2048)),
 ])
 def test_decode_buckets_stream_and_prefill_buckets_group(
         preset, decode, prefill):
-    """At both cells' published widths (2048 x 768, 2048 x 1024) every
-    decode bucket takes the streamed form and every prefill or
-    continuation bucket the grouped one; the CPU stand-ins, whose widths
-    the kernel cannot tile, take the grouped form at any row count."""
+    """The three forms by shape. At the cells' published widths (2048 x
+    768, 2048 x 1024, 2304 x 1024) every decode bucket takes the streamed
+    form and every prefill or continuation bucket the tiled one (sorted
+    into groups as the grouped form sorts them, through one kernel); the
+    CPU stand-ins, whose widths no kernel can tile, keep the plain grouped
+    form (``ragged_dot``) at any row count."""
     cfg = getattr(LlamaConfig, preset)()
-    assert (cfg.dim, cfg.moe_mlp_dim) in ((2048, 768), (2048, 1024))
+    assert (cfg.dim, cfg.moe_mlp_dim) in ((2048, 768), (2048, 1024),
+                                          (2304, 1024))
     assert {moe.expert_form(n, cfg) for n in decode} == {"streamed"}
-    assert {moe.expert_form(n, cfg) for n in prefill} == {"grouped"}
+    assert {moe.expert_form(n, cfg) for n in prefill} == {"tiled"}
     assert moe.expert_form(moe.STREAMED_MAX_ROWS, cfg) == "streamed"
-    assert moe.expert_form(moe.STREAMED_MAX_ROWS + 1, cfg) == "grouped"
-    for tiny in (LlamaConfig.tiny_afmoe(), LlamaConfig.tiny_mla()):
-        assert moe.expert_form(1, tiny) == "grouped"
-    # a step is a whole expert at both widths: its fixed part is paid once
+    assert moe.expert_form(moe.STREAMED_MAX_ROWS + 1, cfg) == "tiled"
+    for tiny in (LlamaConfig.tiny_afmoe(), LlamaConfig.tiny_mla(),
+                 LlamaConfig.tiny_kda()):
+        assert {moe.expert_form(n, tiny) for n in (1, 129, 2048)} == {
+            "grouped"}
+    # the row tile by the static shapes: one MXU tile where the assignments
+    # could give every held expert one (Kimi's 2,048 x 8 over 128 held),
+    # half of it below (Kanana's 2,048 x 6, Trinity's 1,024 x 8)
+    assert moe_ffn.row_tile(2048 * 8, 128) == 128
+    assert moe_ffn.row_tile(2048 * 6, 128) == 64
+    assert moe_ffn.row_tile(1024 * 8, 128) == 64
+    assert moe_ffn.tile_bound(2048 * 8, 128, 128) == 256
+    # a step is a whole expert at these widths: its fixed part is paid
+    # once, and in the tiled kernel an expert's matrices leave HBM once
     assert moe_ffn.inner_tile(cfg.dim, cfg.moe_mlp_dim, 2) == cfg.moe_mlp_dim
     assert moe_ffn.inner_tile(4096, 2048, 2) == 512
 
 
 @pytest.mark.parametrize("case", kernel_check.expert_cases(
-    8, 2, 256, 128, max_num_seqs=16), ids=lambda c: c.name)
+    8, 2, 256, 128, max_num_seqs=16, prefill_rows=300, held=5),
+    ids=lambda c: c.name)
 def test_the_chip_checks_case_builder_agrees_with_its_oracle(case):
     """``ops.kernel_check.expert_cases`` is what
     ``tests/test_kernel_lowering.py`` compiles for the v5e at the cells'
     widths: here the same builder, small, interpreted, against its oracle
-    (an inactive last row among the rows)."""
+    (an inactive last row among the rows; the tiled case holds five of the
+    eight experts)."""
     assert case.max_abs_err(interpret=True) <= case.tol
 
 
@@ -207,3 +363,14 @@ def test_the_trace_name_is_read_with_the_grouped_product():
     expert product."""
     assert moe_ffn.KERNEL_NAME.startswith(moe.GROUPED_NAME)
     assert moe_ffn.KERNEL_NAME != moe.GROUPED_NAME
+
+
+def test_the_tiled_trace_name_is_read_with_the_product_not_the_streamed():
+    """``moe_ffn_share.*`` reads the tiled kernel (its name begins with the
+    grouped product's); ``moe_streamed_hbm_roofline.moe``, a ``re.search``
+    of the streamed kernel's name, does not."""
+    import re
+
+    assert moe_ffn.TILED_NAME.startswith(moe.GROUPED_NAME)
+    assert not re.search(moe_ffn.KERNEL_NAME, moe_ffn.TILED_NAME)
+    assert not re.search(moe_ffn.TILED_NAME, moe_ffn.KERNEL_NAME)
