@@ -300,10 +300,11 @@ DEFAULT_CONTRACT = Contract(
         # would bury the structural reads in noise.
         "StepTelemetry": ClassPolicy(
             immutable_after_init=("ttft", "tpot", "queue_wait", "step_gap",
-                                  "_lock"),
+                                  "_lock", "_stream_lock"),
             lock_guarded={"_steps": "_lock", "_gauges": "_lock",
                           "_tenants": "_lock", "_tenant_ttft": "_lock",
-                          "_flush_reasons": "_lock"},
+                          "_flush_reasons": "_lock",
+                          "_stream": "_stream_lock"},
             owning_modules=("obs/steploop.py",),
         ),
         # The admission gate's shed counters take writes from every
@@ -522,6 +523,9 @@ DEFAULT_CONTRACT = Contract(
             "EngineLoop._futures_lock",
             "TenantLedger._lock",
             "StepTelemetry._lock",
+            # every stream thread and the server's event loop pass here
+            # once a written SSE event
+            "StepTelemetry._stream_lock",
             "FlightRecorder._lock",
             "HostKVTier._lock",
             "AdmissionGate._lock",

@@ -1329,7 +1329,8 @@ class LLMEngine:
             finished_ids=[f.req_id for f in self._done_this_step],
             tenants=tenants, input_uploads=self._step_uploads,
             state_slots=(self.cache.slots_live if self._state_layers
-                         else None))
+                         else None),
+            tokens=self._tokens_this_step)
         self._step_uploads = 0
         self._last_rollback_tokens = rb
         # first-use executable builds are warmup, not throughput: a step

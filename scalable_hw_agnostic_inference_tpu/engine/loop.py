@@ -126,12 +126,14 @@ class EngineLoop:
             # this guards direct submitters during the drain window
             raise RuntimeError("engine loop is draining")
         fut: Future = Future()
+        now = time.monotonic()
         self._submit_q.put(
             (list(prompt_ids), params or SamplingParams(),
              (prefix, cross_states, cross_len, on_token, deadline_at,
               priority, tenant, already_generated, already_lp,
-              orig_n_prompt, kv_holders, traceparent, idem_key,
-              time.monotonic()), fut))
+              orig_n_prompt, kv_holders, traceparent, idem_key, now), fut))
+        # the request's way in ends at this stamp (``ingress_seconds``)
+        self._tele.ingress_submitted(now)
         # close the put-after-drain window: if the loop died between our
         # _stop check and the put, nobody will ever drain this item
         if self._stop.is_set():
@@ -155,10 +157,12 @@ class EngineLoop:
         if self._draining.is_set():
             raise RuntimeError("engine loop is draining")
         futs: List[Future] = [Future() for _ in params_list]
+        now = time.monotonic()
         self._submit_q.put(
             (list(prompt_ids), list(params_list),
              (list(on_tokens) if on_tokens else [None] * len(futs),
-              deadline_at, priority, tenant, time.monotonic()), futs))
+              deadline_at, priority, tenant, now), futs))
+        self._tele.ingress_submitted(now)
         if self._stop.is_set():
             self._fail_all(RuntimeError("engine loop is stopped"))
         return futs

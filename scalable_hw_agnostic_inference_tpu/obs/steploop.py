@@ -18,12 +18,15 @@ formats.
 
 from __future__ import annotations
 
+import contextvars
+import queue
+from bisect import bisect_left
 import threading
 import time
 from collections import deque
 from typing import Any, Dict, List, Optional, Sequence, Tuple
 
-from .trace import annotate
+from .trace import Trace, annotate
 
 #: explicit histogram bounds (seconds). TTFT includes queue time, so its
 #: range reaches minutes; TPOT is per-token decode pace (milliseconds).
@@ -45,6 +48,16 @@ STEP_GAP_BUCKETS = (0.0001, 0.00025, 0.0005, 0.001, 0.0025, 0.005, 0.01,
 #: request that arrives during a prefill program waits out the program here
 INTAKE_WAIT_BUCKETS = (0.0005, 0.001, 0.0025, 0.005, 0.01, 0.025, 0.05, 0.1,
                        0.25, 0.5, 1.0, 5.0)
+#: a streamed token's hops out of the program (commit -> taken by the
+#: stream thread -> handed on encoded -> written to the socket), their sum,
+#: and a finished request's wait for its last byte. 50 us to 10 s: the mean
+#: is tens of microseconds a hop on a quiet host and the tail is the finding
+STREAM_BUCKETS = (0.00005, 0.0001, 0.00025, 0.0005, 0.001, 0.0025, 0.005,
+                  0.01, 0.025, 0.05, 0.1, 0.25, 0.5, 1.0, 2.5, 5.0, 10.0)
+#: a request's way in: the ASGI app's first stamp to ``EngineLoop.submit``
+#: (body read, JSON, the admission gate, the lane, tokenising the prompt)
+INGRESS_BUCKETS = (0.0005, 0.001, 0.0025, 0.005, 0.01, 0.025, 0.05, 0.1,
+                   0.25, 0.5, 1.0, 2.5, 5.0, 10.0)
 #: the phases of the ``engine-loop`` thread, in the order the event path
 #: walks them. Exactly one is open at any instant (``phase_enter`` closes
 #: the open one at the clock read that opens the next), so their seconds
@@ -55,6 +68,18 @@ PHASES = ("loop.idle", "loop.intake", "engine.fetch", "engine.apply",
           "engine.admit", "engine.prefill", "engine.chunk", "engine.verify",
           "engine.decode", "engine.marshal", "engine.commit",
           "engine.record", "loop.resolve")
+#: the phases that never wait for the device or for a queue: their wall
+#: time (``phase_cpu_wall_s``) less their CPU time (``phase_cpu_s``) is time
+#: the thread wanted to run and did not (the interpreter lock, the scheduler)
+NON_WAITING_PHASES = ("engine.admit", "engine.marshal", "engine.commit",
+                      "engine.apply", "engine.record", "loop.intake",
+                      "loop.resolve")
+#: the thread's CPU clock is read at the boundaries of one step in this
+#: many (and of the loop's phases behind it): ``time.thread_time()`` is a
+#: system call, 0.35 us on a workstation and 5.8 us on the chip's host,
+#: where eleven boundaries a step would cost the four-chip cell's 8 ms
+#: cycle 0.9% (PERF.md, PR 38)
+CPU_SAMPLE_EVERY = 8
 #: engine phase -> the field of the step's ring record its milliseconds
 #: add to (the four dispatch families share one)
 _STEP_FIELD = {"engine.fetch": "fetch_ms", "engine.apply": "apply_ms",
@@ -77,7 +102,10 @@ class BucketHistogram:
     """Thread-safe fixed-bucket histogram (Prometheus-shaped: cumulative
     bucket counts + sum + count), dependency-free so the engine can own it."""
 
-    def __init__(self, bounds: Sequence[float]):
+    def __init__(self, bounds: Sequence[float],
+                 lock: Optional[threading.Lock] = None):
+        """``lock``: one shared by several histograms whose owner observes
+        them together (``observe_locked`` under one acquisition)."""
         self.bounds: Tuple[float, ...] = tuple(sorted(float(b)
                                                       for b in bounds))
         if not self.bounds:
@@ -85,18 +113,20 @@ class BucketHistogram:
         self._counts = [0] * (len(self.bounds) + 1)  # last = +Inf
         self._sum = 0.0
         self._n = 0
-        self._lock = threading.Lock()
+        self._lock = lock if lock is not None else threading.Lock()
 
     def observe(self, v: float) -> None:
-        i = 0
-        for b in self.bounds:
-            if v <= b:
-                break
-            i += 1
+        i = bisect_left(self.bounds, v)   # the first bound v is not over
         with self._lock:
             self._counts[i] += 1
             self._sum += v
             self._n += 1
+
+    def observe_locked(self, v: float) -> None:
+        """``observe`` for a caller that holds this histogram's lock."""
+        self._counts[bisect_left(self.bounds, v)] += 1
+        self._sum += v
+        self._n += 1
 
     @property
     def count(self) -> int:
@@ -133,6 +163,201 @@ class _PhaseScope:
     def __exit__(self, *exc) -> bool:
         self.tele.phase_enter(self.prev)
         return False
+
+
+class _Ingress:
+    """One request between the ASGI app's first stamp and its
+    ``EngineLoop.submit``: what ``ingress_inflight`` counts while ``open``."""
+
+    __slots__ = ("t_begin", "open", "token")
+
+    def __init__(self, t_begin: float):
+        self.t_begin, self.open, self.token = t_begin, True, None
+
+
+#: the request's ``_Ingress``, set by the serving layer's ``_InferScope`` and
+#: carried onto the lane thread by its contextvars copy (as the deadline and
+#: the QoS tag are), where ``EngineLoop.submit`` closes it
+_ingress: "contextvars.ContextVar[Optional[_Ingress]]" = (
+    contextvars.ContextVar("request_ingress", default=None))
+
+
+class StreamTrack:
+    """One streamed response's way out, from the engine's ``on_token`` to
+    the socket: the token queue, the stamps of the event in flight and the
+    stream's own tallies. Made by :meth:`StepTelemetry.stream_open`.
+
+    Three threads touch it, no two of them one field at a time: the
+    engine-loop thread calls :meth:`put` for each token (and
+    :meth:`resolved` as it resolves the request's future), the stream
+    thread takes tokens from ``q`` and calls :meth:`took`, :meth:`hand_on`,
+    :meth:`wrote`, :meth:`last` and (as the generator closes)
+    :meth:`close`, the server's event loop calls :meth:`sent` after each
+    chunk it wrote. A chunk is pulled only after the one before it was
+    written, so at most one event is in flight and the stream thread runs
+    only between two ``sent``. The event loop, which every stream of the
+    pod shares, only stamps; the shared counters and histograms move on
+    the stream's own thread (``wrote``), under the telemetry's stream
+    lock, once a written event."""
+
+    def __init__(self, tele: "StepTelemetry",
+                 trace: Optional[Trace] = None):
+        self.tele = tele
+        self.trace = trace
+        self.q: "queue.Queue[Tuple[int, float]]" = queue.Queue()
+        self.n_put = 0       # loop thread
+        self.held = 0        # stream thread: taken, in no event yet
+        self._t_commit = self._t_taken = 0.0
+        self._pending: Optional[Tuple[int, float, float, float]] = None
+        self._final = False
+        # event loop: chunks written since ``wrote`` last looked
+        self._t_written = 0.0
+        self._w_events = self._w_bytes = 0
+        self.n_sent = 0      # from here on: under the stream lock
+        self.n_events = 0
+        self._deliver_sum = self._deliver_max = 0.0
+        self._t_first = 0.0
+        self._t_resolved: Optional[float] = None
+        self._ended = self._settled = False
+
+    # -- engine-loop thread -------------------------------------------------
+
+    def put(self, tok: int) -> None:
+        """The engine's ``on_token``: the token with the stamp of the phase
+        boundary that committed it (``engine.commit``'s, which
+        ``phase_enter`` read: no clock is read here)."""
+        tele = self.tele
+        self.q.put((tok, tele.phase_t0))
+        self.n_put += 1
+        tele.stream_tokens_put += 1
+
+    def resolved(self, fut=None) -> None:
+        """The request's future resolved (its done-callback: the loop thread,
+        inside ``loop.resolve`` or a cancel): its row is free and no token
+        follows. From here to the last byte the caller is draining."""
+        tele = self.tele
+        with tele._stream_lock:
+            if self._t_resolved is not None:
+                return
+            self._t_resolved = tele.phase_t0
+            if not self._ended:
+                tele._stream["draining"] += 1
+            self._settle_locked()
+
+    # -- the server's event loop --------------------------------------------
+
+    def sent(self, n_bytes: int) -> None:
+        """``StreamingResponse.on_sent``: the chunk handed on last is on
+        the socket. A stamp and two adds: the rest is ``wrote``'s."""
+        self._t_written = time.monotonic()
+        self._w_events += 1
+        self._w_bytes += n_bytes
+
+    # -- stream thread ------------------------------------------------------
+
+    def took(self, t_commit: float) -> None:
+        """A token left ``q`` (call right behind ``q.get``)."""
+        self._t_commit, self._t_taken = t_commit, time.monotonic()
+        self.held += 1
+
+    def hand_on(self, timed: bool = True) -> None:
+        """The event about to be yielded carries every token taken since
+        the last one; ``timed``: with the last of them's stamps (not for
+        the tail a finished stream flushes: it waited for the future)."""
+        self._pending = (self.held, self._t_commit if timed else 0.0,
+                         self._t_taken, time.monotonic())
+        self.held = 0
+
+    def wrote(self) -> None:
+        """Behind the ``yield`` of an event that carried tokens (the drain
+        has written it and asked for the next chunk): the ONE locked call
+        a written event. Chunks that carry none (the preamble, the finish
+        event) are counted with the next call."""
+        n_events = self._w_events
+        if not n_events:
+            return      # resumed to be closed: nothing was written
+        now, n_bytes = self._t_written, self._w_bytes
+        self._w_events = self._w_bytes = 0
+        pending, self._pending = self._pending, None
+        tele = self.tele
+        with tele._stream_lock:
+            c = tele._stream
+            c["events_sent"] += n_events
+            c["bytes_sent"] += n_bytes
+            if pending is not None:
+                n, t_commit, t_taken, t_handed = pending
+                c["tokens_sent"] += n
+                self.n_sent += n
+                if t_commit:
+                    deliver = now - t_commit
+                    tele.stream_wake.observe_locked(t_taken - t_commit)
+                    tele.stream_encode.observe_locked(t_handed - t_taken)
+                    tele.stream_write.observe_locked(now - t_handed)
+                    tele.stream_deliver.observe_locked(deliver)
+                    self.n_events += 1
+                    self._deliver_sum += deliver
+                    self._deliver_max = max(self._deliver_max, deliver)
+                    self._t_first = self._t_first or t_commit
+
+    def last(self) -> None:
+        """The chunk about to be yielded is the stream's last."""
+        self.wrote()
+        self._final = True
+
+    def close(self) -> None:
+        """The generator is done (its ``finally``): whole if its last chunk
+        was written, aborted otherwise (client gone, write failed)."""
+        whole = self._final and self._w_events > 0
+        now = self._t_written if whole else time.monotonic()
+        self.wrote()
+        self._end(whole, now)
+
+    def _end(self, whole: bool, now: float) -> None:
+        """Once a stream. Whole: what was taken and is in no event (a
+        partial character a stop left behind) went with the stream's end;
+        aborted: what was put and not sent is dropped once the future has
+        resolved too (the engine puts until the cancel lands)."""
+        tele = self.tele
+        with tele._stream_lock:
+            if self._ended:
+                return
+            self._ended = True
+            c = tele._stream
+            lag = None
+            if whole:
+                c["streams_ended"] += 1
+                c["tokens_sent"] += self.held
+                self.n_sent += self.held
+                self.held = 0
+                if self._t_resolved is not None:
+                    lag = max(0.0, now - self._t_resolved)
+                    tele.stream_finish_lag.observe_locked(lag)
+            else:
+                c["streams_aborted"] += 1
+            if self._t_resolved is not None:
+                c["draining"] -= 1
+            self._settle_locked()
+            n_sent, n_events = self.n_sent, self.n_events
+        if self.trace is not None:
+            attrs: Dict[str, Any] = {"tokens": n_sent, "events": n_events}
+            if n_events:
+                attrs["deliver_mean_ms"] = round(
+                    self._deliver_sum / n_events * 1e3, 3)
+                attrs["deliver_max_ms"] = round(self._deliver_max * 1e3, 3)
+            if lag is not None:
+                attrs["finish_lag_ms"] = round(lag * 1e3, 3)
+            if not whole:
+                attrs["aborted"] = True
+            self.trace.add_span("stream.deliver", self._t_first or now, now,
+                                **attrs)
+
+    def _settle_locked(self) -> None:
+        """With the stream ended AND the future resolved ``n_put`` is
+        final: what was never sent is dropped, and put = sent + dropped."""
+        if (self._ended and self._t_resolved is not None
+                and not self._settled):
+            self._settled = True
+            self.tele._stream["tokens_dropped"] += self.n_put - self.n_sent
 
 
 class StepTelemetry:
@@ -188,12 +413,46 @@ class StepTelemetry:
         self.queue_wait = BucketHistogram(QUEUE_WAIT_BUCKETS)
         self.step_gap = BucketHistogram(STEP_GAP_BUCKETS)
         self.intake_wait = BucketHistogram(INTAKE_WAIT_BUCKETS)
+        # a token's way out and a request's way in, counted where they
+        # happen, off the loop thread (StreamTrack, ingress_*): one lock of
+        # their own, so a stream thread never holds the lock the loop
+        # thread takes at every phase boundary. ``draining`` (future
+        # resolved, last byte not written) and ``ingress_inflight`` (begun,
+        # not yet submitted) are gauges, a locked step a REQUEST.
+        self._stream_lock = threading.Lock()
+        self.stream_wake = BucketHistogram(STREAM_BUCKETS, self._stream_lock)
+        self.stream_encode = BucketHistogram(STREAM_BUCKETS,
+                                             self._stream_lock)
+        self.stream_write = BucketHistogram(STREAM_BUCKETS, self._stream_lock)
+        self.stream_deliver = BucketHistogram(STREAM_BUCKETS,
+                                              self._stream_lock)
+        self.stream_finish_lag = BucketHistogram(STREAM_BUCKETS,
+                                                 self._stream_lock)
+        self.ingress = BucketHistogram(INGRESS_BUCKETS, self._stream_lock)
+        self._stream: Dict[str, int] = dict.fromkeys(
+            ("tokens_sent", "tokens_dropped", "events_sent", "bytes_sent",
+             "streams_started", "streams_ended", "streams_aborted",
+             "draining", "ingress_inflight"), 0)
+        # tokens handed to streams: written by the ONE thread that steps
+        # the engine (``on_token`` runs there), a plain add with no lock
+        self.stream_tokens_put = 0
+        # the engine's own output, whatever path delivers it
+        self.tokens_committed = 0
         # where the engine-loop thread's time goes (PHASES): cumulative
         # seconds by phase, the open phase with its start and annotation,
         # and the running step's share by ring-record field. One thread,
         # the one that steps the engine, enters phases; any thread reads.
+        # ``phase_cpu_s``: the same thread's CPU seconds by phase, over
+        # the phases of one step in ``CPU_SAMPLE_EVERY``
+        # (``time.thread_time()`` at their boundaries; closed phases only:
+        # another thread cannot read this one's CPU clock), and
+        # ``phase_cpu_wall_s``: the wall seconds of those same phases
         self.phase_s: Dict[str, float] = dict.fromkeys(PHASES, 0.0)
+        self.phase_cpu_s: Dict[str, float] = dict.fromkeys(PHASES, 0.0)
+        self.phase_cpu_wall_s: Dict[str, float] = dict.fromkeys(PHASES, 0.0)
         self.phase_t0 = 0.0          # monotonic start of the open phase
+        self._cpu_sampled = True     # until the first step says otherwise
+        self._phase_cpu0: Optional[float] = None   # the CPU clock then
         self._phase: Optional[str] = None
         self._phase_ann = None
         self._step_ms: Dict[str, float] = {}
@@ -287,12 +546,17 @@ class StepTelemetry:
 
     def phase_enter(self, name: Optional[str]) -> Optional[str]:
         """Close the open phase and open ``name`` (``None``: open nothing)
-        at one read of the monotonic clock. The closed phase's seconds add
-        to ``phase_s`` and to the running step's ring record; the opened
-        one is a ``TraceAnnotation`` through ``obs.trace.annotate`` (tracing
-        switched off: seconds only), an engine phase's with its step's
-        number: the join with the ring. Returns the phase it closed."""
+        at one read of the monotonic clock (in a sampled step, one of the
+        thread's CPU clock too). The closed phase's seconds add to
+        ``phase_s`` (where both its ends read the CPU clock, its CPU
+        seconds to ``phase_cpu_s`` and its seconds to ``phase_cpu_wall_s``)
+        and to the running step's ring record;
+        the opened one is a ``TraceAnnotation`` through ``obs.trace.annotate``
+        (tracing switched off: seconds only), an engine phase's with its
+        step's number: the join with the ring. Returns the phase it
+        closed."""
         now = time.monotonic()
+        cpu = time.thread_time() if self._cpu_sampled else None
         if self._phase_ann is not None:
             self._phase_ann.__exit__(None, None, None)
         with self._lock:
@@ -300,6 +564,12 @@ class StepTelemetry:
             if prev is not None:
                 dt = now - self.phase_t0
                 self.phase_s[prev] = self.phase_s.get(prev, 0.0) + dt
+                if cpu is not None and self._phase_cpu0 is not None:
+                    self.phase_cpu_s[prev] = (
+                        self.phase_cpu_s.get(prev, 0.0)
+                        + cpu - self._phase_cpu0)
+                    self.phase_cpu_wall_s[prev] = (
+                        self.phase_cpu_wall_s.get(prev, 0.0) + dt)
                 field = _STEP_FIELD.get(prev)
                 if field is not None:
                     self._step_ms[field] = (self._step_ms.get(field, 0.0)
@@ -308,6 +578,7 @@ class StepTelemetry:
                     # the record was written as this phase opened
                     self._steps[-1]["record_ms"] = round(dt * 1e3, 4)
             self._phase, self.phase_t0 = name, now
+            self._phase_cpu0 = cpu
             step = self._step_no
         if name is None:
             self._phase_ann = None
@@ -333,7 +604,62 @@ class StepTelemetry:
             self._step_ms = {}
             self._step_no = self.steps + 1
             self._waiting_peak = n_waiting
+            self._cpu_sampled = self._step_no % CPU_SAMPLE_EVERY == 0
         return self.phase_enter("engine.admit")
+
+    # -- a token's way out, a request's way in -----------------------------
+
+    def stream_open(self, trace: Optional[Trace] = None) -> StreamTrack:
+        """A streamed response starts: its :class:`StreamTrack`, whose
+        ``put`` is the request's ``on_token``; ``trace``: the request's,
+        which gets the stream's one summary span."""
+        with self._stream_lock:
+            self._stream["streams_started"] += 1
+        return StreamTrack(self, trace)
+
+    def ingress_begin(self, t_begin: float) -> _Ingress:
+        """A request that will reach the engine was begun at ``t_begin``
+        (the ASGI app's first stamp) and is on its way in; close it with
+        :meth:`ingress_end` in the context that called this."""
+        ing = _Ingress(t_begin)
+        with self._stream_lock:
+            self._stream["ingress_inflight"] += 1
+        ing.token = _ingress.set(ing)
+        return ing
+
+    def ingress_submitted(self, now: float) -> None:
+        """``EngineLoop.submit``, on the caller's thread, at its own stamp:
+        the context's request, if it is still on its way in, has arrived."""
+        ing = _ingress.get()
+        if ing is None:
+            return
+        with self._stream_lock:
+            if not ing.open:
+                return
+            ing.open = False
+            self._stream["ingress_inflight"] -= 1
+            self.ingress.observe_locked(max(0.0, now - ing.t_begin))
+
+    def ingress_end(self, ing: _Ingress) -> None:
+        """The request's scope ends; one that never submitted (refused on
+        the way, or served without the engine) leaves the gauge here."""
+        with self._stream_lock:
+            if ing.open:
+                ing.open = False
+                self._stream["ingress_inflight"] -= 1
+        _ingress.reset(ing.token)
+
+    def stream_snapshot(self) -> Dict[str, int]:
+        """The ``stream`` entry of :meth:`snapshot`: counters, the two
+        gauges, and ``backlog`` (put, neither sent nor dropped yet)."""
+        with self._stream_lock:
+            out = dict(self._stream)
+        # read BEHIND the others: a token is put before it is sent or
+        # dropped, so the backlog never reads below 0
+        out["tokens_put"] = self.stream_tokens_put
+        out["backlog"] = (out["tokens_put"] - out["tokens_sent"]
+                          - out["tokens_dropped"])
+        return out
 
     # -- per-tenant attribution (multi-tenant QoS) -------------------------
 
@@ -489,14 +815,23 @@ class StepTelemetry:
                     finished_ids: Sequence[int] = (),
                     tenants: Optional[Dict[str, Sequence[int]]] = None,
                     input_uploads: int = 0,
-                    state_slots: Optional[int] = None) -> None:
+                    state_slots: Optional[int] = None,
+                    tokens: int = 0) -> None:
         """One engine ``step()`` completed; ``kind`` names the decode path
         taken (``"decode"``, ``"spec"``, ``"idle"``). ``finished_ids`` are
         the engine request ids that reached a terminal state this step —
         the join key between ``/debug/flight`` step records and request
         traces (whose root carries ``engine_req_id``). ``state_slots``:
         arena slots held at the step's end, of a model with recurrent
-        layers (the record's ``state_slots_live``, ``kda.slots_live``)."""
+        layers (the record's ``state_slots_live``, ``kda.slots_live``).
+        ``tokens``: what the step committed (``tokens_committed``). Every
+        record also says where the callers outside the engine stand:
+        ``ingress_inflight``, ``streams_draining``, ``stream_backlog``."""
+        # single int reads, no lock on the loop thread for them; sent and
+        # dropped BEFORE put, so the backlog never reads below 0
+        # shai-lint: allow(guarded-read) one-int gauges of a step record
+        c = self._stream
+        sent_or_dropped = c["tokens_sent"] + c["tokens_dropped"]
         total = self.total_blocks or 1
         used = max(0, total - blocks_free)
         # pressure vs occupancy: evictable prefix-cache blocks are
@@ -521,6 +856,9 @@ class StepTelemetry:
             "kv_occupancy": round(used / total, 4),
             "rollback_tokens": rollback_tokens,
             "finished_ids": list(finished_ids),
+            "ingress_inflight": c["ingress_inflight"],
+            "streams_draining": c["draining"],
+            "stream_backlog": self.stream_tokens_put - sent_or_dropped,
         }
         if spec:
             rec["spec"] = dict(spec)
@@ -532,6 +870,7 @@ class StepTelemetry:
             self.steps += 1
             self.requests_finished += finished
             self.decode_input_uploads += input_uploads
+            self.tokens_committed += tokens
             rec["step"] = self.steps
             for field in _STEP_FIELDS:
                 rec[field] = round(self._step_ms.get(field, 0.0), 4)
@@ -601,6 +940,7 @@ class StepTelemetry:
                 "kv_blocks_total": self.total_blocks,
                 "pipeline_flushes": self.pipeline_flushes,
                 "decode_input_uploads": self.decode_input_uploads,
+                "tokens_committed": self.tokens_committed,
                 "pad_tokens": self.pad_tokens,
                 "real_tokens": self.real_tokens,
             }
@@ -630,7 +970,10 @@ class StepTelemetry:
                 out["phase_s"][self._phase] = (
                     out["phase_s"].get(self._phase, 0.0)
                     + max(0.0, time.monotonic() - self.phase_t0))
+            out["phase_cpu_s"] = dict(self.phase_cpu_s)
+            out["phase_cpu_wall_s"] = dict(self.phase_cpu_wall_s)
             out.update(self._gauges)
+        out["stream"] = self.stream_snapshot()
         kvt = self.kvtier
         if kvt is not None:
             # host-tier saturation + hit rate travel with the engine
@@ -656,4 +999,11 @@ class StepTelemetry:
                 "tpot_seconds": self.tpot.snapshot(),
                 "queue_wait_seconds": self.queue_wait.snapshot(),
                 "step_gap_seconds": self.step_gap.snapshot(),
-                "intake_wait_seconds": self.intake_wait.snapshot()}
+                "intake_wait_seconds": self.intake_wait.snapshot(),
+                "stream_wake_seconds": self.stream_wake.snapshot(),
+                "stream_encode_seconds": self.stream_encode.snapshot(),
+                "stream_write_seconds": self.stream_write.snapshot(),
+                "stream_deliver_seconds": self.stream_deliver.snapshot(),
+                "stream_finish_lag_seconds":
+                    self.stream_finish_lag.snapshot(),
+                "ingress_seconds": self.ingress.snapshot()}

@@ -217,13 +217,19 @@ class Trace:
     :meth:`add_span` with explicit timestamps)."""
 
     def __init__(self, name: str, trace_id: Optional[str] = None,
-                 remote_parent: Optional[str] = None, **attrs):
+                 remote_parent: Optional[str] = None,
+                 t_begin: float = 0.0, **attrs):
+        """``t_begin``: a monotonic stamp the caller already took as the
+        request began; the root span starts there (0: now)."""
         self.trace_id = trace_id or new_trace_id()
         self.remote_parent = remote_parent
         self._lock = threading.Lock()
         self.spans: List[Span] = []
-        self.root = Span(name, new_span_id(), None, time.time(),
-                         time.monotonic(), attrs=dict(attrs))
+        now = time.monotonic()
+        t_begin = min(t_begin, now) if t_begin else now
+        self.root = Span(name, new_span_id(), None,
+                         time.time() - (now - t_begin), t_begin,
+                         attrs=dict(attrs))
         self.spans.append(self.root)
 
     # -- span lifecycle ----------------------------------------------------
@@ -408,16 +414,17 @@ def span(name: str, annotation: bool = True, **attrs):
 
 def begin_request_trace(name: str,
                         traceparent_header: Optional[str] = None,
-                        **attrs) -> Optional[Trace]:
+                        t_begin: float = 0.0, **attrs) -> Optional[Trace]:
     """Trace for one inbound request, continuing the caller's W3C context
-    when a valid ``traceparent`` header arrived. None when tracing is off."""
+    when a valid ``traceparent`` header arrived. None when tracing is off.
+    ``t_begin``: the stamp the request began at (:class:`Trace`)."""
     if not _enabled:
         return None
     parsed = parse_traceparent(traceparent_header)
     if parsed:
         return Trace(name, trace_id=parsed[0], remote_parent=parsed[1],
-                     **attrs)
-    return Trace(name, **attrs)
+                     t_begin=t_begin, **attrs)
+    return Trace(name, t_begin=t_begin, **attrs)
 
 
 # -- validation (used by tests and the flight recorder's self-checks) --------

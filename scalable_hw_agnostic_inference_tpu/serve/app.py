@@ -440,6 +440,10 @@ def create_app(
             self._token = None
             self._qos_token = None
             self._handed_off = False
+            # the request's way in (``ingress_inflight``, from the ASGI
+            # app's first stamp to ``EngineLoop.submit``), on the engine's
+            # telemetry where the service has one
+            self._tele = self._ingress = None
             # resolved at __enter__: the ledger-bounded tenant label every
             # shed/charge/inflight count for this request attributes to
             self.tenant = ""
@@ -461,6 +465,10 @@ def create_app(
             with inflight_lock:
                 state["inflight"] += 1
                 state["lane_pending"] += 1
+            self._tele = service.engine_telemetry()
+            if self._tele is not None:
+                self._ingress = self._tele.ingress_begin(
+                    self.request.t_begin)
             return dl
 
         def charge(self, out) -> None:
@@ -521,6 +529,8 @@ def create_app(
                     state["inflight"] -= 1
                     state["lane_pending"] -= 1
                 ledger.note_done(self.tenant)
+            if self._ingress is not None:
+                self._tele.ingress_end(self._ingress)
             rz_deadline.reset_current_deadline(self._token)
             rz_qos.reset_current_qos(self._qos_token)
             return False

@@ -18,6 +18,7 @@ import inspect
 import json
 import logging
 import re
+import time
 import traceback
 from typing import Any, Awaitable, Callable, Dict, List, Optional, Tuple
 from urllib.parse import parse_qsl
@@ -45,7 +46,11 @@ class HTTPError(Exception):
 class Request:
     """One HTTP request as seen by a handler."""
 
-    def __init__(self, scope: Dict, body: bytes):
+    def __init__(self, scope: Dict, body: bytes, t_begin: float = 0.0):
+        # monotonic stamp the app took as it began the request, before the
+        # body was read: the root span starts here, and so does the
+        # request's way in (``ingress_seconds``)
+        self.t_begin = t_begin or time.monotonic()
         self.method: str = scope["method"].upper()
         self.path: str = scope["path"]
         self.headers: Dict[str, str] = {
@@ -100,11 +105,21 @@ class StreamingResponse(Response):
     """Incrementally-produced body (SSE token streams). ``iterator`` yields
     ``str``/``bytes`` chunks — a SYNC generator; the app drives it on an
     executor thread so a blocking token queue doesn't stall the event loop.
-    No content-length: the server sends it chunked-encoded."""
+    No content-length: the server sends it chunked-encoded.
+
+    ``on_sent``: called by the drain, on the event loop, with the byte
+    count of each chunk it wrote (the stream's own accounting: a token's
+    way out ends there). ``annotate_write``: the drain writes a
+    ``serve.stream.write`` profiler annotation around each send (the
+    owner samples: one stream in sixteen)."""
 
     def __init__(self, iterator, status: int = 200,
                  media_type: str = "text/event-stream",
-                 headers: Optional[Dict[str, str]] = None):
+                 headers: Optional[Dict[str, str]] = None,
+                 on_sent: Optional[Callable[[int], None]] = None,
+                 annotate_write: bool = False):
+        self.on_sent = on_sent
+        self.annotate_write = annotate_write
         self.status = status
         self.headers = dict(headers or {})
         self.headers.setdefault("content-type", media_type)
@@ -289,6 +304,7 @@ class App:
         # run startup lazily so in-process apps behave like served ones.
         await self._run_startup()
 
+        t_begin = time.monotonic()
         body = b""
         while True:
             message = await receive()
@@ -299,7 +315,7 @@ class App:
             elif message["type"] == "http.disconnect":  # pragma: no cover
                 return
 
-        request = Request(scope, body)
+        request = Request(scope, body, t_begin)
         # W3C trace-context ingest: a valid upstream traceparent continues
         # the caller's trace id; otherwise (or with tracing off → None) a
         # fresh trace roots here. The whole request — dispatch, model call,
@@ -308,8 +324,8 @@ class App:
         tp_header = request.headers.get("traceparent")
         if not self._trace_excluded(request.path):
             tr = obs_trace.begin_request_trace(
-                f"{request.method} {request.path}",
-                tp_header, method=request.method, path=request.path)
+                f"{request.method} {request.path}", tp_header,
+                t_begin=t_begin, method=request.method, path=request.path)
         elif obs_trace.parse_traceparent(tp_header) is not None:
             # excluded surfaces begin a trace ONLY when the caller sent a
             # valid traceparent: bare poll traffic (kubelet, /stats scrape)
@@ -317,8 +333,8 @@ class App:
             # (/kv/blocks, /kv/pull, /kv/migrate from a traced request)
             # join the caller's trace as server-side child spans
             tr = obs_trace.begin_request_trace(
-                f"{request.method} {request.path}",
-                tp_header, method=request.method, path=request.path)
+                f"{request.method} {request.path}", tp_header,
+                t_begin=t_begin, method=request.method, path=request.path)
         request.trace = tr
 
         def _finish_trace(status: int) -> None:
@@ -401,6 +417,7 @@ class App:
         loop = asyncio.get_event_loop()
         it = iter(response.iterator)
         _END = object()
+        on_sent, annotated = response.on_sent, response.annotate_write
 
         def _next():
             try:
@@ -453,12 +470,21 @@ class App:
                     chunk = chunk.encode()
                 if not chunk:
                     continue
+                message = {"type": "http.response.body", "body": chunk,
+                           "more_body": True}
                 try:
-                    await send({"type": "http.response.body",
-                                "body": chunk, "more_body": True})
+                    if annotated:
+                        # the write itself, on the profiler's clock; the
+                        # send suspends only behind a full socket buffer
+                        with obs_trace.annotate("serve.stream.write"):
+                            await send(message)
+                    else:
+                        await send(message)
                 except Exception:
                     aborted = True  # socket died mid-write
                     break
+                if on_sent is not None:
+                    on_sent(len(chunk))
             if not aborted:
                 await send({"type": "http.response.body", "body": b""})
         finally:

@@ -32,7 +32,6 @@ try:  # gated: available in the serving image; optional in minimal envs
         CollectorRegistry,
         Counter,
         Histogram,
-        start_http_server,
     )
 
     _HAVE_PROM = True
@@ -59,6 +58,28 @@ ENGINE_HISTOGRAMS = {
     "intake_wait_seconds": ("shai_intake_wait_seconds",
                             "Submit on the caller's thread to intake by "
                             "the engine loop, which runs between steps"),
+    "stream_wake_seconds": ("shai_engine_stream_wake_seconds",
+                            "A streamed token's commit on the engine loop "
+                            "to its stream thread taking it from the queue"),
+    "stream_encode_seconds": ("shai_engine_stream_encode_seconds",
+                              "Taken to handed on as an encoded SSE event "
+                              "(text assembly, JSON)"),
+    "stream_write_seconds": ("shai_engine_stream_write_seconds",
+                             "Handed on to written to the socket (the "
+                             "executor future, the event loop's wake-up, "
+                             "the chunked write)"),
+    "stream_deliver_seconds": ("shai_engine_stream_deliver_seconds",
+                               "Commit to written: a token's whole way out "
+                               "of the program"),
+    "stream_finish_lag_seconds": ("shai_engine_stream_finish_lag_seconds",
+                                  "A request's future resolved to its "
+                                  "stream's last chunk written: how long a "
+                                  "caller is still fed after its row is "
+                                  "free"),
+    "ingress_seconds": ("shai_engine_ingress_seconds",
+                        "The ASGI app's first stamp of a request to its "
+                        "submit to the engine loop (body, JSON, admission "
+                        "gate, lane, tokenising)"),
 }
 _ENGINE_GAUGES = {
     "running": ("shai_engine_running", "Sequences decoding right now"),
@@ -97,6 +118,9 @@ _ENGINE_COUNTERS = {
                              "Host-to-device arrays put for decode, verify "
                              "and fused dispatches (a block-table refresh "
                              "counts one)"),
+    "tokens_committed": ("shai_engine_tokens_committed",
+                         "Output tokens the engine's steps committed, "
+                         "whatever path delivers them"),
 }
 #: pad/real token counters export with a ``phase`` label (prefill /
 #: chunk / decode / verify — where in a request's life the pad burned).
@@ -116,6 +140,25 @@ _PAD_PHASE_COUNTERS = {
 #: phases tile the thread, so the rates over a window sum to one.
 _PHASE_SECONDS = ("shai_engine_phase_seconds_total",
                   "Seconds the engine-loop thread spent in each phase")
+#: the same thread's CPU seconds over the phases of one step in
+#: obs.steploop.CPU_SAMPLE_EVERY, and those phases' wall seconds: for the
+#: phases that wait for nothing (obs.steploop.NON_WAITING_PHASES), wall
+#: less CPU is time the thread wanted to run and did not
+_PHASE_CPU_SECONDS = ("shai_engine_phase_cpu_seconds_total",
+                      "CPU seconds of the engine-loop thread in each phase, "
+                      "over the sampled steps")
+_PHASE_CPU_WALL_SECONDS = ("shai_engine_phase_cpu_wall_seconds_total",
+                           "Wall seconds of the phases whose CPU seconds "
+                           "were taken")
+#: a token's way out and a request's way in (obs.steploop ``stream``)
+_STREAM_COUNTERS = ("shai_engine_stream_total",
+                    "Streamed responses, by counter: tokens_put, "
+                    "tokens_sent, tokens_dropped (an aborted or stopped "
+                    "stream's remainder), events_sent, bytes_sent, "
+                    "streams_started, streams_ended, streams_aborted; "
+                    "gauges: backlog (put, neither sent nor dropped), "
+                    "draining (future resolved, last byte not written), "
+                    "ingress_inflight (requests begun, not yet submitted)")
 #: what routing, the attention window and the latent kernel did
 #: (obs.steploop ``moe`` / ``window`` / ``mla``): one family each, the
 #: snapshot's keys under ``counter``
@@ -318,7 +361,14 @@ class EngineTelemetryCollector:
         for phase, secs in sorted((snap.get("phase_s") or {}).items()):
             c.add_metric([self.app, phase], float(secs))
         yield c
-        for key, family in (("moe", _MOE_COUNTERS),
+        for key, family in (("phase_cpu_s", _PHASE_CPU_SECONDS),
+                            ("phase_cpu_wall_s", _PHASE_CPU_WALL_SECONDS)):
+            c = CounterMetricFamily(*family, labels=["app", "phase"])
+            for phase, secs in sorted((snap.get(key) or {}).items()):
+                c.add_metric([self.app, phase], float(secs))
+            yield c
+        for key, family in (("stream", _STREAM_COUNTERS),
+                            ("moe", _MOE_COUNTERS),
                             ("window", _WINDOW_COUNTERS),
                             ("mla", _MLA_COUNTERS),
                             ("kda", _KDA_COUNTERS)):
@@ -694,10 +744,3 @@ class MetricsPublisher:
                 "pod": self.pod_name,
                 "data": data,
             }), file=self._stream, flush=True)
-
-    def start_exporter(self, port: int) -> bool:
-        """Start the Prometheus scrape endpoint; returns False if unavailable."""
-        if not (_HAVE_PROM and self.registry is not None):
-            return False
-        start_http_server(port, registry=self.registry)
-        return True

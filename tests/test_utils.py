@@ -154,7 +154,6 @@ class TestMetrics:
         assert pub.attach_engine_telemetry(lambda: None) is False
         pub.publish_engine({"steps": 3, "waiting": 1.0, "kind": "decode"})
         pub.publish_engine({"steps": 3, "waiting": 2.0})  # deduped: same step
-        assert pub.start_exporter(9999) is False
         lines = [json.loads(l) for l in buf.getvalue().strip().splitlines()]
         assert lines[0]["data"]["sd21-counter"] == 1
         assert lines[1]["data"]["sd21-spec-acceptance"] == 0.7
