@@ -485,7 +485,7 @@ DEFAULT_CONTRACT = Contract(
     },
     dict_guards={
         # serve.app closure state shared between the event loop and lane/
-        # stream threads: the in-flight counters must move under the lock
+        # stream-pool threads: the in-flight counters must move under the lock
         "serve/app.py": {
             "state": (("inflight", "lane_pending"), "inflight_lock"),
         },
@@ -523,8 +523,8 @@ DEFAULT_CONTRACT = Contract(
             "EngineLoop._futures_lock",
             "TenantLedger._lock",
             "StepTelemetry._lock",
-            # every stream thread and the server's event loop pass here
-            # once a written SSE event
+            # the server's event loop passes here once a written SSE event,
+            # the engine loop once a resolved request
             "StepTelemetry._stream_lock",
             "FlightRecorder._lock",
             "HostKVTier._lock",

@@ -113,7 +113,8 @@ class EngineLoop:
         LLaVA-style). ``cross_states``: optional mllama cross-attention
         states [Lv, dim] (gated cross layers attend them). ``on_token``:
         streaming callback — called from the loop thread, once per output
-        token, in order; must be cheap (a queue put). ``deadline_at``:
+        token, in order; must be cheap (an append: ``StreamTrack.put``).
+        ``deadline_at``:
         absolute monotonic deadline (0 = none) — the engine expires the
         request with stop reason ``"timeout"`` once passed. ``priority``/
         ``tenant``: QoS class and tenant attribution (``resilience.qos``)
@@ -300,6 +301,7 @@ class EngineLoop:
         for fut in pending:
             if not fut.done():
                 fut.set_exception(err)
+        self._tele.stream_flush()   # their streams end now
 
     def _drain_cancels(self) -> None:
         while True:
@@ -337,6 +339,9 @@ class EngineLoop:
                         self._do_migrate_all()
                     finally:
                         self._migrate_done.set()
+                # what intake, the cancels and the sweep resolved (an end
+                # mark each) or streamed: one wake-up for all of it
+                self._tele.stream_flush()
                 if not self.engine.has_work:
                     # async decode: going idle can leave the final lookahead
                     # step in flight (every slot finished at its commit) —
@@ -352,6 +357,9 @@ class EngineLoop:
                             fut = self._futures.pop(fin.req_id, None)
                         if fut is not None:
                             fut.set_result(fin)
+                    # the tokens left as the step left engine.commit; this
+                    # wakes the streams whose requests just resolved
+                    self._tele.stream_flush()
                 except Exception:
                     log.exception("engine step failed")
                     self._stop.set()  # dead loop must refuse new submissions

@@ -48,8 +48,8 @@ STEP_GAP_BUCKETS = (0.0001, 0.00025, 0.0005, 0.001, 0.0025, 0.005, 0.01,
 #: request that arrives during a prefill program waits out the program here
 INTAKE_WAIT_BUCKETS = (0.0005, 0.001, 0.0025, 0.005, 0.01, 0.025, 0.05, 0.1,
                        0.25, 0.5, 1.0, 5.0)
-#: a streamed token's hops out of the program (commit -> taken by the
-#: stream thread -> handed on encoded -> written to the socket), their sum,
+#: a streamed token's hops out of the program (commit -> taken by its
+#: stream -> handed on encoded -> written to the socket), their sum,
 #: and a finished request's wait for its last byte. 50 us to 10 s: the mean
 #: is tens of microseconds a hop on a quiet host and the tail is the finding
 STREAM_BUCKETS = (0.00005, 0.0001, 0.00025, 0.0005, 0.001, 0.0025, 0.005,
@@ -187,30 +187,43 @@ class StreamTrack:
     the socket: the token queue, the stamps of the event in flight and the
     stream's own tallies. Made by :meth:`StepTelemetry.stream_open`.
 
-    Three threads touch it, no two of them one field at a time: the
-    engine-loop thread calls :meth:`put` for each token (and
-    :meth:`resolved` as it resolves the request's future), the stream
-    thread takes tokens from ``q`` and calls :meth:`took`, :meth:`hand_on`,
-    :meth:`wrote`, :meth:`last` and (as the generator closes)
-    :meth:`close`, the server's event loop calls :meth:`sent` after each
-    chunk it wrote. A chunk is pulled only after the one before it was
-    written, so at most one event is in flight and the stream thread runs
-    only between two ``sent``. The event loop, which every stream of the
-    pod shares, only stamps; the shared counters and histograms move on
-    the stream's own thread (``wrote``), under the telemetry's stream
-    lock, once a written event."""
+    Two threads touch it, no two of them one field at a time. The
+    engine-loop thread calls :meth:`put` for each token and
+    :meth:`resolved` as it resolves the request's future (an end mark
+    behind the last token): an append and a mark on the telemetry's dirty
+    list, no lock and no wake-up of their own. As a step leaves
+    ``engine.commit`` (and where the loop resolved or cancelled requests)
+    :meth:`StepTelemetry.stream_flush` posts ONE ``call_soon_threadsafe``
+    for every stream the step touched. The server's event loop runs the
+    rest: the response's async generator takes EVERYTHING ``q`` holds when
+    its turn comes (:meth:`took` a token), sends it as one event
+    (:meth:`hand_on`, :meth:`sent` behind the write, :meth:`wrote` as it
+    resumes), waits in :meth:`wait` when ``q`` is empty, and ends in
+    :meth:`last` and :meth:`close`. So an event carries one token while
+    the stream keeps up and several when it has fallen behind; at most one
+    event is in flight. The shared counters and histograms move under the
+    telemetry's stream lock, once a written event (``wrote``)."""
 
     def __init__(self, tele: "StepTelemetry",
                  trace: Optional[Trace] = None):
         self.tele = tele
         self.trace = trace
-        self.q: "queue.Queue[Tuple[int, float]]" = queue.Queue()
+        # (token, commit stamp); the end mark is (None, resolve stamp). A
+        # SimpleQueue: ``put`` never blocks and takes no Python-level lock,
+        # and nobody waits in ``get`` (the stream takes with ``get_nowait``)
+        self.q: "queue.SimpleQueue[Tuple[Optional[int], float]]" = (
+            queue.SimpleQueue())
         self.n_put = 0       # loop thread
-        self.held = 0        # stream thread: taken, in no event yet
+        self._dirty = False  # on the telemetry's dirty list, not yet flushed
+        # event loop: the loop the stream is drained on (set before its
+        # first wait) and the future its turn waits on
+        self._loop = None
+        self._waiter = None
+        self.held = 0        # event loop: taken, in no event yet
         self._t_commit = self._t_taken = 0.0
         self._pending: Optional[Tuple[int, float, float, float]] = None
         self._final = False
-        # event loop: chunks written since ``wrote`` last looked
+        # chunks written since ``wrote`` last looked
         self._t_written = 0.0
         self._w_events = self._w_bytes = 0
         self.n_sent = 0      # from here on: under the stream lock
@@ -230,11 +243,13 @@ class StreamTrack:
         self.q.put((tok, tele.phase_t0))
         self.n_put += 1
         tele.stream_tokens_put += 1
+        self._touch()
 
     def resolved(self, fut=None) -> None:
         """The request's future resolved (its done-callback: the loop thread,
         inside ``loop.resolve`` or a cancel): its row is free and no token
-        follows. From here to the last byte the caller is draining."""
+        follows, so the end mark goes behind the last one. From here to the
+        last byte the caller is draining."""
         tele = self.tele
         with tele._stream_lock:
             if self._t_resolved is not None:
@@ -243,8 +258,33 @@ class StreamTrack:
             if not self._ended:
                 tele._stream["draining"] += 1
             self._settle_locked()
+        self.q.put((None, tele.phase_t0))
+        self._touch()
+
+    def _touch(self) -> None:
+        """``q`` got something: the next :meth:`StepTelemetry.stream_flush`
+        wakes this stream."""
+        if not self._dirty:
+            self._dirty = True
+            self.tele._stream_dirty.append(self)
 
     # -- the server's event loop --------------------------------------------
+
+    def wait(self, loop):
+        """An awaitable for "``q`` holds something" (``loop``: the running
+        event loop, which the flush will wake). Done at once if it already
+        does: what was put before ``_loop`` was known woke nobody."""
+        self._loop = loop
+        w = self._waiter = loop.create_future()
+        if not self.q.empty():
+            w.set_result(None)
+        return w
+
+    def wake(self) -> None:
+        """The flush's call on the event loop: the stream's turn."""
+        w = self._waiter
+        if w is not None and not w.done():
+            w.set_result(None)
 
     def sent(self, n_bytes: int) -> None:
         """``StreamingResponse.on_sent``: the chunk handed on last is on
@@ -253,17 +293,18 @@ class StreamTrack:
         self._w_events += 1
         self._w_bytes += n_bytes
 
-    # -- stream thread ------------------------------------------------------
-
     def took(self, t_commit: float) -> None:
-        """A token left ``q`` (call right behind ``q.get``)."""
+        """A token left ``q`` (call right behind ``q.get_nowait``)."""
         self._t_commit, self._t_taken = t_commit, time.monotonic()
         self.held += 1
 
     def hand_on(self, timed: bool = True) -> None:
         """The event about to be yielded carries every token taken since
-        the last one; ``timed``: with the last of them's stamps (not for
-        the tail a finished stream flushes: it waited for the future)."""
+        the last one, ``held`` of them, with the LAST one's stamps: where
+        an event carries several (the stream had fallen behind), its wake
+        is the newest token's, its deliver the newest's, and the older
+        ones waited longer than it says. ``timed``: not for the tail a
+        finished stream flushes (it waited for the future)."""
         self._pending = (self.held, self._t_commit if timed else 0.0,
                          self._t_taken, time.monotonic())
         self.held = 0
@@ -271,10 +312,12 @@ class StreamTrack:
     def wrote(self) -> None:
         """Behind the ``yield`` of an event that carried tokens (the drain
         has written it and asked for the next chunk): the ONE locked call
-        a written event. Chunks that carry none (the preamble, the finish
-        event) are counted with the next call."""
-        n_events = self._w_events
-        if not n_events:
+        a written event, which counts its ``held`` tokens and itself
+        (``events_sent`` counts the events that carried tokens, so
+        ``tokens_sent`` over it is the tokens an event carried: 1.0 while
+        every stream keeps up). Chunks that carry none (the preamble, the
+        finish event, ``[DONE]``) add their bytes with the next call."""
+        if not self._w_events:
             return      # resumed to be closed: nothing was written
         now, n_bytes = self._t_written, self._w_bytes
         self._w_events = self._w_bytes = 0
@@ -282,10 +325,10 @@ class StreamTrack:
         tele = self.tele
         with tele._stream_lock:
             c = tele._stream
-            c["events_sent"] += n_events
             c["bytes_sent"] += n_bytes
             if pending is not None:
                 n, t_commit, t_taken, t_handed = pending
+                c["events_sent"] += 1
                 c["tokens_sent"] += n
                 self.n_sent += n
                 if t_commit:
@@ -360,6 +403,12 @@ class StreamTrack:
             self.tele._stream["tokens_dropped"] += self.n_put - self.n_sent
 
 
+def _wake_streams(tracks: List[StreamTrack]) -> None:
+    """On the event loop, once a flush: every touched stream's turn."""
+    for track in tracks:
+        track.wake()
+
+
 class StepTelemetry:
     """One engine's step-loop instruments: cumulative counters, request
     latency histograms, and a bounded ring of per-step records (the flight
@@ -415,8 +464,8 @@ class StepTelemetry:
         self.intake_wait = BucketHistogram(INTAKE_WAIT_BUCKETS)
         # a token's way out and a request's way in, counted where they
         # happen, off the loop thread (StreamTrack, ingress_*): one lock of
-        # their own, so a stream thread never holds the lock the loop
-        # thread takes at every phase boundary. ``draining`` (future
+        # their own, so the server's event loop never holds the lock the
+        # loop thread takes at every phase boundary. ``draining`` (future
         # resolved, last byte not written) and ``ingress_inflight`` (begun,
         # not yet submitted) are gauges, a locked step a REQUEST.
         self._stream_lock = threading.Lock()
@@ -436,6 +485,9 @@ class StepTelemetry:
         # tokens handed to streams: written by the ONE thread that steps
         # the engine (``on_token`` runs there), a plain add with no lock
         self.stream_tokens_put = 0
+        # the streams whose queue got something since the last flush (a
+        # deque: appended and popped from any thread with no lock)
+        self._stream_dirty: deque = deque()
         # the engine's own output, whatever path delivers it
         self.tokens_committed = 0
         # where the engine-loop thread's time goes (PHASES): cumulative
@@ -469,6 +521,7 @@ class StepTelemetry:
         # each one is a serialization point the steady path avoids
         self.pipeline_flushes = 0
         self._flush_reasons: Dict[str, int] = {}
+        self._step_flushes: List[str] = []   # the open step's, by reason
         # pad-waste accounting: per dispatch, how many token slots the
         # executable walked for REAL context vs shape padding (batch pad
         # rows + the paged kernel's tiles beyond each row's live tokens +
@@ -536,7 +589,15 @@ class StepTelemetry:
             self.recompiles += 1
 
     def count_flush(self, reason: str = "") -> None:
+        """One flush of the lookahead. Inside a step it is counted as the
+        step is recorded, WITH it (``record_step``), so ``pipeline_flushes``
+        and ``steps`` move together: a snapshot taken between a step's
+        flush and its record would read one flush more than steps, and a
+        window in which every step flushes a share over 100%."""
         with self._lock:
+            if self._step_no > self.steps:      # a step is open
+                self._step_flushes.append(reason)
+                return
             self.pipeline_flushes += 1
             if reason:
                 self._flush_reasons[reason] = (
@@ -555,6 +616,8 @@ class StepTelemetry:
         (tracing switched off: seconds only), an engine phase's with its
         step's number: the join with the ring. Returns the phase it
         closed."""
+        if self._stream_dirty and self._phase == "engine.commit":
+            self.stream_flush()   # commit's last act, on its own clock
         now = time.monotonic()
         cpu = time.thread_time() if self._cpu_sampled else None
         if self._phase_ann is not None:
@@ -616,6 +679,33 @@ class StepTelemetry:
         with self._stream_lock:
             self._stream["streams_started"] += 1
         return StreamTrack(self, trace)
+
+    def stream_flush(self) -> None:
+        """Wake every stream whose queue got something since the last call:
+        ONE ``call_soon_threadsafe`` on the server's event loop, whatever
+        their number (one a loop, where tests run several). Called by the
+        engine-loop thread as a step leaves ``engine.commit`` (from
+        ``phase_enter``) and by ``EngineLoop`` where it resolved or
+        cancelled requests; a stream that is not being drained yet finds
+        its tokens when it first looks."""
+        dirty = self._stream_dirty
+        by_loop: Dict[Any, List[StreamTrack]] = {}
+        while dirty:
+            try:
+                track = dirty.popleft()
+            except IndexError:      # another thread's flush took it
+                break
+            # cleared BEFORE the wake is posted: a put from here on marks
+            # the stream again, and one from before is in ``q`` by the time
+            # the woken stream looks
+            track._dirty = False
+            if track._loop is not None:
+                by_loop.setdefault(track._loop, []).append(track)
+        for loop, tracks in by_loop.items():
+            try:
+                loop.call_soon_threadsafe(_wake_streams, tracks)
+            except RuntimeError:    # the loop is closed: so are its streams
+                pass
 
     def ingress_begin(self, t_begin: float) -> _Ingress:
         """A request that will reach the engine was begun at ``t_begin``
@@ -868,6 +958,11 @@ class StepTelemetry:
             if state_slots is not None and self.kda is not None:
                 self.kda["slots_live"] = int(state_slots)
             self.steps += 1
+            self.pipeline_flushes += len(self._step_flushes)
+            for reason in filter(None, self._step_flushes):
+                self._flush_reasons[reason] = (
+                    self._flush_reasons.get(reason, 0) + 1)
+            self._step_flushes = []
             self.requests_finished += finished
             self.decode_input_uploads += input_uploads
             self.tokens_committed += tokens

@@ -136,8 +136,8 @@ class AdmissionGate:
         # overflow with the same queue-depth threshold. Without this, a
         # burst of blocking calls parks unboundedly in the lane queue and
         # overload becomes latency collapse with zero 429s. ``lane_pending``
-        # counts only lane-bound requests — live SSE streams run on the
-        # stream pool and must not read as executor queue depth (they are
+        # counts only lane-bound requests — live SSE streams are drained on
+        # the event loop and must not read as executor queue depth (they are
         # still visible to ``inflight``/MAX_INFLIGHT above).
         if (lane_width > 0
                 and lane_pending - lane_width > self.thresholds.max_queue_depth):

@@ -503,8 +503,9 @@ def create_app(
             abort, and generator close alike). Keeps live SSE streams
             visible to MAX_INFLIGHT and the drain's in-flight wait. The
             lane-pending count drops NOW: the submission's lane thread is
-            already free and the stream's engine work runs on the stream
-            pool, so an open stream must not read as executor queue depth."""
+            already free and the stream is drained on the event loop (a
+            sync iterator's on the stream pool), so an open stream must
+            not read as executor queue depth."""
             self._handed_off = True
             with inflight_lock:
                 state["lane_pending"] -= 1
@@ -1296,17 +1297,38 @@ def create_app(
                         release = scope.hand_off_inflight()
                         inner = out.iterator
 
+                        def drained():
+                            release()
+                            dt = time.perf_counter() - t0
+                            collector.record(dt)
+                            pub.publish(dt)
+
                         def timed_iter():
                             try:
                                 for chunk in inner:
                                     yield chunk
                             finally:
-                                release()
-                                dt = time.perf_counter() - t0
-                                collector.record(dt)
-                                pub.publish(dt)
+                                drained()
 
-                        out.iterator = timed_iter()
+                        async def timed_aiter():
+                            try:
+                                async for chunk in inner:
+                                    yield chunk
+                            finally:
+                                # ``async for`` leaves an abandoned async
+                                # generator to the collector: close it here,
+                                # so its finally-path (the engine cancel)
+                                # runs with this one's
+                                try:
+                                    aclose = getattr(inner, "aclose", None)
+                                    if aclose is not None:
+                                        await aclose()
+                                finally:
+                                    drained()
+
+                        out.iterator = (timed_aiter()
+                                        if hasattr(inner, "__aiter__")
+                                        else timed_iter())
                         return out
                 scope.charge(out)
                 dt = time.perf_counter() - t0

@@ -103,8 +103,13 @@ class Response:
 
 class StreamingResponse(Response):
     """Incrementally-produced body (SSE token streams). ``iterator`` yields
-    ``str``/``bytes`` chunks — a SYNC generator; the app drives it on an
-    executor thread so a blocking token queue doesn't stall the event loop.
+    ``str``/``bytes`` chunks. An ASYNC iterator is driven on the server's
+    event loop itself, one ``anext`` a chunk and no thread: it must not
+    block (the vllm unit's token stream waits on a waker the engine loop
+    sets once a step, and sends everything its queue holds as one event,
+    so a delta may carry several tokens when a stream has fallen behind).
+    A SYNC iterator is driven on a ``_stream_pool`` thread, a pull a
+    chunk, so one that blocks (a queue, a file) does not stall the loop.
     No content-length: the server sends it chunked-encoded.
 
     ``on_sent``: called by the drain, on the event loop, with the byte
@@ -135,13 +140,16 @@ _STREAM_POOL = None
 
 
 def _stream_pool():
-    """Executor reserved for StreamingResponse chunk pulls (see usage)."""
+    """Executor for what a stream may block on: the chunk pulls of a
+    StreamingResponse over a SYNC iterator (see ``_drain_pooled``), and
+    the rare blocking step of an async one (a migration's hand-off). The
+    engine's token streams are async iterators and take no thread here."""
     global _STREAM_POOL
     if _STREAM_POOL is None:
         from concurrent.futures import ThreadPoolExecutor
 
-        # one thread per concurrently-live stream; 64 covers every engine's
-        # max_num_seqs with slack, and idle threads cost only stack pages
+        # one thread per concurrently-live pooled stream; idle threads cost
+        # only stack pages
         _STREAM_POOL = ThreadPoolExecutor(max_workers=64,
                                           thread_name_prefix="sse-stream")
     return _STREAM_POOL
@@ -400,24 +408,126 @@ class App:
         """Pump a StreamingResponse to the client while watching for
         ``http.disconnect``.
 
-        The old loop only ever awaited the next chunk, so a client that
-        went away mid-SSE was invisible: the chunk generator kept running
-        (parking a ``_stream_pool`` thread in ``_next``) and the engine
-        kept decoding for a dead socket until ``max_new_tokens``. Now the
-        drain races each chunk pull against the ASGI disconnect message;
-        when the client goes first, the generator is CLOSED — its
-        ``finally`` path is the cancellation seam every streaming handler
-        already owns (e.g. the vllm unit's ``loop.cancel(fut)``), so
-        abandoned requests free their KV blocks and slot the same way an
-        explicit stop sequence does. A failed socket write is treated
-        identically (the disconnect often shows up there first).
+        A client that goes away mid-SSE must not leave its generator
+        running and the engine decoding for a dead socket until
+        ``max_new_tokens``: when the ASGI disconnect message comes first,
+        the generator is CLOSED — its ``finally`` path is the cancellation
+        seam every streaming handler already owns (e.g. the vllm unit's
+        ``loop.cancel(fut)``), so abandoned requests free their KV blocks
+        and slot the same way an explicit stop sequence does. A failed
+        socket write is treated identically (the disconnect often shows up
+        there first).
+
+        Two ways to pull, by the iterator's kind, one way to write
+        (``write``): an async iterator on this event loop
+        (``_drain_async``), a sync one through the pool
+        (``_drain_pooled``).
         """
         import asyncio
 
-        loop = asyncio.get_event_loop()
-        it = iter(response.iterator)
-        _END = object()
         on_sent, annotated = response.on_sent, response.annotate_write
+
+        async def write(chunk) -> bool:
+            """One chunk onto the socket and into the stream's accounts;
+            False: the socket died mid-write."""
+            if isinstance(chunk, str):
+                chunk = chunk.encode()
+            if not chunk:
+                return True
+            message = {"type": "http.response.body", "body": chunk,
+                       "more_body": True}
+            try:
+                if annotated:
+                    # the write itself, on the profiler's clock; the send
+                    # suspends only behind a full socket buffer
+                    with obs_trace.annotate("serve.stream.write"):
+                        await send(message)
+                else:
+                    await send(message)
+            except Exception:
+                return False
+            if on_sent is not None:
+                on_sent(len(chunk))
+            return True
+
+        async def _until_disconnect():
+            # receive() contract after the request body: the next message
+            # is http.disconnect once the client actually goes away
+            # (serve.httpd blocks until socket EOF; httpx.ASGITransport
+            # resolves at response end). A transport error counts too.
+            try:
+                while True:
+                    message = await receive()
+                    if message["type"] == "http.disconnect":
+                        return
+            except Exception:
+                return
+
+        gone = asyncio.ensure_future(_until_disconnect())
+        try:
+            if hasattr(response.iterator, "__aiter__"):
+                whole = await self._drain_async(response.iterator, write,
+                                                gone)
+            else:
+                whole = await self._drain_pooled(response.iterator, write,
+                                                 gone)
+            if whole:
+                await send({"type": "http.response.body", "body": b""})
+        finally:
+            gone.cancel()
+            try:
+                await gone
+            except (asyncio.CancelledError, Exception):
+                pass
+
+    @staticmethod
+    async def _drain_async(iterator, write, gone) -> bool:
+        """Drive an async iterator here, on the event loop: no thread, no
+        executor future, no wait set a chunk. The pump is a task of its
+        own so that the disconnect can cancel it wherever it waits (in the
+        generator's idle wait, or in a write behind a full socket buffer);
+        the ``aclose()`` behind it runs the generator's ``finally`` at
+        once. True: the stream ended whole."""
+        import asyncio
+
+        ait = iterator.__aiter__()
+
+        async def pump() -> bool:
+            async for chunk in ait:
+                if not await write(chunk):
+                    return False    # socket died mid-write
+            return True
+
+        task = asyncio.ensure_future(pump())
+
+        def client_gone(_):
+            task.cancel()
+
+        gone.add_done_callback(client_gone)
+        try:
+            return await task
+        except asyncio.CancelledError:
+            if asyncio.current_task().cancelling():
+                raise       # this request's own task was cancelled
+            return False    # the client went away mid-stream
+        finally:
+            gone.remove_done_callback(client_gone)
+            aclose = getattr(ait, "aclose", None)
+            if aclose is not None:
+                try:
+                    await aclose()
+                except Exception:
+                    log.exception("stream iterator close failed")
+
+    @staticmethod
+    async def _drain_pooled(iterator, write, gone) -> bool:
+        """Drive a sync iterator through ``_stream_pool``, a pull a chunk
+        raced against the disconnect. True: the stream ended whole."""
+        import asyncio
+
+        loop = asyncio.get_event_loop()
+        it = iter(iterator)
+        _END = object()
 
         def _next():
             try:
@@ -433,30 +543,15 @@ class App:
                 except Exception:
                     log.exception("stream iterator close failed")
 
-        async def _until_disconnect():
-            # receive() contract after the request body: the next message
-            # is http.disconnect once the client actually goes away
-            # (serve.httpd blocks until socket EOF; httpx.ASGITransport
-            # resolves at response end). A transport error counts too.
-            try:
-                while True:
-                    message = await receive()
-                    if message["type"] == "http.disconnect":
-                        return
-            except Exception:
-                return
-
-        gone = loop.create_task(_until_disconnect())
         pull = None
         aborted = False
         try:
             while True:
-                # dedicated pool: each live SSE stream parks one thread
-                # in _next (possibly for minutes on a queued request);
-                # the default executor is capped at min(32, cpus+4) and
-                # shared with asyncio internals (getaddrinfo), so
-                # saturating it stalls every OTHER stream and DNS
-                # lookup (ADVICE r3)
+                # dedicated pool: each live pooled stream parks one thread
+                # in _next (possibly for minutes); the default executor is
+                # capped at min(32, cpus+4) and shared with asyncio
+                # internals (getaddrinfo), so saturating it stalls every
+                # OTHER stream and DNS lookup (ADVICE r3)
                 pull = loop.run_in_executor(_stream_pool(), _next)
                 done, _ = await asyncio.wait(
                     {pull, gone}, return_when=asyncio.FIRST_COMPLETED)
@@ -466,38 +561,16 @@ class App:
                 chunk = pull.result()
                 if chunk is _END:
                     break
-                if isinstance(chunk, str):
-                    chunk = chunk.encode()
-                if not chunk:
-                    continue
-                message = {"type": "http.response.body", "body": chunk,
-                           "more_body": True}
-                try:
-                    if annotated:
-                        # the write itself, on the profiler's clock; the
-                        # send suspends only behind a full socket buffer
-                        with obs_trace.annotate("serve.stream.write"):
-                            await send(message)
-                    else:
-                        await send(message)
-                except Exception:
+                if not await write(chunk):
                     aborted = True  # socket died mid-write
                     break
-                if on_sent is not None:
-                    on_sent(len(chunk))
-            if not aborted:
-                await send({"type": "http.response.body", "body": b""})
+            return not aborted
         finally:
-            gone.cancel()
-            try:
-                await gone
-            except (asyncio.CancelledError, Exception):
-                pass
             if aborted:
                 # a generator cannot be closed while executing: wait for
-                # the in-flight pull (our generators poll bounded queues,
-                # so this is short), then close on a pool thread so the
-                # handler's finally-path (engine cancel) runs off-loop
+                # the in-flight pull (a generator that polls a bounded
+                # queue makes this short), then close on a pool thread so
+                # the handler's finally-path runs off-loop
                 if pull is not None and not pull.done():
                     try:
                         await asyncio.wait_for(

@@ -60,14 +60,16 @@ ENGINE_HISTOGRAMS = {
                             "the engine loop, which runs between steps"),
     "stream_wake_seconds": ("shai_engine_stream_wake_seconds",
                             "A streamed token's commit on the engine loop "
-                            "to its stream thread taking it from the queue"),
+                            "to its stream taking it from the queue, on "
+                            "the server's event loop (the newest token's, "
+                            "where an event carries several)"),
     "stream_encode_seconds": ("shai_engine_stream_encode_seconds",
                               "Taken to handed on as an encoded SSE event "
                               "(text assembly, JSON)"),
     "stream_write_seconds": ("shai_engine_stream_write_seconds",
                              "Handed on to written to the socket (the "
-                             "executor future, the event loop's wake-up, "
-                             "the chunked write)"),
+                             "chunked write, behind a full buffer its "
+                             "drain)"),
     "stream_deliver_seconds": ("shai_engine_stream_deliver_seconds",
                                "Commit to written: a token's whole way out "
                                "of the program"),
@@ -154,7 +156,9 @@ _PHASE_CPU_WALL_SECONDS = ("shai_engine_phase_cpu_wall_seconds_total",
 _STREAM_COUNTERS = ("shai_engine_stream_total",
                     "Streamed responses, by counter: tokens_put, "
                     "tokens_sent, tokens_dropped (an aborted or stopped "
-                    "stream's remainder), events_sent, bytes_sent, "
+                    "stream's remainder), events_sent (those that carried "
+                    "tokens: one may carry several when a stream fell "
+                    "behind), bytes_sent, "
                     "streams_started, streams_ended, streams_aborted; "
                     "gauges: backlog (put, neither sent nor dropped), "
                     "draining (future resolved, last byte not written), "

@@ -728,9 +728,10 @@ class VllmService(ModelService):
     def migrate_inflight(self) -> int:
         """Drain migrate phase: the engine loop snapshots-and-finishes
         every live request ('migrated' Finished, manifest attached); the
-        lane/stream threads blocked on those futures then SHIP the
-        manifests and return/stream the handoff records — outside every
-        engine structure, the shai-race contract."""
+        lane threads blocked on those futures (a stream: a pool thread
+        its generator hands the hop to) then SHIP the manifests and
+        return/stream the handoff records — outside every engine
+        structure, the shai-race contract."""
         loop = getattr(self, "loop", None)
         if loop is None:
             return 0
@@ -1193,14 +1194,23 @@ class VllmService(ModelService):
     def _openai_stream(self, prompt: str, body: Dict[str, Any], kind: str,
                        add_special: bool = True):
         """SSE token stream (OpenAI ``stream: true``): the engine's
-        ``on_token`` callback feeds a queue; the response generator decodes
+        ``on_token`` callback feeds the stream's queue; the response's
+        ASYNC generator, driven on the server's event loop, decodes
         incrementally (holding back partial UTF-8 sequences) and emits
-        OpenAI-shaped chunks, finishing with ``data: [DONE]``."""
+        OpenAI-shaped chunks, finishing with ``data: [DONE]``.
+
+        When its turn comes the stream sends EVERYTHING its queue holds as
+        one event: one token while it keeps up, several when it has fallen
+        behind (an OpenAI delta may carry any amount of text). Nothing
+        waits to fill an event. The engine loop wakes the event loop once a
+        step for all streams (``StepTelemetry.stream_flush``); the request's
+        resolution puts an end mark behind its last token, which is what
+        ends the stream."""
+        import asyncio
         import json as _json
-        import queue as _q
         import time as _time
 
-        from ..asgi import StreamingResponse
+        from ..asgi import StreamingResponse, _stream_pool
 
         self._require_decode_role()
         if self._openai_n(body) != 1:
@@ -1219,16 +1229,17 @@ class VllmService(ModelService):
             "max_new_tokens": body.get("max_tokens", default_mnt)})
         stop = body.get("stop") or []
         stops = [stop] if isinstance(stop, str) else list(stop)
-        # captured HERE (handler context): the chunk generator drains on a
-        # stream-pool thread where the request contextvar is absent
+        # captured HERE (handler context): the chunk generator is driven by
+        # the drain, behind the handler, where the request contextvar is
+        # absent
         result_timeout = self._result_timeout()
         req_trace = obs_trace.current_trace()
         req_span = obs_trace.current_span()
         stream_no = self._next_openai_id()
         rid = f"shai-{stream_no}"
         # the stream's way out, counted where it happens (StreamTrack): the
-        # engine puts each token with its commit's stamp, this thread's
-        # generator takes and encodes, the drain reports each write
+        # engine puts each token with its commit's stamp, the generator
+        # takes and encodes, the drain reports each write
         track = self._engine.obs.stream_open(trace=req_trace)
         annotated = stream_no % STREAM_ANNOTATE_EVERY == 0
         tokq = track.q
@@ -1263,50 +1274,53 @@ class VllmService(ModelService):
 
         asm = SseTextAssembler(self._decode, stops)
 
-        def chunks():
+        async def chunks():
             first = True
             finish = None
-            # the drain can close a generator only between pulls, so one
-            # that waits (a queued request) or holds bytes back (a partial
-            # character) without yielding cannot be cancelled by a client
-            # that went away: after a second with nothing to send, hand
-            # back an empty turn (the drain sends no empty chunk). A stream
-            # whose tokens flow never takes one
-            quiet_s = 1.0
-            t_turn = _time.monotonic()
+            ended = False   # the end mark was taken: the future is done
+            aloop = asyncio.get_running_loop()
             try:
                 if kind == "chat":
                     yield event("", None, True)  # role preamble chunk
                     first = False
-                while True:
-                    if _time.monotonic() - t_turn >= quiet_s:
-                        yield ""
-                        t_turn = _time.monotonic()
-                    try:
-                        tok, t_commit = tokq.get(timeout=0.2)
-                    except _q.Empty:
-                        if fut.done() and tokq.empty():
-                            break
+                while not ended:
+                    if tokq.empty():
+                        # the one place a stream waits: the drain cancels
+                        # it here when the client goes away
+                        if finish is None:
+                            await track.wait(aloop)
+                        else:
+                            async with asyncio.timeout(result_timeout):
+                                await track.wait(aloop)
                         continue
-                    track.took(t_commit)
-                    # WORK only under the annotation, never the wait above
+                    # this turn's event: everything the queue holds. WORK
+                    # only under the annotation, never the wait above
+                    delta = ""
                     with (obs_trace.annotate("serve.stream.encode")
                           if annotated else obs_trace.NOOP):
-                        delta = asm.push(tok)
+                        while not tokq.empty():
+                            tok, t_commit = tokq.get_nowait()
+                            if tok is None:
+                                ended = True
+                                break
+                            if finish is not None:
+                                continue    # behind a stop: dropped
+                            track.took(t_commit)
+                            delta += asm.push(tok)
+                            if asm.stopped:
+                                # the engine would decode to
+                                # max_new_tokens for nobody — abort and
+                                # reclaim the slot/blocks; what it puts
+                                # until the cancel lands is dropped
+                                finish = "stop"
+                                self.loop.cancel(fut)
                         ev = event(delta, None, first) if delta else None
                     if ev is not None:
                         track.hand_on()
                         yield ev
                         track.wrote()
                         first = False
-                        t_turn = _time.monotonic()
-                    if asm.stopped:
-                        # the engine would decode to max_new_tokens for
-                        # nobody — abort and reclaim the slot/blocks
-                        finish = "stop"
-                        self.loop.cancel(fut)
-                        break
-                fin = fut.result(timeout=result_timeout)
+                fin = fut.result()      # done: the end mark is behind it
                 if req_trace is not None and fin.timing:
                     req_trace.add_phase_spans(fin.timing, parent=req_span)
                     req_trace.root.attrs.setdefault("engine_req_id",
@@ -1317,8 +1331,10 @@ class VllmService(ModelService):
                     # the peer + resume handle the client (or cova)
                     # replays against — the continuation streams from
                     # the new pod, token-identical to an uninterrupted
-                    # run (the live-migration contract)
-                    handoff = self._migrated_handoff(fin)
+                    # run (the live-migration contract). The hand-off
+                    # ships the manifest over the network: off the loop
+                    handoff = await aloop.run_in_executor(
+                        _stream_pool(), self._migrated_handoff, fin)
                     yield ("data: " + _json.dumps({"migrated": {
                         "peer": handoff["peer"],
                         "resume": handoff["resume"],

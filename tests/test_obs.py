@@ -282,6 +282,28 @@ def test_step_record_carries_its_phases_and_the_queue_at_entry(monkeypatch):
     assert "loop.resolve" in log.names
 
 
+def test_a_steps_flush_is_counted_with_the_step_and_never_ahead_of_it():
+    """``pipeline_flushes`` and ``steps`` move together: a snapshot taken
+    between a step's flush and its record reads no flush without its step,
+    so a window in which every step flushes reads a share of 100, not
+    101."""
+    t = StepTelemetry()
+    for _ in range(3):
+        t.begin_step(0)
+        t.count_flush("admission")
+        mid = t.snapshot()
+        assert mid["pipeline_flushes"] == mid["steps"]
+        t.record_step(kind="decode", duration_s=0.0, n_running=1,
+                      n_waiting=0, n_chunking=0, blocks_free=0)
+        t.phase_enter(None)
+        snap = t.snapshot()
+        assert snap["pipeline_flushes"] == snap["steps"]
+    t.count_flush("idle")           # between steps: counted at once
+    snap = t.snapshot()
+    assert snap["flush_by_reason"] == {"admission": 3, "idle": 1}
+    assert snap["pipeline_flushes"] == 4 and snap["steps"] == 3
+
+
 def test_counters_by_reason_and_phase_ride_the_snapshot():
     t = StepTelemetry()
     t.count_flush("admission")

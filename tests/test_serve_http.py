@@ -433,3 +433,169 @@ def test_server_stop_runs_shutdown_hooks():
     while not ran["v"] and time.time() < deadline:
         time.sleep(0.01)
     assert ran["v"], "shutdown hooks never ran on server stop"
+
+
+# ---------------------------------------------------------------------------
+# StreamingResponse: an async iterator on the event loop, a sync one pooled
+# ---------------------------------------------------------------------------
+
+async def drive_asgi(app, path, body=None, disconnect=None, slow_s=0.0,
+                     fail_after=None, method="POST"):
+    """One request through ``app`` by raw ASGI, with a ``receive`` and a
+    ``send`` of the test's own: the test decides when the client goes away
+    (``disconnect``), how slowly the socket takes a chunk and which write
+    fails. Returns (status, chunks)."""
+    raw = json.dumps(body).encode() if body is not None else b""
+    scope = {"type": "http", "method": method, "path": path,
+             "query_string": b"", "headers": [
+                 (b"content-type", b"application/json"),
+                 (b"content-length", str(len(raw)).encode())]}
+    asked, out = [False], {"status": None, "chunks": []}
+    gone = disconnect or asyncio.Event()
+
+    async def receive():
+        if not asked[0]:
+            asked[0] = True
+            return {"type": "http.request", "body": raw, "more_body": False}
+        await gone.wait()
+        return {"type": "http.disconnect"}
+
+    async def send(message):
+        if message["type"] == "http.response.start":
+            out["status"] = message["status"]
+        elif message.get("body"):
+            if fail_after is not None and len(out["chunks"]) >= fail_after:
+                raise ConnectionResetError("the socket died")
+            if slow_s:
+                await asyncio.sleep(slow_s)
+            out["chunks"].append(message["body"])
+
+    await asyncio.wait_for(app(scope, receive, send), timeout=60.0)
+    return out["status"], out["chunks"]
+
+
+async def _drive_stream(app, path, gone=None, fail_after=None):
+    _, chunks = await drive_asgi(app, path, disconnect=gone,
+                                 fail_after=fail_after, method="GET")
+    return chunks
+
+
+def _stream_app(make_iterator, **kw):
+    from scalable_hw_agnostic_inference_tpu.serve.asgi import (
+        StreamingResponse,
+    )
+
+    app = App("t")
+
+    @app.get("/s")
+    def s(request):
+        return StreamingResponse(make_iterator(), **kw)
+
+    return app
+
+
+@pytest.mark.asyncio
+@pytest.mark.parametrize("kind", ["async", "sync"])
+async def test_an_async_iterator_is_drained_on_the_loop_a_sync_one_pooled(kind):
+    """The same chunks either way; the async generator's body runs on the
+    event loop's own thread, the sync one's on an ``sse-stream`` thread."""
+    ran_on, sizes = [], []
+
+    async def agen():
+        for c in ("ab", "", b"cde", "f"):
+            ran_on.append(threading.current_thread().name)
+            await asyncio.sleep(0)
+            yield c
+
+    def gen():
+        for c in ("ab", "", b"cde", "f"):
+            ran_on.append(threading.current_thread().name)
+            yield c
+
+    app = _stream_app(agen if kind == "async" else gen, on_sent=sizes.append)
+    chunks = await _drive_stream(app, "/s")
+    assert chunks == [b"ab", b"cde", b"f"] and sizes == [2, 3, 1]
+    here = threading.current_thread().name
+    if kind == "async":
+        assert set(ran_on) == {here}
+    else:
+        assert all(n.startswith("sse-stream") for n in ran_on), ran_on
+
+
+@pytest.mark.asyncio
+@pytest.mark.parametrize("how", ["client_goes_away_while_it_waits",
+                                 "write_fails"])
+async def test_an_abandoned_async_iterator_is_closed_at_once(how):
+    """The generator's ``finally`` is the cancellation seam: it runs as soon
+    as the client is gone, also while the generator waits for something
+    that never comes, with no thread to wait out."""
+    closed, never = [], asyncio.Event()
+
+    async def agen():
+        try:
+            yield "one"
+            if how == "write_fails":
+                yield "two"
+            await never.wait()
+            yield "never"
+        finally:
+            closed.append(time.monotonic())
+
+    gone = asyncio.Event()
+    app = _stream_app(agen)
+    call = asyncio.ensure_future(_drive_stream(
+        app, "/s", gone=gone, fail_after=1 if how == "write_fails" else None))
+    if how != "write_fails":
+        await asyncio.sleep(0.05)
+        assert not call.done() and closed == []
+        t0 = time.monotonic()
+        gone.set()
+    else:
+        t0 = time.monotonic()
+    chunks = await asyncio.wait_for(call, timeout=5.0)
+    assert chunks == [b"one"]
+    assert len(closed) == 1 and closed[0] - t0 < 0.2
+
+
+@pytest.mark.asyncio
+async def test_an_async_iterator_that_raises_fails_the_request_not_the_loop():
+    closed = []
+
+    async def agen():
+        try:
+            yield "one"
+            raise RuntimeError("the producer broke")
+        finally:
+            closed.append(True)
+
+    app = _stream_app(agen)
+    with pytest.raises(RuntimeError, match="the producer broke"):
+        await _drive_stream(app, "/s")
+    assert closed == [True]
+
+
+def test_httpd_streams_an_async_iterator_chunked():
+    """Through the real server: chunked framing, several events in a chunk
+    stay one chunk, and the connection is reusable behind the stream."""
+    import http.client
+
+    async def agen():
+        yield "data: a\n\n"
+        await asyncio.sleep(0.01)
+        yield "data: b\n\ndata: c\n\n"
+        yield "data: [DONE]\n\n"
+
+    server = Server(_stream_app(agen), host="127.0.0.1", port=0)
+    host, port = server.start_background()
+    try:
+        conn = http.client.HTTPConnection(host, port, timeout=10)
+        for _ in range(2):          # the second rides the same connection
+            conn.request("GET", "/s")
+            r = conn.getresponse()
+            assert r.status == 200
+            assert r.getheader("transfer-encoding") == "chunked"
+            assert r.read() == (b"data: a\n\ndata: b\n\ndata: c\n\n"
+                                b"data: [DONE]\n\n")
+        conn.close()
+    finally:
+        server.stop()

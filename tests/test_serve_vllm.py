@@ -14,7 +14,7 @@ from scalable_hw_agnostic_inference_tpu.models.registry import get_model
 from scalable_hw_agnostic_inference_tpu.serve.app import create_app
 from scalable_hw_agnostic_inference_tpu.utils.env import ServeConfig
 
-from test_serve_http import make_client, wait_ready
+from test_serve_http import drive_asgi, make_client, wait_ready
 
 
 def _char_decode(ids):
@@ -273,9 +273,12 @@ def test_stream_abandonment_cancels_engine_request():
             "hello world",
             {"max_tokens": service.ecfg.max_new_tokens, "temperature": 0.0},
             "completion")
-        it = iter(resp.iterator)
-        next(it)            # at least one chunk flowed
-        it.close()          # GeneratorExit — simulates the disconnect
+        async def abandon():
+            it = resp.iterator.__aiter__()
+            await anext(it)     # at least one chunk flowed
+            await it.aclose()   # GeneratorExit — simulates the disconnect
+
+        asyncio.run(abandon())
         deadline = time.time() + 30
         while time.time() < deadline:
             eng = service._engine
@@ -557,3 +560,223 @@ def test_geometry_serving_tier_registry():
     cfg = g["llama-1b-geometry"]()
     assert (cfg.dim, cfg.n_layers, cfg.vocab_size) == (2048, 16, 128256)
     assert g["mistral-7b-geometry"]().vocab_size == 32768
+
+
+# -- the token stream alone: a stub engine loop behind `_openai_stream` --------
+#
+# No model and no engine: the test puts the tokens itself, so it decides how
+# many a stream's queue holds when its turn comes.
+
+class _StubLoop:
+    """What `_openai_stream` asks of an EngineLoop; `on_token` is the
+    stream's own `StreamTrack.put`."""
+
+    def __init__(self, tele):
+        from concurrent.futures import Future
+
+        self.tele, self.fut, self.cancelled = tele, Future(), []
+        self.on_token = None
+
+    def submit(self, ids, params, on_token=None, **kw):
+        self.on_token = on_token
+        return self.fut
+
+    def cancel(self, fut):
+        self.cancelled.append(fut)
+
+    def put(self, toks, t_commit=None):
+        """A step's commit: the tokens, then the one wake-up."""
+        import time as _time
+
+        self.tele.phase_t0 = t_commit or _time.monotonic()
+        for tok in toks:
+            self.on_token(tok)
+        self.tele.stream_flush()
+
+    def resolve(self, stop_reason="length"):
+        from types import SimpleNamespace
+
+        self.fut.set_result(SimpleNamespace(
+            stop_reason=stop_reason, timing=None, req_id=0))
+        self.tele.stream_flush()
+
+
+def _stub_stream(body=None, kind="completion"):
+    """(StreamingResponse, stub loop, telemetry) of one streamed request."""
+    from types import SimpleNamespace
+
+    from scalable_hw_agnostic_inference_tpu.obs.steploop import StepTelemetry
+
+    _, service = make_service()
+    tele = StepTelemetry()
+    service._engine = SimpleNamespace(obs=tele)
+    service.loop = _StubLoop(tele)
+    service._encode = lambda text, add_special=True: [1, 2, 3]
+    service._decode = lambda ids: "".join(chr(97 + int(i) % 26) for i in ids)
+    service._sampling_from = lambda payload: None
+    resp = service._openai_stream("hello", dict(body or {}), kind)
+    return resp, service.loop, tele
+
+
+def _event_texts(chunks):
+    import json as _json
+
+    return [_json.loads(c[6:])["choices"][0]["text"] for c in chunks
+            if c.startswith("data: {")]
+
+
+async def _pull(resp, it):
+    """One chunk as the drain takes it: pulled, written, reported."""
+    chunk = await anext(it)
+    resp.on_sent(len(chunk))
+    return chunk
+
+
+async def _rest(resp, it):
+    out = []
+    try:
+        while True:
+            out.append(await _pull(resp, it))
+    except StopAsyncIteration:
+        return out
+
+
+@pytest.mark.asyncio
+@pytest.mark.parametrize("k", [1, 2, 7, 40])
+async def test_what_the_queue_holds_leaves_as_one_event(k):
+    """k tokens queued before the stream's turn are ONE event whose text is
+    theirs in order; the events' sum is the unbatched stream's."""
+    resp, loop, tele = _stub_stream()
+    toks = list(range(3, 3 + k))
+    loop.put(toks)
+    it = resp.iterator.__aiter__()
+    first = await _pull(resp, it)
+    want = "".join(chr(97 + t % 26) for t in toks)
+    assert _event_texts([first]) == [want]
+    loop.put([30])          # the stream keeps up: one token, one event
+    second = await _pull(resp, it)
+    assert _event_texts([second]) == ["e"]
+    loop.put([31, 32])
+    loop.resolve()
+    rest = await _rest(resp, it)
+    assert rest[-1] == "data: [DONE]\n\n"
+    texts = _event_texts([first, second] + rest)
+    assert "".join(texts) == want + "efg"
+    assert texts[-1] == ""      # the finish event carries no text
+    s = tele.stream_snapshot()
+    assert s["tokens_put"] == s["tokens_sent"] == k + 3
+    assert s["tokens_dropped"] == s["backlog"] == 0
+    assert loop.cancelled == []
+
+
+@pytest.mark.asyncio
+async def test_a_stop_inside_a_batch_ends_the_batch_there():
+    """The stop's own text and what follows it in the same batch never
+    leave; the engine request is cancelled, and what it puts until the
+    cancel lands is dropped."""
+    import json as _json
+
+    resp, loop, tele = _stub_stream({"stop": "e"})
+    it = resp.iterator.__aiter__()
+    loop.put([1, 2, 3, 4, 5, 6, 7])      # b c d e f g h: the stop is inside
+    ev = await _pull(resp, it)
+    assert _event_texts([ev]) == ["bcd"]
+    pending = asyncio.ensure_future(_pull(resp, it))
+    await asyncio.sleep(0.01)
+    assert loop.cancelled == [loop.fut] and not pending.done()
+    loop.put([8, 9])                    # behind the stop, before the cancel
+    loop.resolve("cancelled")
+    rest = [await pending] + await _rest(resp, it)
+    assert _event_texts(rest) == [""]
+    assert _json.loads(rest[0][6:])["choices"][0]["finish_reason"] == "stop"
+    assert rest[-1] == "data: [DONE]\n\n"
+    s = tele.stream_snapshot()
+    # sent: b c d and the stop's own token, which went with the stream's end
+    assert (s["tokens_put"], s["tokens_sent"], s["tokens_dropped"]) == (
+        9, 4, 5)
+    assert s["streams_ended"] == 1 and s["backlog"] == 0
+
+
+@pytest.mark.asyncio
+async def test_the_stream_ends_when_its_request_does():
+    """The end mark behind the last token ends the stream: no poll's
+    timeout stands between the future's resolution and ``[DONE]`` (the
+    best of three: a loaded test machine may hold any one of them up)."""
+    import threading
+    import time as _time
+
+    lags = []
+    for _ in range(3):
+        resp, loop, tele = _stub_stream()
+        it = resp.iterator.__aiter__()
+        loop.put([1])
+        await _pull(resp, it)
+        pending = asyncio.ensure_future(_rest(resp, it))
+        await asyncio.sleep(0.05)           # the stream waits, idle
+        assert not pending.done()
+        t = {}
+
+        def engine_thread():
+            t["resolved"] = _time.monotonic()
+            loop.resolve()
+
+        threading.Thread(target=engine_thread).start()
+        rest = await asyncio.wait_for(pending, timeout=5.0)
+        lags.append(_time.monotonic() - t["resolved"])
+        assert rest[-1] == "data: [DONE]\n\n"
+    assert min(lags) < 0.05, f"the streams ended {lags} s behind their requests"
+
+
+@pytest.mark.asyncio
+@pytest.mark.parametrize("reason,key", [("rejected", "error"),
+                                        ("timeout", "error")])
+async def test_an_in_band_record_still_closes_the_stream(reason, key):
+    import json as _json
+
+    resp, loop, tele = _stub_stream()
+    it = resp.iterator.__aiter__()
+    loop.put([1, 2])
+    await _pull(resp, it)
+    loop.resolve(reason)
+    rest = await _rest(resp, it)
+    assert key in _json.loads(rest[0][6:]) and rest[-1] == "data: [DONE]\n\n"
+    s = tele.stream_snapshot()
+    assert s["tokens_put"] == s["tokens_sent"] == 2 and s["streams_ended"] == 1
+
+
+@pytest.mark.asyncio
+async def test_a_client_that_leaves_an_idle_stream_cancels_its_request():
+    """The stream waits for its first token (a queued request): the
+    disconnect closes it at once, with no pull in flight to wait out, and
+    its ``finally`` cancels the engine request."""
+    import threading
+    import time as _time
+
+    from scalable_hw_agnostic_inference_tpu.serve import asgi
+
+    resp, loop, tele = _stub_stream()
+    app = asgi.App("t")
+
+    @app.get("/s")
+    def s(request):
+        return resp
+
+    gone = asyncio.Event()
+    before = {t.ident for t in threading.enumerate()}
+    call = asyncio.ensure_future(drive_asgi(app, "/s", disconnect=gone,
+                                            method="GET"))
+    await asyncio.sleep(0.05)
+    assert not call.done() and loop.cancelled == []
+    # nothing was pulled on a thread: the stream waits on the event loop
+    assert {t.ident for t in threading.enumerate()} == before
+    t0 = _time.monotonic()
+    gone.set()
+    status, chunks = await asyncio.wait_for(call, timeout=5.0)
+    # at once: no pull to wait out (it could take a second, the quiet turn)
+    assert _time.monotonic() - t0 < 0.2
+    assert loop.cancelled == [loop.fut]
+    assert status == 200 and chunks == []
+    loop.resolve("cancelled")
+    s_ = tele.stream_snapshot()
+    assert (s_["streams_started"], s_["streams_aborted"]) == (1, 1)
+    assert s_["draining"] == s_["backlog"] == 0
