@@ -401,24 +401,61 @@ def _cross_layer(lp: Dict, x: jax.Array, cross_k: jax.Array,
     return x + g_mlp * m * gate
 
 
+def _set_blocks(leaf: jax.Array, tbl: jax.Array,
+                blocks: jax.Array) -> jax.Array:
+    """``leaf [N, ...]`` with the blocks ``tbl [B, m]`` names replaced by
+    ``blocks [B, m, ...]``, scattered as rows of the leaf's flat view
+    ``[N, Bs * Hkv, Dh]`` (:func:`_scatter_blocks` says why)."""
+    flat = leaf.reshape(leaf.shape[0], -1, leaf.shape[-1])
+    flat = flat.at[tbl].set(
+        blocks.astype(leaf.dtype).reshape(tbl.shape + flat.shape[1:]))
+    return flat.reshape(leaf.shape)
+
+
 def _scatter_blocks(kv_layer: Dict, tbl: jax.Array, k: jax.Array,
-                    v: jax.Array, quant: bool) -> Dict:
+                    v: jax.Array, quant: bool,
+                    shardings: Optional["EngineShardings"] = None) -> Dict:
     """Scatter whole fresh KV blocks ``[B, m, Bs, Hkv, Dh]`` into one pool
     layer. int8 pools (``SHAI_KV_QUANT``) quantize per block x kv-head on
     the way in (``ops.quant.quantize_kv_blocks``) and scatter the f32
     scales alongside — THE quantized-write seam every prefill/continuation
-    scatter goes through."""
+    scatter goes through.
+
+    Every leaf is written through its flat view ``[N, Bs * Hkv, Dh]``
+    (:func:`_set_blocks`), so that the donated leaf is updated in place. A
+    pool leaf ``[N, Bs, Hkv, Dh]`` lives with its heads on the sublanes
+    (minor tile ``(Hkv, 128)`` below eight heads); given the 4-D scatter
+    ``leaf.at[tbl].set(blocks)`` the TPU compiler wants a block's TOKENS
+    there (``{3,1,2,0:T(8,128)}``) and, at 4 and 2 heads (a device's
+    share under TP counts), re-lays the WHOLE leaf into that layout and back
+    around the few blocks written: two pool-sized copies a leaf a program,
+    10 ms of a 27 ms prefill program at four heads over 10,241 blocks. The
+    view has the same bytes in the same order whatever ``Hkv`` is, its rows
+    fill whole ``(8, 128)`` tiles, and the compiled program is bitcast,
+    scatter, bitcast (``tests/test_pool_write_layout.py`` holds it to
+    that). Under TP the view is taken of each device's own heads
+    (``shard_map``): ``Bs * Hkv`` as one axis could not be split on heads,
+    and the partitioner would gather the pool.
+    """
+    fresh = {"k": k, "v": v}
     if quant:
         from ..ops.quant import quantize_kv_blocks
 
-        kq, ksc = quantize_kv_blocks(k)
-        vq, vsc = quantize_kv_blocks(v)
-        return {"k": kv_layer["k"].at[tbl].set(kq),
-                "v": kv_layer["v"].at[tbl].set(vq),
-                "ks": kv_layer["ks"].at[tbl].set(ksc),
-                "vs": kv_layer["vs"].at[tbl].set(vsc)}
-    return {"k": kv_layer["k"].at[tbl].set(k.astype(kv_layer["k"].dtype)),
-            "v": kv_layer["v"].at[tbl].set(v.astype(kv_layer["v"].dtype))}
+        fresh["k"], fresh["ks"] = quantize_kv_blocks(k)
+        fresh["v"], fresh["vs"] = quantize_kv_blocks(v)
+
+    def write(layer, tbl, fresh):
+        return {n: _set_blocks(layer[n], tbl, fresh[n]) for n in layer}
+
+    if shardings is None:
+        return write(kv_layer, tbl, fresh)
+    specs = {n: s.spec for n, s in shardings.kv_pool(1, quant)[0].items()}
+    return jax.shard_map(
+        write, mesh=shardings.mesh,
+        # a block of the update carries the leaf's axes behind [B, m]
+        in_specs=(specs, P(), {n: P(None, None, *s[1:])
+                               for n, s in specs.items()}),
+        out_specs=specs, check_vma=False)(kv_layer, tbl, fresh)
 
 
 def _write_slots(state: Dict, slots: jax.Array, s: jax.Array,
@@ -521,7 +558,7 @@ def make_prefill(cfg: LlamaConfig, block_size: int, blocks_per_seq: int,
                 k.reshape(B, m_used, block_size, cfg.n_kv_heads,
                           cfg.head_dim),
                 v.reshape(B, m_used, block_size, cfg.n_kv_heads,
-                          cfg.head_dim), kv_quant)
+                          cfg.head_dim), kv_quant, shardings)
             return (o,)
 
         # gated cross-attention over vision states: no rope, no KV pool
@@ -715,7 +752,7 @@ def make_prefill_cont(cfg: LlamaConfig, block_size: int, blocks_per_seq: int,
                 k.reshape(B, c_blocks, block_size, cfg.n_kv_heads,
                           cfg.head_dim),
                 v.reshape(B, c_blocks, block_size, cfg.n_kv_heads,
-                          cfg.head_dim), kv_quant)
+                          cfg.head_dim), kv_quant, shardings)
             return (_ragged_pool_attention(q, kv[pi], tables, positions,
                                            block_size, shardings, window),)
 
@@ -804,7 +841,7 @@ def make_prefill_cont(cfg: LlamaConfig, block_size: int, blocks_per_seq: int,
                 k.reshape(B, c_blocks, block_size, cfg.n_kv_heads,
                           cfg.head_dim),
                 v.reshape(B, c_blocks, block_size, cfg.n_kv_heads,
-                          cfg.head_dim), kv_quant)
+                          cfg.head_dim), kv_quant, shardings)
             return (o,)
 
         (x,), _ = _run_layers(
@@ -959,6 +996,11 @@ def _make_token_forward(cfg: LlamaConfig, block_size: int,
                     vs_ = vs_.at[bt].set(vsn)
                 kv[pi] = {"k": kpool, "v": vpool, "ks": ks_, "vs": vs_}
             else:
+                # one token a row through the flat view [N * Bs, Hkv, Dh]:
+                # the same device as _scatter_blocks' view (the leaf's own
+                # byte order, so the donated leaf is written in place and
+                # not re-laid around the write), with the heads kept an
+                # axis, so that TP splits it without a shard_map
                 pool_shape = kv[pi]["k"].shape
                 kflat = kv[pi]["k"].reshape(-1, cfg.n_kv_heads, cfg.head_dim)
                 vflat = kv[pi]["v"].reshape(-1, cfg.n_kv_heads, cfg.head_dim)
@@ -1355,7 +1397,8 @@ def make_fused_step(cfg: LlamaConfig, block_size: int, blocks_per_seq: int,
             kv[li] = _scatter_blocks(
                 kv[li], tbl_chunk,
                 kc.reshape(1, c_blocks, block_size, Hkv, Dh),
-                vc.reshape(1, c_blocks, block_size, Hkv, Dh), kv_quant)
+                vc.reshape(1, c_blocks, block_size, Hkv, Dh), kv_quant,
+                shardings)
             if kv_quant:
                 from ..ops.quant import requantize_block_tokens
 

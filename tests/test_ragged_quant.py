@@ -101,6 +101,64 @@ def test_requantize_single_token_into_empty_block():
 
 
 # ---------------------------------------------------------------------------
+# engine: the block-write seam vs the plain scatter it replaces
+# ---------------------------------------------------------------------------
+
+@pytest.mark.parametrize("tp", [1, 2], ids=["one-device", "tp2"])
+@pytest.mark.parametrize("quant", [False, True], ids=["float", "int8"])
+@pytest.mark.parametrize("heads", [2, 4, 8])
+def test_block_write_seam_equals_plain_scatter(heads, quant, tp):
+    """``_scatter_blocks`` writes through a flat view of each leaf (and,
+    under TP, of each device's own heads): the pool it returns is, bit for
+    bit, what ``leaf.at[tbl].set(blocks)`` returns. Two rows; the second
+    row's table is the null one, so its blocks all land in block 0."""
+    from scalable_hw_agnostic_inference_tpu.engine.runner import (
+        EngineShardings,
+        _scatter_blocks,
+    )
+    from scalable_hw_agnostic_inference_tpu.models.llama import (
+        geometry_params,
+    )
+
+    N, Bs, Dh, m = 12, 8, 16, 3
+    rng = np.random.default_rng(heads)
+    layer = {n: jnp.asarray(rng.normal(size=(N, Bs, heads, Dh)), jnp.float32)
+             for n in ("k", "v")}
+    if quant:
+        layer["k"], layer["ks"] = quantize_kv_blocks(layer["k"])
+        layer["v"], layer["vs"] = quantize_kv_blocks(layer["v"])
+    k, v = (jnp.asarray(rng.normal(size=(2, m, Bs, heads, Dh)), jnp.float32)
+            for _ in range(2))
+    tbl = jnp.asarray([[7, 3, 9], [0, 0, 0]], jnp.int32)
+
+    @jax.jit
+    def plain(layer, tbl, k, v):
+        fresh = {"k": k, "v": v}
+        if quant:
+            fresh["k"], fresh["ks"] = quantize_kv_blocks(k)
+            fresh["v"], fresh["vs"] = quantize_kv_blocks(v)
+        return {n: layer[n].at[tbl].set(fresh[n]) for n in layer}
+
+    want = plain(layer, tbl, k, v)
+    sh = None
+    if tp > 1:
+        cfg = LlamaConfig(vocab_size=64, dim=heads * Dh, n_layers=1,
+                          n_heads=heads, n_kv_heads=heads, head_dim=Dh,
+                          mlp_dim=32)
+        mesh = jax.sharding.Mesh(np.array(jax.devices()[:tp]), ("tp",))
+        sh = EngineShardings(
+            mesh, jax.eval_shape(lambda: geometry_params(cfg)), cfg)
+        layer = jax.device_put(layer, sh.kv_pool(1, quant)[0])
+    got = jax.jit(lambda layer, tbl, k, v: _scatter_blocks(
+        layer, tbl, k, v, quant, sh))(layer, tbl, k, v)
+    assert set(got) == set(want)
+    for n in want:
+        assert got[n].dtype == want[n].dtype
+        np.testing.assert_array_equal(np.asarray(got[n]),
+                                      np.asarray(want[n]), err_msg=n)
+
+
+# ---------------------------------------------------------------------------
 # ops: the pool kernel (interpret) vs the XLA gather reference
 # ---------------------------------------------------------------------------
 
