@@ -12,6 +12,9 @@ from benchmark.spec import Spec
 
 SPEC = Spec()
 RUN = [sys.executable, os.path.join(SPEC.root, "benchmark", "run.py")]
+#: the one dry run of ``_dry_runs`` that is traced, by name (it was the last
+#: of them, and "the last" moves with every cell a later PR appends)
+TRACED = "kimi-linear-48b-a3b-bf16-ep2.prefill-rate-16k"
 
 
 def _run(args, tmp_path, **env):
@@ -23,14 +26,14 @@ def _run(args, tmp_path, **env):
 
 def _dry_runs():
     """The first cell of every configuration and of every traffic mix, in
-    ``BENCHMARK.json``'s order, the last of them traced."""
+    ``BENCHMARK.json``'s order, ``TRACED`` of them traced."""
     cells, configs, mixes = [], set(), set()
     for w in SPEC.bench["workloads"]:
         if w["config"] not in configs or w["traffic"] not in mixes:
             cells.append(w["name"])
         configs.add(w["config"])
         mixes.add(w["traffic"])
-    return [(c, int(c == cells[-1])) for c in cells]
+    return [(c, int(c == TRACED)) for c in cells]
 
 
 @pytest.mark.parametrize("workload,trace", _dry_runs())

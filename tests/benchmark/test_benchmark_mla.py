@@ -29,8 +29,10 @@ MLA = ["mla_decode_share.mla", "mla_decode_hbm_roofline.mla",
 def test_the_benchmark_is_whole_with_the_new_configuration():
     assert SPEC.problems() == []
     entry = [c for c in SPEC.bench["configs"] if c["name"] == NAME][0]
-    assert SPEC.bench["configs"][-1] is entry       # appended, not inserted
-    assert SPEC.bench["workloads"][-1]["name"] == CELL
+    # appended, not inserted: behind the three configurations and four
+    # cells that were there (not necessarily LAST: the next PR appends too)
+    assert SPEC.bench["configs"][3] is entry
+    assert SPEC.bench["workloads"][4]["name"] == CELL
     assert entry["reduced"] == M["reduced"] == ["num_hidden_layers"]
     assert M["published"] == {"num_hidden_layers": 48}
     assert M["model_type"] == "deepseek_v3" and M["chips"] == 1
@@ -67,7 +69,8 @@ def test_the_traffic_is_the_issues(key, value):
 @pytest.mark.parametrize("name", MLA)
 def test_the_new_metrics_are_the_new_cells_alone(name):
     entry, mf = SPEC.metric_entry(name), SPEC.layer_metric(name)
-    assert entry["workloads"] == [CELL] and entry["moves"] == "out_tok_per_s"
+    # the first of its list: a second latent configuration may join
+    assert entry["workloads"][0] == CELL and entry["moves"] == "out_tok_per_s"
     assert name in SPEC.cell_layer_metrics(CELL)
     assert mf["layer"] in ("kernels", "model step")
     if "roofline" in name:

@@ -15,25 +15,34 @@ CELLS = ["trinity-mini-bf16.decode-sat-4k",
 KANANA, TRINITY = (SPEC.config("kanana-2-30b-a3b-bf16"),
                    SPEC.config("trinity-mini-bf16"))
 NEW = ["moe_streamed_share.moe", "moe_streamed_hbm_roofline.moe"]
+#: the cells the benchmark had when these metrics came, the routed two among
+#: them: a later routed cell may join behind ``CELLS``
+KNOWN = ["mistral-7b-int8.decode-sat", "mistral-7b-int8.prefill-rate",
+         "mistral-7b-bf16-tp4.decode-sat"] + CELLS
 
 
 def test_the_benchmark_is_whole_with_the_new_metrics():
     assert SPEC.problems() == []
-    assert [m["name"] for m in SPEC.bench["per_layer"][-2:]] == NEW
+    names = [m["name"] for m in SPEC.bench["per_layer"]]
+    first = names.index(NEW[0])
+    # appended behind the last entry of the PR before, and together (not
+    # necessarily LAST: the next PR appends too)
+    assert names[first - 1] == "latent_visible_mean.mla"
+    assert names[first:first + 2] == NEW
 
 
 @pytest.mark.parametrize("name", NEW)
 def test_the_new_metrics_are_the_routed_cells_alone(name):
     entry, mf = SPEC.metric_entry(name), SPEC.layer_metric(name)
-    assert entry["workloads"] == CELLS
+    assert entry["workloads"][:2] == CELLS
     assert entry["moves"] == mf["moves"] == "out_tok_per_s"
     assert entry["layer"] == mf["layer"] == "kernels"
     assert entry["unit"] == "%" and entry["better"] == "higher"
     for cell in CELLS:
         assert name in SPEC.cell_layer_metrics(cell)
-    for w in SPEC.bench["workloads"]:
-        if w["name"] not in CELLS:
-            assert name not in SPEC.cell_layer_metrics(w["name"])
+    for w in KNOWN:
+        if w not in CELLS:
+            assert name not in SPEC.cell_layer_metrics(w)
 
 
 @pytest.mark.parametrize("cfg,mb", [(KANANA, 9_437_184), (TRINITY, 12_582_912)],

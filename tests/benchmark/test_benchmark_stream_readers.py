@@ -30,7 +30,9 @@ SERVE = ["mistral-7b-int8.decode-sat", "mistral-7b-bf16-tp4.decode-sat",
          "kanana-2-30b-a3b-bf16.decode-sat-8k"]
 RATE = ["mistral-7b-int8.prefill-rate",
         "kimi-linear-48b-a3b-bf16-ep2.prefill-rate-16k"]
-ALL = [w["name"] for w in SPEC.bench["workloads"]]
+#: the six cells the benchmark had when these metrics came (PR 38), in
+#: ``BENCHMARK.json``'s order: a later cell may join any list behind them
+KNOWN = [SERVE[0], RATE[0], SERVE[1], SERVE[2], SERVE[3], RATE[1]]
 HTTP, ADM = "HTTP and lanes", "admission and scheduler"
 #: metric -> (reader kind, source, layer, moves, cells)
 NEW = {
@@ -271,16 +273,19 @@ def test_a_new_metric_names_a_reader_and_keys_that_exist(name):
 
 @pytest.mark.parametrize("name", sorted(NEW))
 def test_a_new_metric_is_reported_in_its_cells_alone(name):
+    """Of the cells that were there: its list starts with them, in their
+    order, and none of the others reports it."""
     cells = NEW[name][-1]
-    assert SPEC.metric_entry(name)["workloads"] == cells
-    assert [w for w in ALL if name in SPEC.cell_layer_metrics(w)] == cells
+    assert SPEC.metric_entry(name)["workloads"][:len(cells)] == cells
+    assert [w for w in KNOWN if name in SPEC.cell_layer_metrics(w)] == cells
 
 
 def test_the_suffixes_are_the_cells_of_their_end_to_end_metric():
     by = {m["name"]: m for m in SPEC.bench["end_to_end"]}
-    assert by["out_tok_per_s"]["workloads"] == SERVE
-    assert by["ttft_p90_ms"]["workloads"] == RATE
-    assert by["gap_p95_ms"]["workloads"] == RATE
+    # each list starts with the accepted cells; a later cell stands behind
+    assert by["out_tok_per_s"]["workloads"][:len(SERVE)] == SERVE
+    assert by["ttft_p90_ms"]["workloads"][:len(RATE)] == RATE
+    assert by["gap_p95_ms"]["workloads"][:len(RATE)] == RATE
     assert sorted(n for n in NEW if n.endswith(".serve")) == sorted(
         n for n, v in NEW.items() if v[-1] == SERVE)
     assert sorted(n for n in NEW if n.endswith(".rate")) == sorted(
