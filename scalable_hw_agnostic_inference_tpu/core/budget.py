@@ -270,10 +270,12 @@ def causal_lm_budget(cfg, ecfg, *, hbm_gib_per_chip: float = HBM_GIB["v5e"],
 
     # paged KV pool (engine.runner allocation): self-attn layers only —
     # cross layers hold the per-slot vision KV counted separately below
-    # and layers that have cache rows only: a recurrent (KDA) layer costs
-    # the pool nothing and every slot a state (priced below)
-    n_state = len(getattr(cfg, "kda_layers", ()))
-    n_self = cfg.n_layers - len(cfg.cross_attention_layers) - n_state
+    # and layers that have cache rows only: a recurrent layer (KDA, a
+    # state-space mixer) costs the pool nothing and every slot a state
+    # (priced below); a block that is a feed-forward part alone, neither
+    n_state = len(getattr(cfg, "state_layers", ()))
+    n_self = getattr(cfg, "n_paged_layers",
+                     cfg.n_layers - len(cfg.cross_attention_layers))
     num_blocks = ecfg.num_blocks or (
         ecfg.max_model_len * ecfg.max_num_seqs // ecfg.block_size)
     kv_heads_chip = (cfg.n_kv_heads // tp if cfg.n_kv_heads % tp == 0

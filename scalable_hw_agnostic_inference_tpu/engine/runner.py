@@ -25,7 +25,7 @@ import jax.numpy as jnp
 from jax.sharding import NamedSharding, PartitionSpec as P
 
 from ..models.llama import LlamaConfig
-from ..ops import kda, mla
+from ..ops import kda, mla, ssm
 from ..ops.attention import dot_product_attention, on_tpu_platform
 from ..ops.moe import expert_layer, gated_mlp
 from ..ops.quant import quant_matmul
@@ -100,8 +100,13 @@ def _proj(x: jax.Array, p: Dict[str, jax.Array]) -> jax.Array:
     return quant_matmul(x, p)
 
 
-def _mlp(lp: Dict, x: jax.Array) -> jax.Array:
-    return gated_mlp(lp["mlp"], x)
+def _mlp(lp: Dict, x: jax.Array, act: str = "silu") -> jax.Array:
+    return gated_mlp(lp["mlp"], x, act)
+
+
+#: a recurrent KIND's two phases (``prefill``, ``decode``), by
+#: ``LlamaConfig.state_kind``
+_RECURRENT = {"kda": kda, "ssm": ssm}
 
 
 def _head_rmsnorm(x: jax.Array, scale: jax.Array, eps: float) -> jax.Array:
@@ -148,20 +153,25 @@ class LayerKind:
     """What one layer is, read off the model config (never a model's
     name): gated cross-attention over vision states or self-attention;
     the keys a query sees behind it (0 = all); rotary embedding or none;
-    a routed FFN or the dense MLP; ``kda``: linear attention (``ops.kda``),
-    whose state is a slot's and not the pool's."""
+    a routed FFN or the dense MLP; ``state``: a recurrent mixer (linear
+    attention, ``ops.kda``, or a state-space one, ``ops.ssm``), whose state
+    is a slot's and not the pool's; ``part``: ``"mixer"`` or ``"ffn"``
+    where the block is that part ALONE behind one norm, ``""`` where it is
+    a mixer then a feed-forward part."""
     cross: bool = False
     window: int = 0
     rope: bool = True
     moe: bool = False
-    kda: bool = False
+    state: bool = False
+    part: str = ""
 
 
 def layer_kinds(cfg: LlamaConfig) -> List[LayerKind]:
     cross = set(cfg.cross_attention_layers)
     return [LayerKind(cross=li in cross, window=cfg.window_of(li),
                       rope=cfg.rope_of(li), moe=cfg.moe_of(li),
-                      kda=cfg.kda_of(li))
+                      state=cfg.state_of(li),
+                      part=cfg.part_of(li))
             for li in range(cfg.n_layers)]
 
 
@@ -179,11 +189,14 @@ def _layer(lp: Dict, kind: LayerKind, xs, positions, attend,
     sees; tuples in, a tuple of ``[B, T, H, Dh]`` out. With latent
     attention (``cfg.latent``, the attention KIND) ``ks`` are the tokens'
     cache rows ``[B, T, latent_width]`` and ``vs`` is the layer's ``kv_b``
-    leaf, which the program expands or absorbs as its phase wants. In a KDA
-    layer (``kind.kda``) ``qs`` are the NORMED streams ``[B, T, dim]`` and
-    ``ks`` the layer's attention leaves: the program's closure makes the
-    recurrence's operands (``ops.kda.inputs``), runs its phase of it over
-    its slots and hands back the gated outputs ``[B, T, H * d]``. ``cross``:
+    leaf, which the program expands or absorbs as its phase wants. In a
+    recurrent layer (``kind.state``) ``qs`` are the NORMED streams ``[B, T,
+    dim]`` and ``ks`` the layer's mixer leaves: the program's closure runs
+    its phase of the model's recurrent kind over its slots (``prefill`` or
+    ``decode`` of ``ops.kda`` / ``ops.ssm``) and hands back the gated
+    outputs ``[B, T, H * d]``. A block of ONE part (``kind.part``) has one
+    norm (``lp["norm"]``) and one residual add: the mixer alone, or the
+    feed-forward part alone (``attend`` is then not called). ``cross``:
     ``(k, v, has_image, cross_len)`` of a cross layer, which attends those
     and touches no pool. ``active``: per stream, the rows that hold a real
     token (bool, ``[B, T]``); padded rows route to no expert.
@@ -195,12 +208,23 @@ def _layer(lp: Dict, kind: LayerKind, xs, positions, attend,
         return tuple(_cross_layer(lp, x, ck, cv, has_image, cfg,
                                   cross_len=cross_len, shardings=shardings)
                      for x in xs), None
+    if kind.part == "ffn":
+        out, stats = [], None
+        for i, x in enumerate(xs):
+            f, st = _ffn(lp, kind, _rmsnorm(x, lp["norm"]["scale"],
+                                            cfg.rms_eps), cfg,
+                         None if active is None else active[i])
+            if st is not None:
+                stats = st if stats is None else stats + st
+            out.append(x + f)
+        return tuple(out), stats
     at, Dh = lp["attn"], cfg.head_dim
+    first_norm = lp["norm" if kind.part else "attn_norm"]["scale"]
     qs, ks, vs, gates = [], [], [], []
     for x, pos in zip(xs, positions):
         B, T, _ = x.shape
-        h = _rmsnorm(x, lp["attn_norm"]["scale"], cfg.rms_eps)
-        if kind.kda:
+        h = _rmsnorm(x, first_norm, cfg.rms_eps)
+        if kind.state:
             qs.append(h), gates.append(None)
             continue
         q = _proj(h, at["q"]).reshape(B, T, cfg.n_heads, Dh)
@@ -218,7 +242,7 @@ def _layer(lp: Dict, kind: LayerKind, xs, positions, attend,
             k = apply_rope(k, pos, cfg.rope_theta, cfg.rope_scaling)
         qs.append(q), ks.append(k), vs.append(v)
         gates.append(_proj(h, at["gate"]) if cfg.attn_gate else None)
-    if kind.kda:
+    if kind.state:
         os = attend(tuple(qs), at, None, 0)
     else:
         os = attend(tuple(qs), tuple(ks),
@@ -233,27 +257,35 @@ def _layer(lp: Dict, kind: LayerKind, xs, positions, attend,
         if cfg.sandwich_norms:
             h = _rmsnorm(h, lp["post_attn_norm"]["scale"], cfg.rms_eps)
         x = x + h
+        if kind.part == "mixer":
+            out.append(x)
+            continue
         m = _rmsnorm(x, lp["mlp_norm"]["scale"], cfg.rms_eps)
-        if kind.moe:
-            f, st = expert_layer(
-                lp["moe"], m, cfg,
-                active=None if active is None else active[i],
-                held=cfg.held)
+        f, st = _ffn(lp, kind, m, cfg,
+                     None if active is None else active[i])
+        if st is not None:
             stats = st if stats is None else stats + st
-        else:
-            f = _mlp(lp, m)
         if cfg.sandwich_norms:
             f = _rmsnorm(f, lp["post_mlp_norm"]["scale"], cfg.rms_eps)
         out.append(x + f)
     return tuple(out), stats
 
 
+def _ffn(lp: Dict, kind: LayerKind, m: jax.Array, cfg: LlamaConfig, active):
+    """A layer's feed-forward part on the normed stream ``m``: the routed
+    experts (``(f, stats)``) or the dense MLP (``(f, None)``)."""
+    if kind.moe:
+        return expert_layer(lp["moe"], m, cfg, active=active, held=cfg.held)
+    return _mlp(lp, m, cfg.mlp_act), None
+
+
 def _run_layers(p: Dict, cfg: LlamaConfig, xs, positions, attend, *,
-                cross=None, active=None, shardings=None, attend_kda=None):
+                cross=None, active=None, shardings=None, attend_state=None):
     """Walk the stack through :func:`_layer`. ``attend(pi, qs, ks, vs,
-    window)`` gets the layer's POOL index first (cross layers own no pool
-    entry; a KDA layer's entry of the state list is its slot arena, and
-    ``attend_kda`` is its closure); ``cross(ci) -> (k, v, has_image,
+    window)`` gets the layer's POOL index first (cross layers and blocks
+    that are a feed-forward part alone own no pool entry; a recurrent
+    layer's entry of the state list is its slot arena, and ``attend_state``
+    is its closure); ``cross(ci) -> (k, v, has_image,
     cross_len)`` serves the ``ci``-th cross layer. Returns ``(xs, stats)``,
     the routed layers' stats summed (``None`` with no routed layer)."""
     ci = pi = 0
@@ -265,10 +297,15 @@ def _run_layers(p: Dict, cfg: LlamaConfig, xs, positions, attend, *,
                            cross=cross(ci), shardings=shardings)
             ci += 1
             continue
-        xs, st = _layer(lp, kind, xs, positions,
-                        functools.partial(attend_kda if kind.kda else attend,
-                                          pi), cfg, active=active)
-        pi += 1
+        if kind.part == "ffn":
+            xs, st = _layer(lp, kind, xs, positions, None, cfg,
+                            active=active)
+        else:
+            xs, st = _layer(
+                lp, kind, xs, positions,
+                functools.partial(attend_state if kind.state else attend,
+                                  pi), cfg, active=active)
+            pi += 1
         if st is not None:
             stats = st if stats is None else stats + st
     return xs, stats
@@ -458,15 +495,6 @@ def _scatter_blocks(kv_layer: Dict, tbl: jax.Array, k: jax.Array,
         out_specs=specs, check_vma=False)(kv_layer, tbl, fresh)
 
 
-def _write_slots(state: Dict, slots: jax.Array, s: jax.Array,
-                 tail: jax.Array) -> Dict:
-    """A KDA layer's slot arena with ``slots``' states and tails replaced
-    (``[K, ...]`` each): THE write seam of prefill and continuation. A
-    dummy row's slot is the null slot (the arena's last)."""
-    return {"s": state["s"].at[slots].set(s.astype(state["s"].dtype)),
-            "t": state["t"].at[slots].set(tail.astype(state["t"].dtype))}
-
-
 def _pool_scales(kv_layer: Dict):
     """``(k_scale, v_scale)`` of an int8 pool layer, ``(None, None)`` for a
     float pool — the read-side twin of :func:`_scatter_blocks`."""
@@ -531,16 +559,16 @@ def make_prefill(cfg: LlamaConfig, block_size: int, blocks_per_seq: int,
                 B, m_used, block_size, -1).astype(pool.dtype))}
             return (o,)
 
-        def attend_kda(pi, hs, at, _vs, _window):
+        def attend_state(pi, hs, at, _vs, _window):
             # from position 0 the scan starts from a ZERO state and a zero
             # tail, whatever the slot held: a reused slot needs no clearing.
             # The bucket's padded tail is identity tokens, so what is
             # written is the state and tail the last REAL token left
             (h,) = hs
-            q, k, v, g, beta, tail = kda.inputs(at, h, None, n, cfg)
-            o, s = kda.scan(q, k, v, g, beta, kernel=on_tpu_platform())
-            kv[pi] = _write_slots(kv[pi], slots, s, tail)
-            return (kda.output(at, h, o, cfg),)
+            o, kv[pi] = _RECURRENT[cfg.state_kind].prefill(
+                at, h, kv[pi], slots, n, cfg, carry=False,
+                kernel=on_tpu_platform())
+            return (o,)
 
         def attend(pi, qs, ks, vs, window):
             (q,), (k,), (v,) = qs, ks, vs
@@ -569,7 +597,7 @@ def make_prefill(cfg: LlamaConfig, block_size: int, blocks_per_seq: int,
             cross=lambda ci: (cross_kv[ci]["k"], cross_kv[ci]["v"],
                               has_image, cross_len),
             active=(positions < n[:, None],), shardings=shardings,
-            attend_kda=attend_kda)
+            attend_state=attend_state)
         last = jnp.take_along_axis(x, (n - 1).reshape(B, 1, 1), axis=1)
         return kv, _logits(p, last, cfg)[:, 0]  # [B, V]
 
@@ -803,16 +831,14 @@ def make_prefill_cont(cfg: LlamaConfig, block_size: int, blocks_per_seq: int,
                 B, c_blocks, block_size, -1).astype(pool.dtype))}
             return (o,)
 
-        def attend_kda(pi, hs, at, _vs, _window):
+        def attend_state(pi, hs, at, _vs, _window):
             # a continuation chunk's prefix is the SLOT's state and tail,
             # not the pool: read, scanned over the chunk, written back
             (h,) = hs
-            q, k, v, g, beta, tail = kda.inputs(
-                at, h, kv[pi]["t"][slots], n_text, cfg)
-            o, s = kda.scan(q, k, v, g, beta, kv[pi]["s"][slots],
-                            kernel=on_tpu_platform())
-            kv[pi] = _write_slots(kv[pi], slots, s, tail)
-            return (kda.output(at, h, o, cfg),)
+            o, kv[pi] = _RECURRENT[cfg.state_kind].prefill(
+                at, h, kv[pi], slots, n_text, cfg, carry=True,
+                kernel=on_tpu_platform())
+            return (o,)
 
         def attend(pi, qs, ks, vs, window):
             (q,), (k,), (v,) = qs, ks, vs
@@ -850,7 +876,7 @@ def make_prefill_cont(cfg: LlamaConfig, block_size: int, blocks_per_seq: int,
             cross=lambda ci: (cross_kv[ci]["k"], cross_kv[ci]["v"],
                               has_image, cross_len),
             active=(offs < n_text[:, None],), shardings=shardings,
-            attend_kda=attend_kda)
+            attend_state=attend_state)
         last = jnp.take_along_axis(x, (n_text - 1).reshape(B, 1, 1), axis=1)
         return kv, _logits(p, last, cfg)[:, 0]  # [B, V]
 
@@ -956,21 +982,17 @@ def _make_token_forward(cfg: LlamaConfig, block_size: int,
                 paged=paged)
             return (mla.unabsorb(u, kv_b, cfg),)
 
-        def attend_kda(pi, hs, at, _vs, _window):
+        def attend_state(pi, hs, at, _vs, _window):
             # one recurrent step a row, in place on the row's slot; a
             # padded or finished row steps the NULL slot (the arena's last),
             # so no sequence's state or tail is touched for it
             assert T == 1, "one token a step over recurrent state"
             (h,) = hs
-            arena, tails = kv[pi]["s"], kv[pi]["t"]
-            slots = jnp.where(active > 0, slot_idx, arena.shape[0] - 1)
-            q, k, v, g, beta, tail = kda.inputs(at, h, tails[slots], None,
-                                                cfg)
-            o, arena = kda.step_slots(q[:, 0], k[:, 0], v[:, 0], g[:, 0],
-                                      beta[:, 0], arena, slots, kernel=paged)
-            kv[pi] = {"s": arena,
-                      "t": tails.at[slots].set(tail.astype(tails.dtype))}
-            return (kda.output(at, h, o[:, None], cfg),)
+            slots = jnp.where(active > 0, slot_idx,
+                              kv[pi]["s"].shape[0] - 1)
+            o, kv[pi] = _RECURRENT[cfg.state_kind].decode(
+                at, h, kv[pi], slots, cfg, kernel=paged)
+            return (o,)
 
         def attend(pi, qs, ks, vs, window):
             (q,), (kk,), (vv,) = qs, ks, vs
@@ -1042,7 +1064,7 @@ def _make_token_forward(cfg: LlamaConfig, block_size: int,
                               cross_len),
             active=None if active is None or not cfg.n_experts else (
                 jnp.broadcast_to(active[:, None] > 0, (B, T)),),
-            shardings=shardings, attend_kda=attend_kda)
+            shardings=shardings, attend_state=attend_state)
         return kv, _logits(p, x, cfg), stats  # [B, T, V] f32
 
     return fwd

@@ -46,6 +46,15 @@ Cache = List[LayerCache]
 #: AFMoE's attention pattern: three window layers, then one full layer
 _AFMOE_PERIOD = ("sliding_attention",) * 3 + ("full_attention",)
 
+#: a hybrid pattern's letters (HF ``hybrid_override_pattern``): what part a
+#: block IS (``block_parts``) and what its mixer is (``layer_types``)
+_HYBRID_LETTERS = {"M": ("mixer", "state_space"),
+                   "*": ("mixer", "full_attention"),
+                   "E": ("ffn", "none"), "-": ("ffn", "none")}
+
+#: Nemotron-3-Nano's 52 blocks, and the first nine of them
+_NEMOTRON3_PATTERN = "MEMEM*EMEMEM*EMEMEM*EMEMEM*EMEMEM*EMEMEMEM*EMEMEMEME"
+
 
 @dataclasses.dataclass(frozen=True)
 class LlamaConfig:
@@ -73,8 +82,10 @@ class LlamaConfig:
     # -- what a layer IS, as data (``layer_kind``): the engine's one layer
     # function reads these and no model's name --------------------------
     # per-layer attention kind, HF's names: "sliding_attention" (a window
-    # of ``sliding_window`` keys), "full_attention", or "linear_attention"
-    # (KDA, ``ops.kda``: recurrent slot state, no cache rows); () = all full
+    # of ``sliding_window`` keys), "full_attention", "linear_attention"
+    # (KDA, ``ops.kda``: recurrent slot state, no cache rows) or
+    # "state_space" (a Mamba-2 mixer, ``ops.ssm``: the same kind of state);
+    # () = all full; "none" where the block has no mixer (``block_parts``)
     layer_types: Tuple[str, ...] = ()
     sliding_window: int = 0
     # full-attention layers carry rotary embedding (False: none at all)
@@ -119,6 +130,28 @@ class LlamaConfig:
     # the router scores: this chip's share of an expert-parallel layout
     # (the stacked expert leaves are that slice); () = all of them
     experts_held: Tuple[int, ...] = ()
+    # -- what a BLOCK is, as data: () = every layer is a mixer THEN a
+    # feed-forward part, each behind its own norm; else, per layer,
+    # "mixer" (ONE norm, the mixer ``layer_types`` names, ONE residual add)
+    # or "ffn" (one norm, the routed or dense feed-forward part, one add).
+    # An "ffn" block costs neither the pool nor a slot anything
+    block_parts: Tuple[str, ...] = ()
+    # the feed-forward parts' form, experts, shared expert and dense MLP
+    # alike: "silu" is ``Down(silu(Gate x) * Up x)``, three matrices;
+    # "relu2" is ``Down(relu(Up x) ** 2)``, two (no gate leaf)
+    mlp_act: str = "silu"
+    # the shared expert's width; 0 = ``moe_mlp_dim * n_shared_experts``
+    shared_mlp_dim: int = 0
+    # -- "state_space" layers (Mamba-2): ``ssm_heads`` heads of
+    # ``ssm_head_dim`` channels over ``ssm_state`` state lanes, ``B`` and
+    # ``C`` shared by the heads of each of ``ssm_groups`` groups, behind ONE
+    # causal depthwise convolution (with bias) of ``ssm_conv`` taps over x,
+    # B and C together. Their per-sequence state is a SLOT's
+    ssm_heads: int = 0
+    ssm_head_dim: int = 0
+    ssm_state: int = 0
+    ssm_groups: int = 1
+    ssm_conv: int = 4
 
     def __post_init__(self):
         # sequence fields normalize to tuples so configs hash and compare
@@ -135,12 +168,21 @@ class LlamaConfig:
         if not isinstance(self.experts_held, tuple):
             object.__setattr__(self, "experts_held",
                                tuple(self.experts_held))
+        if not isinstance(self.block_parts, tuple):
+            object.__setattr__(self, "block_parts", tuple(self.block_parts))
         if self.head_dim is None:
             object.__setattr__(self, "head_dim", self.dim // self.n_heads)
         if self.layer_types and len(self.layer_types) != self.n_layers:
             raise ValueError(
                 f"layer_types names {len(self.layer_types)} layers, "
                 f"n_layers is {self.n_layers}")
+        if self.block_parts and (
+                len(self.block_parts) != self.n_layers
+                or set(self.block_parts) - {"mixer", "ffn"}):
+            raise ValueError(
+                f"block_parts names {len(self.block_parts)} blocks of "
+                f"{sorted(set(self.block_parts))}, n_layers is "
+                f"{self.n_layers} and a part is 'mixer' or 'ffn'")
         if self.latent and (
                 self.head_dim != self.qk_nope_head_dim + self.qk_rope_head_dim
                 or self.n_kv_heads != self.n_heads or not self.v_head_dim):
@@ -172,27 +214,73 @@ class LlamaConfig:
     def rope_of(self, li: int) -> bool:
         return bool(self.window_of(li) or self.rope_on_full_attention)
 
+    def part_of(self, li: int) -> str:
+        """``"mixer"`` or ``"ffn"`` where block ``li`` is that part ALONE,
+        ``""`` where it is a mixer then a feed-forward part."""
+        return self.block_parts[li] if self.block_parts else ""
+
     def moe_of(self, li: int) -> bool:
-        return bool(self.n_experts) and li >= self.n_dense_layers
+        return (bool(self.n_experts) and li >= self.n_dense_layers
+                and self.part_of(li) != "mixer")
 
     def kda_of(self, li: int) -> bool:
         """Layer ``li`` is linear attention: slot state, no cache rows."""
         return bool(self.layer_types) and (
             self.layer_types[li] == "linear_attention")
 
+    def ssm_of(self, li: int) -> bool:
+        """Layer ``li`` is a state-space mixer: slot state, no cache rows."""
+        return bool(self.layer_types) and (
+            self.layer_types[li] == "state_space")
+
+    def state_of(self, li: int) -> bool:
+        """Layer ``li`` keeps recurrent slot state, whatever its kind."""
+        return self.kda_of(li) or self.ssm_of(li)
+
+    @property
+    def _pool_order(self) -> List[int]:
+        """The layers that own an entry of the engine's per-layer state
+        list, in order: every block with a mixer but the cross layers."""
+        return [li for li in range(self.n_layers)
+                if li not in self.cross_attention_layers
+                and self.part_of(li) != "ffn"]
+
     @property
     def kda_layers(self) -> Tuple[int, ...]:
-        """Pool indices (cross layers own none) of the KDA layers: where
-        the engine's per-layer state list holds a slot arena and no
-        blocks."""
-        pool = [li for li in range(self.n_layers)
-                if li not in self.cross_attention_layers]
-        return tuple(pi for pi, li in enumerate(pool) if self.kda_of(li))
+        """Pool indices (cross layers and blocks without a mixer own none)
+        of the KDA layers: where the engine's per-layer state list holds a
+        slot arena and no blocks."""
+        return tuple(pi for pi, li in enumerate(self._pool_order)
+                     if self.kda_of(li))
+
+    @property
+    def state_layers(self) -> Tuple[int, ...]:
+        """Pool indices of every layer that keeps recurrent slot state,
+        whatever its kind."""
+        return tuple(pi for pi, li in enumerate(self._pool_order)
+                     if self.state_of(li))
+
+    @property
+    def state_kind(self) -> str:
+        """``"kda"``, ``"ssm"`` or ``""``: the recurrent kind of this
+        model's slot state (a model has one)."""
+        if not self.state_layers:
+            return ""
+        return "kda" if self.kda_layers else "ssm"
+
+    @property
+    def n_paged_layers(self) -> int:
+        """Layers whose tokens cost the paged pool rows."""
+        return len(self._pool_order) - len(self.state_layers)
 
     @property
     def recurrent(self) -> bool:
         """Some layer keeps recurrent slot state."""
-        return bool(self.kda_layers)
+        return bool(self.state_layers)
+
+    @property
+    def shared_width(self) -> int:
+        return self.shared_mlp_dim or self.moe_mlp_dim * self.n_shared_experts
 
     @property
     def held(self) -> Optional[Tuple[int, int]]:
@@ -206,9 +294,8 @@ class LlamaConfig:
     @property
     def window_layers(self) -> Tuple[int, ...]:
         """Pool indices (cross layers own none) of the window layers."""
-        pool = [li for li in range(self.n_layers)
-                if li not in self.cross_attention_layers]
-        return tuple(pi for pi, li in enumerate(pool) if self.window_of(li))
+        return tuple(pi for pi, li in enumerate(self._pool_order)
+                     if self.window_of(li))
 
     @property
     def n_moe_layers(self) -> int:
@@ -220,7 +307,8 @@ class LlamaConfig:
         (the contiguous-cache flax module does not)."""
         return bool(self.n_experts or self.layer_types or self.qk_norm
                     or self.attn_gate or self.sandwich_norms
-                    or self.embed_scale or self.latent or self.recurrent)
+                    or self.embed_scale or self.latent or self.recurrent
+                    or self.block_parts or self.mlp_act != "silu")
 
     @classmethod
     def tiny(cls) -> "LlamaConfig":
@@ -408,6 +496,65 @@ class LlamaConfig:
             route_norm=True, route_scale=2.446, kv_lora_rank=32,
             qk_nope_head_dim=16, qk_rope_head_dim=8, v_head_dim=16,
             kda_heads=4, kda_head_dim=16, kda_conv=4, experts_held=(0, 8))
+
+    @classmethod
+    def hybrid(cls, pattern: str, **kw) -> "LlamaConfig":
+        """A model whose blocks are ONE part each, by a pattern's letters
+        (HF ``hybrid_override_pattern``): ``M`` a state-space mixer, ``*``
+        attention, ``E`` (or ``-``) a feed-forward part."""
+        parts, kinds = zip(*(_HYBRID_LETTERS[c] for c in pattern))
+        return cls(n_layers=len(pattern), block_parts=parts,
+                   layer_types=kinds, **kw)
+
+    @classmethod
+    def nemotron3_nano(cls, pattern: str = _NEMOTRON3_PATTERN,
+                       experts_held: Tuple[int, ...] = ()) -> "LlamaConfig":
+        """NVIDIA-Nemotron-3-Nano-30B-A3B (``model_type: nemotron_h``)
+        geometry: 52 blocks of ONE part each (one norm, one residual add):
+        23 Mamba-2 mixers (64 heads of 64 over 128 state lanes, 8 groups of
+        B and C, a convolution of 4 with bias: a float32 state of 64 x 64 x
+        128 a sequence a block), 6 attention blocks (32 query heads over 2
+        key/value heads of 128, NO positional embedding) and 23 routed
+        blocks (128 sigmoid-routed experts of TWO matrices 2688 x 1856 with
+        ``relu ** 2`` between, 6 a token, renormalised and scaled by 2.5,
+        beside one shared expert of 3712); a 131k vocabulary, untied. Whole
+        it is 63 GB in bf16; ``pattern`` cuts the depth, ``experts_held``
+        the experts to one chip's share."""
+        return cls.hybrid(
+            pattern, vocab_size=131072, dim=2688, n_heads=32, n_kv_heads=2,
+            head_dim=128, mlp_dim=1856, max_seq_len=262144,
+            rope_theta=10000.0, rms_eps=1e-5, rope_on_full_attention=False,
+            n_experts=128, n_experts_per_tok=6, n_shared_experts=1,
+            moe_mlp_dim=1856, shared_mlp_dim=3712, route_norm=True,
+            route_scale=2.5, mlp_act="relu2", ssm_heads=64, ssm_head_dim=64,
+            ssm_state=128, ssm_groups=8, ssm_conv=4,
+            experts_held=experts_held)
+
+    @classmethod
+    def nemotron3_nano_stage(cls) -> "LlamaConfig":
+        """One chip's share of a stage of Nemotron-3-Nano-30B-A3B: the
+        embedding, the head and the model's first nine blocks (``MEMEM*EME``:
+        four mixers, four routed blocks, one attention block), the routed
+        blocks divided over TWO chips by experts: 64 of the 128 held here.
+        Not a servable whole model: 7.0 GB of 63 GB."""
+        return cls.nemotron3_nano(_NEMOTRON3_PATTERN[:9], (0, 64))
+
+    @classmethod
+    def tiny_ssm(cls) -> "LlamaConfig":
+        """CI-tier stand-in with Nemotron-3-Nano's mechanisms in the cut's
+        pattern (``MEMEM*EME``): blocks of one part each, state-space
+        mixers of 4 heads of 16 over 32 state lanes in 2 groups behind a
+        convolution of 4, one attention block of 4 heads over 2 without
+        positional embedding, 16 two-matrix ``relu ** 2`` experts top-6
+        beside a shared one of twice their width, 8 of them held here."""
+        return cls.hybrid(
+            _NEMOTRON3_PATTERN[:9], vocab_size=512, dim=64, n_heads=4,
+            n_kv_heads=2, head_dim=32, mlp_dim=16, max_seq_len=8192,
+            rope_theta=10000.0, rms_eps=1e-5, rope_on_full_attention=False,
+            n_experts=16, n_experts_per_tok=6, n_shared_experts=1,
+            moe_mlp_dim=16, shared_mlp_dim=32, route_norm=True,
+            route_scale=2.5, mlp_act="relu2", ssm_heads=4, ssm_head_dim=16,
+            ssm_state=32, ssm_groups=2, ssm_conv=4, experts_held=(0, 8))
 
     @classmethod
     def llama3_70b(cls) -> "LlamaConfig":
@@ -671,9 +818,10 @@ def cache_leaves(cfg: LlamaConfig, li: Optional[int] = None
     shape behind ``[num_blocks, block_size]``. Per-head keys and values,
     or — latent attention — one leaf ``c`` of ``latent_width`` lanes: the
     normed latent, the shared rotary key, zeros to a lane multiple. ``li``
-    names the layer: a KDA layer costs the pool nothing (``{}``; what it
-    costs a SLOT is ``state_leaves``). ``None``: a layer that has rows."""
-    if li is not None and cfg.kda_of(li):
+    names the layer: a KDA or state-space layer costs the pool nothing
+    (``{}``; what it costs a SLOT is ``state_leaves``), and so does a block
+    that is a feed-forward part alone. ``None``: a layer that has rows."""
+    if li is not None and (cfg.state_of(li) or cfg.part_of(li) == "ffn"):
         return {}
     if cfg.latent:
         return {"c": (cfg.latent_width,)}
@@ -681,13 +829,18 @@ def cache_leaves(cfg: LlamaConfig, li: Optional[int] = None
             "v": (cfg.n_kv_heads, cfg.head_dim)}
 
 
-def state_leaves(cfg: LlamaConfig) -> Dict[str, Tuple[Tuple[int, ...], str]]:
-    """What ONE slot costs in ONE KDA layer, by leaf: ``(shape, dtype)``
-    behind ``[slots]`` (``ops.kda.state_shapes``); ``{}`` for a model with
-    no such layer."""
-    if not cfg.recurrent:
+def state_leaves(cfg: LlamaConfig, li: Optional[int] = None
+                 ) -> Dict[str, Tuple[Tuple[int, ...], str]]:
+    """What ONE slot costs in ONE recurrent layer, by leaf: ``(shape,
+    dtype)`` behind ``[slots]``, as the model's recurrent KIND says
+    (``ops.kda.state_shapes``, ``ops.ssm.state_shapes``); ``{}`` for a
+    model with no such layer, and for layer ``li`` where it is none."""
+    if not cfg.recurrent or (li is not None and not cfg.state_of(li)):
         return {}
-    from ..ops.kda import state_shapes
+    if cfg.state_kind == "ssm":
+        from ..ops.ssm import state_shapes
+    else:
+        from ..ops.kda import state_shapes
 
     return state_shapes(cfg)
 
@@ -782,6 +935,33 @@ KDA_CONV_STD = 0.29
 KDA_A_RANGE = (1.0, 16.0)
 KDA_DT_RANGE = (1e-3, 1e-1)
 
+#: a state-space mixer's seeded leaves that are not N(0, std), as the public
+#: ``modeling_nemotron_h.py`` / ``mamba_ssm`` initialisation draws them: the
+#: depthwise convolution's taps and bias uniform on +-0.5 (a ``Conv1d`` of
+#: 4 taps a channel: +-1/sqrt(4)); ``A`` = 1 .. heads (``A_log`` its log);
+#: ``softplus(dt_bias)`` log-uniform in (``time_step_min``,
+#: ``time_step_max``) and never under ``time_step_floor``; ``D`` = 1. A head
+#: then remembers from a fraction of a token (A 64, dt 0.1) to a thousand
+#: (A 1, dt 0.001).
+SSM_CONV_RANGE = (-0.5, 0.5)
+SSM_DT_RANGE = (1e-3, 1e-1)
+SSM_DT_FLOOR = 1e-4
+
+#: the ``down`` leaf of a two-matrix ``relu ** 2`` part, as a multiple of the
+#: tier's deviation. At one deviation throughout, ``relu(u) ** 2`` of a
+#: unit-deviation ``u`` has a fourth-moment-sized second moment (1.5 against
+#: a gated part's 0.17 for ``silu(g) * u``), so an expert returns five times
+#: what a gated expert of the other routed configurations returns (an RMS of
+#: 1.1 of the stream's 4 at 2688 x 1856) and ONE flipped choice of six (the
+#: bfloat16 stream against the float32 reference, at a near-tie of two
+#: scores) moves the stream by a sixth: the reference check's largest
+#: difference then read 0.6-1.2 on sixteen weight seeds and 1.95, over its
+#: bound, on a seventeenth (PERF.md section 6, PR 45). A quarter gives an
+#: expert's output the size a gated expert's has (0.28), as a trained
+#: model's experts are small beside its residual stream. Speed does not
+#: depend on it.
+RELU2_DOWN_GAIN = 0.25
+
 #: geometry-tier weight statistics: float kernels ~ N(0, GEOMETRY_STD);
 #: int8 kernels uniform on [-127, 127] under ONE constant per-channel scale
 #: chosen so the dequantized weights have the same standard deviation
@@ -831,7 +1011,7 @@ def geometry_params(cfg: LlamaConfig, dtype=jnp.bfloat16,
             "cache)")
     if cfg.recurrent and (quant or mesh is not None):
         raise ValueError(
-            "KDA layers have no int8 weights and no sharding plan yet "
+            "recurrent layers have no int8 weights and no sharding plan yet "
             "(quantization: int8 / tensor_parallel_size > 1 with recurrent "
             "state)")
     D, HD = cfg.dim, cfg.head_dim
@@ -878,23 +1058,31 @@ def geometry_params(cfg: LlamaConfig, dtype=jnp.bfloat16,
                                     (cfg.vocab_size, D), dtype)},
         "final_norm": norm("final_norm"),
     }
+    # a gated part has three matrices, a "relu2" part two (no gate)
+    firsts = ("up",) if cfg.mlp_act == "relu2" else ("gate", "up")
+
+    down_gain = RELU2_DOWN_GAIN if cfg.mlp_act == "relu2" else 1.0
+
     def mlp(path: str, width: int):
-        return {"gate": lin(f"{path}/gate", D, width),
-                "up": lin(f"{path}/up", D, width),
-                "down": lin(f"{path}/down", width, D)}
+        return {**{n: lin(f"{path}/{n}", D, width) for n in firsts},
+                "down": lin(f"{path}/down", width, D, down_gain)}
 
     # the router scores all E experts; the stacked leaves are the held ones
     E, F, Eh = cfg.n_experts, cfg.moe_mlp_dim, cfg.n_experts_held
     for i in range(cfg.n_layers):
         lp = f"layer_{i}"
-        layer: Dict[str, Any] = {
+        part = cfg.part_of(i)
+        # a block of one part has ONE norm; a layer of two, one before each
+        layer: Dict[str, Any] = {"norm": norm(f"{lp}/norm")} if part else {
             "attn_norm": norm(f"{lp}/attn_norm"),
             "mlp_norm": norm(f"{lp}/mlp_norm"),
         }
         if cfg.sandwich_norms:
             layer["post_attn_norm"] = norm(f"{lp}/post_attn_norm")
             layer["post_mlp_norm"] = norm(f"{lp}/post_mlp_norm")
-        if cfg.moe_of(i):
+        if part == "mixer":
+            pass                                  # no feed-forward part
+        elif cfg.moe_of(i):
             # a layer's experts are stacked leaves; the router and the
             # bias that only selects stay float32
             mo = f"{lp}/moe"
@@ -902,15 +1090,43 @@ def geometry_params(cfg: LlamaConfig, dtype=jnp.bfloat16,
                 "router": {"kernel": rand(f"{mo}/router/kernel", (D, E),
                                           jnp.float32)},
                 "bias": rand(f"{mo}/bias", (E,), jnp.float32),
+                # a gated expert's first matrices are stacked ``[E, D, F]``;
+                # an ungated one's ``up`` BY ROWS, ``[E, F, D]`` as ``down``
+                # (``ops.pallas.moe_ffn.first_products`` says why)
                 "experts": {
-                    "gate": rand(f"{mo}/experts/gate", (Eh, D, F), dtype),
-                    "up": rand(f"{mo}/experts/up", (Eh, D, F), dtype),
-                    "down": rand(f"{mo}/experts/down", (Eh, F, D), dtype)},
-                "shared": mlp(f"{mo}/shared", F * cfg.n_shared_experts),
+                    **{n: rand(f"{mo}/experts/{n}",
+                               (Eh, D, F) if len(firsts) == 2 else (Eh, F, D),
+                               dtype) for n in firsts},
+                    "down": rand(f"{mo}/experts/down", (Eh, F, D), dtype,
+                                 down_gain)},
+                "shared": mlp(f"{mo}/shared", cfg.shared_width),
             }
         else:
             layer["mlp"] = mlp(f"{lp}/mlp", cfg.mlp_dim)
-        if i in cfg.cross_attention_layers:
+        if part == "ffn":
+            pass                                  # no mixer
+        elif cfg.ssm_of(i):
+            # the public names: in_proj (z | x B C | dt), conv1d and its
+            # bias, dt_bias, A_log, D, the gated norm, out_proj
+            at, SH = f"{lp}/attn", cfg.ssm_heads
+            inner = SH * cfg.ssm_head_dim
+            wide = inner + 2 * cfg.ssm_groups * cfg.ssm_state
+            dts = jnp.maximum(jnp.exp(uniform(
+                (SH,), *(float(np.log(x)) for x in SSM_DT_RANGE))),
+                SSM_DT_FLOOR)
+            layer["attn"] = {
+                "in": lin(f"{at}/in", D, inner + wide + SH),
+                "conv": uniform((cfg.ssm_conv, wide),
+                                *SSM_CONV_RANGE).astype(dtype),
+                "conv_bias": uniform((wide,), *SSM_CONV_RANGE).astype(dtype),
+                "A_log": jnp.log(jnp.arange(1, SH + 1, dtype=jnp.float32)),
+                # softplus^-1 of the drawn dt
+                "dt_bias": dts + jnp.log(-jnp.expm1(-dts)),
+                "D": jnp.ones((SH,), jnp.float32),
+                "norm": norm(f"{at}/norm", inner),
+                "o": lin(f"{at}/o", inner, D),
+            }
+        elif i in cfg.cross_attention_layers:
             ca = f"{lp}/cross_attn"
             layer["cross_attn"] = {
                 "q": lin(f"{ca}/q", D, q_out), "k": lin(f"{ca}/k", D, kv_out),

@@ -564,11 +564,14 @@ class StepTelemetry:
         # latent-layer steps, and the cache rows the steps' live rows held,
         # summed over latent layers. None = no latent cache.
         self.mla: Optional[Dict[str, int]] = None
-        # what the recurrent (KDA) layers did, counted on the host where
-        # it is known: real tokens x KDA layers through the prefill scan,
-        # continuation programs that read a slot's state, live rows x KDA
-        # layers stepped in decode dispatches. None = no such layer.
+        # what the recurrent layers did, counted on the host where it is
+        # known, under the model's recurrent KIND (``kda``: linear
+        # attention; ``ssm``: state-space mixers): real tokens x recurrent
+        # layers through the prefill scan, continuation programs that read
+        # a slot's state, live rows x recurrent layers stepped in decode
+        # dispatches. None = no layer of that kind.
         self.kda: Optional[Dict[str, int]] = None
+        self.ssm: Optional[Dict[str, int]] = None
         self.warmed_executables = 0  # closed-set size at readiness
         # last-step gauges (scraped between steps)
         self._gauges: Dict[str, float] = {}
@@ -869,19 +872,24 @@ class StepTelemetry:
                                    + int(tokens_visible))
             self.mla = m
 
-    def count_kda(self, prefill_tokens: int = 0, chunk_carries: int = 0,
-                  rows_stepped: int = 0) -> None:
-        """One dispatch of a model with KDA layers; a model without them
-        counts nothing (every argument 0) and shows no ``kda`` entry."""
+    def count_recurrent(self, kind: str, prefill_tokens: int = 0,
+                        chunk_carries: int = 0,
+                        rows_stepped: int = 0) -> None:
+        """One dispatch of a model with recurrent layers of ``kind``
+        (``"kda"`` or ``"ssm"``: the snapshot's entry); a model without
+        them counts nothing (every argument 0) and shows no such entry."""
         if not (prefill_tokens or chunk_carries or rows_stepped):
             return
+        assert kind in ("kda", "ssm"), kind
         with self._lock:
-            m = self.kda if self.kda is not None else dict.fromkeys(
-                ("prefill_tokens", "chunk_carries", "rows_stepped"), 0)
+            m = getattr(self, kind)
+            if m is None:
+                m = dict.fromkeys(
+                    ("prefill_tokens", "chunk_carries", "rows_stepped"), 0)
             m["prefill_tokens"] += int(prefill_tokens)
             m["chunk_carries"] += int(chunk_carries)
             m["rows_stepped"] += int(rows_stepped)
-            self.kda = m
+            setattr(self, kind, m)
 
     def count_window(self, walked: int, skipped: int, visible: int,
                      dead: int, held: int) -> None:
@@ -913,7 +921,8 @@ class StepTelemetry:
         the join key between ``/debug/flight`` step records and request
         traces (whose root carries ``engine_req_id``). ``state_slots``:
         arena slots held at the step's end, of a model with recurrent
-        layers (the record's ``state_slots_live``, ``kda.slots_live``).
+        layers (the record's ``state_slots_live``, ``kda.slots_live`` or
+        ``ssm.slots_live``).
         ``tokens``: what the step committed (``tokens_committed``). Every
         record also says where the callers outside the engine stand:
         ``ingress_inflight``, ``streams_draining``, ``stream_backlog``."""
@@ -955,8 +964,9 @@ class StepTelemetry:
         if state_slots is not None:
             rec["state_slots_live"] = int(state_slots)
         with self._lock:
-            if state_slots is not None and self.kda is not None:
-                self.kda["slots_live"] = int(state_slots)
+            for m in (self.kda, self.ssm):
+                if state_slots is not None and m is not None:
+                    m["slots_live"] = int(state_slots)
             self.steps += 1
             self.pipeline_flushes += len(self._step_flushes)
             for reason in filter(None, self._step_flushes):
@@ -1058,6 +1068,8 @@ class StepTelemetry:
                 out["mla"] = dict(self.mla)
             if self.kda is not None:
                 out["kda"] = dict(self.kda)
+            if self.ssm is not None:
+                out["ssm"] = dict(self.ssm)
             # the open phase's seconds so far included: two readings
             # differ by the time between them, whatever each caught open
             out["phase_s"] = dict(self.phase_s)

@@ -278,3 +278,44 @@ def step_slots(q, k, v, g, beta, arena, slots, *, kernel: bool):
         return kda_decode_step(q, k, v, g, beta, arena, slots)
     o, s = step(q, k, v, g, beta, arena[slots])
     return o, arena.at[slots].set(s)
+
+
+# -- a mixer's phases, as the engine's programs call them (the recurrent
+# KINDS share this interface: ``ops.ssm`` has the same two) ----------------
+
+def write_slots(state: Dict, slots: jax.Array, s: jax.Array,
+                tail: jax.Array) -> Dict:
+    """A recurrent layer's slot arena with ``slots``' states and tails
+    replaced (``[K, ...]`` each): THE write seam of prefill and
+    continuation. A dummy row's slot is the null slot (the arena's last)."""
+    return {"s": state["s"].at[slots].set(s.astype(state["s"].dtype)),
+            "t": state["t"].at[slots].set(tail.astype(state["t"].dtype))}
+
+
+def prefill(at: Dict, h: jax.Array, state: Dict, slots: jax.Array,
+            n_valid: jax.Array, cfg, *, carry: bool, kernel: bool):
+    """A prefill (``carry`` False: from a ZERO state and tail, whatever the
+    slot held) or continuation (``carry``: from the rows' ``slots`` of the
+    arena ``state``) program's pass over ``h`` ``[B, T, D]``, the state and
+    tail the last REAL token left written to the slots. Returns ``(out [B,
+    T, H * d], state)``."""
+    q, k, v, g, beta, tail = inputs(
+        at, h, state["t"][slots] if carry else None, n_valid, cfg)
+    if carry:
+        o, s = scan(q, k, v, g, beta, state["s"][slots], kernel=kernel)
+    else:
+        o, s = scan(q, k, v, g, beta, kernel=kernel)
+    state = write_slots(state, slots, s, tail)
+    return output(at, h, o, cfg), state
+
+
+def decode(at: Dict, h: jax.Array, state: Dict, slots: jax.Array, cfg, *,
+           kernel: bool):
+    """One decode step of ``h`` ``[B, 1, D]`` in place on the rows' slots.
+    Returns ``(out [B, 1, H * d], state)``."""
+    arena, tails = state["s"], state["t"]
+    q, k, v, g, beta, tail = inputs(at, h, tails[slots], None, cfg)
+    o, arena = step_slots(q[:, 0], k[:, 0], v[:, 0], g[:, 0], beta[:, 0],
+                          arena, slots, kernel=kernel)
+    state = {"s": arena, "t": tails.at[slots].set(tail.astype(tails.dtype))}
+    return output(at, h, o[:, None], cfg), state

@@ -12,7 +12,7 @@ from __future__ import annotations
 
 import dataclasses
 import functools
-from typing import Callable, List, Sequence
+from typing import Callable, Dict, List, Sequence
 
 import jax
 import jax.numpy as jnp
@@ -361,12 +361,26 @@ def latent_cases(n_heads: int, head_dim: int, v_head_dim: int, width: int,
 TOL_EXPERTS = 2 * 2.0 ** -8
 
 
+#: the rows' deviation an expert case draws, by what an expert is
+_X_STD = {"silu": 1.0, "relu2": 0.5}
+
+
+def _expert_leaves(act: str, gate, up, down) -> Dict:
+    """The stacked expert leaves as the layer holds them: an ungated
+    (``relu2``) expert has no ``gate`` (the drawn one is dropped)."""
+    if act == "relu2":
+        return {"up": jnp.swapaxes(up, 1, 2), "down": down}
+    return {"gate": gate, "up": up, "down": down}
+
+
 def _expert_inputs(key, *, n_experts: int, held: int, top_k: int, D: int,
-                   F: int, rows: int):
+                   F: int, rows: int, x_std: float = 1.0):
     """``(x, sel, w, gate, up, down)``: ``rows`` rows of ``top_k`` distinct
     experts of ``n_experts`` each (a shared popularity plus each row's own
     noise: top-k of it, so some experts hold several rows and some none),
-    the last row inactive, and the leaves of ``held`` experts."""
+    the last row inactive, and the leaves of ``held`` experts. ``x_std``:
+    the rows' deviation (``relu(u) ** 2`` grows with its square: at half,
+    a row's weighted sum stays under 1 as the gated form's does at 1)."""
     kx, ks, kw, kg, ku, kd = jax.random.split(key, 6)
     leaf = lambda k, s: (jax.random.normal(k, s, jnp.float32)       # noqa: E731
                          * 0.02).astype(jnp.bfloat16)
@@ -375,13 +389,15 @@ def _expert_inputs(key, *, n_experts: int, held: int, top_k: int, D: int,
     _, sel = jax.lax.top_k(logits, top_k)
     sel = sel.astype(jnp.int32).at[rows - 1].set(n_experts)
     w = jnp.full((rows, top_k), 1.0 / top_k, jnp.float32)
-    return (jax.random.normal(kx, (rows, D), jnp.bfloat16), sel, w,
-            leaf(kg, (held, D, F)), leaf(ku, (held, D, F)),
+    x = jax.random.normal(kx, (rows, D), jnp.bfloat16)
+    if x_std != 1.0:
+        x = (x.astype(jnp.float32) * x_std).astype(jnp.bfloat16)
+    return (x, sel, w, leaf(kg, (held, D, F)), leaf(ku, (held, D, F)),
             leaf(kd, (held, F, D)))
 
 
 def _expert_case(n_experts: int, top_k: int, D: int, F: int,
-                 rows: int) -> KernelCase:
+                 rows: int, act: str = "silu") -> KernelCase:
     """The streamed expert product (``moe_grouped_ffn_streamed``) at one
     decode bucket: ``rows`` rows of ``top_k`` distinct experts each, drawn
     so that some experts hold several rows and some none; the last row is
@@ -390,27 +406,31 @@ def _expert_case(n_experts: int, top_k: int, D: int, F: int,
     from .pallas.moe_ffn import moe_streamed_ffn
 
     make = functools.partial(_expert_inputs, n_experts=n_experts,
-                             held=n_experts, top_k=top_k, D=D, F=F, rows=rows)
+                             held=n_experts, top_k=top_k, D=D, F=F, rows=rows,
+                             x_std=_X_STD[act])
 
     def sizes(sel):
         return moe.expert_counts(sel, n_experts)
 
     def kernel(x, sel, w, gate, up, down, interpret):
+        ex = _expert_leaves(act, gate, up, down)
         return moe_streamed_ffn(
-            x, *moe.streamed_operands(sel, w, sizes(sel), 0), gate, up, down,
-            interpret=interpret)
+            x, *moe.streamed_operands(sel, w, sizes(sel), 0),
+            ex.get("gate"), ex["up"], down, interpret=interpret, act=act)
 
     def oracle(x, sel, w, gate, up, down):
-        return moe._grouped({"gate": gate, "up": up, "down": down}, x, sel,
-                            w, sizes(sel), 0)
+        return moe._grouped(_expert_leaves(act, gate, up, down), x, sel,
+                            w, sizes(sel), 0, act)
 
+    tag = "" if act == "silu" else f"-{act}"
     return KernelCase(
-        name=f"experts-E{n_experts}k{top_k}-D{D}-F{F}-b{rows}",
+        name=f"experts-E{n_experts}k{top_k}-D{D}-F{F}-b{rows}{tag}",
         make_inputs=make, kernel=kernel, oracle=oracle, tol=TOL_EXPERTS)
 
 
 def _tiled_expert_case(n_experts: int, top_k: int, D: int, F: int,
-                       rows: int, held: int) -> KernelCase:
+                       rows: int, held: int,
+                       act: str = "silu") -> KernelCase:
     """The tiled expert product (``moe_grouped_ffn_tiled``) at one prefill
     bucket: ``rows`` tokens of ``top_k`` distinct experts of ``n_experts``
     each, a shared popularity making the groups uneven, the LAST ``held``
@@ -421,34 +441,38 @@ def _tiled_expert_case(n_experts: int, top_k: int, D: int, F: int,
     first = n_experts - held
 
     make = functools.partial(_expert_inputs, n_experts=n_experts, held=held,
-                             top_k=top_k, D=D, F=F, rows=rows)
+                             top_k=top_k, D=D, F=F, rows=rows,
+                             x_std=_X_STD[act])
 
     def product(form):
         def run(x, sel, w, gate, up, down, **interpret):
             sizes = moe.expert_counts(sel, n_experts)[first:]
-            return form({"gate": gate, "up": up, "down": down}, x, sel, w,
-                        sizes, first, **interpret)
+            return form(_expert_leaves(act, gate, up, down), x, sel, w,
+                        sizes, first, act, **interpret)
         return run
 
+    tag = "" if act == "silu" else f"-{act}"
     return KernelCase(
         name=(f"experts-tiled-E{held}of{n_experts}k{top_k}-D{D}-F{F}"
-              f"-b{rows}"),
+              f"-b{rows}{tag}"),
         make_inputs=make, kernel=product(moe._tiled),
         oracle=product(moe._grouped), tol=TOL_EXPERTS)
 
 
 def expert_cases(n_experts: int, top_k: int, D: int, F: int, *,
                  max_num_seqs: int = 8, prefill_rows: int = 0,
-                 held: int = 0) -> List[KernelCase]:
+                 held: int = 0, act: str = "silu") -> List[KernelCase]:
     """The streamed expert product at an engine's largest decode bucket and
     at one small one (rows the kernel pads to a tile of sublanes); with
     ``prefill_rows``, the tiled one at that bucket, ``held`` of the experts
-    on this chip (default all)."""
-    cases = [_expert_case(n_experts, top_k, D, F, rows)
+    on this chip (default all). ``act``: what an expert is
+    (``ops.pallas.moe_ffn.activation``)."""
+    cases = [_expert_case(n_experts, top_k, D, F, rows, act)
              for rows in sorted({max_num_seqs, min(max_num_seqs, 8)})]
     if prefill_rows:
         cases.append(_tiled_expert_case(n_experts, top_k, D, F,
-                                        prefill_rows, held or n_experts))
+                                        prefill_rows, held or n_experts,
+                                        act))
     return cases
 
 
@@ -561,4 +585,82 @@ def kda_cases(n_heads: int, head_dim: int, *, bucket: int = 2048,
     largest decode bucket and at a small one."""
     return [_kda_chunk_case(n_heads, head_dim, bucket, 1)] + [
         _kda_step_case(n_heads, head_dim, rows, max_num_seqs)
+        for rows in sorted({max_num_seqs, min(max_num_seqs, 4)})]
+
+
+#: the state-space kernels against the token-by-token recurrence, float32
+#: on both sides: what is left is the order of the sums (a chunk's decay
+#: matrix against one step a token), relative to outputs of some tens
+TOL_SSM = 2e-3
+
+
+def _ssm_operands(key, B: int, T: int, H: int, P: int, N: int, G: int):
+    """Seeded operands of the recurrence: unit ``x``, ``B`` and ``C``, a
+    step size log-uniform in 0.001-0.1 and ``A`` = 1 .. heads, as the
+    public initialisation draws them."""
+    kx, kb, kc, kd = jax.random.split(key, 4)
+    dt = jnp.exp(jax.random.uniform(kd, (B, T, H), minval=np.log(1e-3),
+                                    maxval=np.log(0.1)))
+    return (jax.random.normal(kx, (B, T, H, P)),
+            jax.random.normal(kb, (B, T, G, N)),
+            jax.random.normal(kc, (B, T, G, N)), dt,
+            -jnp.arange(1, H + 1, dtype=jnp.float32) * dt)
+
+
+def _ssm_chunk_case(H: int, P: int, N: int, G: int, T: int,
+                    rows: int) -> KernelCase:
+    """The chunk kernel over ``T`` tokens from a state that is not zero (a
+    continuation chunk), final state and outputs side by side."""
+    from . import ssm
+    from .pallas.ssm_chunk import ssm_chunk_prefill
+
+    def make(key):
+        k0, k1 = jax.random.split(key)
+        return _ssm_operands(k0, rows, T, H, P, N, G) + (
+            jax.random.normal(k1, (rows, H, P, N)),)
+
+    def flat(y, s):
+        return jnp.concatenate([y.reshape(-1), s.reshape(-1)])
+
+    return KernelCase(
+        name=f"ssm-chunk-H{H}x{P}x{N}-T{T}-b{rows}", make_inputs=make,
+        kernel=lambda *a, interpret: flat(
+            *ssm_chunk_prefill(*a, interpret=interpret)),
+        oracle=lambda *a: flat(*ssm.recurrence(*a)), tol=TOL_SSM)
+
+
+def _ssm_step_case(H: int, P: int, N: int, G: int, rows: int,
+                   slots: int) -> KernelCase:
+    """The step kernel for ``rows`` rows over an arena of ``slots`` and the
+    null slot, as ``_kda_step_case``: the last two rows padded."""
+    from . import ssm
+    from .pallas.ssm_step import ssm_decode_step
+
+    def make(key):
+        k0, k1, k2 = jax.random.split(key, 3)
+        ops = tuple(a[:, 0] for a in _ssm_operands(k0, rows, 1, H, P, N, G))
+        ids = jax.random.permutation(k2, slots)[:rows].astype(jnp.int32)
+        ids = jnp.where(jnp.arange(rows) >= rows - 2, slots, ids)
+        return ops + (jax.random.normal(k1, (slots + 1, H, P, N)), ids)
+
+    def keep_null(y, arena):
+        return jnp.concatenate([y[:-2].reshape(-1), arena[:-1].reshape(-1)])
+
+    return KernelCase(
+        name=f"ssm-step-H{H}x{P}x{N}-b{rows}-S{slots}", make_inputs=make,
+        kernel=lambda *a, interpret: keep_null(
+            *ssm_decode_step(*a, interpret=interpret)),
+        oracle=lambda *a: keep_null(*ssm.step_slots(*a, kernel=False)),
+        tol=TOL_SSM)
+
+
+def ssm_cases(heads: int, head_dim: int, state: int, groups: int, *,
+              bucket: int = 512, prefill_rows: int = 1,
+              max_num_seqs: int = 16) -> List[KernelCase]:
+    """The kernel calls an engine with state-space mixers dispatches: the
+    chunk kernel over a prefill bucket, and the step kernel at the largest
+    decode bucket and at a small one."""
+    return [_ssm_chunk_case(heads, head_dim, state, groups, bucket,
+                            prefill_rows)] + [
+        _ssm_step_case(heads, head_dim, state, groups, rows, max_num_seqs)
         for rows in sorted({max_num_seqs, min(max_num_seqs, 4)})]

@@ -31,9 +31,9 @@ accounting calls too — picks how the product is made:
   the layout and one back: 4.20 ms a Kimi-Linear layer of 2,048 tokens,
   3.12 Kanana-2's, 2.94 Trinity-Mini's of 1,024, the kernel 67-81% of the
   held bytes' time (my chip run, PR 37).
-- ``"grouped"`` (widths no kernel can tile, ``D`` or ``F`` no multiple of
-  128: the CPU stand-ins, whatever their rows; the plain form and both
-  kernels' oracle): the assignments sorted by expert, counted into group
+- ``"grouped"`` (widths no kernel can tile, ``D`` no multiple of 128 or
+  ``F`` none of 64: the CPU stand-ins, whatever their rows; the plain form
+  and both kernels' oracle): the assignments sorted by expert, counted into group
   sizes, pushed through three grouped products (gate, up, down:
   ``jax.lax.ragged_dot`` over the stacked expert leaves ``[E, D, F]`` /
   ``[E, F, D]``), then weighted and summed back. It costs the assignments'
@@ -64,6 +64,17 @@ or padded row, go to no expert at all (grouped: they sort behind the last
 group and carry weight 0; tiled: they have no row in the layout and are
 selected away; streamed: a zero of the combine matrix). Summing
 the holders' parts, plus the shared expert once, is the uncut layer.
+
+What an expert IS is data (``cfg.mlp_act``), a static argument of every
+form and part of no name: ``"silu"``, ``Down(silu(Gate x) * Up x)`` over
+three matrices stacked ``[E, D, F]``, ``[E, D, F]``, ``[E, F, D]``; or
+``"relu2"``, ``Down(relu(Up x) ** 2)`` over TWO, both stacked by the expert's
+``F`` rows (``[E, F, D]``: ``ops.pallas.moe_ffn.first_products`` says why),
+so that a width that is no multiple of 128 (1856 = 14 x 128 + 64) is taken
+whole by both kernels. Alone on a v5e at 2688 x 1856, 64 of 128 experts
+held (``scripts/ssm_bench.py``; my chip run, PR 45): streamed, 128 rows, 61
+held experts touched 1.67 ms, 89% of their bytes' time (the plain form
+15.5); tiled, 2,048 rows 3.42 ms, 46% (the plain form 22.1).
 
 No token is dropped, whatever the load: there is no capacity.
 """
@@ -102,8 +113,13 @@ def route(mp: Dict, x2: jax.Array, cfg) -> Tuple[jax.Array, jax.Array]:
     return sel.astype(jnp.int32), w * cfg.route_scale
 
 
-def gated_mlp(p: Dict, x: jax.Array) -> jax.Array:
-    """``Down(silu(Gate x) * Up x)`` over ``nn.Dense``-shaped leaves."""
+def gated_mlp(p: Dict, x: jax.Array, act: str = "silu") -> jax.Array:
+    """``Down(silu(Gate x) * Up x)`` over ``nn.Dense``-shaped leaves; with
+    ``act`` ``"relu2"`` the two-matrix ``Down(relu(Up x) ** 2)`` (no
+    ``gate`` leaf)."""
+    if act == "relu2":
+        return quant_matmul(
+            jnp.square(jax.nn.relu(quant_matmul(x, p["up"]))), p["down"])
     return quant_matmul(
         jax.nn.silu(quant_matmul(x, p["gate"])) * quant_matmul(x, p["up"]),
         p["down"])
@@ -123,15 +139,20 @@ def expert_form(n_rows: int, cfg) -> str:
     product of a call with ``n_rows`` rows takes (module docstring). A
     function of the static row count and the model's widths, and of nothing
     else."""
-    if cfg.dim % 128 or cfg.moe_mlp_dim % 128:
+    # ``D`` is a block's lanes and must be whole tiles; ``F`` is taken in
+    # tiles of 128 or, where it is no multiple of that, whole (1856 = 14 x
+    # 128 + 64): half a tile of lanes is the least a whole-width block
+    # leaves the MXU
+    if cfg.dim % 128 or cfg.moe_mlp_dim % 64:
         return "grouped"
     return "streamed" if n_rows <= STREAMED_MAX_ROWS else "tiled"
 
 
 def _grouped(ex: Dict, x2: jax.Array, sel: jax.Array, w: jax.Array,
-             sizes: jax.Array, first: int) -> jax.Array:
+             sizes: jax.Array, first: int, act: str = "silu") -> jax.Array:
     """``[N, D]`` float32: the ``N x k`` assignments sorted by expert,
-    through three grouped products, weighted and summed back."""
+    through three grouped products (two of an ungated expert), weighted and
+    summed back."""
     N, k = sel.shape
     count = sizes.shape[0]
     local = sel.reshape(N * k) - first
@@ -141,9 +162,16 @@ def _grouped(ex: Dict, x2: jax.Array, sel: jax.Array, w: jax.Array,
     tok = order // k
     xs = x2[tok]                                          # [N * k, D]
     with jax.named_scope(GROUPED_NAME):
-        g = jax.lax.ragged_dot(xs, ex["gate"], sizes)
-        u = jax.lax.ragged_dot(xs, ex["up"], sizes)
-        d = jax.lax.ragged_dot(jax.nn.silu(g) * u, ex["down"], sizes)
+        if act == "relu2":
+            # an ungated expert's ``up`` is stacked by rows, ``[E, F, D]``
+            # (``ops.pallas.moe_ffn.first_products`` says why)
+            u = jax.lax.ragged_dot(xs, jnp.swapaxes(ex["up"], 1, 2), sizes)
+            h = jnp.square(jax.nn.relu(u))
+        else:
+            g = jax.lax.ragged_dot(xs, ex["gate"], sizes)
+            u = jax.lax.ragged_dot(xs, ex["up"], sizes)
+            h = jax.nn.silu(g) * u
+        d = jax.lax.ragged_dot(h, ex["down"], sizes)
     ws = jnp.where(mine, w.reshape(N * k), 0.0)[order]
     # rows behind the last group hold whatever the product left there:
     # select, do not multiply (0 * garbage may be NaN)
@@ -221,7 +249,7 @@ def tiled_operands(sel: jax.Array, sizes: jax.Array, first: int, tm: int,
 
 
 def _tiled(ex: Dict, x2: jax.Array, sel: jax.Array, w: jax.Array,
-           sizes: jax.Array, first: int, *,
+           sizes: jax.Array, first: int, act: str = "silu", *,
            interpret: Optional[bool] = None,
            tile_rows: Optional[int] = None) -> jax.Array:
     """``[N, D]`` float32: the assignments grouped by expert into a layout
@@ -233,8 +261,8 @@ def _tiled(ex: Dict, x2: jax.Array, sel: jax.Array, w: jax.Array,
     N, k = sel.shape
     tok, tile_expert, n_tiles, mine, pos = tiled_operands(
         sel, sizes, first, tile_rows or row_tile(N * k, sizes.shape[0]))
-    d = moe_tiled_ffn(x2[tok], tile_expert, n_tiles, ex["gate"], ex["up"],
-                      ex["down"], interpret=interpret)
+    d = moe_tiled_ffn(x2[tok], tile_expert, n_tiles, ex.get("gate"),
+                      ex["up"], ex["down"], interpret=interpret, act=act)
     # an assignment computed nowhere here reads row 0 and is selected away
     # (select, do not multiply: the rows behind the last real tile hold
     # whatever was there)
@@ -268,13 +296,13 @@ def streamed_operands(sel: jax.Array, w: jax.Array, sizes: jax.Array,
 
 
 def _streamed(ex: Dict, x2: jax.Array, sel: jax.Array, w: jax.Array,
-              sizes: jax.Array, first: int) -> jax.Array:
+              sizes: jax.Array, first: int, act: str = "silu") -> jax.Array:
     """``[N, D]`` float32: every row through every touched expert held
     here, weighted by the dense combine matrix (``ops.pallas.moe_ffn``)."""
     from .pallas.moe_ffn import moe_streamed_ffn
 
     return moe_streamed_ffn(x2, *streamed_operands(sel, w, sizes, first),
-                            ex["gate"], ex["up"], ex["down"])
+                            ex.get("gate"), ex["up"], ex["down"], act=act)
 
 
 _FORMS = {"streamed": _streamed, "tiled": _tiled, "grouped": _grouped}
@@ -306,7 +334,7 @@ def expert_layer(mp: Dict, x: jax.Array, cfg, *,
                        jnp.max(counts)])
     product = _FORMS[expert_form(N, cfg)]
     y = product(mp["experts"], x2, sel, w, counts[first:first + count],
-                first).astype(x.dtype)
+                first, cfg.mlp_act).astype(x.dtype)
     if cfg.n_shared_experts:
-        y = y + gated_mlp(mp["shared"], x2)
+        y = y + gated_mlp(mp["shared"], x2, cfg.mlp_act)
     return y.reshape(*lead, D), stats

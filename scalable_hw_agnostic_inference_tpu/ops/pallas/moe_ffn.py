@@ -108,20 +108,56 @@ _TILE_BUDGET_BYTES = 32 * 2 ** 20
 _RESIDENT_BYTES = 8 * 2 ** 20
 
 
-def inner_tile(D: int, F: int, itemsize: int) -> int:
+def activation(act: str, g, u):
+    """What stands between an expert's first product(s) and its down
+    product, in float32: ``silu(g) * u`` of a gated expert (three
+    matrices), ``relu(u) ** 2`` of an ungated one (two; ``g`` is None)."""
+    if act == "relu2":
+        assert g is None, "relu2 experts have no gate matrix"
+        r = jnp.maximum(u, 0.0)
+        return r * r
+    assert act == "silu", act
+    return jax.nn.silu(g) * u
+
+
+def first_products(x, gu_refs):
+    """``(g or None, u)`` in float32: ``x @ gate`` and ``x @ up`` of a gated
+    expert (leaves ``[D, F]``), ``x @ up^T`` alone of an ungated one, whose
+    ``up`` is stacked BY ROWS (``[F, D]``, as ``down`` is): the model width
+    is then both leaves' minor dimension, whole lane tiles whatever ``F``
+    is. (A leaf ``[E, 2688, 1856]`` the TPU stores transposed of its own
+    accord, and re-lays it whole before every kernel call that wants it
+    otherwise: 0.64 GB copied a routed block a decode step, PERF.md PR 45.)"""
+    if len(gu_refs) == 2:
+        g, u = (jnp.dot(x, r[...], preferred_element_type=jnp.float32)
+                for r in gu_refs)
+        return g, u
+    (up_ref,) = gu_refs
+    return None, jax.lax.dot_general(
+        x, up_ref[...], (((1,), (1,)), ((), ())),
+        preferred_element_type=jnp.float32)
+
+
+def inner_tile(D: int, F: int, itemsize: int, n_mats: int = 3) -> int:
     """Columns of ``F`` one grid step takes: all of them where two copies of
-    an expert's three ``[D, F]`` matrices fit the budget, else the largest
-    multiple of 128 dividing ``F`` that does (at least 128)."""
-    fits = lambda tf: 2 * 3 * D * tf * itemsize <= _TILE_BUDGET_BYTES  # noqa: E731
+    an expert's ``n_mats`` ``[D, F]`` matrices fit the budget or ``F`` is no
+    multiple of 128 (a block must then be the whole width: 1856 = 14 x 128 +
+    64 is taken whole, 39.9 MB of two matrices twice, inside the chip's
+    VMEM under the raised limit), else the largest multiple of 128 dividing
+    ``F`` that fits (at least 128)."""
+    fits = lambda tf: (                                       # noqa: E731
+        2 * n_mats * D * tf * itemsize <= _TILE_BUDGET_BYTES)
     if fits(F) or F % 128:
         return F
     tiles = [tf for tf in range(128, F, 128) if F % tf == 0 and fits(tf)]
     return max(tiles, default=128)
 
 
-def _ffn_kernel(ids_ref, n_ref, x_ref, c_ref, g_ref, u_ref, d_ref, o_ref):
+def _ffn_kernel(ids_ref, n_ref, x_ref, c_ref, *refs, act: str):
     # ids_ref [steps], n_ref [1] SMEM; x_ref [N, D]; c_ref [N, E'] f32;
-    # g_ref, u_ref [D, tf]; d_ref [tf, D]; o_ref [N, D] f32, resident
+    # refs: (g_ref,) u_ref [D, tf]; d_ref [tf, D]; o_ref [N, D] f32,
+    # resident (an ungated expert has no g_ref)
+    *gu_refs, d_ref, o_ref = refs
     i, j = pl.program_id(0), pl.program_id(1)
 
     @pl.when((i == 0) & (j == 0))
@@ -131,45 +167,49 @@ def _ffn_kernel(ids_ref, n_ref, x_ref, c_ref, g_ref, u_ref, d_ref, o_ref):
     @pl.when(i < n_ref[0])
     def _expert_tile():
         x = x_ref[...]
-        g = jnp.dot(x, g_ref[...], preferred_element_type=jnp.float32)
-        u = jnp.dot(x, u_ref[...], preferred_element_type=jnp.float32)
+        g, u = first_products(x, gu_refs)
         # this expert's column of the combine matrix: a masked lane sum
         # (a dynamic lane slice would want an aligned start)
         c = c_ref[...]
         lane = jax.lax.broadcasted_iota(jnp.int32, c.shape, 1)
         w = jnp.sum(jnp.where(lane == ids_ref[i], c, 0.0), axis=1,
                     keepdims=True)                              # [N, 1]
-        h = (jax.nn.silu(g) * u * w).astype(d_ref.dtype)
+        h = (activation(act, g, u) * w).astype(d_ref.dtype)
         o_ref[...] += jnp.dot(h, d_ref[...],
                               preferred_element_type=jnp.float32)
 
 
-@functools.partial(jax.jit, static_argnames=("tile_f", "interpret"))
+@functools.partial(jax.jit, static_argnames=("tile_f", "interpret", "act"))
 def moe_streamed_ffn(
     x: jax.Array,          # [N, D] the rows, in the experts' operand type
     combine: jax.Array,    # [N, E'] float32: a row's weight on each expert
     ids: jax.Array,        # [steps] int32 touched experts, ascending, then
                            # the last touched one repeated
     n_touched: jax.Array,  # [] or [1] int32: how many of ``ids`` are real
-    gate: jax.Array,       # [E', D, F]
-    up: jax.Array,         # [E', D, F]
+    gate: Optional[jax.Array],  # [E', D, F]; None: an ungated expert
+    up: jax.Array,         # [E', D, F]; an ungated expert's: [E', F, D]
     down: jax.Array,       # [E', F, D]
     *,
     tile_f: Optional[int] = None,
     interpret: Optional[bool] = None,
+    act: str = "silu",
 ) -> jax.Array:
     """``[N, D]`` float32: every row through every touched expert, weighted
     by ``combine``. ``E'`` is the experts the leaves stack (the held slice);
-    ``steps`` bounds the walk (``min(E', N * k)`` covers any routing)."""
+    ``steps`` bounds the walk (``min(E', N * k)`` covers any routing).
+    ``act`` and whether there is a ``gate`` say what an expert IS
+    (:func:`activation`): static, and part of no name."""
     from jax.experimental.pallas import tpu as pltpu
 
     N, D = x.shape
-    _E, _, F = gate.shape
+    _E, F, _ = down.shape
+    firsts = [m for m in (gate, up) if m is not None]
+    n_mats, itemsize = len(firsts) + 1, up.dtype.itemsize
     if interpret is None:
         from ..attention import on_tpu_platform
 
         interpret = not on_tpu_platform()
-    tf = tile_f or inner_tile(D, F, gate.dtype.itemsize)
+    tf = tile_f or inner_tile(D, F, itemsize, n_mats)
     nj = F // tf
     rows = -(-N // 16) * 16
     if rows != N:
@@ -183,20 +223,21 @@ def moe_streamed_ffn(
         return jnp.where(i < n_ref[0], j, nj - 1)
 
     whole = lambda i, j, *_: (0, 0)                       # noqa: E731
+    by_rows = pl.BlockSpec((None, tf, D), lambda i, j, ids_ref, n_ref: (
+        ids_ref[i], tile(i, j, ids_ref, n_ref), 0))
+    first = by_rows if gate is None else pl.BlockSpec(
+        (None, D, tf), lambda i, j, ids_ref, n_ref: (
+            ids_ref[i], 0, tile(i, j, ids_ref, n_ref)))
     out = pl.pallas_call(
-        _ffn_kernel,
+        functools.partial(_ffn_kernel, act=act),
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=2,
             grid=(steps, nj),
             in_specs=[
                 pl.BlockSpec((rows, D), whole),
                 pl.BlockSpec(combine.shape, whole),
-                pl.BlockSpec((None, D, tf), lambda i, j, ids_ref, n_ref: (
-                    ids_ref[i], 0, tile(i, j, ids_ref, n_ref))),
-                pl.BlockSpec((None, D, tf), lambda i, j, ids_ref, n_ref: (
-                    ids_ref[i], 0, tile(i, j, ids_ref, n_ref))),
-                pl.BlockSpec((None, tf, D), lambda i, j, ids_ref, n_ref: (
-                    ids_ref[i], tile(i, j, ids_ref, n_ref), 0)),
+                *[first] * len(firsts),
+                by_rows,
             ],
             out_specs=pl.BlockSpec((rows, D), whole),
         ),
@@ -204,12 +245,12 @@ def moe_streamed_ffn(
         # every step adds into the one resident block: nothing is parallel
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary", "arbitrary"),
-            vmem_limit_bytes=(2 * 3 * D * tf * gate.dtype.itemsize
+            vmem_limit_bytes=(2 * n_mats * D * tf * itemsize
                               + _RESIDENT_BYTES)),
         interpret=interpret,
         name=KERNEL_NAME,
     )(ids.astype(jnp.int32), n_touched.astype(jnp.int32).reshape(1),
-      x, combine.astype(jnp.float32), gate, up, down)
+      x, combine.astype(jnp.float32), *firsts, down)
     return out[:N]
 
 
@@ -235,17 +276,17 @@ def tile_bound(n_assignments: int, count: int, tm: int) -> int:
     return -(-n_assignments // tm) + min(count, n_assignments)
 
 
-def _tiled_kernel(te_ref, n_ref, x_ref, g_ref, u_ref, d_ref, o_ref):
-    # te_ref [tiles], n_ref [1] SMEM; x_ref [tm, D]; g_ref, u_ref [D, tf];
-    # d_ref [tf, D]; o_ref [tm, D], resident over j
+def _tiled_kernel(te_ref, n_ref, x_ref, *refs, act: str):
+    # te_ref [tiles], n_ref [1] SMEM; x_ref [tm, D]; refs: (g_ref,) u_ref
+    # [D, tf]; d_ref [tf, D]; o_ref [tm, D], resident over j
+    *gu_refs, d_ref, o_ref = refs
     i, j = pl.program_id(0), pl.program_id(1)
 
     @pl.when(i < n_ref[0])
     def _row_tile():
         x = x_ref[...]
-        g = jnp.dot(x, g_ref[...], preferred_element_type=jnp.float32)
-        u = jnp.dot(x, u_ref[...], preferred_element_type=jnp.float32)
-        h = (jax.nn.silu(g) * u).astype(d_ref.dtype)
+        g, u = first_products(x, gu_refs)
+        h = activation(act, g, u).astype(d_ref.dtype)
         part = jnp.dot(h, d_ref[...], preferred_element_type=jnp.float32)
 
         @pl.when(j == 0)
@@ -257,19 +298,20 @@ def _tiled_kernel(te_ref, n_ref, x_ref, g_ref, u_ref, d_ref, o_ref):
             o_ref[...] += part.astype(o_ref.dtype)
 
 
-@functools.partial(jax.jit, static_argnames=("tile_f", "interpret"))
+@functools.partial(jax.jit, static_argnames=("tile_f", "interpret", "act"))
 def moe_tiled_ffn(
     xs: jax.Array,           # [tiles * tm, D] the rows grouped by expert,
                              # each group starting on a row tile
     tile_expert: jax.Array,  # [tiles] int32: the expert of each row tile,
                              # behind the last real tile that one's again
     n_tiles: jax.Array,      # [] or [1] int32: how many tiles are real
-    gate: jax.Array,         # [E', D, F]
-    up: jax.Array,           # [E', D, F]
+    gate: Optional[jax.Array],  # [E', D, F]; None: an ungated expert
+    up: jax.Array,           # [E', D, F]; an ungated expert's: [E', F, D]
     down: jax.Array,         # [E', F, D]
     *,
     tile_f: Optional[int] = None,
     interpret: Optional[bool] = None,
+    act: str = "silu",
 ) -> jax.Array:
     """``[tiles * tm, D]``: each row through its tile's expert, unweighted,
     in the rows' type where a step is a whole expert (rounded once, as the
@@ -279,14 +321,16 @@ def moe_tiled_ffn(
     from jax.experimental.pallas import tpu as pltpu
 
     R, D = xs.shape
-    _E, _, F = gate.shape
+    _E, F, _ = down.shape
+    firsts = [m for m in (gate, up) if m is not None]
+    n_mats, itemsize = len(firsts) + 1, up.dtype.itemsize
     tiles = tile_expert.shape[0]
     tm = R // tiles
     if interpret is None:
         from ..attention import on_tpu_platform
 
         interpret = not on_tpu_platform()
-    tf = tile_f or inner_tile(D, F, gate.dtype.itemsize)
+    tf = tile_f or inner_tile(D, F, itemsize, n_mats)
     nj = F // tf
     out_dtype = xs.dtype if nj == 1 else jnp.float32
 
@@ -299,20 +343,20 @@ def moe_tiled_ffn(
         return jnp.where(i < n_ref[0], j, nj - 1)
 
     rows = lambda i, j, te_ref, n_ref: (row(i, n_ref), 0)   # noqa: E731
-    itemsize = gate.dtype.itemsize
+    by_rows = pl.BlockSpec((None, tf, D), lambda i, j, te_ref, n_ref: (
+        te_ref[i], col(i, j, n_ref), 0))
+    first = by_rows if gate is None else pl.BlockSpec(
+        (None, D, tf), lambda i, j, te_ref, n_ref: (
+            te_ref[i], 0, col(i, j, n_ref)))
     return pl.pallas_call(
-        _tiled_kernel,
+        functools.partial(_tiled_kernel, act=act),
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=2,
             grid=(tiles, nj),
             in_specs=[
                 pl.BlockSpec((tm, D), rows),
-                pl.BlockSpec((None, D, tf), lambda i, j, te_ref, n_ref: (
-                    te_ref[i], 0, col(i, j, n_ref))),
-                pl.BlockSpec((None, D, tf), lambda i, j, te_ref, n_ref: (
-                    te_ref[i], 0, col(i, j, n_ref))),
-                pl.BlockSpec((None, tf, D), lambda i, j, te_ref, n_ref: (
-                    te_ref[i], col(i, j, n_ref), 0)),
+                *[first] * len(firsts),
+                by_rows,
             ],
             out_specs=pl.BlockSpec((tm, D), rows),
         ),
@@ -322,7 +366,7 @@ def moe_tiled_ffn(
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary", "arbitrary"),
             vmem_limit_bytes=(
-                2 * 3 * D * tf * itemsize
+                2 * n_mats * D * tf * itemsize
                 # the rows and the result twice, the product in float32;
                 # g, u and h of one tile
                 + tm * (D * (2 * itemsize + 3 * 4) + tf * 3 * 4)
@@ -330,4 +374,4 @@ def moe_tiled_ffn(
         interpret=interpret,
         name=TILED_NAME,
     )(tile_expert.astype(jnp.int32), n_tiles.astype(jnp.int32).reshape(1),
-      xs, gate, up, down)
+      xs, *firsts, down)

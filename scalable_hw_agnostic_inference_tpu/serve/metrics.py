@@ -186,6 +186,13 @@ _KDA_COUNTERS = ("shai_engine_kda_total",
                  "chunk_carries (continuation programs that read a slot's "
                  "state), rows_stepped (live rows x KDA layers in decode "
                  "dispatches), slots_live (gauge: arena slots held)")
+_SSM_COUNTERS = ("shai_engine_ssm_total",
+                 "Recurrent (state-space) layers, by counter: "
+                 "prefill_tokens (real tokens x state-space layers through "
+                 "the chunked scan), chunk_carries (continuation programs "
+                 "that read a slot's state), rows_stepped (live rows x "
+                 "state-space layers in decode dispatches), slots_live "
+                 "(gauge: arena slots held)")
 #: conformance-layer gauge families: each instrument riding the engine
 #: telemetry object exports its flat numeric snapshot verbatim under a
 #: prefix — obs.slo → shai_slo_* (per-objective burn rates + breach),
@@ -375,7 +382,8 @@ class EngineTelemetryCollector:
                             ("moe", _MOE_COUNTERS),
                             ("window", _WINDOW_COUNTERS),
                             ("mla", _MLA_COUNTERS),
-                            ("kda", _KDA_COUNTERS)):
+                            ("kda", _KDA_COUNTERS),
+                            ("ssm", _SSM_COUNTERS)):
             if snap.get(key):
                 c = CounterMetricFamily(*family, labels=["app", "counter"])
                 for counter, v in sorted(snap[key].items()):

@@ -223,7 +223,11 @@ def _geometry_models():
     stage: Kimi-Linear-48B-A3B's leading dense layer and one period (three
     KDA layers to one MLA layer), 128 of each layer's 256 experts held here
     (the other chip of the two that share the stage holds the rest):
-    9.32 GB of 98 GB."""
+    9.32 GB of 98 GB. ``nemotron-3-nano-geometry`` likewise:
+    Nemotron-3-Nano-30B-A3B's first nine blocks of ONE part each (four
+    Mamba-2 mixers, four routed blocks of two-matrix ``relu ** 2`` experts,
+    one attention block), 64 of each routed block's 128 experts held here:
+    7.04 GB of 63 GB."""
     from ...models.llama import LlamaConfig
 
     return {
@@ -234,6 +238,7 @@ def _geometry_models():
         "trinity-mini-geometry": LlamaConfig.trinity_mini_stage,
         "kanana-2-geometry": LlamaConfig.kanana2_stage,
         "kimi-linear-geometry": LlamaConfig.kimi_linear_stage,
+        "nemotron-3-nano-geometry": LlamaConfig.nemotron3_nano_stage,
     }
 
 
@@ -242,7 +247,7 @@ def _stand_in_models():
     seed, the byte tokenizer, and in the ``vllm`` unit ONE tiny engine
     shape. ``tiny-afmoe`` has ``trinity-mini-geometry``'s mechanisms,
     ``tiny-mla`` ``kanana-2-geometry``'s, ``tiny-kda``
-    ``kimi-linear-geometry``'s."""
+    ``kimi-linear-geometry``'s, ``tiny-ssm`` ``nemotron-3-nano-geometry``'s."""
     from ...models.llama import LlamaConfig
 
     return {
@@ -250,6 +255,7 @@ def _stand_in_models():
         "tiny-afmoe": LlamaConfig.tiny_afmoe,
         "tiny-mla": LlamaConfig.tiny_mla,
         "tiny-kda": LlamaConfig.tiny_kda,
+        "tiny-ssm": LlamaConfig.tiny_ssm,
     }
 
 

@@ -55,16 +55,20 @@ class VllmService(ModelService):
     coalesce into the running batch.
 
     ``MODEL_ID``: a hub id, ``tiny`` / ``tiny-afmoe`` / ``tiny-mla`` /
-    ``tiny-kda`` (the hermetic stand-ins), or a geometry id
+    ``tiny-kda`` / ``tiny-ssm`` (the hermetic stand-ins), or a geometry id
     (``units/causal_lm.py``): an architecture at its published widths over
-    seeded weights. Three of those are ONE CHIP'S STAGE of a pipeline and
+    seeded weights. Four of those are ONE CHIP'S STAGE of a pipeline and
     not a servable whole model: ``trinity-mini-geometry`` (AFMoE: routed
     experts, window and full layers), ``kanana-2-geometry``
     (``deepseek_v3``: a latent paged cache with absorbed decode beside
     routed experts; 7 of 48 layers) and ``kimi-linear-geometry``
     (``kimi_linear``: recurrent slot state in three KDA layers of four
     beside the latent pool of the fourth; 5 of 27 layers, 128 of 256
-    experts a layer).
+    experts a layer) and ``nemotron-3-nano-geometry`` (``nemotron_h``:
+    blocks that are a mixer alone or a feed-forward part alone, recurrent
+    slot state in four Mamba-2 mixers beside one attention block's paged
+    keys, two-matrix ``relu ** 2`` experts; 9 of 52 blocks, 64 of 128
+    experts a routed block).
     """
 
     task = "text-generation"
@@ -398,10 +402,13 @@ class VllmService(ModelService):
         loop = getattr(self, "loop", None)
         if loop is not None:
             loop.drain(budget_s)
+        eng = getattr(self, "_engine", None)
+        if eng is not None and (loop is None or not loop.alive):
+            # a stopped engine gives its loaded programs back
+            eng.release_executables()
         # bounded copy-out join: an in-flight KV demotion copy publishes
         # (or is abandoned, logged) INSIDE the grace period instead of the
         # daemon thread being orphaned until SIGKILL mid-transfer
-        eng = getattr(self, "_engine", None)
         tier = getattr(getattr(eng, "cache", None), "tier", None)
         if tier is not None:
             tier.close(max(0.5, budget_s - (_time.monotonic() - t0)))

@@ -14,7 +14,12 @@ both routed cells' widths and largest decode buckets (128 experts of 2048 x
 configurations' largest prefill programs (2048 rows over 128 of Kimi's 256
 experts of 2304 x 1024, 2048 over Kanana's 128, 1024 over Trinity's); and
 Kimi-Linear's: flash over 16,384
-keys, KDA's chunk and step kernels at 32 heads of 128. The case builders themselves
+keys, KDA's chunk and step kernels at 32 heads of 128; and Nemotron-3-Nano's:
+the state-space chunk kernel over its largest prefill program (4 rows of
+512) and its step kernel at 128 rows (64 heads of 64 x 128, 8 groups), both
+expert kernels on two-matrix ``relu ** 2`` experts of 2688 x 1856 (1856 = 14
+x 128 + 64: whole-width blocks), 64 of 128 held, and flash and the paged
+kernel at 32 query heads over 2 key/value heads. The case builders themselves
 are checked against their oracles in interpret mode by the smoke's dry run
 (``tests/test_chip_smoke.py``; the expert cases by
 ``tests/test_moe_ffn_kernel.py``).
@@ -55,6 +60,22 @@ def _cases():
         32, 192, 128, 2048, 16384))
     for c in kernel_check.kda_cases(32, 128, bucket=2048, max_num_seqs=16):
         seen.setdefault(c.name, c)
+    # Nemotron-3-Nano's stage: both state-space kernels, the expert kernels
+    # at a width that is 64 mod 128 (the streamed at 128 and 8 rows, the
+    # tiled over a 2048-row program with half the experts held), and the
+    # attention block's 32 query heads over TWO key/value heads
+    for c in kernel_check.ssm_cases(64, 64, 128, 8, bucket=512,
+                                    prefill_rows=4, max_num_seqs=128):
+        seen.setdefault(c.name, c)
+    for c in kernel_check.expert_cases(128, 6, 2688, 1856, max_num_seqs=128,
+                                       prefill_rows=2048, held=64,
+                                       act="relu2"):
+        seen.setdefault(c.name, c)
+    for c in kernel_check.engine_cases(32, 2, 128, buckets=(256, 512),
+                                       max_model_len=2080,
+                                       max_num_seqs=128):
+        if "int8" not in c.name:      # the boot refuses an 8-bit pool here
+            seen.setdefault(c.name, c)
     return list(seen.values())
 
 
