@@ -304,6 +304,7 @@ DEFAULT_CONTRACT = Contract(
             lock_guarded={"_steps": "_lock", "_gauges": "_lock",
                           "_tenants": "_lock", "_tenant_ttft": "_lock",
                           "_flush_reasons": "_lock",
+                          "_ahead_reasons": "_lock",
                           "_stream": "_stream_lock"},
             owning_modules=("obs/steploop.py",),
         ),

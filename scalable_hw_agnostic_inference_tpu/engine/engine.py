@@ -36,7 +36,12 @@ from ..ops.pallas.paged_attention import live_tile_tokens, tile_tokens
 from ..ops.sampling import sample_logits
 from .cache import PagedKVCache, RecurrentSpec
 from .config import EngineConfig
-from .resident import InflightStep, ResidentBatch, composition_sig
+from .resident import (
+    FirstTokens,
+    InflightStep,
+    ResidentBatch,
+    composition_sig,
+)
 from .runner import FOLD_STRIDE, make_decode, make_prefill
 from .types import (  # noqa: F401  (re-exported: public engine API)
     Finished,
@@ -484,8 +489,12 @@ class LLMEngine:
         # The lock-step path stays intact as the differential oracle.
         self._async = _resolve_async()
         self._pipe: Optional[InflightStep] = None
+        # admissions' first tokens still on the device (at most a final
+        # chunk's and an admission's, both of one step): read where the
+        # next dispatch needs them, ``_resolve_first_tokens``
+        self._first: List[FirstTokens] = []
         self._res = ResidentBatch()
-        self._t_fetch = 0.0          # last decode-readback completion
+        self._t_fetch = 0.0          # return of the last blocking read
         self._last_decode_step = -2  # step-gap continuity gate
         self._ids = itertools.count()
         self._step_count = 0
@@ -676,6 +685,7 @@ class LLMEngine:
         engine's single-owner discipline; the SHIP happens on a serving
         thread outside it. Read-only with respect to the request's
         lifecycle — :meth:`migrate_out` is snapshot + finish."""
+        self._resolve_first_tokens()   # ``pending_token`` is read below
         for r in self.waiting:
             if r.req_id == req_id:
                 # queued: no KV exists yet — a pure prompt replay (the
@@ -751,6 +761,7 @@ class LLMEngine:
         # mirrors (the extra token is the discarded lookahead, exactly
         # the _abort contract)
         self._flush_pipeline("migrate", req=cut_slot.req)
+        self._resolve_first_tokens()
         for s in self.slots:
             if s is None or s.req.req_id != req_id:
                 continue
@@ -822,8 +833,10 @@ class LLMEngine:
             # one extra token for this slot: retire it so the host mirrors
             # are current before teardown — the extra token is discarded
             # (never emitted) and its block reservation frees with the
-            # slot's release below, same flush
+            # slot's release below, same flush. A first token still on
+            # the device is read too: it was sampled, so its TTFT counts
             self._flush_pipeline(reason, req=abort_slot.req)
+            self._resolve_first_tokens()
         for s in self.slots:
             if s is not None and s.req.req_id == req_id:
                 self._record_tpot(s)
@@ -919,7 +932,12 @@ class LLMEngine:
         the step has nothing to decide: no admission work (``_can_admit``:
         a waiter AND a free slot — callers queued behind full slots are
         not work), no slot mid-prefill, no deadline due, no drafter.
-        Anything else is an event step: flush, then the lock-step order.
+        Anything else is an event step. It too dispatches before it reads:
+        the prefill or continuation program goes out BEHIND the decode
+        step in flight, the lookahead is retired while it runs, and the
+        next decode dispatch is marshalled before the admission's first
+        tokens are read (``_step_async``). Only a due deadline flushes
+        first: its teardown needs current mirrors.
         """
         outer = self.obs.begin_step(len(self.waiting))
         try:
@@ -1021,17 +1039,35 @@ class LLMEngine:
     # stop checks, on_token streaming, logprobs assembly, obs records) then
     # runs while step N+1 executes. Any event that changes batch
     # composition or control flow — join/finish/preempt, deadline expiry,
-    # cancellation, spec-decode entry, bucket change — flushes the pipeline
-    # first: the in-flight step is retired, surviving slots' host mirrors
-    # catch up, and a finished/cancelled slot's extra computed token is
+    # cancellation, spec-decode entry, bucket change — flushes the pipeline:
+    # the in-flight step is retired, surviving slots' host mirrors catch
+    # up, and a finished/cancelled slot's extra computed token is
     # discarded (never emitted; its reservation frees with the slot).
+    #
+    # An event step dispatches before it reads. Retiring step N only
+    # mirrors its sampled tokens into ``pending_token`` (and logprob
+    # entries); rows finish and slots and blocks free in ``_commit_pending``,
+    # which ran in the call that dispatched N. So the free slots, the
+    # allocator and the queue the admission ladder sees, its program's
+    # inputs and its rng fold do not depend on N's result, and
+    # ``cache.kv`` is already N's output as a future: the prefill or
+    # continuation program and the sampler behind it are queued while N
+    # still runs, and the flush that follows reads N while THEY run. The
+    # admission's first tokens stay on the device (``FirstTokens``), its
+    # rows are seated unresolved, and ``_decode_dispatch`` grows, marshals
+    # the new composition and fills positions before the one read that
+    # resolves them (``_resolve_first_tokens``). Whoever reads a running
+    # row's ``pending_token`` outside that order (abort, migration,
+    # snapshot, preemption, the drafter) flushes and resolves for itself;
+    # both are no-ops with nothing in flight. A due deadline keeps the
+    # flush first: ``_expire_deadlines`` tears rows down.
     #
     # A request that WAITS is not such an event; a request that can be
     # ADMITTED is. The gate is ``_can_admit`` (a waiter and a free slot),
     # the same predicate the admission ladder enters on, so a saturated
     # engine — every slot full, callers queued behind them: the state of
     # every batch and agent deployment — streams until a commit frees a
-    # slot, flushes once for ``admission``, admits, and re-establishes the
+    # slot, admits, flushes once for ``admission``, and re-establishes the
     # pipe in the same call. A queued request's due deadline still makes
     # an event step; cancels and migrations flush at their own sites.
     #
@@ -1067,13 +1103,21 @@ class LLMEngine:
                 and not deadline_due and self._drafter is None):
             self._steady_step()
         else:
-            if self._pipe is not None:
-                self._flush_pipeline(
-                    "deadline" if deadline_due else
-                    "admission" if admitting else
-                    "chunking" if chunking else "spec")
+            reason = ("deadline" if deadline_due else
+                      "admission" if admitting else
+                      "chunking" if chunking else "spec")
+            if deadline_due:
+                self._flush_pipeline(reason)
+            ahead, kv = self._pipe, self.cache.kv
             self._expire_deadlines()
             self._admit_phase()
+            # every prefill or continuation program hands back the pool:
+            # a new ``cache.kv`` behind a lookahead nobody retired is a
+            # program queued while step N still ran
+            if (ahead is not None and self._pipe is ahead
+                    and self.cache.kv is not kv):
+                self.obs.count_ahead(reason)
+            self._flush_pipeline(reason)
             if any(s is not None for s in self.slots):
                 self._decode_dispatch()
             self._flush_chunk()  # deferred window never outlives its step
@@ -1175,9 +1219,13 @@ class LLMEngine:
         self._commit_pending(running)
 
     def _decode_dispatch(self) -> None:
-        """Event-path decode: host-marshaled dispatch (mirrors are current)
-        with the readback DEFERRED to the next step — re-establishes the
-        pipeline in the same call that handled the event."""
+        """Event-path decode: host-marshaled dispatch (the lookahead is
+        retired) with the readback DEFERRED to the next step —
+        re-establishes the pipeline in the same call that handled the
+        event. Everything but the new rows' first tokens is known the
+        moment the admission is decided, so the grow, the new
+        composition's marshal and put, and the positions are done while
+        the admission's program runs; the first tokens are read last."""
         self.obs.phase_enter("engine.marshal")
         if self._drafter is not None and self._spec_step():
             self._step_kind = "spec"
@@ -1197,8 +1245,10 @@ class LLMEngine:
         tokens = np.zeros((Bb,), np.int32)
         pos = np.zeros((Bb,), np.int32)
         for i, s in enumerate(running):
-            tokens[i] = s.pending_token
             pos[i] = self.cache.seq(s.req.req_id).n_tokens - 1
+        self._resolve_first_tokens()
+        for i, s in enumerate(running):
+            tokens[i] = s.pending_token
         tokens_dev, pos_dev, fold = self._put_step(
             (tokens, pos, self._fold()))
         self._dispatch_async(decode, running, Bb, tokens_dev, pos_dev, a,
@@ -1223,16 +1273,23 @@ class LLMEngine:
             self.obs.count_recurrent(
                 self._state_kind,
                 rows_stepped=len(running) * self._state_layers)
-        cold = self._pipe is None
+        cold = bool(self._pipe is None and gap_ok and self._t_fetch
+                    and self._last_decode_step == self._step_count - 1)
+        # read BEFORE the dispatch hands the pool on
+        queued = cold and self._program_queued()
         with self.obs.phase("engine.decode"):
             t_d = self.obs.phase_t0
             (self.cache.kv, nxt, pos_next, fold_next, top_ids, top_lp,
              tok_lp, *fetch) = decode(*args)
-        if cold and gap_ok and self._t_fetch \
-                and self._last_decode_step == self._step_count - 1:
-            # flush/cold step: the dispatch had to wait for the readback —
-            # this gap is the serialization cost of the event
-            self.obs.step_gap.observe(max(0.0, t_d - self._t_fetch))
+        if cold:
+            # flush/cold step: how long the device had nothing queued
+            # before this dispatch. Nothing, where a program of this event
+            # step still runs; else no longer than since the last blocking
+            # read returned (the first tokens', or with none the
+            # lookahead's): that read found the device drained or left one
+            # short program behind it
+            self.obs.step_gap.observe(
+                0.0 if queued else max(0.0, t_d - self._t_fetch))
         self._last_decode_step = self._step_count
         self._pipe = InflightStep(
             sig=composition_sig(running, Bb), running=list(running),
@@ -1270,10 +1327,52 @@ class LLMEngine:
         self.obs.phase_enter(outer)
         return t_f
 
+    def _program_queued(self) -> bool:
+        """Whether the device still runs a program that writes the pool:
+        ``cache.kv`` is the last one's output, a future until it ends."""
+        return not jax.tree.leaves(self.cache.kv)[0].is_ready()
+
+    def _await_first(self, toks, logits, rows) -> None:
+        """THE end of every admission rung and of the final continuation
+        chunk: ``toks``, the sampler's output, stays on the device, and
+        ``rows`` ((row of ``toks``, the ``_Running`` just seated with an
+        unresolved token)) wait for ``_resolve_first_tokens``. The
+        lock-step oracle reads where it samples."""
+        want_lp = any(s.req.params.logprobs for _, s in rows)
+        self._first.append(
+            FirstTokens(rows, toks, logits if want_lp else None))
+        if not self._async:
+            self._resolve_first_tokens()
+
+    def _resolve_first_tokens(self) -> None:
+        """Read the admissions' first tokens back (no-op when none wait):
+        the other blocking read of the async loop, made where the token is
+        needed. ``_decode_dispatch`` calls it between its marshal and its
+        token input; every other reader of a running row's
+        ``pending_token`` before it reads. TTFT and ``t_first`` are
+        stamped here, where the token exists on the host."""
+        if not self._first:
+            return
+        recs, self._first = self._first, []
+        with self.obs.phase("engine.fetch"):
+            # shai-lint: allow(host-sync) the first tokens' one read
+            fetched = jax.device_get([r.toks for r in recs])
+        self._t_fetch = self.obs.phase_t0   # where the read returned
+        for rec, toks in zip(recs, fetched):
+            for i, s in rec.rows:
+                s.pending_token = int(toks[i])
+                s.t_first = self._mark_first_token(s.req)
+            if rec.logits is not None:
+                self._record_admission_lps(
+                    rec.logits, [int(t) for t in toks],
+                    [(i, s) for i, s in rec.rows if s.req.params.logprobs])
+
     def _flush_pipeline(self, reason: str,
                         req: Optional[Request] = None) -> None:
         """Retire the in-flight lookahead (no-op when none): the explicit
-        pipeline flush every composition/control-flow event pays. Counted
+        pipeline flush every composition/control-flow event pays. An event
+        step pays it BEHIND its admission's dispatch, so the read overlaps
+        the program; the flush still happens, it no longer drains. Counted
         per reason — a high flush rate is the 'pipeline never gets to
         stream' signal on ``/metrics``. ``req``: the request this flush is
         attributable to (abort/migrate/kv-restore sites know one) — its
@@ -1520,10 +1619,11 @@ class LLMEngine:
             out.update(req.obs_extra)
         return out
 
-    def _start_slot(self, slot: int, req: Request, tok: int) -> None:
-        """Seat a fully-prefilled request with its sampled first token."""
-        self.slots[slot] = _Running(req, slot, [], pending_token=tok,
-                                    t_first=self._mark_first_token(req))
+    def _seat(self, slot: int, req: Request) -> _Running:
+        """Seat a fully-prefilled request, its sampled first token still
+        on the device (``_await_first``)."""
+        s = self.slots[slot] = _Running(req, slot, [], pending_token=-1)
+        return s
 
     def generate(self, prompts: Sequence[Sequence[int]],
                  params: Optional[SamplingParams] = None) -> List[Finished]:
@@ -1612,15 +1712,10 @@ class LLMEngine:
         # (vision-conditioned) requests, whose blocks must NOT
         # content-address by tokens alone — and cross engines disable the
         # cache at construction anyway
-        rng = self._admit_rng()
-        with self.obs.phase("engine.fetch"):
-            tok = int(self._sample1(
-                logits, rng, req.params.temperature, req.params.top_k,
-                req.params.top_p)[0])
-        self._start_slot(slot, req, tok)
-        if req.params.logprobs:
-            self._record_admission_lps(logits, [tok],
-                                       [(0, self.slots[slot])])
+        toks = self._sample1(
+            logits, self._admit_rng(), req.params.temperature,
+            req.params.top_k, req.params.top_p)
+        self._await_first(toks, logits, [(0, self._seat(slot, req))])
 
     # -- re-homed plumbing (engine/warm.py, cross.py, logprobs.py) ---------
     # thin delegates so the admission ladder reads unchanged while the
@@ -1756,21 +1851,14 @@ class LLMEngine:
         for req in group:  # batch rows are always plain text
             self.cache.register_prefix(req.prompt_ids,
                                        self.cache.seq(req.req_id).blocks)
-        rng = self._admit_rng()
-        with self.obs.phase("engine.fetch"):
-            toks = np.asarray(self._sample1(
-                logits, rng, self._put(temp), self._put(topk),
-                self._put(topp)))
-        lp_rows = []
+        toks = self._sample1(logits, self._admit_rng(), self._put(temp),
+                             self._put(topk), self._put(topp))
+        rows = []
         for i, req in enumerate(group):
             slot = self._free_slot()
             self._has_image[slot] = 0.0
-            self._start_slot(slot, req, int(toks[i]))
-            if req.params.logprobs:
-                lp_rows.append((i, self.slots[slot]))
-        if lp_rows:
-            self._record_admission_lps(logits, [int(t) for t in toks],
-                                       lp_rows)
+            rows.append((i, self._seat(slot, req)))
+        self._await_first(toks, logits, rows)
 
     def _fabric_probe(self, req, hashes: List[int],
                       from_block: int) -> int:
@@ -1928,16 +2016,11 @@ class LLMEngine:
         self._note_program_pad(n, chunk_bucket - n,
                                phase="prefill")  # chunk bucket tail
         self.cache.register_prefix(req.prompt_ids, alloc.blocks)
-        rng = self._admit_rng()
-        with self.obs.phase("engine.fetch"):
-            tok = int(self._sample1(
-                logits, rng, req.params.temperature, req.params.top_k,
-                req.params.top_p)[0])
+        toks = self._sample1(
+            logits, self._admit_rng(), req.params.temperature,
+            req.params.top_k, req.params.top_p)
         self._has_image[slot] = 0.0
-        self._start_slot(slot, req, tok)
-        if req.params.logprobs:
-            self._record_admission_lps(logits, [tok],
-                                       [(0, self.slots[slot])])
+        self._await_first(toks, logits, [(0, self._seat(slot, req))])
         return True
 
     def _admit_fanout(self) -> bool:
@@ -2014,21 +2097,14 @@ class LLMEngine:
             topk[i] = r.params.top_k
             topp[i] = r.params.top_p
         tiled = jnp.broadcast_to(logits[0], (Kp,) + logits.shape[1:])
-        rng = self._admit_rng()
-        with self.obs.phase("engine.fetch"):
-            toks = np.asarray(self._sample1(
-                tiled, rng, self._put(temp), self._put(topk),
-                self._put(topp)))
-        lp_rows = []
+        toks = self._sample1(tiled, self._admit_rng(), self._put(temp),
+                             self._put(topk), self._put(topp))
+        rows = []
         for i, r in enumerate(group):
             slot = self._free_slot()
             self._has_image[slot] = 0.0
-            self._start_slot(slot, r, int(toks[i]))
-            if r.params.logprobs:
-                lp_rows.append((i, self.slots[slot]))
-        if lp_rows:
-            self._record_admission_lps(tiled, [int(t) for t in toks],
-                                       lp_rows)
+            rows.append((i, self._seat(slot, r)))
+        self._await_first(toks, tiled, rows)
         return True
 
     def _admit_long(self) -> None:
@@ -2147,15 +2223,13 @@ class LLMEngine:
             # either single-fold stream
             rng = jax.random.fold_in(
                 jax.random.fold_in(self._rng, self._step_count), 3)
-            with self.obs.phase("engine.fetch"):
-                tok = int(self._sample1(
-                    logits, rng, req.params.temperature, req.params.top_k,
-                    req.params.top_p)[0])
-            s.pending_token = tok
+            toks = self._sample1(
+                logits, rng, req.params.temperature, req.params.top_k,
+                req.params.top_p)
+            # joins the decode batch, its token unresolved like any
+            # admitted row's
             s.prefill_cursor = None
-            s.t_first = self._mark_first_token(req)
-            if req.params.logprobs:
-                self._record_admission_lps(logits, [tok], [(0, s)])
+            self._await_first(toks, logits, [(0, s)])
         else:
             # intermediate chunk: its full blocks are final too — publish
             # them per chunk instead of only at prompt completion (the
@@ -2420,10 +2494,12 @@ class LLMEngine:
         an unauthenticated X-SHAI-Priority header must not become a free
         anti-preemption lever on a FIFO pod (and the differential oracle
         stays exact even for tagged traffic)."""
-        # defensive: preemption streams/commits the victim's pending token,
-        # so the host mirror must be current (the event paths flush before
-        # ever reaching the allocator; this covers any future caller)
+        # preemption streams/commits the victim's pending token, so the
+        # host mirror must be current: the event paths flush before they
+        # reach the allocator, but the victim may be the row this very
+        # step admitted, its first token still on the device
         self._flush_pipeline("preempt")
+        self._resolve_first_tokens()
         victims = [s for s in self.slots if s is not None]
         if self._sched is not None:
             victim = max(victims,
@@ -2673,6 +2749,7 @@ class LLMEngine:
         running = self._running_slots()
         if not running:
             return False
+        self._resolve_first_tokens()   # the drafter reads pending tokens
         drafts: Dict[int, List[int]] = {}
         for s in running:
             p = s.req.params

@@ -33,7 +33,15 @@ holds none; ``_put_step`` is the same put, counted as
   step later), the donated next-positions array, the next step's rng
   fold index (a device counter the program advances), and the logprob
   outputs.
-  Retiring the record is the ONLY place the host blocks on the device.
+
+* :class:`FirstTokens` records one admission's sampled first tokens, left
+  on the device behind the prefill (or final continuation) program that
+  made them: the rows are seated at once with an unresolved token, and
+  the engine reads the record where the token is NEEDED (the next decode
+  dispatch's token input, after its marshal), not where it is made.
+
+  Retiring the lookahead and resolving the first tokens are the ONLY two
+  places the async loop blocks on the device.
 
 Layering: pure data + marshaling helpers; the scheduling policy (when to
 flush, when to reuse) lives in ``engine.engine``.
@@ -81,6 +89,15 @@ class InflightStep:
         return sum(int(getattr(a, "nbytes", 0) or 0)
                    for a in (self.nxt, self.pos_next, self.top_ids,
                              self.top_lp, self.tok_lp))
+
+
+@dataclasses.dataclass
+class FirstTokens:
+    """One admission's first tokens, sampled and not yet read back."""
+
+    rows: List[Tuple[int, Any]]       # (row of ``toks``, the seated _Running)
+    toks: Any                         # device [K] sampled tokens
+    logits: Optional[Any]             # kept only where a row wants logprobs
 
 
 class ResidentBatch:

@@ -19,7 +19,9 @@ and the self-repetition every greedy decode drifts into.
 Async-decode interplay (``SHAI_ASYNC_DECODE``, engine.resident): drafting
 reads each slot's ``pending_token``, so a speculative step is a pipeline
 *event* — the engine flushes (retires) any in-flight lookahead dispatch
-before ``_spec_step`` runs, and the verify dispatch shares the
+before ``_spec_step`` runs, ``_spec_step`` reads back first tokens an
+admission of the same step left on the device, and the verify dispatch
+shares the
 device-resident batch view (tables/active/sampling knobs) with decode
 instead of re-marshaling it host->device per step.
 

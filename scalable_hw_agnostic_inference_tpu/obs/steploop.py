@@ -522,6 +522,12 @@ class StepTelemetry:
         self.pipeline_flushes = 0
         self._flush_reasons: Dict[str, int] = {}
         self._step_flushes: List[str] = []   # the open step's, by reason
+        # event steps whose prefill or continuation program was queued
+        # while a decode step was still in flight (by the step's reason,
+        # ``admission`` or ``chunking``): the flush behind it read that
+        # step while the program ran, and drained nothing
+        self.events_dispatched_ahead = 0
+        self._ahead_reasons: Dict[str, int] = {}
         # pad-waste accounting: per dispatch, how many token slots the
         # executable walked for REAL context vs shape padding (batch pad
         # rows + the paged kernel's tiles beyond each row's live tokens +
@@ -605,6 +611,14 @@ class StepTelemetry:
             if reason:
                 self._flush_reasons[reason] = (
                     self._flush_reasons.get(reason, 0) + 1)
+
+    def count_ahead(self, reason: str) -> None:
+        """One event step that dispatched its program behind the decode
+        step in flight."""
+        with self._lock:
+            self.events_dispatched_ahead += 1
+            self._ahead_reasons[reason] = (
+                self._ahead_reasons.get(reason, 0) + 1)
 
     # -- phases of the engine-loop thread -----------------------------------
 
@@ -1044,6 +1058,7 @@ class StepTelemetry:
                 "warmed_executables": self.warmed_executables,
                 "kv_blocks_total": self.total_blocks,
                 "pipeline_flushes": self.pipeline_flushes,
+                "events_dispatched_ahead": self.events_dispatched_ahead,
                 "decode_input_uploads": self.decode_input_uploads,
                 "tokens_committed": self.tokens_committed,
                 "pad_tokens": self.pad_tokens,
@@ -1060,6 +1075,7 @@ class StepTelemetry:
                 for p in set(self.real_by_phase) | set(self.pad_by_phase)}
             out["dispatches_by_phase"] = dict(self.dispatches_by_phase)
             out["flush_by_reason"] = dict(self._flush_reasons)
+            out["ahead_by_reason"] = dict(self._ahead_reasons)
             if self.moe is not None:
                 out["moe"] = dict(self.moe)
             if self.window is not None:

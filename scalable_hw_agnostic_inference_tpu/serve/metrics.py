@@ -52,9 +52,10 @@ ENGINE_HISTOGRAMS = {
     "queue_wait_seconds": ("shai_queue_wait_seconds",
                            "Submit-to-admission wait in the engine queue"),
     "step_gap_seconds": ("shai_engine_step_gap_seconds",
-                         "Inter-step device gap: host time between a decode "
-                         "readback and the next dispatch (0 when the async "
-                         "pipeline dispatched ahead of the readback)"),
+                         "Inter-step device gap: host time between the last "
+                         "blocking readback and the next decode dispatch (0 "
+                         "when the dispatch went out ahead of the readback "
+                         "or behind a program still running)"),
     "intake_wait_seconds": ("shai_intake_wait_seconds",
                             "Submit on the caller's thread to intake by "
                             "the engine loop, which runs between steps"),
@@ -116,6 +117,10 @@ _ENGINE_COUNTERS = {
     "pipeline_flushes": ("shai_engine_pipeline_flushes",
                          "Async-decode lookahead steps retired early by a "
                          "composition/control-flow event"),
+    "events_dispatched_ahead": ("shai_engine_events_dispatched_ahead",
+                                "Event steps whose prefill or continuation "
+                                "program was queued while a decode step was "
+                                "still in flight"),
     "decode_input_uploads": ("shai_engine_decode_input_uploads",
                              "Host-to-device arrays put for decode, verify "
                              "and fused dispatches (a block-table refresh "

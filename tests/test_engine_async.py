@@ -721,3 +721,281 @@ def test_a_request_submitted_while_a_step_runs_waits_at_intake(
     req = next(r for r in eng.waiting if r.req_id == rid)
     assert req.t_enqueue == req.t_submit
     assert eng.obs.histograms()["intake_wait_seconds"]["count"] == 2
+
+
+# ---------------------------------------------------------------------------
+# an event step dispatches before it reads
+# ---------------------------------------------------------------------------
+
+def record_order(eng, monkeypatch):
+    """Recording wrappers round the step programs, the sampler and the
+    loop's reads: the returned list holds, in call order, ``prefill``,
+    ``cont`` and ``decode`` (a program's dispatch), ``sample``, ``retire``
+    (the lookahead's read), ``marshal`` (a full ``_marshal_running``) and
+    ``first_read`` (a ``_resolve_first_tokens`` with something to read)."""
+    log = []
+
+    def noting(name, fn):
+        def run(*args, **kw):
+            log.append(name)
+            return fn(*args, **kw)
+        return run
+
+    for attr, name in (("_sample1", "sample"), ("_retire_pipe", "retire"),
+                       ("_marshal_running", "marshal")):
+        monkeypatch.setattr(eng, attr, noting(name, getattr(eng, attr)))
+
+    def note_programs(attr, name):
+        """``attr`` hands out a program, or (batch bucket, program)."""
+        inner = getattr(eng, attr)
+
+        def get(*args, **kw):
+            out = inner(*args, **kw)
+            if isinstance(out, tuple):
+                return out[0], noting(name, out[1])
+            return noting(name, out)
+        monkeypatch.setattr(eng, attr, get)
+
+    for attr, name in (("_prefill_for", "prefill"), ("_cont_for", "cont"),
+                       ("_decode_for", "decode")):
+        note_programs(attr, name)
+    resolve = eng._resolve_first_tokens
+
+    def noted_resolve():
+        if eng._first:
+            log.append("first_read")
+        resolve()
+
+    monkeypatch.setattr(eng, "_resolve_first_tokens", noted_resolve)
+    return log
+
+
+LONG = [1] + [7, 9, 11] * 23          # 70 tokens: chunks of 32, 32 and 6
+ORDER_CASES = {
+    # case: (chunk steps taken before the step under test, prompts that
+    # arrive for it, the step's calls in order, its reason)
+    "batch-admission": (
+        None, [[8, 8, 9], [5, 6]],
+        ["prefill", "sample", "retire", "marshal", "first_read", "decode"],
+        "admission"),
+    "long-prompt-admission": (
+        None, [LONG], ["prefill", "retire", "decode"], "admission"),
+    "intermediate-chunk": (
+        0, [], ["cont", "retire", "decode"], "chunking"),
+    "final-chunk": (
+        1, [],
+        ["cont", "sample", "retire", "marshal", "first_read", "decode"],
+        "chunking"),
+    "admission-beside-a-final-chunk": (
+        1, [[8, 8, 9]],
+        ["cont", "sample", "prefill", "sample", "retire", "marshal",
+         "first_read", "decode"],
+        "admission"),
+}
+
+
+@pytest.mark.parametrize("case", sorted(ORDER_CASES))
+def test_an_event_step_dispatches_before_it_reads(tiny_model, monkeypatch,
+                                                  case):
+    """With a lookahead in flight, an event step queues its prefill or
+    continuation program and the sampler BEFORE step N is read, marshals
+    the new composition before it reads the first tokens, and counts
+    itself in ``events_dispatched_ahead``."""
+    chunks_before, arrivals, want, reason = ORDER_CASES[case]
+    eng = make_engine(tiny_model, True, monkeypatch, max_model_len=128)
+    sp = SamplingParams(temperature=0.0, max_new_tokens=40)
+    eng.add_request([3, 4, 5], sp)
+    for _ in range(3):
+        eng.step()
+    if chunks_before is not None:
+        eng.add_request(LONG, sp)
+        for _ in range(1 + chunks_before):
+            eng.step()
+        assert eng.n_chunking == 1
+    assert eng._pipe is not None, "no lookahead in flight"
+    before = eng.obs.snapshot()
+    for prompt in arrivals:
+        eng.add_request(prompt, sp)
+    log = record_order(eng, monkeypatch)
+    eng.step()
+    assert log == want
+    # the reads lie behind every program and sampler of the admission
+    last_queued = max(i for i, c in enumerate(log)
+                      if c in ("prefill", "cont", "sample"))
+    assert last_queued < log.index("retire")
+    snap = eng.obs.snapshot()
+    assert snap["events_dispatched_ahead"] \
+        == before["events_dispatched_ahead"] + 1
+    assert snap["ahead_by_reason"].get(reason, 0) \
+        == before["ahead_by_reason"].get(reason, 0) + 1
+    # a flush still happens, under the same reason; it no longer drains
+    assert snap["flush_by_reason"].get(reason, 0) \
+        == before["flush_by_reason"].get(reason, 0) + 1
+    assert not eng._first and eng._pipe is not None
+    assert all(s is None or s.prefill_cursor is not None
+               or s.pending_token >= 0 for s in eng.slots)
+    while eng.has_work:
+        eng.step()
+    assert pool_balanced(eng)
+
+
+def _run_timed(eng, schedule, sp_of, kw_of=lambda i: {}):
+    """``_run_schedule`` that also says WHEN: (finished by add index, the
+    ``step()`` call each finished in, every streamed (add index, token) in
+    callback order, the TTFT count behind each call)."""
+    fins, fin_step, events, index_of, ttfts = {}, {}, [], {}, []
+    step = 0
+    while True:
+        for prompt in schedule.get(step, ()):
+            i = len(index_of)
+            rid = eng.add_request(
+                prompt, sp_of(i),
+                on_token=lambda t, i=i: events.append((i, t)), **kw_of(i))
+            index_of[rid] = i
+        if eng.has_work:
+            for f in eng.step():
+                fins[index_of[f.req_id]] = f
+                fin_step[index_of[f.req_id]] = step
+            ttfts.append(eng.ttft.count)
+        step += 1
+        if not eng.has_work and step > max(schedule, default=0):
+            return fins, fin_step, events, ttfts
+
+
+def _assert_same_timed(out_async, out_lock):
+    (fa, sa, ea, ta), (fb, sb, eb, tb) = out_async, out_lock
+    assert set(fa) == set(fb)
+    for i in fa:
+        assert_finished_equal(fa[i], fb[i])
+    assert sa == sb, "a request finished on another step() call"
+    assert ea == eb, "on_token order diverged"
+    assert ta == tb, "a first token reached the host on another call"
+
+
+def _first_token_of(tiny_model, monkeypatch, prompt):
+    probe = make_engine(tiny_model, False, monkeypatch)
+    [fin] = probe.generate([prompt], SamplingParams(temperature=0.0,
+                                                    max_new_tokens=2))
+    return fin.token_ids[0]
+
+
+JOIN = {0: [[3, 4, 5]], 4: [[8, 8, 9], [5, 6]], 7: [LONG], 9: [[42, 43]]}
+SEAM_CASES = {
+    "first-token-is-eos": dict(eos_of=[8, 8, 9]),
+    "one-new-token": dict(sp=dict(max_new_tokens=1)),
+    "seeded-topk": dict(sp=dict(temperature=0.9, top_k=5)),
+    "seeded-topp": dict(sp=dict(temperature=0.7, top_p=0.8)),
+    "logprobs": dict(sp=dict(logprobs=3)),
+    "preempted-in-the-step-that-admits": dict(
+        schedule={0: [[11, 7, 7, 7], [12, 7, 7, 7]], 4: [[13] + [9] * 9]},
+        sp=dict(max_new_tokens=14), over=dict(num_blocks=6), preempts=True),
+}
+
+
+@pytest.mark.parametrize("case", sorted(SEAM_CASES))
+def test_unresolved_first_tokens_match_lockstep(tiny_model, monkeypatch,
+                                                case):
+    """Rows joining behind a lookahead in flight, where the first token
+    decides something at once: tokens, logprobs, finish calls, stream order
+    and the call each TTFT lands in are the lock-step oracle's."""
+    spec = SEAM_CASES[case]
+    knobs = dict(temperature=0.0, max_new_tokens=9)
+    knobs.update(spec.get("sp", {}))
+    if "eos_of" in spec:
+        knobs["eos_id"] = _first_token_of(tiny_model, monkeypatch,
+                                          spec["eos_of"])
+    over = dict(max_model_len=128)
+    over.update(spec.get("over", {}))
+    out, snaps = {}, {}
+    for mode in (True, False):
+        eng = make_engine(tiny_model, mode, monkeypatch, **over)
+        preempt, unresolved = eng._preempt_lowest, []
+        monkeypatch.setattr(
+            eng, "_preempt_lowest",
+            lambda: (unresolved.append(bool(eng._first)), preempt())[1])
+        out[mode] = _run_timed(eng, spec.get("schedule", JOIN),
+                               lambda i: SamplingParams(**knobs))
+        if mode and spec.get("preempts"):
+            # the victim was the row this step admitted, token unread
+            assert any(unresolved)
+        snaps[mode] = eng.obs.snapshot()
+        assert pool_balanced(eng) and not eng._first
+    _assert_same_timed(out[True], out[False])
+    assert snaps[True]["events_dispatched_ahead"] >= 1
+    assert snaps[False]["events_dispatched_ahead"] == 0
+    if "eos_of" in spec:
+        assert out[True][0][1].stop_reason == "eos"
+        assert out[True][0][1].token_ids == []
+    if spec.get("preempts"):
+        assert snaps[True]["preemptions"] == snaps[False]["preemptions"] > 0
+
+
+@pytest.mark.parametrize("leave", ["cancel", "deadline"])
+def test_a_row_leaves_with_its_first_token_unresolved(tiny_model,
+                                                      monkeypatch, leave):
+    """No step ends with a first token still on the device, so the state
+    is made by hand (the admission ladder alone, behind a lookahead in
+    flight): the teardown reads the token back first, so its TTFT counts
+    as the oracle's does, nothing is emitted, and the blocks are
+    conserved."""
+    sp = SamplingParams(temperature=0.0, max_new_tokens=12)
+    out = {}
+    for mode in (True, False):
+        eng = make_engine(tiny_model, mode, monkeypatch)
+        keep = eng.add_request([3, 4, 5], sp)
+        for _ in range(3):
+            eng.step()
+        rid = eng.add_request(
+            [8, 8, 9], sp,
+            deadline_at=(time.monotonic() + 0.05 if leave == "deadline"
+                         else 0.0))
+        eng._admit_phase()
+        assert bool(eng._first) is mode
+        assert (eng._pipe is not None) is mode
+        n_ttft = eng.ttft.count
+        if leave == "cancel":
+            fins = {rid: eng.cancel(rid)}
+        else:
+            time.sleep(0.06)
+            fins = {f.req_id: f for f in eng.step()}
+        assert not eng._first
+        assert eng.ttft.count == n_ttft + (1 if mode else 0) == 2
+        while eng.has_work:
+            for f in eng.step():
+                fins[f.req_id] = f
+        out[mode] = fins
+        assert fins[rid].stop_reason == ("cancelled" if leave == "cancel"
+                                         else "timeout")
+        assert fins[rid].token_ids == []
+        assert pool_balanced(eng)
+    for rid in out[True]:
+        assert_finished_equal(out[True][rid], out[False][rid])
+    assert len(out[True][keep].token_ids) == 12
+
+
+def test_an_admission_behind_a_lookahead_on_recurrent_state(monkeypatch):
+    """The same order over a slot arena (the ``tiny-ssm`` stand-in): the
+    admitted rows are seated WITH their arena slots while the step in
+    flight still steps the others'; tokens and finish calls are the
+    oracle's, blocks and slots balance."""
+    from scalable_hw_agnostic_inference_tpu.models.llama import (
+        geometry_params,
+    )
+
+    cfg = LlamaConfig.tiny_ssm()
+    model = (cfg, geometry_params(cfg, dtype=jnp.float32, seed=3))
+    schedule = {0: [[3, 4, 5, 6]], 4: [[8, 8, 9], [5, 6, 7, 7, 2]],
+                6: [[1] + [7, 9] * 20]}       # 41 tokens: two programs
+    out = {}
+    for mode in (True, False):
+        eng = make_engine(model, mode, monkeypatch, max_model_len=128)
+        out[mode] = _run_timed(
+            eng, schedule,
+            lambda i: SamplingParams(temperature=0.0, max_new_tokens=7))
+        snap = eng.obs.snapshot()
+        assert pool_balanced(eng) and not eng._first
+        assert eng.cache.slots_live == 0 and eng.cache.leaked_bytes == 0
+        if mode:
+            assert snap["ahead_by_reason"].get("admission", 0) >= 2
+            assert snap["ahead_by_reason"].get("chunking", 0) >= 1
+    _assert_same_timed(out[True], out[False])

@@ -323,6 +323,89 @@ def test_counters_by_reason_and_phase_ride_the_snapshot():
     assert snap["intake_wait_count"] == 0
 
 
+def test_events_dispatched_ahead_ride_the_snapshot_by_reason():
+    t = StepTelemetry()
+    assert t.snapshot()["events_dispatched_ahead"] == 0
+    t.count_ahead("admission")
+    t.count_ahead("admission")
+    t.count_ahead("chunking")
+    snap = t.snapshot()
+    assert snap["events_dispatched_ahead"] == 3
+    assert snap["ahead_by_reason"] == {"admission": 2, "chunking": 1}
+    assert snap["pipeline_flushes"] == 0      # a flush is counted apart
+
+
+def _gap(eng):
+    snap = eng.obs.step_gap.snapshot()
+    return snap["count"], snap["sum"]
+
+
+@pytest.mark.parametrize("queued", [True, False],
+                         ids=["program-still-queued", "device-drained"])
+def test_step_gap_of_an_event_step(tiny_model, monkeypatch, queued):
+    """``step_gap`` is how long the device had nothing queued before a
+    decode dispatch. On an event step that is nothing where the dispatch
+    found a program of the step still running, and otherwise no longer
+    than the time since the first tokens' read returned: not the time
+    since step N was retired, which lies before the marshal."""
+    from scalable_hw_agnostic_inference_tpu.engine.engine import (
+        SamplingParams,
+    )
+
+    eng = make_engine(tiny_model)
+    assert eng._async
+    sp = SamplingParams(temperature=0.0, max_new_tokens=30)
+    # a step that builds a program observes no gap: build them first
+    eng.generate([[3, 4, 5], [8, 8, 9]],
+                 SamplingParams(temperature=0.0, max_new_tokens=2))
+    eng.finish_pending()
+    eng.add_request([3, 4, 5], sp)
+    for _ in range(3):
+        eng.step()
+    assert eng._pipe is not None
+    stamps = {}
+    retire, resolve = eng._retire_pipe, eng._resolve_first_tokens
+    decode_for = eng._decode_for
+
+    def stamped_retire(pipe):
+        stamps["retired"] = t = retire(pipe)
+        return t
+
+    def stamped_resolve():
+        pending = bool(eng._first)
+        resolve()
+        if pending:
+            stamps["first_read"] = eng._t_fetch
+
+    def stamped_decode_for(*a, **kw):
+        bb, fn = decode_for(*a, **kw)
+
+        def run(*args):
+            stamps["dispatch"] = time.monotonic()
+            return fn(*args)
+        return bb, run
+
+    monkeypatch.setattr(eng, "_retire_pipe", stamped_retire)
+    monkeypatch.setattr(eng, "_resolve_first_tokens", stamped_resolve)
+    monkeypatch.setattr(eng, "_decode_for", stamped_decode_for)
+    if queued:
+        monkeypatch.setattr(eng, "_program_queued", lambda: True)
+    eng.add_request([8, 8, 9], sp)
+    n0, sum0 = _gap(eng)
+    eng.step()                       # admits behind the lookahead
+    n1, sum1 = _gap(eng)
+    assert n1 == n0 + 1
+    assert stamps["retired"] < stamps["first_read"] <= stamps["dispatch"]
+    if queued:
+        assert sum1 == sum0
+    else:
+        assert 0.0 <= sum1 - sum0 <= (stamps["dispatch"]
+                                      - stamps["first_read"]) + 1e-9
+        assert sum1 - sum0 < stamps["dispatch"] - stamps["retired"]
+    while eng.has_work:
+        eng.step()
+
+
 def test_phase_spans_put_intake_before_queue():
     tr = Trace("req")
     now = time.monotonic()
@@ -664,6 +747,27 @@ async def test_metrics_exposes_engine_histograms_and_gauges(spec_app):
         assert not any(k.startswith("pipeline_flush_")
                        for k in st["service"])
         assert "exports" in st["aot"]
+
+
+@pytest.mark.asyncio
+async def test_events_dispatched_ahead_on_stats_and_metrics(spec_app):
+    pytest.importorskip("prometheus_client")
+    cfg, service, app = spec_app
+    async with make_client(app) as c:
+        await wait_ready(c, timeout=600.0)
+        await c.post("/generate", json={"prompt": "p q r p q r",
+                                        "temperature": 0.0,
+                                        "max_new_tokens": 4})
+        text = (await c.get("/metrics")).text
+        eng = (await c.get("/stats")).json()["engine"]
+    # beside the flushes, in both places, and by reason on /stats
+    assert "shai_engine_pipeline_flushes_total" in text
+    line = next(ln for ln in text.splitlines() if ln.startswith(
+        'shai_engine_events_dispatched_ahead_total{app="llm-obs"}'))
+    assert float(line.split()[-1]) >= eng["events_dispatched_ahead"] >= 0
+    assert (sum(eng["ahead_by_reason"].values())
+            == eng["events_dispatched_ahead"])
+    assert "flush_by_reason" in eng
 
 
 @pytest.mark.asyncio
