@@ -15,11 +15,13 @@ around it:
 - :func:`recurrence`: one ``lax.scan`` step a token, float32. THE oracle:
   the chunked form, both Pallas kernels (``ops.pallas.kda_chunk``,
   ``ops.pallas.kda_step``) and the served path's tests are held to it.
-- :func:`chunk_math`: ``CHUNK`` tokens at once from the state that enters
-  the chunk (the WY form of the delta rule with a per-channel decay). It is
-  written on plain 2-D values so that the chunk kernel's body IS this
-  function; :func:`chunked` maps it over rows and heads and scans it over
-  the chunks (the engine's prefill off the TPU).
+- :func:`chunk_math`: ``CHUNK`` tokens at once from the states that enter
+  the chunk (the WY form of the delta rule with a per-channel decay), for a
+  GROUP of heads on a leading axis: 19 matrix products a head, the heads'
+  chains side by side. It is written on plain values so that the chunk
+  kernel's body IS this function (a grid step takes a group of heads);
+  :func:`chunked` maps it over rows, a row's heads one group, and scans it
+  over the chunks (the engine's prefill off the TPU).
 - :func:`step`: one token for a batch of rows (the engine's decode off the
   TPU; the step kernel's oracle beside the recurrence).
 
@@ -163,14 +165,53 @@ def recurrence(q, k, v, g, beta, s0=None):
 
 # -- the chunked form -------------------------------------------------------
 
-def _mm(a, b):
-    return jax.lax.dot_general(a, b, (((1,), (0,)), ((), ())), precision=_HI,
-                               preferred_element_type=jnp.float32)
+def _mm(a, b, precision=_HI):
+    """``a [..., M, K] @ b [..., K, N]``, the leading (head) axes batched."""
+    n = a.ndim - 2
+    return jax.lax.dot_general(
+        a, b, (((n + 1,), (n,)), (tuple(range(n)),) * 2),
+        precision=precision, preferred_element_type=jnp.float32)
 
 
 def _mm_nt(a, b):
-    return jax.lax.dot_general(a, b, (((1,), (1,)), ((), ())), precision=_HI,
-                               preferred_element_type=jnp.float32)
+    """``a [..., M, K] @ b [..., N, K]^T``, the leading axes batched."""
+    n = a.ndim - 2
+    return jax.lax.dot_general(
+        a, b, (((n + 1,), (n + 1,)), (tuple(range(n)),) * 2),
+        precision=_HI, preferred_element_type=jnp.float32)
+
+
+def _mm_tn(a, b):
+    """``a [..., K, M]^T @ b [..., K, N]``, the leading axes batched."""
+    n = a.ndim - 2
+    return jax.lax.dot_general(
+        a, b, (((n,), (n,)), (tuple(range(n)),) * 2),
+        precision=_HI, preferred_element_type=jnp.float32)
+
+
+def _bf16_part(x):
+    """The leading bfloat16 of a float32, by truncation, as a float32."""
+    bits = jax.lax.bitcast_convert_type(x, jnp.uint32)
+    return jax.lax.bitcast_convert_type(bits & jnp.uint32(0xFFFF0000),
+                                        jnp.float32)
+
+
+def _mask_mm(mask, x):
+    """``mask @ x`` for a 0/1 ``mask`` ``[M, K]`` and a general float32
+    ``x`` ``[..., K, N]``, in the three passes that are not a product with
+    an exact zero. ``Precision.HIGHEST`` on the TPU is six products of
+    bfloat16 parts (``hi``, ``mid``, ``lo`` of each operand, split by
+    truncation); a mask's ``mid`` and ``lo`` are exactly 0, so three of the
+    six add nothing. What is left is ``mask @ lo + mask @ mid + mask @
+    hi``, in that order (the compiler's own), each a product of operands
+    that bfloat16 holds exactly: one pass each, whatever the precision."""
+    hi = _bf16_part(x)
+    mid = _bf16_part(x - hi)
+    lo = _bf16_part((x - hi) - mid)
+    m = jnp.broadcast_to(mask.astype(jnp.float32),
+                         x.shape[:-2] + mask.shape)
+    one = jax.lax.Precision.DEFAULT
+    return (_mm(m, lo, one) + _mm(m, mid, one)) + _mm(m, hi, one)
 
 
 def _neumann(x, eye, n: int):
@@ -186,9 +227,10 @@ def _neumann(x, eye, n: int):
 
 
 def chunk_math(q, k, kb, vb, g, st):
-    """One chunk of one head. ``q, k, g`` ``[C, d]``; ``kb = beta * k`` and
-    ``vb = beta * v`` ``[C, d]``; ``st`` ``[d_v, d_k]`` the transposed state
-    that enters. All float32. Returns ``(o [C, d_v], st_out)``.
+    """One chunk of a GROUP of heads, the heads' chains side by side.
+    ``q, k, g`` ``[Hg, C, d]``; ``kb = beta * k`` and ``vb = beta * v``
+    ``[Hg, C, d]``; ``st`` ``[Hg, d_v, d_k]`` the transposed states that
+    enter. All float32. Returns ``(o [Hg, C, d_v], st_out)``.
 
     With ``G`` the inclusive cumulative log-decay, ``u`` the delta rule's
     corrected values solve ``(I + A) u = beta (v - K+ S0)``, ``A_ij = beta_i
@@ -198,33 +240,45 @@ def chunk_math(q, k, kb, vb, g, st):
     the cumulative decay at the middle of the row's block of ``BLOCK`` rows,
     never against the chunk's start alone (module docstring); ``(I + A)^-1``
     is the block-diagonal part's Neumann product times the block-lower
-    remainder's, matrix products only."""
-    C, d = q.shape
+    remainder's, matrix products only.
+
+    19 matrix products a head (``C = 64``, ``BLOCK = 16``): the cumulative
+    decay (1: a 0/1 mask's product, three passes, :func:`_mask_mm`); a
+    block's ``A`` and ``P`` rows in ONE product, ``[kb e ; q e]`` against the
+    block's ``k e^-`` (4); the Neumann products (6, then 1, 2, 1); ``[kb ;
+    q] e^G`` against the state in ONE product (1); ``u``, ``P u`` and the
+    state's update (3). The reference point of block ``lo`` is the
+    cumulative decay through its row ``BLOCK / 2 - 1``, which IS a row of
+    ``G``: no second mask product."""
+    C = q.shape[-2]
     row = jax.lax.broadcasted_iota(jnp.int32, (C, C), 0)
     col = jax.lax.broadcasted_iota(jnp.int32, (C, C), 1)
-    G = _mm((col <= row).astype(jnp.float32), g)              # cumulative
-    # the cumulative decay at the MIDDLE of each row's block: the reference
-    # point of the block's exponents, half a block from any of its rows
-    Gs = _mm((col < (row // BLOCK) * BLOCK + BLOCK // 2).astype(jnp.float32),
-             g)
+    G = _mask_mm(col <= row, g)                               # cumulative
     a_rows, p_rows = [], []
     for lo in range(0, C, BLOCK):
-        rs = Gs[lo:lo + 1]                                    # [1, d]
-        e = jnp.exp(G[lo:lo + BLOCK] - rs)
-        kneg = k * jnp.exp(jnp.minimum(rs - G, _EXP_CAP))     # [C, d]
-        a_rows.append(_mm_nt(kb[lo:lo + BLOCK] * e, kneg))
-        p_rows.append(_mm_nt(q[lo:lo + BLOCK] * e, kneg))
-    A = jnp.where(col < row, jnp.concatenate(a_rows, axis=0), 0.0)
-    P = jnp.where(col <= row, jnp.concatenate(p_rows, axis=0), 0.0)
+        # the cumulative decay at the MIDDLE of the block: the reference
+        # point of its exponents, half a block from any of its rows
+        mid = lo + BLOCK // 2 - 1
+        rs = G[..., mid:mid + 1, :]                           # [Hg, 1, d]
+        e = jnp.exp(G[..., lo:lo + BLOCK, :] - rs)
+        kneg = k * jnp.exp(jnp.minimum(rs - G, _EXP_CAP))     # [Hg, C, d]
+        both = _mm_nt(jnp.concatenate(
+            [kb[..., lo:lo + BLOCK, :] * e, q[..., lo:lo + BLOCK, :] * e],
+            axis=-2), kneg)                                   # [Hg, 2B, C]
+        a_rows.append(both[..., :BLOCK, :])
+        p_rows.append(both[..., BLOCK:, :])
+    A = jnp.where(col < row, jnp.concatenate(a_rows, axis=-2), 0.0)
+    P = jnp.where(col <= row, jnp.concatenate(p_rows, axis=-2), 0.0)
     eye = (row == col).astype(jnp.float32)
     diag = jnp.where(row // BLOCK == col // BLOCK, A, 0.0)
     inv_d = _neumann(diag, eye, BLOCK)
     inv = _mm(_neumann(_mm(inv_d, A - diag), eye, C // BLOCK), inv_d)
     decay = jnp.exp(G)                                        # from the start
-    u = _mm(inv, vb - _mm_nt(kb * decay, st))                 # [C, d_v]
-    o = _mm_nt(q * decay, st) + _mm(P, u)
-    g_end = G[C - 1:C]
-    st = st * jnp.exp(g_end) + _mm(u.T, k * jnp.exp(g_end - G))
+    both = _mm_nt(jnp.concatenate([kb * decay, q * decay], axis=-2), st)
+    u = _mm(inv, vb - both[..., :C, :])                       # [Hg, C, d_v]
+    o = both[..., C:, :] + _mm(P, u)
+    g_end = G[..., C - 1:C, :]
+    st = st * jnp.exp(g_end) + _mm_tn(u, k * jnp.exp(g_end - G))
     return o, st
 
 
@@ -232,28 +286,27 @@ def chunked(q, k, v, g, beta, s0=None):
     """:func:`recurrence`'s function, ``CHUNK`` tokens a step: same
     arguments and results. ``T`` is padded to whole chunks with identity
     tokens. Plain ``jnp`` (the engine's prefill off the TPU, and the chunk
-    kernel's shape-for-shape twin)."""
+    kernel's shape-for-shape twin): a row's heads are ONE group."""
     B, T, H, d = q.shape
     if s0 is None:
         s0 = jnp.zeros((B, H, d, d), jnp.float32)
     pad = -T % CHUNK
-    bh = lambda a: jnp.pad(                                   # noqa: E731
-        jnp.moveaxis(a.astype(jnp.float32), 2, 1),
-        ((0, 0), (0, 0), (0, pad), (0, 0))).reshape(
-            B, H, (T + pad) // CHUNK, CHUNK, d)
+    bh = lambda a: jnp.moveaxis(jnp.pad(                      # noqa: E731
+        a.astype(jnp.float32), ((0, 0), (0, pad), (0, 0), (0, 0))).reshape(
+            B, (T + pad) // CHUNK, CHUNK, H, d), 3, 2)   # [B, n, H, C, d]
     b = beta.astype(jnp.float32)[..., None]
     xs = (bh(q), bh(k), bh(k * b), bh(v * b), bh(g))
 
-    def one_head(qh, kh, kbh, vbh, gh, sh):
+    def one_row(qr, kr, kbr, vbr, gr, sr):
         def one(st, x):
             o, st = chunk_math(*x, st)
             return st, o
 
-        st, o = jax.lax.scan(one, sh, (qh, kh, kbh, vbh, gh))
-        return o.reshape(-1, d), st
+        st, o = jax.lax.scan(one, sr, (qr, kr, kbr, vbr, gr))
+        return jnp.moveaxis(o, 1, 2).reshape(-1, H, d), st    # [T, H, d]
 
-    o, s = jax.vmap(jax.vmap(one_head))(*xs, s0.astype(jnp.float32))
-    return jnp.moveaxis(o[:, :, :T], 1, 2), s
+    o, s = jax.vmap(one_row)(*xs, s0.astype(jnp.float32))
+    return o[:, :T], s
 
 
 def scan(q, k, v, g, beta, s0=None, *, kernel: bool):

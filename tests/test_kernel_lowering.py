@@ -14,7 +14,9 @@ both routed cells' widths and largest decode buckets (128 experts of 2048 x
 configurations' largest prefill programs (2048 rows over 128 of Kimi's 256
 experts of 2304 x 1024, 2048 over Kanana's 128, 1024 over Trinity's); and
 Kimi-Linear's: flash over 16,384
-keys, KDA's chunk and step kernels at 32 heads of 128; and Nemotron-3-Nano's:
+keys, KDA's chunk and step kernels at 32 heads of 128 (the chunk kernel
+eight heads a grid step on the layer's own layout, no operand-sized array
+made beside it); and Nemotron-3-Nano's:
 the state-space chunk kernel over its largest prefill program (4 rows of
 512) and its step kernel at 128 rows (64 heads of 64 x 128, 8 groups), both
 expert kernels on two-matrix ``relu ** 2`` experts of 2688 x 1856 (1856 = 14
@@ -60,6 +62,12 @@ def _cases():
         32, 192, 128, 2048, 16384))
     for c in kernel_check.kda_cases(32, 128, bucket=2048, max_num_seqs=16):
         seen.setdefault(c.name, c)
+    # head counts that eight does not divide, at the same 128 channels: 12
+    # are padded to two groups of eight (16 heads a step are refused for
+    # scoped VMEM), 5 go in one step as a whole dimension
+    for heads in (12, 5):
+        c = kernel_check._kda_chunk_case(heads, 128, 256, 1)
+        seen.setdefault(c.name, c)
     # Nemotron-3-Nano's stage: both state-space kernels, the expert kernels
     # at a width that is 64 mod 128 (the streamed at 128 and 8 rows, the
     # tiled over a 2048-row program with half the experts held), and the
@@ -98,3 +106,39 @@ def test_kernel_compiles_for_v5e(case, v5e_sharding):
                                        sharding=v5e_sharding), avals)
     jax.jit(lambda *a: case.kernel(*a, interpret=False)).lower(
         *avals).compile()
+
+
+def _kimi_chunk_case():
+    case = kernel_check.kda_cases(32, 128, bucket=2048, max_num_seqs=16)[0]
+    assert case.name == "kda-chunk-H32x128-T2048-b1"
+    return case
+
+
+def test_the_kda_chunk_kernel_takes_the_layers_own_layout(v5e_sharding):
+    """Kimi's 32 heads go eight a grid step, on blocks of the operands as
+    the layer has them: the compiled call holds the kernel and NO copy,
+    transpose or fusion of an operand-sized array beside it (the head-major
+    form wrote seven of them a layer)."""
+    from scalable_hw_agnostic_inference_tpu.ops.pallas import kda_chunk
+
+    assert kda_chunk.HEAD_GROUP == 8
+    case = _kimi_chunk_case()
+    avals = jax.tree.map(
+        lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype,
+                                       sharding=v5e_sharding),
+        jax.eval_shape(case.make_inputs, jax.random.PRNGKey(0)))
+    compiled = jax.jit(lambda *a: kda_chunk.kda_chunk_prefill(
+        *a, interpret=False)).lower(*avals).compile()
+    text = compiled.as_text()
+    assert "tpu_custom_call" in text and kda_chunk.KERNEL_NAME in text
+    # the operands are 33.5 MB each: nothing that size is made beside them
+    assert compiled.memory_analysis().temp_size_in_bytes < 2 ** 20
+
+
+def test_the_kimi_shaped_chunk_case_still_refuses_a_bfloat16_state():
+    """The case that lowers above is the one the chip holds the kernel to:
+    at Kimi's shape its tolerance is still tens of times under what a state
+    rounded to bfloat16 after every token gives (plain ``jnp``, no kernel)."""
+    case = _kimi_chunk_case()
+    assert case.tol == kernel_check.TOL_KDA == 2e-4
+    assert kernel_check.kda_state_bf16_err(case) > 10 * case.tol
