@@ -35,8 +35,7 @@ SD_BASELINE_IMG_S = 1.0 / 0.67
 UNITS_BY_BENCH = {"llama": "tokens/sec", "t5": "sequences/sec",
                   "mllama": "tokens/sec", "llama_spec": "tokens/sec",
                   "vllm": "tokens/sec", "kvtier": "x", "qos": "x",
-                  "disagg": "x", "ragged": "tokens/sec",
-                  "fused": "x", "migrate": "ms", "kvfabric": "x",
+                  "disagg": "x", "migrate": "ms", "kvfabric": "x",
                   "scaler": "s", "hedge": "x",
                   "sd": "images/sec", "sd8": "images/sec",
                   "flux": "images/sec"}
@@ -64,9 +63,8 @@ def _which_from_argv(argv) -> str:
         return "llama_spec"
     if any(a.startswith("llama") for a in argv):
         return "llama"
-    for k in ("vllm", "kvtier", "qos", "disagg", "ragged", "fused",
-              "migrate", "kvfabric", "scaler", "hedge", "flux", "t5",
-              "mllama", "sd8"):
+    for k in ("vllm", "kvtier", "qos", "disagg", "migrate", "kvfabric",
+              "scaler", "hedge", "flux", "t5", "mllama", "sd8"):
         if k in argv:
             return k
     return "sd"
@@ -563,257 +561,6 @@ def bench_kvtier(tiny: bool) -> dict:
         "tier": {k: snap[k] for k in ("hits", "misses", "stores",
                                       "restored", "evictions", "errors")},
     }
-
-
-def bench_ragged(tiny: bool) -> dict:
-    """Ragged paged attention + int8 KV A/B: one mixed-length decode
-    workload measured with ``SHAI_RAGGED_ATTENTION=1 SHAI_KV_QUANT=int8``
-    vs both off (the bucketed bf16 oracle).
-
-    Reports tok/s at MIXED prompt lengths (the case the bucket ladder
-    padded on), the pad fraction each mode dispatched, the decode
-    executable-ladder entry count (one per batch bucket in both), and
-    ``kv_quant_capacity_ratio``: how many KV blocks each pool dtype fits
-    at a fixed ``SHAI_HBM_GIB`` (params + activations priced by
-    ``core.budget.causal_lm_budget``, per-block bytes measured from the
-    LIVE pools — scales included) — the ~2x batch headroom per HBM byte
-    the int8 pool buys.
-    """
-    import os
-
-    import numpy as np
-
-    from scalable_hw_agnostic_inference_tpu.core.budget import (
-        GIB,
-        causal_lm_budget,
-    )
-    from scalable_hw_agnostic_inference_tpu.engine import EngineConfig
-    from scalable_hw_agnostic_inference_tpu.engine.engine import (
-        LLMEngine,
-        SamplingParams,
-    )
-    from scalable_hw_agnostic_inference_tpu.models import llama as llama_mod
-
-    if tiny:
-        cfg = llama_mod.LlamaConfig.tiny()
-        ecfg = EngineConfig(max_model_len=256, max_num_seqs=4, block_size=8,
-                            context_encoding_buckets=(32, 64, 128),
-                            max_new_tokens=16)
-        lens, new = (12, 40, 90, 120), 12
-        name = "ragged-tiny"
-    else:
-        cfg = llama_mod.LlamaConfig.llama32_1b()
-        ecfg = EngineConfig(max_model_len=1024, max_num_seqs=4,
-                            block_size=16,
-                            context_encoding_buckets=(128, 256, 512),
-                            max_new_tokens=32)
-        lens, new = (60, 200, 450, 700), 24
-        name = "ragged-1b-geometry"
-
-    params = llama_mod.geometry_params(cfg, quant=False)
-    rng = np.random.default_rng(11)
-    prompts = [rng.integers(3, cfg.vocab_size, n).tolist() for n in lens]
-    sp = SamplingParams(temperature=0.0, max_new_tokens=new)
-
-    def measure(ragged_quant: bool):
-        env = ({"SHAI_RAGGED_ATTENTION": "1", "SHAI_KV_QUANT": "int8"}
-               if ragged_quant else
-               {"SHAI_RAGGED_ATTENTION": "0", "SHAI_KV_QUANT": "off"})
-        os.environ.update(env)
-        try:
-            eng = LLMEngine(cfg, params, ecfg)
-        finally:
-            for k in env:
-                os.environ.pop(k, None)
-
-        def run():
-            fins = eng.generate(prompts, sp)
-            assert all(len(f.token_ids) == new for f in fins)
-
-        run()   # warm every executable on the mixed-length path
-        runs = 3
-        t0 = time.perf_counter()
-        for _ in range(runs):
-            run()
-        dt = (time.perf_counter() - t0) / runs
-        snap = eng.obs.snapshot()
-        return {
-            "tok_s": round(len(prompts) * new / dt, 2),
-            "pad_fraction": snap["pad_fraction"],
-            "decode_ladder_entries": len(eng._decode_fns),
-            "executables": eng.n_executables,
-            "kv_pool_bytes": eng.cache.pool_bytes,
-            "kv_pool_blocks": eng.cache.total_blocks,
-        }
-
-    on = measure(True)
-    off = measure(False)
-
-    # capacity math at a pinned HBM size: blocks each pool dtype fits once
-    # params + peak activations are carved out (per-block bytes measured
-    # from the live pools above, scale arrays included)
-    from scalable_hw_agnostic_inference_tpu.obs.util import env_float
-
-    hbm_gib = env_float("SHAI_HBM_GIB", 16.0)
-    budget = causal_lm_budget(cfg, ecfg, hbm_gib_per_chip=hbm_gib)
-    kv_budget = max(0.0, (budget.usable_gib - budget.params_gib
-                          - budget.act_gib)) * GIB
-    blk_off = off["kv_pool_bytes"] / off["kv_pool_blocks"]
-    blk_on = on["kv_pool_bytes"] / on["kv_pool_blocks"]
-    max_blocks_off = int(kv_budget // blk_off)
-    max_blocks_on = int(kv_budget // blk_on)
-    ratio = (round(max_blocks_on / max_blocks_off, 3)
-             if max_blocks_off else 0.0)
-
-    base = _published("ragged_tps")
-    out = _dollars({
-        "metric": f"{name} ragged+int8KV decode tok/s (mixed lens "
-                  f"{list(lens)}, vs bucketed bf16, "
-                  f"{jax.devices()[0].platform})",
-        "value": on["tok_s"],
-        "unit": "tokens/sec",
-        "vs_baseline": round(on["tok_s"] / base, 3) if base else 1.0,
-    })
-    out["ragged_quant"] = on
-    out["bucketed"] = off
-    out["speedup"] = (round(on["tok_s"] / off["tok_s"], 3)
-                      if off["tok_s"] else 0.0)
-    out["kv_quant_capacity_ratio"] = ratio
-    out["max_kv_blocks_at_hbm"] = {"hbm_gib": hbm_gib,
-                                   "bf16": max_blocks_off,
-                                   "int8": max_blocks_on}
-    return out
-
-
-def bench_fused(tiny: bool) -> dict:
-    """Fused mixed-phase step A/B: one mixed prefill/decode workload
-    measured with ``SHAI_FUSED_STEP=1`` (decode rows + the continuation
-    chunk window in ONE ragged dispatch per step) vs the laddered ragged
-    engine (separate decode and continuation executables, serialized
-    dispatches). Ragged + async decode are ON in both modes — the A/B
-    isolates the fusion.
-
-    The workload is the interference case the fusion targets: a second
-    wave of prompts (one long enough to chunk) joins mid-decode, so the
-    laddered engine pays a separate continuation dispatch between decode
-    steps while the fused engine rides the chunk on the SAME dispatch.
-    Reports per-mode TTFT/TPOT medians, the decode-side ladder entry
-    count (fused collapses decode+rcont to one entry per batch bucket),
-    warmup wall time, and ``fused_step_tpot_ratio`` (laddered TPOT /
-    fused TPOT — above 1.0 means the fusion pays).
-    """
-    import os
-    import statistics
-
-    import numpy as np
-
-    from scalable_hw_agnostic_inference_tpu.engine import EngineConfig
-    from scalable_hw_agnostic_inference_tpu.engine.engine import (
-        LLMEngine,
-        SamplingParams,
-    )
-    from scalable_hw_agnostic_inference_tpu.models import llama as llama_mod
-
-    if tiny:
-        cfg = llama_mod.LlamaConfig.tiny()
-        ecfg = EngineConfig(max_model_len=256, max_num_seqs=4, block_size=8,
-                            context_encoding_buckets=(32, 64, 128),
-                            max_new_tokens=16)
-        wave1, wave2, new = (12, 40, 90), (140, 20), 12
-        name = "fused-tiny"
-    else:
-        cfg = llama_mod.LlamaConfig.llama32_1b()
-        ecfg = EngineConfig(max_model_len=1024, max_num_seqs=4,
-                            block_size=16,
-                            context_encoding_buckets=(128, 256, 512),
-                            max_new_tokens=32)
-        wave1, wave2, new = (60, 200, 450), (700, 100), 24
-        name = "fused-1b-geometry"
-
-    params = llama_mod.geometry_params(cfg, quant=False)
-    rng = np.random.default_rng(17)
-    p1 = [rng.integers(3, cfg.vocab_size, n).tolist() for n in wave1]
-    p2 = [rng.integers(3, cfg.vocab_size, n).tolist() for n in wave2]
-    sp = SamplingParams(temperature=0.0, max_new_tokens=new)
-
-    def run_mixed(eng):
-        """Two-wave mixed load: wave 2 (chunked long prompt included)
-        joins after wave 1 started decoding. Returns Finished in
-        submission order."""
-        fins = {}
-        rids = [eng.add_request(p, sp) for p in p1]
-        steps = 0
-        while len(fins) < len(p1) + len(p2):
-            for f in eng.step():
-                fins[f.req_id] = f
-            steps += 1
-            if steps == 2:
-                rids += [eng.add_request(p, sp) for p in p2]
-        return [fins[r] for r in rids]
-
-    def measure(fused: bool):
-        env = {"SHAI_RAGGED_ATTENTION": "1", "SHAI_ASYNC_DECODE": "1",
-               "SHAI_FUSED_STEP": "1" if fused else "0"}
-        os.environ.update(env)
-        try:
-            eng = LLMEngine(cfg, params, ecfg)
-        finally:
-            for k in env:
-                os.environ.pop(k, None)
-        t0 = time.perf_counter()
-        eng.warm_executables()
-        warm_s = time.perf_counter() - t0
-        run_mixed(eng)  # shake out host-side laziness off the clock
-        runs = 3
-        ttfts, tpots, errors = [], [], 0
-        t0 = time.perf_counter()
-        for _ in range(runs):
-            for f in run_mixed(eng):
-                if f.stop_reason != "length" or len(f.token_ids) != new:
-                    errors += 1
-                    continue
-                t = f.timing or {}
-                ttfts.append(t.get("queue_s", 0.0) + t.get("prefill_s", 0.0))
-                tpots.append(t.get("decode_s", 0.0) / max(1, new - 1))
-        dt = (time.perf_counter() - t0) / runs
-        n_prompts = len(p1) + len(p2)
-        # decode-side ladder: the per-step dispatch executables — fused
-        # entries replace BOTH the decode batch ladder and the
-        # dynamic-start continuation ladder
-        ladder = (len(eng._fused_fns) if fused else
-                  len(eng._decode_fns)
-                  + sum(1 for k in eng._prefill if k[0] == "rcont"))
-        return {
-            "tok_s": round(n_prompts * new / dt, 2),
-            "ttft_s_p50": round(statistics.median(ttfts), 4),
-            "tpot_s_p50": round(statistics.median(tpots), 5),
-            "decode_ladder_entries": ladder,
-            "executables": eng.n_executables,
-            "warmup_s": round(warm_s, 2),
-            "errors": errors,
-        }
-
-    on = measure(True)
-    off = measure(False)
-    ratio = (round(off["tpot_s_p50"] / on["tpot_s_p50"], 3)
-             if on["tpot_s_p50"] else 0.0)
-
-    base = _published("fused_step_tpot_ratio")
-    out = {
-        "metric": f"{name} fused mixed-phase step TPOT ratio (laddered/"
-                  f"fused, mixed 2-wave load, {jax.devices()[0].platform})",
-        "value": ratio,
-        "unit": "x",
-        "vs_baseline": round(ratio / base, 3) if base else 1.0,
-        "fused_step_tpot_ratio": ratio,
-        "fused": on,
-        "laddered": off,
-        "ttft_improvement": (round(off["ttft_s_p50"] / on["ttft_s_p50"], 3)
-                             if on["ttft_s_p50"] else 0.0),
-        "ladder_entries_reduced": (on["decode_ladder_entries"]
-                                   < off["decode_ladder_entries"]),
-    }
-    return out
 
 
 def bench_qos(tiny: bool) -> dict:
@@ -1814,7 +1561,6 @@ def main() -> None:
     out = {"llama": bench_llama, "llama_spec": bench_llama_spec,
            "vllm": bench_vllm, "kvtier": bench_kvtier,
            "qos": bench_qos, "disagg": bench_disagg,
-           "ragged": bench_ragged, "fused": bench_fused,
            "migrate": bench_migrate, "kvfabric": bench_kvfabric,
            "scaler": bench_scaler, "hedge": bench_hedge,
            "flux": bench_flux, "t5": bench_t5,

@@ -206,8 +206,7 @@ DEFAULT_CONTRACT = Contract(
         # trace-time crash on device — and on CPU fallbacks a silent
         # per-step serialization
         "engine/runner.py": (
-            "make_decode", "make_verify", "make_fused_step",
-            "_make_token_forward"),
+            "make_decode", "make_verify", "_make_token_forward"),
         # the KV-tier movers' jitted bodies: a host sync traced into the
         # demotion gather or restore scatter would serialize every
         # eviction/warm-hit on the host (same discipline as runner.py)
@@ -229,9 +228,6 @@ DEFAULT_CONTRACT = Contract(
         "_cont_for": ("make_prefill_cont", None),
         "_decode_for": ("make_decode", 1),
         "_verify_for": ("make_verify", 1),
-        # the fused mixed-phase ladder (SHAI_FUSED_STEP): one executable
-        # per batch bucket, returned as (batch_bucket, fused_fn)
-        "_fused_for": ("make_fused_step", 1),
     },
     param_factories={
         # the async dispatch helper receives the compiled decode executable
@@ -260,7 +256,7 @@ DEFAULT_CONTRACT = Contract(
                 "_drafter", "spec", "_spec_rng", "_sample1", "_lp1",
                 "_cross_embed", "_cross_write", "ttft", "tpot", "obs",
                 "_hbm_every", "_hbm_dev", "_async", "_ids", "_res",
-                "_ragged", "_kv_quant", "_fused", "_kv_cow", "role",
+                "_kv_quant", "_kv_cow", "role",
                 "_prefill_role"),
             owning_modules=(
                 "engine/engine.py", "engine/warm.py", "engine/cross.py",
@@ -587,15 +583,6 @@ DEFAULT_CONTRACT = Contract(
             "prefill", "prefill@tp2", "prefill_cont",
             "decode", "decode_feedback",
             "decode@tp2", "decode_feedback@tp2", "decode@tp2_paged",
-            # the dynamic-start continuation (SHAI_RAGGED_ATTENTION), CPU
-            # gather legs
-            "prefill_rcont", "prefill_rcont@tp2",
-            # fused mixed-phase step (SHAI_FUSED_STEP): decode rows + one
-            # continuation-chunk window per dispatch, both async
-            # disciplines on CPU and the tpu-lowered mixed-phase Pallas
-            # leg — donation (pool; pos in feedback) and dtype drift gate
-            # the fused path from day one
-            "fused_step", "fused_step_feedback", "fused_step@tp2",
             # int8 KV pool (SHAI_KV_QUANT): quantized scatter on prefill,
             # requantizing decode write + in-executable dequant, and the
             # scale-carrying tier restore — dtype-drift and donation gate
@@ -615,8 +602,6 @@ DEFAULT_CONTRACT = Contract(
             "prefill", "prefill@tp2", "prefill_cont",
             "decode", "decode_feedback",
             "decode@tp2", "decode_feedback@tp2", "decode@tp2_paged",
-            "prefill_rcont", "prefill_rcont@tp2",
-            "fused_step", "fused_step_feedback", "fused_step@tp2",
             "prefill_kvquant", "decode_kvquant", "tier_restore_quant",
             "verify", "cross_kv", "cross_slot_write",
             "tier_restore",
@@ -627,8 +612,6 @@ DEFAULT_CONTRACT = Contract(
             "prefill", "prefill@tp2", "prefill_cont",
             "decode", "decode_feedback",
             "decode@tp2", "decode_feedback@tp2", "decode@tp2_paged",
-            "prefill_rcont", "prefill_rcont@tp2",
-            "fused_step", "fused_step_feedback", "fused_step@tp2",
             "prefill_kvquant", "decode_kvquant", "tier_restore_quant",
             "verify", "cross_kv", "cross_slot_write",
             "tier_restore",

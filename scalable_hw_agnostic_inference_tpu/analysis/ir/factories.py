@@ -201,61 +201,6 @@ def _build_prefill_cont(key: str) -> IrProgram:
                      donate_args=(1,))
 
 
-def _build_rcont(key: str, tp: bool = False,
-                 kv_quant: bool = False) -> IrProgram:
-    # the ragged continuation (SHAI_RAGGED_ATTENTION): chunk start as DATA
-    # — ONE executable per chunk bucket. Built on the CPU platform, so the
-    # traced attention is the XLA gather reference (the Pallas leg is
-    # covered by decode@tp2_paged's tpu lowering).
-    import jax.numpy as jnp
-
-    from ...engine.runner import make_prefill_cont
-
-    cfg = _tiny_cfg()
-    sh = _engine_shardings(cfg, _mesh("tp")) if tp else None
-    fn = make_prefill_cont(cfg, BS, BPS, BUCKET, shardings=sh,
-                           kv_quant=kv_quant, ragged=True)
-    rep = sh.rep if sh else None
-    _, params = _param_sds(cfg, sh)
-    args = (params, _kv_sds(cfg, sh, quant=kv_quant),
-            _sds((1, BUCKET), jnp.int32, rep),
-            _sds((1,), jnp.int32, rep),
-            _sds((1, BPS), jnp.int32, rep),
-            _sds((1,), jnp.int32, rep))
-    return IrProgram(key=key, factory="make_prefill_cont",
-                     anchor_path=RUNNER, jitted=fn, args=args,
-                     donate_args=(1,), compile_cpu=not tp)
-
-
-def _build_fused(key: str, feedback: bool, tp: bool = False,
-                 paged: bool = False, kv_quant: bool = False,
-                 compile_cpu: bool = False) -> IrProgram:
-    # the fused mixed-phase step (SHAI_FUSED_STEP): decode rows + one
-    # continuation-chunk window in ONE dispatch. CPU legs trace the
-    # per-section reference attentions; the @tp2 leg lowers the flattened
-    # mixed-phase Pallas call for the tpu platform (paged=True forces the
-    # kernel, dryrun-style, like decode@tp2_paged)
-    import jax.numpy as jnp
-
-    from ...engine.runner import make_fused_step
-
-    cfg = _tiny_cfg()
-    sh = _engine_shardings(cfg, _mesh("tp")) if tp else None
-    fn = make_fused_step(cfg, BS, BPS, B, BUCKET, shardings=sh,
-                         paged=paged, feedback=feedback, kv_quant=kv_quant)
-    rep = sh.rep if sh else None
-    args = _decode_args(cfg, rep=rep, shardings=sh, quant=kv_quant) + (
-        _sds((1, BUCKET), jnp.int32, rep),     # c_ids
-        _sds((1,), jnp.int32, rep),            # c_ntext
-        _sds((1, BPS), jnp.int32, rep),        # c_table
-        _sds((1,), jnp.int32, rep))            # c_start
-    return IrProgram(
-        key=key, factory="make_fused_step", anchor_path=RUNNER, jitted=fn,
-        args=args, donate_args=(1, 3) if feedback else (1,),
-        compile_cpu=compile_cpu,
-        lowering_platforms=("tpu",) if paged else None)
-
-
 def _build_tier_restore_quant(key: str) -> IrProgram:
     # the quantized restore scatter: int8 blocks + f32 scale rows move in
     # ONE donated call per layer (all four pool buffers donate-and-rebind)
@@ -408,19 +353,6 @@ BUILDERS = {
                                                    compile_cpu=True),
     "decode@tp2_paged": lambda k: _build_decode(k, feedback=False, tp=True,
                                                 paged=True),
-    # the dynamic-start continuation (SHAI_RAGGED_ATTENTION)
-    "prefill_rcont": lambda k: _build_rcont(k),
-    "prefill_rcont@tp2": lambda k: _build_rcont(k, tp=True),
-    # fused mixed-phase step (SHAI_FUSED_STEP): decode + chunk window in
-    # one dispatch — donation (pool always; pos in the feedback variant)
-    # and dtype drift are judged on both async disciplines, and the @tp2
-    # leg lowers the flattened mixed-phase Pallas call for tpu
-    "fused_step": lambda k: _build_fused(k, feedback=False,
-                                         compile_cpu=True),
-    "fused_step_feedback": lambda k: _build_fused(k, feedback=True,
-                                                  compile_cpu=True),
-    "fused_step@tp2": lambda k: _build_fused(k, feedback=False, tp=True,
-                                             paged=True),
     # int8 KV pool (SHAI_KV_QUANT): the quantized scatter (prefill write),
     # the requantizing decode write + in-executable dequant reads, and the
     # scale-carrying tier restore

@@ -139,9 +139,6 @@ class LLMEngine:
                            "quantised expert product yet)")
         if self._window_pool_layers and self._kv_quant:
             refused.append("SHAI_KV_QUANT=int8 with window layers")
-        if (self._window_pool_layers or self._moe_layers) and _env_flag(
-                "SHAI_FUSED_STEP", False):
-            refused.append("SHAI_FUSED_STEP with window or expert layers")
         if self._window_pool_layers and _env_flag("SHAI_KVTIER", False):
             refused.append("SHAI_KVTIER (the host KV tier) with window "
                            "layers")
@@ -170,12 +167,6 @@ class LLMEngine:
                     (ecfg.speculative_enabled,
                      "speculative decoding (no multi-token verify over a "
                      "latent pool)"),
-                    (_env_flag("SHAI_RAGGED_ATTENTION", False),
-                     "SHAI_RAGGED_ATTENTION (the dynamic-start "
-                     "continuation reads k and v heads)"),
-                    (_env_flag("SHAI_FUSED_STEP", False),
-                     "SHAI_FUSED_STEP (the fused step reads k and v "
-                     "heads)"),
                     (bool(model_cfg.cross_attention_layers),
                      "cross-attention layers")):
                 if on:
@@ -201,11 +192,6 @@ class LLMEngine:
                     (ecfg.speculative_enabled,
                      "speculative decoding (a rejected draft cannot be "
                      "rolled back out of a state)"),
-                    (_env_flag("SHAI_RAGGED_ATTENTION", False),
-                     "SHAI_RAGGED_ATTENTION (the dynamic-start "
-                     "continuation carries no slot)"),
-                    (_env_flag("SHAI_FUSED_STEP", False),
-                     "SHAI_FUSED_STEP (the fused step carries no slot)"),
                     (_env_flag("SHAI_KV_COW", False),
                      "SHAI_KV_COW (a forked sibling has no copy of the "
                      "state)"),
@@ -225,17 +211,6 @@ class LLMEngine:
             raise ValueError(
                 "this model's layers are not served with: "
                 + "; ".join(refused))
-        # SHAI_RAGGED_ATTENTION (default off) selects ONE thing: the
-        # dynamic-start continuation. Chunked prefill's one-per-start
-        # ladder collapses to one executable per chunk bucket whose
-        # chunk queries attend through the pool kernel, a query a row
-        # (runner.make_prefill_cont(ragged=True)); it is also what
-        # SHAI_FUSED_STEP rides. Decode and verify do not read it: they
-        # are one program per batch bucket either way. Text engines only:
-        # the dynamic-start continuation does not carry the mllama cross
-        # tail.
-        self._ragged = bool(_env_flag("SHAI_RAGGED_ATTENTION", False)
-                            and not model_cfg.cross_attention_layers)
         # prefix caching serves the plain-text path only: cross models'
         # cache semantics (vision states) don't content-address by tokens
         prefix_caching = (ecfg.enable_prefix_caching
@@ -335,25 +310,6 @@ class LLMEngine:
             # host-side, own stream — device rng folds stay byte-identical
             # to vanilla decode
             self._spec_rng = np.random.default_rng(ecfg.seed + 0x5EC)
-        # fused mixed-phase step (SHAI_FUSED_STEP, default off): decode and
-        # the chunked-prefill continuation share ONE ragged executable per
-        # batch bucket — the decode, dynamic-start-continuation, and
-        # cached-admission-continuation ladders all collapse into it. Rides
-        # the ragged kernel (rows fuse by pure layout, the kernel never
-        # learns phases) and stays out of speculative engines (verify owns
-        # multi-token dispatch there). Off keeps the laddered engine as the
-        # token-exact oracle the fused differential tests compare against.
-        self._fused = bool(_env_flag("SHAI_FUSED_STEP", False)
-                           and self._ragged
-                           and not ecfg.speculative_enabled)
-        self._fused_fns: Dict[int, Any] = {}
-        # deferred continuation window: an intermediate chunk parks its
-        # (ids, n_text, table, start) here and rides the NEXT decode
-        # dispatch as the fused executable's chunk section instead of
-        # paying its own dispatch; consumed by _take_chunk_args, flushed
-        # by every path that would skip or reorder around that dispatch
-        self._pending_chunk: Optional[tuple] = None
-        self._null_chunk: Optional[list] = None
         # copy-on-write KV fan-out (SHAI_KV_COW, default off): an n>1
         # sampling group admits ONE shared prefill and every sibling forks
         # the prompt blocks copy-on-write (cache.fork_sequence); the first
@@ -969,7 +925,6 @@ class LLMEngine:
         self._admit_phase()
         if any(s is not None for s in self.slots):
             self._decode_step()
-        self._flush_chunk()  # a deferred window never outlives its step
         self._record_step(t0)
         return self._done_this_step
 
@@ -1120,7 +1075,6 @@ class LLMEngine:
             self._flush_pipeline(reason)
             if any(s is not None for s in self.slots):
                 self._decode_dispatch()
-            self._flush_chunk()  # deferred window never outlives its step
         self._record_step(t0)
         return self._done_this_step
 
@@ -1141,7 +1095,7 @@ class LLMEngine:
         return jax.device_put(x, self.shardings.rep)
 
     def _put_step(self, x):
-        """``_put`` for a decode, verify or fused dispatch: the same put,
+        """``_put`` for a decode or verify dispatch: the same put,
         each array of it counted (``decode_input_uploads``: what a step
         hands the device beyond what already lives there)."""
         self._step_uploads += len(jax.tree.leaves(x))
@@ -1234,10 +1188,7 @@ class LLMEngine:
         self._grow_running(lambda s: 1)
         running = self._running_slots()
         if not running:
-            # chunk-only step (every live slot is mid-prefill): nothing
-            # rides the decode dispatch, so the window pays its own
-            self._flush_chunk()
-            return
+            return      # every live slot is mid-prefill
         n_exec = self.n_executables
         Bb, decode = self._decode_for(len(running))
         self._note_dispatch_pad(running, Bb)
@@ -1730,8 +1681,7 @@ class LLMEngine:
         check boots fifteen) otherwise runs into the kernel's limit of
         mappings and dies loading the next one. Any program asked for
         afterwards is built again."""
-        for fns in (self._prefill, self._decode_fns, self._verify_fns,
-                    self._fused_fns):
+        for fns in (self._prefill, self._decode_fns, self._verify_fns):
             fns.clear()
 
     def warm_executables(self, prefix_lens: Sequence[int] = (0,)) -> int:
@@ -1906,14 +1856,6 @@ class LLMEngine:
         n_total = len(req.prompt_ids)
         if n_total <= self.ecfg.block_size:
             return False  # no full block to share
-        if self._fused and self._kv_quant:
-            # int8 pools re-quantize a written block over EVERYTHING in it:
-            # the fused C-sized window writes pad garbage past the cached
-            # remainder that the laddered chunk_bucket never touched, so
-            # the tail block's scale (and every real token quantized under
-            # it) would diverge from the oracle — fall through to plain
-            # admission, which prefills from scratch and stays exact
-            return False
         slot = self._free_slot()
         if slot is None:
             # probe NOTHING while blocked on a slot: a waiting request
@@ -1941,7 +1883,7 @@ class LLMEngine:
                     n_total, (len(cached) + n_tier) * self.ecfg.block_size)
         if start == 0:
             return False
-        chunk_bucket = self._cached_chunk_bucket(n_total - start)
+        chunk_bucket = self.buckets.bucket_for(n_total - start)
         sb = start // self.ecfg.block_size
         if start + chunk_bucket > self.ecfg.max_model_len:
             return False  # chunk executable would overrun blocks_per_seq
@@ -1977,7 +1919,7 @@ class LLMEngine:
                     n_total, len(cached) * self.ecfg.block_size)
                 if start == 0:
                     return False
-                chunk_bucket = self._cached_chunk_bucket(n_total - start)
+                chunk_bucket = self.buckets.bucket_for(n_total - start)
                 sb = start // self.ecfg.block_size
                 if start + chunk_bucket > self.ecfg.max_model_len:
                     return False
@@ -1998,21 +1940,11 @@ class LLMEngine:
         n = n_total - start
         ids = np.zeros((1, chunk_bucket), np.int32)
         ids[0, :n] = req.prompt_ids[start:]
-        if self._fused:
-            # a deferred window must not reorder behind this admission's
-            # own window (the admission may reuse blocks the deferred
-            # chunk is still due to write)
-            self._flush_chunk()
-            logits = self._fused_chunk_call(
-                self._put(ids), self._put([n], np.int32), table,
-                self._put([start], np.int32))
-        else:
-            fn = self._cont_for(sb, chunk_bucket)
-            with self.obs.phase("engine.chunk"):
-                self.cache.kv, logits = fn(self.params, self.cache.kv,
-                                           self._put(ids),
-                                           self._put([n], np.int32),
-                                           table, *self._cont_args(start))
+        fn = self._cont_for(sb, chunk_bucket)
+        with self.obs.phase("engine.chunk"):
+            self.cache.kv, logits = fn(self.params, self.cache.kv,
+                                       self._put(ids),
+                                       self._put([n], np.int32), table)
         self._note_program_pad(n, chunk_bucket - n,
                                phase="prefill")  # chunk bucket tail
         self.cache.register_prefix(req.prompt_ids, alloc.blocks)
@@ -2173,47 +2105,19 @@ class LLMEngine:
         table = self._put(
             self.cache.seq(req.req_id).table(self.ecfg.blocks_per_seq)[None])
         final = start + n >= len(req.prompt_ids)
-        if self._fused and not final:
-            # intermediate chunk: DEFER the window — it rides this step's
-            # decode dispatch as the fused executable's chunk section (one
-            # dispatch where the ladder paid two; THE interference win).
-            # Its logits are discarded exactly as the laddered oracle
-            # discards intermediate-chunk logits; registration and the
-            # cursor advance keep the oracle's timing.
-            self._flush_chunk()  # never stack two windows
-            self._pending_chunk = (self._put(ids),
-                                   self._put([n], np.int32), table,
-                                   self._put([start], np.int32))
-            self._note_program_pad(n, C - n, phase="chunk")
-            self.cache.register_prefix(
-                req.prompt_ids[:start + n],
-                self.cache.seq(req.req_id).blocks)
-            s.prefill_cursor = start + C
-            return
-        if self._fused:
-            # final chunk: its sampled token joins THIS step's decode
-            # batch — that circular dependency forbids sharing the decode
-            # dispatch, so the window runs chunk-only (null decode rows);
-            # 2 dispatches, the laddered oracle's own structure
-            self._flush_chunk()
-            logits = self._fused_chunk_call(
-                self._put(ids), self._put([n], np.int32), table,
-                self._put([start], np.int32))
-        else:
-            fn = self._cont_for(start // self.ecfg.block_size)
-            args = [self.params, self.cache.kv, self._put(ids),
-                    self._put([n], np.int32), table]
-            args += self._cont_args(start)  # ragged: start rides as data
-            # recurrent layers: the chunk reads its slot's state, not the
-            # pool, and writes it back
-            args += self._slot_args([s.slot])
-            self.obs.count_recurrent(
-                self._state_kind, prefill_tokens=n * self._state_layers,
-                chunk_carries=bool(self._state_layers))
-            if self._cross_kv is not None:
-                args += list(self._slot_cross_args(s.slot))
-            with self.obs.phase("engine.chunk"):
-                self.cache.kv, logits = fn(*args)
+        fn = self._cont_for(start // self.ecfg.block_size)
+        args = [self.params, self.cache.kv, self._put(ids),
+                self._put([n], np.int32), table]
+        # recurrent layers: the chunk reads its slot's state, not the
+        # pool, and writes it back
+        args += self._slot_args([s.slot])
+        self.obs.count_recurrent(
+            self._state_kind, prefill_tokens=n * self._state_layers,
+            chunk_carries=bool(self._state_layers))
+        if self._cross_kv is not None:
+            args += list(self._slot_cross_args(s.slot))
+        with self.obs.phase("engine.chunk"):
+            self.cache.kv, logits = fn(*args)
         self._note_program_pad(n, C - n, phase="chunk")  # final-chunk tail
         if final:
             self.cache.register_prefix(
@@ -2244,20 +2148,6 @@ class LLMEngine:
         from .runner import make_prefill_cont
 
         bucket = self.buckets.max if bucket is None else bucket
-        if self._ragged:
-            # ONE dynamic-start executable per chunk bucket replaces the
-            # whole one-per-start continuation ladder; callers append the
-            # start array to the call args (_cont_args)
-            key = ("rcont", bucket)
-            if key not in self._prefill:
-                _faults.get().raise_at(_faults.COMPILE)
-                if self._warmed:
-                    self.obs.count_recompile()
-                self._prefill[key] = make_prefill_cont(
-                    self.cfg, self.ecfg.block_size, self.ecfg.blocks_per_seq,
-                    bucket, shardings=self.shardings,
-                    kv_quant=self._kv_quant, ragged=True)
-            return self._prefill[key]
         key = ("cont", start_blocks, bucket)
         if key not in self._prefill:
             _faults.get().raise_at(_faults.COMPILE)
@@ -2271,42 +2161,12 @@ class LLMEngine:
                 kv_quant=self._kv_quant)
         return self._prefill[key]
 
-    def _cont_key(self, start_blocks: int, bucket: int):
-        """The warm-ladder key a continuation dispatch will resolve to —
-        the post-ready compile guards in cached admission check THIS, so
-        the ragged ladder's (start-free) keys gate correctly."""
-        if self._ragged:
-            return ("rcont", bucket)
-        return ("cont", start_blocks, bucket)
-
-    def _cached_chunk_bucket(self, remainder: int) -> int:
-        """Window the cached-admission continuation dispatches: the fused
-        step's chunk section is pinned to the largest prefill bucket (one
-        executable per batch bucket — sizing it per remainder would grow
-        the ladder back); the laddered engine keeps the smallest covering
-        bucket."""
-        if self._fused:
-            return self.buckets.max
-        return self.buckets.bucket_for(remainder)
-
     def _cont_cold(self, sb: int, chunk_bucket: int) -> bool:
         """Post-ready compile guard for a continuation dispatch: True when
         the executable it would resolve to was never warmed (the cold-
-        graph-behind-the-LB bug). The fused step dispatches chunk-only
-        windows through the bb=1 fused executable."""
-        if not self._warmed:
-            return False
-        if self._fused:
-            return 1 not in self._fused_fns
-        return self._cont_key(sb, chunk_bucket) not in self._prefill
-
-    def _cont_args(self, start: int) -> list:
-        """Trailing args a continuation executable takes beyond
-        ``(params, kv, ids, n_text, block_tables)``: the ragged variant
-        carries the chunk start as DATA."""
-        if self._ragged:
-            return [self._put([start], np.int32)]
-        return []
+        graph-behind-the-LB bug)."""
+        return (self._warmed
+                and ("cont", sb, chunk_bucket) not in self._prefill)
 
     def _cached_starts(self) -> List[int]:
         """THE closed set of continuation starts (token units) — both the
@@ -2356,8 +2216,6 @@ class LLMEngine:
     def _decode_for(self, n_active: int = -1):
         """Decode executable for the smallest batch bucket covering the
         running set: the rows it holds choose the program, nothing else."""
-        if self._fused:
-            return self._fused_decode_for(n_active)
         bb = (self.ecfg.max_num_seqs if n_active < 0
               else self._batch_bucket(n_active))
         if bb not in self._decode_fns:
@@ -2372,95 +2230,6 @@ class LLMEngine:
                 bb, shardings=self.shardings, feedback=self._async,
                 kv_quant=self._kv_quant)
         return bb, self._decode_fns[bb]
-
-    # -- fused mixed-phase step (SHAI_FUSED_STEP) --------------------------
-
-    def _fused_for(self, n_active: int = -1):
-        """Fused mixed-phase executable for the smallest batch bucket
-        covering the running set: the decode rows plus ONE continuation-
-        chunk window in a single ragged dispatch (runner.make_fused_step).
-        Mirrors ``_decode_for``'s ladder discipline — one entry per batch
-        bucket; the chunk window is pinned to the largest prefill
-        bucket."""
-        bb = (self.ecfg.max_num_seqs if n_active < 0
-              else self._batch_bucket(n_active))
-        if bb not in self._fused_fns:
-            from .runner import make_fused_step
-
-            _faults.get().raise_at(_faults.COMPILE)
-            if self._warmed:
-                self.obs.count_recompile()
-            self._fused_fns[bb] = make_fused_step(
-                self.cfg, self.ecfg.block_size, self.ecfg.blocks_per_seq,
-                bb, self.buckets.max, shardings=self.shardings,
-                feedback=self._async, kv_quant=self._kv_quant)
-        return bb, self._fused_fns[bb]
-
-    def _fused_decode_for(self, n_active: int = -1):
-        """The decode-shaped view of the fused executable: append the
-        pending (or null) chunk-window args and drop the trailing chunk
-        logits, so ``_decode_for``'s callers dispatch it unchanged. An
-        intermediate chunk deferred by ``_continue_prefill`` rides THIS
-        dispatch; its logits are discarded exactly as the laddered oracle
-        discards intermediate-chunk logits."""
-        bb, fused = self._fused_for(n_active)
-
-        def decode(*args):
-            out = fused(*args, *self._take_chunk_args())
-            return out[:-1]
-
-        return bb, decode
-
-    def _null_chunk_args(self) -> list:
-        """Device-cached null chunk window: zero ids over null block 0
-        with ``n_text=1`` — a pure-decode fused dispatch carries it so
-        the executable signature never changes. Its writes land in
-        reserved block 0, outside every live window; nothing reads them."""
-        if self._null_chunk is None:
-            self._null_chunk = [
-                self._put(np.zeros((1, self.buckets.max), np.int32)),
-                self._put(np.ones((1,), np.int32)),
-                self._put(np.zeros((1, self.ecfg.blocks_per_seq), np.int32)),
-                self._put(np.zeros((1,), np.int32))]
-        return self._null_chunk
-
-    def _take_chunk_args(self) -> list:
-        """Consume the deferred continuation window (or hand out nulls)."""
-        pc, self._pending_chunk = self._pending_chunk, None
-        if pc is None:
-            return self._null_chunk_args()
-        return list(pc)
-
-    def _fused_chunk_call(self, ids_dev, n_dev, table, start_dev):
-        """Chunk-only fused dispatch (bb=1): the decode section runs null
-        rows (active all-false; their block-0 writes are harmless) while
-        the chunk window does the real work. Used for final chunks and
-        cached admission, whose sampled token feeds the SAME step's decode
-        batch — a circular dependency that forbids sharing that dispatch.
-        Returns the chunk's last-real-position logits ``[1, V]``. The
-        decode tokens/pos are SEPARATE zero buffers: the feedback variant
-        donates the position argument, so aliasing them would donate the
-        token buffer too."""
-        _, fused = self._fused_for(1)
-        z = np.zeros((1,), np.int32)
-        args = [self.params, self.cache.kv, self._put(z), self._put(z),
-                self._put(np.zeros((1, self.ecfg.blocks_per_seq), np.int32)),
-                self._put(np.zeros((1,), bool)), self._rng,
-                self._put(self._fold()),
-                self._put(np.ones((1,), np.float32)), self._put(z),
-                self._put(np.ones((1,), np.float32)),
-                ids_dev, n_dev, table, start_dev]
-        with self.obs.phase("engine.chunk"):
-            out = fused(*args)
-        self.cache.kv = out[0]
-        return out[-1]
-
-    def _flush_chunk(self) -> None:
-        """Dispatch any deferred continuation window NOW (no-op when
-        none): paths that skip the decode dispatch — or would reorder KV
-        writes around it — must not leave a window parked."""
-        if self._pending_chunk is not None:
-            self._fused_chunk_call(*self._take_chunk_args())
 
     def _verify_for(self, n_active: int = -1):
         """Speculative verify executable for the smallest batch bucket
@@ -2483,7 +2252,7 @@ class LLMEngine:
     @property
     def n_executables(self) -> int:
         return (len(self._prefill) + len(self._decode_fns)
-                + len(self._verify_fns) + len(self._fused_fns))
+                + len(self._verify_fns))
 
     def _preempt_lowest(self) -> None:
         """Recompute-preempt the lowest-priority, most recently admitted
@@ -2715,14 +2484,6 @@ class LLMEngine:
             "has_image": np.zeros((Bb,), np.float32),
             "cross_len": np.full((Bb,), max(self.cross_seq_len, 1),
                                  np.int32),
-            # mixed-phase row metadata (SHAI_FUSED_STEP / obs): each row's
-            # decode start (its prompt boundary in cache tokens — stable
-            # per decode segment, so the tables-only refresh path never
-            # leaves it stale) and phase (0 = decode; mid-prefill slots
-            # never enter the running view — the fused dispatch composes
-            # its chunk rows itself, phase 1 lives only in that window)
-            "starts": np.zeros((Bb,), np.int32),
-            "phase": np.zeros((Bb,), np.int8),
         }
         for i, s in enumerate(running):
             a["tables"][i] = self.cache.seq(s.req.req_id).table(M)
@@ -2733,7 +2494,6 @@ class LLMEngine:
             a["slot_idx"][i] = s.slot
             a["has_image"][i] = self._has_image[s.slot]
             a["cross_len"][i] = self._cross_len[s.slot]
-            a["starts"][i] = s.req.prefix_len + len(s.req.prompt_ids)
         return a
 
     def _spec_step(self) -> bool:
@@ -2897,8 +2657,7 @@ class LLMEngine:
         self._grow_running(lambda s: 1)
         running = self._running_slots()
         if not running:
-            self._flush_chunk()  # chunk-only step: no decode to ride
-            return
+            return      # every live slot is mid-prefill
         n_exec = self.n_executables
         Bb, decode = self._decode_for(len(running))
         self._note_dispatch_pad(running, Bb)

@@ -19,13 +19,7 @@ holds none; ``_put_step`` is the same put, counted as
 
   The mirror is COLUMN-AGNOSTIC: whatever dict ``engine.
   _marshal_running`` returns is uploaded wholesale, so per-row metadata
-  columns ride along without touching the refresh mechanics. The fused
-  mixed-phase step (``SHAI_FUSED_STEP``) adds two: ``starts`` (each
-  row's decode start — its prompt boundary in cache tokens, constant
-  per decode segment by CONTRACT, which is what keeps the tables-only
-  refresh path truthful) and ``phase`` (int8, 0 = decode for every
-  resident row; the fused dispatch composes its chunk-window rows
-  itself — a nonzero phase never appears in resident state).
+  columns ride along without touching the refresh mechanics.
 
 * :class:`InflightStep` records one dispatched-but-not-retired decode
   step: the device-side sampled tokens (which feed straight back as the

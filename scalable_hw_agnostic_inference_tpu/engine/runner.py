@@ -175,100 +175,80 @@ def layer_kinds(cfg: LlamaConfig) -> List[LayerKind]:
             for li in range(cfg.n_layers)]
 
 
-def _layer(lp: Dict, kind: LayerKind, xs, positions, attend,
+def _layer(lp: Dict, kind: LayerKind, x, positions, attend,
            cfg: LlamaConfig, *, cross=None, active=None, shardings=None):
     """THE decoder layer: every program of this module calls it, with its
     own attention closure. Mistral, mllama and the routed/windowed models
     are its cases, chosen by ``kind`` and the config's flags.
 
-    ``xs``: a tuple of token streams ``[B, T, dim]`` (one, but for the
-    fused step's decode rows and chunk window, which share each layer's
-    attention call), ``positions`` their ``[B, T]`` cache positions.
-    ``attend(qs, ks, vs, window) -> os``: the program's attention — where
-    this layer's new keys and values go in the pool, and what each query
-    sees; tuples in, a tuple of ``[B, T, H, Dh]`` out. With latent
-    attention (``cfg.latent``, the attention KIND) ``ks`` are the tokens'
-    cache rows ``[B, T, latent_width]`` and ``vs`` is the layer's ``kv_b``
+    ``x``: the token stream ``[B, T, dim]``, ``positions`` its ``[B, T]``
+    cache positions. ``attend(q, k, v, window) -> o``: the program's
+    attention — where this layer's new keys and values go in the pool, and
+    what each query sees; arrays in, ``[B, T, H, Dh]`` out. With latent
+    attention (``cfg.latent``, the attention KIND) ``k`` is the tokens'
+    cache rows ``[B, T, latent_width]`` and ``v`` is the layer's ``kv_b``
     leaf, which the program expands or absorbs as its phase wants. In a
-    recurrent layer (``kind.state``) ``qs`` are the NORMED streams ``[B, T,
-    dim]`` and ``ks`` the layer's mixer leaves: the program's closure runs
+    recurrent layer (``kind.state``) ``q`` is the NORMED stream ``[B, T,
+    dim]`` and ``k`` the layer's mixer leaves: the program's closure runs
     its phase of the model's recurrent kind over its slots (``prefill`` or
     ``decode`` of ``ops.kda`` / ``ops.ssm``) and hands back the gated
-    outputs ``[B, T, H * d]``. A block of ONE part (``kind.part``) has one
+    output ``[B, T, H * d]``. A block of ONE part (``kind.part``) has one
     norm (``lp["norm"]``) and one residual add: the mixer alone, or the
     feed-forward part alone (``attend`` is then not called). ``cross``:
     ``(k, v, has_image, cross_len)`` of a cross layer, which attends those
-    and touches no pool. ``active``: per stream, the rows that hold a real
-    token (bool, ``[B, T]``); padded rows route to no expert.
+    and touches no pool. ``active``: the rows that hold a real token (bool,
+    ``[B, T]``); padded rows route to no expert.
 
-    Returns ``(xs, stats)``: ``stats`` the routed FFN's int32 ``[2]``
+    Returns ``(x, stats)``: ``stats`` the routed FFN's int32 ``[2]``
     (experts touched, largest load), ``None`` for a dense layer."""
     if kind.cross:
         ck, cv, has_image, cross_len = cross
-        return tuple(_cross_layer(lp, x, ck, cv, has_image, cfg,
-                                  cross_len=cross_len, shardings=shardings)
-                     for x in xs), None
+        return _cross_layer(lp, x, ck, cv, has_image, cfg,
+                            cross_len=cross_len, shardings=shardings), None
     if kind.part == "ffn":
-        out, stats = [], None
-        for i, x in enumerate(xs):
-            f, st = _ffn(lp, kind, _rmsnorm(x, lp["norm"]["scale"],
-                                            cfg.rms_eps), cfg,
-                         None if active is None else active[i])
-            if st is not None:
-                stats = st if stats is None else stats + st
-            out.append(x + f)
-        return tuple(out), stats
+        f, stats = _ffn(lp, kind, _rmsnorm(x, lp["norm"]["scale"],
+                                           cfg.rms_eps), cfg, active)
+        return x + f, stats
     at, Dh = lp["attn"], cfg.head_dim
-    first_norm = lp["norm" if kind.part else "attn_norm"]["scale"]
-    qs, ks, vs, gates = [], [], [], []
-    for x, pos in zip(xs, positions):
-        B, T, _ = x.shape
-        h = _rmsnorm(x, first_norm, cfg.rms_eps)
-        if kind.state:
-            qs.append(h), gates.append(None)
-            continue
+    B, T, _ = x.shape
+    h = _rmsnorm(x, lp["norm" if kind.part else "attn_norm"]["scale"],
+                 cfg.rms_eps)
+    gate = None
+    if kind.state:
+        o = attend(h, at, None, 0)
+    else:
         q = _proj(h, at["q"]).reshape(B, T, cfg.n_heads, Dh)
         if cfg.latent:
-            q, row = _latent_qk(at, h, q, pos, cfg, kind.rope)
-            qs.append(q), ks.append(row), gates.append(None)
-            continue
-        k = _proj(h, at["k"]).reshape(B, T, cfg.n_kv_heads, Dh)
-        v = _proj(h, at["v"]).reshape(B, T, cfg.n_kv_heads, Dh)
-        if cfg.qk_norm:
-            q = _head_rmsnorm(q, at["q_norm"]["scale"], cfg.rms_eps)
-            k = _head_rmsnorm(k, at["k_norm"]["scale"], cfg.rms_eps)
-        if kind.rope:
-            q = apply_rope(q, pos, cfg.rope_theta, cfg.rope_scaling)
-            k = apply_rope(k, pos, cfg.rope_theta, cfg.rope_scaling)
-        qs.append(q), ks.append(k), vs.append(v)
-        gates.append(_proj(h, at["gate"]) if cfg.attn_gate else None)
-    if kind.state:
-        os = attend(tuple(qs), at, None, 0)
-    else:
-        os = attend(tuple(qs), tuple(ks),
-                    at["kv_b"] if cfg.latent else tuple(vs), kind.window)
-    out, stats = [], None
-    for i, (x, o, g) in enumerate(zip(xs, os, gates)):
-        B, T, _ = x.shape
-        o = o.reshape(B, T, -1)
-        if g is not None:
-            o = o * jax.nn.sigmoid(g)
-        h = _proj(o, at["o"])
-        if cfg.sandwich_norms:
-            h = _rmsnorm(h, lp["post_attn_norm"]["scale"], cfg.rms_eps)
-        x = x + h
-        if kind.part == "mixer":
-            out.append(x)
-            continue
-        m = _rmsnorm(x, lp["mlp_norm"]["scale"], cfg.rms_eps)
-        f, st = _ffn(lp, kind, m, cfg,
-                     None if active is None else active[i])
-        if st is not None:
-            stats = st if stats is None else stats + st
-        if cfg.sandwich_norms:
-            f = _rmsnorm(f, lp["post_mlp_norm"]["scale"], cfg.rms_eps)
-        out.append(x + f)
-    return tuple(out), stats
+            q, row = _latent_qk(at, h, q, positions, cfg, kind.rope)
+            o = attend(q, row, at["kv_b"], kind.window)
+        else:
+            k = _proj(h, at["k"]).reshape(B, T, cfg.n_kv_heads, Dh)
+            v = _proj(h, at["v"]).reshape(B, T, cfg.n_kv_heads, Dh)
+            if cfg.qk_norm:
+                q = _head_rmsnorm(q, at["q_norm"]["scale"], cfg.rms_eps)
+                k = _head_rmsnorm(k, at["k_norm"]["scale"], cfg.rms_eps)
+            if kind.rope:
+                q = apply_rope(q, positions, cfg.rope_theta,
+                               cfg.rope_scaling)
+                k = apply_rope(k, positions, cfg.rope_theta,
+                               cfg.rope_scaling)
+            if cfg.attn_gate:
+                gate = _proj(h, at["gate"])
+            o = attend(q, k, v, kind.window)
+    o = o.reshape(B, T, -1)
+    if gate is not None:
+        o = o * jax.nn.sigmoid(gate)
+    h = _proj(o, at["o"])
+    if cfg.sandwich_norms:
+        h = _rmsnorm(h, lp["post_attn_norm"]["scale"], cfg.rms_eps)
+    x = x + h
+    if kind.part == "mixer":
+        return x, None
+    m = _rmsnorm(x, lp["mlp_norm"]["scale"], cfg.rms_eps)
+    f, stats = _ffn(lp, kind, m, cfg, active)
+    if cfg.sandwich_norms:
+        f = _rmsnorm(f, lp["post_mlp_norm"]["scale"], cfg.rms_eps)
+    return x + f, stats
 
 
 def _ffn(lp: Dict, kind: LayerKind, m: jax.Array, cfg: LlamaConfig, active):
@@ -279,36 +259,36 @@ def _ffn(lp: Dict, kind: LayerKind, m: jax.Array, cfg: LlamaConfig, active):
     return _mlp(lp, m, cfg.mlp_act), None
 
 
-def _run_layers(p: Dict, cfg: LlamaConfig, xs, positions, attend, *,
+def _run_layers(p: Dict, cfg: LlamaConfig, x, positions, attend, *,
                 cross=None, active=None, shardings=None, attend_state=None):
-    """Walk the stack through :func:`_layer`. ``attend(pi, qs, ks, vs,
+    """Walk the stack through :func:`_layer`. ``attend(pi, q, k, v,
     window)`` gets the layer's POOL index first (cross layers and blocks
     that are a feed-forward part alone own no pool entry; a recurrent
     layer's entry of the state list is its slot arena, and ``attend_state``
     is its closure); ``cross(ci) -> (k, v, has_image,
-    cross_len)`` serves the ``ci``-th cross layer. Returns ``(xs, stats)``,
+    cross_len)`` serves the ``ci``-th cross layer. Returns ``(x, stats)``,
     the routed layers' stats summed (``None`` with no routed layer)."""
     ci = pi = 0
     stats = None
     for li, kind in enumerate(layer_kinds(cfg)):
         lp = p[f"layer_{li}"]
         if kind.cross:
-            xs, _ = _layer(lp, kind, xs, positions, None, cfg,
-                           cross=cross(ci), shardings=shardings)
+            x, _ = _layer(lp, kind, x, positions, None, cfg,
+                          cross=cross(ci), shardings=shardings)
             ci += 1
             continue
         if kind.part == "ffn":
-            xs, st = _layer(lp, kind, xs, positions, None, cfg,
-                            active=active)
+            x, st = _layer(lp, kind, x, positions, None, cfg,
+                           active=active)
         else:
-            xs, st = _layer(
-                lp, kind, xs, positions,
+            x, st = _layer(
+                lp, kind, x, positions,
                 functools.partial(attend_state if kind.state else attend,
                                   pi), cfg, active=active)
             pi += 1
         if st is not None:
             stats = st if stats is None else stats + st
-    return xs, stats
+    return x, stats
 
 
 # top-N alternatives reported per sampled token when a request asks for
@@ -546,32 +526,29 @@ def make_prefill(cfg: LlamaConfig, block_size: int, blocks_per_seq: int,
         positions = jnp.broadcast_to(jnp.arange(T, dtype=jnp.int32), (B, T))
         tbl = block_tables[:, :m_used]  # [B, m_used]
 
-        def attend_latent(pi, qs, rows, kv_b, window):
+        def attend_latent(pi, q, r, kv_b, window):
             # the expanded path: every head's keys and values up-projected
             # from the prompt's own latents, then the flash kernel (keys of
             # head_dim beside values of v_head_dim); the pool gets the rows
-            (q,), (r,) = qs, rows
             k, v = mla.expand(r, kv_b, cfg)
             o = dot_product_attention(q, k, v, kv_lengths=n, causal=True,
                                       scale=mla.softmax_scale(cfg))
             pool = kv[pi]["c"]
             kv[pi] = {"c": pool.at[tbl].set(r.reshape(
                 B, m_used, block_size, -1).astype(pool.dtype))}
-            return (o,)
+            return o
 
-        def attend_state(pi, hs, at, _vs, _window):
+        def attend_state(pi, h, at, _v, _window):
             # from position 0 the scan starts from a ZERO state and a zero
             # tail, whatever the slot held: a reused slot needs no clearing.
             # The bucket's padded tail is identity tokens, so what is
             # written is the state and tail the last REAL token left
-            (h,) = hs
             o, kv[pi] = _RECURRENT[cfg.state_kind].prefill(
                 at, h, kv[pi], slots, n, cfg, carry=False,
                 kernel=on_tpu_platform())
-            return (o,)
+            return o
 
-        def attend(pi, qs, ks, vs, window):
-            (q,), (k,), (v,) = qs, ks, vs
+        def attend(pi, q, k, v, window):
             # causal within the prompt; pad keys masked by the true length —
             # kv_lengths (not a mask) keeps the pallas flash kernel eligible
             # for bucketed prefill shapes (VERDICT r1 #3); head-split
@@ -587,16 +564,16 @@ def make_prefill(cfg: LlamaConfig, block_size: int, blocks_per_seq: int,
                           cfg.head_dim),
                 v.reshape(B, m_used, block_size, cfg.n_kv_heads,
                           cfg.head_dim), kv_quant, shardings)
-            return (o,)
+            return o
 
         # gated cross-attention over vision states: no rope, no KV pool
         # traffic — its keys are static per request
-        (x,), _ = _run_layers(
-            p, cfg, (x,), (positions,),
+        x, _ = _run_layers(
+            p, cfg, x, positions,
             attend_latent if cfg.latent else attend,
             cross=lambda ci: (cross_kv[ci]["k"], cross_kv[ci]["v"],
                               has_image, cross_len),
-            active=(positions < n[:, None],), shardings=shardings,
+            active=positions < n[:, None], shardings=shardings,
             attend_state=attend_state)
         last = jnp.take_along_axis(x, (n - 1).reshape(B, 1, 1), axis=1)
         return kv, _logits(p, last, cfg)[:, 0]  # [B, V]
@@ -648,9 +625,7 @@ def _pool_kernel_call(shardings: Optional["EngineShardings"],
     Mosaic kernel cannot be auto-partitioned; attention is head-local so
     the split needs no collectives). int8 scale arrays ride along when
     present, split on the same kv-head axis as the blocks they scale.
-    Shared by decode/verify (``_make_token_forward``), the dynamic-start
-    continuation (``_ragged_pool_attention``) and the fused step so the
-    sharding specs can never diverge between them. ``window``: a window
+    Decode and verify call it (``_make_token_forward``). ``window``: a window
     layer's bound, handed to the kernel as a static argument (0 hands it
     nothing)."""
     from ..ops.pallas.paged_attention import paged_decode_attention as kernel
@@ -678,36 +653,10 @@ def _pool_kernel_call(shardings: Optional["EngineShardings"],
     )(qf, kpool, vpool, tf, lf, ks, vs)
 
 
-def _ragged_pool_attention(q: jax.Array, kv_layer: Dict, tables: jax.Array,
-                           positions: jax.Array, block_size: int,
-                           shardings: Optional["EngineShardings"],
-                           window: int = 0):
-    """Ragged attention of ``[B, T, H, D]`` queries over the paged pool:
-    the Pallas pool kernel on TPU platforms (``T`` queries flattened
-    into the row axis, through the shared ``_pool_kernel_call`` dispatch
-    seam), the XLA gather reference elsewhere (which XLA partitions
-    automatically). int8 pool scales ride along either way."""
-    B, T, H, D = q.shape
-    ks, vs = _pool_scales(kv_layer)
-    kpool, vpool = kv_layer["k"], kv_layer["v"]
-    from ..ops.attention import on_tpu_platform, ragged_gather_attention
-
-    if not on_tpu_platform():
-        return ragged_gather_attention(q, kpool, vpool, tables, positions,
-                                       ks, vs, window=window)
-    L = tables.shape[1] * block_size
-    qf = q.reshape(B * T, H, D)
-    tf = jnp.repeat(tables, T, axis=0) if T > 1 else tables
-    lf = jnp.clip(positions + 1, 1, L).reshape(B * T)
-    o = _pool_kernel_call(shardings, qf, kpool, vpool, tf, lf, ks, vs,
-                          window=window)
-    return o.reshape(B, T, H, D)
-
-
 def make_prefill_cont(cfg: LlamaConfig, block_size: int, blocks_per_seq: int,
-                      bucket: int, start_blocks: int = 0,
+                      bucket: int, start_blocks: int,
                       shardings: Optional[EngineShardings] = None,
-                      kv_quant: bool = False, ragged: bool = False):
+                      kv_quant: bool = False):
     """Compile a CONTINUATION prefill chunk: ``cont(params, kv, ids, n_text,
     block_tables) -> (kv, next_logits)``.
 
@@ -733,74 +682,15 @@ def make_prefill_cont(cfg: LlamaConfig, block_size: int, blocks_per_seq: int,
     same as ``make_prefill``); the signature gains the
     ``(cross_kv, has_image, cross_len)`` tail.
 
-    ``ragged`` (``SHAI_RAGGED_ATTENTION``): the chunk start becomes DATA —
-    ``cont(params, kv, ids, n_text, block_tables, start)`` — and the
-    chunk's queries attend their prior context *through the pool* via the
-    ragged path (per-query lengths) instead of a static-offset dense
-    gather. ONE executable per chunk bucket replaces the whole
-    one-per-start continuation ladder, killing the pad waste of
-    intermediate chunks compiled for the largest start. Text engines only
-    (the ragged gate excludes cross configs).
-
     ``kv_quant``: int8 pool — the prior-context gather dequantizes, the
     chunk scatter quantizes per block x head (``_scatter_blocks``).
     """
     assert bucket % block_size == 0
-    assert ragged or start_blocks >= 1
+    assert start_blocks >= 1
     start = start_blocks * block_size
     c_blocks = bucket // block_size
-    assert ragged or start_blocks + c_blocks <= blocks_per_seq
+    assert start_blocks + c_blocks <= blocks_per_seq
     cross_set = set(cfg.cross_attention_layers)
-    assert not (ragged and (cross_set or cfg.latent or cfg.recurrent)), \
-        "ragged continuation serves text engines with per-head keys " \
-        "(the engine gate)"
-
-    def _ragged_impl(params, kv, ids, n_text, block_tables, start_arr):
-        p = params["params"]
-        B = ids.shape[0]  # == 1
-        x = _embed(p, ids, cfg)
-        T = x.shape[1]  # == bucket
-        start_arr = start_arr.astype(jnp.int32)
-        offs = jnp.broadcast_to(jnp.arange(T, dtype=jnp.int32), (B, T))
-        positions = start_arr[:, None] + offs
-        sb = start_arr // block_size                        # [B]
-        tbl_chunk = jnp.take_along_axis(
-            block_tables,
-            sb[:, None] + jnp.arange(c_blocks, dtype=jnp.int32)[None, :],
-            axis=1)                                         # [B, c_blocks]
-        tables = block_tables[:, :blocks_per_seq]
-
-        def attend(pi, qs, ks, vs, window):
-            (q,), (k,), (v,) = qs, ks, vs
-            # scatter the chunk FIRST: its queries then attend their own
-            # freshly-written keys through the pool, exactly like decode —
-            # [prior, chunk] is the pool's table order, no concat needed
-            kv[pi] = _scatter_blocks(
-                kv[pi], tbl_chunk,
-                k.reshape(B, c_blocks, block_size, cfg.n_kv_heads,
-                          cfg.head_dim),
-                v.reshape(B, c_blocks, block_size, cfg.n_kv_heads,
-                          cfg.head_dim), kv_quant, shardings)
-            return (_ragged_pool_attention(q, kv[pi], tables, positions,
-                                           block_size, shardings, window),)
-
-        (x,), _ = _run_layers(p, cfg, (x,), (positions,), attend,
-                              active=(offs < n_text[:, None],))
-        last = jnp.take_along_axis(x, (n_text - 1).reshape(B, 1, 1), axis=1)
-        return kv, _logits(p, last, cfg)[:, 0]  # [B, V]
-
-    if ragged:
-        def cont(params, kv, ids, n_text, block_tables, start):
-            return _ragged_impl(params, kv, ids, n_text, block_tables,
-                                start)
-
-        if shardings is None:
-            return jax.jit(cont, donate_argnums=(1,))
-        sh, rep = shardings, shardings.rep
-        kvsh = sh.kv_pool(cfg.n_layers, quant=kv_quant)
-        return jax.jit(cont, donate_argnums=(1,),
-                       in_shardings=(sh.params, kvsh, rep, rep, rep, rep),
-                       out_shardings=(kvsh, rep))
 
     def _cont_impl(params, kv, ids, n_text, block_tables, cross_kv=None,
                    has_image=None, cross_len=None, slots=None):
@@ -816,12 +706,11 @@ def make_prefill_cont(cfg: LlamaConfig, block_size: int, blocks_per_seq: int,
                 + jnp.arange(block_size)[None, None, :]).reshape(B, start)
         tbl_chunk = block_tables[:, start_blocks:start_blocks + c_blocks]
 
-        def attend_latent(pi, qs, rows, kv_b, window):
+        def attend_latent(pi, q, r, kv_b, window):
             # the chunk's prefix is LATENT in the pool: its rows are
             # gathered through the block table and expanded beside the
             # chunk's own (one up-projection of start + T rows a layer,
             # a third of the absorbed form's operations at these widths)
-            (q,), (r,) = qs, rows
             pool = kv[pi]["c"]
             prior = pool.reshape(-1, pool.shape[-1])[goff].astype(r.dtype)
             k, v = mla.expand(jnp.concatenate([prior, r], axis=1), kv_b, cfg)
@@ -829,19 +718,17 @@ def make_prefill_cont(cfg: LlamaConfig, block_size: int, blocks_per_seq: int,
                                       scale=mla.softmax_scale(cfg))
             kv[pi] = {"c": pool.at[tbl_chunk].set(r.reshape(
                 B, c_blocks, block_size, -1).astype(pool.dtype))}
-            return (o,)
+            return o
 
-        def attend_state(pi, hs, at, _vs, _window):
+        def attend_state(pi, h, at, _v, _window):
             # a continuation chunk's prefix is the SLOT's state and tail,
             # not the pool: read, scanned over the chunk, written back
-            (h,) = hs
             o, kv[pi] = _RECURRENT[cfg.state_kind].prefill(
                 at, h, kv[pi], slots, n_text, cfg, carry=True,
                 kernel=on_tpu_platform())
-            return (o,)
+            return o
 
-        def attend(pi, qs, ks, vs, window):
-            (q,), (k,), (v,) = qs, ks, vs
+        def attend(pi, q, k, v, window):
             if kv_quant:
                 # int8 prior context: block-shaped gather so the
                 # per-(block, head) scales broadcast on the dequant
@@ -868,14 +755,14 @@ def make_prefill_cont(cfg: LlamaConfig, block_size: int, blocks_per_seq: int,
                           cfg.head_dim),
                 v.reshape(B, c_blocks, block_size, cfg.n_kv_heads,
                           cfg.head_dim), kv_quant, shardings)
-            return (o,)
+            return o
 
-        (x,), _ = _run_layers(
-            p, cfg, (x,), (positions,),
+        x, _ = _run_layers(
+            p, cfg, x, positions,
             attend_latent if cfg.latent else attend,
             cross=lambda ci: (cross_kv[ci]["k"], cross_kv[ci]["v"],
                               has_image, cross_len),
-            active=(offs < n_text[:, None],), shardings=shardings,
+            active=offs < n_text[:, None], shardings=shardings,
             attend_state=attend_state)
         last = jnp.take_along_axis(x, (n_text - 1).reshape(B, 1, 1), axis=1)
         return kv, _logits(p, last, cfg)[:, 0]  # [B, V]
@@ -967,12 +854,11 @@ def _make_token_forward(cfg: LlamaConfig, block_size: int,
             behind = positions[:, :, None] - jnp.arange(L)[None, None, :]
             mask = (behind >= 0)[:, None]               # [B, 1, T, L]
 
-        def attend_latent(pi, qs, rows, kv_b, window):
+        def attend_latent(pi, q, r, kv_b, window):
             # the absorbed path: the new tokens' rows go into the pool,
             # the queries into the rows' own coordinates, and every head
             # reads the row's tiles ONCE through the latent kernel (the
             # gather reference off the TPU); W^V comes after the softmax
-            (q,), (r,) = qs, rows
             pool = kv[pi]["c"]
             kv[pi] = {"c": pool.reshape(-1, pool.shape[-1]).at[widx].set(
                 r.astype(pool.dtype)).reshape(pool.shape)}
@@ -980,22 +866,20 @@ def _make_token_forward(cfg: LlamaConfig, block_size: int,
                 mla.absorb_q(q, kv_b, cfg), kv[pi]["c"], tables, positions,
                 rank=cfg.kv_lora_rank, scale=mla.softmax_scale(cfg),
                 paged=paged)
-            return (mla.unabsorb(u, kv_b, cfg),)
+            return mla.unabsorb(u, kv_b, cfg)
 
-        def attend_state(pi, hs, at, _vs, _window):
+        def attend_state(pi, h, at, _v, _window):
             # one recurrent step a row, in place on the row's slot; a
             # padded or finished row steps the NULL slot (the arena's last),
             # so no sequence's state or tail is touched for it
             assert T == 1, "one token a step over recurrent state"
-            (h,) = hs
             slots = jnp.where(active > 0, slot_idx,
                               kv[pi]["s"].shape[0] - 1)
             o, kv[pi] = _RECURRENT[cfg.state_kind].decode(
                 at, h, kv[pi], slots, cfg, kernel=paged)
-            return (o,)
+            return o
 
-        def attend(pi, qs, ks, vs, window):
-            (q,), (kk,), (vv,) = qs, ks, vs
+        def attend(pi, q, kk, vv, window):
             if kv_quant:
                 # int8 pool: one read-modify-write requantize per new token
                 # (T is 1 for decode, k+1 for verify — a tiny unroll); the
@@ -1038,32 +922,32 @@ def _make_token_forward(cfg: LlamaConfig, block_size: int,
                     jnp.repeat(tables, T, axis=0) if T > 1 else tables,
                     jnp.clip(positions + 1, 1, L).reshape(B * T),
                     ksc, vsc, window=window)
-                return (o.reshape(B, T, cfg.n_heads, cfg.head_dim),)
+                return o.reshape(B, T, cfg.n_heads, cfg.head_dim)
             if kv_quant:
                 # deviceless int8 path: the gather reference dequantizes
                 # right after the block gather (ops.attention)
                 from ..ops.attention import ragged_gather_attention
 
-                return (ragged_gather_attention(
+                return ragged_gather_attention(
                     q, kv[pi]["k"], kv[pi]["v"], tables, positions, ksc,
-                    vsc, window=window),)
+                    vsc, window=window)
             kflat = kv[pi]["k"].reshape(-1, cfg.n_kv_heads, cfg.head_dim)
             vflat = kv[pi]["v"].reshape(-1, cfg.n_kv_heads, cfg.head_dim)
             # a window layer's query also drops what lies a window behind
             m = mask & (behind < window)[:, None] if window else mask
-            return (dot_product_attention(q, kflat[goff], vflat[goff],
-                                          mask=m),)
+            return dot_product_attention(q, kflat[goff], vflat[goff],
+                                         mask=m)
 
         # slot_idx maps the COMPACTED batch row back to its slot's rows in
         # the full cross-kv buffers (gather fuses into the attention read)
-        (x,), stats = _run_layers(
-            p, cfg, (x,), (positions,),
+        x, stats = _run_layers(
+            p, cfg, x, positions,
             attend_latent if cfg.latent else attend,
             cross=lambda ci: (cross_kv[ci]["k"][slot_idx],
                               cross_kv[ci]["v"][slot_idx], has_image,
                               cross_len),
-            active=None if active is None or not cfg.n_experts else (
-                jnp.broadcast_to(active[:, None] > 0, (B, T)),),
+            active=None if active is None or not cfg.n_experts else
+            jnp.broadcast_to(active[:, None] > 0, (B, T)),
             shardings=shardings, attend_state=attend_state)
         return kv, _logits(p, x, cfg), stats  # [B, T, V] f32
 
@@ -1304,199 +1188,3 @@ def make_verify(cfg: LlamaConfig, block_size: int, blocks_per_seq: int,
     return jax.jit(verify, donate_argnums=(1,),
                    in_shardings=in_sh,
                    out_shardings=(kvsh,) + (rep,) * 8)
-
-
-def make_fused_step(cfg: LlamaConfig, block_size: int, blocks_per_seq: int,
-                    max_num_seqs: int, bucket: int,
-                    shardings: Optional[EngineShardings] = None,
-                    paged: Optional[bool] = None, feedback: bool = False,
-                    kv_quant: bool = False):
-    """Compile ONE mixed-phase ragged engine step (``SHAI_FUSED_STEP``):
-    the whole decode batch PLUS one chunked-prefill continuation window in
-    a single dispatch.
-
-    ``fused(params, kv, tokens [B], pos [B], tables [B, M], active [B],
-    rng, fold, temperature [B], top_k [B], top_p [B], c_ids [1, C],
-    c_ntext [1], c_table [1, M], c_start [1]) ->
-    (kv, next_tokens [B][, pos + 1, fold + FOLD_STRIDE], top_ids, top_lp,
-    tok_lp, c_logits [1, V])``.
-
-    Two sections share one layer walk over one donated pool:
-
-    - the DECODE section is ``make_decode``'s math verbatim — the ``T=1``
-      ``_make_token_forward`` body (same write offsets, same int8
-      read-modify-write requantize) with on-device sampling + logprobs —
-      so fused-off/fused-on token-exactness reduces to the section
-      ordering argument below;
-    - the CHUNK section is the dynamic-start continuation's math verbatim
-      (``make_prefill_cont(ragged=True)``): dynamic ``c_start``, chunk
-      scatter first, queries attending their prior context through the
-      pool. Its ``c_logits`` come back RAW — the host samples with the
-      group-specific rng fold, exactly as the laddered path does. A step
-      with no chunk passes null args (zero ids/table, ``c_ntext=1``,
-      ``c_start=0``): the window writes into the reserved null block 0
-      and its logits are dropped, the harmless-garbage padding
-      convention.
-
-    Exactness vs the laddered oracle hangs on per-layer ordering: the
-    chunk scatters BEFORE the decode rows write, matching the oracle's
-    device order (the continuation dispatch completes before the decode
-    dispatch it precedes), so any write collision through a stale table
-    resolves identically. Decode queries then read the chunk's
-    layer-``l`` keys like the oracle's decode step reads the finished
-    continuation's; the chunk's queries never read this step's decode
-    writes (decode rows write past their own prompts into blocks the
-    chunk's ``length``-bounded reads cannot reach — block tables only
-    ever share REGISTERED full prefix blocks, and the null block 0 sits
-    outside every live window).
-
-    On TPU both sections' queries flatten into ONE ragged kernel call
-    (``ops.attention.mixed_phase_ragged_attention`` — ``B + C``
-    single-query rows, the kernel blind to phase). Off-TPU each section
-    keeps its own oracle's attention function (dense gather + mask or the
-    int8 gather reference for decode, ``ragged_gather_attention`` for the
-    chunk) because the two reference softmaxes need not be bitwise
-    interchangeable.
-
-    One executable per BATCH BUCKET replaces the decode batch ladder, the
-    per-bucket dynamic-start continuation ladder, and the
-    cached-admission entries: the chunk window ``C`` is pinned to the
-    largest prefill bucket. Text engines only (the ragged gate excludes
-    cross configs).
-    """
-    assert bucket % block_size == 0
-    assert not cfg.cross_attention_layers, \
-        "fused step serves text engines (the ragged gate)"
-    assert not cfg.window_layers and not cfg.n_experts, \
-        "fused step: no window layers, no experts (the boot refuses them)"
-    c_blocks = bucket // block_size
-    L = block_size * blocks_per_seq
-    paged = _resolve_paged(paged)
-    pool_call = functools.partial(_pool_kernel_call, shardings)
-
-    def _fused_impl(params, kv, tokens, pos, tables, active, rng, fold,
-                    temperature, top_k, top_p, c_ids, c_ntext, c_table,
-                    c_start):
-        from ..ops.attention import mixed_phase_ragged_attention
-
-        rng = jax.random.fold_in(rng, fold)  # as make_decode
-        p = params["params"]
-        B = max_num_seqs
-        C = bucket
-        Hkv, Dh = cfg.n_kv_heads, cfg.head_dim
-        # -- decode section inputs: make_decode verbatim (T == 1) --------
-        x = _embed(p, tokens[:, None], cfg)
-        positions = pos[:, None]                                # [B, 1]
-        pblk = positions // block_size
-        blk = jnp.where(
-            pblk < blocks_per_seq,
-            jnp.take_along_axis(
-                tables, jnp.clip(pblk, 0, blocks_per_seq - 1), axis=1),
-            0)
-        widx = blk * block_size + positions % block_size
-        if not paged and not kv_quant and not cfg.latent:
-            goff = (tables[:, :, None] * block_size
-                    + jnp.arange(block_size)[None, None, :]).reshape(B, L)
-            mask = (jnp.arange(L)[None, None, :]
-                    <= positions[:, :, None])[:, None]  # [B, 1, 1, L]
-        # -- chunk section inputs: the ragged continuation verbatim ------
-        xc = _embed(p, c_ids, cfg)
-        c_start32 = c_start.astype(jnp.int32)
-        c_positions = c_start32[:, None] + jnp.broadcast_to(
-            jnp.arange(C, dtype=jnp.int32), (1, C))
-        sb = c_start32 // block_size
-        tbl_chunk = jnp.take_along_axis(
-            c_table,
-            sb[:, None] + jnp.arange(c_blocks, dtype=jnp.int32)[None, :],
-            axis=1)                                      # [1, c_blocks]
-
-        def attend(li, qs, ks, vs, window):
-            # the two streams of the one layer call: decode rows, chunk
-            (q, qc), (kk, kc), (vv, vc) = qs, ks, vs
-            # chunk scatter FIRST each layer: the oracle's continuation
-            # dispatch finishes before its decode dispatch, so stale-table
-            # write collisions must resolve in the same order here
-            kv[li] = _scatter_blocks(
-                kv[li], tbl_chunk,
-                kc.reshape(1, c_blocks, block_size, Hkv, Dh),
-                vc.reshape(1, c_blocks, block_size, Hkv, Dh), kv_quant,
-                shardings)
-            if kv_quant:
-                from ..ops.quant import requantize_block_tokens
-
-                kpool, vpool = kv[li]["k"], kv[li]["v"]
-                ks_, vs_ = kv[li]["ks"], kv[li]["vs"]
-                bt = blk[:, 0]
-                pin = positions[:, 0] % block_size
-                kq, ksn = requantize_block_tokens(
-                    kpool[bt], ks_[bt], kk[:, 0], pin)
-                vq, vsn = requantize_block_tokens(
-                    vpool[bt], vs_[bt], vv[:, 0], pin)
-                kv[li] = {"k": kpool.at[bt].set(kq),
-                          "v": vpool.at[bt].set(vq),
-                          "ks": ks_.at[bt].set(ksn),
-                          "vs": vs_.at[bt].set(vsn)}
-            else:
-                pool_shape = kv[li]["k"].shape
-                kflat = kv[li]["k"].reshape(-1, Hkv, Dh)
-                vflat = kv[li]["v"].reshape(-1, Hkv, Dh)
-                kflat = kflat.at[widx].set(kk.astype(kflat.dtype))
-                vflat = vflat.at[widx].set(vv.astype(vflat.dtype))
-                kv[li] = {"k": kflat.reshape(pool_shape),
-                          "v": vflat.reshape(pool_shape)}
-            ksc, vsc = _pool_scales(kv[li])
-            if paged:
-                o_dec, o_chk = mixed_phase_ragged_attention(
-                    q.reshape(B, cfg.n_heads, Dh),
-                    qc.reshape(C, cfg.n_heads, Dh),
-                    kv[li]["k"], kv[li]["v"], tables, c_table,
-                    pos, c_positions.reshape(C), ksc, vsc,
-                    pool_call=pool_call)
-                return (o_dec.reshape(B, 1, cfg.n_heads, Dh),
-                        o_chk.reshape(1, C, cfg.n_heads, Dh))
-            # off-TPU each section keeps ITS OWN oracle's attention
-            # function — the two reference softmaxes need not match
-            # bitwise, and token-exactness is per-section
-            if kv_quant:
-                from ..ops.attention import ragged_gather_attention
-
-                o = ragged_gather_attention(
-                    q, kv[li]["k"], kv[li]["v"], tables, positions,
-                    ksc, vsc)
-            else:
-                kflat = kv[li]["k"].reshape(-1, Hkv, Dh)
-                vflat = kv[li]["v"].reshape(-1, Hkv, Dh)
-                o = dot_product_attention(q, kflat[goff], vflat[goff],
-                                          mask=mask)
-            return o, _ragged_pool_attention(qc, kv[li], c_table,
-                                             c_positions, block_size,
-                                             shardings)
-
-        (x, xc), _ = _run_layers(p, cfg, (x, xc), (positions, c_positions),
-                                 attend)
-        logits = _logits(p, x, cfg)[:, 0]                       # [B, V]
-        nxt = sample_logits(logits, rng, temperature, top_k, top_p)
-        top_ids, top_lp, tok_lp = token_logprobs(logits, nxt)
-        lastc = jnp.take_along_axis(xc, (c_ntext - 1).reshape(1, 1, 1),
-                                    axis=1)
-        c_logits = _logits(p, lastc, cfg)[:, 0]                 # [1, V]
-        if feedback:
-            return (kv, nxt, pos + 1, fold + FOLD_STRIDE, top_ids, top_lp,
-                    tok_lp, c_logits)
-        return kv, nxt, top_ids, top_lp, tok_lp, c_logits
-
-    def fused(params, kv, tokens, pos, tables, active, rng, fold,
-              temperature, top_k, top_p, c_ids, c_ntext, c_table, c_start):
-        return _fused_impl(params, kv, tokens, pos, tables, active, rng,
-                           fold, temperature, top_k, top_p, c_ids, c_ntext,
-                           c_table, c_start)
-
-    donate = (1, 3) if feedback else (1,)
-    if shardings is None:
-        return jax.jit(fused, donate_argnums=donate)
-    sh, rep = shardings, shardings.rep
-    kvsh = sh.kv_pool(cfg.n_layers, quant=kv_quant)
-    in_sh = (sh.params, kvsh) + (rep,) * 13
-    out_sh = (kvsh,) + (rep,) * (7 if feedback else 5)
-    return jax.jit(fused, donate_argnums=donate,
-                   in_shardings=in_sh, out_shardings=out_sh)

@@ -39,53 +39,27 @@ def warm_executables(eng, prefix_lens: Sequence[int] = (0,)) -> int:
             elif 0 < p < b and eng._cross_kv is None:
                 eng._prefill_for(b, p)  # prefix path stays single-seq
                 n += 1
-    if eng._fused:
-        # fused mixed-phase step (SHAI_FUSED_STEP): the decode grid below
-        # builds the fused executables, and chunked-prefill continuation
-        # and cached admission ride the SAME executables — the rcont
-        # ladder has no fused-mode callers, so warming it would compile
-        # dead code
-        pass
-    elif eng._ragged:
-        # ragged continuation ladder (SHAI_RAGGED_ATTENTION): the chunk
-        # start is DATA, so ONE executable per chunk bucket covers every
-        # start offset the bucketed ladder compiled one-by-one — the
-        # chunked path and every cached-admission (warm start, bucket)
-        # pair alike
-        want = set()
-        if eng.ecfg.max_model_len > eng.buckets.max:
-            want.add(eng.buckets.max)
-        if eng.cache.prefix_caching:
-            for s in eng._cached_starts():
-                for cb in eng.buckets.buckets:
-                    if s + cb <= eng.ecfg.max_model_len:
-                        want.add(cb)
-        for cb in sorted(want):
-            if ("rcont", cb) not in eng._prefill:
-                eng._cont_for(0, cb)
-                n += 1
-    else:
-        if eng.ecfg.max_model_len > eng.buckets.max:
-            # chunked-prefill ladder: one continuation executable per chunk
-            # start past the largest bucket (cross engines included — their
-            # cont executables carry the cross-args tail)
-            C = eng.buckets.max
-            start = C
-            while start + C <= eng.ecfg.max_model_len:
-                eng._cont_for(start // eng.ecfg.block_size)
-                n += 1
-                start += C
-        if eng.cache.prefix_caching:
-            # cached-admission ladder: (warm start, chunk bucket) pairs so
-            # a cache hit never compiles post-ready (closed set — the SAME
-            # _cached_starts list admission picks from)
-            for s in eng._cached_starts():
-                for cb in eng.buckets.buckets:
-                    if s + cb <= eng.ecfg.max_model_len:
-                        key = ("cont", s // eng.ecfg.block_size, cb)
-                        if key not in eng._prefill:
-                            eng._cont_for(s // eng.ecfg.block_size, cb)
-                            n += 1
+    if eng.ecfg.max_model_len > eng.buckets.max:
+        # chunked-prefill ladder: one continuation executable per chunk
+        # start past the largest bucket (cross engines included — their
+        # cont executables carry the cross-args tail)
+        C = eng.buckets.max
+        start = C
+        while start + C <= eng.ecfg.max_model_len:
+            eng._cont_for(start // eng.ecfg.block_size)
+            n += 1
+            start += C
+    if eng.cache.prefix_caching:
+        # cached-admission ladder: (warm start, chunk bucket) pairs so
+        # a cache hit never compiles post-ready (closed set — the SAME
+        # _cached_starts list admission picks from)
+        for s in eng._cached_starts():
+            for cb in eng.buckets.buckets:
+                if s + cb <= eng.ecfg.max_model_len:
+                    key = ("cont", s // eng.ecfg.block_size, cb)
+                    if key not in eng._prefill:
+                        eng._cont_for(s // eng.ecfg.block_size, cb)
+                        n += 1
     bb = 1
     batch_buckets = []
     while bb < eng.ecfg.max_num_seqs:
@@ -148,15 +122,6 @@ def _run_warm_calls(eng) -> None:
             eng._lp1(logits, zeros((K,))))
 
     for key, fn in list(eng._prefill.items()):
-        if key[0] == "rcont":
-            # dynamic-start ragged continuation: the start rides as data
-            # (a zero start against the null table writes into reserved
-            # block 0 — garbage there is allowed by contract)
-            eng.cache.kv, logits = fn(
-                eng.params, eng.cache.kv, zeros((1, key[1])), ones((1,)),
-                zeros((1, M)), zeros((1,)))
-            warm_sampler(logits, per_row=False)
-            continue
         if key[0] == "cont":
             args = [eng.params, eng.cache.kv, zeros((1, key[2])),
                     ones((1,)), zeros((1, M))] + null_slots(1)
@@ -206,17 +171,6 @@ def _run_warm_calls(eng) -> None:
             args[1:4] = [eng.cache.kv, nxt, rest[0]]
             args[7] = rest[1]
             eng.cache.kv, nxt, *rest = fn(*args)
-        nxt.block_until_ready()
-    for bb, fn in list(eng._fused_fns.items()):
-        # fused mixed-phase executables: decode-style null rows plus the
-        # 4-arg null chunk window (ntext=1 against the zero table — the
-        # write lands in reserved block 0, allowed by contract). tokens
-        # and pos must be SEPARATE buffers: the feedback variant donates
-        # the position argument.
-        args = step_args(bb, zeros((bb,))) + [
-            zeros((1, eng.buckets.max)), ones((1,)), zeros((1, M)),
-            zeros((1,))]
-        eng.cache.kv, nxt, *_rest = fn(*args)
         nxt.block_until_ready()
     K = eng.ecfg.num_speculative_tokens
     for bb, fn in list(eng._verify_fns.items()):

@@ -537,14 +537,13 @@ class StepTelemetry:
         self.real_tokens = 0
         # per-phase split of the same accounting (prefill admission /
         # chunk continuation / decode / verify): where the pad waste
-        # lives decides WHICH ladder to collapse — the fused-step A/B
-        # (bench.py fused) reads its win off the decode+chunk rows
+        # lives decides WHICH ladder to collapse
         self.pad_by_phase: Dict[str, int] = {}
         self.real_by_phase: Dict[str, int] = {}
         # the same accounting's count of dispatches: work done has a
         # number of programs, not only of tokens
         self.dispatches_by_phase: Dict[str, int] = {}
-        # host-to-device arrays put for decode, verify and fused dispatches
+        # host-to-device arrays put for decode and verify dispatches
         # (a table refresh counts one, a new composition each of its
         # arrays): over ``steps`` it says whether a steady step hands the
         # device only what changed

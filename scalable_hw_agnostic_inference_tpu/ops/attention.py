@@ -249,7 +249,7 @@ def dot_product_attention(
     return _xla_attention(q, k, v, mask, bias, scale)
 
 
-# -- ragged paged attention (reference fallback + dispatch) ------------------
+# -- ragged paged attention (the deviceless reference) ----------------------
 
 
 def ragged_gather_attention(
@@ -307,82 +307,3 @@ def ragged_gather_attention(
         mask = mask & (jnp.arange(L)[None, None, :]
                        > positions[:, :, None] - window)[:, None]
     return _xla_attention(q, kctx, vctx, mask, None, scale)
-
-
-def ragged_paged_attention(
-    q: jax.Array,           # [rows, H, D] one query per row
-    k_pool: jax.Array,
-    v_pool: jax.Array,
-    tables: jax.Array,      # [rows, M]
-    lengths: jax.Array,     # [rows] valid token count per row
-    k_scale: Optional[jax.Array] = None,
-    v_scale: Optional[jax.Array] = None,
-    *,
-    scale: Optional[float] = None,
-) -> jax.Array:
-    """Ragged paged attention with implementation dispatch: the Pallas
-    kernel on TPU platforms, the XLA gather reference elsewhere (tier-1
-    runs deviceless). Multi-token callers flatten ``T`` queries into the
-    row axis with per-row ``lengths``, the layout both impls share."""
-    if on_tpu_platform():
-        from .pallas.paged_attention import paged_decode_attention
-
-        return paged_decode_attention(q, k_pool, v_pool, tables, lengths,
-                                      k_scale, v_scale, scale=scale)
-    out = ragged_gather_attention(
-        q[:, None], k_pool, v_pool, tables,
-        (lengths.astype(jnp.int32) - 1)[:, None], k_scale, v_scale,
-        scale=scale)
-    return out[:, 0]
-
-
-def mixed_phase_ragged_attention(
-    q_dec: jax.Array,       # [B, H, D] decode queries, one per slot row
-    q_chunk: jax.Array,     # [C, H, D] continuation-chunk queries (1 seq)
-    k_pool: jax.Array,
-    v_pool: jax.Array,
-    tables_dec: jax.Array,  # [B, M] per-slot block tables
-    c_table: jax.Array,     # [1, M] the chunking sequence's table
-    pos_dec: jax.Array,     # [B] each decode row's own cache position
-    c_pos: jax.Array,       # [C] per-chunk-query cache positions
-    k_scale: Optional[jax.Array] = None,
-    v_scale: Optional[jax.Array] = None,
-    *,
-    scale: Optional[float] = None,
-    pool_call=None,
-):
-    """Mixed-phase ragged attention (``SHAI_FUSED_STEP``): ``B`` decode
-    rows and one ``C``-token continuation chunk attend the paged pool in
-    ONE ragged dispatch.
-
-    The ragged kernel is already row-oriented — every row carries its own
-    ``(table, length)`` and pays only its own live blocks — so phases fuse
-    by pure layout: the chunk's ``C`` single-query rows are appended after
-    the ``B`` decode rows (chunk rows share one block table, repeated),
-    lengths are each query's ``position + 1``, and the kernel never learns
-    which phase a row belongs to. The outputs split back at row ``B``:
-    ``(o_dec [B, H, D], o_chunk [C, H, D])``.
-
-    ``pool_call`` is the caller's pre-bound dispatch seam (the engine
-    passes ``runner._pool_kernel_call`` closed over the kernel and the TP
-    shardings); when ``None`` the rows go through
-    :func:`ragged_paged_attention` — Pallas on TPU, the XLA gather oracle
-    elsewhere — which is the path the fused-vs-laddered exactness tests
-    pin first (gather oracle before kernel).
-    """
-    B, _H, _D = q_dec.shape
-    C = q_chunk.shape[0]
-    M = tables_dec.shape[1]
-    block_size = k_pool.shape[1]
-    L = M * block_size
-    qf = jnp.concatenate([q_dec, q_chunk], axis=0)
-    tf = jnp.concatenate([tables_dec, jnp.repeat(c_table, C, axis=0)],
-                         axis=0)
-    lf = jnp.clip(jnp.concatenate([pos_dec, c_pos]) + 1, 1, L).astype(
-        jnp.int32)
-    if pool_call is None:
-        of = ragged_paged_attention(qf, k_pool, v_pool, tf, lf, k_scale,
-                                    v_scale, scale=scale)
-    else:
-        of = pool_call(qf, k_pool, v_pool, tf, lf, k_scale, v_scale)
-    return of[:B], of[B:]

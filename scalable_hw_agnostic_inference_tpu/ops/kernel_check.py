@@ -92,7 +92,7 @@ def _pool_case(H: int, Hkv: int, D: int, block_size: int,
     block tables: rows of mixed context lengths, each with its own table
     (a decode step), or, ``one_seq``, ``rows`` consecutive queries of ONE
     sequence flattened a query a row, all sharing its table (what
-    speculative verify and the dynamic-start continuation dispatch).
+    speculative verify dispatches).
 
     ``tile_edges`` is what a kernel that walks several pool blocks a tile
     can get wrong: lengths on both sides of a tile's edge, and every pool

@@ -295,10 +295,9 @@ def paged_decode_attention(
     ``cdiv(lengths[b], tile)`` tiles of its table and never looks past
     them, so the table's width only bounds what a row may hold. A row of
     length 0 (an inactive or pad slot, a table of zeros) walks one tile of
-    the null block and returns finite values. Multi-token callers
-    (speculative verify, the dynamic-start continuation, the fused step)
-    flatten their ``T`` queries into the batch axis with per-query lengths:
-    the kernel never learns which phase a row belongs to.
+    the null block and returns finite values. A multi-token caller
+    (speculative verify) flattens its ``T`` queries into the batch axis
+    with per-query lengths.
 
     ``k_scale``/``v_scale``: per-block x kv-head f32 scales of an int8 pool
     (``SHAI_KV_QUANT=int8``), dequantized in-kernel. ``window`` (static;

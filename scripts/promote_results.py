@@ -29,10 +29,6 @@ KEYS = {"sd": "sd21_img_s",
         # KV tiering (PR 10): cold/warm-host-tier TTFT ratio on prompt
         # replay after eviction pressure (bench.py kvtier)
         "kvtier": "kvtier_warm_ttft_speedup",
-        # ragged paged attention + int8 KV (PR 11): mixed-length decode
-        # tok/s with ragged+quant on; the line also carries
-        # kv_quant_capacity_ratio (blocks per fixed SHAI_HBM_GIB)
-        "ragged": "ragged_tps",
         # multi-tenant QoS (PR 12): high-priority tenant p99 TTFT under a
         # low-priority flood, FIFO/QoS ratio (bench.py qos)
         "qos": "qos_flood_p99_ratio",
@@ -44,10 +40,6 @@ KEYS = {"sd": "sd21_img_s",
         # a mid-decode drain cut, KV shipped through the MIGRATE envelope
         # vs manifest-only recompute; errors REQUIRED 0 (bench.py migrate)
         "migrate": "migrate_resume_p50_ms",
-        # fused mixed-phase step (PR 16): laddered/fused TPOT ratio under
-        # a two-wave mixed prefill/decode load — chunk windows ride the
-        # decode dispatch; errors REQUIRED 0 (bench.py fused)
-        "fused": "fused_step_tpot_ratio",
         # KV fabric (PR 17): fabric-off/fabric-on TTFT p50 ratio under a
         # shared-system-prompt load — the peer-probe rung pulls the run
         # from the holder pod instead of re-prefilling; token-exactness

@@ -106,8 +106,7 @@ def test_the_stage_is_the_published_model_cut_in_depth_alone():
     (20, {}),                                 # below the window (32)
     (75, {}),      # past it: chunks of 32 at starts 32 and 64 cross its edge
     (40, {"SHAI_PAGED_DECODE": "1"}),         # the Pallas paged kernel
-    (75, {"SHAI_RAGGED_ATTENTION": "1"}),     # the ragged continuation
-], ids=["below-window", "chunks-cross-the-edge", "paged-kernel", "ragged"])
+], ids=["below-window", "chunks-cross-the-edge", "paged-kernel"])
 def test_engine_agrees_with_the_plain_reference_on_logits(
         tiny_params, n_prompt, env, monkeypatch):
     for k, v in env.items():
@@ -325,20 +324,31 @@ def test_skipping_the_sorts_changes_no_token(temps, top_k, top_p):
 
 @pytest.mark.parametrize("env,over,names", [
     ({"SHAI_KV_QUANT": "int8"}, {}, "SHAI_KV_QUANT=int8 with window layers"),
-    ({"SHAI_FUSED_STEP": "1", "SHAI_RAGGED_ATTENTION": "1"}, {},
-     "SHAI_FUSED_STEP with window or expert layers"),
     ({"SHAI_KVTIER": "1"}, {"enable_prefix_caching": True},
      "SHAI_KVTIER"),
     ({}, {"quantization": "int8"}, "quantization: int8 with expert layers"),
     ({}, {"tensor_parallel_size": 2},
      "tensor_parallel_size > 1 with expert layers"),
-], ids=["int8-kv", "fused-step", "kvtier", "int8-weights", "tp"])
+], ids=["int8-kv", "kvtier", "int8-weights", "tp"])
 def test_unsupported_combinations_are_refused_by_name(
         tiny_params, env, over, names, monkeypatch):
     for k, v in env.items():
         monkeypatch.setenv(k, v)
     with pytest.raises(ValueError, match=names):
         _engine(tiny_params, **over)
+
+
+def test_the_deleted_switches_are_not_read(tiny_params, monkeypatch):
+    """``SHAI_RAGGED_ATTENTION`` and ``SHAI_FUSED_STEP`` chose programs that
+    are gone. A deployment that still sets them boots (they were refused
+    here by name) and serves what one without them serves."""
+    sp = SamplingParams(temperature=0.0, max_new_tokens=6)
+    prompt = _prompt(40)             # a prefill and one continuation chunk
+    [plain] = _engine(tiny_params).generate([prompt], sp)
+    monkeypatch.setenv("SHAI_RAGGED_ATTENTION", "1")
+    monkeypatch.setenv("SHAI_FUSED_STEP", "1")
+    [flagged] = _engine(tiny_params).generate([prompt], sp)
+    assert flagged.token_ids == plain.token_ids
 
 
 @pytest.mark.parametrize("kw,names", [

@@ -153,39 +153,6 @@ def test_bench_lines_carry_cost_basis():
     assert out["per_dollar_vs_inf2"] > 0
 
 
-def test_ragged_key_promotes_tokens_per_second():
-    # PR-11 tentpole: the ragged+int8KV bench publishes under its own key
-    # and dispatches as its own variant (never banking as another bench)
-    assert promote.KEYS["ragged"] == "ragged_tps"
-    bspec = importlib.util.spec_from_file_location(
-        "bench", os.path.join(ROOT, "bench.py"))
-    bench = importlib.util.module_from_spec(bspec)
-    bspec.loader.exec_module(bench)
-    assert bench._which_from_argv(["bench.py", "ragged"]) == "ragged"
-    assert bench.UNITS_BY_BENCH["ragged"] == "tokens/sec"
-
-
-@pytest.mark.slow  # tier-1 budget: see scripts/check_tier1_budget.py
-def test_ragged_bench_acceptance_on_cpu_tiny():
-    """The PR-11 acceptance numbers, measured: decode executable-ladder
-    entries reduced, pad fraction no higher at mixed lengths, and the int8
-    pool fitting ~2x the KV blocks at the same SHAI_HBM_GIB."""
-    r = subprocess.run(
-        [sys.executable, os.path.join(ROOT, "bench.py"),
-         "ragged", "--cpu"],
-        capture_output=True, text=True, timeout=900,
-        env={**os.environ, "JAX_PLATFORMS": "cpu"})
-    out = json.loads(r.stdout.strip().splitlines()[-1])
-    assert out["platform"] == "cpu"
-    assert out["unit"] == "tokens/sec"
-    on, off = out["ragged_quant"], out["bucketed"]
-    assert on["decode_ladder_entries"] < off["decode_ladder_entries"]
-    assert on["pad_fraction"] <= off["pad_fraction"]   # one kernel body
-    assert 1.7 <= out["kv_quant_capacity_ratio"] <= 2.1
-    blocks = out["max_kv_blocks_at_hbm"]
-    assert blocks["int8"] > 1.7 * blocks["bf16"]
-
-
 def test_qos_key_promotes_flood_p99_ratio():
     # PR-12 tentpole: the multi-tenant QoS bench publishes under its own
     # key and dispatches as its own variant (never banking as another
@@ -275,48 +242,6 @@ def test_migrate_bench_acceptance_on_cpu_tiny():
     # cpu-tiny proxy and flakes under CI load — assert sanity here, the
     # >1 win claim belongs to real-geometry runs
     assert out["recompute_over_migrate_ratio"] > 0.7, out
-
-
-def test_fused_key_promotes_tpot_ratio():
-    # PR-16 tentpole: the fused mixed-phase step bench publishes under
-    # its own key and dispatches as its own variant (never banking as
-    # another bench)
-    assert promote.KEYS["fused"] == "fused_step_tpot_ratio"
-    bspec = importlib.util.spec_from_file_location(
-        "bench", os.path.join(ROOT, "bench.py"))
-    bench = importlib.util.module_from_spec(bspec)
-    bspec.loader.exec_module(bench)
-    assert bench._which_from_argv(["bench.py", "fused"]) == "fused"
-    assert bench._which_from_argv(["bench.py", "fused",
-                                   "--cpu"]) == "fused"
-    assert bench.UNITS_BY_BENCH["fused"] == "x"
-    assert promote.is_real(_entry(metric="fused step tpot ratio (tpu)",
-                                  unit="x"))
-
-
-@pytest.mark.slow  # tier-1 budget: see scripts/check_tier1_budget.py
-def test_fused_bench_acceptance_on_cpu_tiny():
-    """The PR-16 acceptance numbers, measured: under the two-wave mixed
-    load the fused engine's decode-side ladder is strictly smaller than
-    the laddered engine's (one entry per batch bucket replaces the
-    decode grid AND the ragged continuation ladder), and no request
-    errored in either mode (errors REQUIRED 0 — the fusion is a
-    dispatch-shape change, never a correctness trade). The TPOT/TTFT
-    wins are dispatch-overhead effects too noisy for CI wall clocks;
-    the ratio claims belong to real-geometry runs."""
-    r = subprocess.run(
-        [sys.executable, os.path.join(ROOT, "bench.py"),
-         "fused", "--cpu"],
-        capture_output=True, text=True, timeout=900,
-        env={**os.environ, "JAX_PLATFORMS": "cpu"})
-    out = json.loads(r.stdout.strip().splitlines()[-1])
-    assert out["platform"] == "cpu" and out["unit"] == "x"
-    on, off = out["fused"], out["laddered"]
-    assert on["decode_ladder_entries"] < off["decode_ladder_entries"]
-    assert out["ladder_entries_reduced"] is True
-    assert on["errors"] == 0 and off["errors"] == 0, out
-    assert out["value"] == out["fused_step_tpot_ratio"] > 0
-    assert on["ttft_s_p50"] > 0 and on["tpot_s_p50"] > 0
 
 
 def test_kvfabric_key_promotes_warm_ttft_ratio():

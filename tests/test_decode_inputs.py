@@ -258,6 +258,27 @@ def test_uploads_are_counted_on_the_snapshot(tiny_model, monkeypatch):
         "dispatches_by_phase"]["decode"]
 
 
+def test_marshal_builds_what_the_programs_take(tiny_model, monkeypatch):
+    """``_marshal_running`` builds, and the resident mirror uploads, the
+    arrays a decode or verify dispatch hands its program, and no other: a
+    composition change costs one put of exactly those."""
+    import inspect
+    import re
+
+    eng = make_engine(tiny_model, True, monkeypatch)
+    eng.add_request([1, 2, 3], SamplingParams(max_new_tokens=4))
+    eng.step()
+    built = set(eng._marshal_running(eng._running_slots(), 1))
+    for dispatch in (LLMEngine._dispatch_async, LLMEngine._spec_step):
+        taken = set(re.findall(r'\ba\["(\w+)"\]',
+                               inspect.getsource(dispatch)))
+        assert taken <= built, dispatch.__name__
+    # the lock-step step adds the three it makes anew every step
+    lock = set(re.findall(r'\bd\["(\w+)"\]',
+                          inspect.getsource(LLMEngine._decode_step)))
+    assert lock - {"tokens", "pos", "fold"} == built == set(eng._res.arrays)
+
+
 @pytest.mark.parametrize("async_on", [True, False], ids=["async", "lockstep"])
 def test_a_padding_row_asks_the_sampler_for_nothing(tiny_model, monkeypatch,
                                                     async_on):

@@ -777,9 +777,6 @@ def test_the_arena_has_no_int8_pool_and_no_host_tier(kw):
      "SHAI_KVTIER .*migration.* with recurrent state"),
     ({}, {"speculative_model": "[ngram]", "num_speculative_tokens": 2},
      "speculative decoding .*rolled back.* with recurrent state"),
-    ({"SHAI_RAGGED_ATTENTION": "1"}, {},
-     "SHAI_RAGGED_ATTENTION .* with recurrent state"),
-    ({"SHAI_FUSED_STEP": "1"}, {}, "SHAI_FUSED_STEP .* with recurrent state"),
     ({"SHAI_KV_COW": "1"}, {}, "SHAI_KV_COW .* with recurrent state"),
     ({}, {"tensor_parallel_size": 2},
      "tensor_parallel_size > 1 .* with recurrent state"),
@@ -787,14 +784,27 @@ def test_the_arena_has_no_int8_pool_and_no_host_tier(kw):
      "quantization: int8 .* with recurrent state"),
     ({"SHAI_KV_QUANT": "int8"}, {},
      "SHAI_KV_QUANT=int8 .* with recurrent state"),
-], ids=["prefix-caching", "kvtier", "speculation", "ragged", "fused-step",
-        "copy-on-write", "tp", "int8-weights", "int8-kv"])
+], ids=["prefix-caching", "kvtier", "speculation", "copy-on-write", "tp",
+        "int8-weights", "int8-kv"])
 def test_unsupported_combinations_are_refused_by_name(
         tiny_params, env, over, names, monkeypatch):
     for k_, v_ in env.items():
         monkeypatch.setenv(k_, v_)
     with pytest.raises(ValueError, match=names):
         _engine(tiny_params, **over)
+
+
+def test_the_deleted_switches_are_not_read(tiny_params, monkeypatch):
+    """``SHAI_RAGGED_ATTENTION`` and ``SHAI_FUSED_STEP`` chose programs that
+    are gone. A deployment that still sets them boots (they were refused
+    here by name) and serves what one without them serves."""
+    sp = SamplingParams(temperature=0.0, max_new_tokens=6)
+    prompt = _prompt(40)             # a prefill and one continuation chunk
+    [plain] = _engine(tiny_params).generate([prompt], sp)
+    monkeypatch.setenv("SHAI_RAGGED_ATTENTION", "1")
+    monkeypatch.setenv("SHAI_FUSED_STEP", "1")
+    [flagged] = _engine(tiny_params).generate([prompt], sp)
+    assert flagged.token_ids == plain.token_ids
 
 
 def test_a_soft_prefix_is_refused_and_a_snapshot_is_a_cold_manifest(

@@ -1,17 +1,9 @@
-"""Ragged paged attention + int8 KV-block quantization (PR 11).
+"""int8 KV-block quantization, and the pool kernel that reads it.
 
-Two oracles pin the tentpole:
-
-- ``SHAI_RAGGED_ATTENTION=1`` with quant OFF must be TOKEN-EXACT against
-  the bucketed engine (the executable ladder it replaces) — the masked
-  online-softmax over a longer window adds only exact-zero contributions,
-  so tokens, logprobs, stop reasons, and pool balance are identical across
-  greedy/topk/topp, both async disciplines, preemption, chunked prefill,
-  and the speculative fallback.
-- ``SHAI_KV_QUANT=int8`` trades exactness for ~2x KV capacity: the
-  contract is a greedy-token match RATE against the bf16 pool plus exact
-  pool/ledger accounting (device and host tier) — and byte-exact tier
-  round-trips (blocks and scales are copied, never re-quantized).
+``SHAI_KV_QUANT=int8`` trades exactness for ~2x KV capacity: the contract
+is a greedy-token match RATE against the bf16 pool plus exact pool/ledger
+accounting (device and host tier) — and byte-exact tier round-trips
+(blocks and scales are copied, never re-quantized).
 """
 
 import numpy as np
@@ -31,7 +23,6 @@ from scalable_hw_agnostic_inference_tpu.models.llama import (
 )
 from scalable_hw_agnostic_inference_tpu.ops.attention import (
     ragged_gather_attention,
-    ragged_paged_attention,
 )
 from scalable_hw_agnostic_inference_tpu.ops.pallas.paged_attention import (
     paged_decode_attention,
@@ -178,7 +169,7 @@ def _pool_fixture(quant):
 
 
 @pytest.mark.parametrize("quant", [False, True], ids=["bf16", "int8"])
-def test_ragged_kernel_matches_gather_reference(quant):
+def test_pool_kernel_matches_gather_reference(quant):
     q, kp, vp, ks, vs, tables, lengths = _pool_fixture(quant)
     ref = ragged_gather_attention(q[:, None], kp, vp, tables,
                                   (lengths - 1)[:, None], ks, vs)[:, 0]
@@ -186,14 +177,6 @@ def test_ragged_kernel_matches_gather_reference(quant):
                                  interpret=True)
     np.testing.assert_allclose(np.asarray(out), np.asarray(ref),
                                rtol=2e-5, atol=2e-5)
-
-
-def test_ragged_dispatcher_uses_reference_on_cpu():
-    q, kp, vp, ks, vs, tables, lengths = _pool_fixture(False)
-    out = ragged_paged_attention(q, kp, vp, tables, lengths)
-    ref = ragged_gather_attention(q[:, None], kp, vp, tables,
-                                  (lengths - 1)[:, None])[:, 0]
-    np.testing.assert_allclose(np.asarray(out), np.asarray(ref))
 
 
 def test_bucketed_paged_kernel_accepts_int8_pool():
@@ -210,7 +193,7 @@ def test_bucketed_paged_kernel_accepts_int8_pool():
 
 
 # ---------------------------------------------------------------------------
-# engine: ragged-on / quant-off is token-exact vs the bucketed oracle
+# engine: the tiny model, float pool or int8
 # ---------------------------------------------------------------------------
 
 @pytest.fixture(scope="module")
@@ -221,17 +204,15 @@ def tiny_model():
     return cfg, params
 
 
-def make_engine(tiny_model, monkeypatch, *, ragged=False, quant=False,
-                async_on=True, **over):
+def make_engine(tiny_model, monkeypatch, *, quant=False, async_on=True,
+                **over):
     cfg, params = tiny_model
     monkeypatch.setenv("SHAI_ASYNC_DECODE", "1" if async_on else "0")
-    monkeypatch.setenv("SHAI_RAGGED_ATTENTION", "1" if ragged else "0")
     monkeypatch.setenv("SHAI_KV_QUANT", "int8" if quant else "")
     kw = dict(max_model_len=128, max_num_seqs=3, block_size=8,
               context_encoding_buckets=(16, 32), max_new_tokens=16)
     kw.update(over)
     eng = LLMEngine(cfg, params, EngineConfig(**kw))
-    assert eng._ragged is ragged
     assert eng._kv_quant is quant
     return eng
 
@@ -240,131 +221,19 @@ def pool_balanced(eng) -> bool:
     return eng.cache.allocator.n_free == eng.ecfg.total_blocks - 1
 
 
-def assert_finished_equal(a, b):
-    assert a.req_id == b.req_id
-    assert a.token_ids == b.token_ids, (a.req_id, a.token_ids, b.token_ids)
-    assert a.stop_reason == b.stop_reason
-    if a.logprobs is None or b.logprobs is None:
-        assert a.logprobs == b.logprobs
-        return
-    assert len(a.logprobs) == len(b.logprobs)
-    for e1, e2 in zip(a.logprobs, b.logprobs):
-        assert e1["token"] == e2["token"]
-        assert e1["logprob"] == pytest.approx(e2["logprob"], abs=1e-5)
-
-
 MIXED = [[1, 5, 9], [2] * 20, [7, 3] * 14, [4]]  # mixed lengths, on purpose
 
 
-@pytest.mark.slow  # tier-1 budget: see scripts/check_tier1_budget.py
-@pytest.mark.parametrize("sp", [
-    SamplingParams(temperature=0.0, max_new_tokens=8, logprobs=2),
-    pytest.param(SamplingParams(temperature=0.9, top_k=5, max_new_tokens=8),
-                 marks=pytest.mark.slow),
-    pytest.param(SamplingParams(temperature=0.7, top_p=0.8,
-                                max_new_tokens=8),
-                 marks=pytest.mark.slow),
-], ids=["greedy", "topk", "topp"])
-@pytest.mark.parametrize("async_on", [True, False], ids=["async", "sync"])
-def test_ragged_matches_bucketed_oracle(tiny_model, monkeypatch, sp,
-                                        async_on):
-    a = make_engine(tiny_model, monkeypatch, ragged=True, async_on=async_on)
-    b = make_engine(tiny_model, monkeypatch, ragged=False,
-                    async_on=async_on)
-    fa = a.generate(MIXED, sp)
-    fb = b.generate(MIXED, sp)
-    for x, y in zip(fa, fb):
-        assert_finished_equal(x, y)
-    assert pool_balanced(a) and pool_balanced(b)
-
-
-@pytest.mark.slow
-def test_ragged_preemption_parity(tiny_model, monkeypatch):
-    # a pool too small for the batch forces recompute-preemption mid-run
-    sp = SamplingParams(temperature=0.0, max_new_tokens=12)
-    outs = {}
-    for ragged in (True, False):
-        eng = make_engine(tiny_model, monkeypatch, ragged=ragged,
-                          num_blocks=6)
-        fins = eng.generate([[1, 2, 3, 4, 5, 6], [9, 8, 7, 6, 5]], sp)
-        outs[ragged] = [(f.token_ids, f.stop_reason) for f in fins]
-        assert eng.obs.preemptions >= 1
-        assert pool_balanced(eng)
-    assert outs[True] == outs[False]
-
-
-def test_ragged_chunked_prefill_parity(tiny_model, monkeypatch):
-    # prompt > largest bucket: the ragged engine runs the dynamic-start
-    # continuation executable, the bucketed engine the per-start ladder
-    rng = np.random.default_rng(5)
-    long_prompt = rng.integers(3, 200, 70).tolist()
-    sp = SamplingParams(temperature=0.0, max_new_tokens=8)
-    outs = {}
-    for ragged in (True, False):
-        eng = make_engine(tiny_model, monkeypatch, ragged=ragged)
-        [fin] = eng.generate([long_prompt], sp)
-        outs[ragged] = fin.token_ids
-        assert pool_balanced(eng)
-    assert outs[True] == outs[False]
-    # the ragged engine really took the dynamic-start path
-    eng = make_engine(tiny_model, monkeypatch, ragged=True)
-    eng.generate([long_prompt], sp)
-    assert any(k[0] == "rcont" for k in eng._prefill)
-    assert not any(k[0] == "cont" for k in eng._prefill)
-
-
-@pytest.mark.slow
-def test_ragged_speculative_fallback_parity(tiny_model, monkeypatch):
+def test_pad_accounting_fraction(tiny_model, monkeypatch):
     sp = SamplingParams(temperature=0.0, max_new_tokens=10)
-    prompts = [[5, 6, 5, 6, 5, 6, 5], [1, 2, 3]]
-    outs = {}
-    for ragged in (True, False):
-        eng = make_engine(tiny_model, monkeypatch, ragged=ragged,
-                          speculative_model="[ngram]",
-                          num_speculative_tokens=3)
-        fins = eng.generate(prompts, sp)
-        outs[ragged] = [f.token_ids for f in fins]
-        assert eng.spec.verify_steps + eng.spec.fallback_steps > 0
-        assert pool_balanced(eng)
-    assert outs[True] == outs[False]
-
-
-@pytest.mark.slow  # tier-1 budget: see scripts/check_tier1_budget.py
-def test_ragged_ladder_shrinks_and_stays_closed(tiny_model, monkeypatch):
-    # the measurable claim: fewer continuation executables at warm (decode
-    # is one program a batch bucket either way), and the warmed set stays
-    # closed over a mixed-length run (no post-ready compiles — the
-    # cold-graph-behind-the-LB discipline)
-    kw = dict(max_model_len=128, enable_prefix_caching=True)
-    a = make_engine(tiny_model, monkeypatch, ragged=True, **kw)
-    b = make_engine(tiny_model, monkeypatch, ragged=False, **kw)
-    a.warm_executables()
-    b.warm_executables()
-    assert sorted(a._decode_fns) == sorted(b._decode_fns) == [1, 2, 3]
-    assert a.n_executables < b.n_executables
-    sp = SamplingParams(temperature=0.0, max_new_tokens=6)
-    rng = np.random.default_rng(9)
-    a.generate([rng.integers(3, 200, n).tolist()
-                for n in (4, 20, 40, 70)], sp)
-    assert a.obs.recompiles == 0
-    # prefix caching holds registered blocks by design — no LIVE leak
-    assert a.cache.leaked_blocks == 0
-
-
-def test_pad_accounting_ragged_equals_bucketed(tiny_model, monkeypatch):
-    sp = SamplingParams(temperature=0.0, max_new_tokens=10)
-    fracs = {}
-    for ragged in (True, False):
-        eng = make_engine(tiny_model, monkeypatch, ragged=ragged)
-        eng.generate(MIXED, sp)
-        snap = eng.obs.snapshot()
-        assert snap["real_tokens"] > 0
-        assert snap["pad_tokens"] >= 0
-        assert 0.0 <= snap["pad_fraction"] < 1.0
-        fracs[ragged] = snap["pad_fraction"]
-    # the flag chooses the continuation alone: decode dispatches are the
-    # same programs on the same rows, so they pad the same
-    assert fracs[True] == fracs[False]
+    eng = make_engine(tiny_model, monkeypatch)
+    eng.generate(MIXED, sp)
+    snap = eng.obs.snapshot()
+    assert snap["real_tokens"] > 0
+    assert snap["pad_tokens"] > 0       # MIXED fills no bucket exactly
+    assert snap["pad_fraction"] == pytest.approx(
+        snap["pad_tokens"] / (snap["pad_tokens"] + snap["real_tokens"]),
+        abs=1e-4)
 
 
 # ---------------------------------------------------------------------------
@@ -380,14 +249,10 @@ def _greedy_match_rate(fa, fb) -> float:
     return agree / max(1, total)
 
 
-@pytest.mark.parametrize("ragged", [
-    pytest.param(False, marks=pytest.mark.slow),  # tier-1 budget
-    True,
-], ids=["bucketed", "ragged"])
-def test_kv_quant_greedy_match_rate(tiny_model, monkeypatch, ragged):
+def test_kv_quant_greedy_match_rate(tiny_model, monkeypatch):
     sp = SamplingParams(temperature=0.0, max_new_tokens=12)
-    q = make_engine(tiny_model, monkeypatch, ragged=ragged, quant=True)
-    f = make_engine(tiny_model, monkeypatch, ragged=ragged, quant=False)
+    q = make_engine(tiny_model, monkeypatch, quant=True)
+    f = make_engine(tiny_model, monkeypatch, quant=False)
     rate = _greedy_match_rate(q.generate(MIXED, sp), f.generate(MIXED, sp))
     # int8 KV is lossy by design; the serving contract is a HIGH greedy
     # match rate, not exactness (threshold mirrors the PARITY.md style)
@@ -417,14 +282,13 @@ def test_kv_quant_pool_bytes_and_ledger_attribution(tiny_model,
     assert lay["ks"].shape == (q.cache.total_blocks, q.cfg.n_kv_heads)
 
 
-@pytest.mark.slow
 def test_kv_quant_cancel_evict_fuzz_pool_exact(tiny_model, monkeypatch):
-    # seeded schedule fuzz with quant + ragged + prefix caching + host
+    # seeded schedule fuzz with quant + prefix caching + host
     # tier: every request terminal exactly once, device pool balanced,
     # host tier accounting exact — the PR's accounting acceptance gate
     monkeypatch.setenv("SHAI_KVTIER", "1")
     monkeypatch.setenv("SHAI_KVTIER_ASYNC", "0")
-    eng = make_engine(tiny_model, monkeypatch, ragged=True, quant=True,
+    eng = make_engine(tiny_model, monkeypatch, quant=True,
                       enable_prefix_caching=True, num_blocks=20,
                       max_model_len=128)
     assert eng.cache.tier is not None
@@ -491,7 +355,6 @@ def test_tier_roundtrip_quant_bytes_exact():
         np.testing.assert_array_equal(ent[4], vs[:, j])
 
 
-@pytest.mark.slow
 def test_engine_tier_restore_quant_replay_greedy_equal(tiny_model,
                                                        monkeypatch):
     # demote a prompt's quantized blocks to the host tier under eviction

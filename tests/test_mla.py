@@ -344,17 +344,27 @@ def test_the_cache_refuses_what_reads_k_and_v(kw, names):
     ({"SHAI_KVTIER": "1"}, {}, "SHAI_KVTIER .*kvnet frames.* with a latent"),
     ({}, {"speculative_model": "[ngram]", "num_speculative_tokens": 2},
      "speculative decoding .* with a latent cache"),
-    ({"SHAI_RAGGED_ATTENTION": "1"}, {},
-     "SHAI_RAGGED_ATTENTION .* with a latent cache"),
-    ({"SHAI_FUSED_STEP": "1"}, {}, "SHAI_FUSED_STEP .* with a latent cache"),
 ], ids=["tp", "int8-weights", "int8-kv", "prefix-caching", "kvtier",
-        "speculation", "ragged", "fused-step"])
+        "speculation"])
 def test_unsupported_combinations_are_refused_by_name(
         tiny_params, env, over, names, monkeypatch):
     for k, v in env.items():
         monkeypatch.setenv(k, v)
     with pytest.raises(ValueError, match=names):
         _engine(tiny_params, **over)
+
+
+def test_the_deleted_switches_are_not_read(tiny_params, monkeypatch):
+    """``SHAI_RAGGED_ATTENTION`` and ``SHAI_FUSED_STEP`` chose programs that
+    are gone. A deployment that still sets them boots (they were refused
+    here by name) and serves what one without them serves."""
+    sp = SamplingParams(temperature=0.0, max_new_tokens=6)
+    prompt = _prompt(40)             # a prefill and one continuation chunk
+    [plain] = _engine(tiny_params).generate([prompt], sp)
+    monkeypatch.setenv("SHAI_RAGGED_ATTENTION", "1")
+    monkeypatch.setenv("SHAI_FUSED_STEP", "1")
+    [flagged] = _engine(tiny_params).generate([prompt], sp)
+    assert flagged.token_ids == plain.token_ids
 
 
 @pytest.mark.parametrize("kw,names", [

@@ -652,8 +652,7 @@ def test_a_stopped_engine_gives_its_programs_back(tiny_params):
     [before] = eng.generate([prompt], GREEDY)
     assert eng._prefill and eng._decode_fns
     eng.release_executables()
-    assert not (eng._prefill or eng._decode_fns or eng._verify_fns
-                or eng._fused_fns)
+    assert not (eng._prefill or eng._decode_fns or eng._verify_fns)
     [after] = eng.generate([prompt], GREEDY)
     assert after.token_ids == before.token_ids
 
@@ -667,9 +666,6 @@ def test_a_stopped_engine_gives_its_programs_back(tiny_params):
      "SHAI_KVTIER .*migration.* with recurrent state"),
     ({}, {"speculative_model": "[ngram]", "num_speculative_tokens": 2},
      "speculative decoding .*rolled back.* with recurrent state"),
-    ({"SHAI_RAGGED_ATTENTION": "1"}, {},
-     "SHAI_RAGGED_ATTENTION .* with recurrent state"),
-    ({"SHAI_FUSED_STEP": "1"}, {}, "SHAI_FUSED_STEP .* with recurrent state"),
     ({"SHAI_KV_COW": "1"}, {}, "SHAI_KV_COW .* with recurrent state"),
     ({}, {"tensor_parallel_size": 2},
      "tensor_parallel_size > 1 .* with recurrent state"),
@@ -677,14 +673,27 @@ def test_a_stopped_engine_gives_its_programs_back(tiny_params):
      "quantization: int8 .* with recurrent state"),
     ({"SHAI_KV_QUANT": "int8"}, {},
      "SHAI_KV_QUANT=int8 .* with recurrent state"),
-], ids=["prefix-caching", "kvtier", "speculation", "ragged", "fused-step",
-        "copy-on-write", "tp", "int8-weights", "int8-kv"])
+], ids=["prefix-caching", "kvtier", "speculation", "copy-on-write", "tp",
+        "int8-weights", "int8-kv"])
 def test_unsupported_combinations_are_refused_by_name(
         tiny_params, env, over, names, monkeypatch):
     for k_, v_ in env.items():
         monkeypatch.setenv(k_, v_)
     with pytest.raises(ValueError, match=names):
         _engine(tiny_params, **over)
+
+
+def test_the_deleted_switches_are_not_read(tiny_params, monkeypatch):
+    """``SHAI_RAGGED_ATTENTION`` and ``SHAI_FUSED_STEP`` chose programs that
+    are gone. A deployment that still sets them boots (they were refused
+    here by name) and serves what one without them serves."""
+    sp = SamplingParams(temperature=0.0, max_new_tokens=6)
+    prompt = _prompt(40)             # a prefill and one continuation chunk
+    [plain] = _engine(tiny_params).generate([prompt], sp)
+    monkeypatch.setenv("SHAI_RAGGED_ATTENTION", "1")
+    monkeypatch.setenv("SHAI_FUSED_STEP", "1")
+    [flagged] = _engine(tiny_params).generate([prompt], sp)
+    assert flagged.token_ids == plain.token_ids
 
 
 def test_a_soft_prefix_is_refused(tiny_params):
