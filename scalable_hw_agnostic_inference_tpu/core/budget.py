@@ -288,8 +288,10 @@ def causal_lm_budget(cfg, ecfg, *, hbm_gib_per_chip: float = HBM_GIB["v5e"],
 
     kv_quant = env_str("SHAI_KV_QUANT", "").strip().lower() == "int8"
     kv_dtype = 1.0 if kv_quant else 2.0
+    # a head's lanes in the pool: ``head_lanes`` where it is declared
     kv_bytes = (num_blocks * ecfg.block_size * n_self * 2
-                * kv_heads_chip * cfg.head_dim * kv_dtype)
+                * kv_heads_chip * getattr(cfg, "kv_lanes", cfg.head_dim)
+                * kv_dtype)
     if cfg.latent:
         # one latent row a token a layer, whatever the heads (the boot
         # refuses an 8-bit pool and a tp split with it)

@@ -228,7 +228,7 @@ class LLMEngine:
             tier = maybe_host_tier(
                 n_layers=n_pool_layers, block_size=ecfg.block_size,
                 n_kv_heads=model_cfg.n_kv_heads,
-                head_dim=model_cfg.head_dim,
+                head_dim=model_cfg.kv_lanes,
                 dtype=np.int8 if self._kv_quant else np.dtype(kv_dtype),
                 quant=self._kv_quant)
         # disaggregated serving role (kvnet): env wins over ecfg.role. A
@@ -263,7 +263,7 @@ class LLMEngine:
                 ecfg.block_size,
                 model_cfg.n_kv_heads // (mesh.shape["tp"] if self.shardings
                                          is not None else 1),
-                model_cfg.head_dim, np.int8 if self._kv_quant else kv_dtype)
+                model_cfg.kv_lanes, np.int8 if self._kv_quant else kv_dtype)
         self.cache = PagedKVCache(
             n_pool_layers, cache_leaves(model_cfg),
             ecfg.total_blocks, ecfg.block_size,

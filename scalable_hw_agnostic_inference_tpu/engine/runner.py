@@ -25,7 +25,7 @@ import jax.numpy as jnp
 from jax.sharding import NamedSharding, PartitionSpec as P
 
 from ..models.llama import LlamaConfig
-from ..ops import kda, mla, ssm
+from ..ops import kda, mla, shortconv, ssm
 from ..ops.attention import dot_product_attention, on_tpu_platform
 from ..ops.moe import expert_layer, gated_mlp
 from ..ops.quant import quant_matmul
@@ -106,7 +106,7 @@ def _mlp(lp: Dict, x: jax.Array, act: str = "silu") -> jax.Array:
 
 #: a recurrent KIND's two phases (``prefill``, ``decode``), by
 #: ``LlamaConfig.state_kind``
-_RECURRENT = {"kda": kda, "ssm": ssm}
+_RECURRENT = {"kda": kda, "ssm": ssm, "conv": shortconv}
 
 
 def _head_rmsnorm(x: jax.Array, scale: jax.Array, eps: float) -> jax.Array:
@@ -154,8 +154,9 @@ class LayerKind:
     name): gated cross-attention over vision states or self-attention;
     the keys a query sees behind it (0 = all); rotary embedding or none;
     a routed FFN or the dense MLP; ``state``: a recurrent mixer (linear
-    attention, ``ops.kda``, or a state-space one, ``ops.ssm``), whose state
-    is a slot's and not the pool's; ``part``: ``"mixer"`` or ``"ffn"``
+    attention, ``ops.kda``, a state-space one, ``ops.ssm``, or a gated
+    short convolution, ``ops.shortconv``), whose state is a slot's and not
+    the pool's; ``part``: ``"mixer"`` or ``"ffn"``
     where the block is that part ALONE behind one norm, ``""`` where it is
     a mixer then a feed-forward part."""
     cross: bool = False
@@ -191,8 +192,8 @@ def _layer(lp: Dict, kind: LayerKind, x, positions, attend,
     recurrent layer (``kind.state``) ``q`` is the NORMED stream ``[B, T,
     dim]`` and ``k`` the layer's mixer leaves: the program's closure runs
     its phase of the model's recurrent kind over its slots (``prefill`` or
-    ``decode`` of ``ops.kda`` / ``ops.ssm``) and hands back the gated
-    output ``[B, T, H * d]``. A block of ONE part (``kind.part``) has one
+    ``decode`` of ``_RECURRENT``'s module) and hands back the gated output
+    ``[B, T, H * d]``. A block of ONE part (``kind.part``) has one
     norm (``lp["norm"]``) and one residual add: the mixer alone, or the
     feed-forward part alone (``attend`` is then not called). ``cross``:
     ``(k, v, has_image, cross_len)`` of a cross layer, which attends those
@@ -234,7 +235,14 @@ def _layer(lp: Dict, kind: LayerKind, x, positions, attend,
                                cfg.rope_scaling)
             if cfg.attn_gate:
                 gate = _proj(h, at["gate"])
-            o = attend(q, k, v, kind.window)
+            if cfg.head_lanes:
+                # narrow heads ride on ``kv_lanes``: zero lanes add nothing
+                # to a score and return zeros, which are cut again
+                q, k, v = (jnp.pad(a, ((0, 0),) * 3 + (
+                    (0, cfg.kv_lanes - Dh),)) for a in (q, k, v))
+                o = attend(q, k, v, kind.window)[..., :Dh]
+            else:
+                o = attend(q, k, v, kind.window)
     o = o.reshape(B, T, -1)
     if gate is not None:
         o = o * jax.nn.sigmoid(gate)
@@ -352,7 +360,7 @@ def make_cross_slot_write(cfg: LlamaConfig):
 
 
 def _tp_attention(shardings: Optional["EngineShardings"], q, k, v, *,
-                  kv_lengths=None, causal=False, window=0):
+                  kv_lengths=None, causal=False, window=0, scale=None):
     """Self/cross attention, head-split over ``tp`` via shard_map under TP.
 
     The flash kernel behind ``dot_product_attention`` (``ops.pallas``) is a
@@ -369,18 +377,20 @@ def _tp_attention(shardings: Optional["EngineShardings"], q, k, v, *,
     """
     if shardings is None:
         return dot_product_attention(q, k, v, kv_lengths=kv_lengths,
-                                     causal=causal, window=window)
+                                     causal=causal, window=window,
+                                     scale=scale)
     heads = P(None, None, "tp", None)
     if kv_lengths is None:
         return jax.shard_map(
             lambda q_, k_, v_: dot_product_attention(
-                q_, k_, v_, causal=causal, window=window),
+                q_, k_, v_, causal=causal, window=window, scale=scale),
             mesh=shardings.mesh, in_specs=(heads,) * 3, out_specs=heads,
             check_vma=False,
         )(q, k, v)
     return jax.shard_map(
         lambda q_, k_, v_, n_: dot_product_attention(
-            q_, k_, v_, kv_lengths=n_, causal=causal, window=window),
+            q_, k_, v_, kv_lengths=n_, causal=causal, window=window,
+            scale=scale),
         mesh=shardings.mesh,
         in_specs=(heads, heads, heads, P(None)),
         out_specs=heads,
@@ -555,15 +565,15 @@ def make_prefill(cfg: LlamaConfig, block_size: int, blocks_per_seq: int,
             # shard_map under TP (the raw Mosaic kernel cannot be
             # auto-partitioned)
             o = _tp_attention(shardings, q, k, v, kv_lengths=n, causal=True,
-                              window=window)
+                              window=window, scale=cfg.attn_scale)
             # scatter each row's k/v blocks into the pool ([B, m_used]
             # index); int8 pools quantize per block x head on the way in
             kv[pi] = _scatter_blocks(
                 kv[pi], tbl,
                 k.reshape(B, m_used, block_size, cfg.n_kv_heads,
-                          cfg.head_dim),
+                          cfg.kv_lanes),
                 v.reshape(B, m_used, block_size, cfg.n_kv_heads,
-                          cfg.head_dim), kv_quant, shardings)
+                          cfg.kv_lanes), kv_quant, shardings)
             return o
 
         # gated cross-attention over vision states: no rope, no KV pool
@@ -619,7 +629,7 @@ def make_prefill(cfg: LlamaConfig, block_size: int, blocks_per_seq: int,
 
 def _pool_kernel_call(shardings: Optional["EngineShardings"],
                       qf, kpool, vpool, tf, lf, ks=None, vs=None,
-                      window: int = 0):
+                      window: int = 0, scale: Optional[float] = None):
     """THE dispatch seam for the paged pool kernel on flattened rows:
     direct call on one device, head-split shard_map under TP (the raw
     Mosaic kernel cannot be auto-partitioned; attention is head-local so
@@ -627,11 +637,13 @@ def _pool_kernel_call(shardings: Optional["EngineShardings"],
     present, split on the same kv-head axis as the blocks they scale.
     Decode and verify call it (``_make_token_forward``). ``window``: a window
     layer's bound, handed to the kernel as a static argument (0 hands it
-    nothing)."""
+    nothing), and so is ``scale`` (``LlamaConfig.attn_scale``)."""
     from ..ops.pallas.paged_attention import paged_decode_attention as kernel
 
     if window:
         kernel = functools.partial(kernel, window=window)
+    if scale is not None:
+        kernel = functools.partial(kernel, scale=scale)
     if shardings is None:
         return kernel(qf, kpool, vpool, tf, lf, ks, vs)
     heads_q = P(None, "tp", None)
@@ -736,25 +748,26 @@ def make_prefill_cont(cfg: LlamaConfig, block_size: int, blocks_per_seq: int,
 
                 kprior = dequantize_kv_blocks(
                     kv[pi]["k"][tbl_prior], kv[pi]["ks"][tbl_prior],
-                    q.dtype).reshape(B, start, cfg.n_kv_heads, cfg.head_dim)
+                    q.dtype).reshape(B, start, cfg.n_kv_heads, cfg.kv_lanes)
                 vprior = dequantize_kv_blocks(
                     kv[pi]["v"][tbl_prior], kv[pi]["vs"][tbl_prior],
-                    q.dtype).reshape(B, start, cfg.n_kv_heads, cfg.head_dim)
+                    q.dtype).reshape(B, start, cfg.n_kv_heads, cfg.kv_lanes)
             else:
-                kflat = kv[pi]["k"].reshape(-1, cfg.n_kv_heads, cfg.head_dim)
-                vflat = kv[pi]["v"].reshape(-1, cfg.n_kv_heads, cfg.head_dim)
+                kflat = kv[pi]["k"].reshape(-1, cfg.n_kv_heads, cfg.kv_lanes)
+                vflat = kv[pi]["v"].reshape(-1, cfg.n_kv_heads, cfg.kv_lanes)
                 kprior = kflat[goff].astype(q.dtype)
                 vprior = vflat[goff].astype(q.dtype)
             kcat = jnp.concatenate([kprior, k], axis=1)  # [B, start+T, ...]
             vcat = jnp.concatenate([vprior, v], axis=1)
             o = _tp_attention(shardings, q, kcat, vcat, kv_lengths=n,
-                              causal=True, window=window)
+                              causal=True, window=window,
+                              scale=cfg.attn_scale)
             kv[pi] = _scatter_blocks(
                 kv[pi], tbl_chunk,
                 k.reshape(B, c_blocks, block_size, cfg.n_kv_heads,
-                          cfg.head_dim),
+                          cfg.kv_lanes),
                 v.reshape(B, c_blocks, block_size, cfg.n_kv_heads,
-                          cfg.head_dim), kv_quant, shardings)
+                          cfg.kv_lanes), kv_quant, shardings)
             return o
 
         x, _ = _run_layers(
@@ -873,8 +886,8 @@ def _make_token_forward(cfg: LlamaConfig, block_size: int,
             # padded or finished row steps the NULL slot (the arena's last),
             # so no sequence's state or tail is touched for it
             assert T == 1, "one token a step over recurrent state"
-            slots = jnp.where(active > 0, slot_idx,
-                              kv[pi]["s"].shape[0] - 1)
+            n_slots = next(iter(kv[pi].values())).shape[0]
+            slots = jnp.where(active > 0, slot_idx, n_slots - 1)
             o, kv[pi] = _RECURRENT[cfg.state_kind].decode(
                 at, h, kv[pi], slots, cfg, kernel=paged)
             return o
@@ -908,8 +921,8 @@ def _make_token_forward(cfg: LlamaConfig, block_size: int,
                 # not re-laid around the write), with the heads kept an
                 # axis, so that TP splits it without a shard_map
                 pool_shape = kv[pi]["k"].shape
-                kflat = kv[pi]["k"].reshape(-1, cfg.n_kv_heads, cfg.head_dim)
-                vflat = kv[pi]["v"].reshape(-1, cfg.n_kv_heads, cfg.head_dim)
+                kflat = kv[pi]["k"].reshape(-1, cfg.n_kv_heads, cfg.kv_lanes)
+                vflat = kv[pi]["v"].reshape(-1, cfg.n_kv_heads, cfg.kv_lanes)
                 kflat = kflat.at[widx].set(kk.astype(kflat.dtype))
                 vflat = vflat.at[widx].set(vv.astype(vflat.dtype))
                 kv[pi] = {"k": kflat.reshape(pool_shape),
@@ -917,12 +930,12 @@ def _make_token_forward(cfg: LlamaConfig, block_size: int,
             ksc, vsc = _pool_scales(kv[pi])
             if paged:
                 o = _pool_kernel_call(
-                    shardings, q.reshape(B * T, cfg.n_heads, cfg.head_dim),
+                    shardings, q.reshape(B * T, cfg.n_heads, cfg.kv_lanes),
                     kv[pi]["k"], kv[pi]["v"],
                     jnp.repeat(tables, T, axis=0) if T > 1 else tables,
                     jnp.clip(positions + 1, 1, L).reshape(B * T),
-                    ksc, vsc, window=window)
-                return o.reshape(B, T, cfg.n_heads, cfg.head_dim)
+                    ksc, vsc, window=window, scale=cfg.attn_scale)
+                return o.reshape(B, T, cfg.n_heads, cfg.kv_lanes)
             if kv_quant:
                 # deviceless int8 path: the gather reference dequantizes
                 # right after the block gather (ops.attention)
@@ -930,13 +943,13 @@ def _make_token_forward(cfg: LlamaConfig, block_size: int,
 
                 return ragged_gather_attention(
                     q, kv[pi]["k"], kv[pi]["v"], tables, positions, ksc,
-                    vsc, window=window)
-            kflat = kv[pi]["k"].reshape(-1, cfg.n_kv_heads, cfg.head_dim)
-            vflat = kv[pi]["v"].reshape(-1, cfg.n_kv_heads, cfg.head_dim)
+                    vsc, window=window, scale=cfg.attn_scale)
+            kflat = kv[pi]["k"].reshape(-1, cfg.n_kv_heads, cfg.kv_lanes)
+            vflat = kv[pi]["v"].reshape(-1, cfg.n_kv_heads, cfg.kv_lanes)
             # a window layer's query also drops what lies a window behind
             m = mask & (behind < window)[:, None] if window else mask
             return dot_product_attention(q, kflat[goff], vflat[goff],
-                                         mask=m)
+                                         mask=m, scale=cfg.attn_scale)
 
         # slot_idx maps the COMPACTED batch row back to its slot's rows in
         # the full cross-kv buffers (gather fuses into the attention read)

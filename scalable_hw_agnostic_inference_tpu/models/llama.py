@@ -23,6 +23,7 @@ from __future__ import annotations
 
 import dataclasses
 import functools
+import importlib
 import itertools
 from typing import Any, Dict, List, Optional, Tuple
 
@@ -52,6 +53,13 @@ _HYBRID_LETTERS = {"M": ("mixer", "state_space"),
                    "*": ("mixer", "full_attention"),
                    "E": ("ffn", "none"), "-": ("ffn", "none")}
 
+#: the layer types whose per-sequence state is a SLOT's, and the recurrent
+#: KIND each is (``LlamaConfig.state_kind``); the kind's ``state_shapes``
+#: and two phases live in the module ``_STATE_MODULES`` names under ``ops``
+_STATE_KINDS = {"linear_attention": "kda", "state_space": "ssm",
+                "conv": "conv"}
+_STATE_MODULES = {"kda": "kda", "ssm": "ssm", "conv": "shortconv"}
+
 #: Nemotron-3-Nano's 52 blocks, and the first nine of them
 _NEMOTRON3_PATTERN = "MEMEM*EMEMEM*EMEMEM*EMEMEM*EMEMEM*EMEMEMEM*EMEMEMEME"
 
@@ -79,12 +87,24 @@ class LlamaConfig:
     # width of one attention head; None = ``dim // n_heads`` (filled in by
     # ``__post_init__``), a number where the published config says otherwise
     head_dim: Optional[int] = None
+    # lanes a head's keys and values take in the paged pool and in every
+    # attention call; 0 = ``head_dim``. A head narrower than the TPU's 128
+    # lanes says 128 here: q, k and v are zero-padded to it behind the head
+    # norms and the rotary embedding, the softmax scale stays ``head_dim **
+    # -0.5`` (``attn_scale``) and the output's pad lanes are cut. The v5e
+    # stores a pool leaf whose minor dimension is 64 TRANSPOSED (blocks on
+    # the lanes) and re-lays it whole before a kernel call, and Mosaic
+    # refuses the paged kernel's 64-lane slice of a 128-lane tile: a
+    # declared pad (as ``latent_width``'s) keeps the leaf in its own order
+    head_lanes: int = 0
     # -- what a layer IS, as data (``layer_kind``): the engine's one layer
     # function reads these and no model's name --------------------------
     # per-layer attention kind, HF's names: "sliding_attention" (a window
     # of ``sliding_window`` keys), "full_attention", "linear_attention"
-    # (KDA, ``ops.kda``: recurrent slot state, no cache rows) or
-    # "state_space" (a Mamba-2 mixer, ``ops.ssm``: the same kind of state);
+    # (KDA, ``ops.kda``: recurrent slot state, no cache rows),
+    # "state_space" (a Mamba-2 mixer, ``ops.ssm``: the same kind of state)
+    # or "conv" (a double-gated short convolution, ``ops.shortconv``: a
+    # slot that holds the convolution's tail alone);
     # () = all full; "none" where the block has no mixer (``block_parts``)
     layer_types: Tuple[str, ...] = ()
     sliding_window: int = 0
@@ -152,6 +172,11 @@ class LlamaConfig:
     ssm_state: int = 0
     ssm_groups: int = 1
     ssm_conv: int = 4
+    # -- "conv" layers (a short convolution between two gates): taps of the
+    # causal depthwise convolution over ``dim`` channels (HF
+    # ``conv_L_cache``). Their per-sequence state is a SLOT's: the last
+    # ``conv_taps - 1`` inputs of the convolution and nothing else
+    conv_taps: int = 3
 
     def __post_init__(self):
         # sequence fields normalize to tuples so configs hash and compare
@@ -183,6 +208,12 @@ class LlamaConfig:
                 f"block_parts names {len(self.block_parts)} blocks of "
                 f"{sorted(set(self.block_parts))}, n_layers is "
                 f"{self.n_layers} and a part is 'mixer' or 'ffn'")
+        if self.head_lanes and (self.head_lanes < self.head_dim
+                                or self.kv_lora_rank):
+            raise ValueError(
+                f"head_lanes {self.head_lanes} pads per-head keys and "
+                f"values of head_dim {self.head_dim}: it is no less, and "
+                f"latent attention pads its own row (latent_width)")
         if self.latent and (
                 self.head_dim != self.qk_nope_head_dim + self.qk_rope_head_dim
                 or self.n_kv_heads != self.n_heads or not self.v_head_dim):
@@ -203,6 +234,18 @@ class LlamaConfig:
         128 lanes (the device pads a last dim to that anyway; a declared
         pad keeps every copy and product aligned)."""
         return -(-(self.kv_lora_rank + self.qk_rope_head_dim) // 128) * 128
+
+    @property
+    def kv_lanes(self) -> int:
+        """Lanes of one head's keys (and values) in the pool and in the
+        attention calls: ``head_lanes``, or ``head_dim`` as it is."""
+        return self.head_lanes or self.head_dim
+
+    @property
+    def attn_scale(self) -> Optional[float]:
+        """The softmax scale where the calls cannot read it off the lanes
+        (``head_lanes``); None: each call's own ``lanes ** -0.5``."""
+        return self.head_dim ** -0.5 if self.head_lanes else None
 
     def window_of(self, li: int) -> int:
         """Keys layer ``li``'s queries see behind them, themselves
@@ -233,9 +276,15 @@ class LlamaConfig:
         return bool(self.layer_types) and (
             self.layer_types[li] == "state_space")
 
+    def conv_of(self, li: int) -> bool:
+        """Layer ``li`` is a gated short convolution: slot state (the
+        convolution's tail), no cache rows."""
+        return bool(self.layer_types) and self.layer_types[li] == "conv"
+
     def state_of(self, li: int) -> bool:
         """Layer ``li`` keeps recurrent slot state, whatever its kind."""
-        return self.kda_of(li) or self.ssm_of(li)
+        return bool(self.layer_types) and (
+            self.layer_types[li] in _STATE_KINDS)
 
     @property
     def _pool_order(self) -> List[int]:
@@ -262,11 +311,10 @@ class LlamaConfig:
 
     @property
     def state_kind(self) -> str:
-        """``"kda"``, ``"ssm"`` or ``""``: the recurrent kind of this
-        model's slot state (a model has one)."""
-        if not self.state_layers:
-            return ""
-        return "kda" if self.kda_layers else "ssm"
+        """``"kda"``, ``"ssm"``, ``"conv"`` or ``""``: the recurrent kind
+        of this model's slot state (a model has one)."""
+        return next((_STATE_KINDS[t] for t in self.layer_types
+                     if t in _STATE_KINDS), "")
 
     @property
     def n_paged_layers(self) -> int:
@@ -557,6 +605,55 @@ class LlamaConfig:
             ssm_state=32, ssm_groups=2, ssm_conv=4, experts_held=(0, 8))
 
     @classmethod
+    def lfm2_24b(cls, layer_types: Tuple[str, ...] = (),
+                 n_dense_layers: int = 2) -> "LlamaConfig":
+        """LFM2-24B-A2B (``model_type: lfm2_moe``) geometry: 40 layers, 30
+        of them a double-gated short convolution (``out(C * conv3(B *
+        x))``: no attention, no scan; a slot's state is the last two inputs
+        of the convolution, 2 x 2048 values) and every fourth from the third
+        on attention (32 query heads over 8 key/value heads of 64,
+        QK-normed, rotary at theta 1e6, cached on 128 lanes:
+        ``head_lanes``); two leading dense layers of 11,776, then 64
+        sigmoid-routed experts of 1536, 4 a token, scores renormalised,
+        scaled by 1, no shared expert; a 65k vocabulary, the head tied to
+        the embedding. Whole it is 48 GB in bf16; ``layer_types`` and
+        ``n_dense_layers`` cut the depth."""
+        layer_types = tuple(layer_types) or tuple(
+            "full_attention" if li % 4 == 2 else "conv" for li in range(40))
+        return cls(
+            vocab_size=65536, dim=2048, n_layers=len(layer_types),
+            n_heads=32, n_kv_heads=8, head_dim=64, head_lanes=128,
+            mlp_dim=11776, max_seq_len=128000, rope_theta=1000000.0,
+            rms_eps=1e-5, tie_embeddings=True, layer_types=layer_types,
+            qk_norm=True, n_experts=64, n_experts_per_tok=4, moe_mlp_dim=1536,
+            n_dense_layers=n_dense_layers, route_norm=True, route_scale=1.0,
+            conv_taps=3)
+
+    @classmethod
+    def lfm2_24b_stage(cls) -> "LlamaConfig":
+        """One chip's pipeline stage of LFM2-24B-A2B: the embedding (the
+        tied head), ONE leading dense layer and the model's layers 1-9
+        (conv, attention, conv, conv, conv, attention, conv, conv, conv:
+        two whole periods of four behind the dense layer), every expert of
+        a layer held. Not a servable whole model: 10.4 GB of 48 GB."""
+        return cls.lfm2_24b(cls.lfm2_24b().layer_types[1:10], 1)
+
+    @classmethod
+    def tiny_lfm2(cls) -> "LlamaConfig":
+        """CI-tier stand-in with LFM2-24B-A2B's mechanisms in the cut's
+        pattern: a dense conv layer, then two periods of attention (4
+        QK-normed rotary heads of 16 over 2, cached on 32 lanes) and three
+        conv layers of 3 taps, 16 sigmoid-routed experts of 16 top-4 with
+        no shared expert, every one held, the head tied to the embedding."""
+        return cls(
+            vocab_size=512, dim=64, n_layers=9, n_heads=4, n_kv_heads=2,
+            head_dim=16, head_lanes=32, mlp_dim=128, max_seq_len=8192,
+            rope_theta=1000000.0, rms_eps=1e-5, tie_embeddings=True,
+            layer_types=cls.lfm2_24b().layer_types[1:10], qk_norm=True,
+            n_experts=16, n_experts_per_tok=4, moe_mlp_dim=16,
+            n_dense_layers=1, route_norm=True, route_scale=1.0, conv_taps=3)
+
+    @classmethod
     def llama3_70b(cls) -> "LlamaConfig":
         """Llama-3-70B / DeepSeek-R1-Distill-Llama-70B geometry — the
         reference's biggest deployment (TP=32,
@@ -574,6 +671,29 @@ class LlamaConfig:
 
     @classmethod
     def from_hf(cls, hf) -> "LlamaConfig":
+        if "conv" in (getattr(hf, "layer_types", None) or ()):
+            # the ``lfm2_moe`` keys: gated short convolutions beside
+            # QK-normed attention, routed experts behind the dense layers
+            return cls(
+                vocab_size=hf.vocab_size, dim=hf.hidden_size,
+                n_layers=hf.num_hidden_layers,
+                n_heads=hf.num_attention_heads,
+                n_kv_heads=hf.num_key_value_heads,
+                mlp_dim=hf.intermediate_size,
+                max_seq_len=hf.max_position_embeddings,
+                rope_theta=float(hf.rope_parameters["rope_theta"]),
+                rms_eps=hf.norm_eps,
+                tie_embeddings=getattr(hf, "tie_word_embeddings", True),
+                layer_types=tuple(hf.layer_types), qk_norm=True,
+                head_lanes=-(-(hf.hidden_size // hf.num_attention_heads)
+                             // 128) * 128,
+                n_experts=hf.num_experts,
+                n_experts_per_tok=hf.num_experts_per_tok,
+                moe_mlp_dim=hf.moe_intermediate_size,
+                n_dense_layers=hf.num_dense_layers,
+                route_norm=bool(hf.norm_topk_prob),
+                route_scale=float(hf.routed_scaling_factor),
+                conv_taps=hf.conv_L_cache)
         return cls(
             vocab_size=hf.vocab_size,
             dim=hf.hidden_size,
@@ -825,24 +945,22 @@ def cache_leaves(cfg: LlamaConfig, li: Optional[int] = None
         return {}
     if cfg.latent:
         return {"c": (cfg.latent_width,)}
-    return {"k": (cfg.n_kv_heads, cfg.head_dim),
-            "v": (cfg.n_kv_heads, cfg.head_dim)}
+    return {"k": (cfg.n_kv_heads, cfg.kv_lanes),
+            "v": (cfg.n_kv_heads, cfg.kv_lanes)}
 
 
 def state_leaves(cfg: LlamaConfig, li: Optional[int] = None
                  ) -> Dict[str, Tuple[Tuple[int, ...], str]]:
     """What ONE slot costs in ONE recurrent layer, by leaf: ``(shape,
     dtype)`` behind ``[slots]``, as the model's recurrent KIND says
-    (``ops.kda.state_shapes``, ``ops.ssm.state_shapes``); ``{}`` for a
-    model with no such layer, and for layer ``li`` where it is none."""
+    (``state_shapes`` of the kind's module under ``ops``:
+    ``_STATE_MODULES``); ``{}`` for a model with no such layer, and for
+    layer ``li`` where it is none."""
     if not cfg.recurrent or (li is not None and not cfg.state_of(li)):
         return {}
-    if cfg.state_kind == "ssm":
-        from ..ops.ssm import state_shapes
-    else:
-        from ..ops.kda import state_shapes
-
-    return state_shapes(cfg)
+    ops = importlib.import_module(
+        "..ops." + _STATE_MODULES[cfg.state_kind], __package__)
+    return ops.state_shapes(cfg)
 
 
 def cache_specs(
@@ -946,6 +1064,16 @@ KDA_DT_RANGE = (1e-3, 1e-1)
 SSM_CONV_RANGE = (-0.5, 0.5)
 SSM_DT_RANGE = (1e-3, 1e-1)
 SSM_DT_FLOOR = 1e-4
+
+def conv_tap_range(taps: int) -> Tuple[float, float]:
+    """The range a gated short convolution's seeded taps are drawn from, as
+    the public code's depthwise ``nn.Conv1d`` draws them by default: uniform
+    on +-1 / sqrt(taps) (0.577 at 3 taps). At the tier's 0.02 the
+    convolution would return a fiftieth of its input and the layer nothing
+    beside the residual stream, so a reversed or dropped tap would pass any
+    comparison."""
+    return (-taps ** -0.5, taps ** -0.5)
+
 
 #: the ``down`` leaf of a two-matrix ``relu ** 2`` part, as a multiple of the
 #: tier's deviation. At one deviation throughout, ``relu(u) ** 2`` of a
@@ -1099,7 +1227,9 @@ def geometry_params(cfg: LlamaConfig, dtype=jnp.bfloat16,
                                dtype) for n in firsts},
                     "down": rand(f"{mo}/experts/down", (Eh, F, D), dtype,
                                  down_gain)},
-                "shared": mlp(f"{mo}/shared", cfg.shared_width),
+                # no ``shared`` leaf where the model has no shared expert
+                **({"shared": mlp(f"{mo}/shared", cfg.shared_width)}
+                   if cfg.n_shared_experts else {}),
             }
         else:
             layer["mlp"] = mlp(f"{lp}/mlp", cfg.mlp_dim)
@@ -1125,6 +1255,15 @@ def geometry_params(cfg: LlamaConfig, dtype=jnp.bfloat16,
                 "D": jnp.ones((SH,), jnp.float32),
                 "norm": norm(f"{at}/norm", inner),
                 "o": lin(f"{at}/o", inner, D),
+            }
+        elif cfg.conv_of(i):
+            # the public names: in_proj (B | C | x), conv, out_proj; no bias
+            at = f"{lp}/attn"
+            layer["attn"] = {
+                "in": lin(f"{at}/in", D, 3 * D),
+                "conv": uniform((cfg.conv_taps, D),
+                                *conv_tap_range(cfg.conv_taps)).astype(dtype),
+                "o": lin(f"{at}/o", D, D),
             }
         elif i in cfg.cross_attention_layers:
             ca = f"{lp}/cross_attn"

@@ -28,6 +28,11 @@ from typing import Any, Dict, List, Optional, Sequence, Tuple
 
 from .trace import Trace, annotate
 
+#: the recurrent KINDS a model's slot state can be
+#: (``LlamaConfig.state_kind``): each is an attribute here, an entry of the
+#: snapshot and a counter family (``count_recurrent``)
+RECURRENT_KINDS = ("kda", "ssm", "conv")
+
 #: explicit histogram bounds (seconds). TTFT includes queue time, so its
 #: range reaches minutes; TPOT is per-token decode pace (milliseconds).
 TTFT_BUCKETS = (0.01, 0.025, 0.05, 0.1, 0.25, 0.5, 1.0, 2.5, 5.0, 10.0,
@@ -571,12 +576,13 @@ class StepTelemetry:
         self.mla: Optional[Dict[str, int]] = None
         # what the recurrent layers did, counted on the host where it is
         # known, under the model's recurrent KIND (``kda``: linear
-        # attention; ``ssm``: state-space mixers): real tokens x recurrent
-        # layers through the prefill scan, continuation programs that read
-        # a slot's state, live rows x recurrent layers stepped in decode
-        # dispatches. None = no layer of that kind.
-        self.kda: Optional[Dict[str, int]] = None
-        self.ssm: Optional[Dict[str, int]] = None
+        # attention; ``ssm``: state-space mixers; ``conv``: gated short
+        # convolutions): real tokens x recurrent layers through the prefill
+        # pass, continuation programs that read a slot's state, live rows x
+        # recurrent layers stepped in decode dispatches. None = no layer of
+        # that kind.
+        for kind in RECURRENT_KINDS:
+            setattr(self, kind, None)
         self.warmed_executables = 0  # closed-set size at readiness
         # last-step gauges (scraped between steps)
         self._gauges: Dict[str, float] = {}
@@ -889,11 +895,11 @@ class StepTelemetry:
                         chunk_carries: int = 0,
                         rows_stepped: int = 0) -> None:
         """One dispatch of a model with recurrent layers of ``kind``
-        (``"kda"`` or ``"ssm"``: the snapshot's entry); a model without
+        (one of ``RECURRENT_KINDS``: the snapshot's entry); a model without
         them counts nothing (every argument 0) and shows no such entry."""
         if not (prefill_tokens or chunk_carries or rows_stepped):
             return
-        assert kind in ("kda", "ssm"), kind
+        assert kind in RECURRENT_KINDS, kind
         with self._lock:
             m = getattr(self, kind)
             if m is None:
@@ -977,7 +983,7 @@ class StepTelemetry:
         if state_slots is not None:
             rec["state_slots_live"] = int(state_slots)
         with self._lock:
-            for m in (self.kda, self.ssm):
+            for m in (getattr(self, kind) for kind in RECURRENT_KINDS):
                 if state_slots is not None and m is not None:
                     m["slots_live"] = int(state_slots)
             self.steps += 1
@@ -1081,10 +1087,9 @@ class StepTelemetry:
                 out["window"] = dict(self.window)
             if self.mla is not None:
                 out["mla"] = dict(self.mla)
-            if self.kda is not None:
-                out["kda"] = dict(self.kda)
-            if self.ssm is not None:
-                out["ssm"] = dict(self.ssm)
+            for kind in RECURRENT_KINDS:
+                if getattr(self, kind) is not None:
+                    out[kind] = dict(getattr(self, kind))
             # the open phase's seconds so far included: two readings
             # differ by the time between them, whatever each caught open
             out["phase_s"] = dict(self.phase_s)

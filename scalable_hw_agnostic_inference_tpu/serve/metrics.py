@@ -198,6 +198,13 @@ _SSM_COUNTERS = ("shai_engine_ssm_total",
                  "that read a slot's state), rows_stepped (live rows x "
                  "state-space layers in decode dispatches), slots_live "
                  "(gauge: arena slots held)")
+_CONV_COUNTERS = ("shai_engine_conv_total",
+                  "Recurrent (gated short convolution) layers, by counter: "
+                  "prefill_tokens (real tokens x conv layers through "
+                  "prefill programs), chunk_carries (continuation programs "
+                  "that read a slot's tail), rows_stepped (live rows x conv "
+                  "layers in decode dispatches), slots_live (gauge: arena "
+                  "slots held)")
 #: conformance-layer gauge families: each instrument riding the engine
 #: telemetry object exports its flat numeric snapshot verbatim under a
 #: prefix — obs.slo → shai_slo_* (per-objective burn rates + breach),
@@ -388,7 +395,8 @@ class EngineTelemetryCollector:
                             ("window", _WINDOW_COUNTERS),
                             ("mla", _MLA_COUNTERS),
                             ("kda", _KDA_COUNTERS),
-                            ("ssm", _SSM_COUNTERS)):
+                            ("ssm", _SSM_COUNTERS),
+                            ("conv", _CONV_COUNTERS)):
             if snap.get(key):
                 c = CounterMetricFamily(*family, labels=["app", "counter"])
                 for counter, v in sorted(snap[key].items()):

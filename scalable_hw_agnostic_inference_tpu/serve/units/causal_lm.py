@@ -227,7 +227,11 @@ def _geometry_models():
     Nemotron-3-Nano-30B-A3B's first nine blocks of ONE part each (four
     Mamba-2 mixers, four routed blocks of two-matrix ``relu ** 2`` experts,
     one attention block), 64 of each routed block's 128 experts held here:
-    7.04 GB of 63 GB."""
+    7.04 GB of 63 GB. ``lfm2-24b-a2b-geometry`` is a stage again:
+    LFM2-24B-A2B's second dense layer and two periods of its routed layers
+    (seven gated short convolutions, two attention layers of 64-wide
+    heads), every one of a layer's 64 experts held, the head tied to the
+    embedding: 10.4 GB of 48 GB."""
     from ...models.llama import LlamaConfig
 
     return {
@@ -239,6 +243,7 @@ def _geometry_models():
         "kanana-2-geometry": LlamaConfig.kanana2_stage,
         "kimi-linear-geometry": LlamaConfig.kimi_linear_stage,
         "nemotron-3-nano-geometry": LlamaConfig.nemotron3_nano_stage,
+        "lfm2-24b-a2b-geometry": LlamaConfig.lfm2_24b_stage,
     }
 
 
@@ -247,7 +252,8 @@ def _stand_in_models():
     seed, the byte tokenizer, and in the ``vllm`` unit ONE tiny engine
     shape. ``tiny-afmoe`` has ``trinity-mini-geometry``'s mechanisms,
     ``tiny-mla`` ``kanana-2-geometry``'s, ``tiny-kda``
-    ``kimi-linear-geometry``'s, ``tiny-ssm`` ``nemotron-3-nano-geometry``'s."""
+    ``kimi-linear-geometry``'s, ``tiny-ssm`` ``nemotron-3-nano-geometry``'s,
+    ``tiny-lfm2`` ``lfm2-24b-a2b-geometry``'s."""
     from ...models.llama import LlamaConfig
 
     return {
@@ -256,6 +262,7 @@ def _stand_in_models():
         "tiny-mla": LlamaConfig.tiny_mla,
         "tiny-kda": LlamaConfig.tiny_kda,
         "tiny-ssm": LlamaConfig.tiny_ssm,
+        "tiny-lfm2": LlamaConfig.tiny_lfm2,
     }
 
 

@@ -55,9 +55,10 @@ class VllmService(ModelService):
     coalesce into the running batch.
 
     ``MODEL_ID``: a hub id, ``tiny`` / ``tiny-afmoe`` / ``tiny-mla`` /
-    ``tiny-kda`` / ``tiny-ssm`` (the hermetic stand-ins), or a geometry id
+    ``tiny-kda`` / ``tiny-ssm`` / ``tiny-lfm2`` (the hermetic stand-ins),
+    or a geometry id
     (``units/causal_lm.py``): an architecture at its published widths over
-    seeded weights. Four of those are ONE CHIP'S STAGE of a pipeline and
+    seeded weights. Five of those are ONE CHIP'S STAGE of a pipeline and
     not a servable whole model: ``trinity-mini-geometry`` (AFMoE: routed
     experts, window and full layers), ``kanana-2-geometry``
     (``deepseek_v3``: a latent paged cache with absorbed decode beside
@@ -68,7 +69,10 @@ class VllmService(ModelService):
     blocks that are a mixer alone or a feed-forward part alone, recurrent
     slot state in four Mamba-2 mixers beside one attention block's paged
     keys, two-matrix ``relu ** 2`` experts; 9 of 52 blocks, 64 of 128
-    experts a routed block).
+    experts a routed block) and ``lfm2-24b-a2b-geometry`` (``lfm2_moe``:
+    recurrent slot state that is a short convolution's tail in seven
+    layers of nine, 64-wide QK-normed heads in the other two, 64 routed
+    experts a layer all held, a tied head; 9 of 40 layers).
     """
 
     task = "text-generation"

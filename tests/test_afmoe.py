@@ -591,7 +591,7 @@ def test_every_stand_in_gets_the_one_tiny_engine_shape(served):
     )
 
     assert set(_stand_in_models()) == {"tiny", "tiny-afmoe", "tiny-mla",
-                                      "tiny-kda", "tiny-ssm"}
+                                      "tiny-kda", "tiny-ssm", "tiny-lfm2"}
     assert not set(_stand_in_models()) & set(_geometry_models())
     ecfg = served.service.ecfg
     assert ecfg.max_num_seqs == served.config["engine"]["max_num_seqs"]

@@ -21,11 +21,16 @@ the state-space chunk kernel over its largest prefill program (4 rows of
 512) and its step kernel at 128 rows (64 heads of 64 x 128, 8 groups), both
 expert kernels on two-matrix ``relu ** 2`` experts of 2688 x 1856 (1856 = 14
 x 128 + 64: whole-width blocks), 64 of 128 held, and flash and the paged
-kernel at 32 query heads over 2 key/value heads. The case builders themselves
+kernel at 32 query heads over 2 key/value heads; and LFM2-24B-A2B's: both
+expert kernels at 64 experts of 2048 x 1536 top-4, flash at 32 heads over 8
+of 64 and, at the 128 lanes such heads are served on, flash and the paged
+kernel (which Mosaic refuses at 64). The case builders themselves
 are checked against their oracles in interpret mode by the smoke's dry run
 (``tests/test_chip_smoke.py``; the expert cases by
 ``tests/test_moe_ffn_kernel.py``).
 """
+
+import dataclasses
 
 import jax
 import numpy as np
@@ -84,6 +89,22 @@ def _cases():
                                        max_num_seqs=128):
         if "int8" not in c.name:      # the boot refuses an 8-bit pool here
             seen.setdefault(c.name, c)
+    # LFM2-24B-A2B's stage: 64 experts of 3 x 2048 x 1536 top-4, all held
+    # (the streamed kernel at 128 rows, the tiled one over 2048); flash at
+    # its heads AS DECLARED (32 over 8 of 64), and flash and the paged
+    # kernel at the 128 lanes the engine serves them on (``head_lanes``:
+    # Mosaic refuses the paged kernel at 64, the test below holds that)
+    for c in kernel_check.expert_cases(64, 4, 2048, 1536, max_num_seqs=128,
+                                       prefill_rows=2048):
+        seen.setdefault(c.name, c)
+    for lanes in (64, 128):
+        for c in kernel_check.engine_cases(32, 8, lanes, buckets=(256, 512),
+                                           max_model_len=1600,
+                                           max_num_seqs=128):
+            if "int8" not in c.name and (lanes == 128 or "flash" in c.name):
+                seen.setdefault(f"{c.name}-D{lanes}",
+                                dataclasses.replace(
+                                    c, name=f"{c.name}-D{lanes}"))
     return list(seen.values())
 
 
@@ -106,6 +127,25 @@ def test_kernel_compiles_for_v5e(case, v5e_sharding):
                                        sharding=v5e_sharding), avals)
     jax.jit(lambda *a: case.kernel(*a, interpret=False)).lower(
         *avals).compile()
+
+
+def test_the_paged_kernel_is_refused_at_heads_of_64(v5e_sharding):
+    """Why a 64-wide head is cached on 128 lanes (``LlamaConfig.head_lanes``):
+    Mosaic cannot slice 64 lanes of the pool's 128-lane tiles, so the paged
+    kernel does not compile over a pool ``[N, bs * Hkv, 64]`` (and the v5e
+    would store such a leaf with its blocks on the lanes and re-lay it whole
+    before every call). A libtpu that takes it makes this test fail: the
+    pad can then go (``ROADMAP.md`` Queue 1)."""
+    case = [c for c in kernel_check.engine_cases(
+        32, 8, 64, buckets=(256,), max_model_len=1600, max_num_seqs=128)
+        if c.name.startswith("paged") and "int8" not in c.name][0]
+    avals = jax.tree.map(
+        lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype,
+                                       sharding=v5e_sharding),
+        jax.eval_shape(case.make_inputs, jax.random.PRNGKey(0)))
+    with pytest.raises(Exception, match="aligned to tiling"):
+        jax.jit(lambda *a: case.kernel(*a, interpret=False)).lower(
+            *avals).compile()
 
 
 def _kimi_chunk_case():

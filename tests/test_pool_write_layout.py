@@ -164,3 +164,46 @@ def test_trinity_program_copies_no_pool_leaf(program, topo):
     assert sum(op == "scatter" for op, _ in found) >= 2 * cfg.n_layers
     copies = [line for op, line in found if op == "copy"]
     assert not copies, "\n".join(copies)
+
+
+def test_lfm2_decode_program_compiles_and_copies_no_pool_leaf(topo):
+    """LFM2-24B-A2B's stage at the cell's sizes (128 rows, 12,864 blocks,
+    every expert held): the whole decode program compiles for the v5e, the
+    pool's leaves come in and go out in their own row-major order on 128
+    lanes a head (``head_lanes``; at the declared 64 the chip lays such a
+    leaf out with its BLOCKS on the lanes and Mosaic refuses the paged
+    kernel), and nothing pool-sized is copied."""
+    from scalable_hw_agnostic_inference_tpu.models.llama import (
+        cache_leaves,
+        state_leaves,
+    )
+
+    cfg = LlamaConfig.lfm2_24b_stage()
+    n_blocks, M, B = 12864, 100, 128
+    _, rep = _shardings(topo, cfg, 1)
+    s = lambda shape, dt: SDS(shape, dt, sharding=rep)    # noqa: E731
+    params = jax.tree.map(lambda a: s(a.shape, a.dtype),
+                          jax.eval_shape(lambda: geometry_params(cfg)))
+    leaf = {n: s((n_blocks, BLOCK) + per, jnp.bfloat16)
+            for n, per in cache_leaves(cfg).items()}
+    assert leaf["k"].shape == (n_blocks, BLOCK, 8, 128)
+    arena = {n: s((B + 1,) + tuple(shp), jnp.bfloat16)
+             for n, (shp, _) in state_leaves(cfg).items()}
+    kv = [dict(arena if pi in cfg.state_layers else leaf) for pi in range(9)]
+    with topo.platform_override("tpu"):
+        lowered = runner.make_decode(
+            cfg, BLOCK, M, B, paged=True, feedback=True).lower(
+            params, kv, s((B,), jnp.int32), s((B,), jnp.int32),
+            s((B, M), jnp.int32), s((B,), jnp.float32),
+            s((2,), jnp.uint32), s((), jnp.int32), s((B,), jnp.float32),
+            s((B,), jnp.int32), s((B,), jnp.float32), s((B,), jnp.int32))
+    compiled = lowered.compile()
+    text = compiled.as_text()
+    assert "bf16[12864,16,8,128]{3,2,1,0:T(8,128)(2,1)} parameter" in text
+    found = pool_sized(compiled, n_blocks * BLOCK * 8 * 128)
+    assert sum(op == "scatter" for op, _ in found) == 4      # k, v x 2
+    copies = [line for op, line in found if op == "copy"]
+    assert not copies, "\n".join(copies)
+    mem = compiled.memory_analysis()
+    assert mem.argument_size_in_bytes == pytest.approx(12.05e9, rel=1e-3)
+    assert mem.temp_size_in_bytes < 2 ** 28
