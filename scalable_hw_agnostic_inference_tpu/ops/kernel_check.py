@@ -260,21 +260,38 @@ def _latent_flash_case(H: int, D: int, Dv: int, T: int, S: int) -> KernelCase:
 
 def _latent_pool_case(H: int, width: int, rank: int, scale: float,
                       block_size: int, blocks_per_seq: int, rows: int,
-                      tile_edges: bool = False,
-                      one_seq: bool = False) -> KernelCase:
+                      tile_edges: bool = False, one_seq: bool = False,
+                      wait_edges: bool = False) -> KernelCase:
     """The absorbed latent kernel over ``rows`` single-query rows with
     shuffled block tables: ragged lengths with an EMPTY row (length 0, a
     table of zeros) among them, or lengths on both sides of a tile's edge
     over a pool whose every block no row holds is NaN; ``one_seq``:
-    consecutive queries of one sequence, a query a row."""
+    consecutive queries of one sequence, a query a row. ``wait_edges``,
+    over the same NaN pool, is what the ORDER of a tile's copies and waits
+    can get wrong, a row a length (``rows`` is not read): an empty row
+    FOLLOWED by a full one (the prefetch across rows), a last tile that
+    holds exactly one block, both sides of every edge between the groups a
+    tile is waited for in, the same a tile on, and rows of exactly two and
+    three whole tiles."""
     from .mla import latent_gather_attention
-    from .pallas.mla_paged_attention import mla_paged_decode, mla_tile_tokens
+    from .pallas.mla_paged_attention import (
+        mla_paged_decode,
+        mla_tile_tokens,
+        mla_wait_tokens,
+    )
 
     L = blocks_per_seq * block_size
     t = mla_tile_tokens(block_size)
     if one_seq:
         mid = t if tile_edges else L // 2 + 5
         lens = [mid - rows // 2 + 1 + i for i in range(rows)]
+    elif wait_edges:
+        w = mla_wait_tokens(block_size)
+        lens = [0, L, t + 1, t + block_size]
+        for edge in range(w, t, w):
+            lens += [edge - 1, edge, edge + 1]
+        lens += [t + w + 1, 2 * t, 3 * t]
+        rows = len(lens)
     elif tile_edges:
         lens = [t - 1, t, t + 1, 2 * t, 2 * t + 1, 1, block_size + 3, L]
     else:
@@ -292,7 +309,7 @@ def _latent_pool_case(H: int, width: int, rank: int, scale: float,
             tables = jnp.repeat(tables[:1], rows, axis=0)
         n = jnp.asarray(lens, jnp.int32)
         tables = jnp.where((n > 0)[:, None], tables, 0)   # an empty slot
-        if tile_edges:
+        if tile_edges or wait_edges:
             held = (jnp.arange(blocks_per_seq)[None, :] * block_size
                     < n[:, None])
             owned = jnp.zeros((n_blocks,), bool).at[0].set(True).at[
@@ -319,6 +336,7 @@ def _latent_pool_case(H: int, width: int, rank: int, scale: float,
     return KernelCase(
         name=(f"mla-H{H}-w{width}r{rank}-bs{block_size}-M{blocks_per_seq}"
               f"-b{rows}{'-edges' if tile_edges else ''}"
+              f"{'-waits' if wait_edges else ''}"
               f"{'-oneseq' if one_seq else ''}"),
         make_inputs=make, kernel=kernel, oracle=oracle, tol=TOL_BF16)
 
@@ -332,8 +350,8 @@ def latent_cases(n_heads: int, head_dim: int, v_head_dim: int, width: int,
     over keys of ``head_dim`` beside values of ``v_head_dim`` at each
     prefill bucket and at the last continuation start, and the absorbed
     kernel over the full block table: ragged rows with an empty one, the
-    tile's edges over a NaN-poisoned pool, and one sequence's consecutive
-    queries."""
+    tile's edges and the wait groups' edges over a NaN-poisoned pool, and
+    one sequence's consecutive queries."""
     M = max_model_len // block_size
     top = max(buckets)
     scale = head_dim ** -0.5
@@ -349,6 +367,8 @@ def latent_cases(n_heads: int, head_dim: int, v_head_dim: int, width: int,
                                    M, 8, tile_edges=True))
     cases.append(_latent_pool_case(n_heads, width, rank, scale, block_size,
                                    M, 8, tile_edges=True, one_seq=True))
+    cases.append(_latent_pool_case(n_heads, width, rank, scale, block_size,
+                                   M, 0, wait_edges=True))
     return cases
 
 

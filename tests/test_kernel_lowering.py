@@ -8,7 +8,10 @@ ones ``chip_smoke.py`` then runs on the chip against the XLA oracles
 block sizes 16 and 128, bf16 and int8-KV pools; and Kanana-2's latent
 attention at its published widths: flash over keys of 192 beside values of
 128 at the cell's buckets and its last continuation start, the absorbed
-kernel over 640-lane rows at 64 rows; and the streamed expert product at
+kernel over 640-lane rows at 64 rows, at its tile's edges and at the edges
+of the groups a tile is waited for in (two slots of 1,024 x 640 bfloat16:
+2.6 MB of scratch, inside Mosaic's default scoped VMEM, so the call states
+no limit); and the streamed expert product at
 both routed cells' widths and largest decode buckets (128 experts of 2048 x
 768 at 64 rows, of 2048 x 1024 at 32) and the tiled one at the three routed
 configurations' largest prefill programs (2048 rows over 128 of Kimi's 256

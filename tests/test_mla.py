@@ -33,6 +33,7 @@ from scalable_hw_agnostic_inference_tpu.models.llama import (
 from scalable_hw_agnostic_inference_tpu.ops import kernel_check, mla
 from scalable_hw_agnostic_inference_tpu.ops.pallas.mla_paged_attention import (
     mla_tile_tokens,
+    mla_wait_tokens,
 )
 from scalable_hw_agnostic_inference_tpu.ops.rope import (
     apply_rope,
@@ -241,25 +242,53 @@ def _kernel_cases():
         4, 128, 64, 128, 32, block_size=8, buckets=(16, 32),
         max_model_len=2048, max_num_seqs=5) + kernel_check.latent_cases(
         2, 64, 64, 256, 160, block_size=128, buckets=(128,),
-        max_model_len=1024, max_num_seqs=3)[-3:]
+        max_model_len=1024, max_num_seqs=3)[-4:]
 
 
 @pytest.mark.parametrize("case", _kernel_cases(), ids=lambda c: c.name)
 def test_latent_kernels_agree_with_their_oracles(case):
     """Ragged rows with an empty one (length 0, a table of zeros), both
     sides of a tile's edge over a pool that is NaN wherever no row holds
-    it, one sequence's queries a row each; flash with values narrower than
-    keys, a prefill bucket and a continuation chunk."""
+    it, one sequence's queries a row each, and what the order of a tile's
+    copies and waits can break (an empty row before a full one, a last
+    tile of one block, every wait group's edge, rows of whole tiles);
+    flash with values narrower than keys, a prefill bucket and a
+    continuation chunk."""
     assert case.max_abs_err(interpret=True) <= case.tol
 
 
 def test_the_cases_cover_the_latent_kernels():
     names = [c.name for c in _kernel_cases()]
     assert sum(n.startswith("flash-latent") for n in names) == 3
-    assert sum(n.startswith("mla-") for n in names) == 6
+    assert sum(n.startswith("mla-") for n in names) == 8
     assert any(n.endswith("-edges-oneseq") for n in names)
+    assert sum(n.endswith("-waits") for n in names) == 2
     assert mla_tile_tokens(16) == 1024 and mla_tile_tokens(8) == 1024
     assert mla_tile_tokens(4096) == 4096        # a block is its own tile
+    # a tile is waited for in four groups; a block of a group's size or
+    # more is its own group
+    assert mla_wait_tokens(16) == 256 and mla_wait_tokens(8) == 256
+    assert mla_wait_tokens(128) == 256 and mla_wait_tokens(512) == 512
+    assert mla_wait_tokens(4096) == 4096
+
+
+def test_the_wait_case_holds_the_lengths_the_issue_order_can_break():
+    """An empty row FOLLOWED by a full one, a last tile of exactly one
+    block, both sides of every wait group's edge, and whole tiles."""
+    case = kernel_check.latent_cases(
+        4, 128, 64, 128, 32, block_size=8, buckets=(16,),
+        max_model_len=4096, max_num_seqs=5)[-1]
+    assert case.name.endswith("-waits")
+    _q, _c, tables, n = jax.jit(case.make_inputs)(jax.random.PRNGKey(0))
+    lens = np.asarray(n).tolist()
+    assert tables.shape == (len(lens), 512)
+    assert not np.asarray(tables)[0].any()      # the empty row's table
+    t, w, L = mla_tile_tokens(8), mla_wait_tokens(8), 4096
+    assert lens[:2] == [0, L]
+    assert t + 1 in lens and t + 8 in lens
+    for edge in range(w, t, w):
+        assert {edge - 1, edge, edge + 1} <= set(lens)
+    assert {t + w + 1, 2 * t, 3 * t} <= set(lens)
 
 
 # -- the cache with a latent leaf -------------------------------------------
