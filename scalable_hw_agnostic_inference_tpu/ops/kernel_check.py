@@ -650,24 +650,32 @@ def _ssm_chunk_case(H: int, P: int, N: int, G: int, T: int,
 
 
 def _ssm_step_case(H: int, P: int, N: int, G: int, rows: int,
-                   slots: int) -> KernelCase:
+                   slots: int, padded: int = 2) -> KernelCase:
     """The step kernel for ``rows`` rows over an arena of ``slots`` and the
-    null slot, as ``_kda_step_case``: the last two rows padded."""
+    null slot, as ``_kda_step_case``: the rows' slots are a permutation's
+    head (neither sorted nor adjacent), the last ``padded`` rows carry the
+    null slot; the arena comes back whole, so a write to a slot no row
+    names shows."""
     from . import ssm
     from .pallas.ssm_step import ssm_decode_step
+
+    live = rows - padded
 
     def make(key):
         k0, k1, k2 = jax.random.split(key, 3)
         ops = tuple(a[:, 0] for a in _ssm_operands(k0, rows, 1, H, P, N, G))
         ids = jax.random.permutation(k2, slots)[:rows].astype(jnp.int32)
-        ids = jnp.where(jnp.arange(rows) >= rows - 2, slots, ids)
+        ids = jnp.where(jnp.arange(rows) >= live, slots, ids)
         return ops + (jax.random.normal(k1, (slots + 1, H, P, N)), ids)
 
     def keep_null(y, arena):
-        return jnp.concatenate([y[:-2].reshape(-1), arena[:-1].reshape(-1)])
+        return jnp.concatenate([y[:live].reshape(-1),
+                                arena[:-1].reshape(-1)])
 
     return KernelCase(
-        name=f"ssm-step-H{H}x{P}x{N}-b{rows}-S{slots}", make_inputs=make,
+        name=(f"ssm-step-H{H}x{P}x{N}-b{rows}-S{slots}"
+              + ("" if padded == 2 else f"-pad{padded}")),
+        make_inputs=make,
         kernel=lambda *a, interpret: keep_null(
             *ssm_decode_step(*a, interpret=interpret)),
         oracle=lambda *a: keep_null(*ssm.step_slots(*a, kernel=False)),
@@ -679,8 +687,13 @@ def ssm_cases(heads: int, head_dim: int, state: int, groups: int, *,
               max_num_seqs: int = 16) -> List[KernelCase]:
     """The kernel calls an engine with state-space mixers dispatches: the
     chunk kernel over a prefill bucket, and the step kernel at the largest
-    decode bucket and at a small one."""
+    decode bucket and at a small one (the last two rows padded), at the
+    bucket of ONE row (a grid of one step, nothing padded) and at the
+    largest with half its rows padded (the null slot again and again)."""
+    step = functools.partial(_ssm_step_case, heads, head_dim, state, groups,
+                             slots=max_num_seqs)
+    steps = {c.name: c for c in [
+        step(rows) for rows in sorted({max_num_seqs, min(max_num_seqs, 4)})
+    ] + [step(1, padded=0), step(max_num_seqs, padded=max_num_seqs // 2)]}
     return [_ssm_chunk_case(heads, head_dim, state, groups, bucket,
-                            prefill_rows)] + [
-        _ssm_step_case(heads, head_dim, state, groups, rows, max_num_seqs)
-        for rows in sorted({max_num_seqs, min(max_num_seqs, 4)})]
+                            prefill_rows)] + list(steps.values())

@@ -8,9 +8,12 @@ interpreted; its times mean nothing).
 The chunk kernel over the largest prefill program's tokens (4 rows of 512,
 64 heads of 64 x 128, a state that is not zero): its time, and the
 recurrence's ``4 P N`` operations a token and head over 197 TFLOP/s as a
-share of it. The step kernel at each decode bucket over a 129-slot arena,
-eight steps chained in one program: its time, and the rows' states read and
-written (``2 x 64 x 64 x 128 x 4`` B a row) over 819 GB/s as a share of it.
+share of it. The step kernel at EVERY decode bucket the engine warms (1, 2,
+4, ... 128 rows, all live) over a 129-slot arena, eight steps chained in
+one program: its time beside its bytes' time (the rows' states read and
+written, ``2 x 64 x 64 x 128 x 4`` B a row, over 819 GB/s) and the share;
+its ``ssm_cases`` (two padded rows, one row, half the rows padded) are
+compared with the oracle first.
 The streamed expert kernel at 128 rows and at 8, and the tiled one at 2,048
 rows: each one's time beside the plain form's on the same assignments, and
 the bytes of the held experts touched over 819 GB/s as a share of it. Errors
@@ -52,6 +55,29 @@ def timed(f, args, n):
     return (time.perf_counter() - t0) / n
 
 
+def _time_step(case, dry, n):
+    """One step's time at a case's rows: the arena donated and handed on,
+    as the engine's step does, and STEPS steps chained in one program, so
+    that the device's time is read and not the host's dispatch."""
+    def chain(x, Bm, Cm, dt, ld, arena, ids):
+        for _ in range(STEPS):
+            y, arena = ssm_decode_step(x, Bm, Cm, dt, ld, arena, ids,
+                                       interpret=dry)
+            x = x + 0.0 * y        # a step needs the one before
+        return y, arena
+
+    f = jax.jit(chain, donate_argnums=(5,))
+    *ops, arena, ids = jax.jit(case.make_inputs)(jax.random.PRNGKey(0))
+    for _ in range(2):
+        _, arena = f(*ops, arena, ids)
+    jax.block_until_ready(arena)
+    t0 = time.perf_counter()
+    for _ in range(n):
+        _, arena = f(*ops, arena, ids)
+    jax.block_until_ready(arena)
+    return (time.perf_counter() - t0) / n / STEPS * 1e3
+
+
 def mixer_kernels(dry):
     H, P, N, G = (4, 16, 32, 2) if dry else (64, 64, 128, 8)
     T, rows_p, slots = (160, 2, 4) if dry else (512, 4, 128)
@@ -60,40 +86,28 @@ def mixer_kernels(dry):
     for case in kernel_check.ssm_cases(H, P, N, G, bucket=T,
                                        prefill_rows=rows_p,
                                        max_num_seqs=slots):
-        args = jax.jit(case.make_inputs)(jax.random.PRNGKey(0))
-        rows = args[0].shape[0]
         rec = {"case": case.name, "tol": case.tol,
                "max_abs_err": case.max_abs_err(interpret=dry)}
         if "chunk" in case.name:
+            args = jax.jit(case.make_inputs)(jax.random.PRNGKey(0))
             f = jax.jit(lambda *a: ssm_chunk_prefill(*a, interpret=dry))
             rec["ms"] = timed(f, args, n) * 1e3
-            least = 4.0 * H * P * N * T * rows / MXU_FLOPS_PER_S
-        else:
-            # the arena donated and handed on, as the engine's step does,
-            # and STEPS steps chained in one program, so that the device's
-            # time is read and not the host's dispatch
-            def chain(x, Bm, Cm, dt, ld, arena, ids):
-                for _ in range(STEPS):
-                    y, arena = ssm_decode_step(x, Bm, Cm, dt, ld, arena, ids,
-                                               interpret=dry)
-                    x = x + 0.0 * y        # a step needs the one before
-                return y, arena
-
-            f = jax.jit(chain, donate_argnums=(5,))
-            *ops, arena, ids = args
-            for _ in range(2):
-                _, arena = f(*ops, arena, ids)
-            jax.block_until_ready(arena)
-            t0 = time.perf_counter()
-            for _ in range(n):
-                _, arena = f(*ops, arena, ids)
-            jax.block_until_ready(arena)
-            rec["ms"] = (time.perf_counter() - t0) / n / STEPS * 1e3
-            least = 2.0 * H * P * N * 4 * rows / HBM_BYTES_PER_S
-        rec["roofline_share"] = least / (rec["ms"] / 1e3)
+            least = 4.0 * H * P * N * T * args[0].shape[0] / MXU_FLOPS_PER_S
+            rec["roofline_share"] = least / (rec["ms"] / 1e3)
         rec["ok"] = rec["max_abs_err"] <= case.tol
         print(json.dumps(rec), flush=True)
         out.append(rec)
+    # the step kernel at EVERY decode bucket the engine warms, all rows live
+    rows = 1
+    while rows <= slots:
+        case = kernel_check._ssm_step_case(H, P, N, G, rows, slots, padded=0)
+        rec = {"case": case.name, "rows": rows,
+               "ms": _time_step(case, dry, n),
+               "bytes_ms": 2.0 * H * P * N * 4 * rows / HBM_BYTES_PER_S * 1e3}
+        rec["roofline_share"] = rec["bytes_ms"] / rec["ms"]
+        print(json.dumps(rec), flush=True)
+        out.append(rec)
+        rows *= 2
     return out
 
 

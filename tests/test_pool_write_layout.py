@@ -207,3 +207,55 @@ def test_lfm2_decode_program_compiles_and_copies_no_pool_leaf(topo):
     mem = compiled.memory_analysis()
     assert mem.argument_size_in_bytes == pytest.approx(12.05e9, rel=1e-3)
     assert mem.temp_size_in_bytes < 2 ** 28
+
+
+def test_nemotron_decode_program_steps_each_arena_in_place(topo):
+    """Nemotron-3-Nano's stage at the cell's sizes (128 rows, 16,704
+    blocks, 64 of 128 experts held): the compiled decode program holds ONE
+    custom call named ``ssm_decode_step`` a mixer block, each with a state
+    arena as an operand ALIASED to its output, and nothing else yields an
+    arena-sized array but the arenas coming in: no copy, gather or scatter
+    of 129 slots of 2 MiB. ``ssm_decode_share.ssm`` and
+    ``ssm_decode_hbm_roofline.ssm`` read the device's trace by that one
+    name, and price a row's state read and written once."""
+    from scalable_hw_agnostic_inference_tpu.models.llama import (
+        cache_leaves,
+        state_leaves,
+    )
+
+    cfg = LlamaConfig.nemotron3_nano_stage()
+    n_blocks, M, B = 16704, 130, 128
+    mixers = len(cfg.state_layers)
+    _, rep = _shardings(topo, cfg, 1)
+    s = lambda shape, dt: SDS(shape, dt, sharding=rep)    # noqa: E731
+    params = jax.tree.map(lambda a: s(a.shape, a.dtype),
+                          jax.eval_shape(lambda: geometry_params(cfg)))
+    leaf = {n: s((n_blocks, BLOCK) + per, jnp.bfloat16)
+            for n, per in cache_leaves(cfg).items()}
+    arena = {n: s((B + 1,) + tuple(shp), jnp.dtype(dt or jnp.bfloat16))
+             for n, (shp, dt) in state_leaves(cfg).items()}
+    assert arena["s"].shape == (129, 64, 64, 128) and mixers == 4
+    assert arena["s"].dtype == jnp.float32
+    kv = [dict(arena if pi in cfg.state_layers else leaf) for pi in range(5)]
+    with topo.platform_override("tpu"):
+        lowered = runner.make_decode(
+            cfg, BLOCK, M, B, paged=True, feedback=True).lower(
+            params, kv, s((B,), jnp.int32), s((B,), jnp.int32),
+            s((B, M), jnp.int32), s((B,), jnp.float32),
+            s((2,), jnp.uint32), s((), jnp.int32), s((B,), jnp.float32),
+            s((B,), jnp.int32), s((B,), jnp.float32), s((B,), jnp.int32))
+    compiled = lowered.compile()
+    calls = [line for line in compiled.as_text().splitlines()
+             if "tpu_custom_call" in line
+             and re.search(r'op_name="[^"]*ssm_decode_step', line)]
+    assert len(calls) == mixers, len(calls)
+    for line in calls:
+        # output 1 of (y, arena) IS an operand, and it is the one arena
+        assert re.search(r"output_to_operand_aliasing=\{\{1\}: \(\d+, ", line)
+        result = line.split(" custom-call(")[0]
+        assert result.count("f32[129,64,64,128]") == 1, line[:300]
+    found = pool_sized(compiled, 129 * 64 * 64 * 128)
+    moved = [line for op, line in found
+             if op not in ("parameter", "bitcast", "get-tuple-element")]
+    assert not moved, "\n".join(moved)
+    assert sum(op == "parameter" for op, _ in found) == mixers

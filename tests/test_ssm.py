@@ -292,22 +292,53 @@ def test_the_cases_cover_both_kernels_and_the_null_slot():
         64, 64, 128, 8, prefill_rows=4, max_num_seqs=128)]
     assert names == ["ssm-chunk-H64x64x128-T512-b4",
                      "ssm-step-H64x64x128-b4-S128",
-                     "ssm-step-H64x64x128-b128-S128"]
-    *_, arena, ids = SSM_CASES[-1].make_inputs(jax.random.PRNGKey(0))
+                     "ssm-step-H64x64x128-b128-S128",
+                     "ssm-step-H64x64x128-b1-S128-pad0",
+                     "ssm-step-H64x64x128-b128-S128-pad64"]
+    *_, arena, ids = SSM_CASES[2].make_inputs(jax.random.PRNGKey(0))
     assert arena.shape[0] == 7 and list(np.asarray(ids[-2:])) == [6, 6]
+    *_, ids = SSM_CASES[3].make_inputs(jax.random.PRNGKey(0))
+    assert ids.shape == (1,) and int(ids[0]) < 6
+    *_, ids = SSM_CASES[4].make_inputs(jax.random.PRNGKey(0))
+    assert list(np.asarray(ids[3:])) == [6, 6, 6]
 
 
-def test_the_step_kernel_leaves_every_other_slot_alone():
+@pytest.mark.parametrize("rows,slots", [
+    (6, [5, 1, 8, 3, 11, 11]),         # the last two padded
+    (1, [3]),                          # a bucket of ONE row
+    (1, [11]),                         # ... that is padded
+    (6, [9, 2, 6, 11, 11, 11]),        # half the rows padded, all the null
+    (4, [11, 11, 11, 11]),             # nobody live
+    (7, [9, 2, 6, 0, 4, 10, 7]),       # neither sorted nor adjacent
+    (5, [11, 4, 11, 0, 8]),            # padded rows BETWEEN live ones
+], ids=["two-padded", "one-row", "one-padded-row", "half-padded", "all-padded",
+        "scattered", "padded-between"])
+def test_the_step_kernel_leaves_every_other_slot_alone(rows, slots):
+    """Against ``ssm.step_slots(kernel=False)`` within ``TOL_SSM``: the live
+    rows' outputs, their slots' states, and every slot no row names (the
+    null slot is nobody's)."""
     from scalable_hw_agnostic_inference_tpu.ops.pallas.ssm_step import (
         ssm_decode_step,
     )
 
-    *ops, arena, ids = SSM_CASES[-1].make_inputs(jax.random.PRNGKey(4))
-    _, after = ssm_decode_step(*ops, arena, ids, interpret=True)
+    k0, k1 = jax.random.split(jax.random.PRNGKey(4 + rows))
+    ops = [a[:, 0] for a in kernel_check._ssm_operands(
+        k0, rows, 1, 4, 16, 32, 2)]
+    arena = jax.random.normal(k1, (12, 4, 16, 32))
+    ids = jnp.asarray(slots, jnp.int32)
+    null = arena.shape[0] - 1
+    y, after = ssm_decode_step(*ops, arena, ids, interpret=True)
+    want_y, want = ssm.step_slots(*ops, arena, ids, kernel=False)
     named = set(np.asarray(ids).tolist())
-    for slot in range(arena.shape[0] - 1):
+    for slot in range(null):
         same = np.array_equal(np.asarray(after[slot]), np.asarray(arena[slot]))
         assert same == (slot not in named), slot
+    live = np.asarray(ids) != null
+    np.testing.assert_allclose(np.asarray(after[:null]),
+                               np.asarray(want[:null]),
+                               atol=kernel_check.TOL_SSM, rtol=0)
+    np.testing.assert_allclose(np.asarray(y)[live], np.asarray(want_y)[live],
+                               atol=kernel_check.TOL_SSM, rtol=0)
 
 
 def test_the_mixer_is_transformers_mamba2_torch_path(tiny_params):
