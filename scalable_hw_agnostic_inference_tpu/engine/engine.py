@@ -41,6 +41,7 @@ from .resident import (
     InflightStep,
     ResidentBatch,
     composition_sig,
+    feed_first_tokens,
 )
 from .runner import FOLD_STRIDE, make_decode, make_prefill
 from .types import (  # noqa: F401  (re-exported: public engine API)
@@ -320,6 +321,13 @@ class LLMEngine:
         self._fanout_groups: Dict[int, set] = {}
         self._rid_parent: Dict[int, int] = {}
         self._sample1 = jax.jit(sample_logits)
+        # an admission's sampled tokens into their rows of the next decode
+        # step's token input, on the device (``_decode_dispatch``); placed
+        # like every other step input, so the decode call reshards nothing
+        self._feed1 = jax.jit(
+            feed_first_tokens,
+            out_shardings=(None if self.shardings is None
+                           else self.shardings.rep))
         from .runner import token_logprobs
 
         self._lp1 = jax.jit(token_logprobs)  # prefill-logit logprob readout
@@ -446,8 +454,9 @@ class LLMEngine:
         self._async = _resolve_async()
         self._pipe: Optional[InflightStep] = None
         # admissions' first tokens still on the device (at most a final
-        # chunk's and an admission's, both of one step): read where the
-        # next dispatch needs them, ``_resolve_first_tokens``
+        # chunk's and an admission's, both of one step): fed to the next
+        # decode dispatch there and read behind it,
+        # ``_resolve_first_tokens``
         self._first: List[FirstTokens] = []
         self._res = ResidentBatch()
         self._t_fetch = 0.0          # return of the last blocking read
@@ -1010,12 +1019,18 @@ class LLMEngine:
     # still runs, and the flush that follows reads N while THEY run. The
     # admission's first tokens stay on the device (``FirstTokens``), its
     # rows are seated unresolved, and ``_decode_dispatch`` grows, marshals
-    # the new composition and fills positions before the one read that
-    # resolves them (``_resolve_first_tokens``). Whoever reads a running
-    # row's ``pending_token`` outside that order (abort, migration,
-    # snapshot, preemption, the drafter) flushes and resolves for itself;
-    # both are no-ops with nothing in flight. A due deadline keeps the
-    # flush first: ``_expire_deadlines`` tears rows down.
+    # the new composition, fills positions and the tokens the host holds,
+    # writes the sampler's output into the new rows of that token input ON
+    # the device (``_feed1``) and dispatches the decode step: it is queued
+    # behind the program and the sampler with no host read between them.
+    # The one read that resolves the first tokens
+    # (``_resolve_first_tokens``) follows the dispatch and returns while
+    # the step runs; the commit behind it streams them. Whoever reads a
+    # running row's ``pending_token`` outside that order (abort,
+    # migration, snapshot, preemption, the drafter) flushes and resolves
+    # for itself, and the dispatch then finds nothing to feed; both are
+    # no-ops with nothing in flight. A due deadline keeps the flush first:
+    # ``_expire_deadlines`` tears rows down.
     #
     # A request that WAITS is not such an event; a request that can be
     # ADMITTED is. The gate is ``_can_admit`` (a waiter and a free slot),
@@ -1029,8 +1044,11 @@ class LLMEngine:
     # Token-exactness vs the lock-step oracle holds by construction: the
     # dispatch composition, batch-row packing, and rng folds of step k are
     # all functions of state known BEFORE step k-1's readback (a finishing
-    # slot participates in exactly one extra dispatch in both disciplines),
-    # so pipelining only reorders host work, never device inputs.
+    # slot participates in exactly one extra dispatch in both disciplines,
+    # a row whose FIRST token ends it included: its commit stands behind
+    # the dispatch either way), and a fed row's token input is the value
+    # the host would have put, so pipelining only reorders host work,
+    # never device inputs.
 
     def _step_async(self, t0: float) -> List[Finished]:
         self._step_count += 1
@@ -1177,12 +1195,20 @@ class LLMEngine:
         retired) with the readback DEFERRED to the next step —
         re-establishes the pipeline in the same call that handled the
         event. Everything but the new rows' first tokens is known the
-        moment the admission is decided, so the grow, the new
-        composition's marshal and put, and the positions are done while
-        the admission's program runs; the first tokens are read last."""
+        moment the admission is decided, and those are on the device
+        (``FirstTokens``): the grow, the new composition's marshal, the
+        positions and the one put are done while the admission's program
+        runs, the sampler's output is written into the token input ON the
+        device (``_feed1``, once a record), and the decode step is queued
+        behind the program and the sampler with no host read between
+        them. The first tokens are read AFTER the dispatch, while the
+        step runs, and committed then."""
         self.obs.phase_enter("engine.marshal")
+        met = bool(self._first)
         if self._drafter is not None and self._spec_step():
             self._step_kind = "spec"
+            if met:     # the drafter read them first
+                self.obs.count_first_tokens(fed=False)
             return
         self._step_kind = "decode"
         self._grow_running(lambda s: 1)
@@ -1197,13 +1223,29 @@ class LLMEngine:
         pos = np.zeros((Bb,), np.int32)
         for i, s in enumerate(running):
             pos[i] = self.cache.seq(s.req.req_id).n_tokens - 1
-        self._resolve_first_tokens()
-        for i, s in enumerate(running):
-            tokens[i] = s.pending_token
-        tokens_dev, pos_dev, fold = self._put_step(
-            (tokens, pos, self._fold()))
+            # a row that waits in ``_first`` keeps the placeholder
+            tokens[i] = max(s.pending_token, 0)
+        # each waiting record's batch rows, by row of its ``toks`` (a dummy
+        # row of the sampler's points past the batch). None waits where a
+        # preemption inside the grow read a ``pending_token`` on the way:
+        # it resolved for itself and the host fill covered every row
+        dsts = []
+        if self._first:
+            row_of = {s.slot: i for i, s in enumerate(running)}
+            for rec in self._first:
+                dst = np.full(rec.toks.shape, Bb, np.int32)
+                for src, s in rec.rows:
+                    dst[src] = row_of[s.slot]
+                dsts.append(dst)
+        tokens_dev, pos_dev, fold, *dsts = self._put_step(
+            (tokens, pos, self._fold(), *dsts))
+        for rec, dst in zip(self._first, dsts):
+            tokens_dev = self._feed1(tokens_dev, dst, rec.toks)
         self._dispatch_async(decode, running, Bb, tokens_dev, pos_dev, a,
                              fold, gap_ok=self.n_executables == n_exec)
+        if met:
+            self.obs.count_first_tokens(fed=bool(self._first))
+        self._resolve_first_tokens()    # returns while the step runs
         self._commit_pending(running)
 
     def _dispatch_async(self, decode, running, Bb: int, tokens_dev,
@@ -1236,9 +1278,11 @@ class LLMEngine:
             # flush/cold step: how long the device had nothing queued
             # before this dispatch. Nothing, where a program of this event
             # step still runs; else no longer than since the last blocking
-            # read returned (the first tokens', or with none the
-            # lookahead's): that read found the device drained or left one
-            # short program behind it
+            # read returned (the lookahead's; the first tokens' only where
+            # a drafter or a preemption read them on the way): that read
+            # found the device drained or left one short program behind
+            # it. An upper bound, and a loose one on a fed step that finds
+            # the program just ended: the whole marshal lies in it
             self.obs.step_gap.observe(
                 0.0 if queued else max(0.0, t_d - self._t_fetch))
         self._last_decode_step = self._step_count
@@ -1287,8 +1331,9 @@ class LLMEngine:
         """THE end of every admission rung and of the final continuation
         chunk: ``toks``, the sampler's output, stays on the device, and
         ``rows`` ((row of ``toks``, the ``_Running`` just seated with an
-        unresolved token)) wait for ``_resolve_first_tokens``. The
-        lock-step oracle reads where it samples."""
+        unresolved token)) wait there: the next decode dispatch feeds
+        them to its step on the device and ``_resolve_first_tokens`` reads
+        them behind it. The lock-step oracle reads where it samples."""
         want_lp = any(s.req.params.logprobs for _, s in rows)
         self._first.append(
             FirstTokens(rows, toks, logits if want_lp else None))
@@ -1297,10 +1342,11 @@ class LLMEngine:
 
     def _resolve_first_tokens(self) -> None:
         """Read the admissions' first tokens back (no-op when none wait):
-        the other blocking read of the async loop, made where the token is
-        needed. ``_decode_dispatch`` calls it between its marshal and its
-        token input; every other reader of a running row's
-        ``pending_token`` before it reads. TTFT and ``t_first`` are
+        the other blocking read of the async loop, made where the HOST
+        needs the token. ``_decode_dispatch`` calls it behind its
+        dispatch (the step took the tokens on the device), so the read
+        returns while the step runs; every other reader of a running
+        row's ``pending_token`` before it reads. TTFT and ``t_first`` are
         stamped here, where the token exists on the host."""
         if not self._first:
             return

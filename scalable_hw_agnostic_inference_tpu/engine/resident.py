@@ -30,9 +30,11 @@ holds none; ``_put_step`` is the same put, counted as
 
 * :class:`FirstTokens` records one admission's sampled first tokens, left
   on the device behind the prefill (or final continuation) program that
-  made them: the rows are seated at once with an unresolved token, and
-  the engine reads the record where the token is NEEDED (the next decode
-  dispatch's token input, after its marshal), not where it is made.
+  made them: the rows are seated at once with an unresolved token, the
+  next decode dispatch writes the record's tokens into its token input
+  ON the device (:func:`feed_first_tokens`) and goes out, and the engine
+  reads the record behind that dispatch, where the HOST needs the token
+  (the commit that streams it), not where it is made.
 
   Retiring the lookahead and resolving the first tokens are the ONLY two
   places the async loop blocks on the device.
@@ -92,6 +94,14 @@ class FirstTokens:
     rows: List[Tuple[int, Any]]       # (row of ``toks``, the seated _Running)
     toks: Any                         # device [K] sampled tokens
     logits: Optional[Any]             # kept only where a row wants logprobs
+
+
+def feed_first_tokens(tokens, dst, toks):
+    """A decode step's token input with one record's sampled tokens
+    written into their batch rows, traced under ``jax.jit``: ``dst[i]`` is
+    the batch row of ``toks[i]``; a dummy row of the sampler's points past
+    the batch and is dropped."""
+    return tokens.at[dst].set(toks, mode="drop")
 
 
 class ResidentBatch:

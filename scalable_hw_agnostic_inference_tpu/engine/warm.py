@@ -104,6 +104,10 @@ def _run_warm_calls(eng) -> None:
         """A recurrent model's warm rows write the arena's null slot."""
         return eng._slot_args([eng._null_slot] * n)
 
+    #: sampler rows -> one sampler output of that many: what the feed
+    #: program is warmed on, below
+    sampled = {}
+
     def warm_sampler(logits, per_row: bool) -> None:
         """The admission-time sampler and the first token's logprob readout
         are part of the closed set too. They are plain jits, keyed on what
@@ -114,10 +118,11 @@ def _run_warm_calls(eng) -> None:
         K = logits.shape[0]
         key = eng._admit_rng()  # the eager fold is warmed by being made
         if per_row:  # _admit_batch / _admit_fanout: per-row knob arrays
-            eng._sample1(logits, key, ones((K,), np.float32), zeros((K,)),
-                         ones((K,), np.float32))
+            sampled[K] = eng._sample1(
+                logits, key, ones((K,), np.float32), zeros((K,)),
+                ones((K,), np.float32))
         if K == 1:   # _admit_one, prefix and continuation: scalar knobs
-            eng._sample1(logits, key, 1.0, 0, 1.0)
+            sampled[K] = eng._sample1(logits, key, 1.0, 0, 1.0)
         jax.block_until_ready(
             eng._lp1(logits, zeros((K,))))
 
@@ -171,6 +176,12 @@ def _run_warm_calls(eng) -> None:
             args[1:4] = [eng.cache.kv, nxt, rest[0]]
             args[7] = rest[1]
             eng.cache.kv, nxt, *rest = fn(*args)
+            # an event step writes an admission's sampled tokens into this
+            # bucket's token input on the device (``_decode_dispatch``):
+            # one tiny program a (decode bucket, sampler rows) pair, warmed
+            # on the sampler's OWN output for the same reason
+            for K, toks in sampled.items():
+                eng._feed1(zeros((bb,)), zeros((K,)), toks)
         nxt.block_until_ready()
     K = eng.ecfg.num_speculative_tokens
     for bb, fn in list(eng._verify_fns.items()):

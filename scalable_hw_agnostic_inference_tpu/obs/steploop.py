@@ -533,6 +533,13 @@ class StepTelemetry:
         # step while the program ran, and drained nothing
         self.events_dispatched_ahead = 0
         self._ahead_reasons: Dict[str, int] = {}
+        # event steps whose decode dispatch met admissions' first tokens
+        # still on the device, and those of them whose decode step went
+        # out BEFORE the tokens were read (fed on the device). Equal where
+        # nothing reads a pending token on the way (no drafter, no
+        # preemption inside the grow)
+        self.first_token_events = 0
+        self.first_token_events_fed = 0
         # pad-waste accounting: per dispatch, how many token slots the
         # executable walked for REAL context vs shape padding (batch pad
         # rows + the paged kernel's tiles beyond each row's live tokens +
@@ -624,6 +631,15 @@ class StepTelemetry:
             self.events_dispatched_ahead += 1
             self._ahead_reasons[reason] = (
                 self._ahead_reasons.get(reason, 0) + 1)
+
+    def count_first_tokens(self, fed: bool) -> None:
+        """One event step whose decode dispatch met first tokens still on
+        the device; ``fed``: its decode step went out before their read.
+        Both move under one lock, so no snapshot reads one without the
+        other."""
+        with self._lock:
+            self.first_token_events += 1
+            self.first_token_events_fed += int(fed)
 
     # -- phases of the engine-loop thread -----------------------------------
 
@@ -1064,6 +1080,8 @@ class StepTelemetry:
                 "kv_blocks_total": self.total_blocks,
                 "pipeline_flushes": self.pipeline_flushes,
                 "events_dispatched_ahead": self.events_dispatched_ahead,
+                "first_token_events": self.first_token_events,
+                "first_token_events_fed": self.first_token_events_fed,
                 "decode_input_uploads": self.decode_input_uploads,
                 "tokens_committed": self.tokens_committed,
                 "pad_tokens": self.pad_tokens,

@@ -121,6 +121,13 @@ _ENGINE_COUNTERS = {
                                 "Event steps whose prefill or continuation "
                                 "program was queued while a decode step was "
                                 "still in flight"),
+    "first_token_events": ("shai_engine_first_token_events",
+                           "Event steps whose decode dispatch met an "
+                           "admission's first tokens still on the device"),
+    "first_token_events_fed": ("shai_engine_first_token_events_fed",
+                               "Those of them whose decode step was "
+                               "dispatched before the first tokens were "
+                               "read (fed on the device)"),
     "decode_input_uploads": ("shai_engine_decode_input_uploads",
                              "Host-to-device arrays put for decode, verify "
                              "and fused dispatches (a block-table refresh "

@@ -142,6 +142,91 @@ def test_no_step_argument_is_resharded_on_a_mesh(tiny_model, monkeypatch):
         assert n == 0
 
 
+def test_fed_first_tokens_are_replicated_over_four_devices(monkeypatch):
+    """Tensor parallel 4 on the host mesh: the token input an event step
+    builds on the device (the sampler's output written into the rows that
+    wait for it) carries the engine's replicated sharding, it IS the
+    array the decode call takes, and that call reshards nothing."""
+    import dataclasses
+
+    import jax._src.array as jarray
+
+    cfg = dataclasses.replace(LlamaConfig.tiny(), n_kv_heads=4)
+    params = LlamaForCausalLM(cfg, dtype=jnp.float32).init(
+        jax.random.PRNGKey(0), jnp.zeros((1, 8), jnp.int32))
+    eng = make_engine((cfg, params), True, monkeypatch, tp=4,
+                      max_model_len=128, max_new_tokens=64)
+    assert len(eng.shardings.rep.device_set) == 4
+    eng.warm_executables()
+    resharded, fed, decodes = [], [], []
+    real = jarray.shard_device_array
+    monkeypatch.setattr(
+        jarray, "shard_device_array",
+        lambda x, *a, **k: resharded.append(x.shape) or real(x, *a, **k))
+    feed = eng._feed1
+    monkeypatch.setattr(
+        eng, "_feed1", lambda *a: fed.append(feed(*a)) or fed[-1])
+    record_calls(eng._decode_fns, decodes, lambda: len(resharded))
+    sp = SamplingParams(temperature=0.8, top_k=8, max_new_tokens=12)
+    eng.add_request([1, 17, 42, 99, 7], sp)
+    for _ in range(3):
+        eng.step()
+    eng.add_request([3, 5, 8], sp)
+    eng.add_request(list(range(2, 50)), sp)   # its final chunk feeds too
+    while eng.has_work:
+        eng.step()
+    assert len(fed) >= 3
+    rep = eng.shardings.rep
+    for tokens in fed:
+        assert tokens.sharding.is_equivalent_to(rep, tokens.ndim)
+        assert tokens.committed
+    taken = {id(args[2]) for _, args, _ in decodes}
+    assert {id(t) for t in fed} <= taken
+    for _, args, n in decodes:
+        assert_placed(eng, args, 9)
+        assert n == 0
+    snap = eng.obs.snapshot()
+    assert snap["first_token_events_fed"] == snap["first_token_events"] \
+        == len(fed)
+
+
+@pytest.mark.parametrize("tp", [0, 2], ids=["one-device", "tp2"])
+def test_an_admission_step_compiles_nothing_after_warm_up(tiny_model,
+                                                          monkeypatch, tp):
+    """The feed program is part of the closed set: one (decode bucket,
+    sampler rows) pair each, warmed on the sampler's own output. After
+    ``warm_executables`` admissions of every batch size and a long
+    prompt's final chunk, joining every decode bucket, compile nothing:
+    XLA is asked for no program and ``recompiles`` stays 0."""
+    eng = make_engine(tiny_model, True, monkeypatch, tp=tp,
+                      max_model_len=128, max_num_seqs=4)
+    eng.warm_executables()
+    compiled = []
+
+    def listener(event, secs, fun_name="", **kw):
+        if event == "/jax/core/compile/backend_compile_duration":
+            compiled.append(fun_name)
+
+    jax.monitoring.register_event_duration_secs_listener(listener)
+    try:
+        sp = SamplingParams(temperature=0.7, top_k=6, max_new_tokens=10)
+        arrivals = {0: [[1, 2, 3]], 2: [[4, 5], [6, 7, 8]],
+                    4: [list(range(2, 50))], 14: [[9]] * 4}
+        step = 0
+        while eng.has_work or step <= max(arrivals):
+            for prompt in arrivals.get(step, ()):
+                eng.add_request(prompt, sp)
+            if eng.has_work:
+                eng.step()
+            step += 1
+    finally:
+        jax.monitoring.unregister_event_duration_listener(listener)
+    snap = eng.obs.snapshot()
+    assert snap["first_token_events_fed"] == snap["first_token_events"] >= 4
+    assert snap["recompiles"] == 0
+    assert not compiled, compiled
+
+
 # ---------------------------------------------------------------------------
 # the rng
 # ---------------------------------------------------------------------------
@@ -244,13 +329,14 @@ def test_a_steady_step_launches_one_program(tiny_model, monkeypatch):
 
 def test_uploads_are_counted_on_the_snapshot(tiny_model, monkeypatch):
     """``decode_input_uploads``: an event step puts the composition's arrays,
-    its tokens, positions and fold index; the lock-step discipline puts all
-    of them every step."""
+    its tokens, positions and fold index and, in the same transfer, one
+    array of batch rows for each record of first tokens it feeds on the
+    device; the lock-step discipline puts all of a step's every step."""
     eng = make_engine(tiny_model, True, monkeypatch)
     eng.add_request([1, 2, 3], SamplingParams(max_new_tokens=4))
     eng.step()
     n_arrays = len(eng._res.arrays)
-    assert eng.obs.snapshot()["decode_input_uploads"] == n_arrays + 3
+    assert eng.obs.snapshot()["decode_input_uploads"] == n_arrays + 3 + 1
     lock = make_engine(tiny_model, False, monkeypatch)
     lock.generate([[1, 2, 3]], SamplingParams(max_new_tokens=4))
     snap = lock.obs.snapshot()

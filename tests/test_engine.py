@@ -337,7 +337,8 @@ def test_engine_warm_executables_closed_set(tiny_model):
 
 
 ENGINE_FNS = {f"jit({n})" for n in (
-    "prefill", "cont", "decode", "sample_logits", "token_logprobs")}
+    "prefill", "cont", "decode", "sample_logits", "token_logprobs",
+    "feed_first_tokens")}
 
 
 @contextlib.contextmanager

@@ -728,12 +728,16 @@ def test_a_request_submitted_while_a_step_runs_waits_at_intake(
 # ---------------------------------------------------------------------------
 
 def record_order(eng, monkeypatch):
-    """Recording wrappers round the step programs, the sampler and the
-    loop's reads: the returned list holds, in call order, ``prefill``,
-    ``cont`` and ``decode`` (a program's dispatch), ``sample``, ``retire``
-    (the lookahead's read), ``marshal`` (a full ``_marshal_running``) and
-    ``first_read`` (a ``_resolve_first_tokens`` with something to read)."""
+    """Recording wrappers round the step programs, the sampler, the feed
+    program and the loop's reads: the returned list holds, in call order,
+    ``prefill``, ``cont`` and ``decode`` (a program's dispatch), ``sample``,
+    ``feed`` (a record's sampled tokens written into the token input on the
+    device), ``retire`` (the lookahead's read), ``marshal`` (a full
+    ``_marshal_running``), ``first_read`` (a ``_resolve_first_tokens`` with
+    something to read) and ``read`` (a ``jax.device_get`` or an
+    ``np.asarray`` of a device array anywhere but inside ``retire``)."""
     log = []
+    retiring = []
 
     def noting(name, fn):
         def run(*args, **kw):
@@ -741,9 +745,38 @@ def record_order(eng, monkeypatch):
             return fn(*args, **kw)
         return run
 
-    for attr, name in (("_sample1", "sample"), ("_retire_pipe", "retire"),
+    for attr, name in (("_sample1", "sample"), ("_feed1", "feed"),
                        ("_marshal_running", "marshal")):
         monkeypatch.setattr(eng, attr, noting(name, getattr(eng, attr)))
+    retire = eng._retire_pipe
+
+    def noted_retire(pipe):
+        log.append("retire")
+        retiring.append(1)
+        try:
+            return retire(pipe)
+        finally:
+            retiring.pop()
+
+    monkeypatch.setattr(eng, "_retire_pipe", noted_retire)
+    device_get, asarray = jax.device_get, np.asarray
+
+    def noted_get(x):
+        if not retiring:
+            log.append("read")
+        retiring.append(1)          # its own ``np.asarray`` is the same read
+        try:
+            return device_get(x)
+        finally:
+            retiring.pop()
+
+    def noted_asarray(x, *args, **kw):
+        if isinstance(x, jax.Array) and not retiring:
+            log.append("read")
+        return asarray(x, *args, **kw)
+
+    monkeypatch.setattr(jax, "device_get", noted_get)
+    monkeypatch.setattr(np, "asarray", noted_asarray)
 
     def note_programs(attr, name):
         """``attr`` hands out a program, or (batch bucket, program)."""
@@ -776,7 +809,8 @@ ORDER_CASES = {
     # arrive for it, the step's calls in order, its reason)
     "batch-admission": (
         None, [[8, 8, 9], [5, 6]],
-        ["prefill", "sample", "retire", "marshal", "first_read", "decode"],
+        ["prefill", "sample", "retire", "marshal", "feed", "decode",
+         "first_read", "read"],
         "admission"),
     "long-prompt-admission": (
         None, [LONG], ["prefill", "retire", "decode"], "admission"),
@@ -784,12 +818,13 @@ ORDER_CASES = {
         0, [], ["cont", "retire", "decode"], "chunking"),
     "final-chunk": (
         1, [],
-        ["cont", "sample", "retire", "marshal", "first_read", "decode"],
+        ["cont", "sample", "retire", "marshal", "feed", "decode",
+         "first_read", "read"],
         "chunking"),
     "admission-beside-a-final-chunk": (
         1, [[8, 8, 9]],
         ["cont", "sample", "prefill", "sample", "retire", "marshal",
-         "first_read", "decode"],
+         "feed", "feed", "decode", "first_read", "read"],
         "admission"),
 }
 
@@ -798,9 +833,13 @@ ORDER_CASES = {
 def test_an_event_step_dispatches_before_it_reads(tiny_model, monkeypatch,
                                                   case):
     """With a lookahead in flight, an event step queues its prefill or
-    continuation program and the sampler BEFORE step N is read, marshals
-    the new composition before it reads the first tokens, and counts
-    itself in ``events_dispatched_ahead``."""
+    continuation program and the sampler BEFORE step N is read, writes the
+    sampler's output into the decode step's token input on the device
+    (once a record) and dispatches the decode step BEFORE it reads the
+    first tokens: between the sampler's dispatch and the decode's the host
+    reads step N (``retire``) and nothing else. It counts itself in
+    ``events_dispatched_ahead`` and, where first tokens waited, in
+    ``first_token_events`` and ``first_token_events_fed``."""
     chunks_before, arrivals, want, reason = ORDER_CASES[case]
     eng = make_engine(tiny_model, True, monkeypatch, max_model_len=128)
     sp = SamplingParams(temperature=0.0, max_new_tokens=40)
@@ -823,7 +862,13 @@ def test_an_event_step_dispatches_before_it_reads(tiny_model, monkeypatch,
     last_queued = max(i for i, c in enumerate(log)
                       if c in ("prefill", "cont", "sample"))
     assert last_queued < log.index("retire")
+    # no read of the host's but step N's stands before the decode dispatch
+    assert "read" not in log[:log.index("decode")]
     snap = eng.obs.snapshot()
+    met = 1 if "first_read" in log else 0
+    assert log.count("feed") == log.count("sample")
+    for key in ("first_token_events", "first_token_events_fed"):
+        assert snap[key] == before[key] + met
     assert snap["events_dispatched_ahead"] \
         == before["events_dispatched_ahead"] + 1
     assert snap["ahead_by_reason"].get(reason, 0) \
@@ -847,11 +892,18 @@ def _run_timed(eng, schedule, sp_of, kw_of=lambda i: {}):
     step = 0
     while True:
         for prompt in schedule.get(step, ()):
-            i = len(index_of)
-            rid = eng.add_request(
-                prompt, sp_of(i),
-                on_token=lambda t, i=i: events.append((i, t)), **kw_of(i))
-            index_of[rid] = i
+            # ``(prompt, n)``: a fan-out group of n siblings (SHAI_KV_COW)
+            prompt, n = prompt if isinstance(prompt, tuple) else (prompt, 1)
+            parent = -2
+            for _ in range(n):
+                i = len(index_of)
+                kw = dict(kw_of(i), parent_rid=parent) if n > 1 \
+                    else kw_of(i)
+                rid = eng.add_request(
+                    prompt, sp_of(i),
+                    on_token=lambda t, i=i: events.append((i, t)), **kw)
+                index_of[rid] = i
+                parent = rid if parent == -2 else parent
         if eng.has_work:
             for f in eng.step():
                 fins[index_of[f.req_id]] = f
@@ -889,7 +941,36 @@ SEAM_CASES = {
     "preempted-in-the-step-that-admits": dict(
         schedule={0: [[11, 7, 7, 7], [12, 7, 7, 7]], 4: [[13] + [9] * 9]},
         sp=dict(max_new_tokens=14), over=dict(num_blocks=6), preempts=True),
+    # ``records``: the rows of each ``FirstTokens`` record that the widest
+    # event step's decode dispatch met still on the device
+    "two-records-in-one-step": dict(     # a final chunk's, then a batch's
+        schedule={0: [[3, 4, 5]], 3: [LONG], 5: [[42, 43], [8, 8, 9]]},
+        over=dict(max_num_seqs=4), records=[1, 2]),
+    "fan-out-of-three": dict(
+        schedule={0: [[3, 4, 5]], 4: [([8, 8, 9], 3)]}, cow=True,
+        sp=dict(temperature=0.9, top_k=5), over=dict(max_num_seqs=4),
+        records=[3]),
+    "batch-of-four-one-ends-at-its-first-token": dict(
+        schedule={0: [[3, 4, 5]], 4: [[8, 8, 9], [5, 6], [7, 7, 7], [9, 1]]},
+        sp_of={3: dict(max_new_tokens=1)}, over=dict(max_num_seqs=5),
+        records=[4]),
+    "recurrent-state": dict(
+        model="tiny_ssm",
+        schedule={0: [[3, 4, 5, 6]], 4: [[8, 8, 9], [5, 6, 7, 7, 2]],
+                  6: [[1] + [7, 9] * 20]},       # 41 tokens: two programs
+        sp=dict(max_new_tokens=7), records=[2]),
 }
+
+
+def _model_of(tiny_model, spec):
+    if "model" not in spec:
+        return tiny_model
+    from scalable_hw_agnostic_inference_tpu.models.llama import (
+        geometry_params,
+    )
+
+    cfg = getattr(LlamaConfig, spec["model"])()
+    return cfg, geometry_params(cfg, dtype=jnp.float32, seed=3)
 
 
 @pytest.mark.parametrize("case", sorted(SEAM_CASES))
@@ -906,23 +987,49 @@ def test_unresolved_first_tokens_match_lockstep(tiny_model, monkeypatch,
                                           spec["eos_of"])
     over = dict(max_model_len=128)
     over.update(spec.get("over", {}))
+    monkeypatch.setenv("SHAI_KV_COW", "1" if spec.get("cow") else "0")
+    model = _model_of(tiny_model, spec)
     out, snaps = {}, {}
     for mode in (True, False):
-        eng = make_engine(tiny_model, mode, monkeypatch, **over)
+        eng = make_engine(model, mode, monkeypatch, **over)
         preempt, unresolved = eng._preempt_lowest, []
         monkeypatch.setattr(
             eng, "_preempt_lowest",
             lambda: (unresolved.append(bool(eng._first)), preempt())[1])
-        out[mode] = _run_timed(eng, spec.get("schedule", JOIN),
-                               lambda i: SamplingParams(**knobs))
+        dispatch, met = eng._decode_dispatch, []
+        monkeypatch.setattr(
+            eng, "_decode_dispatch",
+            lambda: (met.append([len(r.rows) for r in eng._first]),
+                     dispatch())[1])
+        out[mode] = _run_timed(
+            eng, spec.get("schedule", JOIN),
+            lambda i: SamplingParams(
+                **dict(knobs, **spec.get("sp_of", {}).get(i, {}))))
         if mode and spec.get("preempts"):
             # the victim was the row this step admitted, token unread
             assert any(unresolved)
         snaps[mode] = eng.obs.snapshot()
         assert pool_balanced(eng) and not eng._first
+        if mode and "records" in spec:
+            assert max(met, key=lambda m: (len(m), sum(m))) \
+                == spec["records"]
+        # every dispatch that met first tokens fed them on the device,
+        # but where a preemption read them on the way; the oracle reads
+        # where it samples and meets none
+        events = sum(1 for m in met if m)
+        assert snaps[mode]["first_token_events"] == events
+        assert events - sum(unresolved) \
+            <= snaps[mode]["first_token_events_fed"] <= events
     _assert_same_timed(out[True], out[False])
     assert snaps[True]["events_dispatched_ahead"] >= 1
     assert snaps[False]["events_dispatched_ahead"] == 0
+    assert snaps[True]["first_token_events_fed"] >= 1
+    if not spec.get("preempts"):
+        assert snaps[True]["first_token_events_fed"] \
+            == snaps[True]["first_token_events"]
+    if "sp_of" in spec:
+        (i, _), = spec["sp_of"].items()
+        assert len(out[True][0][i].token_ids) == 1
     if "eos_of" in spec:
         assert out[True][0][1].stop_reason == "eos"
         assert out[True][0][1].token_ids == []

@@ -335,6 +335,44 @@ def test_events_dispatched_ahead_ride_the_snapshot_by_reason():
     assert snap["pipeline_flushes"] == 0      # a flush is counted apart
 
 
+def test_first_token_events_ride_the_snapshot():
+    t = StepTelemetry()
+    snap = t.snapshot()
+    assert snap["first_token_events"] == snap["first_token_events_fed"] == 0
+    t.count_first_tokens(fed=True)
+    t.count_first_tokens(fed=False)  # met, and read before the dispatch
+    snap = t.snapshot()
+    assert snap["first_token_events"] == 2
+    assert snap["first_token_events_fed"] == 1
+    assert snap["events_dispatched_ahead"] == 0      # counted apart
+
+
+def test_first_token_events_are_all_fed_with_no_drafter(tiny_model):
+    """A run of admissions, batches and a long prompt's final chunk among
+    them, with no drafter and no preemption: every decode dispatch that
+    met first tokens on the device went out before their read."""
+    from scalable_hw_agnostic_inference_tpu.engine.engine import (
+        SamplingParams,
+    )
+
+    eng = make_engine(tiny_model)
+    assert eng._async and eng._drafter is None
+    sp = SamplingParams(temperature=0.0, max_new_tokens=6)
+    prompts = [[3, 4, 5], [8, 8, 9], [5, 6], [1] + [7, 9, 11] * 13, [4, 2]]
+    arrivals = {0: prompts[:1], 2: prompts[1:3], 4: prompts[3:], 9: [[7]]}
+    step = 0
+    while eng.has_work or step <= max(arrivals):
+        for prompt in arrivals.get(step, ()):
+            eng.add_request(prompt, sp)
+        if eng.has_work:
+            eng.step()
+        step += 1
+    snap = eng.obs.snapshot()
+    assert snap["preemptions"] == 0
+    assert snap["requests_finished"] == 6
+    assert snap["first_token_events_fed"] == snap["first_token_events"] >= 4
+
+
 def _gap(eng):
     snap = eng.obs.step_gap.snapshot()
     return snap["count"], snap["sum"]
@@ -344,10 +382,12 @@ def _gap(eng):
                          ids=["program-still-queued", "device-drained"])
 def test_step_gap_of_an_event_step(tiny_model, monkeypatch, queued):
     """``step_gap`` is how long the device had nothing queued before a
-    decode dispatch. On an event step that is nothing where the dispatch
-    found a program of the step still running, and otherwise no longer
-    than the time since the first tokens' read returned: not the time
-    since step N was retired, which lies before the marshal."""
+    decode dispatch. An event step feeds the first tokens on the device
+    and dispatches before it reads them: the gap is nothing where the
+    dispatch found a program of the step still running, and otherwise no
+    longer than the time since the last blocking read returned, which is
+    the flush's of step N now that the first tokens' lies behind the
+    dispatch. Such a step counts itself met and fed."""
     from scalable_hw_agnostic_inference_tpu.engine.engine import (
         SamplingParams,
     )
@@ -392,16 +432,19 @@ def test_step_gap_of_an_event_step(tiny_model, monkeypatch, queued):
         monkeypatch.setattr(eng, "_program_queued", lambda: True)
     eng.add_request([8, 8, 9], sp)
     n0, sum0 = _gap(eng)
+    before = eng.obs.snapshot()
     eng.step()                       # admits behind the lookahead
     n1, sum1 = _gap(eng)
     assert n1 == n0 + 1
-    assert stamps["retired"] < stamps["first_read"] <= stamps["dispatch"]
+    assert stamps["retired"] < stamps["dispatch"] <= stamps["first_read"]
     if queued:
         assert sum1 == sum0
     else:
         assert 0.0 <= sum1 - sum0 <= (stamps["dispatch"]
-                                      - stamps["first_read"]) + 1e-9
-        assert sum1 - sum0 < stamps["dispatch"] - stamps["retired"]
+                                      - stamps["retired"]) + 1e-9
+    snap = eng.obs.snapshot()
+    for key in ("first_token_events", "first_token_events_fed"):
+        assert snap[key] == before[key] + 1
     while eng.has_work:
         eng.step()
 
@@ -768,6 +811,33 @@ async def test_events_dispatched_ahead_on_stats_and_metrics(spec_app):
     assert (sum(eng["ahead_by_reason"].values())
             == eng["events_dispatched_ahead"])
     assert "flush_by_reason" in eng
+
+
+@pytest.mark.asyncio
+async def test_first_token_events_on_stats_and_metrics(spec_app):
+    """Both counters beside ``events_dispatched_ahead``, on ``/stats`` and
+    in the Prometheus text. This app runs a drafter, which reads the
+    pending token on the host before anything is dispatched: events are
+    met, none is fed, and that is what it says."""
+    pytest.importorskip("prometheus_client")
+    cfg, service, app = spec_app
+    async with make_client(app) as c:
+        await wait_ready(c, timeout=600.0)
+        await c.post("/generate", json={"prompt": "s t u s t u",
+                                        "temperature": 0.0,
+                                        "max_new_tokens": 4})
+        text = (await c.get("/metrics")).text
+        eng = (await c.get("/stats")).json()["engine"]
+
+    def scraped(name):
+        line = next(ln for ln in text.splitlines()
+                    if ln.startswith(name + '_total{app="llm-obs"}'))
+        return float(line.split()[-1])
+
+    assert scraped("shai_engine_first_token_events") \
+        >= eng["first_token_events"] >= 1
+    assert scraped("shai_engine_first_token_events_fed") \
+        == eng["first_token_events_fed"] == 0
 
 
 @pytest.mark.asyncio
