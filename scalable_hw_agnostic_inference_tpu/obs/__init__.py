@@ -16,6 +16,9 @@ and the flight recorder.
 - ``obs.slo``       per-model TTFT/TPOT/error objectives as rolling
                     multi-window burn rates (the failover trigger feed)
 - ``obs.sentinel``  live tok/s vs PERF_MODEL.json projection conformance
+- ``obs.stops``     when the process did not run: garbage-collection pauses
+                    from ``gc.callbacks``, whole-process stops from a
+                    heartbeat thread, each with its cause
 
 Layering: ``obs`` imports nothing from the rest of the package (and no
 third-party deps), so engine AND serve may both depend on it.
@@ -29,6 +32,7 @@ from .flight import FlightRecorder  # noqa: F401
 from .hbm import DriftDetector, HbmLedger  # noqa: F401
 from .sentinel import PerfSentinel  # noqa: F401
 from .slo import SloEngine, SloTargets  # noqa: F401
+from .stops import ProcessStops  # noqa: F401
 from .steploop import (  # noqa: F401
     BucketHistogram,
     QUEUE_WAIT_BUCKETS,

@@ -86,9 +86,13 @@ class FlightRecorder:
 
     def dump(self, step_source: Optional[Callable[[int],
                                                   List[Dict]]] = None,
-             n_requests: Optional[int] = None) -> Dict[str, Any]:
-        """The ``/debug/flight`` payload: newest-last request timelines and
-        (when an engine feed exists) the recent step records."""
+             n_requests: Optional[int] = None,
+             stop_source: Optional[Callable[[], List[Dict]]] = None
+             ) -> Dict[str, Any]:
+        """The ``/debug/flight`` payload: newest-last request timelines,
+        (when an engine feed exists) the recent step records and (when the
+        process counts them: ``obs.stops``) the recent stops and full
+        collections, which an operator joins to a stalled step by time."""
         with self._lock:
             reqs = list(self._requests)
             total = self._seq
@@ -102,10 +106,13 @@ class FlightRecorder:
                          "steps": self.max_steps},
             "requests": reqs,
             "engine_steps": [],
+            "stops": [],
         }
         if step_source is not None:
             try:
                 out["engine_steps"] = step_source(self.max_steps)
             except Exception as e:  # a dead engine must not break the dump
                 out["engine_steps_error"] = f"{type(e).__name__}: {e}"
+        if stop_source is not None:
+            out["stops"] = stop_source()
         return out

@@ -93,6 +93,11 @@ _STEP_FIELD = {"engine.fetch": "fetch_ms", "engine.apply": "apply_ms",
                "engine.chunk": "dispatch_ms", "engine.verify": "dispatch_ms",
                "engine.decode": "dispatch_ms"}
 _STEP_FIELDS = tuple(dict.fromkeys(_STEP_FIELD.values()))
+#: a step is STALLED where its duration passes ten times the ring's median,
+#: and this floor: a 47 ms chunk step beside 3 ms decode steps is the
+#: schedule's, not a stall, and the shortest stop worth a name is 0.1 s
+STALL_FLOOR_S = 0.1
+STALL_TIMES_MEDIAN = 10.0
 #: bounded tenant-label cardinality for the per-tenant instruments: at
 #: most this many distinct tenants get their own label; later arrivals
 #: collapse into "other" so a hostile client minting tenant names cannot
@@ -455,6 +460,10 @@ class StepTelemetry:
         # engine when SHAI_QOS is on: its pick/aging counters ride the
         # same provider seam into /stats -> "qos"
         self.qos_sched = None
+        # the process's collections and stops (obs.stops.ProcessStops):
+        # attached by the serving app that started it, so its groups ``gc``
+        # and ``stops`` ride the snapshot; an engine with no app has none
+        self.stops = None
         # per-tenant attribution (bounded: MAX_TENANT_LABELS + "other"):
         # cumulative request/finish counts, TTFT histograms, and the
         # last-step waiting/running gauges the engine feeds when QoS (or
@@ -515,6 +524,16 @@ class StepTelemetry:
         self._step_ms: Dict[str, float] = {}
         self._step_no = 0
         self._waiting_peak = 0
+        # stalled steps: the ring's median duration (taken once a ring's
+        # length of judged steps: a sort of 256 a step is 20 us the loop
+        # thread does not have; none until the ring has filled once), the
+        # judged steps since, and what was counted
+        self._stall_median_s: Optional[float] = None
+        self._stall_every = max(1, max_steps)
+        self._stall_judged = 0
+        self._stall_steps = 0
+        self._stall_excess_s = 0.0
+        self._stall_by_phase: Dict[str, int] = {}
         # cumulative counters
         self.steps = 0
         self.preemptions = 0
@@ -1019,6 +1038,8 @@ class StepTelemetry:
             rec["preemptions_total"] = self.preemptions
             rec["recompiles_total"] = self.recompiles
             self._steps.append(rec)
+            if kind != "idle":
+                self._judge_stall(rec, duration_s)
             self._gauges = {
                 "running": float(n_running),
                 "waiting": float(n_waiting),
@@ -1043,7 +1064,36 @@ class StepTelemetry:
                     ent["running"] = int(n_run)
             self._last_step_mono = time.monotonic()
 
+    def _judge_stall(self, rec: Dict[str, Any], duration_s: float) -> None:
+        """Mark and count ``rec`` (the ring's newest) if it stalled; the
+        caller holds ``_lock``. The phase that held it is the record's
+        longest field: ``fetch`` says the device or a read, anything else
+        the host. No clock is read: the duration and the fields are the
+        step's own."""
+        median = self._stall_median_s
+        if median is not None and duration_s > max(
+                STALL_FLOOR_S, STALL_TIMES_MEDIAN * median):
+            rec["stalled"] = True
+            field = max(_STEP_FIELDS, key=rec.__getitem__)
+            phase = field[:-3] if rec[field] > 0 else "other"
+            self._stall_steps += 1
+            self._stall_excess_s += duration_s - median
+            self._stall_by_phase[phase] = (
+                self._stall_by_phase.get(phase, 0) + 1)
+        self._stall_judged += 1
+        if self._stall_judged >= self._stall_every:
+            self._stall_judged = 0
+            # shai-lint: allow(guarded-read) caller-holds-lock helper
+            durations = sorted(r["duration_s"] for r in self._steps
+                               if r["kind"] != "idle")
+            self._stall_median_s = durations[(len(durations) - 1) // 2]
+
     # -- readouts ----------------------------------------------------------
+
+    def open_phase(self) -> Optional[str]:
+        """The phase the loop thread has open now: one unlocked read, for
+        the record of a stop (``obs.stops``)."""
+        return self._phase
 
     def last_step_age_s(self, now: Optional[float] = None) -> float:
         """Seconds since the last completed engine step (since construction
@@ -1118,7 +1168,12 @@ class StepTelemetry:
             out["phase_cpu_s"] = dict(self.phase_cpu_s)
             out["phase_cpu_wall_s"] = dict(self.phase_cpu_wall_s)
             out.update(self._gauges)
+            out["stall"] = {"steps": self._stall_steps,
+                            "excess_s": self._stall_excess_s,
+                            "steps_by_phase": dict(self._stall_by_phase)}
         out["stream"] = self.stream_snapshot()
+        if self.stops is not None:
+            out.update(self.stops.snapshot())   # ``gc`` and ``stops``
         kvt = self.kvtier
         if kvt is not None:
             # host-tier saturation + hit rate travel with the engine

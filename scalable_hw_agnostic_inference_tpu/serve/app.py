@@ -32,6 +32,7 @@ import time
 from typing import Any, Callable, Dict, List, Optional, Tuple
 
 from ..obs import FlightRecorder
+from ..obs import stops as obs_stops
 from ..obs import trace as obs_trace
 from ..resilience import deadline as rz_deadline
 from ..resilience import faults as rz_faults
@@ -328,6 +329,14 @@ def create_app(
     # gauges/counters, resolved lazily at scrape time
     pub.attach_engine_telemetry(service.engine_telemetry)
     pub.attach_idempotency(lambda: idem)
+    # when the process did not run (obs.stops): started with the app and
+    # stopped with it, below; a stop's record names the phase the engine
+    # loop had open, through the same lazy seam the telemetry comes by
+    proc_stops = obs_stops.PROCESS
+
+    def _loop_phase() -> Optional[str]:
+        tele = service.engine_telemetry()
+        return None if tele is None else tele.open_phase()
     # the model lane: probes never queue behind it. Width 1 serializes device
     # access; engine-backed services widen it (their infer only enqueues).
     lane = concurrent.futures.ThreadPoolExecutor(
@@ -348,6 +357,10 @@ def create_app(
         t0 = time.perf_counter()
         try:
             service.load()
+            tele = service.engine_telemetry()
+            if tele is not None:
+                # /stats engine.gc|stops, the shai_process_* families
+                tele.stops = proc_stops
             state["loaded"] = True
             log.info("%s: model loaded in %.1fs", cfg.app, time.perf_counter() - t0)
             if cfg.warmup:
@@ -366,7 +379,18 @@ def create_app(
         # Loading runs on the model lane, NOT the event loop: the listen
         # socket binds immediately and /health + /readiness answer during the
         # multi-minute cold compile (/readiness returns 503 "loading").
+        # The instrument first: the warm-up's collections are counted.
+        proc_stops.loop_phase = _loop_phase
+        proc_stops.start()
         state["load_future"] = lane.submit(_do_load_and_warm)
+
+    @app.shutdown
+    def _stop_instruments():
+        proc_stops.stop()
+        if proc_stops.loop_phase is _loop_phase:
+            # the process's instrument outlives the app: it must not keep
+            # the app's service alive, nor ask a dead engine for its phase
+            proc_stops.loop_phase = None
 
     async def _run_model(fn: Callable, *args):
         loop = asyncio.get_running_loop()
@@ -1094,7 +1118,8 @@ def create_app(
     @app.get("/debug/flight")
     def debug_flight(request: Request):
         """Postmortem dump: the last-N completed request timelines (span
-        trees, W3C trace ids) + the last-M engine step records. Bounded
+        trees, W3C trace ids) + the last-M engine step records + the last
+        stops and full collections of the process (``stops``). Bounded
         rings — safe to curl on a degraded pod at any time."""
         n_req = None
         if "requests" in request.query:
@@ -1103,7 +1128,7 @@ def create_app(
             except ValueError:
                 raise HTTPError(400, "requests must be an integer")
         return flight.dump(step_source=service.step_records,
-                           n_requests=n_req)
+                           n_requests=n_req, stop_source=proc_stops.recent)
 
     @app.get("/trace/{trace_id}")
     def trace_by_id(request: Request, trace_id: str):

@@ -212,6 +212,41 @@ _CONV_COUNTERS = ("shai_engine_conv_total",
                   "that read a slot's tail), rows_stepped (live rows x conv "
                   "layers in decode dispatches), slots_live (gauge: arena "
                   "slots held)")
+#: when the pod did not run, and by what it was stopped (obs.stops: the
+#: snapshot's ``gc`` and ``stops`` groups, there once the serving app has
+#: started the instrument): (family, label, the group's key for a label
+#: value). Runbook: a token gap every stream of the pod shares is one of
+#: these; ``cause="frozen"`` is the machine's, not the program's.
+_PROCESS_COUNTERS = {
+    "gc": (
+        ("shai_process_gc_pause_seconds_total",
+         "Seconds every thread of the pod stood still inside a garbage "
+         "collection, by the generation collected (2: the whole heap)",
+         "generation", "pause_s_gen{}", ("0", "1", "2")),
+        ("shai_process_gc_collections_total",
+         "Garbage collections, by the generation collected",
+         "generation", "collections_gen{}", ("0", "1", "2"))),
+    "stops": (
+        ("shai_process_stopped_seconds_total",
+         "Seconds the process's heartbeat thread woke late by (over 50 ms "
+         "behind a 20 ms sleep), by cause: gc (a collection covers it), "
+         "frozen (the process's CPU clock stood still: the machine stopped "
+         "it), starved (a thread kept the interpreter lock)",
+         "cause", "{}_s", ("frozen", "starved", "gc")),
+        ("shai_process_stops_total",
+         "Late wakes of the heartbeat thread, by cause",
+         "cause", "count_{}", ("frozen", "starved", "gc"))),
+}
+#: engine steps whose duration passed ten times the step ring's median and
+#: 0.1 s (obs.steploop ``stall``), by the phase that held most of the step
+#: (``fetch``: the device or a read; anything else: the host), and the
+#: seconds they ran over the median
+_STALLED_STEPS = ("shai_engine_stalled_steps_total",
+                  "Engine steps that took over ten times the step ring's "
+                  "median duration (and over 0.1 s), by the step's longest "
+                  "phase")
+_STALLED_SECONDS = ("shai_engine_stalled_seconds_total",
+                    "Seconds stalled steps ran over the median step")
 #: conformance-layer gauge families: each instrument riding the engine
 #: telemetry object exports its flat numeric snapshot verbatim under a
 #: prefix — obs.slo → shai_slo_* (per-objective burn rates + breach),
@@ -409,6 +444,23 @@ class EngineTelemetryCollector:
                 for counter, v in sorted(snap[key].items()):
                     c.add_metric([self.app, counter], float(v))
                 yield c
+        for group, families in _PROCESS_COUNTERS.items():
+            if group in snap:
+                for name, doc, label, key, values in families:
+                    c = CounterMetricFamily(name, doc, labels=["app", label])
+                    for v in values:
+                        c.add_metric([self.app, v],
+                                     float(snap[group].get(key.format(v), 0)))
+                    yield c
+        stall = snap.get("stall")
+        if stall is not None:
+            c = CounterMetricFamily(*_STALLED_STEPS, labels=["app", "phase"])
+            for phase, n in sorted(stall["steps_by_phase"].items()):
+                c.add_metric([self.app, phase], float(n))
+            yield c
+            c = CounterMetricFamily(*_STALLED_SECONDS, labels=["app"])
+            c.add_metric([self.app], float(stall["excess_s"]))
+            yield c
         hists = tele.histograms()
         for key, (name, doc) in ENGINE_HISTOGRAMS.items():
             hs = hists.get(key)
